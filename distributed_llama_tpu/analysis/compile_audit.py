@@ -5,8 +5,8 @@ the forward step per window bucket, the K-step scan per (k, mode), the
 verify block per (T, mode) — each dispatched at a fixed set of array
 shapes/dtypes. Recompile creep (a new T bucket minted on the latency path, a
 dtype drifting through a refactor, a shape leaking per-request) is invisible
-to unit tests and BENCH_r03/r04-class expensive on hardware: XLA compiles
-mid-traffic and the request eating the compile times out.
+to unit tests and expensive on hardware: XLA compiles mid-traffic and the
+request eating the compile times out.
 
 This auditor is runtime-assisted: `CompileAudit` patches the program
 factories (`make_sharded_forward`, `make_decode_loop`,

@@ -43,7 +43,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 
 # first-party scan roots, mirroring the original perf/smoke_lint.py scope
 SCAN_DIRS = ("distributed_llama_tpu", "tests", "perf", "examples")
-TOP_FILES = ("bench.py", "launch.py", "__graft_entry__.py")
+TOP_FILES = ("bench.py", "chip_smoke.py", "launch.py", "__graft_entry__.py")
 
 _SUPPRESS_RE = re.compile(
     r"#\s*dlint:\s*ignore\[([^\]]*)\](\s*--\s*(.*\S))?")

@@ -1,9 +1,8 @@
 """Hot-path host-sync lint (rules `hot-sync`, `hot-impure`).
 
-BENCH_r03/r04 documented the failure mode this pass exists for: a silent
-device->host sync (or an accidental recompile) landing on the decode hot
-path and reaching hardware undetected, halving throughput with no test
-failing. The conventions:
+The failure mode this pass exists for: a silent device->host sync (or an
+accidental recompile) landing on the decode hot path and reaching hardware
+undetected, halving throughput with no test failing. The conventions:
 
     def _issue_super_step(...):  # hot-path
         A host-side hot function (scheduler issue/deliver/chain paths, the

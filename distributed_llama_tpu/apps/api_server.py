@@ -29,6 +29,7 @@ from urllib.parse import parse_qs, urlsplit
 from ..obs import flight, metrics, reqctx, trace
 from ..obs.process import install_process_metrics
 from ..ops import matmul as matmul_ops
+from ..platform_env import describe, xla_compiles
 from ..resilience import faults
 from ..resilience.errors import (DeadlineExceeded, EngineClosed,
                                  EngineDraining, EngineSaturated,
@@ -202,6 +203,12 @@ class ApiState:
         # resolved lazily on the first response_format request
         self.constrain_vocab: list[bytes] | None = None
         self.model_name = "distributed-llama-tpu"
+        # the block the start-up line printed, served by /healthz and
+        # /v1/stats: a replica on the wrong device says so to whoever polls
+        # it. Static for the life of the process.
+        inner = batch_engine._eng if batch_engine is not None else engine
+        self.device = describe(inner.dtype, inner.use_pallas)
+        xla_compiles.install()  # /v1/stats "compile" (no-op after start())
 
 
 def _now() -> int:
@@ -276,6 +283,8 @@ def _stats_payload(state: "ApiState") -> dict:
     rather than a Prometheus scraper."""
     out: dict = {"model": state.model_name, "time": _now(),
                  "replica": _load_block(state),
+                 "device": state.device,
+                 "compile": xla_compiles.snapshot(),
                  "metrics": metrics.snapshot()}
     if state.supervisor is not None:
         out["supervisor"] = state.supervisor.stats()
@@ -334,6 +343,9 @@ def _stats_payload(state: "ApiState") -> dict:
     if inner is not None:
         out["kernels"] = {"policy": str(inner.use_pallas),
                           "fused_matmul": bool(inner.fused_matmul),
+                          # paged-attention reader of the device block pool:
+                          # the Pallas kernel, or the XLA gather
+                          "paged_kernel": bool(inner.paged_kernel),
                           "selections": matmul_ops.kernel_selections()}
     return out
 
@@ -869,13 +881,14 @@ class Handler(BaseHTTPRequestHandler):
             be = self.state.batch_engine
             alive = be is None or be.scheduler_alive()
             sup = self.state.supervisor
-            replica = _load_block(self.state)  # identity+load for routers
+            # identity+load for routers, and the device this replica is on
+            who = {"replica": _load_block(self.state),
+                   "device": self.state.device}
             if self.state.draining or (be is not None and be.draining):
-                self._json(503, {"status": "draining", "replica": replica})
+                self._json(503, {"status": "draining", **who})
             elif not alive:
                 self._json(503, {"status": "unhealthy",
-                                 "reason": "scheduler thread dead",
-                                 "replica": replica})
+                                 "reason": "scheduler thread dead", **who})
             elif sup is not None and not sup.healthy:
                 # the supervisor caught a wedged engine: stay out of fleet
                 # rotation for the recovery window (or permanently, state
@@ -883,9 +896,9 @@ class Handler(BaseHTTPRequestHandler):
                 # requests elsewhere (docs/ROBUSTNESS.md)
                 self._json(503, {"status": "unhealthy",
                                  "reason": f"supervisor: engine {sup.state}",
-                                 "replica": replica})
+                                 **who})
             else:
-                self._json(200, {"status": "ok", "replica": replica})
+                self._json(200, {"status": "ok", **who})
         elif self.path == "/metrics":
             self._raw(200, "text/plain; version=0.0.4; charset=utf-8",
                       metrics.render().encode())
@@ -1329,10 +1342,7 @@ def install_sigterm_drain(server: ThreadingHTTPServer, state: ApiState,
 
 
 def main(argv=None) -> None:
-    from ..platform_env import apply_platform_env
-
-    apply_platform_env()
-    from .dllama import build_parser, make_engine, make_sampler
+    from .dllama import build_parser, make_engine, make_sampler, startup
 
     p = build_parser(include_mode=False)
     p.add_argument("--port", type=int, default=9990)
@@ -1477,6 +1487,7 @@ def main(argv=None) -> None:
                         "batch-class admissions are refused (they would "
                         "widen every shared dispatch further); 0 = off")
     args = p.parse_args(argv)
+    startup(args)
     from .dllama import dump_trace, install_trace
 
     install_trace(args)
@@ -1496,12 +1507,9 @@ def main(argv=None) -> None:
             p.error("--kv-cache-storage host|disc requires --batch 1: the "
                     "paged cache is single-sequence. For long-context serving "
                     "use --sp (more chips) or --batch 1.")
-        import jax.numpy as jnp
-
         from ..runtime.batch_engine import BatchEngine
-        from .dllama import _FT, init_pod
+        from .dllama import _FT, policy_kwargs
 
-        init_pod(args)
         batch_engine = BatchEngine.load(
             args.model, args.tokenizer, max_seq_len=args.max_seq_len,
             weights_ftype=_FT[args.weights_float_type] if args.weights_float_type
@@ -1528,10 +1536,7 @@ def main(argv=None) -> None:
             tp=args.tp, dp=args.dp, pod=args.pod,
             cache_write=args.cache_write, moe_sharding=args.moe_sharding,
             fused_prologue=args.prologue, prefill_kernel=args.prefill_kernel,
-            fused_matmul=args.fused_matmul,
-            dtype=(None if args.dtype == "auto"
-                   else jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32),
-            use_pallas=False if args.no_pallas else None,
+            fused_matmul=args.fused_matmul, **policy_kwargs(args),
             compress_collectives=args.buffer_float_type == "q80" and (args.tp or 1) > 1)
         engine = None
         sampler = make_sampler(args, batch_engine.spec)

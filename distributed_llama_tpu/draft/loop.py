@@ -124,9 +124,7 @@ def make_draft_loop(spec: ModelSpec, mesh, params, steps: int, *,
             jnp.arange(steps, dtype=jnp.int32))
         return toks, pos, kc, vc
 
-    from ..compat import shard_map
-
-    sharded = shard_map(
+    sharded = jax.shard_map(
         loop, mesh=mesh,
         in_specs=(param_specs, P(), P(), P(), kv_spec, kv_spec, P(), P(),
                   P()),

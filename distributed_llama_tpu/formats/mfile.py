@@ -252,8 +252,20 @@ def write_header(f: BinaryIO, spec: ModelSpec, weights_ftype: FloatType) -> None
     f.write(data)
 
 
-def write_tensor(f: BinaryIO, x: np.ndarray, ftype: FloatType) -> int:
-    """Flattened tensor -> reference byte stream (converter/writer.py:96-107)."""
+def write_tensor(f: BinaryIO, x: np.ndarray | QTensor, ftype: FloatType) -> int:
+    """Flattened tensor -> reference byte stream (converter/writer.py:96-107).
+    A planar QTensor already in `ftype` is written as it stands: a synthetic
+    checkpoint at a published size draws its blocks directly and never exists
+    in f32 (examples/make_tiny_model.py --arch)."""
+    if isinstance(x, QTensor):
+        if x.ftype != ftype or x.layout != "planar" or ftype not in (
+                FloatType.Q40, FloatType.Q80):
+            raise ValueError(f"cannot write a {x.layout} {x.ftype.name} "
+                             f"QTensor as {ftype.name}")
+        to_bytes = q40_to_bytes if ftype == FloatType.Q40 else q80_to_bytes
+        buf = to_bytes(np.asarray(x.data), np.asarray(x.scales))
+        f.write(buf)
+        return len(buf)
     flat = np.asarray(x, dtype=np.float32).reshape(-1)
     if ftype == FloatType.F32:
         buf = flat.astype("<f4").tobytes()
@@ -270,10 +282,11 @@ def write_tensor(f: BinaryIO, x: np.ndarray, ftype: FloatType) -> int:
 
 
 def write_model(path: str, spec: ModelSpec, tensors_iter, weights_ftype: FloatType) -> None:
-    """Write a `.m` from an iterator of (name, np.ndarray) in file order.
+    """Write a `.m` from an iterator of (name, np.ndarray | QTensor) in file order.
 
     `tensors_iter` must yield tensors in the exact order documented in load_model; norms
     and embedding are forced F32 regardless of weights_ftype (convert-llama.py:79-85).
+    A tensor may arrive in several consecutive row chunks under the same name.
     """
     norm_names = {"embedding", "rms_att", "rms_ffn", "rms_moe", "rms_ffn2", "rms_final"}
     with open(path, "wb") as f:

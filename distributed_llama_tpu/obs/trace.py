@@ -27,7 +27,7 @@ Design constraints, in priority order:
 
 Optional `jax.profiler` pass-through: with `jax_annotations=True` each span
 also enters a jax.profiler.TraceAnnotation, so the spans show up inside an
-XLA device trace (perf/PROFILE.md workflow) under the same names.
+XLA device trace under the same names.
 """
 
 from __future__ import annotations

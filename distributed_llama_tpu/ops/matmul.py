@@ -24,8 +24,10 @@ import jax
 import jax.numpy as jnp
 
 from ..obs import metrics
+from ..platform_env import interpret_requested
 from ..quants import QTensor
 from ..resilience import faults
+from ..resilience.errors import FaultInjected, TransientDispatchError
 
 # "fused" is a strict superset of "all": everything "all" lowers plus the
 # residual-add / silu·mul epilogue fusions wired through models/forward.py
@@ -102,23 +104,32 @@ def qmatmul(x: jax.Array, w: QTensor, *, use_pallas: bool | str = False,
     if use_pallas in FUSED_POLICIES and m > 1 and w.layout == "i4p":
         from .pallas_q4_mm import q4_matmul, q4_mm_supported
 
-        try:
-            # fires BEFORE the shape gate so the fault-matrix cells are
-            # non-vacuous on any fused engine; any raise degrades to XLA
-            faults.fire("matmul.kernel_select", m=m, n=w.shape[0])
-            if q4_mm_supported(w, m):
-                fuse_res = residual is not None and use_pallas == "fused"
-                y = q4_matmul(x, w, out_dtype=out_dtype or x.dtype,
-                              residual=residual if fuse_res else None)
-                _record("q4_mm+res" if fuse_res else "q4_mm", m, w)
-                if residual is not None and not fuse_res:
-                    return _res_add(y, residual, out_dtype or x.dtype)
-                return y
-        except Exception:  # noqa: BLE001 — any kernel-path failure -> XLA
-            _record("xla-fallback", m, w)
+        if not _kernel_select_ok(m, w):
             return _qmatmul_xla(x, w, out_dtype=out_dtype, residual=residual)
+        if q4_mm_supported(w, m):
+            fuse_res = residual is not None and use_pallas == "fused"
+            y = q4_matmul(x, w, out_dtype=out_dtype or x.dtype,
+                          residual=residual if fuse_res else None)
+            _record("q4_mm+res" if fuse_res else "q4_mm", m, w)
+            if residual is not None and not fuse_res:
+                return _res_add(y, residual, out_dtype or x.dtype)
+            return y
     _record("xla", m, w)
     return _qmatmul_xla(x, w, out_dtype=out_dtype, residual=residual)
+
+
+def _kernel_select_ok(m: int, w: QTensor, op: str = "mm") -> bool:
+    """The `matmul.kernel_select` injection point (docs/ROBUSTNESS.md): fires
+    BEFORE the shape gate so the fault-matrix cells are non-vacuous on any
+    fused engine. An injected fault degrades that call site to the XLA
+    lowering, recorded as `xla-fallback`; nothing else is caught here, so a
+    kernel that fails to trace or lower fails the step that asked for it."""
+    try:
+        faults.fire("matmul.kernel_select", m=m, n=w.shape[0])
+    except (FaultInjected, TransientDispatchError):
+        _record("xla-fallback", m, w, op=op)
+        return False
+    return True
 
 
 def _res_add(y: jax.Array, residual: jax.Array, out_dtype) -> jax.Array:
@@ -156,17 +167,14 @@ def qmatmul_gated(x: jax.Array, w1: QTensor, w3: QTensor, *, act,
             and act_name in ("silu", "gelu_tanh")):
         from .pallas_q4_mm import q4_gated_matmul, q4_gated_supported
 
-        try:
-            faults.fire("matmul.kernel_select", m=m, n=w1.shape[0])
-            if q4_gated_supported(w1, w3, m):
-                y = q4_gated_matmul(x, w1, w3, act=act_name,
-                                    out_dtype=out_dtype or x.dtype)
-                _record("q4_gated_mm", m, w1, op="gated")
-                return y
-        except Exception:  # noqa: BLE001 — any kernel-path failure -> XLA
-            _record("xla-fallback", m, w1, op="gated")
+        if not _kernel_select_ok(m, w1, op="gated"):
             return (act(_qmatmul_xla(x, w1, out_dtype=out_dtype))
                     * _qmatmul_xla(x, w3, out_dtype=out_dtype))
+        if q4_gated_supported(w1, w3, m):
+            y = q4_gated_matmul(x, w1, w3, act=act_name,
+                                out_dtype=out_dtype or x.dtype)
+            _record("q4_gated_mm", m, w1, op="gated")
+            return y
     return (act(qmatmul(x, w1, use_pallas=use_pallas, out_dtype=out_dtype))
             * qmatmul(x, w3, use_pallas=use_pallas, out_dtype=out_dtype))
 
@@ -189,14 +197,14 @@ def qmatmul_q80(xq: jax.Array, sx: jax.Array, w: QTensor, *,
 
             if w.groups == 1 and q4_decode_supported(w):
                 y = _q4_matvec_inline(xq, sx, w.data, w.scales,
-                                      interpret=jax.default_backend() != "tpu")
+                                      interpret=interpret_requested())
                 return y.reshape(1, 1, y.shape[0]).astype(out_dtype)
         elif w.layout == "i8":
             from .pallas_q8 import _q8_matvec_inline, q8_decode_supported
 
             if q8_decode_supported(w):
                 y = _q8_matvec_inline(xq, sx, w.data, w.scales,
-                                      interpret=jax.default_backend() != "tpu")
+                                      interpret=interpret_requested())
                 return y.reshape(1, 1, y.shape[0]).astype(out_dtype)
     xhat = jnp_dequantize_i8(xq, sx, dtype=jnp.float32)  # (1, K)
     wd = w.dequantize(dtype=jnp.float32)

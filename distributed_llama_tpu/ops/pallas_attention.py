@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..platform_env import interpret_requested
+
 _NEG = -1e30  # f32 mask value; exp(_NEG - max) == 0 exactly in f32
 
 
@@ -133,7 +135,7 @@ def fused_decode_attention(q, kc, vc, k_new, v_new, layer_idx, pos, *,
     Returns (hk, g, hs) f32.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_requested()
     hk, g, hs = q.shape
     l, b, hk2, s, hs2 = kc.shape
     assert b == 1 and hk2 == hk and hs2 == hs, (q.shape, kc.shape)

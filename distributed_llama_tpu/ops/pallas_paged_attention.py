@@ -18,7 +18,7 @@ live here:
   flash-attention (m, l, acc) carry in VMEM scratch merges the blocks; the
   current chunk's uncommitted K/V (T = 1 for the decode scan, T = 1+k for
   the speculative verify dispatch) folds in at the last grid step with an
-  in-chunk causal mask. f16 never appears (BENCH_r03's mosaic 'f16' trap):
+  in-chunk causal mask. f16 never appears (Mosaic cannot lower f16 refs):
   cache blocks load in their storage dtype and are cast to f32 in-kernel.
 
 Numerics: the kernel's blockwise online softmax is mathematically exact but
@@ -35,6 +35,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..platform_env import interpret_requested
 
 _NEG = -1e30  # f32 mask value; exp(_NEG - max) == 0 exactly in f32
 
@@ -138,7 +140,7 @@ def paged_attention(q, kc, vc, k_new, v_new, tables, lengths, layer_idx, *,
     Returns (B, T, hq, hs) f32.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_requested()
     b, t, hq, hs = q.shape
     l, n, hk, bt, hs2 = kc.shape
     assert hs2 == hs and k_new.shape == (b, hk, t, hs), (q.shape, kc.shape,

@@ -17,11 +17,12 @@ quantized row is the only activation HBM traffic.
 
 Numerics: the rmsnorm reduction runs in f32 with the same mean-square + eps
 formula as ops.kernels.rmsnorm (reference funcs.cpp rms(), eps inside the mean);
-quantization IS pallas_q8._quantize_row (shared helper, pure jnp, usable inside
-kernel bodies). Mosaic portability: all intermediates are f32/i32 except the
-final i8 cast — every op is in the known-good set (perf/PROFILE.md op matrix);
-no f16, no narrow-int arithmetic, no sub-32-bit minor-dim insertion (the one
-f32 minor-dim insert is 32-bit, which Mosaic supports).
+the quantization IS pallas_q8._quantize_blocks (shared helper, pure jnp, usable
+inside kernel bodies). Mosaic portability: the kernels see the row block-major,
+(K/32, 32) with one quant block per row, a view taken in XLA where it is free:
+splitting a (1, K) lane vector into blocks in-kernel is a shape cast the chip's
+compiler refuses. All intermediates are f32 except the final i8 cast; no f16,
+no narrow-int arithmetic.
 
 Opt-in (Engine fused_prologue / bench --prologue) until a hardware A/B lands —
 the round-4 lesson is not to ship never-executed kernels as defaults.
@@ -36,29 +37,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..platform_env import interpret_requested
 from ..quants import QK
 
 
-def _quantize_store(xb, xq_ref, sx_ref):
-    """Shared epilogue: per-32-block absmax quantize of xb (1, K) f32 into the
-    int8 row + f32 block-scale outputs. The math is pallas_q8._quantize_row
-    itself (pure jnp, kernel-body safe) — one source of truth for the Q80
+def _quantize_store(g, xq_ref, sx_ref):
+    """Shared epilogue: per-block absmax quantize of g (nb, QK) f32 — one
+    quant block per row — into the int8 blocks + f32 block scales. The math
+    is pallas_q8._quantize_blocks itself: one source of truth for the Q80
     formula."""
-    from .pallas_q8 import _quantize_row
+    from .pallas_q8 import _quantize_blocks
 
-    k = xb.shape[1]
-    xq, sx = _quantize_row(xb.reshape(k), k // QK)
-    xq_ref[:] = xq.reshape(1, k)
-    sx_ref[:] = sx
+    xq_ref[:], sx_ref[:] = _quantize_blocks(g)
 
 
 def _rmsnorm_q80_kernel(x_ref, w_ref, xq_ref, sx_ref, *, eps: float):
-    x = x_ref[:].astype(jnp.float32)  # (1, K)
-    k = x.shape[1]
-    ms = jnp.sum(x * x, axis=1, keepdims=True) / k  # (1, 1), f32 reduction
+    x = x_ref[:].astype(jnp.float32)  # (nb, QK)
+    ms = jnp.sum(x * x) / x.size  # f32 reduction over the whole row
     inv = jnp.reciprocal(jnp.sqrt(ms + eps))
-    xb = x * inv * w_ref[:].astype(jnp.float32)
-    _quantize_store(xb, xq_ref, sx_ref)
+    _quantize_store(x * inv * w_ref[:].astype(jnp.float32), xq_ref, sx_ref)
 
 
 def _quantize_kernel(x_ref, xq_ref, sx_ref):
@@ -71,41 +68,38 @@ def prologue_supported(k: int) -> bool:
     return k % QK == 0 and k <= (1 << 16)
 
 
+def _row_call(kernel, nb: int, n_in: int, interpret: bool):
+    """One-block pallas_call over the activation row viewed block-major as
+    (nb, QK): Mosaic cannot split a (1, K) lane vector into quant blocks
+    in-kernel (unsupported shape cast), so the view is taken in XLA where it
+    is free. Outputs (nb, QK) int8 and (nb, 1) f32."""
+    full = pl.BlockSpec((nb, QK), lambda: (0, 0), memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        kernel,
+        in_specs=[full] * n_in,
+        out_specs=[full, pl.BlockSpec((nb, 1), lambda: (0, 0),
+                                      memory_space=pltpu.VMEM)],
+        out_shape=[jax.ShapeDtypeStruct((nb, QK), jnp.int8),
+                   jax.ShapeDtypeStruct((nb, 1), jnp.float32)],
+        interpret=interpret,
+    )
+
+
 @functools.partial(jax.jit, static_argnames=("eps", "interpret"))
 def _rmsnorm_q80(x, w, *, eps: float, interpret: bool):
     _, k = x.shape
     nb = k // QK
-    return pl.pallas_call(
-        functools.partial(_rmsnorm_q80_kernel, eps=eps),
-        in_specs=[
-            pl.BlockSpec((1, k), lambda: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, k), lambda: (0, 0), memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, k), lambda: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, nb), lambda: (0, 0), memory_space=pltpu.VMEM),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((1, k), jnp.int8),
-                   jax.ShapeDtypeStruct((1, nb), jnp.float32)],
-        interpret=interpret,
-    )(x, w)
+    xq, sx = _row_call(functools.partial(_rmsnorm_q80_kernel, eps=eps), nb, 2,
+                       interpret)(x.reshape(nb, QK), w.reshape(nb, QK))
+    return xq.reshape(1, k), sx.reshape(1, nb)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _quantize(x, *, interpret: bool):
     _, k = x.shape
     nb = k // QK
-    return pl.pallas_call(
-        _quantize_kernel,
-        in_specs=[pl.BlockSpec((1, k), lambda: (0, 0), memory_space=pltpu.VMEM)],
-        out_specs=[
-            pl.BlockSpec((1, k), lambda: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, nb), lambda: (0, 0), memory_space=pltpu.VMEM),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((1, k), jnp.int8),
-                   jax.ShapeDtypeStruct((1, nb), jnp.float32)],
-        interpret=interpret,
-    )(x)
+    xq, sx = _row_call(_quantize_kernel, nb, 1, interpret)(x.reshape(nb, QK))
+    return xq.reshape(1, k), sx.reshape(1, nb)
 
 
 def rmsnorm_quantize_q80(x: jax.Array, w: jax.Array, eps: float,
@@ -114,7 +108,7 @@ def rmsnorm_quantize_q80(x: jax.Array, w: jax.Array, eps: float,
     sx (1, nb) f32) of rmsnorm(x, w) quantized per 32-block."""
     k = x.shape[-1]
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_requested()
     return _rmsnorm_q80(x.reshape(1, k), w.reshape(1, k), eps=float(eps),
                         interpret=interpret)
 
@@ -124,5 +118,5 @@ def quantize_q80_row(x: jax.Array, *, interpret: bool | None = None):
     sx (1, nb) f32)."""
     k = x.shape[-1]
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_requested()
     return _quantize(x.reshape(1, k), interpret=interpret)

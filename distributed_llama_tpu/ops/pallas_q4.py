@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..platform_env import interpret_requested
 from ..quants import QK, QTensor
 
 
@@ -164,7 +165,11 @@ def _q4_matvec_inline(xq, sx, wp, scales, *, interpret: bool = False):
     nb = k // QK
     assert kh * 2 == k and scales.shape == (n, nb), (xq.shape, wp.shape, scales.shape)
     ssum = jnp.sum(xq.reshape(nb, QK), axis=1, dtype=jnp.int32)[None, :]
-    bn = _pick_bn(n, k)
+    # the (k, nb) Xexp scratch (lanes padded to 128) shares the chip's 16 MiB
+    # scoped VMEM with the double-buffered weight block and its two unpacked
+    # planes: at K=14336 the default 3 MiB block overran it by 1.9 MiB
+    xexp_bytes = k * pl.cdiv(nb, 128) * 128
+    bn = _pick_bn(n, k, min(3 << 20, ((12 << 20) - xexp_bytes) // 4))
     return pl.pallas_call(
         _matvec_kernel_inline,
         grid=(pl.cdiv(n, bn),),
@@ -195,7 +200,7 @@ def q4_matvec(x: jax.Array, w: QTensor, *, out_dtype=None,
         raise ValueError("q4_matvec needs i4p-layout weights (QTensor.to_i4p_layout)")
     assert w.data.ndim == 2, w.data.shape
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_requested()
     if inline_xexp is None:
         inline_xexp = INLINE_XEXP_DEFAULT
     from .pallas_q8 import _expand_q80, _quantize_row

@@ -4,23 +4,28 @@ q4 matvec kernel.
 The decode matvec (ops/pallas_q4.py) is a T=1 tool: its block-diagonal Xexp
 trick needs one activation row. Prefill (T>1) and batched decode (B>1) run the
 XLA dequant+dot path (ops/matmul.py), which dequantizes the i4p planes to bf16
-operands that XLA may MATERIALIZE through HBM (~3.6x the packed bytes at 7B;
-perf/PROFILE.md's prefill cost model). This kernel keeps the dequant in VMEM:
-each grid step loads a packed (bn, bkp) nibble tile + its f16-bit scales,
-decodes to bf16 in registers, and feeds the MXU — weights stream from HBM
-exactly once at the file's own 0.5625 B/weight density regardless of M.
+operands that XLA may MATERIALIZE through HBM (~3.6x the packed bytes at 7B).
+This kernel keeps the dequant in VMEM: each grid step loads a packed (bn, bkp)
+nibble tile, picks its two scale tiles out of the row block's scales (decoded
+once per row block into tile-major VMEM scratch, _split_scales), decodes to
+bf16 in registers, and feeds the MXU — weights stream from HBM exactly once at
+the file's own 0.5625 B/weight density regardless of M.
 
 Split-plane addressing: i4p byte column c holds the LOW nibble of element c and
 the HIGH nibble of element K/2 + c (QTensor.to_i4p_layout), so one packed tile
 covers two disjoint K-ranges; the kernel takes the activation block TWICE with
-block-index maps offset by K/2 (x_lo / x_hi views of the same array) and the
-scales likewise (s_lo / s_hi).
+block-index maps offset by K/2 (x_lo / x_hi views of the same array), and
+scale tile j serves the low plane, tile j + gk the high plane.
 
-Mosaic portability (perf/PROFILE.md op matrix): nibble extraction widens
-through i32 (no narrow shifts), the -8 offset and per-block scaling happen in
-f32 (no i8 subtract), scales decode from f16 BIT PATTERNS with the proven
-integer-exact _f16_bits_to_f32, and the dot is bf16xbf16->f32 on the MXU. No
-f16 refs anywhere.
+Mosaic portability: nibble extraction widens through i32 (no narrow shifts),
+the -8 offset and per-block scaling happen in f32 (no i8 subtract), scales
+decode from f16 BIT PATTERNS with the integer-exact _f16_bits_to_f32, and the
+dot is bf16xbf16->f32 on the MXU. No f16 refs anywhere. Two things the
+interpreter accepts and the chip's compiler refuses shaped the scale path: a
+(bn, bkp/32) scale block is narrower than a lane tile, and a (bn, bkp) ->
+(bn, bkp/32, 32) reshape is an unsupported shape cast, so the scales arrive
+as whole rows and widen with jnp.repeat (tests/test_tpu_compile.py holds the
+family to the chip's compiler at Llama-3-8B shapes).
 
 Opt-in (Engine prefill_kernel / DLT_PREFILL_KERNEL, bench --prefill-kernel)
 until a hardware A/B lands — same policy as the prologue kernels. The batched
@@ -29,8 +34,8 @@ DLT_FUSED_MATMUL, --fused-matmul): the same kernel family with the legal
 epilogues fused — residual add in the accumulator init (q4_matmul residual=)
 and the silu·mul FFN gate pair as one kernel over the separate w1/w3 planes
 (q4_gated_matmul) — serving decode M=B, verify M=B·(1+k), and drafter rows
-(docs/SERVING.md "Kernel selection"; byte model in perf/PROFILE.md "Batched
-fused Q40 cost model", measured by perf/q4_mm_bench.py).
+(docs/SERVING.md "Kernel selection"; byte model computed by
+perf/q4_mm_bench.py).
 """
 
 from __future__ import annotations
@@ -42,27 +47,41 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..platform_env import interpret_requested
 from ..quants import QK, QTensor
 from .pallas_q4 import _f16_bits_to_f32
 
 
 # hot-path: traced
-def _tile_partial(xlo_ref, xhi_ref, wp_ref, slo_ref, shi_ref, *, bn, bkp):
+def _split_scales(s_ref, st_ref):
+    """Decode one row-block's f16-bit scales (bn, K/32) once and lay them out
+    tile-major in VMEM scratch (2*gk, bn, sb): entry t holds the sb block
+    scales of packed-column tile t (low plane) or t - gk (high plane). The
+    slices are static, so the K grid step can pick its tile with a leading-
+    axis index — Mosaic has no unaligned dynamic lane slice."""
+    sf = _f16_bits_to_f32(s_ref[:])
+    sb = st_ref.shape[2]
+    for t in range(st_ref.shape[0]):
+        st_ref[t] = sf[:, t * sb:(t + 1) * sb]
+
+
+# hot-path: traced
+def _tile_partial(xlo_ref, xhi_ref, wp_ref, st_ref, *, gk):
     """One grid step's (M, bn) partial product: decode the packed (bn, bkp)
-    nibble tile + both scale views in VMEM and hit the MXU twice (low-plane
-    and high-plane K-ranges of the split-plane layout)."""
+    nibble tile against its two scale tiles in VMEM and hit the MXU twice
+    (low-plane and high-plane K-ranges of the split-plane layout)."""
+    j = pl.program_id(1)
     wp = wp_ref[:]  # (bn, bkp) uint8 packed columns
     lo = (wp & jnp.uint8(0x0F)).astype(jnp.int32)  # elements [c, c+bkp)
     hi = wp.astype(jnp.int32) >> 4  # elements [K/2+c, K/2+c+bkp)
 
-    def dequant(q_i32, s_ref):
-        s = _f16_bits_to_f32(s_ref[:])  # (bn, bkp//QK)
-        qf = q_i32.astype(jnp.float32) - 8.0
-        qf = qf.reshape(bn, bkp // QK, QK) * s[:, :, None]
-        return qf.reshape(bn, bkp).astype(jnp.bfloat16)
+    def dequant(q_i32, s):
+        # s (bn, bkp//QK): each block scale covers QK consecutive lanes
+        qf = (q_i32.astype(jnp.float32) - 8.0) * jnp.repeat(s, QK, axis=1)
+        return qf.astype(jnp.bfloat16)
 
-    w_lo = dequant(lo, slo_ref)
-    w_hi = dequant(hi, shi_ref)
+    w_lo = dequant(lo, st_ref[j])
+    w_hi = dequant(hi, st_ref[j + gk])
     acc = jax.lax.dot_general(
         xlo_ref[:].astype(jnp.bfloat16), w_lo, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)  # (M, bn)
@@ -82,35 +101,30 @@ def _act_f32(a, act: str):
     return 0.5 * a * (1.0 + jnp.tanh(c * a * (1.0 + 0.044715 * a * a)))
 
 
-def _mm_kernel(xlo_ref, xhi_ref, wp_ref, slo_ref, shi_ref, o_ref, *, bn, bkp):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
+def _mm_kernel(xlo_ref, xhi_ref, wp_ref, s_ref, o_ref, st_ref, *, gk):
+    @pl.when(pl.program_id(1) == 0)
     def _init():
+        _split_scales(s_ref, st_ref)
         o_ref[:] = jnp.zeros_like(o_ref)
 
-    o_ref[:] += _tile_partial(xlo_ref, xhi_ref, wp_ref, slo_ref, shi_ref,
-                              bn=bn, bkp=bkp)
+    o_ref[:] += _tile_partial(xlo_ref, xhi_ref, wp_ref, st_ref, gk=gk)
 
 
-def _mm_res_kernel(xlo_ref, xhi_ref, wp_ref, slo_ref, shi_ref, res_ref, o_ref,
-                   *, bn, bkp):
+def _mm_res_kernel(xlo_ref, xhi_ref, wp_ref, s_ref, res_ref, o_ref, st_ref,
+                   *, gk):
     """Residual-fused variant: the accumulator STARTS at the residual block
     (same (M, bn) tile the output covers), so `res + x @ w.T` costs zero extra
     HBM round-trips — the residual streams in once with the output tile."""
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
+        _split_scales(s_ref, st_ref)
         o_ref[:] = res_ref[:].astype(jnp.float32)
 
-    o_ref[:] += _tile_partial(xlo_ref, xhi_ref, wp_ref, slo_ref, shi_ref,
-                              bn=bn, bkp=bkp)
+    o_ref[:] += _tile_partial(xlo_ref, xhi_ref, wp_ref, st_ref, gk=gk)
 
 
-def _gated_mm_kernel(xlo_ref, xhi_ref, w1p_ref, s1lo_ref, s1hi_ref,
-                     w3p_ref, s3lo_ref, s3hi_ref, o_ref, acc1_ref, acc3_ref,
-                     *, bn, bkp, gk, act):
+def _gated_mm_kernel(xlo_ref, xhi_ref, w1p_ref, s1_ref, w3p_ref, s3_ref,
+                     o_ref, acc1_ref, acc3_ref, st1_ref, st3_ref, *, gk, act):
     """FFN gate-pair fusion: act(x @ w1.T) * (x @ w3.T) in ONE kernel. Both
     accumulators live in VMEM scratch across the sequential K grid; the
     silu/gelu·mul epilogue runs on the last K step, so the (M, hidden)
@@ -119,13 +133,13 @@ def _gated_mm_kernel(xlo_ref, xhi_ref, w1p_ref, s1lo_ref, s1hi_ref,
 
     @pl.when(j == 0)
     def _init():
+        _split_scales(s1_ref, st1_ref)
+        _split_scales(s3_ref, st3_ref)
         acc1_ref[:] = jnp.zeros_like(acc1_ref)
         acc3_ref[:] = jnp.zeros_like(acc3_ref)
 
-    acc1_ref[:] += _tile_partial(xlo_ref, xhi_ref, w1p_ref, s1lo_ref, s1hi_ref,
-                                 bn=bn, bkp=bkp)
-    acc3_ref[:] += _tile_partial(xlo_ref, xhi_ref, w3p_ref, s3lo_ref, s3hi_ref,
-                                 bn=bn, bkp=bkp)
+    acc1_ref[:] += _tile_partial(xlo_ref, xhi_ref, w1p_ref, st1_ref, gk=gk)
+    acc3_ref[:] += _tile_partial(xlo_ref, xhi_ref, w3p_ref, st3_ref, gk=gk)
 
     @pl.when(j == gk - 1)
     def _epilogue():
@@ -145,15 +159,30 @@ def _pick_bkp(kh: int) -> int | None:
     return None
 
 
+# VMEM the tile-major scale scratch of one kernel may take. It sits beside the
+# double-buffered operand tiles in the chip's 16 MiB of scoped VMEM: at 11 MiB
+# (K=11008, the 7B w2 shape, one weight) the matmul still compiles at M=512;
+# a gated pair at that K would need 21.5 MiB and is declined.
+_SCALE_SCRATCH_LIMIT = 11 << 20
+
+
+def _scale_scratch_bytes(kh: int) -> int:
+    """Bytes of one weight's (2*gk, bn, sb) f32 scale scratch, each (bn, sb)
+    tile padded to a 128-lane tile."""
+    return 2 * (kh // _pick_bkp(kh)) * _BN * 128 * 4
+
+
 def q4_mm_supported(w: QTensor, m: int) -> bool:
     """Whether the fused dequant-matmul can run this weight for M activation
     rows: i4p layout, self-contained pack (groups folded away by
     _localize_qtensors under TP), half-plane divisible into lane-aligned tiles,
-    and an (M, bn) f32 accumulator that stays tiny."""
+    an (M, bn) f32 accumulator that stays tiny, and a scale scratch that
+    fits VMEM."""
     if w.layout != "i4p" or w.groups != 1 or w.data.ndim != 2:
         return False
     kh = w.data.shape[1]  # K/2 packed columns
-    return _pick_bkp(kh) is not None and m <= 512
+    return (_pick_bkp(kh) is not None and m <= 512
+            and _scale_scratch_bytes(kh) <= _SCALE_SCRATCH_LIMIT)
 
 
 def _grid_geom(x, wp, scales):
@@ -179,14 +208,19 @@ def _x_specs(m, bkp, gk):
     ]
 
 
-def _w_specs(bn, bkp, sb, gk):
-    # one packed-nibble tile + its low/high scale views
+def _w_specs(bn, bkp, nb):
+    # one packed-nibble tile per step; the row block's scales whole (their
+    # block index does not move along K, so they are fetched once per row
+    # block). A (bn, bkp/32) scale tile would be narrower than a lane tile,
+    # which the TPU lowering refuses.
     return [
         pl.BlockSpec((bn, bkp), lambda i, j: (i, j), memory_space=pltpu.VMEM),
-        pl.BlockSpec((bn, sb), lambda i, j: (i, j), memory_space=pltpu.VMEM),
-        pl.BlockSpec((bn, sb), lambda i, j: (i, j + gk),
-                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((bn, nb), lambda i, j: (i, 0), memory_space=pltpu.VMEM),
     ]
+
+
+def _scale_scratch(bn, gk, sb):
+    return pltpu.VMEM((2 * gk, bn, sb), jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -196,16 +230,16 @@ def _q4_matmul(x, wp, scales, *, interpret: bool = False):
     m = x.shape[0]
     n = wp.shape[0]
     bn, bkp, gk, sb = _grid_geom(x, wp, scales)
-    kernel = functools.partial(_mm_kernel, bn=bn, bkp=bkp)
     return pl.pallas_call(
-        kernel,
+        functools.partial(_mm_kernel, gk=gk),
         grid=(pl.cdiv(n, bn), gk),
-        in_specs=_x_specs(m, bkp, gk) + _w_specs(bn, bkp, sb, gk),
+        in_specs=_x_specs(m, bkp, gk) + _w_specs(bn, bkp, scales.shape[1]),
         out_specs=pl.BlockSpec((m, bn), lambda i, j: (0, i),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        scratch_shapes=[_scale_scratch(bn, gk, sb)],
         interpret=interpret,
-    )(x, x, wp, scales, scales)
+    )(x, x, wp, scales)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -216,19 +250,19 @@ def _q4_matmul_res(x, wp, scales, res, *, interpret: bool = False):
     n = wp.shape[0]
     assert res.shape == (m, n), (res.shape, (m, n))
     bn, bkp, gk, sb = _grid_geom(x, wp, scales)
-    kernel = functools.partial(_mm_res_kernel, bn=bn, bkp=bkp)
     return pl.pallas_call(
-        kernel,
+        functools.partial(_mm_res_kernel, gk=gk),
         grid=(pl.cdiv(n, bn), gk),
-        in_specs=(_x_specs(m, bkp, gk) + _w_specs(bn, bkp, sb, gk) + [
+        in_specs=(_x_specs(m, bkp, gk) + _w_specs(bn, bkp, scales.shape[1]) + [
             pl.BlockSpec((m, bn), lambda i, j: (0, i),
                          memory_space=pltpu.VMEM),
         ]),
         out_specs=pl.BlockSpec((m, bn), lambda i, j: (0, i),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        scratch_shapes=[_scale_scratch(bn, gk, sb)],
         interpret=interpret,
-    )(x, x, wp, scales, scales, res)
+    )(x, x, wp, scales, res)
 
 
 @functools.partial(jax.jit, static_argnames=("act", "interpret"))
@@ -241,20 +275,20 @@ def _q4_gated_matmul(x, w1p, s1, w3p, s3, *, act: str,
     assert w3p.shape == w1p.shape and s3.shape == s1.shape, (
         w1p.shape, w3p.shape, s1.shape, s3.shape)
     bn, bkp, gk, sb = _grid_geom(x, w1p, s1)
-    kernel = functools.partial(_gated_mm_kernel, bn=bn, bkp=bkp, gk=gk,
-                               act=act)
+    w_specs = _w_specs(bn, bkp, s1.shape[1])
     return pl.pallas_call(
-        kernel,
+        functools.partial(_gated_mm_kernel, gk=gk, act=act),
         grid=(pl.cdiv(n, bn), gk),
-        in_specs=(_x_specs(m, bkp, gk) + _w_specs(bn, bkp, sb, gk)
-                  + _w_specs(bn, bkp, sb, gk)),
+        in_specs=_x_specs(m, bkp, gk) + w_specs + w_specs,
         out_specs=pl.BlockSpec((m, bn), lambda i, j: (0, i),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((m, bn), jnp.float32),
-                        pltpu.VMEM((m, bn), jnp.float32)],
+                        pltpu.VMEM((m, bn), jnp.float32),
+                        _scale_scratch(bn, gk, sb),
+                        _scale_scratch(bn, gk, sb)],
         interpret=interpret,
-    )(x, x, w1p, s1, s1, w3p, s3, s3)
+    )(x, x, w1p, s1, w3p, s3)
 
 
 def _flatten_rows(x):
@@ -277,7 +311,7 @@ def q4_matmul(x: jax.Array, w: QTensor, *, out_dtype=None,
             f"groups={w.groups}, shape={getattr(w.data, 'shape', None)}, "
             f"M={m_total}); gate with q4_mm_supported")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_requested()
     k = x.shape[-1]
     if residual is None:
         y = _q4_matmul(x.reshape(m_total, k), w.data, w.scales,
@@ -293,10 +327,12 @@ def q4_gated_supported(w1: QTensor, w3: QTensor, m: int) -> bool:
     """Whether the fused FFN gate-pair kernel can serve act(x@w1.T) * (x@w3.T):
     both weights individually kernel-eligible and shape-identical (they tile
     on one grid), plus VMEM headroom for the two (M, bn) scratch
-    accumulators."""
+    accumulators and both scale scratches."""
     return (q4_mm_supported(w1, m) and q4_mm_supported(w3, m)
             and w1.data.shape == w3.data.shape
-            and w1.scales.shape == w3.scales.shape)
+            and w1.scales.shape == w3.scales.shape
+            and 2 * _scale_scratch_bytes(w1.data.shape[1])
+            <= _SCALE_SCRATCH_LIMIT)
 
 
 def q4_gated_matmul(x: jax.Array, w1: QTensor, w3: QTensor, *,
@@ -315,7 +351,7 @@ def q4_gated_matmul(x: jax.Array, w1: QTensor, w3: QTensor, *,
     if act not in ("silu", "gelu_tanh"):
         raise ValueError(f"unsupported epilogue activation {act!r}")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_requested()
     k = x.shape[-1]
     y = _q4_gated_matmul(x.reshape(m_total, k), w1.data, w1.scales,
                          w3.data, w3.scales, act=act, interpret=interpret)

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..platform_env import interpret_requested
 from ..quants import QK, QTensor
 
 
@@ -145,17 +146,21 @@ def _q8_matvec_inline(xq, sx, w8, scales, *, interpret: bool = False):
     )(xq, sx, w8, scales)
 
 
+def _quantize_blocks(g: jax.Array):
+    """Q80 quantization of g (nb, QK) f32, one quant block per row -> (int8
+    (nb, QK), block scales (nb, 1) f32). Exactly the reference's Q80 buffer
+    semantics (src/tasks.cpp:96-135). Pure jnp and free of reshapes, so it is
+    also the body of the prologue kernels (ops/pallas_prologue.py)."""
+    absmax = jnp.max(jnp.abs(g), axis=-1, keepdims=True)
+    inv = jnp.where(absmax > 0, 127.0 / absmax, 0.0)
+    return jnp.round(g * inv).astype(jnp.int8), absmax / 127.0
+
+
 def _quantize_row(x_row: jax.Array, nb: int):
     """Per-32-block Q80 quantization of one activation row (K,) -> (xq (K,) int8,
-    sx (1, nb) f32). Exactly the reference's Q80 buffer semantics
-    (src/tasks.cpp:96-135)."""
-    k = x_row.shape[0]
-    g = x_row.reshape(nb, QK).astype(jnp.float32)
-    absmax = jnp.max(jnp.abs(g), axis=-1)
-    sx = absmax / 127.0
-    inv = jnp.where(absmax > 0, 127.0 / absmax, 0.0)
-    xq = jnp.round(g * inv[:, None]).astype(jnp.int8).reshape(k)
-    return xq, sx[None, :]
+    sx (1, nb) f32)."""
+    xq, sx = _quantize_blocks(x_row.reshape(nb, QK).astype(jnp.float32))
+    return xq.reshape(x_row.shape[0]), sx.reshape(1, nb)
 
 
 def block_diag_scatter(xq: jax.Array, nb: int) -> jax.Array:
@@ -200,7 +205,7 @@ def q8_matvec(x: jax.Array, w: QTensor, *, out_dtype=None,
             "(or QTensor.to_i8_layout) on the params first")
     assert w.data.ndim == 2, w.data.shape
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_requested()
     # precise (f32 activations, no Q80 step) is a parity-test tool, explicit opt-in only:
     # the production decode path quantizes activations to int8 exactly like the
     # reference's Q80 buffers regardless of the ambient compute dtype.

@@ -34,6 +34,24 @@ from jax.sharding import Mesh
 AXIS_DP, AXIS_SP, AXIS_TP = "dp", "sp", "tp"
 
 
+def _device_grid(shape: tuple[int, int, int], devs: list) -> np.ndarray:
+    """Devices laid out so that mesh neighbours are ICI neighbours. On TPU the
+    enumeration order is not a ring: a 2x2 host lists its chips (0,0) (1,0)
+    (0,1) (1,1), whose second and fourth hops are diagonals, so the per-layer
+    all-reduce ring would cross the torus. create_device_mesh reorders them
+    (2x2: 0, 1, 3, 2). Other platforms, and shapes it cannot map, keep the
+    enumeration order."""
+    if devs[0].platform == "tpu":
+        from jax.experimental import mesh_utils
+
+        try:
+            return mesh_utils.create_device_mesh(shape, devices=devs)
+        except (ValueError, NotImplementedError, AssertionError) as e:
+            print(f"💡 mesh {shape}: device order left as enumerated "
+                  f"({e})", flush=True)
+    return np.array(devs).reshape(shape)
+
+
 def make_mesh(tp: int | None = None, sp: int = 1, dp: int = 1,
               devices: list | None = None) -> Mesh:
     """Build a (dp, sp, tp) mesh. Defaults: all devices on tp."""
@@ -44,8 +62,8 @@ def make_mesh(tp: int | None = None, sp: int = 1, dp: int = 1,
         tp = n // (sp * dp)
     need = dp * sp * tp
     assert need <= n, f"mesh {dp}x{sp}x{tp} needs {need} devices, have {n}"
-    grid = np.array(devs[:need]).reshape(dp, sp, tp)
-    return Mesh(grid, (AXIS_DP, AXIS_SP, AXIS_TP))
+    return Mesh(_device_grid((dp, sp, tp), list(devs[:need])),
+                (AXIS_DP, AXIS_SP, AXIS_TP))
 
 
 def init_multihost(coordinator: str | None = None, num_processes: int | None = None,
@@ -97,16 +115,9 @@ def make_pod_mesh(tp: int | None = None, sp: int = 1, dp: int | None = None) -> 
         dp = n_total // (sp * tp)
     assert dp * sp * tp == n_total, (dp, sp, tp, n_total)
     if n_slices == 1:
-        # one ICI domain (single- or multi-host). create_device_mesh reorders the
-        # devices so mesh neighbors are torus neighbors — raw jax.devices()
-        # enumeration order would let the per-layer all-reduce ring cross the ICI
-        # torus non-contiguously on multi-host slices (e.g. v5p-16 tp=16).
-        try:
-            grid = mesh_utils.create_device_mesh((dp, sp, tp), devices=devs)
-            return Mesh(grid, (AXIS_DP, AXIS_SP, AXIS_TP))
-        except (ValueError, NotImplementedError, AssertionError):
-            # non-TPU platforms / shapes create_device_mesh cannot map: plain order
-            return make_mesh(tp=tp, sp=sp, dp=dp, devices=devs)
+        # one ICI domain (single- or multi-host): make_mesh lays the devices
+        # out so mesh neighbors are torus neighbors (_device_grid)
+        return make_mesh(tp=tp, sp=sp, dp=dp, devices=devs)
     assert dp % n_slices == 0, (
         f"dp={dp} must span the {n_slices} slices (tp/sp must fit inside one "
         f"slice: {sp * tp} chips vs {n_total // n_slices} per slice)")

@@ -190,15 +190,13 @@ def make_sharded_forward(spec: ModelSpec, mesh: Mesh, params: dict[str, Any], *,
                             paged_kernel=paged_kernel)
     rope_type = spec.rope_type
 
-    from ..compat import shard_map
-
     if paged:
         def step(p, rope_cos, rope_sin, tokens, kc, vc, start_pos, tables):
             rope = RopeTables(rope_cos, rope_sin, rope_type)
             return fwd(p, rope=rope, tokens=tokens, k_cache=kc, v_cache=vc,
                        start_pos=start_pos, block_tables=tables)
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             step, mesh=mesh,
             in_specs=(param_specs, P(), P(), tok_spec, kv_spec, kv_spec,
                       pos_spec, P()),
@@ -219,7 +217,7 @@ def make_sharded_forward(spec: ModelSpec, mesh: Mesh, params: dict[str, Any], *,
         return fwd(p, rope=rope, tokens=tokens, k_cache=kc, v_cache=vc,
                    start_pos=start_pos)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         step, mesh=mesh,
         in_specs=(param_specs, P(), P(), tok_spec, kv_spec, kv_spec, pos_spec),
         out_specs=(tok_spec, kv_spec, kv_spec),

@@ -3,8 +3,8 @@
 PR 4 gave the BatchEngine a watchdog *reading* — `batch_dispatch_age_seconds`,
 seconds since the scheduler last completed a device dispatch while work is in
 flight — but nothing consumed it: a wedged engine (a dispatch hung in the
-backend, the BENCH_r03/r04 documented outage mode where even a trivial fenced
-op never completes) sat at 100% unavailability while /healthz kept answering
+backend, where even a trivial op never completes) sat at 100% unavailability
+while /healthz kept answering
 "ok" and every queued client waited forever.
 
 The EngineSupervisor closes that loop (docs/ROBUSTNESS.md "Hung-engine

@@ -1071,8 +1071,7 @@ class BatchEngine:
                        reinit: bool = True) -> bool:
         """Supervisor escalation (resilience/supervisor.py, docs/ROBUSTNESS.md):
         the scheduler stopped making progress — a device dispatch (or its
-        result transfer) is hung, the BENCH_r03/r04 documented backend outage
-        shape — so act instead of observing:
+        result transfer) is hung — so act instead of observing:
 
         1. ABANDON the wedged scheduler thread: bump the engine epoch. The
            stuck thread cannot be interrupted, but every path it can wake on

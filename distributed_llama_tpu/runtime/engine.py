@@ -158,15 +158,16 @@ class Engine:
                  paged_kernel: bool | None = None):
         self.spec = spec
         self.tokenizer = tokenizer
-        on_tpu = jax.default_backend() == "tpu"
-        # decode is HBM-bound on TPU: bf16 activations/caches halve cache traffic, and
-        # matvec numerics are int8 (Q80) in the kernel either way. f32 on CPU keeps the
-        # golden/parity tests exact.
-        self.dtype = dtype if dtype is not None else (jnp.bfloat16 if on_tpu
-                                                      else jnp.float32)
+        # what the backend decides when the caller left it open — bf16 +
+        # kernels on tpu, f32 + XLA elsewhere — is resolved (and explained on
+        # the start-up line) in one place; kernels off the chip need the
+        # explicit interpret request
+        from ..platform_env import resolve_kernel_policy
+
+        policy = resolve_kernel_policy(use_pallas, dtype)
+        self.dtype = policy.dtype
+        use_pallas = policy.use_pallas
         self.compress = compress_collectives
-        if use_pallas is None:
-            use_pallas = on_tpu
         # one rounded resident value drives every paged-mode decision (the
         # fits-check, the tp default, and the ring allocation) — three
         # different thresholds here previously let `--kv-cache-resident 1000`
@@ -276,7 +277,7 @@ class Engine:
         # paged-attention kernel gate (ops/pallas_paged_attention.py):
         # explicit request (kwarg / DLT_PAGED_KERNEL) wins; default follows
         # use_pallas (TPU + quantized weights). CPU tests force it on via
-        # the env knob — the kernel then runs in interpret mode.
+        # the env knob, under the suite's interpret request.
         self.paged_kernel = bool(
             self._paged_kernel_req if self._paged_kernel_req is not None
             else self.use_pallas) and self.kv_pool is not None
@@ -288,7 +289,7 @@ class Engine:
         self.params = shard_params(params, self.mesh, spec,
                                    moe_sharding=self.moe_sharding)
         # global (all-shard) weight bytes one decode step streams — per-chip traffic
-        # divides by tp; used for the achieved-GB/s printout (perf/PROFILE.md)
+        # divides by tp; used for the achieved-GB/s printout
         self.decode_weight_bytes = decode_stream_bytes(self.params, spec)
         self.rope = RopeTables.create(spec)
         self.batch = batch
@@ -626,7 +627,7 @@ class Engine:
                 self.params, self.rope, toks, self.k_cache,
                 self.v_cache, self._pos_arg(self.pos))
         self.pos += t
-        out = np.asarray(logits)[0]  # host transfer: the honest dispatch fence
+        out = np.asarray(logits)[0]  # the sampler needs them on the host
         dt = time.perf_counter() - t0
         # a 1-token dispatch is decode-shaped regardless of which loop issued
         # it (prefill's tail chunks of 1 land here too — same program, same

@@ -807,7 +807,7 @@ def run_router_cell(router, point: str, kind: str) -> list[str]:
 def run_supervisor_cell() -> list[str]:
     """Hung-engine supervision (resilience/supervisor.py): a deterministic
     fault-injected hang (latency fault parking the scheduler in a 600 s
-    sleep at batch.dispatch — the BENCH_r03/r04 backend-outage stand-in)
+    sleep at batch.dispatch — the stand-in for a dispatch hung in the backend)
     must be recovered within the supervisor's escalation threshold: the
     in-flight request fails with the RETRIABLE EngineWedged, the backend
     re-initializes, and a fault-free probe completes on the fresh scheduler

@@ -1,7 +1,6 @@
 #!/usr/bin/env python
 """Batched fused Q40 dequant-matmul microbench — the serving-shape evidence
-for ops/pallas_q4_mm.py (decode, verify, drafter rows; perf/PROFILE.md
-"Batched fused Q40 cost model").
+for ops/pallas_q4_mm.py (decode, verify, drafter rows).
 
 A fused dispatch should move only
 
@@ -34,10 +33,6 @@ import sys
 import time
 
 import jax
-
-if os.environ.get("JAX_PLATFORMS"):
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 import jax.numpy as jnp
 import numpy as np
 
@@ -63,7 +58,7 @@ SMALL_SHAPES = ((8, 256, 512), (40, 512, 256), (8, 384, 256))
 
 
 def fence(x):
-    np.asarray(jax.device_get(jax.tree_util.tree_leaves(x)[0].ravel()[0]))
+    jax.block_until_ready(x)
 
 
 def timed(fn, *args, reps=10):
@@ -178,6 +173,7 @@ def sec_time(reps):
     CPU the weight n is shrunk so interpret mode stays tractable."""
     from distributed_llama_tpu.ops.matmul import qmatmul
     from distributed_llama_tpu.ops.pallas_q4_mm import (q4_gated_matmul,
+                                                        q4_gated_supported,
                                                         q4_matmul,
                                                         q4_mm_supported)
 
@@ -208,6 +204,10 @@ def sec_time(reps):
                  (x, wl), packed),
             )
             for op, fn, args, weight_bytes in runs:
+                if op == "gated" and not q4_gated_supported(wl, w3, m):
+                    emit(section="time", bucket=bucket, op=op, m=m, n=n_eff,
+                         k=k_eff, skipped="shape outside kernel support")
+                    continue
                 dt = timed(jax.jit(fn), *args, reps=reps)
                 emit(section="time", backend=jax.default_backend(),
                      bucket=bucket, op=op, m=m, n=n_eff, k=k_eff,

@@ -10,8 +10,7 @@ Runs the sharded decode step on the 8-device virtual CPU mesh (JAX_PLATFORMS=cpu
 
 CPU-mesh times are NOT hardware numbers (no ICI; ppermute is a memcpy), but the
 inscan-vs-deferred delta isolates exactly the carry-copy overhead the deferred
-discipline removes, and the analytical budget in perf/PROFILE.md extrapolates the
-HBM terms to a real chip. Emits one JSON line per config.
+discipline removes. Emits one JSON line per config.
 
     python perf/sp_cost.py [--dim 512] [--layers 8] [--seq 1024] [--steps 20]
 """

@@ -1,11 +1,11 @@
 #!/usr/bin/env python
-"""Summarize a bench-results JSONL (warm runner or sweep) into a markdown table.
+"""Summarize a bench-results JSONL (perf/sweep.sh) into a markdown table.
 
-    python perf/summarize_results.py [perf/r5_hw_results.jsonl]
+    python perf/summarize_results.py [perf/sweep_results.jsonl]
 
 Groups each result under its preceding {"section":"cmd"} marker, skips meta/
-heartbeat records, flags errors and profiler-instrumented rows, and prints the
-table PROFILE.md's round sections are built from. Pure stdlib — safe anywhere.
+heartbeat records, flags errors and profiler-instrumented rows, and prints one
+table. Pure stdlib — safe anywhere.
 """
 
 import json
@@ -35,7 +35,7 @@ def rows(path):
 
 
 def main():
-    path = sys.argv[1] if len(sys.argv) > 1 else "perf/r5_hw_results.jsonl"
+    path = sys.argv[1] if len(sys.argv) > 1 else "perf/sweep_results.jsonl"
     seen = []
     for cmd, rec in rows(path):
         seen.append((cmd, rec))

@@ -1,7 +1,7 @@
 #!/bin/bash
-# One-shot hardware measurement sweep — run on a live TPU chip to collect every
-# pending A/B (see perf/PROFILE.md). Each line is a JSON record; tee everything
-# into perf/sweep_results.jsonl for analysis.
+# One-shot hardware measurement sweep: run on a TPU chip to collect every
+# pending A/B, one bench process after another. Each line is a JSON record and
+# names the device it ran on; tee everything into perf/sweep_results.jsonl.
 #
 #   bash perf/sweep.sh [outfile]
 #
@@ -50,8 +50,7 @@ run python bench.py --steps 64 --window 2048
 run python bench.py --steps 64 --device-loop 8
 run python bench.py --steps 64 --device-loop 32
 
-# prefill throughput (chunked prefill is a capability win over the reference;
-# cost model in perf/PROFILE.md)
+# prefill throughput (chunked prefill is a capability win over the reference)
 run python bench.py --prefill 64 --steps 16
 run python bench.py --prefill 128 --steps 16
 run python bench.py --prefill 64 --steps 16 --prefill-kernel

@@ -1,0 +1,146 @@
+"""chip_smoke.py off the chip, and the start-up routine it leans on.
+
+The smoke's contract is checked on a TPU by whoever runs it there; what can be
+held here is everything around the device: the whole control flow at tiny size
+under an explicit CPU + interpret request (which must end not-ok), that the
+parent process stays off JAX, that no failing phase can end in exit code 0,
+and that the start-up routine places the compile cache where it says.
+"""
+
+import ast
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402  (stdlib only: importing it touches no device)
+
+from distributed_llama_tpu import platform_env  # noqa: E402
+
+PHASES = ("device", "model", "cli", "parity", "serve")
+# what a CPU rehearsal is expected to fail on, and nothing else
+CPU_FAILURES = ("device is cpu, not tpu", "the q4_matvec kernel did not engage",
+                "the paged-attention kernel did not engage")
+
+
+def test_rehearsal_runs_every_phase_and_cannot_end_ok(tmp_path):
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
+               JAX_ENABLE_COMPILATION_CACHE="1")
+    p = subprocess.run([sys.executable, "chip_smoke.py", "--rehearse",
+                        "--layers", "2"], cwd=REPO, env=env,
+                       capture_output=True, text=True, timeout=600)
+    lines = [json.loads(x) for x in p.stdout.strip().splitlines()]
+    assert p.returncode != 0, p.stdout
+    assert lines[-1] == {"ok": False, "device": {
+        "platform": "cpu", "kind": lines[-1]["device"]["kind"], "count": 1}}
+    by_phase = {x["phase"]: x for x in lines[:-1]}
+    assert tuple(by_phase) == PHASES
+    for name, line in by_phase.items():
+        unexpected = [f for f in line["failures"]
+                      if not f.endswith(CPU_FAILURES)]
+        assert not unexpected, (name, unexpected)
+    assert by_phase["model"]["ok"] and by_phase["model"]["n_layers"] == 2
+    assert [r["generated"] for r in by_phase["cli"]["runs"]] == [32, 32]
+    parity = by_phase["parity"]
+    assert parity["arms"]["kernels"]["kernels"] is True  # interpret mode ran
+    assert parity["max_rel_err"] <= parity["tolerance"]
+    serve = by_phase["serve"]
+    assert serve["exit_code"] == 0 and serve["stream_events"] > 0
+    assert serve["batch_engine"]["super_steps"] > 0 and serve["paged_kv"]
+    assert serve["device"]["compile_cache"] == str(tmp_path / "cache")
+    assert not os.path.exists(chip_smoke.WORK)  # the checkpoint is removed
+
+
+def test_parent_imports_nothing_but_the_standard_library():
+    """A parent that has touched JAX holds the chip; every import anywhere in
+    chip_smoke.py, at any depth, must be stdlib."""
+    with open(os.path.join(REPO, "chip_smoke.py")) as f:
+        tree = ast.parse(f.read())
+    imported = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            imported.update(a.name.split(".")[0] for a in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            assert node.level == 0, "relative import"
+            imported.add(node.module.split(".")[0])
+    assert imported and imported <= sys.stdlib_module_names, (
+        imported - sys.stdlib_module_names)
+
+
+def _stub_phases(monkeypatch, tmp_path, failing):
+    monkeypatch.setattr(chip_smoke, "WORK", str(tmp_path / "work"))
+    monkeypatch.setattr(chip_smoke, "LOGS", str(tmp_path / "logs"))
+    for name in PHASES:
+        def phase(ctx, name=name):
+            if name == "device":
+                ctx.device = {"platform": "tpu", "kind": "TPU v5 lite",
+                              "count": 1}
+            ok = name != failing
+            return {"phase": name, "ok": ok,
+                    "failures": [] if ok else ["injected"]}
+        monkeypatch.setattr(chip_smoke, f"phase_{name}", phase)
+
+
+@pytest.mark.parametrize("failing", PHASES)
+def test_a_failing_phase_cannot_yield_exit_code_0(monkeypatch, tmp_path,
+                                                  capsys, failing):
+    _stub_phases(monkeypatch, tmp_path, failing)
+    assert chip_smoke.main([]) == 1
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
+    assert lines[-1]["ok"] is False and set(lines[-1]) == {"ok", "device"}
+    ran = [x["phase"] for x in lines[:-1]]
+    # nothing to drive without a device or a checkpoint; otherwise go on
+    stops_at = failing if failing in ("device", "model") else "serve"
+    assert ran == list(PHASES[:PHASES.index(stops_at) + 1])
+
+
+def test_all_phases_ok_gives_the_contract_line_and_exit_code_0(
+        monkeypatch, tmp_path, capsys):
+    _stub_phases(monkeypatch, tmp_path, failing=None)
+    assert chip_smoke.main([]) == 0
+    assert capsys.readouterr().out.splitlines()[-1] == json.dumps(
+        {"ok": True, "device": {"platform": "tpu", "kind": "TPU v5 lite",
+                                "count": 1}})
+
+
+@pytest.fixture
+def cache_config():
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_goes_where_the_environment_says(monkeypatch, tmp_path,
+                                                       cache_config):
+    monkeypatch.setenv(platform_env.CACHE_ENV, str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert platform_env.place_compile_cache() == str(tmp_path)
+    # and nothing is set in code: JAX read the variable itself at import
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_one_fixed_path_in_the_checkout(
+        monkeypatch, cache_config):
+    monkeypatch.delenv(platform_env.CACHE_ENV, raising=False)
+    want = os.path.join(REPO, ".jax_cache")
+    assert platform_env.place_compile_cache() == want
+    assert platform_env.place_compile_cache() == want  # no pid, time or temp
+    assert jax.config.jax_compilation_cache_dir == want
+
+
+def test_kernels_off_the_chip_need_the_interpret_request(monkeypatch):
+    """use_pallas=None resolves to XLA on the CPU and says why; use_pallas=True
+    is an error there unless interpret mode was asked for."""
+    policy = platform_env.resolve_kernel_policy(None)
+    assert policy.use_pallas is False and "cpu" in policy.reason
+    assert platform_env.resolve_kernel_policy(True).use_pallas is True
+    monkeypatch.delenv(platform_env.INTERPRET_ENV)
+    with pytest.raises(ValueError, match="DLT_PALLAS_INTERPRET"):
+        platform_env.resolve_kernel_policy(True)
+    assert platform_env.resolve_kernel_policy(None).use_pallas is False

@@ -14,7 +14,6 @@ from distributed_llama_tpu.parallel.hlo_stats import (collective_traffic,
                                                       jaxpr_collective_traffic)
 from distributed_llama_tpu.quants import FloatType
 from distributed_llama_tpu.runtime.engine import Engine
-from distributed_llama_tpu.compat import shard_map
 
 
 def test_hlo_text_parser():
@@ -49,8 +48,8 @@ def test_jaxpr_walker_counts_scan_iterations():
         out, _ = jax.lax.scan(body, jnp.zeros_like(x), None, length=3)
         return jax.lax.all_gather(out, "tp", tiled=True)
 
-    sm = shard_map(f, mesh=mesh, in_specs=(P("tp"),), out_specs=P(),
-                   check_vma=False)
+    sm = jax.shard_map(f, mesh=mesh, in_specs=(P("tp"),), out_specs=P(),
+                       check_vma=False)
     closed = jax.make_jaxpr(sm)(jnp.ones((8,), jnp.float32))
     t = jaxpr_collective_traffic(closed, dict(mesh.shape))
     assert t.counts["all-reduce"] == 3  # psum inside the scan body, length 3
@@ -110,8 +109,8 @@ def test_cond_counts_heaviest_branch_only():
             lambda x: jax.lax.psum(x[:1], "tp").repeat(2),     # 4 B payload
             x)
 
-    sm = shard_map(f, mesh=mesh, in_specs=(P("tp"), P()), out_specs=P("tp"),
-                   check_vma=False)
+    sm = jax.shard_map(f, mesh=mesh, in_specs=(P("tp"), P()),
+                       out_specs=P("tp"), check_vma=False)
     closed = jax.make_jaxpr(sm)(jnp.ones((8,), jnp.float32), jnp.bool_(True))
     t = jaxpr_collective_traffic(closed, dict(mesh.shape))
     # one branch executes: the heavier (8 B) psum is counted once, not both summed

@@ -49,6 +49,25 @@ def test_q4_mm_supported_gates():
     assert not q4_mm_supported(w8, 8)  # i8 layout unsupported
 
 
+def test_scale_scratch_gate_declines_what_vmem_cannot_hold():
+    """The tile-major scale scratch is lane-padded f32, so a half-plane that
+    only tiles by 128 columns costs 32x its scales: at K=11008 one weight
+    still fits beside the operand tiles (11 MiB; it compiles for v5e at
+    M=512), a gated pair would need 21.5 MiB of the chip's 16 MiB scoped
+    VMEM, which its compiler refuses. The gates read shapes only."""
+    from distributed_llama_tpu.ops.pallas_q4_mm import q4_gated_supported
+
+    def weight(n, k):
+        return QTensor(FloatType.Q40, np.zeros((n, k // 2), np.uint8),
+                       np.zeros((n, k // 32), np.int16), layout="i4p")
+
+    assert q4_mm_supported(weight(256, 11008), 512)
+    assert not q4_gated_supported(weight(256, 11008), weight(256, 11008), 8)
+    assert q4_gated_supported(weight(256, 14336), weight(256, 14336), 128)
+    assert not q4_gated_supported(weight(256, 28672), weight(256, 28672), 8)
+    assert not q4_mm_supported(weight(256, 2 * 125 * 128), 8)  # 31 MiB
+
+
 def _spec():
     # dim 1024 so K/2=512 tiles exactly (q4_mm_supported needs kh % 512 == 0)
     return ModelSpec(arch_type=ArchType.LLAMA, dim=1024, hidden_dim=1024,

@@ -25,7 +25,6 @@ from distributed_llama_tpu.parallel.tp import (init_sharded_kv_cache, make_shard
 from distributed_llama_tpu.quants import FloatType
 from distributed_llama_tpu.runtime.engine import Engine
 from distributed_llama_tpu.runtime.sampler import Sampler
-from distributed_llama_tpu.compat import shard_map
 
 
 @pytest.mark.parametrize("sp", [2, 4])
@@ -47,7 +46,7 @@ def test_ring_attention_equals_full(sp, t):
     def f(q, kc, vc):
         return ring_attention(q, kc, vc, positions, axis_name="sp", axis_size=sp)
 
-    sharded = jax.jit(shard_map(
+    sharded = jax.jit(jax.shard_map(
         f, mesh=mesh,
         in_specs=(P(), P(None, None, "sp", None), P(None, None, "sp", None)),
         out_specs=P(), check_vma=False))
@@ -75,8 +74,9 @@ def test_update_kv_cache_sharded_matches_full(t, start):
         return update_kv_cache_sharded(kc, vc, k_new, v_new, jnp.int32(start),
                                        axis_name="sp")
 
-    sharded = jax.jit(shard_map(f, mesh=mesh, in_specs=(kvp, kvp, P(), P()),
-                                out_specs=(kvp, kvp), check_vma=False))
+    sharded = jax.jit(jax.shard_map(
+        f, mesh=mesh, in_specs=(kvp, kvp, P(), P()),
+        out_specs=(kvp, kvp), check_vma=False))
     kg, vg = sharded(kc, vc, k_new, v_new)
     np.testing.assert_allclose(np.asarray(kg), np.asarray(kw), atol=1e-6)
     np.testing.assert_allclose(np.asarray(vg), np.asarray(vw), atol=1e-6)
