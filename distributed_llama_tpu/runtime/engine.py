@@ -250,6 +250,11 @@ class Engine:
             getattr(t, "ftype", None) in (FloatType.Q40, FloatType.Q80)
             for t in params["blocks"].values())
         self.use_pallas = use_pallas and has_quant
+        if use_pallas and not has_quant:
+            # the start-up line named the policy before any checkpoint was
+            # read; /healthz and /v1/stats report what this engine runs
+            print("💡 kernels: xla, whatever the start-up line said: this "
+                  "checkpoint has no Q40/Q80 weights for the Pallas kernels")
         # fused dequant-matmul for prefill / batched decode
         # (ops/pallas_q4_mm.py): opt-in (flag or DLT_PREFILL_KERNEL=1) until
         # the hardware A/B lands — same policy as the prologue kernels
@@ -579,17 +584,21 @@ class Engine:
         with trace.span("engine.dispatch", {"t": t, "pos": self.pos}):
             return self._infer_traced(tokens, t)
 
-    def _infer_traced(self, tokens: np.ndarray, t: int) -> np.ndarray:
-        faults.fire("engine.dispatch", t=t, pos=self.pos)
-        t0 = time.perf_counter()
-        if self.paged:
-            # warm phase (pos + T within the ring) takes the callback-free
-            # plain step; the paged step only builds once real cold history
-            # is about to exist
-            step = self._step_for("paged_warm" if self.pos + t <= self.kv_resident
-                                  else None)
-        else:
-            step = self._step_for(self._window_for(self.pos + t))
+    def dispatch(self, tokens: np.ndarray) -> jax.Array:
+        """Enqueue the plain (not host-paged) step over T tokens at the current
+        position and advance pos. Returns the (B, T, vocab) logits still on the
+        device and not waited for: `_infer` adds the host copy the sampler
+        needs, a caller that times the device puts its own fence after this."""
+        assert not self.paged, "the host-paged step appends to its store"
+        t = len(tokens)
+        step = self._step_for(self._window_for(self.pos + t))
+        logits, self.k_cache, self.v_cache = step(
+            self.params, self.rope, self._tiled(tokens), self.k_cache,
+            self.v_cache, self._pos_arg(self.pos))
+        self.pos += t
+        return logits
+
+    def _tiled(self, tokens: np.ndarray) -> jax.Array:
         # the host loop drives ONE sequence; with batch>1 slots (BatchEngine backing
         # store) or dp sharding, tile the row across the batch so token/cache/pos
         # shapes stay congruent (rows 1.. do redundant work; BatchEngine drives the
@@ -602,31 +611,37 @@ class Engine:
                   f"across all {self.batch} rows — {self.batch}x redundant compute. "
                   "Use BatchEngine (api_server --batch) to drive real per-row "
                   "requests.", file=sys.stderr)
-        toks = jnp.tile(jnp.asarray(tokens)[None, :], (self.batch, 1))
-        if self.paged and self.pos + t <= self.kv_resident:
-            # warm phase: slot == position, cold empty — plain deferred step
-            # (see _step_for), with the new rows sliced from the committed ring
-            # for the host-store append (the authoritative history the paged
-            # step's cold callbacks will read once the ring wraps)
-            logits, self.k_cache, self.v_cache = step(
-                self.params, self.rope, toks, self.k_cache,
+        return jnp.tile(jnp.asarray(tokens)[None, :], (self.batch, 1))
+
+    def _infer_traced(self, tokens: np.ndarray, t: int) -> np.ndarray:
+        faults.fire("engine.dispatch", t=t, pos=self.pos)
+        t0 = time.perf_counter()
+        if not self.paged:
+            logits = self.dispatch(tokens)
+        elif self.pos + t <= self.kv_resident:
+            # warm phase: slot == position, cold empty, so the callback-free
+            # plain deferred step runs (see _step_for; the paged step only
+            # builds once real cold history is about to exist), with the new
+            # rows sliced from the committed ring for the host-store append
+            # (the authoritative history the paged step's cold callbacks will
+            # read once the ring wraps)
+            logits, self.k_cache, self.v_cache = self._step_for("paged_warm")(
+                self.params, self.rope, self._tiled(tokens), self.k_cache,
                 self.v_cache, self._pos_arg(self.pos))
             self.store.append(
                 np.asarray(self.k_cache[:, :, :, self.pos:self.pos + t]),
                 np.asarray(self.v_cache[:, :, :, self.pos:self.pos + t]),
                 self.pos)
-        elif self.paged:
-            logits, self.k_cache, self.v_cache, (k_rows, v_rows) = step(
-                self.params, self.rope, toks, self.k_cache,
-                self.v_cache, self._pos_arg(self.pos))
+            self.pos += t
+        else:
+            logits, self.k_cache, self.v_cache, (k_rows, v_rows) = (
+                self._step_for(None)(
+                    self.params, self.rope, self._tiled(tokens), self.k_cache,
+                    self.v_cache, self._pos_arg(self.pos)))
             # the host store is the authoritative history the next step's
             # cold callbacks read — append before advancing pos
             self.store.append(np.asarray(k_rows), np.asarray(v_rows), self.pos)
-        else:
-            logits, self.k_cache, self.v_cache = step(
-                self.params, self.rope, toks, self.k_cache,
-                self.v_cache, self._pos_arg(self.pos))
-        self.pos += t
+            self.pos += t
         out = np.asarray(logits)[0]  # the sampler needs them on the host
         dt = time.perf_counter() - t0
         # a 1-token dispatch is decode-shaped regardless of which loop issued

@@ -12,9 +12,8 @@ Default: the tiny example model (dim 256, 4 layers, ~1.6 MB). `--arch NAME` writ
 published geometry of models/presets.py instead (llama3_8b: 6.3 GB), which is what
 chip_smoke.py loads on the chip. Either way the file is written tensor by tensor
 with random Q40 blocks drawn directly, so the model never exists in f32.
-`--layers N` cuts the depth.
 
-Usage: python examples/make_tiny_model.py [outdir] [--arch NAME] [--layers N] [--seed S]
+Usage: python examples/make_tiny_model.py [outdir] [--arch NAME] [--seed S]
 """
 
 import argparse
@@ -100,17 +99,12 @@ def main(argv=None) -> None:
     ap.add_argument("--arch", choices=sorted(ARCHS), default=None,
                     help="a published geometry (models/presets.py); default: "
                          "the tiny example model")
-    ap.add_argument("--layers", type=int, default=0,
-                    help="cut the depth to N layers")
     ap.add_argument("--seed", type=int, default=20260729)
     args = ap.parse_args(argv)
 
     os.makedirs(args.outdir, exist_ok=True)
     name = args.arch or "tiny"
-    geometry = dict(ARCHS[args.arch] if args.arch else TINY)
-    if args.layers:
-        geometry["n_layers"] = args.layers
-    spec = ModelSpec(**geometry).resolved()
+    spec = ModelSpec(**(ARCHS[args.arch] if args.arch else TINY)).resolved()
     mpath = os.path.join(args.outdir, f"{name}.m")
     tpath = os.path.join(args.outdir, f"{name}.t")
     t0 = time.perf_counter()

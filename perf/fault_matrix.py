@@ -33,6 +33,10 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+# a CPU tool by design: the fused family runs the Pallas kernels in interpret
+# mode, and that is something a caller asks for (platform_env.py). The test
+# suite's conftest makes the same request.
+os.environ.setdefault("DLT_PALLAS_INTERPRET", "1")
 
 from distributed_llama_tpu.models.params import init_random_params  # noqa: E402
 from distributed_llama_tpu.models.spec import (ArchType, ModelSpec,  # noqa: E402

@@ -14,6 +14,7 @@ import subprocess
 import sys
 
 import jax
+import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -22,6 +23,7 @@ sys.path.insert(0, REPO)
 import chip_smoke  # noqa: E402  (stdlib only: importing it touches no device)
 
 from distributed_llama_tpu import platform_env  # noqa: E402
+from distributed_llama_tpu.apps import parity  # noqa: E402
 
 PHASES = ("device", "model", "cli", "parity", "serve")
 # what a CPU rehearsal is expected to fail on, and nothing else
@@ -32,9 +34,9 @@ CPU_FAILURES = ("device is cpu, not tpu", "the q4_matvec kernel did not engage",
 def test_rehearsal_runs_every_phase_and_cannot_end_ok(tmp_path):
     env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
                JAX_ENABLE_COMPILATION_CACHE="1")
-    p = subprocess.run([sys.executable, "chip_smoke.py", "--rehearse",
-                        "--layers", "2"], cwd=REPO, env=env,
-                       capture_output=True, text=True, timeout=600)
+    p = subprocess.run([sys.executable, "chip_smoke.py", "--rehearse"],
+                       cwd=REPO, env=env, capture_output=True, text=True,
+                       timeout=600)
     lines = [json.loads(x) for x in p.stdout.strip().splitlines()]
     assert p.returncode != 0, p.stdout
     assert lines[-1] == {"ok": False, "device": {
@@ -45,13 +47,20 @@ def test_rehearsal_runs_every_phase_and_cannot_end_ok(tmp_path):
         unexpected = [f for f in line["failures"]
                       if not f.endswith(CPU_FAILURES)]
         assert not unexpected, (name, unexpected)
-    assert by_phase["model"]["ok"] and by_phase["model"]["n_layers"] == 2
+    assert by_phase["model"]["ok"] and by_phase["model"]["arch"] == "tiny"
     assert [r["generated"] for r in by_phase["cli"]["runs"]] == [32, 32]
-    parity = by_phase["parity"]
-    assert parity["arms"]["kernels"]["kernels"] is True  # interpret mode ran
-    assert parity["max_rel_err"] <= parity["tolerance"]
+    for name, bounds in parity.BOUNDS.items():
+        done = by_phase["parity"]["passes"][name]
+        assert done["arms"]["kernels"]["kernels"] == "True"  # interpret mode
+        assert done["within"] and done["bounds"] == list(bounds)
+    assert by_phase["parity"]["passes"]["shallow"]["canary"]["caught"]
     serve = by_phase["serve"]
     assert serve["exit_code"] == 0 and serve["stream_events"] > 0
+    # the compared pair rewound to the last prompt token; the priming send
+    # prefilled in chunks and ended on a different program
+    repeat = serve["repeat"]
+    assert repeat["path"]["chunks"] == [1]
+    assert repeat["prime_path"]["chunks"][-1] == 8
     assert serve["batch_engine"]["super_steps"] > 0 and serve["paged_kv"]
     assert serve["device"]["compile_cache"] == str(tmp_path / "cache")
     assert not os.path.exists(chip_smoke.WORK)  # the checkpoint is removed
@@ -144,3 +153,88 @@ def test_kernels_off_the_chip_need_the_interpret_request(monkeypatch):
     with pytest.raises(ValueError, match="DLT_PALLAS_INTERPRET"):
         platform_env.resolve_kernel_policy(True)
     assert platform_env.resolve_kernel_policy(None).use_pallas is False
+
+
+def test_engine_says_when_a_checkpoint_leaves_the_kernels_nothing_to_read(capsys):
+    """The start-up line names the policy before a checkpoint is read; an
+    engine that drops it for unquantized weights says so, and reports xla."""
+    from distributed_llama_tpu.models.params import init_random_params
+    from distributed_llama_tpu.models.spec import ArchType, ModelSpec, RopeType
+    from distributed_llama_tpu.quants import FloatType
+    from distributed_llama_tpu.runtime.engine import Engine
+
+    spec = ModelSpec(arch_type=ArchType.LLAMA, dim=64, hidden_dim=128,
+                     n_layers=1, n_heads=4, n_kv_heads=4, vocab_size=64,
+                     seq_len=32, rope_type=RopeType.LLAMA).resolved()
+    engine = Engine(spec, init_random_params(spec, FloatType.F32), tp=1,
+                    use_pallas=True)
+    assert "kernels: xla" in capsys.readouterr().out
+    assert platform_env.describe(engine.dtype, engine.use_pallas)[
+        "kernels"] == "xla"
+
+
+@pytest.fixture(scope="module")
+def tiny_checkpoint(tmp_path_factory):
+    out = tmp_path_factory.mktemp("tiny")
+    subprocess.run([sys.executable, "examples/make_tiny_model.py", str(out)],
+                   cwd=REPO, check=True, capture_output=True)
+    return str(out / "tiny.m")
+
+
+def test_parity_policy_arm_runs_the_opt_in_kernels(tiny_checkpoint):
+    """--policy fused-matmul: the family that lowers everything the other two
+    do. One process only, because each builds five interpret-mode engines."""
+    p = subprocess.run(
+        [sys.executable, "-m", "distributed_llama_tpu.apps.parity", "--model",
+         tiny_checkpoint, "--policy", "fused-matmul", "--steps", "4"],
+        cwd=REPO, capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stdout[-2000:] + p.stderr[-2000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    assert out["ok"] and set(out["passes"]["full"]["arms"]) == {
+        "kernels", "fused-matmul"}
+    engaged = set(out["kernel_selections"].values())
+    assert {"q4_mm", "q4_mm+res", "q4_gated_mm"} <= engaged
+    assert "xla-fallback" not in engaged
+
+
+@pytest.mark.parametrize("policy", sorted(parity.POLICIES))
+def test_parity_policies_are_engine_switches(policy):
+    import inspect
+
+    from distributed_llama_tpu.runtime.engine import Engine
+
+    switches = inspect.signature(Engine.__init__).parameters
+    assert parity.POLICIES[policy] and set(parity.POLICIES[policy]) <= set(
+        switches)
+
+
+def test_parity_shallow_cut_is_the_first_and_the_last_layer(tiny_checkpoint):
+    from distributed_llama_tpu.formats.mfile import load_model
+
+    spec, params = load_model(tiny_checkpoint)
+    cut_spec, cut = parity.shallow_cut(spec, params)
+    assert cut_spec.n_layers == 2 and spec.n_layers == 4
+    assert cut["wcls"] is params["wcls"]
+    for name, whole in params["blocks"].items():
+        for got, want in zip(jax.tree.leaves(cut["blocks"][name]),
+                             jax.tree.leaves(whole)):
+            np.testing.assert_array_equal(got, want[[0, -1]])
+    wo = cut["blocks"]["wo"]
+    wrong = parity.mis_scaled(cut, "wo", 1.125)["blocks"]["wo"]
+    ratio = wrong.scales.astype(np.float32) / wo.scales
+    np.testing.assert_allclose(ratio[0], 1.125, rtol=2e-3)
+    np.testing.assert_array_equal(ratio[1], 1.0)
+    np.testing.assert_array_equal(wrong.data, wo.data)
+
+
+@pytest.mark.parametrize("error, within", [
+    (0.0, True),     # the same logits
+    (0.01, True),    # under every bound
+    (0.1, False),    # a tenth of the scale: inside the full pass's bf16
+                     # floor, and exactly what the shallow pass must refuse
+    (1.0, False)])   # uncorrelated
+def test_parity_shallow_bounds(error, within):
+    rng = np.random.default_rng(0)
+    b = rng.standard_normal((8, 512)).astype(np.float32)
+    a = b + error * rng.standard_normal(b.shape).astype(np.float32)
+    assert parity.compare(a, b, parity.BOUNDS["shallow"])["within"] is within
