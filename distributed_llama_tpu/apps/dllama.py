@@ -143,10 +143,6 @@ def build_parser(include_mode: bool = True) -> argparse.ArgumentParser:
                         "and write a Chrome trace-event JSON at exit — load "
                         "it in Perfetto (ui.perfetto.dev) or chrome://tracing "
                         "(obs/trace.py; docs/OBSERVABILITY.md)")
-    p.add_argument("--trace-annotate", action="store_true",
-                   help="with --trace: also forward each span as a "
-                        "jax.profiler TraceAnnotation so spans appear inside "
-                        "an XLA device trace")
     p.add_argument("--nthreads", type=int, default=None, help="ignored (XLA owns the chip)")
     p.add_argument("--kv-cache-storage", default=None,
                    choices=["ram", "host", "disc"],
@@ -230,7 +226,7 @@ def install_trace(args) -> bool:
         return False
     from ..obs import trace
 
-    trace.install(jax_annotations=getattr(args, "trace_annotate", False))
+    trace.install()
     return True
 
 

@@ -3,9 +3,11 @@
 Sibling modules, all dependency-free and safe to import from any layer:
 
 - `obs.trace`  — thread-safe span tracer with Chrome trace-event JSON export
-  (Perfetto-loadable); process-wide no-op until `trace.install()` runs
-  (`dllama --trace out.json`, `bench.py --trace`); `merge_chrome_traces`
-  folds a fleet's per-process traces into one aligned file.
+  (Perfetto-loadable); records once `trace.install()` runs
+  (`dllama --trace out.json`, `bench.py --trace`), and every span is a
+  `jax.profiler.TraceAnnotation` besides, so a profiler session sees the
+  spans on the device's clock; `merge_chrome_traces` folds a fleet's
+  per-process traces into one aligned file.
 - `obs.metrics` — counters / gauges / histograms with Prometheus text
   exposition, served by `api_server` at `GET /metrics` (and as a JSON
   snapshot at `GET /v1/stats`).
@@ -19,9 +21,10 @@ Sibling modules, all dependency-free and safe to import from any layer:
   tracer drops, build info) for /metrics.
 
 The runtime (engine, batch_engine, speculative, paged_cache, hlo_stats) is
-instrumented unconditionally: metrics cost one lock + add per event and the
-disabled tracer/recorder cost one global check per call site
-(perf/obs_overhead.py pins the overhead at <1% of a decode dispatch).
+instrumented unconditionally: metrics cost one lock + add per event, a span
+with no tracer installed costs one bare profiler annotation, the disabled
+recorder one global check (perf/obs_overhead.py measures the bundle against
+a decode dispatch).
 docs/OBSERVABILITY.md has the full span/metric inventory.
 """
 

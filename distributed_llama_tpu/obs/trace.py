@@ -9,11 +9,11 @@ loadable in Perfetto (ui.perfetto.dev) or chrome://tracing.
 
 Design constraints, in priority order:
 
-1. **Zero-cost when disabled.** Every hot path in the repo calls
-   `obs.trace.span(...)` unconditionally; when no tracer is installed the
-   call returns a shared no-op context manager (one global lookup + one
-   function call, no allocation). perf/obs_overhead.py pins this at <1% of a
-   decode dispatch.
+1. **Cheap when nothing listens.** Every hot path in the repo calls
+   `obs.trace.span(...)` unconditionally. With no tracer installed the call
+   returns a bare `jax.profiler.TraceAnnotation` (about half a microsecond
+   while no profiler session runs; perf/obs_overhead.py measures it), or, in
+   a process that never imported jax, a shared no-op context manager.
 2. **Thread-safe.** The BatchEngine scheduler thread, HTTP handler threads,
    and the main thread all emit spans concurrently; the buffer is a
    lock-guarded deque and span timing state lives on the span object itself
@@ -25,15 +25,20 @@ Design constraints, in priority order:
    relative to tracer start; wall-clock (time.time) appears once in the
    export metadata, so NTP steps can never fold spans over each other.
 
-Optional `jax.profiler` pass-through: with `jax_annotations=True` each span
-also enters a jax.profiler.TraceAnnotation, so the spans show up inside an
-XLA device trace under the same names.
+Every span is also a `jax.profiler.TraceAnnotation` with the span's entry
+args as keyword arguments, tracer or no tracer: a profiler session that
+happens to run (`jax.profiler.start_trace`) records the span on the calling
+thread's line of its host plane, on the clock of the device planes, under
+the same name. There is no switch for it. The class is taken from
+`sys.modules`, never imported: a process that has not imported jax (the
+fleet router) does not do so for a span.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -45,7 +50,8 @@ __all__ = ["Tracer", "span", "instant", "install", "uninstall", "current",
 
 
 class _NullSpan:
-    """Shared no-op context manager returned while tracing is disabled."""
+    """Shared no-op context manager: what span() returns with no tracer
+    installed in a process that has not imported jax."""
 
     __slots__ = ()
 
@@ -90,17 +96,14 @@ class _Span:
             self.args = args
         else:
             self.args.update(args)
+        if self._annot is not None:
+            self._annot.set_metadata(**args)
 
     def __enter__(self):
-        t = self._tracer if self._tracer is not None else _tracer
-        if t is not None and t._annotate:
-            try:
-                import jax.profiler
-
-                self._annot = jax.profiler.TraceAnnotation(self.name)
-                self._annot.__enter__()
-            except Exception:
-                self._annot = None  # device trace unavailable: spans still record
+        cls = _annotation_class()
+        if cls is not None:
+            self._annot = cls(self.name, **(self.args or {}))
+            self._annot.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -114,6 +117,30 @@ class _Span:
         return False
 
 
+_annot_cls = None
+
+
+def _annotation_class():
+    """`jax.profiler.TraceAnnotation` with `_Span`'s `add`, once jax is in
+    this process; None until then. Looked up in sys.modules so that a span
+    never imports jax."""
+    global _annot_cls
+    if _annot_cls is None:
+        profiler = sys.modules.get("jax.profiler")
+        base = getattr(profiler, "TraceAnnotation", None)
+        if base is None:  # jax absent, or still half-way through its import
+            return None
+
+        class _Annotation(base):
+            __slots__ = ()
+
+            def add(self, **args) -> None:
+                self.set_metadata(**args)
+
+        _annot_cls = _Annotation
+    return _annot_cls
+
+
 class Tracer:
     """Thread-safe span recorder with a bounded ring buffer.
 
@@ -123,13 +150,12 @@ class Tracer:
     the child entered after and exited before on the same thread.
     """
 
-    def __init__(self, capacity: int = 65536, *, jax_annotations: bool = False,
-                 pid: int | None = None, process_name: str | None = None):
+    def __init__(self, capacity: int = 65536, *, pid: int | None = None,
+                 process_name: str | None = None):
         assert capacity > 0
         self.capacity = capacity
         self._events: deque = deque(maxlen=capacity)
         self._lock = threading.Lock()  # guards: _events, _thread_names, dropped_events
-        self._annotate = jax_annotations
         self._epoch_ns = time.perf_counter_ns()
         self._wall_start = time.time()
         self.dropped_events = 0
@@ -250,15 +276,14 @@ class Tracer:
 _tracer: Tracer | None = None
 
 
-def install(capacity: int = 65536, *, jax_annotations: bool = False,
+def install(capacity: int = 65536, *,
             process_name: str | None = None) -> Tracer:
     """Enable tracing process-wide; returns the tracer. A second install
     replaces the first; module-level spans already in flight record through
     the NEW tracer at exit (they resolve the installed tracer at record
     time), so a replace can no longer strand events in an orphaned buffer."""
     global _tracer
-    _tracer = Tracer(capacity, jax_annotations=jax_annotations,
-                     process_name=process_name)
+    _tracer = Tracer(capacity, process_name=process_name)
     return _tracer
 
 
@@ -280,14 +305,13 @@ def set_process_name(name: str) -> None:
 
 
 def span(name: str, args: dict | None = None):
-    """`with span("engine.decode", {"t": 1}):` — no-op unless install()ed.
-
-    Args are passed as an optional dict (not **kwargs) so the disabled path
-    does not even build a dict per call site when the caller pre-builds
-    nothing; callers that want rich args construct the dict inline, paying
-    for it only at sites they chose to annotate."""
+    """`with span("engine.decode", {"t": 1}):` — a profiler annotation
+    always, and an event in the ring while a tracer is install()ed."""
     if _tracer is None:
-        return _NULL_SPAN
+        cls = _annot_cls or _annotation_class()
+        if cls is None:
+            return _NULL_SPAN
+        return cls(name, **args) if args else cls(name)
     # tracer=None: module-resolved — records through whichever tracer is
     # installed when the span exits (see _Span docstring)
     return _Span(None, name, args)

@@ -114,6 +114,26 @@ _ROLLBACK_TOKENS = metrics.counter(
 _PARKED_ROW_STEPS = metrics.counter(
     "batch_parked_row_steps_total",
     "Row-steps spent parked (rows riding a dispatch without advancing)")
+# What a dispatch is given against what it needs, from the shapes and the
+# live rows (host integers, nothing read from the device): positions are
+# rows x T (or rows x K of a scan), attention pairs are positions x the
+# window bucket; real ones hold a token of a request, with its causal length.
+_POSITIONS_DISPATCHED = metrics.counter(
+    "batch_positions_dispatched_total",
+    "Token positions the dispatched programs computed: slots x T of a step "
+    "or verify block, slots x K of a scan, parked rows and padding included")
+_POSITIONS_REAL = metrics.counter(
+    "batch_positions_real_total",
+    "Dispatched positions that held a token of a request: a prefill chunk's "
+    "tokens, one per rider or active row, a row's budget in a scan or verify")
+_ATTN_PAIRS_DISPATCHED = metrics.counter(
+    "batch_attn_pairs_dispatched_total",
+    "Query-key pairs the attention kernel was asked for: dispatched "
+    "positions x the window bucket (the context length where unbucketed)")
+_ATTN_PAIRS_REAL = metrics.counter(
+    "batch_attn_pairs_real_total",
+    "Query-key pairs causal attention needs: for each real position, its "
+    "position + 1")
 _PREFILL_TOKENS = metrics.counter(
     "batch_prefill_tokens_total", "Prompt tokens prefilled by the scheduler")
 _DECODE_TOKENS = metrics.counter(
@@ -153,14 +173,16 @@ _DISPATCH_AGE = metrics.gauge(
     "Dispatch watchdog: seconds since the scheduler last completed a device "
     "dispatch, 0 while idle (read at scrape time)")
 # Pipelined super-step telemetry (docs/SERVING.md "Pipelined decode"): the
-# gap histogram is the win (device-idle time between decode dispatches ->
-# ~0 when chained), the flush counter the cost (speculated device work
-# discarded when the host schedule diverged).
+# gap histogram is the win (host time between dispatches -> ~0 when
+# chained), the flush counter the cost (speculated device work discarded
+# when the host schedule diverged).
 _DISPATCH_GAP = metrics.histogram(
     "batch_dispatch_gap_seconds",
-    "Device-idle gap before a decode super-step: host time between the "
-    "previous dispatch's results landing and this dispatch being issued "
-    "(0 when chained from device state while the predecessor is in flight)")
+    "Host time between the previous dispatch's results reaching the host "
+    "and this dispatch being issued, observed before every dispatch that "
+    "follows one without an idle wait (0 when chained from device state "
+    "while the predecessor is in flight). Host clock: the device's own idle "
+    "time is read from a profiler trace (sched.gap_ms)")
 _PIPELINE_DEPTH = metrics.gauge(
     "batch_pipeline_depth",
     "Decode super-steps currently in flight on device (2 = overlapped: one "
@@ -452,12 +474,12 @@ class _InflightStep:
     device, so a chained scan consumes it soundly for any accept outcome."""
 
     __slots__ = ("rows", "k", "starts", "budget", "temps", "toks", "tok",
-                 "pos", "rng", "t_issue", "chained", "kind", "ndraft", "acc",
-                 "cstate")
+                 "pos", "rng", "t_issue", "chained", "window", "kind",
+                 "ndraft", "acc", "cstate")
 
     def __init__(self, rows, k, starts, budget, temps, toks, tok, pos, rng,
-                 t_issue, chained, kind="scan", ndraft=None, acc=None,
-                 cstate=None):
+                 t_issue, chained, window, kind="scan", ndraft=None,
+                 acc=None, cstate=None):
         self.rows = rows  # list[(slot, request)] for budget > 0 rows
         self.k = k
         self.starts = starts  # expected per-row device start positions
@@ -469,6 +491,7 @@ class _InflightStep:
         self.rng = rng  # device (B, 2) advanced xorshift* state
         self.t_issue = t_issue
         self.chained = chained
+        self.window = window  # keys attention ran against (bucket or context)
         self.kind = kind  # "scan" | "verify"
         self.ndraft = ndraft  # verify: per-row draft counts (-1 = parked)
         self.acc = acc  # verify: device (B,) accepted draft lengths
@@ -1754,34 +1777,66 @@ class BatchEngine:
                 time.sleep(min(delay, 1.0))
                 delay *= 2
 
-    def _step(self, tokens_rows: list[list[int]], starts: list[int], t: int,
-              kind: str = "step"):
-        """Run one batched (B, t) step; returns logits (B, t, vocab) np.ndarray."""
+    def _stage(self, tokens_rows: list[list[int]], starts: list[int], t: int):
+        """Host half of one batched (B, t) step, under the caller's
+        `batch.build` span: the window bucket, its program, and the inputs
+        on the device. Returns the keys attention runs against (the bucket,
+        or the context length where there is none) and what _step takes."""
         eng = self._eng
         window = eng._window_for(max(s + t for s in starts))
-        step = eng._step_for(window)
         toks = jnp.asarray(np.asarray(tokens_rows, dtype=np.int32))
         start_pos = jnp.asarray(np.asarray(starts, dtype=np.int32))
+        tables = self._tables() if self.kv_pool is not None else None
+        return (window or self.spec.seq_len,
+                (eng._step_for(window), toks, start_pos, tables))
+
+    def _count_work(self, positions: int, window: int,
+                    real: list[tuple[int, int]]) -> None:
+        """One dispatch's useful-work counters: `positions` per row were
+        dispatched against `window`; `real` lists (start, n) for each run of
+        n request tokens from position start (causal length start + i + 1)."""
+        dispatched = self.slots_n * positions
+        _POSITIONS_DISPATCHED.inc(dispatched)
+        _ATTN_PAIRS_DISPATCHED.inc(dispatched * window)
+        _POSITIONS_REAL.inc(sum(n for _, n in real))
+        _ATTN_PAIRS_REAL.inc(sum(n * p + n * (n + 1) // 2 for p, n in real))
+
+    def _observe_gap(self) -> None:
+        """Before a dispatch is issued from host state: the host time since
+        the previous dispatch's results arrived (nothing ran on the device
+        in between). No observation after an idle wait."""
+        if self._gap_t is not None:
+            _DISPATCH_GAP.observe(max(time.perf_counter() - self._gap_t, 0.0))
+
+    def _step(self, staged, kind: str = "step"):
+        """Dispatch one staged (B, t) step and wait for it; returns logits
+        (B, t, vocab) np.ndarray."""
+        eng = self._eng
+        step, toks, start_pos, tables = staged
         # snapshot the cache refs NOW and rebind only after _dispatched's
         # epoch check: a thread abandoned by recover_wedged mid-stall must
         # neither donate the re-initialized backend's fresh cache arrays nor
         # rebind its stale outputs over them
         kc_in, vc_in = eng.k_cache, eng.v_cache
-        tables = self._tables() if self.kv_pool is not None else None
+        self._observe_gap()
 
         def call():
-            if tables is not None:
-                logits, kc, vc = step(
-                    eng.params, eng.rope, toks, kc_in, vc_in, start_pos,
-                    tables)
-            else:
-                logits, kc, vc = step(
-                    eng.params, eng.rope, toks, kc_in, vc_in, start_pos)
-            return np.asarray(logits), kc, vc
+            with trace.span("batch.launch"):
+                if tables is not None:
+                    logits, kc, vc = step(
+                        eng.params, eng.rope, toks, kc_in, vc_in, start_pos,
+                        tables)
+                else:
+                    logits, kc, vc = step(
+                        eng.params, eng.rope, toks, kc_in, vc_in, start_pos)
+            # the wait for the device and the copy of every row's logits
+            with trace.span("batch.fetch", {"bytes": logits.nbytes}):
+                out = np.asarray(logits)
+            return out, kc, vc
 
         out, eng.k_cache, eng.v_cache = self._dispatched(kind, call)
-        # sync dispatch: results are host-side now — the reference point the
-        # device-idle-gap histogram measures the next decode issue against
+        # sync dispatch: results are host-side now, the reference point of
+        # the next dispatch's batch_dispatch_gap_seconds
         self._gap_t = time.perf_counter()
         return out
 
@@ -1930,7 +1985,7 @@ class BatchEngine:
             starts.append(p)
         return starts
 
-    def _admit(self) -> None:
+    def _admit(self) -> tuple[int, int]:
         """Drain the cross-thread queue into the scheduler-local
         weighted-fair wait queue, reap cancelled/expired queued requests,
         and assign in WFQ order onto free slots — interactive class first,
@@ -1938,8 +1993,10 @@ class BatchEngine:
         slot is free and the fair queue's head is INTERACTIVE, a batch-class
         row is preempted at this super-step boundary (its request re-queued,
         to resume byte-identical later) so interactive TTFT is bounded by
-        one dispatch, not a batch request's whole generation."""
+        one dispatch, not a batch request's whole generation. Returns
+        (requests given a slot, requests left queued)."""
         now = time.perf_counter()
+        admitted = 0
         # preempted rows' prefix harvests are SNAPSHOTTED under the lock
         # but copied device→host after it: jax arrays are immutable, so
         # the captured cache refs survive the slot's reassignment, and the
@@ -2011,9 +2068,12 @@ class BatchEngine:
                         continue  # a slot is free now; re-try this head
                     break
                 self._pending.pop_next()
-            _QUEUE_DEPTH.set(len(self._pending) + self._queue.qsize())
+                admitted += 1
+            queued = len(self._pending) + self._queue.qsize()
+            _QUEUE_DEPTH.set(queued)
         for history, kc, vc, index in harvests:
             self._harvest_rows(history, kc, vc, index)
+        return admitted, queued
 
     def _drain_submit_queue(self) -> None:  # holds: self._plock
         """Move cross-thread submissions into the weighted-fair queue.
@@ -2269,11 +2329,17 @@ class BatchEngine:
                 _SCHED_ALIVE.set(0)
 
     def _loop_once(self) -> None:
-        self._admit()
-        self._reap_slots()
-        prefill = [s for s in self._slots if s.req and s.pending]
-        active = [s for s in self._slots if s.req and not s.pending]
-        _SLOTS_OCCUPIED.set(sum(1 for s in self._slots if s.req is not None))
+        # every statement of a pass lies under a batch.* span (admit,
+        # advance, build, the dispatch with launch and fetch, deliver, wait):
+        # a profiler trace names what the host did while the device idled
+        with trace.span("batch.admit") as sp:
+            admitted, queued = self._admit()
+            self._reap_slots()
+            prefill = [s for s in self._slots if s.req and s.pending]
+            active = [s for s in self._slots if s.req and not s.pending]
+            _SLOTS_OCCUPIED.set(sum(1 for s in self._slots
+                                    if s.req is not None))
+            sp.add(admitted=admitted, queued=queued)
         try:
             if self._inflight is not None:
                 # a chained super-step is running on device: deliver it (and
@@ -2313,7 +2379,7 @@ class BatchEngine:
                 # enqueue latency is set by the notify, not this number.
                 # 0.1 s also bounds queue-TTL/deadline detection while idle.
                 self._gap_t = None  # an idle device is not a starved one
-                with self._cond:
+                with self._cond, trace.span("batch.wait"):
                     if self._queue.empty() and not self._shutdown:
                         self._cond.wait(timeout=0.1)
         except Exception as e:  # unattributable: fail all, survive, back off
@@ -2447,149 +2513,173 @@ class BatchEngine:
         # mixed prefill+decode: each active decode row rides this dispatch with
         # its next token at index 0 (rows advance one token per prefill chunk
         # instead of stalling behind it)
-        riders = [r for r in riders if self._advance_row(r)]
-        chunk = next((c for c in PREFILL_CHUNKS if len(slot.pending) >= c), 1)
-        chunk = min(chunk, room)
-        # keep parked rows' scratch writes inside the cache without touching history:
-        # a parked row writes [pos, pos+chunk) which must fit under seq_len; shrink the
-        # chunk when any OTHER row sits too close to the end (its history would be
-        # corrupted by a clamped write below its pos)
-        for other in self._slots:
-            if other is not slot and other.req is not None:
-                chunk = min(chunk, max(s - other.pos, 1))
-        piece = slot.pending[:chunk]
-        t = len(piece)
-        starts = self._park_positions(t)
-        if slot.req is None:  # reaped by a clamp-park CoW exhaustion
-            return
-        riders = [r for r in riders if r.req is not None]
-        starts[slot.index] = slot.pos
-        rows = [[0] * t for _ in self._slots]
-        rows[slot.index] = piece
-        for r in riders:
-            # real token at index 0, scratch beyond: the rider's positions
-            # pos+1..pos+t-1 are masked future slots its own later decodes
-            # overwrite (in-bounds by the chunk shrink above)
-            starts[r.index] = r.pos
-            rows[r.index] = [r.last_token] + [0] * (t - 1)
-        if self.kv_pool is not None:
-            # block coverage for every committed write this dispatch makes
-            # (the prefill chunk, each rider's one real token); scratch
-            # beyond coverage lands in the scratch block by design. A
-            # RIDER's exhaustion fails the rider, not the innocent prefill
-            # (the victim's own failure propagates and is attributed to it
-            # by _loop_once's request-scope handler)
-            self._paged_ensure(slot, slot.pos + t)
-            for r in riders[:]:
-                try:
-                    self._paged_ensure(r, r.pos + 1)
-                except Exception as e:
-                    if classify(e) != "request":
-                        raise
-                    self._fail_request(r, e)
-                    riders.remove(r)
+        with trace.span("batch.advance", {"rows": len(riders)}):
+            riders = [r for r in riders if self._advance_row(r)]
+        with trace.span("batch.build"):
+            chunk = next((c for c in PREFILL_CHUNKS
+                          if len(slot.pending) >= c), 1)
+            chunk = min(chunk, room)
+            # keep parked rows' scratch writes inside the cache without
+            # touching history: a parked row writes [pos, pos+chunk) which
+            # must fit under seq_len; shrink the chunk when any OTHER row
+            # sits too close to the end (its history would be corrupted by a
+            # clamped write below its pos)
+            for other in self._slots:
+                if other is not slot and other.req is not None:
+                    chunk = min(chunk, max(s - other.pos, 1))
+            piece = slot.pending[:chunk]
+            t = len(piece)
+            starts = self._park_positions(t)
+            if slot.req is None:  # reaped by a clamp-park CoW exhaustion
+                return
+            riders = [r for r in riders if r.req is not None]
+            starts[slot.index] = slot.pos
+            rows = [[0] * t for _ in self._slots]
+            rows[slot.index] = piece
+            for r in riders:
+                # real token at index 0, scratch beyond: the rider's
+                # positions pos+1..pos+t-1 are masked future slots its own
+                # later decodes overwrite (in-bounds by the chunk shrink
+                # above)
+                starts[r.index] = r.pos
+                rows[r.index] = [r.last_token] + [0] * (t - 1)
+            if self.kv_pool is not None:
+                # block coverage for every committed write this dispatch
+                # makes (the prefill chunk, each rider's one real token);
+                # scratch beyond coverage lands in the scratch block by
+                # design. A RIDER's exhaustion fails the rider, not the
+                # innocent prefill (the victim's own failure propagates and
+                # is attributed to it by _loop_once's request-scope handler)
+                self._paged_ensure(slot, slot.pos + t)
+                for r in riders[:]:
+                    try:
+                        self._paged_ensure(r, r.pos + 1)
+                    except Exception as e:
+                        if classify(e) != "request":
+                            raise
+                        self._fail_request(r, e)
+                        riders.remove(r)
+            window, staged = self._stage(rows, starts, t)
         # the dispatch belongs to the prefilling request: bind its context
         # so the span (and any dispatch fault) carries its trace id
         with reqctx.use(slot.req.ctx), \
                 trace.span("batch.mixed_step" if riders else "batch.prefill",
-                           {"chunk": t, "riders": len(riders)}):
-            logits = self._step(rows, starts, t,
+                           {"chunk": t, "riders": len(riders),
+                            "window": window, "slots": self.slots_n}):
+            logits = self._step(staged,
                                 kind="mixed" if riders else "prefill")
-        if riders:
-            self.mixed_steps += 1
-        dt_ms = (time.perf_counter() - t0) * 1000.0
-        flight.event(slot.req.rid, "prefill_chunk", chunk=t,
-                     riders=len(riders), ms=round(dt_ms, 3))
-        (_DISP_MIXED if riders else _DISP_PREFILL).observe(dt_ms / 1000.0)
-        _PREFILL_TOKENS.inc(t)
-        # rows neither prefilling nor riding spent this dispatch parked
-        _PARKED_ROW_STEPS.inc(self.slots_n - 1 - len(riders))
-        self.prefilled_tokens += t
-        slot.pos += t
-        slot.history.extend(piece)
-        slot.pending = slot.pending[t:]
-        if not slot.pending:
-            slot.last_logits = logits[slot.index, -1]
-            slot.last_token = slot.history[-1]
-        slot.req.stats.prefill_ms += dt_ms
-        slot.req.stats.dispatch_ms.append(dt_ms)
-        for r in riders:  # each rider decoded one token in this dispatch
-            r.last_logits = logits[r.index, 0]
-            r.history.append(r.last_token)
-            r.pos += 1
-            r.armed = False  # the dispatch ingested last_token's KV
-            r.req.stats.token_ms.append(dt_ms)
-            r.req.stats.infer_ms.append(dt_ms)
-            r.req.stats.dispatch_ms.append(dt_ms)
+        with trace.span("batch.deliver"):
+            if riders:
+                self.mixed_steps += 1
+            dt_ms = (time.perf_counter() - t0) * 1000.0
+            flight.event(slot.req.rid, "prefill_chunk", chunk=t,
+                         riders=len(riders), ms=round(dt_ms, 3))
+            (_DISP_MIXED if riders else _DISP_PREFILL).observe(dt_ms / 1000.0)
+            _PREFILL_TOKENS.inc(t)
+            # rows neither prefilling nor riding spent this dispatch parked
+            _PARKED_ROW_STEPS.inc(self.slots_n - 1 - len(riders))
+            self._count_work(t, window, [(slot.pos, t)]
+                             + [(r.pos, 1) for r in riders])
+            self.prefilled_tokens += t
+            slot.pos += t
+            slot.history.extend(piece)
+            slot.pending = slot.pending[t:]
+            if not slot.pending:
+                slot.last_logits = logits[slot.index, -1]
+                slot.last_token = slot.history[-1]
+            slot.req.stats.prefill_ms += dt_ms
+            slot.req.stats.dispatch_ms.append(dt_ms)
+            for r in riders:  # each rider decoded one token in this dispatch
+                r.last_logits = logits[r.index, 0]
+                r.history.append(r.last_token)
+                r.pos += 1
+                r.armed = False  # the dispatch ingested last_token's KV
+                r.req.stats.token_ms.append(dt_ms)
+                r.req.stats.infer_ms.append(dt_ms)
+                r.req.stats.dispatch_ms.append(dt_ms)
 
     def _decode_step(self, active: list[_Slot]) -> None:
         # bring every row to its next un-ingested token (host-samples rows at a
         # prefill/single-step boundary; consumes the device-sampled tail after
         # a super-step)
-        for slot in active[:]:
-            if not self._advance_row(slot):
-                active.remove(slot)
-        if self.kv_pool is not None:
-            # every row's next write needs a real block behind it; a pool
-            # that cannot serve even after reclaim fails ONLY that request
+        with trace.span("batch.advance", {"rows": len(active)}):
             for slot in active[:]:
-                try:
-                    self._paged_ensure(slot, slot.pos + 1)
-                except Exception as e:
-                    if classify(e) != "request":
-                        raise
-                    self._fail_request(slot, e)
+                if not self._advance_row(slot):
                     active.remove(slot)
-        if not active:
-            return
-        if self.spec_k:
+        with trace.span("batch.build"):
+            if self.kv_pool is not None:
+                # every row's next write needs a real block behind it; a pool
+                # that cannot serve even after reclaim fails ONLY that request
+                for slot in active[:]:
+                    try:
+                        self._paged_ensure(slot, slot.pos + 1)
+                    except Exception as e:
+                        if classify(e) != "request":
+                            raise
+                        self._fail_request(slot, e)
+                        active.remove(slot)
+            if not active:
+                return
             # speculative path: draft per-row n-gram proposals; when any row
             # has a draft worth verifying, spend this dispatch on a (B, T)
             # verify block instead of the scan — one weight stream for up to
             # T tokens per row. Empty drafts fall through to the scan.
-            plan = self._plan_verify(active)
-            if plan is not None:
-                self._verify_step(*plan)
-                return
-        k = self.superstep
-        if k > 1:
-            with self._plock:
-                waiting = bool(self._pending) or not self._queue.empty()
-            if not waiting:
-                # per-row step budget: stop advancing at max_tokens / context
-                # end (the row parks for the rest of the scan)
-                budgets = {
-                    slot.index: min(k, slot.req.max_tokens - len(slot.req.out),
-                                    self.spec.seq_len - slot.pos)
-                    for slot in active}
-                if max(budgets.values()) >= 2:
-                    self._super_step(active, k, budgets)
-                    return
-        # single batched T=1 step: the admission-latency (and tail) path
+            plan = self._plan_verify(active) if self.spec_k else None
+            budgets = None
+            if plan is None and self.superstep > 1:
+                with self._plock:
+                    waiting = bool(self._pending) or not self._queue.empty()
+                if not waiting:
+                    # per-row step budget: stop advancing at max_tokens /
+                    # context end (the row parks for the rest of the scan)
+                    k = self.superstep
+                    budgets = {
+                        slot.index: min(k,
+                                        slot.req.max_tokens - len(slot.req.out),
+                                        self.spec.seq_len - slot.pos)
+                        for slot in active}
+                    if max(budgets.values()) < 2:
+                        budgets = None
+        if plan is not None:
+            self._verify_step(*plan)
+        elif budgets is not None:
+            self._super_step(active, self.superstep, budgets)
+        else:
+            self._single_step(active)
+
+    def _single_step(self, active: list[_Slot]) -> None:
+        """One batched T=1 step from host state: the admission-latency (and
+        tail) path."""
         t0 = time.perf_counter()
-        starts = self._park_positions(1)
-        # a clamp-park CoW under pool exhaustion may have reaped a row
-        active = [s for s in active if s.req is not None]
-        if not active:
-            return
-        rows = [[0]] * self.slots_n
-        for slot in active:
-            starts[slot.index] = slot.pos
-            rows[slot.index] = [slot.last_token]
-        with trace.span("batch.single_step", {"rows": len(active)}):
-            logits = self._step(rows, starts, 1, kind="single_step")
-        self.decode_steps += 1
-        dt_ms = (time.perf_counter() - t0) * 1000.0
-        _DISP_SINGLE.observe(dt_ms / 1000.0)
-        _PARKED_ROW_STEPS.inc(self.slots_n - len(active))
-        for slot in active:
-            slot.last_logits = logits[slot.index, -1]
-            slot.history.append(slot.last_token)
-            slot.pos += 1
-            slot.armed = False  # the dispatch ingested last_token's KV
-            slot.req.stats.token_ms.append(dt_ms)
-            slot.req.stats.infer_ms.append(dt_ms)
-            slot.req.stats.dispatch_ms.append(dt_ms)
+        with trace.span("batch.build"):
+            starts = self._park_positions(1)
+            # a clamp-park CoW under pool exhaustion may have reaped a row
+            active = [s for s in active if s.req is not None]
+            if not active:
+                return
+            rows = [[0]] * self.slots_n
+            for slot in active:
+                starts[slot.index] = slot.pos
+                rows[slot.index] = [slot.last_token]
+            window, staged = self._stage(rows, starts, 1)
+        with trace.span("batch.single_step",
+                        {"rows": len(active), "window": window,
+                         "slots": self.slots_n}):
+            logits = self._step(staged, kind="single_step")
+        with trace.span("batch.deliver"):
+            self.decode_steps += 1
+            dt_ms = (time.perf_counter() - t0) * 1000.0
+            _DISP_SINGLE.observe(dt_ms / 1000.0)
+            _PARKED_ROW_STEPS.inc(self.slots_n - len(active))
+            self._count_work(1, window, [(s.pos, 1) for s in active])
+            for slot in active:
+                slot.last_logits = logits[slot.index, -1]
+                slot.history.append(slot.last_token)
+                slot.pos += 1
+                slot.armed = False  # the dispatch ingested last_token's KV
+                slot.req.stats.token_ms.append(dt_ms)
+                slot.req.stats.infer_ms.append(dt_ms)
+                slot.req.stats.dispatch_ms.append(dt_ms)
 
     def _batched_loop(self, k: int, mode: str, window: int | None,
                       masked: bool = False):
@@ -2743,36 +2833,38 @@ class BatchEngine:
         slots (the free-rollback discipline); the device carry is rewound to
         the frontier so a chained scan composes for any accept outcome."""
         faults.fire("batch.verify", rows=len(active), block=t)
-        if self.kv_pool is not None:
-            for slot in active[:]:
-                try:
-                    self._paged_ensure(slot, slot.pos + t)
-                except Exception as e:
-                    if classify(e) != "request":
-                        raise
-                    self._fail_request(slot, e)
-                    active.remove(slot)
-                    drafts.pop(slot.index, None)
+        with trace.span("batch.build"):
+            if self.kv_pool is not None:
+                for slot in active[:]:
+                    try:
+                        self._paged_ensure(slot, slot.pos + t)
+                    except Exception as e:
+                        if classify(e) != "request":
+                            raise
+                        self._fail_request(slot, e)
+                        active.remove(slot)
+                        drafts.pop(slot.index, None)
+                if not active:
+                    return
+            starts = self._park_positions(t)
+            # a clamp-park CoW under pool exhaustion may have reaped a row
+            active = [s for s in active if s.req is not None]
             if not active:
                 return
-        starts = self._park_positions(t)
-        # a clamp-park CoW under pool exhaustion may have reaped a row
-        active = [s for s in active if s.req is not None]
-        if not active:
-            return
-        ndraft = [-1] * self.slots_n  # -1 parks the row inside the block
-        props = [[0] * t for _ in range(self.slots_n)]
-        budget = [0] * self.slots_n  # per-row MAX emit (accept + correction)
-        rows: list[tuple[_Slot, BatchRequest]] = []
-        for slot in active:
-            i = slot.index
-            d = drafts.get(i, [])
-            starts[i] = slot.pos
-            props[i] = [slot.last_token] + d + [0] * (t - 1 - len(d))
-            ndraft[i] = len(d)
-            budget[i] = len(d) + 1
-            rows.append((slot, slot.req))
-        fl = self._issue_verify_step(rows, t, ndraft, props, budget, starts)
+            ndraft = [-1] * self.slots_n  # -1 parks the row inside the block
+            props = [[0] * t for _ in range(self.slots_n)]
+            budget = [0] * self.slots_n  # per-row MAX emit (accept + correction)
+            rows: list[tuple[_Slot, BatchRequest]] = []
+            for slot in active:
+                i = slot.index
+                d = drafts.get(i, [])
+                starts[i] = slot.pos
+                props[i] = [slot.last_token] + d + [0] * (t - 1 - len(d))
+                ndraft[i] = len(d)
+                budget[i] = len(d) + 1
+                rows.append((slot, slot.req))
+            fl = self._issue_verify_step(rows, t, ndraft, props, budget,
+                                         starts)
         self._pipeline_advance(fl)
 
     # hot-path
@@ -2800,8 +2892,8 @@ class BatchEngine:
         window = eng._window_for(min(max(starts) + t, self.spec.seq_len))
         masked = self._constrained(rows)
         loop = self._verify_loop(t, mode, window, masked)
-        if self._gap_t is not None:
-            _DISPATCH_GAP.observe(max(time.perf_counter() - self._gap_t, 0.0))
+        window = window or self.spec.seq_len
+        self._observe_gap()
         t_issue = time.perf_counter()
         kc_in, vc_in = eng.k_cache, eng.v_cache  # same stale-epoch discipline
         tables = self._tables() if self.kv_pool is not None else None
@@ -2817,7 +2909,8 @@ class BatchEngine:
             _CONSTRAIN_DISPATCHES.inc()
         with trace.span("batch.verify_issue",
                         {"block": t, "rows": len(rows),
-                         "drafted": sum(max(n, 0) for n in ndraft)}):
+                         "drafted": sum(max(n, 0) for n in ndraft),
+                         "window": window}):
             if masked:
                 def call():
                     toks, acc, tok, pos, rng_out, kc, vc, cst = loop(
@@ -2845,7 +2938,7 @@ class BatchEngine:
             except Exception:
                 pass
         return _InflightStep(rows, t, starts, budget, temps, toks, tok, pos,
-                             rng_out, t_issue, False, kind="verify",
+                             rng_out, t_issue, False, window, kind="verify",
                              ndraft=ndraft, acc=acc, cstate=cst)
 
     def _drafts_ready(self, rows: list) -> bool:
@@ -2872,29 +2965,31 @@ class BatchEngine:
         are overwritten by the slot's next real writes (free rollback). With
         pipelining, the NEXT super-step is chained from this one's device
         carry before delivery starts (_pipeline_advance)."""
-        if self.kv_pool is not None:
-            for slot in active[:]:
-                try:
-                    self._paged_ensure(slot, slot.pos + budgets[slot.index])
-                except Exception as e:
-                    if classify(e) != "request":
-                        raise
-                    self._fail_request(slot, e)
-                    active.remove(slot)
+        with trace.span("batch.build"):
+            if self.kv_pool is not None:
+                for slot in active[:]:
+                    try:
+                        self._paged_ensure(slot,
+                                           slot.pos + budgets[slot.index])
+                    except Exception as e:
+                        if classify(e) != "request":
+                            raise
+                        self._fail_request(slot, e)
+                        active.remove(slot)
+                if not active:
+                    return
+            starts = self._park_positions(1)
+            # a clamp-park CoW under pool exhaustion may have reaped a row
+            active = [s for s in active if s.req is not None]
             if not active:
                 return
-        starts = self._park_positions(1)
-        # a clamp-park CoW under pool exhaustion may have reaped a row
-        active = [s for s in active if s.req is not None]
-        if not active:
-            return
-        budget = [0] * self.slots_n
-        rows: list[tuple[_Slot, BatchRequest]] = []
-        for slot in active:
-            starts[slot.index] = slot.pos
-            budget[slot.index] = budgets[slot.index]
-            rows.append((slot, slot.req))
-        fl = self._issue_super_step(rows, k, budget, starts)
+            budget = [0] * self.slots_n
+            rows: list[tuple[_Slot, BatchRequest]] = []
+            for slot in active:
+                starts[slot.index] = slot.pos
+                budget[slot.index] = budgets[slot.index]
+                rows.append((slot, slot.req))
+            fl = self._issue_super_step(rows, k, budget, starts)
         self._pipeline_advance(fl)
 
     def _pipeline_advance(self, fl: _InflightStep) -> None:
@@ -2905,57 +3000,62 @@ class BatchEngine:
         every row it decodes delivered its full budget and stayed live."""
         nxt = None
         plan = None
-        if self.pipeline and not self._shutdown and not self._draining:
-            plan = self._plan_chain(fl)
-        if plan is not None:
-            with self._plock:
-                waiting = bool(self._pending) or not self._queue.empty()
-            if waiting or any(s.req and s.pending for s in self._slots):
-                # a request needs the next dispatch for admission/prefill:
-                # break the chain instead of extending it — the pipelined
-                # analog of the K -> 1 admission-latency drop
-                _PIPELINE_FLUSHES.labels(reason="admission").inc()
-                plan = None
-        if plan is not None and self.kv_pool is not None:
-            # the chained dispatch's speculative writes need block coverage
-            # (and clamped parks need exclusive blocks) BEFORE issue; a pool
-            # that cannot serve declines the chain instead of failing rows
-            rows, starts, budget, clamp = plan
-            try:
-                for slot, _req in rows:
-                    self._paged_ensure(slot, starts[slot.index]
-                                       + budget[slot.index])
+        with trace.span("batch.build"):
+            if self.pipeline and not self._shutdown and not self._draining:
+                plan = self._plan_chain(fl)
+            if plan is not None:
+                with self._plock:
+                    waiting = bool(self._pending) or not self._queue.empty()
+                if waiting or any(s.req and s.pending for s in self._slots):
+                    # a request needs the next dispatch for admission/
+                    # prefill: break the chain instead of extending it — the
+                    # pipelined analog of the K -> 1 admission-latency drop
+                    _PIPELINE_FLUSHES.labels(reason="admission").inc()
+                    plan = None
+            if plan is not None and self.kv_pool is not None:
+                # the chained dispatch's speculative writes need block
+                # coverage (and clamped parks need exclusive blocks) BEFORE
+                # issue; a pool that cannot serve declines the chain instead
+                # of failing rows
+                rows, starts, budget, clamp = plan
+                try:
+                    for slot, _req in rows:
+                        self._paged_ensure(slot, starts[slot.index]
+                                           + budget[slot.index])
+                    for slot in clamp:
+                        self._paged_cow(slot, self.spec.seq_len - 1,
+                                        self.spec.seq_len)
+                except Exception:
+                    _PIPELINE_FLUSHES.labels(reason="pool").inc()
+                    plan = None
+            if plan is not None:
+                rows, starts, budget, clamp = plan
                 for slot in clamp:
-                    self._paged_cow(slot, self.spec.seq_len - 1,
-                                    self.spec.seq_len)
-            except Exception:
-                _PIPELINE_FLUSHES.labels(reason="pool").inc()
-                plan = None
-        if plan is not None:
-            rows, starts, budget, clamp = plan
-            for slot in clamp:
-                # the chained scan parks this row clamped at seq_len-1,
-                # destroying that history row — flag it before fl's delivery
-                # so a mid-delivery _finish harvests the truncated prefix
-                slot.clamp_pos = self.spec.seq_len - 1
-            nxt = self._issue_super_step(rows, self.superstep, budget, starts,
-                                         chain=fl)
-        try:
-            status = self._deliver_super_step(fl)
-        except BaseException:
+                    # the chained scan parks this row clamped at seq_len-1,
+                    # destroying that history row — flag it before fl's
+                    # delivery so a mid-delivery _finish harvests the
+                    # truncated prefix
+                    slot.clamp_pos = self.spec.seq_len - 1
+                nxt = self._issue_super_step(rows, self.superstep, budget,
+                                             starts, chain=fl)
+        with trace.span("batch.deliver"):
+            try:
+                status = self._deliver_super_step(fl)
+            except BaseException:
+                if nxt is not None:
+                    # delivery failed with the chained dispatch still a
+                    # local: account for it here — _fail_all only sees
+                    # self._inflight
+                    _PIPELINE_FLUSHES.labels(reason="error").inc()
+                _PIPELINE_DEPTH.set(0)
+                raise
             if nxt is not None:
-                # delivery failed with the chained dispatch still a local:
-                # account for it here — _fail_all only sees self._inflight
-                _PIPELINE_FLUSHES.labels(reason="error").inc()
-            _PIPELINE_DEPTH.set(0)
-            raise
-        if nxt is not None:
-            reason = self._chain_divergence(nxt, status)
-            if reason is not None:
-                self._flush_inflight(nxt, reason)
-            else:
-                self._inflight = nxt
-        _PIPELINE_DEPTH.set(1 if self._inflight is not None else 0)
+                reason = self._chain_divergence(nxt, status)
+                if reason is not None:
+                    self._flush_inflight(nxt, reason)
+                else:
+                    self._inflight = nxt
+            _PIPELINE_DEPTH.set(1 if self._inflight is not None else 0)
 
     def _plan_chain(self, fl: _InflightStep):  # hot-path
         """Speculative schedule for the scan super-step after `fl`, assuming
@@ -3044,13 +3144,10 @@ class BatchEngine:
                                      self.spec.seq_len))
         masked = self._constrained(rows)
         loop = self._batched_loop(k, mode, window, masked)
+        window = window or self.spec.seq_len
         if chain is None:
             tok_in, pos_in, rng_in = tokens, starts, rng
-            if self._gap_t is not None:
-                # device-idle gap: results of the previous dispatch landed at
-                # _gap_t and nothing ran on device until this issue
-                _DISPATCH_GAP.observe(max(time.perf_counter() - self._gap_t,
-                                          0.0))
+            self._observe_gap()
         else:
             tok_in, pos_in, rng_in = chain.tok, chain.pos, chain.rng
             _DISPATCH_GAP.observe(0.0)  # chained: the device never went idle
@@ -3072,7 +3169,7 @@ class BatchEngine:
             _CONSTRAIN_DISPATCHES.inc()
         with trace.span("batch.super_step_issue",
                         {"k": k, "rows": len(rows),
-                         "chained": chain is not None}):
+                         "chained": chain is not None, "window": window}):
             if masked:
                 def call():
                     toks, tok, pos, rng_out, kc, vc, cst = loop(
@@ -3100,7 +3197,8 @@ class BatchEngine:
             except Exception:  # an optimization hint only — e.g. dp-sharded
                 pass  # outputs may refuse the whole-array async copy
         return _InflightStep(rows, k, starts, budget, temps, toks, tok, pos,
-                             rng_out, t_issue, chain is not None, cstate=cst)
+                             rng_out, t_issue, chain is not None, window,
+                             cstate=cst)
 
     # hot-path
     def _deliver_super_step(self, fl: _InflightStep) -> dict[int, str]:
@@ -3117,7 +3215,8 @@ class BatchEngine:
         with trace.span("batch.super_step", {"k": k, "rows": len(fl.rows),
                                              "tokens": sum(fl.budget),
                                              "kind": fl.kind,
-                                             "chained": fl.chained}):
+                                             "chained": fl.chained,
+                                             "window": fl.window}):
             toks = np.asarray(fl.toks)  # dlint: ignore[hot-sync] -- THE delivery fence: one (K,B) block transfer per super-step is the design (1 sync per K tokens)
             rng_out = np.asarray(fl.rng)  # dlint: ignore[hot-sync] -- rides the same fence; copy_to_host_async at issue makes this a pickup, not a stall
             acc = np.asarray(fl.acc) if fl.kind == "verify" else None  # dlint: ignore[hot-sync] -- same fence (verify accept lengths)
@@ -3152,6 +3251,9 @@ class BatchEngine:
         # rows that ride the scan without a live request park for all k steps;
         # rows with a short budget park for the steps past it
         _PARKED_ROW_STEPS.inc(self.slots_n * k - sum(fl.budget))
+        self._count_work(k, fl.window,
+                         [(fl.starts[slot.index], fl.budget[slot.index])
+                          for slot, _req in fl.rows])
         status: dict[int, str] = {}
         accs: list[int] = []  # per-row accepted lengths (verify EMA input)
         for slot, req in fl.rows:
@@ -3343,6 +3445,7 @@ class BatchEngine:
         for discarded tokens)."""
         _PIPELINE_FLUSHES.labels(reason=reason).inc()
         _ROLLBACK_TOKENS.inc(sum(fl.budget))
+        self._count_work(fl.k, fl.window, [])  # ran on the device for nothing
         for slot, req in fl.rows:
             flight.event(req.rid, "pipeline_flush", reason=reason,
                          tokens=fl.budget[slot.index])

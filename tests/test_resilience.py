@@ -334,9 +334,13 @@ def test_queue_ttl_expiry(setup):
     try:
         _wait_until(lambda: sum(1 for s in be._slots if s.req) == 2,
                     msg="slots occupied")
-        victim = be.submit([1, 4, 5], 8, _greedy(spec), ttl=0.15)
-        with pytest.raises(DeadlineExceeded):
-            victim.wait(timeout=60)
+        # a latency fault paces the blockers (16 scans of ~40 ms): on a quiet
+        # box they otherwise end inside the TTL and the victim is admitted
+        with faults.active(FaultSpec("batch.dispatch", kind="latency",
+                                     delay_ms=40)):
+            victim = be.submit([1, 4, 5], 8, _greedy(spec), ttl=0.15)
+            with pytest.raises(DeadlineExceeded):
+                victim.wait(timeout=60)
         assert victim.finish == "deadline"
         assert victim.out == []  # never admitted, nothing generated
     finally:

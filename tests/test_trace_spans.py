@@ -1,0 +1,304 @@
+"""Every `obs.trace.span` is a `jax.profiler.TraceAnnotation`, the batched
+scheduler's loop lies under `batch.*` spans from one dispatch to the next,
+and four counters say how much of a dispatch is real work.
+
+The scheduler tests share ONE scripted run on a tiny engine with two slots
+(`scenario`): request A prefills three tokens one by one and blocks the
+scheduler thread inside the callback of its first token while request B is
+submitted; from there on the run is deterministic: a single T=1 step of A
+(B is waiting, so no scan), B's 8-token chunk with A riding it, one K=4
+scan of both rows, and both requests end."""
+
+import glob
+import os
+import subprocess
+import sys
+import threading
+
+import jax
+import pytest
+
+from distributed_llama_tpu.models.params import init_random_params
+from distributed_llama_tpu.models.spec import ArchType, ModelSpec, RopeType
+from distributed_llama_tpu.obs import metrics
+from distributed_llama_tpu.obs import trace as trace_mod
+from distributed_llama_tpu.quants import FloatType
+from distributed_llama_tpu.runtime.batch_engine import BatchEngine
+from distributed_llama_tpu.runtime.sampler import Sampler
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+# ---------------------------------------------------------------- the span
+
+
+def _profiled(tmp_path, body):
+    """Run `body` inside a CPU profiler session with the options
+    benchmark/run.py uses; returns the /host:CPU events named test.*, as
+    (name, duration_ns, {stats})."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    (host,) = [p for p in data.planes if p.name == "/host:CPU"]
+    return [(ev.name, ev.duration_ns, dict(ev.stats))
+            for line in host.lines for ev in line.events
+            if ev.name.startswith("test.")]
+
+
+def test_span_without_a_tracer_reaches_a_profiler_session(tmp_path):
+    trace_mod.uninstall()
+
+    def body():
+        with trace_mod.span("test.step", {"kind": "mixed", "chunk": 64}) as sp:
+            sp.add(bytes=123)  # known only inside the span
+        with trace_mod.span("test.bare"):
+            pass
+
+    events = _profiled(tmp_path, body)
+    assert [e[0] for e in events] == ["test.step", "test.bare"]
+    assert events[0][2] == {"kind": "mixed", "chunk": 64, "bytes": 123}
+    assert events[0][1] > 0 and events[1][2] == {}
+
+
+def test_span_reaches_the_profiler_and_an_installed_tracer(tmp_path):
+    tr = trace_mod.install(capacity=16)
+    try:
+        def body():
+            with trace_mod.span("test.both", {"k": 4}) as sp:
+                sp.add(tokens=9)
+
+        events = _profiled(tmp_path, body)
+    finally:
+        trace_mod.uninstall()
+    assert [(e[0], e[2]) for e in events] == [
+        ("test.both", {"k": 4, "tokens": 9})]
+    ring = [e for e in tr.events() if e["ph"] == "X"]
+    assert [(e["name"], e["args"]) for e in ring] == [
+        ("test.both", {"k": 4, "tokens": 9})]
+
+
+def test_span_costs_nothing_to_speak_of_with_no_listener():
+    """No profiler session, no tracer: a bare annotation, no ring event."""
+    trace_mod.uninstall()
+    sp = trace_mod.span("test.quiet", {"a": 1})
+    assert isinstance(sp, jax.profiler.TraceAnnotation)
+    with sp as inner:
+        inner.add(b=2)
+    tr = trace_mod.install(capacity=4)
+    try:
+        assert tr.events() == []
+    finally:
+        trace_mod.uninstall()
+
+
+def test_a_process_without_jax_gets_a_noop_span_and_imports_nothing():
+    code = (
+        "import sys\n"
+        "from distributed_llama_tpu.obs import trace\n"
+        "s1 = trace.span('router.proxy', {'replica': 'r0'})\n"
+        "s2 = trace.span('router.proxy')\n"
+        "assert s1 is s2, 'not the shared no-op'\n"
+        "with s1 as sp:\n"
+        "    sp.add(code=200)\n"
+        "tr = trace.install()\n"
+        "with trace.span('router.proxy', {'replica': 'r1'}) as sp:\n"
+        "    sp.add(code=200)\n"
+        "ev = [e for e in tr.events() if e['ph'] == 'X']\n"
+        "assert ev[0]['args'] == {'replica': 'r1', 'code': 200}, ev\n"
+        "bad = [m for m in sys.modules if m == 'jax' or m.startswith('jax.')"
+        " or m.startswith('jaxlib')]\n"
+        "assert not bad, bad\n"
+        "print('ok')\n")
+    out = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                         capture_output=True, text=True, timeout=60)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "ok"
+
+
+# ----------------------------------------------------------- the scheduler
+
+SLOTS, K, SEQ = 2, 4, 128
+DISPATCH = ("batch.prefill", "batch.mixed_step", "batch.single_step")
+COUNTERS = ("batch_positions_dispatched_total", "batch_positions_real_total",
+            "batch_attn_pairs_dispatched_total",
+            "batch_attn_pairs_real_total")
+
+
+def _delta(after, before, name):
+    return after[name] - before.get(name, 0.0)
+
+
+@pytest.fixture(scope="module")
+def scenario():
+    spec = ModelSpec(arch_type=ArchType.LLAMA, dim=64, hidden_dim=128,
+                     n_layers=2, n_heads=4, n_kv_heads=4, vocab_size=256,
+                     seq_len=SEQ, rope_type=RopeType.LLAMA).resolved()
+    params = init_random_params(spec, FloatType.Q40, seed=11)
+    be = BatchEngine(spec, params, slots=SLOTS, tp=1, superstep=K)
+    first_token, release = threading.Event(), threading.Event()
+
+    def on_token(_tok):
+        if not first_token.is_set():
+            first_token.set()
+            assert release.wait(60)  # holds the scheduler thread
+
+    tr = trace_mod.install()
+    try:
+        idle = metrics.snapshot()
+        # A emits 1 token after its prefill, 1 before the mixed step, 1 before
+        # the scan and 4 in it; B 1 before the scan and 4 in it
+        a = be.submit([1, 2, 3], 7, Sampler(256, temperature=0.0),
+                      on_token=on_token)
+        assert first_token.wait(120)
+        held = metrics.snapshot()  # three prefill dispatches so far
+        b = be.submit(list(range(10, 18)), 5, Sampler(256, temperature=0.0))
+        release.set()
+        a.wait(120)
+        b.wait(120)
+        done = metrics.snapshot()
+        events = [e for e in tr.events() if e["ph"] == "X"]
+    finally:
+        trace_mod.uninstall()
+        be.close()
+    assert (a.finish, b.finish) == ("length", "length")
+    tid = next(e["tid"] for e in events if e["name"] == "batch.admit")
+    events = sorted((e for e in events if e["tid"] == tid),
+                    key=lambda e: (e["ts"], -e["dur"]))
+    return {"idle": idle, "held": held, "done": done, "events": events}
+
+
+def _passes(events):
+    """The scheduler thread's spans cut into loop passes (each opens with
+    batch.admit), as lists of top-level spans with their children."""
+    passes = []
+    for e in events:
+        if e["name"] == "batch.admit":
+            passes.append([])
+        if not passes:
+            continue
+        top = passes[-1]
+        if top and e["ts"] < top[-1][0]["ts"] + top[-1][0]["dur"]:
+            top[-1][1].append(e)
+        else:
+            top.append((e, []))
+    return passes
+
+
+def _names(top):
+    """Top-level span names of a pass, a run of one name once (deciding what
+    to dispatch and staging it are two batch.build spans in a row)."""
+    names = [e["name"] for e, _ in top]
+    return [n for i, n in enumerate(names) if i == 0 or n != names[i - 1]]
+
+
+def test_a_pass_is_admit_advance_build_dispatch_deliver(scenario):
+    seen = []
+    for top in _passes(scenario["events"]):
+        names = _names(top)
+        if not any(n in DISPATCH for n in names):
+            continue
+        assert names[:3] == ["batch.admit", "batch.advance", "batch.build"]
+        assert names[3] in DISPATCH and names[4:] == ["batch.deliver"], names
+        seen.append(names[3])
+        span, children = top[-2]
+        assert [c["name"] for c in children] == ["batch.launch",
+                                                 "batch.fetch"]
+        for c in children:  # children inside their parent, in order
+            assert span["ts"] <= c["ts"]
+            assert c["ts"] + c["dur"] <= span["ts"] + span["dur"] + 1e-3
+        assert children[0]["ts"] + children[0]["dur"] <= children[1]["ts"]
+        assert children[1]["args"]["bytes"] > 0
+    assert seen == ["batch.prefill"] * 3 + ["batch.single_step",
+                                            "batch.mixed_step"]
+
+
+def test_dispatch_spans_say_chunk_riders_window_and_slots(scenario):
+    args = [{k: v for k, v in e["args"].items() if k != "trace_id"}
+            for e in scenario["events"] if e["name"] in DISPATCH]
+    assert args == (
+        [{"chunk": 1, "riders": 0, "window": SEQ, "slots": SLOTS}] * 3
+        + [{"rows": 1, "window": SEQ, "slots": SLOTS},
+           {"chunk": 8, "riders": 1, "window": SEQ, "slots": SLOTS}])
+    # a request's dispatch keeps its trace id in the ring
+    assert all("trace_id" in e["args"] for e in scenario["events"]
+               if e["name"] in ("batch.prefill", "batch.mixed_step"))
+    issue = [e["args"] for e in scenario["events"]
+             if e["name"] == "batch.super_step_issue"]
+    assert [(a["k"], a["rows"], a["chained"], a["window"]) for a in issue] \
+        == [(K, 2, False, SEQ)]
+    admits = [e["args"] for e in scenario["events"]
+              if e["name"] == "batch.admit"]
+    assert sum(a["admitted"] for a in admits) == 2
+    assert all(a["queued"] == 0 for a in admits)
+
+
+def test_the_spans_of_a_pass_leave_no_time_between_them(scenario):
+    checked = 0
+    for top in _passes(scenario["events"]):
+        if not any(e["name"] in DISPATCH for e, _ in top):
+            continue
+        spans = [e for e, _ in top]
+        whole = spans[-1]["ts"] + spans[-1]["dur"] - spans[0]["ts"]
+        between = sum(b["ts"] - (a["ts"] + a["dur"])
+                      for a, b in zip(spans, spans[1:]))
+        assert 0 <= between < 0.05 * whole, (between, whole,
+                                             [e["name"] for e in spans])
+        checked += 1
+    assert checked == 5
+
+
+def test_the_scan_and_the_idle_wait_lie_under_spans_too(scenario):
+    names = [e["name"] for e in scenario["events"]]
+    i = names.index("batch.super_step_issue")
+    # the issue inside a build span, the block's fetch inside a deliver span
+    assert names[i - 1] == "batch.build"
+    j = names.index("batch.super_step")
+    assert names[j - 1] == "batch.deliver"
+    deliver = scenario["events"][j - 1]
+    fetch = scenario["events"][j]
+    assert deliver["ts"] <= fetch["ts"]
+    assert fetch["ts"] + fetch["dur"] <= deliver["ts"] + deliver["dur"] + 1e-3
+    assert fetch["args"]["window"] == SEQ
+
+
+def test_useful_work_counters_equal_the_hand_computed_values(scenario):
+    got = {n: _delta(scenario["done"], scenario["held"], n) for n in COUNTERS}
+    # single step: A alone at position 3. Mixed: B's chunk of 8 from 0, A
+    # riding at 4. Scan of K=4: A from 5, B from 8. No window bucket under
+    # 256 positions of context: attention runs against all SEQ keys.
+    single = (SLOTS * 1, 1, 3 + 1)
+    mixed = (SLOTS * 8, 8 + 1, sum(range(1, 9)) + (4 + 1))
+    scan = (SLOTS * K, 4 + 4,
+            sum(5 + i + 1 for i in range(4)) + sum(8 + i + 1 for i in range(4)))
+    steps = (single, mixed, scan)
+    assert got == {
+        "batch_positions_dispatched_total": sum(s[0] for s in steps),
+        "batch_positions_real_total": sum(s[1] for s in steps),
+        "batch_attn_pairs_dispatched_total": SEQ * sum(s[0] for s in steps),
+        "batch_attn_pairs_real_total": sum(s[2] for s in steps)}
+    # and A's prefill before it: three chunks of 1 at positions 0, 1, 2
+    before = {n: _delta(scenario["held"], scenario["idle"], n)
+              for n in COUNTERS}
+    assert before == {
+        "batch_positions_dispatched_total": 3 * SLOTS,
+        "batch_positions_real_total": 3,
+        "batch_attn_pairs_dispatched_total": 3 * SLOTS * SEQ,
+        "batch_attn_pairs_real_total": 1 + 2 + 3}
+
+
+def test_dispatch_gap_is_observed_once_per_dispatch_after_the_first(scenario):
+    def count(snap):
+        return snap.get("batch_dispatch_gap_seconds", {"count": 0})["count"]
+
+    # three prefills from an idle engine: the first has no predecessor
+    assert count(scenario["held"]) - count(scenario["idle"]) == 2
+    # then the single step, the mixed step and the scan's issue
+    assert count(scenario["done"]) - count(scenario["held"]) == 3
