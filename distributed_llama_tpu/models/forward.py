@@ -249,8 +249,9 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
         # Device-resident paged KV (docs/PAGED_KV.md): the caches are a
         # BLOCK POOL (L, N, hk, bt, hs) and each row's block table maps
         # virtual positions to pool blocks. Two readers, same semantics:
-        # the Pallas kernel DMAs exactly the table's blocks pool→VMEM
-        # (scalar-prefetch index_map, ops/pallas_paged_attention.py); the
+        # the Pallas kernel copies the table's blocks under each row's
+        # committed length pool→VMEM, 128 keys a step
+        # (ops/pallas_paged_attention.py); the
         # XLA fallback gathers the table into the dense window layout and
         # runs the SAME gqa_attention as the dense deferred branch — so on
         # the CPU mesh paged logits are bit-identical to dense logits
@@ -264,9 +265,8 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
         if paged_kernel:
             from ..ops.pallas_paged_attention import paged_attention
 
-            out = paged_attention(q.astype(jnp.float32), kc, vc, k_t, v_t,
-                                  block_tables, start_pos, layer_idx,
-                                  n_read=nb)
+            out = paged_attention(q, kc, vc, k_t, v_t, block_tables,
+                                  start_pos, layer_idx, n_read=nb)
             att = out.reshape(b, t, hq_local * hs).astype(x.dtype)
         else:
             from ..ops.pallas_paged_attention import paged_gather_kv

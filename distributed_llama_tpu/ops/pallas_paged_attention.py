@@ -1,4 +1,4 @@
-"""Paged attention over the device block pool — decode AND speculative verify.
+"""Paged attention over the device block pool — decode, verify and prefill chunks.
 
 The production counterpart of the device-resident paged KV refactor
 (docs/PAGED_KV.md): KV lives in a (L, N, hk, bt, hs) block pool and each
@@ -11,19 +11,26 @@ live here:
   path as the dense cache, so on the CPU mesh the paged engine is
   bit-identical to the dense engine (the token-identity acceptance bar).
 
-- `paged_attention` — the Pallas kernel: grid (B, hk, n_blocks); the block
-  table rides in as a SCALAR-PREFETCH argument so each grid step's
-  BlockSpec index_map DMAs exactly (layer, table[b, j], h) — no gather, no
-  materialized window, the cache bytes move straight pool→VMEM. A
-  flash-attention (m, l, acc) carry in VMEM scratch merges the blocks; the
-  current chunk's uncommitted K/V (T = 1 for the decode scan, T = 1+k for
-  the speculative verify dispatch) folds in at the last grid step with an
-  in-chunk causal mask. f16 never appears (Mosaic cannot lower f16 refs):
-  cache blocks load in their storage dtype and are cast to f32 in-kernel.
+- `paged_attention` — the Pallas kernel: grid (B,), one row a grid step,
+  all hk heads. The pools stay in HBM; the block table and the lengths ride
+  in as SCALAR-PREFETCH arguments. A row walks its window in steps of 128
+  keys (P = 128 // bt table blocks, 8 at bt 16): the step's blocks are
+  copied pool→VMEM by hand, a block's hk heads in one copy, into a
+  double-buffered (hk, P*bt, hs) tile — no gather, no materialized window —
+  and the score tile of a head is (T*g, 128): full lanes, one accumulator
+  rescale per 128 keys. A row runs ONLY the steps that hold committed keys
+  (trip count ceil(length / 128)): past its length no copy is issued and
+  nothing computed, so a row of length 0 runs only the in-chunk fold. A
+  flash-attention (m, l, acc) carry merges the steps; the current chunk's
+  uncommitted K/V (T = 1 for the decode scan, 1+k for the speculative
+  verify dispatch, 8 or 64 for a prefill chunk) folds in last with an
+  in-chunk causal mask. Operands reach the MXU in the dtype they arrive in
+  (bf16 x bf16 products are exact in f32); statistics, p and the
+  accumulator are f32. f16 never appears (Mosaic cannot lower f16 refs).
 
 Numerics: the kernel's blockwise online softmax is mathematically exact but
 not bit-identical to the one-shot XLA softmax; it is the TPU path
-(`use_pallas` engines / DLT_PAGED_KERNEL=1), with interpret mode on CPU for
+(`use_pallas` engines; `paged_kernel=True`), with interpret mode on CPU for
 parity tests (perf/paged_attn_bench.py gates max|Δ|)."""
 
 from __future__ import annotations
@@ -39,6 +46,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ..platform_env import interpret_requested
 
 _NEG = -1e30  # f32 mask value; exp(_NEG - max) == 0 exactly in f32
+_STEP_KEYS = 128  # keys a kernel step covers: one lane-full score tile
 
 
 def paged_gather_kv(kc, vc, layer_idx, tables, n_read: int):
@@ -64,62 +72,133 @@ def paged_gather_kv(kc, vc, layer_idx, tables, n_read: int):
     return grab(kl), grab(vl)
 
 
-def _kernel(li_ref, tbl_ref, len_ref, q_ref, kn_ref, vn_ref, kb_ref, vb_ref,
-            o_ref, m_ref, l_ref, acc_ref, *, bt, nb, t, g):
-    """Grid step (b, h, j): one kv head's queries against table block j.
+def pages_per_step(n_read: int, bt: int) -> int:
+    """Pool blocks one kernel step covers: as many as make 128 keys (8 at
+    bt 16), at most the n_read the window bucket holds, at least one."""
+    return max(1, min(n_read, _STEP_KEYS // bt))
 
-    Blocks: q (1, 1, t*g, hs) f32 | k_new/v_new (1, 1, t, hs) | kb/vb
-    (1, 1, 1, bt, hs) cache dtype | out (1, 1, t*g, hs) f32. Scratch: the
-    flash (m, l, acc) carry. li/tbl/len are scalar-prefetched (li and tbl
-    are consumed by the BlockSpec index_maps; len masks in-body)."""
+
+def visited_keys(length: int, n_read: int, bt: int) -> int:
+    """Keys of the pool the kernel visits for a row of committed `length`
+    in a window of `n_read` blocks: whole steps up to the one that holds
+    the row's last committed key, none past it, never more than the
+    window. Host integers (runtime/batch_engine.py counts with it)."""
+    step = pages_per_step(n_read, bt) * bt
+    return min(n_read * bt, -(-length // step) * step)
+
+
+def _kernel(li_ref, tbl_ref, len_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm,
+            o_ref, kbuf, vbuf, sem, m_ref, l_ref, *, bt, nb, pp, t, g):
+    """Grid step b: one row's queries, all hk kv heads, against the steps
+    of pp pool blocks that hold its committed keys.
+
+    Blocks: q (1, hk, t*g, hs) | k_new/v_new (1, hk, t, hs) | out
+    (1, hk, t*g, hs) f32, the flash accumulator until the last line. k_hbm/
+    v_hbm are the whole pools, left in HBM. Scratch: kbuf/vbuf (2, hk,
+    pp*bt, hs) double buffers in the pool dtype, sem (2, 2) DMA semaphores
+    (k|v, buffer), the flash (m, l) statistics (hk, t*g, 1). li/tbl/len are
+    scalar-prefetched."""
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    q = q_ref[0, 0]  # (t*g, hs) f32
-    scale = jnp.float32(1.0 / math.sqrt(q.shape[-1]))
+    hk, sk, hs = kbuf.shape[1:]
+    length = len_ref[b]
+    li = li_ref[0]
+    scale = jnp.float32(1.0 / math.sqrt(hs))
+    # steps that hold a committed key; a row of length 0 runs none
+    n_live = jnp.minimum((length + sk - 1) // sk, -(-nb // pp))
+    # the MXU takes q and K as they arrive: bf16 x bf16 with f32 accumulation
+    # gives the products an upcast would; any f32 operand makes the dot f32
+    dt = jnp.promote_types(q_ref.dtype, kbuf.dtype)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    def copies(j, buf):
+        """The step's pp pages, all heads of a page in one copy, landing at
+        16-row offsets of one (pp*bt, hs) tile a head. A page past the
+        window (nb not a multiple of pp) re-reads the window's last one;
+        its keys sit past every length and mask out."""
+        out = []
+        for i in range(pp):
+            page = tbl_ref[b * nb + jnp.minimum(j * pp + i, nb - 1)]
+            rows = pl.ds(i * bt, bt)
+            out.append(pltpu.make_async_copy(
+                k_hbm.at[li, page], kbuf.at[buf, :, rows, :], sem.at[0, buf]))
+            out.append(pltpu.make_async_copy(
+                v_hbm.at[li, page], vbuf.at[buf, :, rows, :], sem.at[1, buf]))
+        return out
 
-    kb = kb_ref[0, 0, 0].astype(jnp.float32)  # (bt, hs)
-    vb = vb_ref[0, 0, 0].astype(jnp.float32)
-    # virtual position of block row r is j*bt + r; rows at/after the row's
-    # committed length are uncommitted garbage (scratch writes, CoW slack)
-    pos = jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0) + j * bt
-    live = pos < len_ref[b]
-    vb = jnp.where(live, vb, 0.0)  # NaN guard: 0 * garbage stays finite
-    s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    s = jnp.where(live.reshape(1, bt), s, _NEG)  # (t*g, bt)
-    m_new = jnp.maximum(m_ref[:], jnp.max(s, axis=1, keepdims=True))
-    a = jnp.exp(m_ref[:] - m_new)
-    p = jnp.exp(s - m_new)
-    l_ref[:] = l_ref[:] * a + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[:] = acc_ref[:] * a + jax.lax.dot_general(
-        p, vb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[:] = m_new
+    @pl.when(n_live > 0)
+    def _first():
+        for c in copies(0, 0):
+            c.start()
 
-    @pl.when(j == nb - 1)
-    def _finalize():
-        # fold the current chunk's uncommitted K/V: query row r (= ti*g+gi)
-        # sits at position len+ti and may attend chunk key tau iff tau <= ti
-        kn = kn_ref[0, 0].astype(jnp.float32)  # (t, hs)
-        vn = vn_ref[0, 0].astype(jnp.float32)
+    m_ref[:] = jnp.full_like(m_ref, _NEG)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    o_ref[:] = jnp.zeros_like(o_ref)
+
+    def step(j, carry):
+        buf = j % 2
+
+        @pl.when(j + 1 < n_live)
+        def _next():
+            for c in copies(j + 1, 1 - buf):
+                c.start()
+
+        for c in copies(j, buf):
+            c.wait()
+        # keys at/after the row's committed length are uncommitted garbage
+        # (scratch writes, CoW slack): only the last live step has any. One
+        # iota a layout: reshaping the (sk, 1) mask to (1, sk) in the kernel
+        # cost Mosaic 27 MB of VMEM and a minute of compile at T=64
+        left = length - j * sk
+        live = jax.lax.broadcasted_iota(jnp.int32, (1, sk), 1) < left
+        live_v = jax.lax.broadcasted_iota(jnp.int32, (sk, 1), 0) < left
+
+        def head(h, c):
+            s = jax.lax.dot_general(
+                q_ref[0, h].astype(dt), kbuf[buf, h].astype(dt),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(live, s, _NEG)  # (t*g, sk)
+            # NaN guard: 0 * garbage stays finite
+            vb = jnp.where(live_v, vbuf[buf, h].astype(jnp.float32), 0.0)
+            m_old = m_ref[h]
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
+            a = jnp.exp(m_old - m_new)
+            p = jnp.exp(s - m_new)
+            l_ref[h] = l_ref[h] * a + jnp.sum(p, axis=1, keepdims=True)
+            o_ref[0, h] = o_ref[0, h] * a + jax.lax.dot_general(
+                p, vb, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+            return c
+
+        jax.lax.fori_loop(0, hk, head, 0)
+        return carry
+
+    jax.lax.fori_loop(0, n_live, step, 0)
+
+    # fold the current chunk's uncommitted K/V: query row r (= ti*g+gi)
+    # sits at position len+ti and may attend chunk key tau iff tau <= ti
+    ti = jax.lax.broadcasted_iota(jnp.int32, (t * g, t), 0) // g
+    tau = jax.lax.broadcasted_iota(jnp.int32, (t * g, t), 1)
+
+    def fold(h, c):
+        q = q_ref[0, h].astype(jnp.float32)
+        kn = kn_ref[0, h].astype(jnp.float32)  # (t, hs)
+        vn = vn_ref[0, h].astype(jnp.float32)
         s_new = jax.lax.dot_general(q, kn, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32) * scale
-        ti = jax.lax.broadcasted_iota(jnp.int32, (t * g, t), 0) // g
-        tau = jax.lax.broadcasted_iota(jnp.int32, (t * g, t), 1)
         s_new = jnp.where(tau <= ti, s_new, _NEG)
-        m_f = jnp.maximum(m_ref[:], jnp.max(s_new, axis=1, keepdims=True))
-        a_f = jnp.exp(m_ref[:] - m_f)
+        m_old = m_ref[h]
+        m_f = jnp.maximum(m_old, jnp.max(s_new, axis=1, keepdims=True))
+        a_f = jnp.exp(m_old - m_f)
         p_new = jnp.exp(s_new - m_f)
-        denom = l_ref[:] * a_f + jnp.sum(p_new, axis=1, keepdims=True)
-        out = acc_ref[:] * a_f + jax.lax.dot_general(
+        denom = l_ref[h] * a_f + jnp.sum(p_new, axis=1, keepdims=True)
+        out = o_ref[0, h] * a_f + jax.lax.dot_general(
             p_new, vn, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        o_ref[0, 0] = out / denom
+        o_ref[0, h] = out / denom
+        return c
+
+    jax.lax.fori_loop(0, hk, fold, 0)
 
 
 @functools.partial(jax.jit,
@@ -128,9 +207,11 @@ def paged_attention(q, kc, vc, k_new, v_new, tables, lengths, layer_idx, *,
                     n_read: int, interpret: bool | None = None):
     """Paged attention of T chunk queries per row against block-table KV.
 
-    q: (B, T, hq, hs) f32/bf16 — T = 1 (decode scan step) or 1+k (verify).
+    q: (B, T, hq, hs) in the activation dtype — T = 1 (decode scan step),
+        1+k (verify) or a prefill chunk. A bf16 q against a bf16 pool goes
+        to the MXU as bf16; any float32 operand makes the dot float32.
     kc/vc: (L, N, hk, bt, hs) FULL stacked pools (any dtype); only the
-        (layer, tables[b, j], h) blocks are ever moved on-chip.
+        (layer, tables[b, j]) blocks under the row's length are moved.
     k_new/v_new: (B, hk, T, hs) — the chunk's uncommitted K/V.
     tables: (B, W) i32 block table (first n_read entries are read).
     lengths: (B,) i32 committed length (row's start position).
@@ -147,34 +228,32 @@ def paged_attention(q, kc, vc, k_new, v_new, tables, lengths, layer_idx, *,
                                                          k_new.shape)
     g = hq // hk
     nb = n_read
-    qr = q.astype(jnp.float32).reshape(b, t, hk, g, hs)
+    pp = pages_per_step(nb, bt)
+    qr = q.reshape(b, t, hk, g, hs)
     qr = jnp.transpose(qr, (0, 2, 1, 3, 4)).reshape(b, hk, t * g, hs)
     tbl_flat = tables[:, :nb].reshape(-1).astype(jnp.int32)  # (B*nb,)
 
+    def row(bi, li, tb, ln):
+        return (bi, 0, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # (layer_idx_arr, tbl_flat, lengths)
-        grid=(b, hk, nb),
+        grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, 1, t * g, hs),
-                         lambda bi, h, j, li, tb, ln: (bi, h, 0, 0)),
-            pl.BlockSpec((1, 1, t, hs),
-                         lambda bi, h, j, li, tb, ln: (bi, h, 0, 0)),
-            pl.BlockSpec((1, 1, t, hs),
-                         lambda bi, h, j, li, tb, ln: (bi, h, 0, 0)),
-            pl.BlockSpec((1, 1, 1, bt, hs),
-                         lambda bi, h, j, li, tb, ln:
-                         (li[0], tb[bi * nb + j], h, 0, 0)),
-            pl.BlockSpec((1, 1, 1, bt, hs),
-                         lambda bi, h, j, li, tb, ln:
-                         (li[0], tb[bi * nb + j], h, 0, 0)),
+            pl.BlockSpec((1, hk, t * g, hs), row),
+            pl.BlockSpec((1, hk, t, hs), row),
+            pl.BlockSpec((1, hk, t, hs), row),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, t * g, hs),
-                               lambda bi, h, j, li, tb, ln: (bi, h, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((t * g, 1), jnp.float32),
-                        pltpu.VMEM((t * g, 1), jnp.float32),
-                        pltpu.VMEM((t * g, hs), jnp.float32)],
+        out_specs=pl.BlockSpec((1, hk, t * g, hs), row),
+        scratch_shapes=[pltpu.VMEM((2, hk, pp * bt, hs), kc.dtype),
+                        pltpu.VMEM((2, hk, pp * bt, hs), vc.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.VMEM((hk, t * g, 1), jnp.float32),
+                        pltpu.VMEM((hk, t * g, 1), jnp.float32)],
     )
-    body = functools.partial(_kernel, bt=bt, nb=nb, t=t, g=g)
+    body = functools.partial(_kernel, bt=bt, nb=nb, pp=pp, t=t, g=g)
     out = pl.pallas_call(
         body,
         grid_spec=grid_spec,

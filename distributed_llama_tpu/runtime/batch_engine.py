@@ -71,6 +71,7 @@ import numpy as np
 
 from ..models.spec import ModelSpec
 from ..obs import flight, metrics, reqctx, trace
+from ..ops.pallas_paged_attention import visited_keys
 from ..resilience import faults
 from ..resilience.errors import (DeadlineExceeded, EngineClosed,
                                  EngineDraining, EngineSaturated,
@@ -130,6 +131,13 @@ _ATTN_PAIRS_DISPATCHED = metrics.counter(
     "batch_attn_pairs_dispatched_total",
     "Query-key pairs the attention kernel was asked for: dispatched "
     "positions x the window bucket (the context length where unbucketed)")
+_ATTN_PAIRS_VISITED = metrics.counter(
+    "batch_attn_pairs_visited_total",
+    "Query-key pairs of the window the attention kernel computed: for every "
+    "row of the dispatch, parked ones too, T x the keys of the steps that "
+    "hold its committed length (ops/pallas_paged_attention.visited_keys; "
+    "the chunk's own T x T fold is in neither this nor the dispatched "
+    "count). Equal to the dispatched count where the kernel does not run")
 _ATTN_PAIRS_REAL = metrics.counter(
     "batch_attn_pairs_real_total",
     "Query-key pairs causal attention needs: for each real position, its "
@@ -1791,15 +1799,39 @@ class BatchEngine:
                 (eng._step_for(window), toks, start_pos, tables))
 
     def _count_work(self, positions: int, window: int,
-                    real: list[tuple[int, int]]) -> None:
+                    real: list[tuple[int, int]], starts: list[int],
+                    budget: list[int] | None = None) -> None:
         """One dispatch's useful-work counters: `positions` per row were
         dispatched against `window`; `real` lists (start, n) for each run of
-        n request tokens from position start (causal length start + i + 1)."""
+        n request tokens from position start (causal length start + i + 1).
+        `starts` is every row's committed length as the device was given it;
+        `budget` (a K-step scan only: `positions` steps of one token) the
+        steps each row advances, its length growing by one a step."""
         dispatched = self.slots_n * positions
         _POSITIONS_DISPATCHED.inc(dispatched)
         _ATTN_PAIRS_DISPATCHED.inc(dispatched * window)
+        if self._eng.paged_kernel:
+            bt = self._kv_bt
+            n_read = -(-window // bt)
+            if budget is None:
+                visited = positions * sum(
+                    visited_keys(st, n_read, bt) for st in starts)
+            else:
+                visited = sum(visited_keys(st + min(i, b), n_read, bt)
+                              for st, b in zip(starts, budget)
+                              for i in range(positions))
+        else:  # the gather path and the dense cache read the whole window
+            visited = dispatched * window
+        _ATTN_PAIRS_VISITED.inc(visited)
         _POSITIONS_REAL.inc(sum(n for _, n in real))
         _ATTN_PAIRS_REAL.inc(sum(n * p + n * (n + 1) // 2 for p, n in real))
+
+    def _count_inflight(self, fl: _InflightStep,
+                        real: list[tuple[int, int]]) -> None:
+        """_count_work of a scan (K steps of one token, a row's length
+        growing with its budget) or a verify block (one step of K)."""
+        self._count_work(fl.k, fl.window, real, fl.starts,
+                         fl.budget if fl.kind == "scan" else None)
 
     def _observe_gap(self) -> None:
         """Before a dispatch is issued from host state: the host time since
@@ -2579,7 +2611,7 @@ class BatchEngine:
             # rows neither prefilling nor riding spent this dispatch parked
             _PARKED_ROW_STEPS.inc(self.slots_n - 1 - len(riders))
             self._count_work(t, window, [(slot.pos, t)]
-                             + [(r.pos, 1) for r in riders])
+                             + [(r.pos, 1) for r in riders], starts)
             self.prefilled_tokens += t
             slot.pos += t
             slot.history.extend(piece)
@@ -2671,7 +2703,8 @@ class BatchEngine:
             dt_ms = (time.perf_counter() - t0) * 1000.0
             _DISP_SINGLE.observe(dt_ms / 1000.0)
             _PARKED_ROW_STEPS.inc(self.slots_n - len(active))
-            self._count_work(1, window, [(s.pos, 1) for s in active])
+            self._count_work(1, window, [(s.pos, 1) for s in active],
+                             starts)
             for slot in active:
                 slot.last_logits = logits[slot.index, -1]
                 slot.history.append(slot.last_token)
@@ -3251,9 +3284,9 @@ class BatchEngine:
         # rows that ride the scan without a live request park for all k steps;
         # rows with a short budget park for the steps past it
         _PARKED_ROW_STEPS.inc(self.slots_n * k - sum(fl.budget))
-        self._count_work(k, fl.window,
-                         [(fl.starts[slot.index], fl.budget[slot.index])
-                          for slot, _req in fl.rows])
+        self._count_inflight(fl, [(fl.starts[slot.index],
+                                   fl.budget[slot.index])
+                                  for slot, _req in fl.rows])
         status: dict[int, str] = {}
         accs: list[int] = []  # per-row accepted lengths (verify EMA input)
         for slot, req in fl.rows:
@@ -3445,7 +3478,7 @@ class BatchEngine:
         for discarded tokens)."""
         _PIPELINE_FLUSHES.labels(reason=reason).inc()
         _ROLLBACK_TOKENS.inc(sum(fl.budget))
-        self._count_work(fl.k, fl.window, [])  # ran on the device for nothing
+        self._count_inflight(fl, [])  # ran on the device for nothing
         for slot, req in fl.rows:
             flight.event(req.rid, "pipeline_flush", reason=reason,
                          tokens=fl.budget[slot.index])

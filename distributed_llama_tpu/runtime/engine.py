@@ -194,16 +194,6 @@ class Engine:
                   file=sys.stderr)
             kv_pool = None
         self.kv_pool = kv_pool
-        if paged_kernel is None:
-            import os
-
-            # tri-state: explicit env wins, unset defers to the use_pallas
-            # resolution below (TPU + quantized weights → kernel on)
-            env = os.environ.get("DLT_PAGED_KERNEL", "").lower()
-            if env in ("1", "true", "yes", "interp"):
-                paged_kernel = True
-            elif env in ("0", "false", "no"):
-                paged_kernel = False
         self._paged_kernel_req = paged_kernel  # resolved after use_pallas
         if pod:
             # multi-host job: mesh over EVERY chip in the job (the SPMD replacement
@@ -279,10 +269,10 @@ class Engine:
         self.fused_matmul = bool(fused_matmul) and bool(self.use_pallas)
         if self.fused_matmul:
             self.use_pallas = "fused"
-        # paged-attention kernel gate (ops/pallas_paged_attention.py):
-        # explicit request (kwarg / DLT_PAGED_KERNEL) wins; default follows
-        # use_pallas (TPU + quantized weights). CPU tests force it on via
-        # the env knob, under the suite's interpret request.
+        # paged-attention kernel gate (ops/pallas_paged_attention.py): the
+        # kwarg wins (the tests' interpret-mode engines); the default follows
+        # use_pallas (TPU + quantized weights), where the kernel beat the
+        # gather path end to end in the dense cell (PERF.md §6, PR 27).
         self.paged_kernel = bool(
             self._paged_kernel_req if self._paged_kernel_req is not None
             else self.use_pallas) and self.kv_pool is not None

@@ -19,7 +19,16 @@ CPU runs use interpret mode (correctness numbers only; GB/s on interpret
 mode measures the interpreter, and the JSON says so). On TPU, append the
 result row to a perf/r*_hw_results.jsonl-style artifact with --json.
 
-Usage: python perf/paged_attn_bench.py [--json out.json] [--iters N]
+`--cells` times the shapes the benchmark's cells dispatch instead (B 8, hk 8,
+g 4, hs 128, bt 16, bf16; T in {1, 8, 64} against the 512 and 1024 window
+buckets, rows' lengths read from a run of `chat-closed`; and against 2048
+and 4096 keys with illustrative long rows), the kernel beside
+the XLA gather path (an engine's `paged_kernel=False`), one call a layer of a
+scan inside one jit, with the bytes and FLOP a call needs over the chip's
+peaks. It uses only `paged_attention` and `paged_attention_xla`, so a copy
+of this file in an older checkout times that checkout's kernel.
+
+Usage: python perf/paged_attn_bench.py [--json out.json] [--iters N] [--cells]
 """
 
 from __future__ import annotations
@@ -146,14 +155,106 @@ def run(iters: int = 20, small: bool = False, interpret=None):
     return rows
 
 
+# TPU v5e peaks (Google Cloud documentation, "TPU v5e"), bf16
+V5E_HBM_BPS = 819e9
+V5E_FLOPS = 197e12
+# committed lengths of the 8 rows of a 64-token dispatch under chat-closed,
+# per window bucket: the median of each rank over the sorted rows of one
+# run's dispatches (54 and 65 of them; mistral-7b.chat-closed, seed
+# 3000000601, PR 27). T=1 and T=8 dispatches carry rows about a fifth longer.
+# The 2048 and 4096 rows are ILLUSTRATIVE, no cell dispatches them yet: the
+# same traffic with one or two long rows (a long-prompt cell, PERF.md §7).
+CELL_LENGTHS = {512: (0, 0, 101, 145, 186, 221, 275, 332),
+                1024: (0, 0, 93, 128, 184, 232, 287, 487),
+                2048: (0, 75, 190, 330, 520, 640, 900, 1500),
+                4096: (0, 75, 190, 330, 520, 640, 1200, 3000)}
+
+
+def bench_cell(t: int, window: int, *, layers=8, reps=4, seed=0,
+               pool_blocks=1280):
+    """One (T, window bucket) of the cells' dispatches: ms a call of the
+    kernel and of the XLA gather path, against what causal attention over
+    the rows' lengths needs. The pool has the dense cell's 1280 blocks: the
+    gather path's time grows with the pool it slices a layer from (0.25 ms
+    at T=64 and 1024 keys from 1025 blocks, 0.30 from 1280: PR 27)."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_llama_tpu.ops.pallas_paged_attention import (
+        paged_attention, paged_attention_xla)
+
+    B, hk, g, hs, bt = 8, 8, 4, 128, 16
+    nb = window // bt
+    n = max(B * nb + 1, pool_blocks)  # the dense cell's pool by default
+    rng = np.random.default_rng(seed)
+
+    def mk(shape):
+        return _mk(rng, shape).astype(jnp.bfloat16)
+
+    kc, vc = mk((layers, n, hk, bt, hs)), mk((layers, n, hk, bt, hs))
+    q = mk((B, t, hk * g, hs))
+    kn, vn = mk((B, hk, t, hs)), mk((B, hk, t, hs))
+    ids = np.arange(1, n)
+    rng.shuffle(ids)
+    tables = jnp.asarray(ids[:B * nb].reshape(B, nb).astype(np.int32))
+    lens = np.asarray(CELL_LENGTHS[window], np.int32)
+    lengths = jnp.asarray(lens)
+
+    def timed(attend):
+        @jax.jit
+        def run(*args):
+            def body(acc, li):
+                return acc + attend(*args, li, n_read=nb), None
+
+            zero = jnp.zeros((B, t, hk * g, hs), jnp.float32)
+            return jax.lax.scan(body, zero, jnp.tile(jnp.arange(layers),
+                                                     reps))[0]
+
+        args = (q, kc, vc, kn, vn, tables, lengths)
+        out = run(*args).block_until_ready()
+        t0 = time.perf_counter()
+        run(*args).block_until_ready()
+        return out, (time.perf_counter() - t0) / (layers * reps)
+
+    out_k, dt_k = timed(paged_attention)
+    out_x, dt_x = timed(paged_attention_xla)
+    # what the call needs: each row's committed keys and its chunk, K and V
+    # once; q in, the output out; two FLOP a multiply-add, QK and PV
+    keys = int(lens.sum()) + B * t
+    need_bytes = 2 * keys * hk * hs * 2 + B * t * hk * g * hs * (2 + 4)
+    need_flop = 4 * hk * g * hs * int(sum(
+        t * int(ln) + t * (t + 1) // 2 for ln in lens))
+    bytes_s, flop_s = need_bytes / V5E_HBM_BPS, need_flop / V5E_FLOPS
+    floor_s = max(bytes_s, flop_s)
+    return {
+        "T": t, "window": window, "lengths": lens.tolist(),
+        "kernel_ms": round(dt_k * 1e3, 4), "xla_ms": round(dt_x * 1e3, 4),
+        "need_bytes": need_bytes, "need_flop": need_flop,
+        "floor_ms": round(floor_s * 1e3, 5),
+        "bound": "bytes" if bytes_s >= flop_s else "flop",
+        "kernel_floor_share_pct": round(100 * floor_s / dt_k, 2),
+        "kernel_vs_xla_max_abs": float(jnp.max(jnp.abs(out_k - out_x))),
+        "out_max_abs": float(jnp.max(jnp.abs(out_x))),
+        "backend": jax.default_backend(),
+    }
+
+
+def run_cells(layers=8, reps=4):
+    return [bench_cell(t, w, layers=layers, reps=reps)
+            for t in (1, 8, 64) for w in CELL_LENGTHS]
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--json", metavar="OUT", default=None)
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--small", action="store_true",
                     help="tiny smoke geometry (the tier-1 gate's shapes)")
+    ap.add_argument("--cells", action="store_true",
+                    help="time the benchmark cells' dispatch shapes")
     args = ap.parse_args(argv)
-    rows = run(iters=args.iters, small=args.small)
+    rows = (run_cells() if args.cells
+            else run(iters=args.iters, small=args.small))
     out = {"bench": "paged_attention", "results": rows}
     print(json.dumps(out, indent=2))
     if args.json:

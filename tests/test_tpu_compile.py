@@ -101,12 +101,12 @@ def gated(m, n, k):
             {"act": "silu"})
 
 
-def paged(b, t, hq, hk, n_read):
+def paged(b, t, hq, hk, n_read, q=F32):
     """B rows x T chunk queries against a (L, N, hk, 16, 128) bf16 pool."""
     pool = ((LAYERS, 517, hk, 16, HS), BF16)
     new = ((b, hk, t, HS), BF16)
     return (paged_attention,
-            [((b, t, hq, HS), F32), pool, pool, new, new, ((b, 128), I32),
+            [((b, t, hq, HS), q), pool, pool, new, new, ((b, 256), I32),
              ((b,), I32), ((), I32)], {"n_read": n_read})
 
 
@@ -134,6 +134,19 @@ CASES = {
     "paged-b8-t1": paged(8, 1, 32, 8, 16),
     "paged-b8-t5": paged(8, 5, 32, 8, 16),
     "paged-tp4-b4-t1": paged(4, 1, 8, 2, 128),
+    # the benchmark cells' dispatches, bf16 q as the engine passes it: a
+    # 64-token chunk, an 8-token chunk and the decode step at the 1024, 512
+    # and 256 buckets; the tp=4 head shard; Grok-1's g = 6 (T*g = 6 rows);
+    # a window the 8-block step does not divide
+    "paged-b8-t64-w1024": paged(8, 64, 32, 8, 64, BF16),
+    "paged-b8-t8-w512": paged(8, 8, 32, 8, 32, BF16),
+    "paged-b8-t1-w1024": paged(8, 1, 32, 8, 64, BF16),
+    "paged-b8-t64-w256": paged(8, 64, 32, 8, 16, BF16),
+    "paged-tp4-b8-t64": paged(8, 64, 8, 2, 64, BF16),
+    "paged-g6-b8-t1": paged(8, 1, 48, 8, 64, BF16),
+    "paged-g6-b8-t64": paged(8, 64, 48, 8, 64, BF16),
+    "paged-b8-t5-w304": paged(8, 5, 32, 8, 19, BF16),
+    "paged-b8-t1-w4096": paged(8, 1, 32, 8, 256, BF16),
     # fused decode attention: one-block and full windows
     "decode-attn-w256": decode_attention(8, 256),
     "decode-attn-w2048": decode_attention(8, 2048),

@@ -294,6 +294,43 @@ def test_useful_work_counters_equal_the_hand_computed_values(scenario):
         "batch_attn_pairs_real_total": 1 + 2 + 3}
 
 
+def test_without_the_kernel_every_dispatched_pair_is_visited(scenario):
+    """The scenario's engine reads the pool through the XLA gather path (no
+    kernel off the chip): the whole window of every row, parked or not."""
+    for a, b in (("idle", "held"), ("held", "done")):
+        assert _delta(scenario[b], scenario[a],
+                      "batch_attn_pairs_visited_total") == _delta(
+            scenario[b], scenario[a], "batch_attn_pairs_dispatched_total") > 0
+
+
+@pytest.mark.parametrize("positions,starts,budget,want", [
+    # a 64-token chunk, 8 rows, the 1024 bucket: whole 128-key steps up to
+    # each row's committed length, nothing for a row at 0
+    (64, [0, 75, 190, 260, 330, 410, 520, 640], None,
+     64 * (0 + 128 + 256 + 384 + 384 + 512 + 640 + 640)),
+    # a decode step: every row under one step
+    (1, [5, 100, 128, 0, 0, 0, 0, 0], None, 3 * 128),
+    # a K=4 scan: row 0 crosses a step boundary after its first token, row
+    # 1 stops growing after its budget of 2, the parked rows stay at 0
+    (4, [128, 254, 0, 0, 0, 0, 0, 0], [4, 2, 0, 0, 0, 0, 0, 0],
+     (128 + 3 * 256) + (256 + 256 + 256 + 256)),
+])
+def test_visited_pairs_follow_each_rows_length(positions, starts, budget,
+                                               want):
+    import types
+
+    be = types.SimpleNamespace(slots_n=len(starts), _kv_bt=16,
+                               _eng=types.SimpleNamespace(paged_kernel=True))
+    names = ("batch_attn_pairs_visited_total",
+             "batch_attn_pairs_dispatched_total")
+    before = metrics.snapshot()
+    BatchEngine._count_work(be, positions, 1024, [], starts, budget)
+    after = metrics.snapshot()
+    visited, dispatched = (_delta(after, before, n) for n in names)
+    assert visited == want
+    assert dispatched == len(starts) * positions * 1024
+
+
 def test_dispatch_gap_is_observed_once_per_dispatch_after_the_first(scenario):
     def count(snap):
         return snap.get("batch_dispatch_gap_seconds", {"count": 0})["count"]
