@@ -7,5 +7,8 @@ profiler's trace.
 From the program it takes `BatchEngine` and its counters, nothing else.
 `BENCHMARK.json` at the root of the repo names the cells; a cell is found by
 name: `configs/<config>.json`, `traffic/<traffic>.json`,
-`layer_metrics/<metric>.py`. Adding one needs new files and new entries only.
+`layer_metrics/<metric>.py`, and a configuration's model family (the
+program's `ModelSpec` for it, its tensors, its float32 reference) by the
+file's `family`: `families/<family>.py`. Adding one needs new files and new
+entries only.
 """

@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -33,13 +34,28 @@ def metrics_of(bench: dict, group: str, workload: str) -> list[dict]:
             if "workloads" not in m or workload in m["workloads"]]
 
 
-def load_reader(name: str):
-    """The reader module of a per-layer metric: layer_metrics/<name>.py."""
-    path = os.path.join(HERE, "layer_metrics", name + ".py")
+def _load(kind: str, directory: str, name: str):
+    path = os.path.join(HERE, directory, name + ".py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"no {kind} {name!r}: looked for {path}")
     spec = importlib.util.spec_from_file_location(
-        "benchmark_layer_metric_" + name.replace(".", "_").replace("-", "_"),
+        f"benchmark_{directory}_" + name.replace(".", "_").replace("-", "_"),
         path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_reader(name: str):
+    """The reader module of a per-layer metric: layer_metrics/<name>.py."""
+    return _load("per-layer metric", "layer_metrics", name)
+
+
+@functools.lru_cache(maxsize=None)
+def load_family(name: str):
+    """The module of a model family, families/<name>.py, named by a
+    configuration file's `family`: `model_spec(cfg)`, `tensor_shapes(cfg)`
+    and `logits_at(cfg, weights, rows, at, precision, flip)` (the plain
+    float32 reference). One module a process: it keeps its compiled blocks."""
+    return _load("model family", "families", name)
 

@@ -99,7 +99,7 @@ def main(argv=None) -> None:
         t0 = time.perf_counter()
         passes = ("shallow", "full") if n < deep else ("shallow",)
         weights = W.make_weights(cfg, seed)
-        probes = probe.probe_tokens(cfg, seed, cfg["engine"]["slots"])
+        probes = probe.probe_tokens(cfg, seed)
         be = probe.build_engine(cfg, weights) if "full" in passes else None
         line = {"seed": seed, "trace": args.trace}
         refs = {name: {} for name in passes}

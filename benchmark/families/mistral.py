@@ -1,9 +1,14 @@
-"""The plain reference: the block graph in jax.numpy float32.
+"""The family of Mistral-7B and Mixtral-8x7B: which `ModelSpec` the program
+is given, which tensors are drawn, and the plain reference, the block graph
+in jax.numpy float32. One family for both configurations: the dense block,
+and the same block with routed experts where the file has
+`num_local_experts`. The harness reaches it through `cells.load_family` and
+calls `model_spec`, `tensor_shapes` and `logits_at`, nothing else.
 
-Written from the published equations of Mistral-7B and Mixtral-8x7B
-(pre-norm decoder blocks: RMSNorm, grouped-query attention with rotary
-embeddings, SwiGLU feed-forward; Mixtral replaces the feed-forward by a
-softmax router over all experts, the top `k` renormalized). No kernel, no
+The reference is written from the published equations of both (pre-norm
+decoder blocks: RMSNorm, grouped-query attention with rotary embeddings,
+SwiGLU feed-forward; Mixtral replaces the feed-forward by a softmax router
+over all experts, the top `k` renormalized). No kernel, no
 cache, no batching of requests beyond a plain leading axis; every position
 attends over the whole sequence before it under a causal mask. It shares no
 code with `models/forward.py`, and takes nothing the program has made: the
@@ -27,30 +32,52 @@ from __future__ import annotations
 
 import numpy as np
 
-from . import weights as W
+from benchmark import weights as W
 
 
-def _rounder(precision: str):
-    import jax.numpy as jnp
+def model_spec(cfg: dict):
+    """The program's ModelSpec for a configuration file's published keys."""
+    from distributed_llama_tpu.models.spec import (ArchType, HiddenAct,
+                                                   ModelSpec)
 
-    if precision == "float32":
-        return lambda x, w: (x, w)
-    if precision == "bfloat16":
-        t = jnp.bfloat16
-    elif precision == "fp8":
-        t = jnp.float8_e4m3fn
-    elif precision == "q80":
-        def q80(x):
-            g = x.reshape(*x.shape[:-1], x.shape[-1] // 32, 32)
-            amax = jnp.max(jnp.abs(g), axis=-1, keepdims=True)
-            d = (amax / 127.0).astype(jnp.float16).astype(jnp.float32)
-            q = jnp.round(g * jnp.where(amax > 0, 127.0 / amax, 0.0))
-            return (q * d).reshape(x.shape)
-        return lambda x, w: (q80(x), w) if w.ndim == 2 else (x, w)
+    moe = cfg.get("num_local_experts", 0)
+    assert cfg.get("hidden_act", "silu") == "silu", cfg.get("hidden_act")
+    return ModelSpec(
+        arch_type=ArchType.MIXTRAL if moe else ArchType.LLAMA,
+        dim=cfg["hidden_size"], hidden_dim=cfg["intermediate_size"],
+        n_layers=cfg["num_hidden_layers"], n_heads=cfg["num_attention_heads"],
+        n_kv_heads=cfg["num_key_value_heads"], vocab_size=cfg["vocab_size"],
+        seq_len=cfg["context"], n_experts=moe,
+        n_active_experts=cfg.get("num_experts_per_tok", 0),
+        hidden_act=HiddenAct.SILU, rope_theta=float(cfg["rope_theta"]),
+        norm_eps=cfg.get("rms_norm_eps", 1e-5)).resolved()
+
+
+def tensor_shapes(cfg: dict) -> dict[str, tuple[tuple[int, ...], bool]]:
+    """name -> (shape with the layer axis, drawn as Q40?), from the
+    published config's keys. Matrices are (out, in), blocks along `in`."""
+    d = cfg["hidden_size"]
+    h = cfg["intermediate_size"]
+    hs = cfg.get("head_dim") or d // cfg["num_attention_heads"]
+    kv = cfg["num_key_value_heads"] * hs
+    qd = cfg["num_attention_heads"] * hs
+    L = cfg["num_hidden_layers"]
+    e = cfg.get("num_local_experts", 0)
+    shapes = {"wq": ((L, qd, d), True), "wk": ((L, kv, d), True),
+              "wv": ((L, kv, d), True), "wo": ((L, d, qd), True)}
+    if e:
+        shapes.update({"router": ((L, e, d), True),
+                       "moe_up": ((L, e, h, d), True),
+                       "moe_gate": ((L, e, h, d), True),
+                       "moe_down": ((L, e, d, h), True)})
     else:
-        raise ValueError(f"unknown precision {precision!r}")
-    return lambda x, w: (x.astype(t).astype(jnp.float32),
-                         w.astype(t).astype(jnp.float32))
+        shapes.update({"w1": ((L, h, d), True), "w2": ((L, d, h), True),
+                       "w3": ((L, h, d), True)})
+    shapes.update({"rms_att": ((L, d), False), "rms_ffn": ((L, d), False),
+                   "rms_final": ((d,), False),
+                   "embedding": ((cfg["vocab_size"], d), False),
+                   "wcls": ((cfg["vocab_size"], d), True)})
+    return shapes
 
 
 def _rmsnorm(x, w, eps):
@@ -85,7 +112,7 @@ def _layer(cfg, x, lw, precision, flip):
     import jax
     import jax.numpy as jnp
 
-    rnd = _rounder(precision)
+    rnd = W.rounder(precision)
 
     def mm(a, qw):  # a @ W.T with W (out, in) dequantized here
         a, w = rnd(a, W.dequantize(*qw))
@@ -157,32 +184,35 @@ def _layer_fn(cfg: dict, precision: str):
     return _LAYER_FNS[key]
 
 
-def logits(cfg: dict, weights: dict, tokens, precision: str = "float32",
-           flip: tuple[int, int, int] | None = None):
-    """Every position's logits for `tokens` (B, T), (B, T, vocab) float32,
-    and every layer's router margins (layers, B, T), see `_layer`.
-    `cfg` holds the published keys; its `num_hidden_layers` must match the
-    layer axis of `weights`. flip = (layer, b, t) swaps one routed expert."""
+def logits_at(cfg: dict, weights: dict, rows, at, precision: str = "float32",
+              flip: tuple[int, int, int] | None = None):
+    """The logits at the positions `at[i]` of row `i` and nowhere else, and
+    each of those positions' smallest router margin over the layers (see
+    `_layer`), row after row: (sum of len(at[i]), vocab) float32 and (sum of
+    len(at[i]),). `rows` are token ids, each row of its own length; the depth
+    is the layer axis of `weights`. flip = (layer, row, t) swaps one routed
+    expert. The rows are padded to the longest with token 3 and run as one
+    batch: a margin is divided by the rms of a layer's router logits over the
+    whole padded batch, and which positions a check judges hangs on that."""
     import jax
     import jax.numpy as jnp
 
-    tokens = np.asarray(tokens)
-    block_names = [n for n in weights
-                   if n not in ("embedding", "rms_final", "wcls")]
-    n_layers = weights["rms_att"].shape[0]
+    t = max(len(r) for r in rows)
+    tokens = np.full((len(rows), t), 3, np.int64)
+    for i, r in enumerate(rows):
+        tokens[i, :len(r)] = r
+    b_at = np.concatenate([np.full(len(a), i) for i, a in enumerate(at)])
+    t_at = np.concatenate([np.asarray(a, np.int64) for a in at])
     with jax.default_matmul_precision("highest"):
         x = jnp.asarray(weights["embedding"])[tokens]
         layer_fn = _layer_fn(cfg, precision)
-        gaps = []
-        for i in range(n_layers):
-            lw = {n: (tuple(a[i] for a in weights[n])
-                      if isinstance(weights[n], tuple) else weights[n][i])
-                  for n in block_names}
-            x, gap = layer_fn(x, lw,
+        margin = np.full(len(b_at), np.inf, np.float32)
+        for i in range(W.depth(weights)):
+            x, gap = layer_fn(x, W.layer(weights, i),
                               (flip[1:] if flip and flip[0] == i else None))
-            gaps.append(np.asarray(gap))
-        x = _rmsnorm(x, jnp.asarray(weights["rms_final"]),
+            margin = np.minimum(margin, np.asarray(gap)[b_at, t_at])
+        x = _rmsnorm(x[b_at, t_at], jnp.asarray(weights["rms_final"]),
                      cfg.get("rms_norm_eps", 1e-5))
-        xr, wr = _rounder(precision)(x, W.dequantize(*weights["wcls"]))
-        out = jnp.einsum("bti,oi->bto", xr, wr)
-        return np.asarray(out, np.float32), np.stack(gaps)
+        xr, wr = W.rounder(precision)(x, W.dequantize(*weights["wcls"]))
+        out = jnp.einsum("ni,oi->no", xr, wr)
+        return np.asarray(out, np.float32), margin

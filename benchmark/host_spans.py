@@ -13,7 +13,8 @@ two device lines `trace_reduce` uses, into the same plain data:
 keeping of the host plane only events named `batch.*` (the fixture
 `fixtures/trace_host_small.json` has this shape). Two reductions:
 `dispatches` says what kind of dispatch each `jit_step` execution was,
-`gaps` says what the host was doing while the device idled. The two clocks
+`gaps` says what the host was doing while the device idled (and `gap_namer`
+names one idle gap so, for the run's `breakdown`). The two clocks
 are not quite one: `clock_offsets` finds the difference from the trace. A
 program that emits no such spans (the parent of the PR that added them)
 gives empty joins, and the readers return nothing.
@@ -26,9 +27,7 @@ import functools
 import glob
 import os
 import statistics
-import sys
 
-from benchmark import cells
 from benchmark.trace_reduce import (MODULES_LINE, OPS_LINE, _line, _union,
                                     device_planes, program_name)
 
@@ -84,18 +83,15 @@ def _window_trace(log_dir: str):
         return None
 
 
-def window_trace():
-    """The trace this process's traced window wrote: `run.py` gives the
-    profiler the directory .bench_trace/<--workload> of the checkout. Parsed
-    once for all readers; None (and one line) where there is none."""
-    argv = sys.argv
-    name = next((argv[i + 1] for i in range(len(argv) - 1)
-                 if argv[i] == "--workload"), None)
-    if name is None:
-        print("host_spans: no --workload on the command line, so no trace "
-              "directory", flush=True)
+def window_trace(trace_dir):
+    """The trace this process's traced window wrote under `trace_dir`, which
+    a reader has from its `ctx.trace_dir` (`run.py` gives the profiler
+    .bench_trace/<workload> of the checkout). Parsed once for the run and all
+    readers; None (and one line) where there is none."""
+    if not trace_dir:
+        print("host_spans: this run kept no trace directory", flush=True)
         return None
-    return _window_trace(os.path.join(cells.ROOT, ".bench_trace", name))
+    return _window_trace(trace_dir)
 
 
 def scheduler_spans(trace: dict) -> list:
@@ -218,6 +214,36 @@ def _innermost(spans: list) -> list[tuple[int, int, str]]:
     return segs
 
 
+def _split(segs: list, seg_starts: list, lo, hi) -> dict[str, float]:
+    """The part of [lo, hi) under each span name of the disjoint `segs`."""
+    parts: dict[str, float] = {}
+    i = max(bisect.bisect_right(seg_starts, lo) - 1, 0)
+    while i < len(segs) and segs[i][0] < hi:
+        part = min(segs[i][1], hi) - max(segs[i][0], lo)
+        if part > 0:
+            parts[segs[i][2]] = parts.get(segs[i][2], 0) + part
+        i += 1
+    return parts
+
+
+def gap_namer(trace: dict):
+    """For `trace_reduce.reduce`: (device plane index, start, end of an idle
+    gap on that plane's clock) -> the innermost scheduler span that covers
+    more of it than any other and than none does, as `gaps` splits it; else
+    None (a program without the spans: every gap)."""
+    segs = _innermost(scheduler_spans(trace))
+    seg_starts = [s[0] for s in segs]
+    offsets = clock_offsets(trace)
+
+    def name(plane: int, lo, hi):
+        off = offsets[plane][0]
+        parts = _split(segs, seg_starts, lo + off, hi + off)
+        best = max(parts, key=parts.get, default=None)
+        unnamed = hi - lo - sum(parts.values())
+        return best if best and parts[best] >= unnamed else None
+    return name
+
+
 def gaps(trace: dict) -> dict:
     """The device's idle time inside the window (the holes in the union of
     "XLA Ops", as `trace_reduce.reduce` takes them), moved onto the host's
@@ -239,13 +265,9 @@ def gaps(trace: dict) -> dict:
             total += hi - lo
             lo, hi = lo + offset, hi + offset
             named = 0
-            i = max(bisect.bisect_right(seg_starts, lo) - 1, 0)
-            while i < len(segs) and segs[i][0] < hi:
-                part = min(segs[i][1], hi) - max(segs[i][0], lo)
-                if part > 0:
-                    idle[segs[i][2]] = idle.get(segs[i][2], 0) + part
-                    named += part
-                i += 1
+            for span, part in _split(segs, seg_starts, lo, hi).items():
+                idle[span] = idle.get(span, 0) + part
+                named += part
             if hi - lo > named:
                 idle[UNNAMED] = idle.get(UNNAMED, 0) + hi - lo - named
     n = max(len(planes), 1)

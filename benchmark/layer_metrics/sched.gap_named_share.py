@@ -11,7 +11,7 @@ SOURCE = "device_trace"
 
 
 def read(ctx):
-    trace = host_spans.window_trace()
+    trace = host_spans.window_trace(ctx.trace_dir)
     if trace is None:
         return None
     g = host_spans.gaps(trace)

@@ -20,7 +20,7 @@ MIN_JOINED = 0.95
 
 
 def read(ctx):
-    trace = host_spans.window_trace()
+    trace = host_spans.window_trace(ctx.trace_dir)
     if trace is None:
         return None
     rows, joined = host_spans.dispatches(trace)

@@ -2,7 +2,12 @@
 
 A few seeded token sequences are teacher-forced through the SAME compiled
 programs the timed window uses, and every recorded position's logits are
-compared by value with the plain float32 reference (`reference.py`). It runs
+compared by value with the plain float32 reference, which the
+configuration's family brings (`families/<family>.py`: `logits_at`), as it
+brings the program's `ModelSpec` for the file. The sequences' lengths are
+the file's: `check.probe_prompts`, the prompt tokens of each row, one row a
+slot (72 to 79 in both cells: 64 + 8 + i x 1), and `check.probe_decode`,
+the tokens forced after each (16). It runs
 in set-up, after the programs are warm, before the profiler starts and before
 the first client; nothing from the window enters it.
 
@@ -31,7 +36,7 @@ them), so no slot goes unjudged. Where the engine's bf16 router and the
 float32 router pick another last expert, that position's logits move by
 about their own scale in a run that is correct. Such a position is taken
 out at the source and not by a low quantile: the reference knows its own
-router's margin at every position (`reference._layer`), and a position whose
+router's margin at every position (`logits_at` returns it), and a position whose
 margin is under the pass's `margin` is counted and printed, not judged. That
 only works where a flip stays at its own position, which is why the MoE
 configuration cuts the shallow pass into ONE-layer engines: behind a second
@@ -44,10 +49,7 @@ import math
 
 import numpy as np
 
-from . import reference, weights as W
-
-PROBE_PROMPT = 72   # row i has PROBE_PROMPT + i prompt tokens: 64 + 8 + i x 1
-PROBE_DECODE = 16   # tokens forced after the prompt; each yields one position
+from . import cells, weights as W
 
 
 class ForcedSampler:
@@ -66,24 +68,6 @@ class ForcedSampler:
         return self.forced[len(self.seen) - 1]
 
 
-def model_spec(cfg: dict):
-    """The program's ModelSpec for a configuration file's published keys."""
-    from distributed_llama_tpu.models.spec import (ArchType, HiddenAct,
-                                                   ModelSpec)
-
-    moe = cfg.get("num_local_experts", 0)
-    assert cfg.get("hidden_act", "silu") == "silu", cfg.get("hidden_act")
-    return ModelSpec(
-        arch_type=ArchType.MIXTRAL if moe else ArchType.LLAMA,
-        dim=cfg["hidden_size"], hidden_dim=cfg["intermediate_size"],
-        n_layers=cfg["num_hidden_layers"], n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], vocab_size=cfg["vocab_size"],
-        seq_len=cfg["context"], n_experts=moe,
-        n_active_experts=cfg.get("num_experts_per_tok", 0),
-        hidden_act=HiddenAct.SILU, rope_theta=float(cfg["rope_theta"]),
-        norm_eps=cfg.get("rms_norm_eps", 1e-5)).resolved()
-
-
 def build_engine(cfg: dict, weights: dict, **overrides):
     """`BatchEngine` on `weights` with the configuration's engine settings."""
     from distributed_llama_tpu.runtime.batch_engine import BatchEngine
@@ -95,25 +79,29 @@ def build_engine(cfg: dict, weights: dict, **overrides):
               kv_block_tokens=eng["kv_block_tokens"],
               kv_pool_blocks=eng["kv_pool_blocks"],
               prefix_cache=eng["prefix_cache"], tp=eng.get("tp", 1))
-    n_layers = weights["rms_att"].shape[0]
-    spec = model_spec({**cfg, "num_hidden_layers": n_layers})
+    spec = cells.load_family(cfg["family"]).model_spec(
+        {**cfg, "num_hidden_layers": W.depth(weights)})
     return BatchEngine(spec, W.to_program_params(weights), None, **kw)
 
 
-def probe_tokens(cfg: dict, seed: int, rows: int):
-    """Per row: (prompt, forced continuation), seeded and distinct."""
+def probe_tokens(cfg: dict, seed: int):
+    """Per row: (prompt, forced continuation), seeded and distinct, of the
+    lengths the configuration's `check` block states; one row a slot."""
+    prompts, decode = cfg["check"]["probe_prompts"], cfg["check"]["probe_decode"]
+    if len(prompts) != cfg["engine"]["slots"]:
+        raise ValueError(f"check.probe_prompts has {len(prompts)} rows for "
+                         f"{cfg['engine']['slots']} slots")
     rng = np.random.default_rng([seed, 0xC4EC])
     out = []
-    for i in range(rows):
-        n = PROBE_PROMPT + i
-        toks = rng.integers(3, cfg["vocab_size"], size=n + PROBE_DECODE)
+    for n in prompts:
+        toks = rng.integers(3, cfg["vocab_size"], size=n + decode)
         out.append((toks[:n].tolist(), toks[n:].tolist()))
     return out
 
 
 def drive(be, probes, timeout: float = 600.0):
     """Every probe through `be` at once (one per slot); per row the logits
-    the sampler was shown, (PROBE_DECODE, vocab). The k-th row of that is
+    the sampler was shown, (len(forced), vocab). The k-th row of that is
     the logits at position len(prompt) - 1 + k of prompt + forced."""
     saved = be.superstep
     be.superstep = 1  # host-sampled T=1 steps: see the module's docstring
@@ -142,20 +130,13 @@ def position_errors(got: np.ndarray, ref: np.ndarray) -> np.ndarray:
 def reference_rows(cfg: dict, weights: dict, probes, precision="float32",
                    flip=None):
     """The reference at the positions `drive` records, in `drive`'s order:
-    logits (rows x PROBE_DECODE, vocab) and each position's smallest router
-    margin over the layers of `weights` (rows x PROBE_DECODE)."""
-    seqs = [p + f[:-1] for p, f in probes]
-    t = max(len(s) for s in seqs)
-    toks = np.full((len(seqs), t), 3, np.int64)
-    for i, s in enumerate(seqs):
-        toks[i, :len(s)] = s
-    n_layers = weights["rms_att"].shape[0]
-    full, gaps = reference.logits({**cfg, "num_hidden_layers": n_layers},
-                                  weights, toks, precision, flip)
-    gaps = gaps.min(axis=0)
-    at = [slice(len(p) - 1, len(p) - 1 + len(f)) for p, f in probes]
-    return (np.concatenate([full[i, s] for i, s in enumerate(at)]),
-            np.concatenate([gaps[i, s] for i, s in enumerate(at)]))
+    logits (recorded positions, vocab) and each position's smallest router
+    margin over the layers of `weights` (recorded positions,). Each row goes
+    to the family at its own length, and only those positions are asked for."""
+    rows = [p + f[:-1] for p, f in probes]
+    at = [range(len(p) - 1, len(p) - 1 + len(f)) for p, f in probes]
+    return cells.load_family(cfg["family"]).logits_at(
+        cfg, weights, rows, at, precision, flip)
 
 
 def free_engine(be) -> None:
@@ -205,7 +186,8 @@ def pass_errors(cfg: dict, weights: dict, probes, spec: dict, got_of,
             refs[key] = reference_rows(cfg, w, probes)
         ref, gaps = refs[key]
         err.append(position_errors(got_of(cut, w, probes), ref))
-        row.append(np.repeat(np.arange(len(probes)), PROBE_DECODE))
+        row.append(np.repeat(np.arange(len(probes)),
+                             [len(f) for _, f in probes]))
         gap.append(gaps)
     return {"err": np.concatenate(err), "row": np.concatenate(row),
             "gap": np.concatenate(gap)}
@@ -243,7 +225,7 @@ def check(cfg: dict, weights: dict, seed: int, full_engine, log=print,
     """Both passes for `weights`. Prints each number compared beside its
     limit; returns the verdicts and `correct`."""
     got_of = got_of or engine_logits(cfg, full_engine)
-    probes = probe_tokens(cfg, seed, cfg["engine"]["slots"])
+    probes = probe_tokens(cfg, seed)
     out = {"correct": True}
     for name in ("shallow", "full"):
         spec = cfg["check"][name]

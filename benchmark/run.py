@@ -11,7 +11,7 @@ than "the logits disagree with the reference" is a message on stderr and a
 non-zero exit with no result line.
 
 `--rehearse 1` with `JAX_PLATFORMS=cpu` runs the same code on the CPU at
-the toy size of `configs/tiny-*.json`: the result is marked as a rehearsal
+the toy size the configuration's file names (`toy`): the result is marked as a rehearsal
 and its numbers carry the prefix `rehearsal.`, never a metric's name.
 """
 
@@ -178,9 +178,11 @@ def warm_shapes(be, cfg: dict, max_pos: int, vocab: int) -> None:
 class Ctx:
     """What a per-layer metric's reader is given."""
 
-    def __init__(self, config, trace, before, after, client):
+    def __init__(self, config, trace, before, after, client, trace_dir=None):
         self.config, self.trace = config, trace
         self.client = client  # what e2e.reduce made of the clients' records
+        # where the window's profile was written, for `host_spans.window_trace`
+        self.trace_dir = trace_dir
         self._before, self._after = before, after
         self._memo: dict = {}
 
@@ -232,20 +234,20 @@ def main(argv=None) -> None:
     compiles = Compiles()
     cache_dir = place_cache()
 
-    from benchmark import e2e, load, probe, traffic, trace_reduce
+    from benchmark import e2e, host_spans, load, probe, traffic, trace_reduce
     from benchmark import weights as W
 
     cfg = cells.load_config(cell["config"])
+    tr = traffic.load(cell["traffic"])
     if rehearse:
-        toy = "tiny-moe" if cfg.get("num_local_experts") else "tiny-dense"
+        toy, context = cfg["toy"], cfg["context"]
         cfg = cells.load_config(toy)
         log(f"REHEARSAL on the CPU at the toy size of configs/{toy}.json: "
             "no number below is a device number")
-    tr = traffic.load(cell["traffic"])
-    if rehearse:
-        # the toy context holds prompts an eighth as long; replies keep their
-        # lengths, so that decoding and not admission fills the toy window
-        scale = cfg["context"] / 4096.0
+        # the toy's context holds prompts shorter by as much as it is shorter
+        # than the configuration's own; replies keep their lengths, so that
+        # decoding and not admission fills the toy window
+        scale = cfg["context"] / context
         tr["prompt_tokens"] = {
             **tr["prompt_tokens"],
             "min": max(2, int(tr["prompt_tokens"]["min"] * scale)),
@@ -354,8 +356,11 @@ def main(argv=None) -> None:
             programs = {}
             for r in readers.values():
                 programs.update(getattr(r, "PROGRAMS", {}))
-            reduced = trace_reduce.reduce(
-                trace_reduce.from_xplane(trace_dir), programs)
+            trace = host_spans.window_trace(trace_dir)  # the readers' too
+            if trace is None:
+                fault(f"the traced window left no profile under {trace_dir}")
+            reduced = trace_reduce.reduce(trace, programs,
+                                          host_spans.gap_namer(trace))
             if reduced["missing"]:
                 fault("the trace has no event for the declared programs "
                       f"{reduced['missing']}")
@@ -363,7 +368,8 @@ def main(argv=None) -> None:
             dev["window_s"] = reduced["window_s"]
             breakdown = {"device_ops": reduced["device_ops"],
                          "idle_gaps": reduced["idle_gaps"][:10]}
-        ctx = Ctx(cfg, reduced, state["before"], state["after"], metrics)
+        ctx = Ctx(cfg, reduced, state["before"], state["after"], metrics,
+                  None if rehearse else trace_dir)
         for m in cells.metrics_of(bench, "per_layer", cell["name"]):
             if rehearse and readers[m["name"]].SOURCE == "device_trace":
                 continue

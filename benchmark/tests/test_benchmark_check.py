@@ -15,7 +15,7 @@ SEED = 2**31 + 11
 def setup(request):
     cfg = cells.load_config(request.param)
     weights = W.make_weights(cfg, SEED)
-    probes = probe.probe_tokens(cfg, SEED, cfg["engine"]["slots"])
+    probes = probe.probe_tokens(cfg, SEED)
     return cfg, weights, probes
 
 
@@ -23,7 +23,7 @@ def setup(request):
 def moe():
     cfg = cells.load_config("tiny-moe")
     weights = W.make_weights(cfg, SEED + 1)
-    probes = probe.probe_tokens(cfg, SEED + 1, cfg["engine"]["slots"])
+    probes = probe.probe_tokens(cfg, SEED + 1)
     pe0, _ = shallow(cfg, weights, probes,
                      lambda cut, w, pr: probe.reference_rows(cfg, w, pr)[0])
     return cfg, weights, probes, pe0
@@ -46,7 +46,7 @@ def test_the_reference_agrees_with_the_program_at_both_depths(setup):
         be.close()
     assert out["correct"]
     assert out["shallow"]["max"] < 1e-3 and out["full"]["max"] < 1e-3
-    n = cfg["engine"]["slots"] * probe.PROBE_DECODE
+    n = cfg["engine"]["slots"] * cfg["check"]["probe_decode"]
     assert out["full"]["positions"] == out["full"]["judged"] == n
     assert out["shallow"]["positions"] == n * len(cfg["check"]["shallow"]["cuts"])
     assert out["shallow"]["rows_judged"] == cfg["engine"]["slots"]
@@ -60,16 +60,16 @@ def test_scales_off_by_an_eighth_fail_the_shallow_pass(setup):
                           cut, W.layer_cut(bad, cut), pr))
     assert not res["within"]
     # every position of the cut that holds layer 0 is over the limit
-    first = probe.PROBE_DECODE * cfg["engine"]["slots"]
+    first = cfg["check"]["probe_decode"] * cfg["engine"]["slots"]
     assert (pe["err"][:first] > cfg["check"]["shallow"]["tol"]).all()
 
 
 def flipped(cfg, probes, pe, pick):
     """The reference with one routed expert swapped in layer 0, at the
     recorded position of the first cut that `pick` chooses by its margin."""
-    n = probe.PROBE_DECODE * len(probes)
-    i = int(pick(pe["gap"][:n]))
-    row, k = divmod(i, probe.PROBE_DECODE)
+    decode = cfg["check"]["probe_decode"]
+    i = int(pick(pe["gap"][:decode * len(probes)]))
+    row, k = divmod(i, decode)
     t = len(probes[row][0]) - 1 + k
 
     def got(cut, w, pr):

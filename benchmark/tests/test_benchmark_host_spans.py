@@ -1,11 +1,9 @@
 """`host_spans`: the join of device executions to the scheduler's spans and
 the attribution of idle time, on a hand-made trace, and the five readers that
-came with them."""
+came with them (their arithmetic: `reader_cases/`, `test_benchmark_readers`)."""
 
-import copy
 import json
 import os
-import sys
 
 import pytest
 
@@ -24,24 +22,24 @@ AFTER = {"batch_positions_real_total": 10.0 + 71.0,
          "batch_attn_pairs_dispatched_total": 50000.0 + 262144.0}
 
 
-@pytest.fixture()
-def trace():
-    with open(os.path.join(cells.HERE, "fixtures",
-                           "trace_host_small.json")) as f:
+def fixture(name):
+    with open(os.path.join(cells.HERE, "fixtures", name)) as f:
         return json.load(f)
 
 
 @pytest.fixture()
-def joined_trace(trace):
-    """The fixture without the execution that has no span."""
-    t = copy.deepcopy(trace)
-    for line in t["planes"][0]["lines"]:
-        del line["events"][0]
-    return t
+def trace():
+    return fixture("trace_host_small.json")
+
+
+@pytest.fixture()
+def joined_trace():
+    """The same without the execution that has no span."""
+    return fixture("trace_host_joined.json")
 
 
 def ctx_for(monkeypatch, trace, before=BEFORE, after=AFTER):
-    monkeypatch.setattr(host_spans, "window_trace", lambda: trace)
+    monkeypatch.setattr(host_spans, "window_trace", lambda trace_dir: trace)
     return Ctx(cells.load_config("mistral-7b"), None, before, after, {})
 
 
@@ -115,18 +113,6 @@ def test_a_trace_without_host_spans_gives_empty_joins(trace):
     assert g["dispatches"] == 0
 
 
-@pytest.mark.parametrize("name,want", [
-    ("step.mixed64_ms", 0.064),  # the one against 1024 keys, not the median
-    ("sched.gap_ms", 8000 / 1e6 / 3),
-    ("sched.gap_named_share", 100.0 * (1 - 2000 / 8000)),
-    ("sched.fill_share", 100.0 * 71 / 512),
-    ("kernel.attn_useful_share", 100.0 * 24000 / 262144),
-])
-def test_reader_arithmetic(monkeypatch, joined_trace, name, want):
-    ctx = ctx_for(monkeypatch, joined_trace)
-    assert ctx.metric(name) == pytest.approx(want)
-
-
 def test_too_few_executions_joined_is_no_reading(monkeypatch, trace, capsys):
     assert ctx_for(monkeypatch, trace).metric("step.mixed64_ms") is None
     assert "under 95 %" in capsys.readouterr().out
@@ -159,21 +145,33 @@ def test_every_new_entry_has_its_reader_and_says_what_it_is():
         assert "workloads" not in entry  # both cells report it
 
 
-def test_the_window_trace_is_found_by_the_workload_and_parsed_once(
-        monkeypatch, tmp_path):
+def test_the_window_trace_is_read_where_the_run_says_and_parsed_once(
+        monkeypatch, tmp_path, capsys):
     calls = []
-    monkeypatch.setattr(cells, "ROOT", str(tmp_path))
     monkeypatch.setattr(host_spans, "from_xplane",
                         lambda d: calls.append(d) or {"planes": []})
-    monkeypatch.setattr(sys, "argv", ["run.py", "--workload", "a.cell",
-                                      "--seed", "1"])
     host_spans._window_trace.cache_clear()
-    assert host_spans.window_trace() == {"planes": []}
-    assert host_spans.window_trace() == {"planes": []}
-    assert calls == [os.path.join(str(tmp_path), ".bench_trace", "a.cell")]
+    where = str(tmp_path / ".bench_trace" / "a.cell")
+    ctx = Ctx({}, None, {}, {}, {}, where)
+    assert host_spans.window_trace(ctx.trace_dir) == {"planes": []}
+    assert host_spans.window_trace(ctx.trace_dir) == {"planes": []}
+    assert calls == [where]
     host_spans._window_trace.cache_clear()
-    monkeypatch.setattr(sys, "argv", ["pytest"])
-    assert host_spans.window_trace() is None
+    # a run that traced nothing (a rehearsal, a test's bare Ctx)
+    assert host_spans.window_trace(Ctx({}, None, {}, {}, {}).trace_dir) is None
+    assert "no trace directory" in capsys.readouterr().out
+
+
+def test_an_idle_gap_is_named_by_the_span_that_covers_most_of_it(trace):
+    name = host_spans.gap_namer(trace)
+    # on the device's clock, 300 ns behind: [1100,1700) lies 500 under
+    # nothing and 100 under two spans; [65700,69700) is tiled, batch.build
+    # 1800 of it; [131700,135700) is half batch.fetch, half nothing
+    assert name(0, 1100, 1700) is None
+    assert name(0, 65700, 69700) == "batch.build"
+    assert name(0, 131700, 135700) == "batch.fetch"
+    trace["planes"] = trace["planes"][:1]  # a program without the spans
+    assert host_spans.gap_namer(trace)(0, 65700, 69700) is None
 
 
 def test_a_missing_trace_file_is_a_line_and_no_exception(tmp_path, capsys):

@@ -45,9 +45,10 @@ def test_every_config_file_states_its_cut(c):
     assert cfg["source"] == c["source"] and len(c["source"]) <= 200
     assert cfg["reduced"] == c["reduced"]
     assert not any(WIDTH.search(k) for k in c["reduced"])
-    for key in ("engine", "check", "assumed", "context"):
+    for key in ("engine", "check", "assumed", "context", "family", "toy"):
         assert key in cfg
-    assert set(cfg["check"]) == {"shallow", "full", "reason"}
+    assert set(cfg["check"]) == {"shallow", "full", "reason", "probe_prompts",
+                                 "probe_decode"}
     for name in ("shallow", "full"):
         spec = cfg["check"][name]
         assert {"quantile", "tol"} <= set(spec) <= {"quantile", "tol", "cuts",

@@ -43,7 +43,7 @@ def test_operations_are_ranked_without_the_enclosing_while(trace):
     assert list(ops)[0] == "%fusion.1"
 
 
-def test_idle_gaps_are_named_by_the_program_that_ran_next(trace):
+def test_idle_gaps_under_no_span_are_named_by_the_program_that_ran_next(trace):
     gaps = trace_reduce.reduce(trace, PROGRAMS)["idle_gaps"]
     assert gaps[0] == ["longest, before jit_plain", pytest.approx(2000 / 1e9)]
     named = dict((k, v) for k, v in gaps if k.startswith("total "))
@@ -51,6 +51,30 @@ def test_idle_gaps_are_named_by_the_program_that_ran_next(trace):
         "total before jit_plain": pytest.approx(2000 / 1e9),
         "total before jit_convert_element_type": pytest.approx(1000 / 1e9),
         "total before jit_step": pytest.approx(900 / 1e9)}
+
+
+def test_idle_gaps_are_named_by_the_scheduler_span_they_lie_under():
+    """Labels only: busy time, window and roles are what they are without
+    the spans."""
+    from benchmark import host_spans
+
+    with open(os.path.join(cells.HERE, "fixtures",
+                           "trace_host_small.json")) as f:
+        trace = json.load(f)
+    programs = {"step": "jit_step"}
+    plain = trace_reduce.reduce(trace, programs)
+    named = trace_reduce.reduce(trace, programs, host_spans.gap_namer(trace))
+    for key in ("busy_s", "window_s", "roles", "missing", "device_ops"):
+        assert named[key] == plain[key]
+    assert [g[1] for g in named["idle_gaps"][:3]] == [
+        g[1] for g in plain["idle_gaps"][:3]]
+    assert sorted(named["idle_gaps"]) == sorted([
+        ["longest, under batch.build", pytest.approx(4000 / 1e9)],
+        ["longest, under batch.fetch", pytest.approx(4000 / 1e9)],
+        ["longest, before jit_step", pytest.approx(600 / 1e9)],
+        ["total under batch.build", pytest.approx(4000 / 1e9)],
+        ["total under batch.fetch", pytest.approx(4000 / 1e9)],
+        ["total before jit_step", pytest.approx(600 / 1e9)]])
 
 
 def test_a_trace_without_a_device_plane_is_an_error(trace):

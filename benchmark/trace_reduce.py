@@ -2,22 +2,21 @@
 program, the operations that took most time, the longest idle gaps.
 
 A trace is held as plain data, {"planes": [{"name", "lines": [{"name",
-"events": [[name, start_ns, duration_ns], ...]}]}]}: `from_xplane` reads
-that out of the `.xplane.pb` the JAX profiler writes, and the fixture under
-`fixtures/` is the same shape, so the reduction is tested without a chip.
+"events": [[name, start_ns, duration_ns], ...]}]}]}: `host_spans.from_xplane`
+reads that out of the `.xplane.pb` the JAX profiler writes, and the fixture
+under `fixtures/` is the same shape, so the reduction is tested without a chip.
 
 On a TPU each chip is a plane "/device:TPU:<n>"; its line "XLA Modules"
 has one event per executed program (named "<jitted name>(<id>)"), its line
 "XLA Ops" one per operation (Pallas kernels by kernel name, XLA fusions by
 fusion name). Busy time is the union of the operations' intervals; an idle
-gap is a hole in that union, named by the program that ran next.
+gap is a hole in that union, named by the scheduler's span it lies under
+(`host_spans.gap_namer`) and, under none, by the program that ran next.
 """
 
 from __future__ import annotations
 
 import bisect
-import glob
-import os
 import re
 
 MODULES_LINE = "XLA Modules"
@@ -26,30 +25,6 @@ OPS_LINE = "XLA Ops"
 
 def device_planes(trace: dict) -> list[dict]:
     return [p for p in trace["planes"] if p["name"].startswith("/device:TPU:")]
-
-
-def from_xplane(log_dir: str) -> dict:
-    """The device planes of the newest trace under `log_dir`."""
-    from jax.profiler import ProfileData
-
-    paths = sorted(glob.glob(os.path.join(
-        log_dir, "plugins", "profile", "*", "*.xplane.pb")))
-    if not paths:
-        raise FileNotFoundError(f"no .xplane.pb under {log_dir}")
-    data = ProfileData.from_file(paths[-1])
-    planes = []
-    for plane in data.planes:
-        if not plane.name.startswith("/device:"):
-            continue
-        lines = []
-        for line in plane.lines:
-            if line.name not in (MODULES_LINE, OPS_LINE):
-                continue
-            lines.append({"name": line.name, "events": [
-                [ev.name, int(ev.start_ns), int(ev.duration_ns)]
-                for ev in line.events]})
-        planes.append({"name": plane.name, "lines": lines})
-    return {"planes": planes}
 
 
 def _line(plane: dict, name: str) -> list:
@@ -86,14 +61,16 @@ def op_name(event_name: str) -> str:
 CONTAINERS = ("%while", "%conditional", "%call")
 
 
-def reduce(trace: dict, programs: dict[str, str]) -> dict:
+def reduce(trace: dict, programs: dict[str, str], span_under=None) -> dict:
     """`programs` maps a role to the jitted name that plays it, e.g.
     {"decode": "jit_plain", "prefill": "jit_step"}. Returns, averaged over
     the device planes: busy_s, window_s (first operation's start to the last
     one's end), per role the device seconds and the count of executions, the
     ten operations with most time, the five longest idle gaps and the five
-    largest totals of idle time, each named by the program that ran next. A
-    role whose program has no event in the trace is listed in `missing`."""
+    largest totals of idle time. A gap is named "under <span>" where
+    `span_under(plane index, start, end)` (`host_spans.gap_namer`) finds a
+    span of the scheduler there, else by the program that ran next. A role
+    whose program has no event in the trace is listed in `missing`."""
     planes = device_planes(trace)
     if not planes:
         raise ValueError("the trace holds no TPU device plane")
@@ -102,7 +79,7 @@ def reduce(trace: dict, programs: dict[str, str]) -> dict:
     ops_time: dict[str, float] = {}
     gaps: list[tuple[float, str]] = []
     gap_totals: dict[str, float] = {}
-    for plane in planes:
+    for p, plane in enumerate(planes):
         ops = _line(plane, OPS_LINE)
         mods = sorted(_line(plane, MODULES_LINE), key=lambda e: e[1])
         if not ops:
@@ -121,12 +98,16 @@ def reduce(trace: dict, programs: dict[str, str]) -> dict:
                     per_role[role][1] += 1
         mod_starts = [m[1] for m in mods]
         for (_, e0), (s1, _) in zip(merged, merged[1:]):
-            # the program whose first operation ended the gap: the last
-            # module that had started by then
-            i = bisect.bisect_right(mod_starts, s1) - 1
-            nxt = program_name(mods[i][0]) if i >= 0 else "unknown"
-            inside = i >= 0 and mods[i][1] < e0
-            label = ("inside " if inside else "before ") + nxt
+            span = span_under(p, e0, s1) if span_under else None
+            if span:
+                label = "under " + span
+            else:
+                # the program whose first operation ended the gap: the last
+                # module that had started by then
+                i = bisect.bisect_right(mod_starts, s1) - 1
+                nxt = program_name(mods[i][0]) if i >= 0 else "unknown"
+                inside = i >= 0 and mods[i][1] < e0
+                label = ("inside " if inside else "before ") + nxt
             gaps.append(((s1 - e0) / 1e9, label))
             gap_totals[label] = gap_totals.get(label, 0.0) + (s1 - e0) / 1e9
     n = len(planes)

@@ -10,44 +10,24 @@ rank one plus noise, and bf16 rounding alone flips the sign of the logits
 (PERF.md, PR 21). Scales centre on 0.02 / 4.3, so weights have a standard
 deviation near 0.02. No checkpoint file is written or read.
 
-`dequantize` is the reference's own reading of those blocks; it shares no
-code with the program's `quants.py`.
+Which tensors a model has is its family's business
+(`families/<family>.py`: `tensor_shapes`). What every family means the same
+by is here: three tensors stand outside the block stack (`NOT_BLOCKS`) and
+every other one has the layer axis first; `dequantize` is the reference's
+own reading of the blocks, sharing no code with the program's `quants.py`;
+`rounder` is how every family's reference makes its controls.
 """
 
 from __future__ import annotations
 
 import numpy as np
 
+from benchmark import cells
+
 QK = 32
+NOT_BLOCKS = ("embedding", "rms_final", "wcls")
 _DELTA = 0.02 / 4.3
 _SEED_MOD = 2**31 - 1
-
-
-def tensor_shapes(cfg: dict) -> dict[str, tuple[tuple[int, ...], bool]]:
-    """name -> (shape with the layer axis, drawn as Q40?), from the
-    published config's keys. Matrices are (out, in), blocks along `in`."""
-    d = cfg["hidden_size"]
-    h = cfg["intermediate_size"]
-    hs = cfg.get("head_dim") or d // cfg["num_attention_heads"]
-    kv = cfg["num_key_value_heads"] * hs
-    qd = cfg["num_attention_heads"] * hs
-    L = cfg["num_hidden_layers"]
-    e = cfg.get("num_local_experts", 0)
-    shapes = {"wq": ((L, qd, d), True), "wk": ((L, kv, d), True),
-              "wv": ((L, kv, d), True), "wo": ((L, d, qd), True)}
-    if e:
-        shapes.update({"router": ((L, e, d), True),
-                       "moe_up": ((L, e, h, d), True),
-                       "moe_gate": ((L, e, h, d), True),
-                       "moe_down": ((L, e, d, h), True)})
-    else:
-        shapes.update({"w1": ((L, h, d), True), "w2": ((L, d, h), True),
-                       "w3": ((L, h, d), True)})
-    shapes.update({"rms_att": ((L, d), False), "rms_ffn": ((L, d), False),
-                   "rms_final": ((d,), False),
-                   "embedding": ((cfg["vocab_size"], d), False),
-                   "wcls": ((cfg["vocab_size"], d), True)})
-    return shapes
 
 
 def _seed_key(seed: int):
@@ -89,11 +69,24 @@ def make_weights(cfg: dict, seed: int) -> dict:
     would hand `BatchEngine`, and what the reference dequantizes."""
     import jax
 
-    shapes = tensor_shapes(cfg)
+    shapes = cells.load_family(cfg["family"]).tensor_shapes(cfg)
     drawn = jax.jit(lambda k: _draw(k, shapes))(_seed_key(seed))
     host = jax.tree.map(np.asarray, drawn)
     del drawn
     return host
+
+
+def depth(weights: dict) -> int:
+    """The layers in `weights`: the leading axis of any block tensor."""
+    t = next(t for n, t in weights.items() if n not in NOT_BLOCKS)
+    return (t[0] if isinstance(t, tuple) else t).shape[0]
+
+
+def layer(weights: dict, i: int) -> dict:
+    """Layer `i`'s tensors alone, without the layer axis: what a reference
+    that walks the block stack hands its block."""
+    return {n: (tuple(a[i] for a in t) if isinstance(t, tuple) else t[i])
+            for n, t in weights.items() if n not in NOT_BLOCKS}
 
 
 def layer_cut(weights: dict, layers: list[int]) -> dict:
@@ -101,7 +94,7 @@ def layer_cut(weights: dict, layers: list[int]) -> dict:
     idx = np.asarray(layers)
     out = {}
     for name, t in weights.items():
-        if name in ("embedding", "rms_final", "wcls"):
+        if name in NOT_BLOCKS:
             out[name] = t
         elif isinstance(t, tuple):
             out[name] = (t[0][idx], t[1][idx])
@@ -132,6 +125,34 @@ def dequantize(packed, scales):
     return out.reshape(*packed.shape[:-2], packed.shape[-2] * QK)
 
 
+def rounder(precision: str):
+    """How a reference makes a control: "float32" rounds nothing;
+    "bfloat16" and "fp8" round both operands of a matrix product to that
+    type first; "q80" rounds the activations before a weight matrix to int8
+    blocks of 32 with one scale, the program's own Q80. What a path in that
+    lower precision would compute."""
+    import jax.numpy as jnp
+
+    if precision == "float32":
+        return lambda x, w: (x, w)
+    if precision == "bfloat16":
+        t = jnp.bfloat16
+    elif precision == "fp8":
+        t = jnp.float8_e4m3fn
+    elif precision == "q80":
+        def q80(x):
+            g = x.reshape(*x.shape[:-1], x.shape[-1] // 32, 32)
+            amax = jnp.max(jnp.abs(g), axis=-1, keepdims=True)
+            d = (amax / 127.0).astype(jnp.float16).astype(jnp.float32)
+            q = jnp.round(g * jnp.where(amax > 0, 127.0 / amax, 0.0))
+            return (q * d).reshape(x.shape)
+        return lambda x, w: (q80(x), w) if w.ndim == 2 else (x, w)
+    else:
+        raise ValueError(f"unknown precision {precision!r}")
+    return lambda x, w: (x.astype(t).astype(jnp.float32),
+                         w.astype(t).astype(jnp.float32))
+
+
 def to_program_params(weights: dict):
     """The drawn tensors in the structure the program's loader returns
     (`formats.mfile.load_model`): QTensor leaves in the planar Q40 layout."""
@@ -141,7 +162,6 @@ def to_program_params(weights: dict):
         return QTensor(FloatType.Q40, t[0], t[1])
 
     blocks = {n: (q(t) if isinstance(t, tuple) else t)
-              for n, t in weights.items()
-              if n not in ("embedding", "rms_final", "wcls")}
+              for n, t in weights.items() if n not in NOT_BLOCKS}
     return {"embedding": weights["embedding"], "blocks": blocks,
             "rms_final": weights["rms_final"], "wcls": q(weights["wcls"])}
