@@ -188,8 +188,7 @@ def synth_q40(key, shape, layout: str):
     return QTensor(FloatType.Q40, packed, scales)
 
 
-def synth_params(spec: ModelSpec, layout: str, fuse: bool = True, tp: int = 1,
-                 keep_gate_pair: bool = False):
+def synth_params(spec: ModelSpec, layout: str, fuse: bool = True, tp: int = 1):
     from distributed_llama_tpu.models.params import _FUSE_GROUPS
     from distributed_llama_tpu.parallel.sharding import effective_kv_heads
 
@@ -205,11 +204,6 @@ def synth_params(spec: ModelSpec, layout: str, fuse: bool = True, tp: int = 1,
             if not all(n in shapes for n in members):
                 continue
             if fused_name == "wqkv" and effective_kv_heads(spec, tp) != spec.n_kv_heads:
-                continue
-            if fused_name == "w13" and keep_gate_pair:
-                # the gated-epilogue kernel fuses across the SEPARATE w1/w3
-                # pair (prepare_for_pallas keep_gate_pair) — merging them
-                # here would shape-gate it off in every --fused-matmul run
                 continue
             lead = shapes[members[0]][0][:-2]  # MoE stacks carry an E axis
             rows = sum(shapes[n][0][-2] for n in members)
@@ -2722,16 +2716,6 @@ def main():
                          "R positions + host cold store, decode timed with "
                          "~128 cold positions (runtime/paged_cache.py). "
                          "Documents the capacity valve's real per-token cost")
-    ap.add_argument("--prefill-kernel", action="store_true",
-                    help="fused dequant-matmul for M>1 (ops/pallas_q4_mm.py): "
-                         "weights stream once at 4-bit density instead of the "
-                         "XLA dequant path — opt-in until the hardware A/B lands")
-    ap.add_argument("--fused-matmul", action="store_true",
-                    help="batched fused-epilogue kernels (use_pallas='fused', "
-                         "the Engine --fused-matmul / DLT_FUSED_MATMUL lever): "
-                         "everything --prefill-kernel enables plus the "
-                         "residual-add and silu·mul gate-pair epilogues; keeps "
-                         "w1/w3 as the separate pair the gated kernel needs")
     args = ap.parse_args()
 
     if args.trace:
@@ -2966,18 +2950,15 @@ def main():
 
     # the configuration asked for, and no other: a kernel that fails to lower
     # is an error with a non-zero exit code, not a weaker rung with a number
-    kern = "fused" if args.fused_matmul else args.prefill_kernel
     state.update(
         layout=layout, cache_write=args.cache_write, prologue=args.prologue,
-        # the kernel-policy rung: "fused" > "all" > True; off the chip the
-        # XLA path (False)
-        use_pallas=(("fused" if kern == "fused" else "all") if kern else True)
-        if on_tpu else False)
+        # every kernel whose gate admits the shape on the chip, XLA off it
+        use_pallas=on_tpu)
 
     def build():
         params = shard_params(
-            synth_params(spec, layout, fuse=not args.no_fuse, tp=args.tp,
-                         keep_gate_pair=args.fused_matmul), mesh, spec)
+            synth_params(spec, layout, fuse=not args.no_fuse, tp=args.tp),
+            mesh, spec)
         state.update(params=params, wbytes=decode_stream_bytes(params, spec))
         kc, vc = init_sharded_kv_cache(spec, mesh, batch=max(args.batch, 1),
                                        dtype=dtype)
@@ -3035,7 +3016,7 @@ def main():
         # report the EFFECTIVE kernel engagement: the dequant-matmul gates
         # per-weight (q4_mm_supported), so an A/B record must say how much of
         # the weight bytes actually took the kernel, not what was requested
-        if state["use_pallas"] in ("all", "fused"):  # ops/matmul FUSED_POLICIES
+        if state["use_pallas"]:
             from distributed_llama_tpu.ops.pallas_q4_mm import q4_mm_supported
 
             eng_b = tot_b = 0
@@ -3116,9 +3097,9 @@ def main():
             "layout": state["layout"], "cache_write": state["cache_write"],
             "attn_window": window or spec.seq_len, "steps": args.steps,
             # which lowering each traced dispatch shape ACTUALLY took
-            # (ops/matmul.py selection registry) — an A/B record claiming
-            # --fused-matmul must show q4_mm/q4_gated_mm here, not a silent
-            # xla-fallback (docs/SERVING.md "Kernel selection")
+            # (ops/matmul.py selection registry): a record of the kernels
+            # must show q4_mm here, not a silent xla-fallback
+            # (docs/SERVING.md "Kernel selection")
             "kernel_policy": str(state["use_pallas"]),
             "kernels": sorted(set(kernel_selections().values())),
         }
@@ -3195,10 +3176,7 @@ def main():
         "attn_window": window or spec.seq_len,
         "device_loop": args.device_loop,
         "steps": args.steps,
-        # report the EFFECTIVE matvec-group fusion: --fused-matmul keeps the
-        # w1/w3 pair split for the gated-epilogue kernel, so a record saying
-        # "fused" there would claim a merge that never happened
-        "fused": bool(not args.no_fuse and not args.fused_matmul),
+        "fused": not args.no_fuse,  # the merged wqkv / w13 matvec groups
         # report the EFFECTIVE prologue state: forward() re-gates it off for
         # non-pallas runs and unsupported dims, and an A/B record claiming a
         # lever that never engaged would corrupt the comparison

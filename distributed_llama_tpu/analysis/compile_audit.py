@@ -116,13 +116,12 @@ class CompileAudit:
             return f",paged={bt}" if bt else ""
 
         def _kern(kw):
-            # kernel-policy dimension (ops/matmul.py): the STRING policies
-            # ("all", "fused") change which programs lower — a fused engine's
-            # T buckets are distinct lowerings from the XLA ones and must be
-            # pinned separately. Boolean policies add nothing, so every
-            # pre-existing pinned key is unchanged.
-            up = kw.get("use_pallas")
-            return f",kernel={up}" if isinstance(up, str) else ""
+            # kernel-policy dimension (ops/matmul.py): an engine with the
+            # Pallas kernels on lowers other programs from the same shapes
+            # (and holds its weights in the kernels' layout), so its buckets
+            # pin under their own keys. Kernels off adds nothing, so every
+            # kernel-off key is the one it always was.
+            return ",kernel=1" if kw.get("use_pallas") else ""
 
         def _mask(kw):
             # grammar-constrained variants (constrain/, docs/SERVING.md
@@ -308,19 +307,17 @@ def run_scenario(keep_engine: bool = False):
             rd3.wait(60)
         finally:
             eng2.close()
-        # phase 8 — fused-kernel policy (ops/pallas_q4_mm.py, --fused-matmul):
-        # a THIRD engine with use_pallas upgraded to "fused", so every program
-        # the batched serving path builds under the kernel policy pins under
-        # its own `kernel=fused` key (the string policy is part of the jit
-        # cache key by construction: different lowerings, different programs).
+        # phase 8 — the Pallas kernels on (ops/pallas_q4_mm.py): a THIRD
+        # engine with use_pallas=True, so every program the batched serving
+        # path builds with the kernels pins under its own `kernel=1` key.
         # The co-resident self-drafter makes verify engagement deterministic
         # for ANY prompt (n-gram proposals on a fresh engine are not) and
-        # pins the drafter's own fused draft_scan/draft_step buckets; the
-        # reachable T buckets must stay inside the kernel-off t=2/3/5 set —
-        # a fused key minting a rogue T bucket fails the gate by name.
+        # pins the drafter's own draft_scan/draft_step buckets; the
+        # reachable T buckets must stay inside the kernel-off t=2/3/5 set:
+        # a kernel key minting a rogue T bucket fails the gate by name.
         eng3 = BatchEngine(spec, params, slots=2, superstep=4, pipeline=True,
                            speculative=4, spec_min_draft=1, tp=1,
-                           use_pallas=True, fused_matmul=True,
+                           use_pallas=True,
                            draft_model=(spec, params))
         try:
             rf1 = eng3.submit(p1, 12, Sampler(V))

@@ -337,12 +337,11 @@ def _stats_payload(state: "ApiState") -> dict:
     # kernel-selection provenance (ops/matmul.py registry, docs/SERVING.md
     # "Kernel selection"): the resolved matmul policy and which lowering each
     # traced dispatch shape actually took — the human-readable view of
-    # matmul_kernel_selected_total, and the place a silent xla-fallback under
-    # --fused-matmul becomes visible without grepping Prometheus
+    # matmul_kernel_selected_total, and the place a silent xla-fallback
+    # becomes visible without grepping Prometheus
     inner = be._eng if be is not None else state.engine
     if inner is not None:
         out["kernels"] = {"policy": str(inner.use_pallas),
-                          "fused_matmul": bool(inner.fused_matmul),
                           # paged-attention reader of the device block pool:
                           # the Pallas kernel, or the XLA gather
                           "paged_kernel": bool(inner.paged_kernel),
@@ -1535,8 +1534,7 @@ def main(argv=None) -> None:
             slo_tpot_interactive=args.slo_tpot,
             tp=args.tp, dp=args.dp, pod=args.pod,
             cache_write=args.cache_write, moe_sharding=args.moe_sharding,
-            fused_prologue=args.prologue, prefill_kernel=args.prefill_kernel,
-            fused_matmul=args.fused_matmul, **policy_kwargs(args),
+            fused_prologue=args.prologue, **policy_kwargs(args),
             compress_collectives=args.buffer_float_type == "q80" and (args.tp or 1) > 1)
         engine = None
         sampler = make_sampler(args, batch_engine.spec)

@@ -86,17 +86,6 @@ def build_parser(include_mode: bool = True) -> argparse.ArgumentParser:
                    help="fused rmsnorm+quantize prologue kernels on the decode "
                         "path (ops/pallas_prologue.py; also DLT_PROLOGUE=1) — "
                         "opt-in until the hardware A/B lands")
-    p.add_argument("--prefill-kernel", action="store_true", default=None,
-                   help="fused 4-bit dequant-matmul for prefill and batched "
-                        "decode (ops/pallas_q4_mm.py; also DLT_PREFILL_KERNEL=1) "
-                        "— opt-in until the hardware A/B lands")
-    p.add_argument("--fused-matmul", action="store_true", default=None,
-                   help="batched fused-epilogue kernels on the decode/verify/"
-                        "drafter hot paths: --prefill-kernel plus residual-add "
-                        "and silu·mul gate-pair epilogues, greedy-identical with "
-                        "automatic XLA fallback (also DLT_FUSED_MATMUL=1; "
-                        "docs/SERVING.md \"Kernel selection\") — opt-in until "
-                        "the hardware A/B lands")
     p.add_argument("--pipeline", action=argparse.BooleanOptionalAction,
                    default=True,
                    help="pipelined super-steps for batched serving (--batch "
@@ -293,8 +282,7 @@ def make_engine(args) -> Engine:
         **policy_kwargs(args),
         compress_collectives=args.buffer_float_type == "q80" and (args.tp or 1) > 1,
         cache_write=args.cache_write, moe_sharding=args.moe_sharding,
-        fused_prologue=args.prologue, prefill_kernel=args.prefill_kernel,
-        fused_matmul=args.fused_matmul,
+        fused_prologue=args.prologue,
         kv_cache_storage=args.kv_cache_storage,
         kv_cache_resident=args.kv_cache_resident,
         kv_cache_dir=args.kv_cache_dir,

@@ -12,14 +12,13 @@ so an argmax would compare noise.
 
     default        kernels on (a TPU's default policy) against use_pallas=False:
                    the Q40 weights stay quantized, XLA dequantizes. This is the
-                   Pallas matvec and fused decode attention against plain XLA.
+                   Pallas matvec and fused decode attention (the T=1 steps) and
+                   the fused dequant-matmul (the chunk) against plain XLA.
     --tp N         tp=1 on one device against tp=N over N devices, both at the
                    default policy; also reports the sharded step's collective
                    counts and what each device holds.
     --policy NAME  the default kernels against one opt-in kernel family
-                   (prologue, prefill-kernel, fused-matmul: the entry points'
-                   flags of the same names). The chunk is where the M>1
-                   kernels engage.
+                   (prologue: the entry points' flag of the same name).
 
 Each pair is compared twice, because one depth cannot do both jobs:
 
@@ -73,9 +72,7 @@ import numpy as np
 BOUNDS = {"shallow": (0.05, 0.035), "full": (0.25, 0.2)}
 CHUNK = 64  # the largest prefill bucket (runtime/engine.py PREFILL_CHUNKS)
 CANARY = ("wo", 1.125)  # matrix, and the factor on its first layer's scales
-POLICIES = {"prologue": dict(fused_prologue=True),
-            "prefill-kernel": dict(prefill_kernel=True),
-            "fused-matmul": dict(fused_matmul=True)}
+POLICIES = {"prologue": dict(fused_prologue=True)}
 
 
 def shallow_cut(spec, params):

@@ -126,9 +126,8 @@ class ModelDrafter:
         # K-step scan burst between verifies: K+1 pending)
         self.catchup_cap = 2 * self.k_cap + 1
         self.dtype = dtype if dtype is not None else jnp.float32
-        # the POLICY passes through unchanged ("fused"/"all" string-valued):
-        # the drafter's k-step scan is the ideal fusion victim — a small
-        # model whose entire weight stream is the per-step cost
+        # the kernel policy passes through unchanged: the drafter's k-step
+        # scan is a small model whose whole weight stream is the step's cost
         has_quant = any(
             getattr(t, "ftype", None) in (FloatType.Q40, FloatType.Q80)
             for t in params["blocks"].values())
@@ -138,7 +137,7 @@ class ModelDrafter:
         if self.use_pallas:
             params = prepare_for_pallas(
                 params, tp, moe_sharding=self.moe_sharding, spec=spec,
-                keep_gate_pair=self.use_pallas == "fused")
+                mesh=mesh)
         self.params = shard_params(params, mesh, spec,
                                    moe_sharding=self.moe_sharding)
         self.rope = RopeTables.create(spec)

@@ -37,7 +37,7 @@ import jax.numpy as jnp
 
 from ..ops.attention import gqa_attention, update_kv_cache
 from ..ops.kernels import ACTS, rmsnorm
-from ..ops.matmul import qmatmul, qmatmul_gated, qmatmul_q80
+from ..ops.matmul import LayerOf, qmatmul, qmatmul_q80, reads_the_stack
 from ..ops.ring_attention import (commit_kv_rows_sharded, ring_attention,
                                   update_kv_cache_sharded)
 from ..ops.rope import RopeTables, apply_rope
@@ -111,10 +111,8 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
     skips the 128-key steps wholly behind it.
 
     residual: optional (B, T, dim) block input; when given the returned
-    attn_out is ALREADY residual-joined (residual + wo-projection). Under
-    use_pallas == "fused" with a single-chip wo (axis_name None) the add runs
-    inside the dequant-matmul kernel's accumulator; otherwise it is the same
-    `residual + y` the caller used to compute — callers must not re-add.
+    attn_out is ALREADY residual-joined (residual + wo-projection, after the
+    TP merge): callers must not re-add.
 
     Head counts in bp may be TP-local slices; the cache sequence axis may be sp-sharded
     (ring attention). The cache WRITE discipline depends on the caller: in-scan mode
@@ -174,13 +172,6 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
             y = qmatmul_q80(aq, asx, bp["wo"], use_pallas=use_pallas,
                             out_dtype=x.dtype)
         else:
-            if (residual is not None and axis_name is None
-                    and use_pallas == "fused"):
-                # single-chip wo: fold the residual into the kernel's f32
-                # accumulator init (TP partials must psum BEFORE the join,
-                # so the fusion is gated to axis_name is None)
-                return qmatmul(att, bp["wo"], use_pallas=use_pallas,
-                               residual=residual)
             y = qmatmul(att, bp["wo"], use_pallas=use_pallas)
         y = _maybe_psum(y, axis_name, compress)
         return y if residual is None else residual + y
@@ -407,11 +398,7 @@ def _dense_ffn(x, bp, spec: ModelSpec, axis_name, use_pallas, compress,
     kernel — the forward()-level gate only validated spec.dim.
 
     residual: optional (B, T, dim); when given the return value is ALREADY
-    residual + ffn(x) — under use_pallas == "fused" with a single-chip w2
-    the add fuses into the down-projection kernel's accumulator init, and the
-    gate/up pair (when kept separate — Engine fused_matmul skips the w13
-    merge) lowers to ONE silu·mul-epilogue kernel whose (B·T, hidden)
-    intermediates never touch HBM. Callers must not re-add."""
+    residual + ffn(x), joined after the TP merge. Callers must not re-add."""
     act = _act(spec)
     if prologue:
         from ..ops.pallas_prologue import (prologue_supported, quantize_q80_row,
@@ -436,20 +423,13 @@ def _dense_ffn(x, bp, spec: ModelSpec, axis_name, use_pallas, compress,
             h = _gated_split(qmatmul(xb, bp["w13"], use_pallas=use_pallas),
                              act, gate_first=True)
         else:
-            h = qmatmul_gated(xb, bp["w1"], bp["w3"], act=act,
-                              act_name=_act_name(spec),
-                              use_pallas=use_pallas)
+            h = (act(qmatmul(xb, bp["w1"], use_pallas=use_pallas))
+                 * qmatmul(xb, bp["w3"], use_pallas=use_pallas))
     if prologue and prologue_supported(h.shape[-1]):
         hq, hsx = quantize_q80_row(h)
         out = qmatmul_q80(hq, hsx, bp["w2"], use_pallas=use_pallas,
                           out_dtype=x.dtype)
     else:
-        if (residual is not None and axis_name is None
-                and use_pallas == "fused"):
-            # single-chip w2: residual folds into the kernel accumulator
-            # (TP partials must psum before the join — see _attention)
-            return qmatmul(h.astype(x.dtype), bp["w2"], use_pallas=use_pallas,
-                           residual=residual)
         out = qmatmul(h.astype(x.dtype), bp["w2"], use_pallas=use_pallas)
     out = _maybe_psum(out, axis_name, compress)
     return out if residual is None else residual + out
@@ -609,8 +589,11 @@ def _moe_ffn(xb, bp, spec: ModelSpec, axis_name, use_pallas, compress,
 def _block(carry, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions,
            axis_name, sp_axis_name, sp_size, use_pallas, compress, window,
            kc_ro=None, vc_ro=None, prologue=False, paged_cold=None,
-           block_tables=None, block_tokens=0, paged_kernel=False):
-    """One transformer block as a scan step. Two cache disciplines:
+           block_tables=None, block_tokens=0, paged_kernel=False,
+           stacks=None):
+    """One transformer block as a scan step. `stacks`: the weights that stay
+    whole over the scan (forward() below), named into `bp` as `LayerOf`
+    this layer's index. Two cache disciplines:
 
     - in-scan (kc_ro is None): caches travel in the carry and are updated in place
       per layer — carry (x, kc, vc).
@@ -631,16 +614,18 @@ def _block(carry, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions
     # layers of more than one kind (forward() below)
     bp, layer_idx, *kind = layer
     rope_on, swa = kind if kind else (None, None)
+    if stacks:
+        bp = {**bp, **{n: LayerOf(w, layer_idx) for n, w in stacks.items()}}
     router_logits = None
     if spec.is_moe and spec.router_input == RouterInput.BLOCK_INPUT:
         # the router reads the residual stream as the block receives it,
         # before the first norm; its logits wait for the expert layer
         with jax.named_scope("moe_route"):
             router_logits = _router_logits(x, bp)
-    # grok residual-joins the NORMALIZED attention output, so the projection
-    # kernel cannot fold the raw residual there; every other arch hands the
-    # block input down as the fusable residual (contract: attn_out returns
-    # already joined when residual is given)
+    # grok residual-joins the NORMALIZED attention output, so the raw
+    # residual cannot join inside _attention there; every other arch hands
+    # the block input down (contract: attn_out returns already joined when
+    # residual is given)
     res_attn = None if spec.arch_type == ArchType.GROK1 else x
     with jax.named_scope("attn"):
         attn_out, kvout = _attention(
@@ -765,6 +750,11 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
         fused_prologue = (use_pallas and t == 1 and tokens.shape[0] == 1
                           and start_pos.ndim == 0
                           and prologue_supported(spec.dim))
+    # the weights the fused dequant-matmul reads stay out of the scan's
+    # sliced operands: it takes its blocks from the whole stack at the
+    # layer's index, and a slice would be a copy of the layer's weights
+    stacks = {n: w for n, w in params["blocks"].items()
+              if reads_the_stack(w, tokens.shape[0] * t, use_pallas)}
     block_fn = functools.partial(_block, spec=spec, rope=rope, start_pos=start_pos,
                                  positions=positions, axis_name=axis_name,
                                  sp_axis_name=sp_axis_name, sp_size=sp_size,
@@ -775,9 +765,10 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
                                  prologue=fused_prologue, paged_cold=paged_cold,
                                  block_tables=block_tables,
                                  block_tokens=block_tokens,
-                                 paged_kernel=paged_kernel)
+                                 paged_kernel=paged_kernel, stacks=stacks)
     layer_ids = jnp.arange(spec.n_layers, dtype=jnp.int32)
-    xs = (params["blocks"], layer_ids)
+    xs = ({n: w for n, w in params["blocks"].items() if n not in stacks},
+          layer_ids)
     if spec.rope_layers or spec.sliding_window:
         # layers of more than one kind in ONE scan: the kind is data
         xs += (jnp.asarray(spec.layer_rope(), jnp.int32),

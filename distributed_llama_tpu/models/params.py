@@ -20,11 +20,13 @@ blocks along `in`. Tensor inventory mirrors the `.m` file exactly
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable
 
 import numpy as np
 
-from ..quants import FloatType, QTensor
+from ..obs import metrics
+from ..quants import QK, FloatType, QTensor
 from .spec import ArchType, ModelSpec
 
 Params = dict[str, Any]
@@ -109,16 +111,41 @@ def _kernel_convertible(t: QTensor, stacked: bool) -> bool:
     return len(shape) == 2 and q8_shape_supported(*shape)
 
 
+_REPACKED = metrics.counter(
+    "weights_repacked_bytes_total",
+    "bytes of weights brought into a kernel layout, by where the shuffle "
+    "ran: on the device (Q40 to split-plane nibbles, prepare_for_pallas) or "
+    "on the host (int8 planes, and the NumPy/native oracle of the former)",
+    labelnames=("where",))
+
+
+def _i4p_groups(t: QTensor, tp: int, col_sharded: bool) -> int | None:
+    """The column groups a Q40 weight's split-plane pack needs, None where the
+    i4p alignment does not hold (the weight then takes int8 planes)."""
+    if t.ftype != FloatType.Q40:
+        return None
+    k = t.shape[-1]
+    groups = tp if col_sharded else 1
+    return groups if k % groups == 0 and (k // groups) % 64 == 0 else None
+
+
 def _decode_layout(t: QTensor, tp: int, col_sharded: bool) -> QTensor:
-    """Pick the decode-kernel layout for one weight: Q40 -> i4p split-plane nibbles
-    (0.5625 B/weight, the file's own density — pallas_q4 kernel); Q80 -> int8 planes
-    (pallas_q8 kernel). Falls back to i8 when the i4p alignment constraints don't hold."""
-    if t.ftype == FloatType.Q40:
-        k = t.shape[-1]
-        groups = tp if col_sharded else 1
-        if k % groups == 0 and (k // groups) % 64 == 0:
-            return t.to_i4p_layout(col_groups=groups)
-    return t.to_i8_layout()
+    """One weight's decode-kernel layout ON THE HOST: Q40 -> i4p split-plane
+    nibbles (0.5625 B/weight, the file's own density); Q80, and Q40 whose
+    alignment i4p cannot take -> int8 planes (pallas_q8 kernel). The oracle
+    of `_repack_on_device`, and the path of the int8 planes."""
+    groups = _i4p_groups(t, tp, col_sharded)
+    out = (t.to_i8_layout() if groups is None
+           else t.to_i4p_layout(col_groups=groups))
+    _REPACKED.labels(where="host").inc(out.nbytes())
+    return out
+
+
+def _split_rows(a, groups: int, row_axis: int):
+    rows = a.shape[row_axis]
+    assert rows % groups == 0, (a.shape, groups)
+    return a.reshape(*a.shape[:row_axis], groups, rows // groups,
+                     *a.shape[row_axis + 1:])
 
 
 def _concat_rows_grouped(tensors: list[QTensor], tp: int, row_axis: int = 1
@@ -137,13 +164,8 @@ def _concat_rows_grouped(tensors: list[QTensor], tp: int, row_axis: int = 1
 
     def cat(leaves):
         # planar leaf shapes: data (..., out, nb, 16|32), scales (..., out, nb)
-        parts = []
-        for a in leaves:
-            rows = a.shape[row_axis]
-            assert rows % tp == 0, (a.shape, tp)
-            parts.append(a.reshape(*a.shape[:row_axis], tp, rows // tp,
-                                   *a.shape[row_axis + 1:]))
-        out = np.concatenate(parts, axis=row_axis + 1)
+        out = np.concatenate([_split_rows(a, tp, row_axis) for a in leaves],
+                             axis=row_axis + 1)
         return out.reshape(*out.shape[:row_axis], -1,
                            *out.shape[row_axis + 2:])
 
@@ -164,49 +186,130 @@ _FUSE_GROUPS = {"wqkv": ("wq", "wk", "wv"), "w13": ("w1", "w3"),
 _FUSE_ROW_AXIS = {"wqkv": 1, "w13": 1, "moe_gu": 2}
 
 
-def fuse_matvec_groups(blocks: Params, spec: ModelSpec | None, tp: int,
-                       moe_sharding: str = "slice",
-                       skip: tuple[str, ...] = ()) -> Params:
-    """Replace wq/wk/wv -> wqkv, w1/w3 -> w13, moe_up/moe_gate -> moe_gu with
-    row-concatenated (TP-group interleaved) planar tensors where safe. Skipped
-    per group when a member is not kernel-convertible or (QKV) when KV-head
-    replication is active (tp > n_kv_heads expands wk/wv rows at shard time,
-    after this runs). Under expert sharding the MoE stacks shard by whole
-    experts, not rows, so moe_gu concatenates with NO group interleave."""
+def _fuse_plan(blocks: Params, spec: ModelSpec | None, tp: int,
+               moe_sharding: str) -> dict[str, int]:
+    """The merged groups that are safe to build: {fused name: the TP-group
+    count its members' rows interleave with}."""
     from ..parallel.sharding import effective_kv_heads
 
-    out = dict(blocks)
+    plan = {}
     for fused, members in _FUSE_GROUPS.items():
-        if fused in skip:
-            continue
         ts = [blocks.get(m) for m in members]
         if not all(isinstance(t, QTensor) and t.layout == "planar"
                    and _kernel_convertible(t, stacked=True) for t in ts):
             continue
         if len({t.ftype for t in ts}) != 1:
             continue
-        row_axis = _FUSE_ROW_AXIS[fused]
         groups = tp
         if fused == "moe_gu" and moe_sharding == "expert":
             groups = 1  # whole experts shard over tp; rows stay unsharded
-        if any(t.shape[row_axis] % groups for t in ts):
+        if any(t.shape[_FUSE_ROW_AXIS[fused]] % groups for t in ts):
             continue
         if fused == "wqkv":
             if spec is None and tp > 1:
                 continue  # can't rule out KV replication without the spec
             if spec is not None and effective_kv_heads(spec, tp) != spec.n_kv_heads:
                 continue  # replication rewrites wk/wv rows later; keep separate
-        out[fused] = _concat_rows_grouped(ts, groups, row_axis=row_axis)
+        plan[fused] = groups
+    return plan
+
+
+def fuse_matvec_groups(blocks: Params, spec: ModelSpec | None, tp: int,
+                       moe_sharding: str = "slice") -> Params:
+    """Replace wq/wk/wv -> wqkv, w1/w3 -> w13, moe_up/moe_gate -> moe_gu with
+    row-concatenated (TP-group interleaved) planar tensors where safe, on the
+    host. Skipped per group when a member is not kernel-convertible or (QKV)
+    when KV-head replication is active (tp > n_kv_heads expands wk/wv rows at
+    shard time, after this runs). Under expert sharding the MoE stacks shard by
+    whole experts, not rows, so moe_gu concatenates with NO group interleave."""
+    out = dict(blocks)
+    for fused, groups in _fuse_plan(blocks, spec, tp, moe_sharding).items():
+        members = _FUSE_GROUPS[fused]
+        out[fused] = _concat_rows_grouped([blocks[m] for m in members], groups,
+                                          row_axis=_FUSE_ROW_AXIS[fused])
         for m in members:
             del out[m]
     return out
 
 
+@functools.lru_cache(maxsize=None)
+def _repack_step(row_groups: int, col_groups: int, row_axis: int, sharding):
+    """The jitted program that repacks ONE slice of the leading (layer) axis
+    and writes it into the donated result: rows of the members concatenated
+    per TP group (`_concat_rows_grouped`), nibbles re-paired within each
+    column group (`quants.jnp_to_i4p`), f16 scales to their bit patterns."""
+    import jax
+
+    from ..quants import jnp_to_i4p
+
+    def step(data, scales, i, member_data, member_scales):
+        def cat(leaves):
+            if len(leaves) == 1:
+                return leaves[0]
+            out = jax.numpy.concatenate(
+                [_split_rows(a, row_groups, row_axis) for a in leaves],
+                axis=row_axis + 1)
+            return out.reshape(*out.shape[:row_axis], -1,
+                               *out.shape[row_axis + 2:])
+
+        d, s = jnp_to_i4p(cat(member_data), cat(member_scales), col_groups)
+        return (jax.lax.dynamic_update_index_in_dim(data, d, i, 0),
+                jax.lax.dynamic_update_index_in_dim(scales, s, i, 0))
+
+    return jax.jit(step, donate_argnums=(0, 1),
+                   out_shardings=None if sharding is None
+                   else (sharding, sharding))
+
+
+def _repack_on_device(members: list[QTensor], row_groups: int,
+                      col_groups: int, sharding=None) -> QTensor:
+    """Planar Q40 tensors (NumPy from a loader, a memory map, or arrays on a
+    device), stacked over a leading axis -> ONE i4p QTensor on the device:
+    the members' rows concatenated per TP group, split-plane packed within
+    `col_groups` column groups. Bit for bit `_concat_rows_grouped` followed by
+    `QTensor.to_i4p_layout(col_groups)`, which stay as the tests' oracle.
+
+    The leading axis is walked: a slice of each member goes up exactly as it
+    lies in the host's memory (no reshape there: a loader's or the device
+    draw's NumPy array need not be C-contiguous, and flattening such a stack
+    is a copy of all of it, 4 s of the dense cell's 7), is shuffled on the
+    device, lands in the donated result and is dropped, so the device holds
+    the result and one slice's tensors, never a second stack. `sharding` (of
+    the result, a NamedSharding) shards the slices the same way less the
+    leading axis, so the shuffle stays shard-local and the result is where
+    shard_params puts it."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    datas = [t.data for t in members]  # (n, ..., out, nb, 16)
+    scales = [t.scales for t in members]  # (n, ..., out, nb)
+    n = datas[0].shape[0]
+    row_axis = scales[0].ndim - 3  # of a slice: (..., out, nb)
+    rows = sum(s.shape[-2] for s in scales)
+    lead = scales[0].shape[:-2]
+    nb = scales[0].shape[-1]
+    slice_sh = None
+    if sharding is not None:
+        slice_sh = NamedSharding(sharding.mesh,
+                                 PartitionSpec(*sharding.spec[1:]))
+    out_d = jnp.zeros((*lead, rows, nb * (QK // 2)), jnp.uint8,
+                      device=sharding)
+    out_s = jnp.zeros((*lead, rows, nb), jnp.int16, device=sharding)
+    step = _repack_step(row_groups, col_groups, row_axis, sharding)
+    for i in range(n):
+        up = [[jax.device_put(a[i], slice_sh) for a in leaves]
+              for leaves in (datas, scales)]
+        out_d, out_s = step(out_d, out_s, np.int32(i), *up)
+    _REPACKED.labels(where="device").inc(out_d.nbytes + out_s.nbytes)
+    return QTensor(FloatType.Q40, out_d, out_s, layout="i4p",
+                   groups=col_groups, row_groups=row_groups)
+
+
 def prepare_for_pallas(params: Params, tp: int = 1,
                        moe_sharding: str = "slice",
                        spec: ModelSpec | None = None,
-                       fuse: bool = True,
-                       keep_gate_pair: bool = False) -> Params:
+                       fuse: bool = True, mesh=None) -> Params:
     """Repack the dense matmul weights into the Pallas decode-kernel layouts
     (i4p packed nibbles for Q40, int8 planes for Q80). Row/col TP slices stay
     32-block-aligned; col-sharded tensors are packed per TP column group so each
@@ -214,33 +317,71 @@ def prepare_for_pallas(params: Params, tp: int = 1,
     whole experts, so their in-axes are NOT column-sliced and pack with groups=1.
 
     fuse=True additionally merges the QKV and gate/up matvec groups into single
-    row-concatenated tensors (fuse_matvec_groups) so decode launches one kernel
-    per group instead of one per tensor. keep_gate_pair=True exempts w1/w3
-    from that merge: the batched gate-pair kernel (ops/pallas_q4_mm.py
-    q4_gated_matmul, Engine fused_matmul) fuses the silu·mul epilogue across
-    the SEPARATE pair, which beats the merged-launch win for M>1."""
+    row-concatenated tensors (`_fuse_plan`) so decode launches one kernel per
+    group instead of one per tensor.
+
+    Q40 goes to the device as the loader left it and is shuffled there
+    (`_repack_on_device`): the result's leaves are device arrays, sharded as
+    `shard_params` would place them when `mesh` is given, on the default
+    device otherwise. The int8 planes are made on the host."""
     import os
+
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from ..parallel.sharding import param_pspecs
 
     out: Params = {"embedding": params["embedding"], "blocks": {},
                    "rms_final": params["rms_final"]}
     fuse = fuse and not os.environ.get("DLT_NO_FUSE")  # field kill-switch
-    blocks = (fuse_matvec_groups(params["blocks"], spec, tp,
-                                 moe_sharding=moe_sharding,
-                                 skip=("w13",) if keep_gate_pair else ())
-              if fuse else params["blocks"])
+    blocks = params["blocks"]
+    plan = _fuse_plan(blocks, spec, tp, moe_sharding) if fuse else {}
+    merged = {m: f for f in plan for m in _FUSE_GROUPS[f]}
+    work: dict[str, tuple[list[QTensor], int]] = {}  # name -> members, row groups
     for name, t in blocks.items():
-        if ((name in _DENSE_MATMULS or name in _FUSE_GROUPS)
-                and _kernel_convertible(t, stacked=True)):
+        if name in merged:
+            work.setdefault(merged[name], (
+                [blocks[m] for m in _FUSE_GROUPS[merged[name]]],
+                plan[merged[name]]))
+        else:
+            work[name] = ([t], getattr(t, "row_groups", 1))
+    pspecs = param_pspecs({"blocks": work}, moe_sharding)
+
+    def convert(members, row_groups, col_sharded, pspec, row_axis=1):
+        t = members[0]
+        if not all(_kernel_convertible(m, stacked=True)
+                   and m.layout == "planar" for m in members):
+            return t
+        col_groups = _i4p_groups(t, tp, col_sharded)
+        if col_groups is None:  # int8 planes: the host's
+            if len(members) > 1:
+                t = _concat_rows_grouped(members, row_groups, row_axis)
+            return _decode_layout(t, tp, col_sharded)
+        sharding = None if mesh is None else NamedSharding(mesh, pspec)
+        return _repack_on_device(members, row_groups, col_groups, sharding)
+
+    for name, (members, row_groups) in work.items():
+        if name in _DENSE_MATMULS or name in _FUSE_GROUPS:
             col = name in _COL_SHARDED and not (
                 moe_sharding == "expert" and name.startswith("moe_"))
-            out["blocks"][name] = _decode_layout(t, tp, col)
+            out["blocks"][name] = convert(
+                members, row_groups, col, pspecs["blocks"][name],
+                _FUSE_ROW_AXIS.get(name, 1))
         else:
-            out["blocks"][name] = t
+            out["blocks"][name] = members[0]
     wcls = params["wcls"]
     if _kernel_convertible(wcls, stacked=False):
-        wcls = _decode_layout(wcls, tp, col_sharded=False)
+        # the head is a stack of one
+        wcls = _map_leaves(
+            convert([_map_leaves(wcls, lambda a: a[None])], 1, False,
+                    PartitionSpec(None, *pspecs["wcls"])), lambda a: a[0])
     out["wcls"] = wcls
     return out
+
+
+def _map_leaves(t: QTensor, fn) -> QTensor:
+    """`fn` over a QTensor's leaves, its layout fields kept."""
+    return QTensor(t.ftype, fn(t.data), fn(t.scales), layout=t.layout,
+                   groups=t.groups, row_groups=t.row_groups)
 
 
 def expected_experts_touched(n_experts: int, k: int, rows: int) -> float:

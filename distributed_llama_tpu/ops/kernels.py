@@ -38,7 +38,7 @@ def relu(x: jnp.ndarray) -> jnp.ndarray:
 
 
 # gate activations by the static names the fused epilogues take
-# (ops/pallas_q4_mm.py _act_f32 matches these formulas in f32)
+# (ops/pallas_moe_grouped.py _act_f32 matches these formulas in f32)
 ACTS = {"silu": silu, "gelu_tanh": gelu_tanh, "relu": relu}
 
 

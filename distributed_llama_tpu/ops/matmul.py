@@ -4,12 +4,12 @@ The reference dispatches on (weightType x inputType) pairs of hand-written SIMD 
 (src/funcs.cpp:424-465, hot path matmulQ40vQ80 at funcs.cpp:287-396). Here there is ONE
 logical op: y[..., out] = x[..., in] · W[out, in], where W may be dense or block-quantized.
 
-Execution paths:
-- decode (one row of activations) with i8-layout weights: `pallas_q8.q8_matvec`, the
-  fused int8-plane MXU kernel (HBM-bandwidth-bound, zero per-weight VPU work).
-- everything else: dequantize-to-dtype + `dot_general`; XLA fuses the scale broadcast
-  into the matmul's operand pipeline. Prefill lands here on purpose — with many
-  activation rows the per-weight dequant amortizes and the MXU runs dense bf16.
+Execution paths (`qmatmul`, one rule from shapes):
+- one row of activations: the matvec kernels (`pallas_q4.q4_matvec` on split-plane
+  Q40, `pallas_q8.q8_matvec` on int8 planes), HBM-bandwidth-bound.
+- 2 to 512 rows on split-plane Q40: `pallas_q4_mm.q4_matmul`, the packed weights
+  decoded in VMEM and fed to the MXU as bf16.
+- everything else, and `use_pallas=False`: dequantize-to-dtype + `dot_general`.
 
 Weights keep the reference's (out, in) row-major orientation with quant blocks along `in`
 (src/commands.cpp:22-39), so TP row/col splits slice whole blocks.
@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import math
 import threading
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -28,10 +29,6 @@ from ..platform_env import interpret_requested
 from ..quants import QTensor
 from ..resilience import faults
 from ..resilience.errors import FaultInjected, TransientDispatchError
-
-# "fused" is a strict superset of "all": everything "all" lowers plus the
-# residual-add / silu·mul epilogue fusions wired through models/forward.py
-FUSED_POLICIES = ("all", "fused")
 
 _KERNEL_SELECTED = metrics.counter(
     "matmul_kernel_selected_total",
@@ -47,9 +44,33 @@ _selections: dict[str, str] = {}
 _selections_lock = threading.Lock()
 
 
-def _record(kernel: str, m: int, w: QTensor, op: str = "mm") -> None:
-    n, kin = w.shape
-    key = f"m={m},n={n},k={kin},layout={w.layout},op={op}"
+class LayerOf(NamedTuple):
+    """Layer `layer` (a traced index) of a weight stacked over layers, not
+    yet sliced: the fused dequant-matmul reads its blocks out of the whole
+    stack, and a slice made for it would be one more copy of every layer's
+    packed weights a dispatch (ops/pallas_q4_mm.py `_q4_matmul`). Any other
+    lowering takes `one()`."""
+    stack: QTensor
+    layer: jax.Array
+
+    def one(self) -> QTensor:
+        return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+            a, self.layer, 0, keepdims=False), self.stack)
+
+
+def reads_the_stack(w, m: int, use_pallas: bool) -> bool:
+    """Whether `qmatmul` at m rows would hand this layer-stacked weight to
+    the fused dequant-matmul, stack and all: what the layer scan
+    (models/forward.py) keeps out of its sliced operands."""
+    from .pallas_q4_mm import q4_mm_supported
+
+    return bool(use_pallas and isinstance(w, QTensor)
+                and q4_mm_supported(w, m, stacked=True))
+
+
+def _record(kernel: str, m: int, w: QTensor) -> None:
+    n, kin = w.shape[-2:]
+    key = f"m={m},n={n},k={kin},layout={w.layout},op=mm"
     with _selections_lock:
         if _selections.get(key) != kernel:
             _selections[key] = kernel
@@ -59,7 +80,7 @@ def _record(kernel: str, m: int, w: QTensor, op: str = "mm") -> None:
 def kernel_selections() -> dict[str, str]:
     """Snapshot of {shape-bucket: kernel} selections recorded at trace time
     (bench.py provenance + /v1/stats). Kernel names: q4_matvec, q8_matvec,
-    q4_mm, q4_mm+res, q4_gated_mm, xla, xla-fallback."""
+    q4_mm, xla, xla-fallback."""
     with _selections_lock:
         return dict(_selections)
 
@@ -70,113 +91,84 @@ def reset_kernel_selections() -> None:
         _selections.clear()
 
 
-def qmatmul(x: jax.Array, w: QTensor, *, use_pallas: bool | str = False,
-            out_dtype=None, residual: jax.Array | None = None) -> jax.Array:
+def qmatmul(x: jax.Array, w: QTensor | LayerOf, *, use_pallas: bool = False,
+            out_dtype=None) -> jax.Array:
     """y = x @ W^T for W of logical shape (out, in); x: (..., in) -> (..., out).
 
-    use_pallas: False = XLA everywhere; True = fused kernels for decode (one
-    activation row); "all" = additionally the fused dequant-matmul for M>1
-    (prefill / batched decode — ops/pallas_q4_mm.py); "fused" = "all" plus the
-    fused epilogues (--fused-matmul / DLT_FUSED_MATMUL).
+    use_pallas: False = XLA everywhere (the tests' oracle); truthy = every
+    kernel whose gate admits the shape, chosen from what is visible here
+    (rows M, the weight's shape, layout and `groups`) and nothing else:
+    M == 1 the matvec kernels, 2 <= M <= 512 on a split-plane Q40 weight the
+    fused dequant-matmul (ops/pallas_q4_mm.py), anything else XLA's
+    dequantize-then-dot. On the chip the dequant-matmul read 1.9 to 7.8 ps a
+    weight at 8 and 64 rows and 6.5 to 10.6 at 512 against XLA's 5.6 to 11.4
+    and 10.3 to 14.1 at every matmul shape of the benchmark's three
+    configurations, the ragged 151936-row head and the expert scan's
+    (28672, 4096) slices among them: level with XLA on SmallThinker's two
+    small projections at 8 and 64 rows, faster everywhere else (PERF.md
+    section 6, PR 31), so the gate declines no shape for speed.
 
-    residual: optional (..., out) tensor; the result is residual + x @ W^T on
-    EVERY path (under "fused" the add runs inside the kernel's accumulator;
-    the fallbacks add in f32 before the out_dtype cast — same rounding as one
-    fused f32 accumulate, so a shape-gated fallback stays token-identical)."""
+    w may be a `LayerOf`: the dequant-matmul then reads the layer out of the
+    stack, every other lowering gets the slice."""
     m = math.prod(x.shape[:-1])
+    dt = out_dtype or x.dtype
+    layer = None
+    if isinstance(w, LayerOf):
+        if reads_the_stack(w.stack, m, use_pallas):
+            w, layer = w.stack, w.layer
+        else:
+            w = w.one()
     if use_pallas and m == 1:
         if w.layout == "i4p":
             from .pallas_q4 import q4_decode_supported, q4_matvec
 
             if w.groups == 1 and q4_decode_supported(w):
                 _record("q4_matvec", m, w)
-                y = q4_matvec(x, w, out_dtype=out_dtype or x.dtype)
-                return y if residual is None else _res_add(y, residual,
-                                                           out_dtype or x.dtype)
+                return q4_matvec(x, w, out_dtype=dt)
         else:
             from .pallas_q8 import q8_decode_supported, q8_matvec
 
             if q8_decode_supported(w):
                 _record("q8_matvec", m, w)
-                y = q8_matvec(x, w, out_dtype=out_dtype or x.dtype)
-                return y if residual is None else _res_add(y, residual,
-                                                           out_dtype or x.dtype)
-    if use_pallas in FUSED_POLICIES and m > 1 and w.layout == "i4p":
+                return q8_matvec(x, w, out_dtype=dt)
+    if use_pallas and m > 1 and w.layout == "i4p":
         from .pallas_q4_mm import q4_matmul, q4_mm_supported
 
         if not _kernel_select_ok(m, w):
-            return _qmatmul_xla(x, w, out_dtype=out_dtype, residual=residual)
-        if q4_mm_supported(w, m):
-            fuse_res = residual is not None and use_pallas == "fused"
-            y = q4_matmul(x, w, out_dtype=out_dtype or x.dtype,
-                          residual=residual if fuse_res else None)
-            _record("q4_mm+res" if fuse_res else "q4_mm", m, w)
-            if residual is not None and not fuse_res:
-                return _res_add(y, residual, out_dtype or x.dtype)
-            return y
+            return _qmatmul_xla(
+                x, w if layer is None else LayerOf(w, layer).one(),
+                out_dtype=out_dtype)
+        if q4_mm_supported(w, m, stacked=layer is not None):
+            _record("q4_mm", m, w)
+            return q4_matmul(x, w, layer=layer, out_dtype=dt)
     _record("xla", m, w)
-    return _qmatmul_xla(x, w, out_dtype=out_dtype, residual=residual)
+    return _qmatmul_xla(x, w, out_dtype=out_dtype)
 
 
-def _kernel_select_ok(m: int, w: QTensor, op: str = "mm") -> bool:
+def _kernel_select_ok(m: int, w: QTensor) -> bool:
     """The `matmul.kernel_select` injection point (docs/ROBUSTNESS.md): fires
     BEFORE the shape gate so the fault-matrix cells are non-vacuous on any
-    fused engine. An injected fault degrades that call site to the XLA
-    lowering, recorded as `xla-fallback`; nothing else is caught here, so a
+    engine with the kernels on. An injected fault degrades that call site to
+    the XLA lowering, recorded as `xla-fallback`; nothing else is caught here, so a
     kernel that fails to trace or lower fails the step that asked for it."""
     try:
-        faults.fire("matmul.kernel_select", m=m, n=w.shape[0])
+        faults.fire("matmul.kernel_select", m=m, n=w.shape[-2])
     except (FaultInjected, TransientDispatchError):
-        _record("xla-fallback", m, w, op=op)
+        _record("xla-fallback", m, w)
         return False
     return True
 
 
-def _res_add(y: jax.Array, residual: jax.Array, out_dtype) -> jax.Array:
-    return (residual.astype(jnp.float32)
-            + y.astype(jnp.float32)).astype(out_dtype)
-
-
-def _qmatmul_xla(x: jax.Array, w: QTensor, *, out_dtype=None,
-                 residual: jax.Array | None = None) -> jax.Array:
+def _qmatmul_xla(x: jax.Array, w: QTensor, *, out_dtype=None) -> jax.Array:
     """The oracle path: dequantize + dot_general; XLA fuses the scale
-    broadcast into the operand pipeline. Residual adds in f32 before the
-    cast (identical rounding to the kernel's f32 accumulator-init)."""
+    broadcast into the operand pipeline."""
     wd = w.dequantize(dtype=x.dtype)
     y = jax.lax.dot_general(
         x, wd,
         dimension_numbers=(((x.ndim - 1,), (wd.ndim - 1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    if residual is not None:
-        y = residual.astype(jnp.float32) + y
     return y.astype(out_dtype or x.dtype)
-
-
-def qmatmul_gated(x: jax.Array, w1: QTensor, w3: QTensor, *, act,
-                  act_name: str, use_pallas: bool | str = False,
-                  out_dtype=None) -> jax.Array:
-    """FFN gate-pair: act(x @ w1^T) * (x @ w3^T). Under use_pallas == "fused"
-    with M>1 and a kernel-eligible i4p pair this lowers to ONE
-    q4_gated_matmul (both weight streams at packed density, intermediates
-    VMEM-only); every other configuration runs two qmatmul calls + the jnp
-    activation (`act`, matching the kernel's `act_name` epilogue)."""
-    m = math.prod(x.shape[:-1])
-    if (use_pallas == "fused" and m > 1
-            and w1.layout == "i4p" and w3.layout == "i4p"
-            and act_name in ("silu", "gelu_tanh", "relu")):
-        from .pallas_q4_mm import q4_gated_matmul, q4_gated_supported
-
-        if not _kernel_select_ok(m, w1, op="gated"):
-            return (act(_qmatmul_xla(x, w1, out_dtype=out_dtype))
-                    * _qmatmul_xla(x, w3, out_dtype=out_dtype))
-        if q4_gated_supported(w1, w3, m):
-            y = q4_gated_matmul(x, w1, w3, act=act_name,
-                                out_dtype=out_dtype or x.dtype)
-            _record("q4_gated_mm", m, w1, op="gated")
-            return y
-    return (act(qmatmul(x, w1, use_pallas=use_pallas, out_dtype=out_dtype))
-            * qmatmul(x, w3, use_pallas=use_pallas, out_dtype=out_dtype))
 
 
 def qmatmul_q80(xq: jax.Array, sx: jax.Array, w: QTensor, *,

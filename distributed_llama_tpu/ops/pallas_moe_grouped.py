@@ -18,8 +18,9 @@ gate's block index offset by hidden / bn. `down`: x Wdown^T. The contraction
 axis is whole in every block (no K grid axis: an output block revisited after
 other blocks would lose its accumulator), and the body walks it in static
 chunks of at most 512 packed columns so the dequantized temporaries stay small.
-Dequantization is ops/pallas_q4_mm.py's: split-plane nibbles through i32, the
--8 and the block scale in f32, bf16 operands to the MXU, f32 accumulation.
+The decode is ops/pallas_q4_mm.py's (`partial_product`): split-plane nibbles
+through i32, the -8 and the bf16-rounded block scale in f32, bf16 operands to
+the MXU, f32 accumulation.
 
 Names: the two pallas_calls are `moe_grouped_q4_gu` and `moe_grouped_q4_down`
 (the trace's readers look for `moe_grouped_q4`).
@@ -35,22 +36,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..platform_env import interpret_requested
-from ..quants import QK, QTensor
-from .pallas_q4 import _f16_bits_to_f32
-from .pallas_q4_mm import _act_f32
+from ..quants import QTensor
+from .pallas_q4_mm import (VMEM_LIMIT, partial_product, pick_bk, scales_f32,
+                           scales_shape)
 
 _BLOCK_BYTES = 1 << 20  # packed bytes of one weight block a grid step
-_VMEM_LIMIT = 64 << 20  # of the chip's 128 MiB; the default scope is 16
-
-
-def _pick_bk(kh: int) -> int:
-    """Packed columns a body chunk decodes at once: the largest lane-aligned
-    width that divides the half-plane, the whole of it where none does (toy
-    sizes under the interpreter)."""
-    for b in (512, 256, 128):
-        if kh % b == 0:
-            return b
-    return kh
 
 
 def _pick_bn(n: int, kh: int) -> int | None:
@@ -83,60 +73,35 @@ def grouped_supported(w: QTensor, n_out: int, interpret: bool) -> bool:
 
 
 # hot-path: traced
-def _partial(xlo_ref, xhi_ref, wp_ref, sf, bk):
-    """(rows, bn) f32: the tile's rows against one expert's (bn, K) block,
-    the packed columns walked in static chunks of bk. A block's scale is
-    spread over its 32 lanes by the MXU (s (bn, sb) times a 0/1 (sb, bk)
-    matrix, exact at HIGHEST), which has time to spare here: `jnp.repeat`
-    along lanes cost the VPU as much as the rest of the decode (3.59 against
-    1.82 ms a layer's call at 8 rows, PERF.md section 6, PR 29)."""
-    kh = wp_ref.shape[-1]
-    sb = bk // QK
-    nbh = kh // QK  # scale columns of the low plane
-    spread = (jax.lax.broadcasted_iota(jnp.int32, (sb, bk), 1) // QK
-              == jax.lax.broadcasted_iota(jnp.int32, (sb, bk), 0)
-              ).astype(jnp.float32)
-    acc = None
-    for c in range(kh // bk):
-        wp = wp_ref[:, c * bk:(c + 1) * bk]
-        lo = (wp & jnp.uint8(0x0F)).astype(jnp.int32)
-        hi = wp.astype(jnp.int32) >> 4
-
-        def dequant(q, s):
-            s = jax.lax.dot_general(
-                s, spread, (((1,), (0,)), ((), ())),
-                precision=jax.lax.Precision.HIGHEST,
-                preferred_element_type=jnp.float32)
-            return ((q.astype(jnp.float32) - 8.0) * s).astype(jnp.bfloat16)
-
-        w_lo = dequant(lo, sf[:, c * sb:(c + 1) * sb])
-        w_hi = dequant(hi, sf[:, nbh + c * sb:nbh + (c + 1) * sb])
-        part = jax.lax.dot_general(
-            xlo_ref[:, c * bk:(c + 1) * bk].astype(jnp.bfloat16), w_lo,
-            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        part += jax.lax.dot_general(
-            xhi_ref[:, c * bk:(c + 1) * bk].astype(jnp.bfloat16), w_hi,
-            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        acc = part if acc is None else acc + part
-    return acc
+def _act_f32(a, act: str):
+    """Epilogue activation on the f32 accumulator, formulas matching
+    ops/kernels.py bit-for-bit in f32 (silu / tanh-approx GELU / ReLU)."""
+    if act == "silu":
+        return a / (1.0 + jnp.exp(-a))
+    if act == "relu":
+        return jnp.maximum(a, 0.0)
+    c = 0.79788456080286535587989211986876  # sqrt(2/pi), as gelu_tanh
+    return 0.5 * a * (1.0 + jnp.tanh(c * a * (1.0 + 0.044715 * a * a)))
 
 
 def _gu_kernel(te_ref, nu_ref, xlo_ref, xhi_ref, up_ref, sup_ref, gate_ref,
-               sgate_ref, o_ref, *, act, bk):
+               sgate_ref, o_ref, sfu_ref, sfg_ref, *, act, bk):
     @pl.when(pl.program_id(1) < nu_ref[0])
     def _():
-        up = _partial(xlo_ref, xhi_ref, up_ref, _f16_bits_to_f32(sup_ref[:]),
-                      bk)
-        gate = _partial(xlo_ref, xhi_ref, gate_ref,
-                        _f16_bits_to_f32(sgate_ref[:]), bk)
+        sfu_ref[:] = scales_f32(sup_ref)
+        sfg_ref[:] = scales_f32(sgate_ref)
+        up = partial_product(xlo_ref, xhi_ref, up_ref, sfu_ref, bk)
+        gate = partial_product(xlo_ref, xhi_ref, gate_ref, sfg_ref, bk)
         o_ref[:] = (up * _act_f32(gate, act)).astype(o_ref.dtype)
 
 
-def _down_kernel(te_ref, nu_ref, xlo_ref, xhi_ref, w_ref, s_ref, o_ref, *, bk):
+def _down_kernel(te_ref, nu_ref, xlo_ref, xhi_ref, w_ref, s_ref, o_ref,
+                 sf_ref, *, bk):
     @pl.when(pl.program_id(1) < nu_ref[0])
     def _():
-        o_ref[:] = _partial(xlo_ref, xhi_ref, w_ref,
-                            _f16_bits_to_f32(s_ref[:]), bk).astype(o_ref.dtype)
+        sf_ref[:] = scales_f32(s_ref)
+        o_ref[:] = partial_product(xlo_ref, xhi_ref, w_ref, sf_ref,
+                                   bk).astype(o_ref.dtype)
 
 
 def _row_block(i, nu_ref):
@@ -168,13 +133,16 @@ def _call(kernel, name, x, operands, w_specs, n_out, bn, tile, out_dtype,
         in_specs=_x_specs(tile, kh) + w_specs,
         out_specs=pl.BlockSpec((tile, bn),
                                lambda n, i, te, nu: (_row_block(i, nu), n)),
+        # a weight's decoded scales (pallas_q4_mm.scales_f32), one each
+        scratch_shapes=[scales_shape(bn, s.shape[-1])
+                        for s in operands[1::2]],
     )
     return pl.pallas_call(
         kernel, grid_spec=grid_spec, name=name,
         out_shape=jax.ShapeDtypeStruct((cap, n_out), out_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(tile_expert, n_used, x, x, *operands)
 
@@ -193,13 +161,13 @@ def _moe_grouped_q4(rows, tile_expert, n_used, up, sup, gate, sgate, down,
     bn_h, bn_d = _pick_bn(hidden, kh), _pick_bn(d_out, hidden // 2)
     nu = jnp.reshape(n_used, (1,)).astype(jnp.int32)
     te = tile_expert.astype(jnp.int32)
-    h = _call(functools.partial(_gu_kernel, act=act, bk=_pick_bk(kh)),
+    h = _call(functools.partial(_gu_kernel, act=act, bk=pick_bk(kh)),
               "moe_grouped_q4_gu", rows, (up, sup, gate, sgate),
               _w_specs(bn_h, kh, sup.shape[-1], 0)
               + _w_specs(bn_h, kh, sgate.shape[-1],
                          hidden // bn_h if merged else 0),
               hidden, bn_h, tile, rows.dtype, te, nu, interpret)
-    return _call(functools.partial(_down_kernel, bk=_pick_bk(hidden // 2)),
+    return _call(functools.partial(_down_kernel, bk=pick_bk(hidden // 2)),
                  "moe_grouped_q4_down", h, (down, sdown),
                  _w_specs(bn_d, hidden // 2, sdown.shape[-1], 0),
                  d_out, bn_d, tile, rows.dtype, te, nu, interpret)

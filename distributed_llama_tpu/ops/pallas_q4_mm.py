@@ -1,41 +1,43 @@
-"""Fused 4-bit dequant-matmul — the prefill / batched-decode counterpart of the
-q4 matvec kernel.
+"""Fused Q40 dequant-matmul for 2 to 512 activation rows: the weights decode in VMEM.
 
-The decode matvec (ops/pallas_q4.py) is a T=1 tool: its block-diagonal Xexp
-trick needs one activation row. Prefill (T>1) and batched decode (B>1) run the
-XLA dequant+dot path (ops/matmul.py), which dequantizes the i4p planes to bf16
-operands that XLA may MATERIALIZE through HBM (~3.6x the packed bytes at 7B).
-This kernel keeps the dequant in VMEM: each grid step loads a packed (bn, bkp)
-nibble tile, picks its two scale tiles out of the row block's scales (decoded
-once per row block into tile-major VMEM scratch, _split_scales), decodes to
-bf16 in registers, and feeds the MXU — weights stream from HBM exactly once at
-the file's own 0.5625 B/weight density regardless of M.
+The decode matvec (ops/pallas_q4.py) is a one-row tool: its block-diagonal
+Xexp trick needs a single activation row. Everything with more rows (prefill
+chunks, the batched decode step, verify blocks, the all-experts scan's
+per-expert slices) used to go through XLA's dequantize-then-dot, which wrote
+the dequantized model to HBM every dispatch: the block scales spread to every
+weight as an array, the packed planes copied to another layout, 7.8 GB of
+temporaries for a 4 GB model (PERF.md section 5). Here the packed nibbles and
+the f16-bit scales cross HBM once a call at the file's 0.5625 bytes a weight,
+become bf16 in VMEM and go to the MXU with float32 accumulation. The decoded
+weight is bit for bit XLA's `QTensor.dequantize(dtype=bf16)`:
+bf16((q - 8) * bf16(scale)).
 
-Split-plane addressing: i4p byte column c holds the LOW nibble of element c and
-the HIGH nibble of element K/2 + c (QTensor.to_i4p_layout), so one packed tile
-covers two disjoint K-ranges; the kernel takes the activation block TWICE with
-block-index maps offset by K/2 (x_lo / x_hi views of the same array), and
-scale tile j serves the low plane, tile j + gk the high plane.
+Split-plane addressing: i4p byte column c holds the LOW nibble of element c
+and the HIGH nibble of element K/2 + c (QTensor.to_i4p_layout), so a packed
+(bn, K/2) block covers all of K; the activations come in as two (M, K/2)
+blocks of the same array, the planes' halves of K.
 
-Mosaic portability: nibble extraction widens through i32 (no narrow shifts),
-the -8 offset and per-block scaling happen in f32 (no i8 subtract), scales
-decode from f16 BIT PATTERNS with the integer-exact _f16_bits_to_f32, and the
-dot is bf16xbf16->f32 on the MXU. No f16 refs anywhere. Two things the
-interpreter accepts and the chip's compiler refuses shaped the scale path: a
-(bn, bkp/32) scale block is narrower than a lane tile, and a (bn, bkp) ->
-(bn, bkp/32, 32) reshape is an unsupported shape cast, so the scales arrive
-as whole rows and widen with jnp.repeat (tests/test_tpu_compile.py holds the
-family to the chip's compiler at Llama-3-8B shapes).
+Blocks follow the shapes (`_pick_bn`): the rows' whole K is in every block,
+so the activations cross HBM once a call and the grid has N / bn steps of up
+to 512 KiB of packed weights; the body walks K in static chunks of at most 512
+packed columns so that the decoded temporaries stay small and the compiler
+can lay one chunk's decode (VPU) beside another's matmul (MXU).
 
-Opt-in (Engine prefill_kernel / DLT_PREFILL_KERNEL, bench --prefill-kernel)
-until a hardware A/B lands — same policy as the prologue kernels. The batched
-serving runtime opts in one level higher (Engine fused_matmul /
-DLT_FUSED_MATMUL, --fused-matmul): the same kernel family with the legal
-epilogues fused — residual add in the accumulator init (q4_matmul residual=)
-and the silu·mul FFN gate pair as one kernel over the separate w1/w3 planes
-(q4_gated_matmul) — serving decode M=B, verify M=B·(1+k), and drafter rows
-(docs/SERVING.md "Kernel selection"; byte model computed by
-perf/q4_mm_bench.py).
+What the chip's compiler refuses and the interpreter accepts shaped the
+decode (tests/test_tpu_compile.py holds the family to it at the cells'
+shapes): no shift or subtract on 8-bit vectors (the nibbles widen through
+i32), no f16 refs (the scales arrive as int16 bit patterns and decode with
+integer math, `_f16_bits_to_f32`), no (bn, bk) -> (bn, bk/32, 32) reshape (a
+block's scale reaches its 32 lanes by a lane gather, `_spread`).
+
+`scales_f32` and `partial_product` are the ONE decode this kernel and the
+grouped expert kernels (ops/pallas_moe_grouped.py) share.
+
+No epilogue. A residual add in the accumulator and a gated act(x Wgate^T) *
+(x Wup^T) pair over the merged w13 stack were built and measured against
+this plain kernel (PERF.md section 6, PR 30): 0.381 against 0.386 ms and
+0.751 against 0.789 ms a call at 512 rows, nothing at 8 and 64, and in the
+dense cell `itl_p95_ms` 110.92 against the plain kernel's 110.62; they went.
 """
 
 from __future__ import annotations
@@ -44,317 +46,227 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..obs import metrics
 from ..platform_env import interpret_requested
 from ..quants import QK, QTensor
 from .pallas_q4 import _f16_bits_to_f32
 
-
-# hot-path: traced
-def _split_scales(s_ref, st_ref):
-    """Decode one row-block's f16-bit scales (bn, K/32) once and lay them out
-    tile-major in VMEM scratch (2*gk, bn, sb): entry t holds the sb block
-    scales of packed-column tile t (low plane) or t - gk (high plane). The
-    slices are static, so the K grid step can pick its tile with a leading-
-    axis index — Mosaic has no unaligned dynamic lane slice."""
-    sf = _f16_bits_to_f32(s_ref[:])
-    sb = st_ref.shape[2]
-    for t in range(st_ref.shape[0]):
-        st_ref[t] = sf[:, t * sb:(t + 1) * sb]
+VMEM_LIMIT = 64 << 20  # of the chip's 128 MiB; the default scope is 16
+_MM_BLOCK_BYTES = 1 << 19  # packed bytes of one weight block a grid step:
+# the body is unrolled over a block, so its program's size follows the block,
+# and every step program carries five of them (`setup_s` 85.4 against the parent's
+# 77.8 at 1 MiB, PR 30); 512 KiB read the same time a call
+_MAX_ROWS = 512  # activation rows a call: the (M, bn) accumulator and the
+# resident (M, K) activations are sized for the widest dispatch the engines
+# make (8 slots x a 64-token chunk)
 
 
-# hot-path: traced
-def _tile_partial(xlo_ref, xhi_ref, wp_ref, st_ref, *, gk):
-    """One grid step's (M, bn) partial product: decode the packed (bn, bkp)
-    nibble tile against its two scale tiles in VMEM and hit the MXU twice
-    (low-plane and high-plane K-ranges of the split-plane layout)."""
-    j = pl.program_id(1)
-    wp = wp_ref[:]  # (bn, bkp) uint8 packed columns
-    lo = (wp & jnp.uint8(0x0F)).astype(jnp.int32)  # elements [c, c+bkp)
-    hi = wp.astype(jnp.int32) >> 4  # elements [K/2+c, K/2+c+bkp)
-
-    def dequant(q_i32, s):
-        # s (bn, bkp//QK): each block scale covers QK consecutive lanes
-        qf = (q_i32.astype(jnp.float32) - 8.0) * jnp.repeat(s, QK, axis=1)
-        return qf.astype(jnp.bfloat16)
-
-    w_lo = dequant(lo, st_ref[j])
-    w_hi = dequant(hi, st_ref[j + gk])
-    acc = jax.lax.dot_general(
-        xlo_ref[:].astype(jnp.bfloat16), w_lo, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)  # (M, bn)
-    acc += jax.lax.dot_general(
-        xhi_ref[:].astype(jnp.bfloat16), w_hi, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    return acc
-
-
-# hot-path: traced
-def _act_f32(a, act: str):
-    """Epilogue activation on the f32 accumulator, formulas matching
-    ops/kernels.py bit-for-bit in f32 (silu / tanh-approx GELU / ReLU)."""
-    if act == "silu":
-        return a / (1.0 + jnp.exp(-a))
-    if act == "relu":
-        return jnp.maximum(a, 0.0)
-    c = 0.79788456080286535587989211986876  # sqrt(2/pi), as gelu_tanh
-    return 0.5 * a * (1.0 + jnp.tanh(c * a * (1.0 + 0.044715 * a * a)))
-
-
-def _mm_kernel(xlo_ref, xhi_ref, wp_ref, s_ref, o_ref, st_ref, *, gk):
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        _split_scales(s_ref, st_ref)
-        o_ref[:] = jnp.zeros_like(o_ref)
-
-    o_ref[:] += _tile_partial(xlo_ref, xhi_ref, wp_ref, st_ref, gk=gk)
-
-
-def _mm_res_kernel(xlo_ref, xhi_ref, wp_ref, s_ref, res_ref, o_ref, st_ref,
-                   *, gk):
-    """Residual-fused variant: the accumulator STARTS at the residual block
-    (same (M, bn) tile the output covers), so `res + x @ w.T` costs zero extra
-    HBM round-trips — the residual streams in once with the output tile."""
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        _split_scales(s_ref, st_ref)
-        o_ref[:] = res_ref[:].astype(jnp.float32)
-
-    o_ref[:] += _tile_partial(xlo_ref, xhi_ref, wp_ref, st_ref, gk=gk)
-
-
-def _gated_mm_kernel(xlo_ref, xhi_ref, w1p_ref, s1_ref, w3p_ref, s3_ref,
-                     o_ref, acc1_ref, acc3_ref, st1_ref, st3_ref, *, gk, act):
-    """FFN gate-pair fusion: act(x @ w1.T) * (x @ w3.T) in ONE kernel. Both
-    accumulators live in VMEM scratch across the sequential K grid; the
-    silu/gelu·mul epilogue runs on the last K step, so the (M, hidden)
-    intermediate activations never exist in HBM at all."""
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        _split_scales(s1_ref, st1_ref)
-        _split_scales(s3_ref, st3_ref)
-        acc1_ref[:] = jnp.zeros_like(acc1_ref)
-        acc3_ref[:] = jnp.zeros_like(acc3_ref)
-
-    acc1_ref[:] += _tile_partial(xlo_ref, xhi_ref, w1p_ref, st1_ref, gk=gk)
-    acc3_ref[:] += _tile_partial(xlo_ref, xhi_ref, w3p_ref, st3_ref, gk=gk)
-
-    @pl.when(j == gk - 1)
-    def _epilogue():
-        o_ref[:] = _act_f32(acc1_ref[:], act) * acc3_ref[:]
-
-
-_BN = 256  # weight rows per grid step
-
-
-def _pick_bkp(kh: int) -> int | None:
-    """Packed columns per grid step: the largest lane-aligned tile width that
-    divides the half-plane exactly (7B's w2 has kh=5504 -> 128; most dims take
-    512). None = untileable (kh not a multiple of 128)."""
+def pick_bk(kh: int) -> int:
+    """Packed columns a body chunk decodes at once: the largest lane-aligned
+    width that divides the half-plane, the whole of it where none does (toy
+    sizes under the interpreter)."""
     for b in (512, 256, 128):
         if kh % b == 0:
             return b
-    return None
+    return kh
 
 
-# VMEM the tile-major scale scratch of one kernel may take. It sits beside the
-# double-buffered operand tiles in the chip's 16 MiB of scoped VMEM: at 11 MiB
-# (K=11008, the 7B w2 shape, one weight) the matmul still compiles at M=512;
-# a gated pair at that K would need 21.5 MiB and is declined.
-_SCALE_SCRATCH_LIMIT = 11 << 20
+def scales_f32(s_ref):
+    """A block's f16-bit scales rounded to bf16, which is what XLA's
+    `dequantize(dtype=bf16)` multiplies by, as the float32 the VPU computes
+    in; padded with zero columns to whole lane tiles for `_spread`. The
+    kernels keep it in a VMEM scratch of `scales_shape`."""
+    s = _f16_bits_to_f32(s_ref[:]).astype(jnp.bfloat16).astype(jnp.float32)
+    pad = -s.shape[1] % 128
+    if pad:
+        s = jnp.concatenate(
+            [s, jnp.zeros((s.shape[0], pad), jnp.float32)], axis=1)
+    return s
 
 
-def _scale_scratch_bytes(kh: int) -> int:
-    """Bytes of one weight's (2*gk, bn, sb) f32 scale scratch, each (bn, sb)
-    tile padded to a 128-lane tile."""
-    return 2 * (kh // _pick_bkp(kh)) * _BN * 128 * 4
+def scales_shape(bn: int, nb: int):
+    return pltpu.VMEM((bn, -(-nb // 128) * 128), jnp.float32)
 
 
-def q4_mm_supported(w: QTensor, m: int) -> bool:
-    """Whether the fused dequant-matmul can run this weight for M activation
-    rows: i4p layout, self-contained pack (groups folded away by
-    _localize_qtensors under TP), half-plane divisible into lane-aligned tiles,
-    an (M, bn) f32 accumulator that stays tiny, and a scale scratch that
-    fits VMEM."""
-    if w.layout != "i4p" or w.groups != 1 or w.data.ndim != 2:
+_GATHER_LANES = jax.lax.GatherDimensionNumbers(
+    offset_dims=(), collapsed_slice_dims=(1,), start_index_map=(1,),
+    operand_batching_dims=(0,), start_indices_batching_dims=(0,))
+
+
+# hot-path: traced
+def _spread(tile, off, bk: int):
+    """Scale columns off .. off + bk / 32 of a (bn, 128) lane tile of decoded
+    scales, each over its block's 32 lanes: (bn, bk). A lane gather inside
+    one vreg a lane tile of output (what `take_along_axis` lowers to, bound
+    directly: its wrapper was three quarters of the time it took to trace
+    this body), which costs the decode nothing measurable: the same kernel
+    with no spread at all reads the same time. What it replaced (PERF.md
+    section 6, PR 30, ps a weight at (28672, 4096), M = 8): a 0/1 matmul in
+    one bf16 pass 2.3 against 1.8, the same at Precision.HIGHEST 8.8 (the
+    grouped expert kernels' form until then), `jnp.repeat` 11.8."""
+    if bk % 128:  # a toy width, its own columns: the interpreter alone
+        return jnp.repeat(tile, QK, axis=1)
+    lane = jax.lax.shift_right_logical(
+        jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1), 5) + off
+    tiles = [jax.lax.gather(
+        tile, (lane + t * (128 // QK))[..., None], _GATHER_LANES, (1, 1),
+        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+        for t in range(bk // 128)]
+    return tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, axis=1)
+
+
+# hot-path: traced
+@jax.jit
+def _chunk_product(xlo, xhi, wp, s_lo, off_lo, s_hi, off_hi):
+    """One chunk of `partial_product`: bk packed columns of a (bn, K/2)
+    block against the rows' two halves. A function of its own under `jit` so
+    that it is traced once a process per (rows, bn, bk), not once for every
+    chunk of every weight of every engine: the body is unrolled over K and a
+    serving process brings up 45 kernels (five matrices at 8, 64 and 512
+    rows, and again at each depth of the output check's engines), 4 to 14
+    chunks each; Mosaic inlines the call. The nibbles widen through i32 (the
+    chip's compiler has no shift or subtract on 8-bit vectors); a
+    sign-extending or a bit-pattern decode of the nibble read the same time
+    as this one."""
+    bk = wp.shape[1]
+    lo = (wp & jnp.uint8(0x0F)).astype(jnp.int32).astype(jnp.float32)
+    hi = (wp.astype(jnp.int32) >> 4).astype(jnp.float32)
+    w_lo = ((lo - 8.0) * _spread(s_lo, off_lo, bk)).astype(jnp.bfloat16)
+    w_hi = ((hi - 8.0) * _spread(s_hi, off_hi, bk)).astype(jnp.bfloat16)
+    contract = (((1,), (1,)), ((), ()))
+    return (jax.lax.dot_general(xlo.astype(jnp.bfloat16), w_lo, contract,
+                                preferred_element_type=jnp.float32)
+            + jax.lax.dot_general(xhi.astype(jnp.bfloat16), w_hi, contract,
+                                  preferred_element_type=jnp.float32))
+
+
+def _scale_tile(s_ref, g0: int, bk: int):
+    """The lane tile of s_ref (`scales_f32`) that holds scale columns
+    g0 .. g0 + bk / 32, and where in it they start: a chunk's columns never
+    straddle two tiles (bk divides the half-plane, bk / 32 divides 128)."""
+    if bk % 128:
+        return s_ref[:, g0:g0 + bk // QK], np.int32(0)
+    w0 = g0 // 128 * 128
+    return s_ref[:, w0:w0 + 128], np.int32(g0 - w0)
+
+
+# hot-path: traced
+def partial_product(xlo_ref, xhi_ref, wp_ref, s_ref, bk):
+    """(rows, bn) f32: the rows' two K halves against one packed (bn, K/2)
+    block with scales s_ref (`scales_f32`, in VMEM), the packed columns
+    walked in static chunks of bk (`_chunk_product`), so that the compiler
+    lays one chunk's decode (VPU) beside another's matmul (MXU): the same
+    body as a `fori_loop` over pairs of 256-column chunks read 45 against 31
+    ms a T = 1 dispatch of the dense cell (PERF.md section 6, PR 30)."""
+    kh = wp_ref.shape[-1]
+    acc = None
+    for c in range(kh // bk):
+        cols = slice(c * bk, (c + 1) * bk)
+        part = _chunk_product(
+            xlo_ref[:, cols], xhi_ref[:, cols], wp_ref[:, cols],
+            *_scale_tile(s_ref, c * bk // QK, bk),
+            *_scale_tile(s_ref, (kh + c * bk) // QK, bk))
+        acc = part if acc is None else acc + part
+    return acc
+
+
+def _mm_kernel(layer_ref, xlo_ref, xhi_ref, wp_ref, s_ref, o_ref, sf_ref, *,
+               bk):
+    sf_ref[:] = scales_f32(s_ref)
+    o_ref[:] = partial_product(xlo_ref, xhi_ref, wp_ref, sf_ref,
+                               bk).astype(o_ref.dtype)
+
+
+def _pick_bn(n: int, kh: int) -> int:
+    """Weight rows a grid step: as many whole lane tiles as keep the packed
+    (bn, K/2) block under _MM_BLOCK_BYTES, at most 512 (the accumulator is
+    (M, bn) float32); n itself where it is smaller. The grid is cdiv(n, bn):
+    a ragged last block (the 151936-row head) reads past the array and its
+    surplus columns are never written."""
+    if n <= 128:
+        return n
+    return min(max(_MM_BLOCK_BYTES // kh // 128, 1) * 128, 512, n // 128 * 128)
+
+
+def q4_mm_supported(w: QTensor, m: int, stacked: bool = False) -> bool:
+    """Whether the fused dequant-matmul runs this weight for m activation
+    rows: split-plane Q40 in one self-contained pack (`groups` folded away by
+    _localize_qtensors under TP), (N, K/2) or with `stacked` a stack of such
+    over layers, a half-plane of whole lane tiles, and no more rows than the
+    resident activations are sized for. One row is the matvec kernel's."""
+    if w.layout != "i4p" or w.groups != 1 or w.data.ndim != 2 + stacked:
         return False
-    kh = w.data.shape[1]  # K/2 packed columns
-    return (_pick_bkp(kh) is not None and m <= 512
-            and _scale_scratch_bytes(kh) <= _SCALE_SCRATCH_LIMIT)
+    return w.data.shape[-1] % 128 == 0 and 2 <= m <= _MAX_ROWS
 
 
-def _grid_geom(x, wp, scales):
-    """(bn, bkp, gk, sb) for one (M, K) x (N, K/2) dispatch, asserting the
-    split-plane shapes line up."""
+@functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
+def _q4_matmul(x, wp, scales, layer, *, out_dtype, interpret: bool = False):
+    """x (M, K) -> (M, N) against layer `layer` of packed nibbles
+    (L, N, K/2) + int16 f16-bit scales (L, N, K/32).
+
+    The layer is an index into the WHOLE stack, prefetched as a scalar and
+    used by the weight blocks' index maps: a layer scan that handed the
+    kernel its slice made XLA copy every layer's packed weights to a buffer
+    of their own first (`dynamic-slice_bitcast_fusion`, `copy`: 7 s of the
+    dense cell's 45 s busy, 9 ms of every dispatch; PERF.md section 6,
+    PR 30)."""
     m, k = x.shape
-    n, kh = wp.shape
-    nb = k // QK
-    assert kh * 2 == k and scales.shape == (n, nb), (x.shape, wp.shape,
-                                                     scales.shape)
-    bkp = _pick_bkp(kh)
-    assert bkp is not None, (kh, "half-plane not tileable; gate with "
-                                 "q4_mm_supported")
-    return min(_BN, n), bkp, kh // bkp, bkp // QK
-
-
-def _x_specs(m, bkp, gk):
-    # two views of x: the tile's low-plane and high-plane K-ranges
-    return [
-        pl.BlockSpec((m, bkp), lambda i, j: (0, j), memory_space=pltpu.VMEM),
-        pl.BlockSpec((m, bkp), lambda i, j: (0, j + gk),
-                     memory_space=pltpu.VMEM),
-    ]
-
-
-def _w_specs(bn, bkp, nb):
-    # one packed-nibble tile per step; the row block's scales whole (their
-    # block index does not move along K, so they are fetched once per row
-    # block). A (bn, bkp/32) scale tile would be narrower than a lane tile,
-    # which the TPU lowering refuses.
-    return [
-        pl.BlockSpec((bn, bkp), lambda i, j: (i, j), memory_space=pltpu.VMEM),
-        pl.BlockSpec((bn, nb), lambda i, j: (i, 0), memory_space=pltpu.VMEM),
-    ]
-
-
-def _scale_scratch(bn, gk, sb):
-    return pltpu.VMEM((2 * gk, bn, sb), jnp.float32)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _q4_matmul(x, wp, scales, *, interpret: bool = False):
-    """x (M, K) -> (M, N) against packed nibbles (N, K/2) + int16 f16-bit scales
-    (N, K/32)."""
-    m = x.shape[0]
-    n = wp.shape[0]
-    bn, bkp, gk, sb = _grid_geom(x, wp, scales)
+    _, n, kh = wp.shape
+    assert kh * 2 == k and scales.shape == (wp.shape[0], n, k // QK), (
+        x.shape, wp.shape, scales.shape)
+    bn = _pick_bn(n, kh)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,  # (layer,)
+        grid=(pl.cdiv(n, bn),),
+        in_specs=[pl.BlockSpec((m, kh), lambda i, l: (0, 0)),
+                  pl.BlockSpec((m, kh), lambda i, l: (0, 1)),
+                  pl.BlockSpec((None, bn, kh), lambda i, l: (l[0], i, 0)),
+                  pl.BlockSpec((None, bn, scales.shape[2]),
+                               lambda i, l: (l[0], i, 0))],
+        out_specs=pl.BlockSpec((m, bn), lambda i, l: (0, i)),
+        scratch_shapes=[scales_shape(bn, scales.shape[2])])
     return pl.pallas_call(
-        functools.partial(_mm_kernel, gk=gk),
-        grid=(pl.cdiv(n, bn), gk),
-        in_specs=_x_specs(m, bkp, gk) + _w_specs(bn, bkp, scales.shape[1]),
-        out_specs=pl.BlockSpec((m, bn), lambda i, j: (0, i),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        scratch_shapes=[_scale_scratch(bn, gk, sb)],
+        functools.partial(_mm_kernel, bk=pick_bk(kh)),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT),
+        name="q4_mm",
         interpret=interpret,
-    )(x, x, wp, scales)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), x, x, wp, scales)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _q4_matmul_res(x, wp, scales, res, *, interpret: bool = False):
-    """x (M, K), res (M, N) -> res + x @ dequant(w).T, residual folded into
-    the accumulator init (one extra streamed operand, no epilogue pass)."""
-    m = x.shape[0]
-    n = wp.shape[0]
-    assert res.shape == (m, n), (res.shape, (m, n))
-    bn, bkp, gk, sb = _grid_geom(x, wp, scales)
-    return pl.pallas_call(
-        functools.partial(_mm_res_kernel, gk=gk),
-        grid=(pl.cdiv(n, bn), gk),
-        in_specs=(_x_specs(m, bkp, gk) + _w_specs(bn, bkp, scales.shape[1]) + [
-            pl.BlockSpec((m, bn), lambda i, j: (0, i),
-                         memory_space=pltpu.VMEM),
-        ]),
-        out_specs=pl.BlockSpec((m, bn), lambda i, j: (0, i),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        scratch_shapes=[_scale_scratch(bn, gk, sb)],
-        interpret=interpret,
-    )(x, x, wp, scales, res)
+_BODIES_LOWERED = metrics.counter(
+    "q4_mm_bodies_lowered_total",
+    "dequant-matmul call sites traced into a program, each of which lowers "
+    "the kernel's body to Mosaic once: five a step program, so it follows "
+    "the number of programs a process brings up (counted at trace time)")
 
 
-@functools.partial(jax.jit, static_argnames=("act", "interpret"))
-def _q4_gated_matmul(x, w1p, s1, w3p, s3, *, act: str,
-                     interpret: bool = False):
-    """act(x @ w1.T) * (x @ w3.T) with both (M, N) accumulators in VMEM
-    scratch — the FFN pair's intermediate activations never touch HBM."""
-    m = x.shape[0]
-    n = w1p.shape[0]
-    assert w3p.shape == w1p.shape and s3.shape == s1.shape, (
-        w1p.shape, w3p.shape, s1.shape, s3.shape)
-    bn, bkp, gk, sb = _grid_geom(x, w1p, s1)
-    w_specs = _w_specs(bn, bkp, s1.shape[1])
-    return pl.pallas_call(
-        functools.partial(_gated_mm_kernel, gk=gk, act=act),
-        grid=(pl.cdiv(n, bn), gk),
-        in_specs=_x_specs(m, bkp, gk) + w_specs + w_specs,
-        out_specs=pl.BlockSpec((m, bn), lambda i, j: (0, i),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((m, bn), jnp.float32),
-                        pltpu.VMEM((m, bn), jnp.float32),
-                        _scale_scratch(bn, gk, sb),
-                        _scale_scratch(bn, gk, sb)],
-        interpret=interpret,
-    )(x, x, w1p, s1, w3p, s3)
-
-
-def _flatten_rows(x):
-    m_total = 1
-    for d in x.shape[:-1]:
-        m_total *= d
-    return m_total, x.shape[:-1]
-
-
-def q4_matmul(x: jax.Array, w: QTensor, *, out_dtype=None,
-              interpret: bool | None = None,
-              residual: jax.Array | None = None) -> jax.Array:
-    """Prefill/batched matmul: x (..., K) against an i4p QTensor (N, K) ->
-    (..., N), weights streamed once at 4-bit density. With `residual`
-    (shape (..., N)) the add is fused into the accumulator init."""
-    m_total, lead = _flatten_rows(x)
-    if not q4_mm_supported(w, m_total):
+def q4_matmul(x: jax.Array, w: QTensor, *, layer=None, out_dtype=None,
+              interpret: bool | None = None) -> jax.Array:
+    """x (..., K) against an i4p QTensor (N, K), or with `layer` (a traced
+    index) against that layer of one stacked (L, N, K) -> (..., N), the
+    weights streamed once at 4-bit density and decoded in VMEM."""
+    x2 = x.reshape(-1, x.shape[-1])
+    if not q4_mm_supported(w, x2.shape[0], stacked=layer is not None):
         raise ValueError(
             f"q4_matmul cannot run this weight (layout={w.layout}, "
             f"groups={w.groups}, shape={getattr(w.data, 'shape', None)}, "
-            f"M={m_total}); gate with q4_mm_supported")
+            f"layer={layer is not None}, M={x2.shape[0]}); gate with "
+            f"q4_mm_supported")
     if interpret is None:
         interpret = interpret_requested()
-    k = x.shape[-1]
-    if residual is None:
-        y = _q4_matmul(x.reshape(m_total, k), w.data, w.scales,
-                       interpret=interpret)
-    else:
-        y = _q4_matmul_res(x.reshape(m_total, k), w.data, w.scales,
-                           residual.reshape(m_total, residual.shape[-1]),
-                           interpret=interpret)
-    return y.reshape(*lead, y.shape[-1]).astype(out_dtype or x.dtype)
-
-
-def q4_gated_supported(w1: QTensor, w3: QTensor, m: int) -> bool:
-    """Whether the fused FFN gate-pair kernel can serve act(x@w1.T) * (x@w3.T):
-    both weights individually kernel-eligible and shape-identical (they tile
-    on one grid), plus VMEM headroom for the two (M, bn) scratch
-    accumulators and both scale scratches."""
-    return (q4_mm_supported(w1, m) and q4_mm_supported(w3, m)
-            and w1.data.shape == w3.data.shape
-            and w1.scales.shape == w3.scales.shape
-            and 2 * _scale_scratch_bytes(w1.data.shape[1])
-            <= _SCALE_SCRATCH_LIMIT)
-
-
-def q4_gated_matmul(x: jax.Array, w1: QTensor, w3: QTensor, *,
-                    act: str = "silu", out_dtype=None,
-                    interpret: bool | None = None) -> jax.Array:
-    """FFN gate-pair: act(x @ w1.T) * (x @ w3.T) for x (..., K) against two
-    i4p QTensors (N, K), one fused kernel — both weight streams at 4-bit
-    density and ZERO HBM traffic for the (..., N) intermediates."""
-    m_total, lead = _flatten_rows(x)
-    if not q4_gated_supported(w1, w3, m_total):
-        raise ValueError(
-            f"q4_gated_matmul cannot run this pair (layouts={w1.layout}/"
-            f"{w3.layout}, shapes={getattr(w1.data, 'shape', None)}/"
-            f"{getattr(w3.data, 'shape', None)}, M={m_total}); gate with "
-            f"q4_gated_supported")
-    if act not in ("silu", "gelu_tanh", "relu"):
-        raise ValueError(f"unsupported epilogue activation {act!r}")
-    if interpret is None:
-        interpret = interpret_requested()
-    k = x.shape[-1]
-    y = _q4_gated_matmul(x.reshape(m_total, k), w1.data, w1.scales,
-                         w3.data, w3.scales, act=act, interpret=interpret)
-    return y.reshape(*lead, y.shape[-1]).astype(out_dtype or x.dtype)
+    _BODIES_LOWERED.inc()
+    wp, scales = w.data, w.scales
+    if layer is None:  # a stack of one
+        wp, scales, layer = wp[None], scales[None], 0
+    y = _q4_matmul(x2, wp, scales, jnp.asarray(layer, jnp.int32),
+                   out_dtype=jnp.dtype(out_dtype or x.dtype),
+                   interpret=interpret)
+    return y.reshape(*x.shape[:-1], y.shape[-1])

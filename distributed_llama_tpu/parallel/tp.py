@@ -179,10 +179,8 @@ def make_sharded_forward(spec: ModelSpec, mesh: Mesh, params: dict[str, Any], *,
         # pool layout (L, N, hk, bt, hs): heads stay on tp, blocks replicated
         kv_spec = P(None, None, AXIS_TP)
     # a 1-member tp axis has nothing to reduce: drop the axis name so every
-    # psum/all_gather elides AND the "fused" policy may fold residual adds
-    # into the matmul kernels (illegal before a real TP merge). Compressed
-    # collectives keep the axis — the Q80 wire quantization is part of their
-    # numerics even over one member.
+    # psum/all_gather elides. Compressed collectives keep the axis: the Q80
+    # wire quantization is part of their numerics even over one member.
     tp_axis = AXIS_TP if (tp > 1 or compress_collectives) else None
     fwd = functools.partial(forward, spec=spec, dtype=dtype, axis_name=tp_axis,
                             sp_axis_name=AXIS_SP if sp > 1 else None, sp_size=sp,

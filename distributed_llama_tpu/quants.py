@@ -213,6 +213,32 @@ def jnp_quantize_q80(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     return q, deltas
 
 
+def jnp_to_i4p(packed: jax.Array, scales: jax.Array, col_groups: int = 1
+               ) -> tuple[jax.Array, jax.Array]:
+    """`QTensor.to_i4p_layout` on the device, bit for bit: planar Q40 bytes
+    (..., nb, 16), or the same flattened to (..., K/2), with f16 scales ->
+    split-plane nibbles (..., K/2) and the scales' int16 bit patterns.
+
+    Within a column group the low plane is blocks 0 .. nbg/2 and the high
+    plane the rest. Output block b of a group (32 bytes) takes its low
+    nibbles from planar block b and its high nibbles from planar block
+    b + nbg/2: bytes 0..15 their low nibbles (block elements 0..15), bytes
+    16..31 their high nibbles (elements 16..31). Nothing is widened past
+    uint8 and no nibble is unpacked to a byte of its own."""
+    k2 = packed.shape[-1] * (packed.shape[-2] if packed.ndim == scales.ndim + 1
+                             else 1)
+    lead = scales.shape[:-1]
+    assert k2 == scales.shape[-1] * (QK // 2), (packed.shape, scales.shape)
+    assert k2 % col_groups == 0 and (k2 // col_groups) % QK == 0, (
+        k2 * 2, col_groups)
+    p = packed.reshape(*lead, col_groups, 2, k2 // col_groups // QK, QK // 2)
+    a, b = p[..., 0, :, :], p[..., 1, :, :]
+    lo = (a & 0x0F) | ((b & 0x0F) << 4)
+    hi = (a >> 4) | (b & 0xF0)
+    data = jnp.stack([lo, hi], axis=-2).reshape(*lead, k2)
+    return data, jax.lax.bitcast_convert_type(scales, jnp.int16)
+
+
 # ---------------------------------------------------------------------------
 # QTensor: a quantized-or-not weight tensor as a pytree
 # ---------------------------------------------------------------------------

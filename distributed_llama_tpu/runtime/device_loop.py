@@ -50,11 +50,9 @@ from ..parallel.tp import _expand_pspec_tree
 
 def _tp_axis(mesh, compress_collectives: bool) -> str | None:
     """AXIS_TP, or None when the tp axis has one member: a 1-member axis has
-    nothing to reduce, so dropping the name elides every psum/all_gather AND
-    lets the "fused" matmul policy fold residual adds into the kernels
-    (illegal before a real TP merge). Compressed collectives keep the axis —
-    their Q80 wire quantization is part of the numerics even over one
-    member."""
+    nothing to reduce, so dropping the name elides every psum/all_gather.
+    Compressed collectives keep the axis: their Q80 wire quantization is
+    part of the numerics even over one member."""
     return AXIS_TP if (mesh.shape[AXIS_TP] > 1 or compress_collectives) else None
 
 

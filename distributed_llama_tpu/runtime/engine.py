@@ -149,8 +149,6 @@ class Engine:
                  compress_collectives: bool = False, batch: int = 1,
                  pod: bool = False, cache_write: str | None = None,
                  moe_sharding: str = "slice", fused_prologue: bool | None = None,
-                 prefill_kernel: bool | None = None,
-                 fused_matmul: bool | None = None,
                  kv_cache_storage: str | None = None,
                  kv_cache_resident: int = 1024,
                  kv_cache_dir: str | None = None,
@@ -249,30 +247,6 @@ class Engine:
             # read; /healthz and /v1/stats report what this engine runs
             print("💡 kernels: xla, whatever the start-up line said: this "
                   "checkpoint has no Q40/Q80 weights for the Pallas kernels")
-        # fused dequant-matmul for prefill / batched decode
-        # (ops/pallas_q4_mm.py): opt-in (flag or DLT_PREFILL_KERNEL=1) until
-        # the hardware A/B lands — same policy as the prologue kernels
-        if prefill_kernel is None:
-            import os
-
-            prefill_kernel = os.environ.get("DLT_PREFILL_KERNEL", "").lower() in (
-                "1", "true", "yes")
-        self.prefill_kernel = prefill_kernel and self.use_pallas
-        if self.prefill_kernel:
-            self.use_pallas = "all"  # qmatmul's M>1 kernel opt-in
-        # fused batched serving path (--fused-matmul / DLT_FUSED_MATMUL):
-        # everything "all" lowers PLUS the fused epilogues — residual add in
-        # the wo/w2 accumulator init and the silu·mul FFN gate-pair kernel
-        # (w1/w3 stay un-merged so the pair kernel can take them). Subsumes
-        # prefill_kernel; opt-in until the hardware A/B lands.
-        if fused_matmul is None:
-            import os
-
-            fused_matmul = os.environ.get("DLT_FUSED_MATMUL", "").lower() in (
-                "1", "true", "yes")
-        self.fused_matmul = bool(fused_matmul) and bool(self.use_pallas)
-        if self.fused_matmul:
-            self.use_pallas = "fused"
         # paged-attention kernel gate (ops/pallas_paged_attention.py): the
         # kwarg wins (the tests' interpret-mode engines); the default follows
         # use_pallas (TPU + quantized weights), where the kernel beat the
@@ -283,8 +257,7 @@ class Engine:
         if self.use_pallas:
             params = prepare_for_pallas(params, self.tp,
                                         moe_sharding=self.moe_sharding,
-                                        spec=spec,
-                                        keep_gate_pair=self.fused_matmul)
+                                        spec=spec, mesh=self.mesh)
         self.params = shard_params(params, self.mesh, spec,
                                    moe_sharding=self.moe_sharding)
         # global (all-shard) weight bytes one decode step streams — per-chip traffic

@@ -139,7 +139,7 @@ DRAFT_CELLS = len(DRAFT_POINTS) * len(DRAFT_KINDS) * 2  # × {pipe, serial}
 # dispatch (ops/matmul.py), BEFORE the shape gate — a raising kernel path
 # must degrade that call site to the XLA lowering (bit-identical by the
 # oracle contract) without killing co-batched rows or the engine. Cells
-# build a FRESH --fused-matmul engine UNDER injection, so kernel selection
+# build a FRESH engine with the kernels on UNDER injection, so kernel selection
 # actually happens while the fault is armed: every output must equal the
 # kernel-off reference byte-for-byte whether the kernel path served or
 # degraded, and fs.fired is asserted > 0 (non-vacuous).
@@ -380,7 +380,7 @@ def run_draft_family() -> tuple[int, list[str]]:
 
 
 def build_fused_engine(pipeline: bool):
-    """A --fused-matmul batched engine (use_pallas upgraded to "fused",
+    """A batched engine with the kernels on (use_pallas=True,
     ops/matmul.py): every M>1 matmul the programs trace runs the kernel
     dispatch, so `matmul.kernel_select` fires while the cell's fault is
     armed and the except-path degrades that call site to XLA."""
@@ -390,7 +390,7 @@ def build_fused_engine(pipeline: bool):
     params = init_random_params(spec, FloatType.Q40, seed=11)
     return spec, BatchEngine(spec, params, slots=2, tp=1, superstep=4,
                              pipeline=pipeline, speculative=4,
-                             use_pallas=True, fused_matmul=True)
+                             use_pallas=True)
 
 
 def run_fused_cell(pipeline: bool, kind: str, refs: dict) -> list[str]:

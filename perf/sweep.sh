@@ -53,8 +53,6 @@ run python bench.py --steps 64 --device-loop 32
 # prefill throughput (chunked prefill is a capability win over the reference)
 run python bench.py --prefill 64 --steps 16
 run python bench.py --prefill 128 --steps 16
-run python bench.py --prefill 64 --steps 16 --prefill-kernel
-run python bench.py --prefill 128 --steps 16 --prefill-kernel
 
 # the other BASELINE.json configs
 run python bench.py --arch tinyllama_1_1b --steps 64

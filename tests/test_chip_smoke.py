@@ -181,31 +181,42 @@ def tiny_checkpoint(tmp_path_factory):
     return str(out / "tiny.m")
 
 
-def test_parity_policy_arm_runs_the_opt_in_kernels(tiny_checkpoint):
-    """--policy fused-matmul: the family that lowers everything the other two
-    do. One process only, because each builds five interpret-mode engines."""
+def test_parity_default_pair_runs_the_dequant_matmul(tiny_checkpoint):
+    """The default pair, kernels against use_pallas=False: the T=1 steps go
+    through the matvec and the 64-token chunk through the fused
+    dequant-matmul, with no call site degraded to XLA. One process only,
+    because it builds five interpret-mode engines."""
     p = subprocess.run(
         [sys.executable, "-m", "distributed_llama_tpu.apps.parity", "--model",
-         tiny_checkpoint, "--policy", "fused-matmul", "--steps", "4"],
-        cwd=REPO, capture_output=True, text=True, timeout=600)
+         tiny_checkpoint, "--steps", "4"],
+        cwd=REPO, capture_output=True, text=True, timeout=600,
+        env={**os.environ, "DLT_PALLAS_INTERPRET": "1"})
     assert p.returncode == 0, p.stdout[-2000:] + p.stderr[-2000:]
     out = json.loads(p.stdout.strip().splitlines()[-1])
     assert out["ok"] and set(out["passes"]["full"]["arms"]) == {
-        "kernels", "fused-matmul"}
+        "kernels", "xla"}
     engaged = set(out["kernel_selections"].values())
-    assert {"q4_mm", "q4_mm+res", "q4_gated_mm"} <= engaged
+    assert {"q4_mm", "q4_matvec"} <= engaged
     assert "xla-fallback" not in engaged
 
 
-@pytest.mark.parametrize("policy", sorted(parity.POLICIES))
-def test_parity_policies_are_engine_switches(policy):
+# the entry points' opt-in families, and the switches that went with the
+# hardware A/B (PR 30): the dequant-matmul is the default, not a policy
+@pytest.mark.parametrize("policy, there", [("prologue", True),
+                                           ("prefill-kernel", False),
+                                           ("fused-matmul", False)])
+def test_parity_policies_are_engine_switches(policy, there):
     import inspect
 
     from distributed_llama_tpu.runtime.engine import Engine
 
     switches = inspect.signature(Engine.__init__).parameters
-    assert parity.POLICIES[policy] and set(parity.POLICIES[policy]) <= set(
-        switches)
+    assert (policy in parity.POLICIES) is there
+    if there:
+        assert parity.POLICIES[policy] and set(
+            parity.POLICIES[policy]) <= set(switches)
+    else:
+        assert policy.replace("-", "_") not in switches
 
 
 def test_parity_shallow_cut_is_the_first_and_the_last_layer(tiny_checkpoint):

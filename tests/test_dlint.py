@@ -102,12 +102,12 @@ def test_compile_manifest_gate_holds_and_catches_injection():
     assert all(f.rule == "compile-manifest" for f in findings)
 
 
-def test_compile_manifest_names_rogue_fused_bucket():
+def test_compile_manifest_names_rogue_kernel_bucket():
     """ISSUE 16 satellite: the kernel policy is part of the program cache
-    key — a verify T bucket minted under the fused policy outside the
-    pinned set must fail the gate BY NAME (kernel=fused in the key), never
-    alias onto the kernel-off pin. The factory call alone records the
-    build (jit traces lazily), so the test costs no compile."""
+    key: a verify T bucket minted with the kernels on outside the pinned
+    set must fail the gate BY NAME (kernel=1 in the key), never alias onto
+    the kernel-off pin. The factory call alone records the build (jit
+    traces lazily), so the test costs no compile."""
     from distributed_llama_tpu.analysis import compile_audit
     from distributed_llama_tpu.models.params import init_random_params
     from distributed_llama_tpu.parallel.mesh import make_mesh
@@ -122,10 +122,10 @@ def test_compile_manifest_names_rogue_fused_bucket():
     with audit:
         device_loop.make_batched_verify_loop(
             spec, make_mesh(tp=1), params, 9, mode="greedy",
-            attn_window=None, use_pallas="fused", kv_block_tokens=16)
+            attn_window=None, use_pallas=True, kv_block_tokens=16)
     findings = compile_audit.diff_manifest(audit.manifest(), pinned)
-    assert findings, "gate missed the rogue fused T bucket"
-    key = "verify[t=9,mode=greedy,window=None,paged=16,kernel=fused]"
+    assert findings, "gate missed the rogue kernel-on T bucket"
+    key = "verify[t=9,mode=greedy,window=None,paged=16,kernel=1]"
     assert any(key in f.message for f in findings), \
         [f.message for f in findings]
 

@@ -1,9 +1,11 @@
 """Fused 4-bit dequant-matmul (ops/pallas_q4_mm.py), interpret mode.
 
-The prefill / batched-decode kernel dequantizes i4p tiles in VMEM and feeds the
-MXU in bf16 — it must match dequantize-to-bf16-then-dot to float tolerance, and
-the split-plane dual-view addressing (one packed tile covers two disjoint
-K-ranges) must survive multi-tile K grids and TP sharding."""
+The kernel decodes i4p blocks in VMEM to the bf16 weights XLA's
+`dequantize(dtype=bf16)` makes, bit for bit, and feeds the MXU: it must match
+dequantize-then-dot to float32 summation order, the split-plane addressing
+(one packed block covers both halves of K) must survive several K chunks, a
+ragged last row block and TP sharding, and every caller reaches it through
+`qmatmul(use_pallas=True)` with `use_pallas=False` as the oracle."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -13,71 +15,89 @@ from distributed_llama_tpu.models.forward import forward, init_kv_cache
 from distributed_llama_tpu.models.params import (init_random_params,
                                                  prepare_for_pallas)
 from distributed_llama_tpu.models.spec import ArchType, ModelSpec, RopeType
-from distributed_llama_tpu.ops.pallas_q4_mm import q4_matmul, q4_mm_supported
+from distributed_llama_tpu.ops.matmul import (kernel_selections, qmatmul,
+                                              reset_kernel_selections)
+from distributed_llama_tpu.ops.pallas_q4_mm import (_pick_bn, pick_bk,
+                                                    q4_matmul,
+                                                    q4_mm_supported)
 from distributed_llama_tpu.ops.rope import RopeTables
 from distributed_llama_tpu.quants import FloatType, QTensor
 
 
-@pytest.mark.parametrize("m,n,k", [(8, 96, 1024), (3, 300, 2048), (1, 64, 1024)])
-def test_q4_matmul_matches_dequant_dot(m, n, k):
-    rng = np.random.RandomState(0)
-    w = QTensor.from_float(rng.randn(n, k).astype(np.float32) * 0.02,
-                           FloatType.Q40).to_i4p_layout()
-    assert q4_mm_supported(w, m)
-    x = jnp.asarray(rng.randn(m, k).astype(np.float32))
+def _w(n, k, seed=0):
+    rng = np.random.RandomState(seed)
+    return QTensor.from_float(rng.randn(n, k).astype(np.float32) * 0.02,
+                              FloatType.Q40).to_i4p_layout()
 
-    wd = w.dequantize(dtype=jnp.bfloat16)
-    want = (x.astype(jnp.bfloat16) @ wd.T).astype(np.float32)
+
+# (8, 96, 1024): one block, one K chunk; (3, 300, 2048): rows no tile
+# divides, two chunks; (40, 640, 256): a ragged second row block (bn 512 of
+# 640) and a chunk of 128; (4, 64, 4096): four chunks of 512; (8, 128,
+# 2560): five chunks of 256, whose scales' lane tiles end in padding
+@pytest.mark.parametrize("m,n,k", [(8, 96, 1024), (3, 300, 2048),
+                                   (40, 640, 256), (4, 64, 4096),
+                                   (8, 128, 2560)])
+def test_q4_matmul_matches_dequant_dot(m, n, k):
+    w = _w(n, k)
+    assert q4_mm_supported(w, m)
+    x = jnp.asarray(np.random.RandomState(1).randn(m, k).astype(np.float32))
+    want = qmatmul(x.astype(jnp.bfloat16), w, use_pallas=False,
+                   out_dtype=jnp.float32)
     got = q4_matmul(x, w, out_dtype=jnp.float32, interpret=True)
-    # per-tile f32 accumulation vs one full-K bf16 dot: order differences at
-    # bf16 product granularity
+    # the same bf16 weights and products, summed in another order
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=1e-2, rtol=3e-2)
+                               atol=1e-4, rtol=1e-4)
+
+
+def test_decoded_weights_are_xlas_bf16_weights_bit_for_bit():
+    """Each row of an identity matrix reads one column of the decoded
+    weights back: they have to BE `dequantize(dtype=bf16)`, whose scales are
+    rounded to bf16 before the product."""
+    w = _w(192, 256, seed=3)
+    got = q4_matmul(jnp.eye(256, dtype=jnp.bfloat16), w,
+                    out_dtype=jnp.float32, interpret=True)
+    want = np.asarray(w.dequantize(dtype=jnp.bfloat16).astype(jnp.float32))
+    np.testing.assert_array_equal(np.asarray(got), want.T)
 
 
 def test_q4_mm_supported_gates():
-    rng = np.random.RandomState(1)
-    w = QTensor.from_float(rng.randn(64, 1024).astype(np.float32),
-                           FloatType.Q40).to_i4p_layout()
-    assert q4_mm_supported(w, 64)
-    assert not q4_mm_supported(w, 1024)  # M cap
-    w_odd = QTensor.from_float(rng.randn(64, 576).astype(np.float32),
-                               FloatType.Q40).to_i4p_layout()
-    assert not q4_mm_supported(w_odd, 8)  # K/2=288 not tileable by 512
-    w8 = QTensor.from_float(rng.randn(64, 1024).astype(np.float32),
+    w = _w(64, 1024, seed=1)
+    assert q4_mm_supported(w, 2) and q4_mm_supported(w, 512)
+    assert not q4_mm_supported(w, 1)  # one row is the matvec kernel's
+    assert not q4_mm_supported(w, 513)  # more rows than the blocks hold
+    assert not q4_mm_supported(_w(64, 576), 8)  # K/2 = 288: no lane tile
+    w8 = QTensor.from_float(np.zeros((64, 1024), np.float32),
                             FloatType.Q80).to_i8_layout()
-    assert not q4_mm_supported(w8, 8)  # i8 layout unsupported
+    assert not q4_mm_supported(w8, 8)  # another layout
+    stacked = QTensor(FloatType.Q40, np.zeros((2, 64, 512), np.uint8),
+                      np.zeros((2, 64, 32), np.int16), layout="i4p")
+    assert not q4_mm_supported(stacked, 8)  # the grouped kernels' stacks
+    with pytest.raises(ValueError, match="q4_mm_supported"):
+        q4_matmul(jnp.ones((1, 1024), jnp.bfloat16), w, interpret=True)
 
 
-def test_scale_scratch_gate_declines_what_vmem_cannot_hold():
-    """The tile-major scale scratch is lane-padded f32, so a half-plane that
-    only tiles by 128 columns costs 32x its scales: at K=11008 one weight
-    still fits beside the operand tiles (11 MiB; it compiles for v5e at
-    M=512), a gated pair would need 21.5 MiB of the chip's 16 MiB scoped
-    VMEM, which its compiler refuses. The gates read shapes only."""
-    from distributed_llama_tpu.ops.pallas_q4_mm import q4_gated_supported
-
-    def weight(n, k):
-        return QTensor(FloatType.Q40, np.zeros((n, k // 2), np.uint8),
-                       np.zeros((n, k // 32), np.int16), layout="i4p")
-
-    assert q4_mm_supported(weight(256, 11008), 512)
-    assert not q4_gated_supported(weight(256, 11008), weight(256, 11008), 8)
-    assert q4_gated_supported(weight(256, 14336), weight(256, 14336), 128)
-    assert not q4_gated_supported(weight(256, 28672), weight(256, 28672), 8)
-    assert not q4_mm_supported(weight(256, 2 * 125 * 128), 8)  # 31 MiB
+@pytest.mark.parametrize("n,k,bn,bk", [
+    (4096, 4096, 256, 512),      # Mistral wo: 512 KiB blocks of 256 rows
+    (4096, 14336, 128, 512),     # w2: 7168 packed columns a row, 14 chunks
+    (151936, 2560, 384, 256),    # SmallThinker's head: a ragged last block
+    (2560, 3584, 256, 256),      # its wo: 1792 packed columns, 7 chunks
+    (2048, 768, 512, 128),       # an expert's down: 384 packed columns
+    (64, 1024, 64, 512)])        # fewer rows than a lane tile
+def test_blocks_follow_the_shapes(n, k, bn, bk):
+    assert (_pick_bn(n, k // 2), pick_bk(k // 2)) == (bn, bk)
+    assert bn * (k // 2) <= 1 << 20
 
 
 def _spec():
-    # dim 1024 so K/2=512 tiles exactly (q4_mm_supported needs kh % 512 == 0)
     return ModelSpec(arch_type=ArchType.LLAMA, dim=1024, hidden_dim=1024,
                      n_layers=2, n_heads=8, n_kv_heads=8, vocab_size=256,
                      seq_len=32, rope_type=RopeType.LLAMA).resolved()
 
 
 def test_prefill_forward_kernel_matches_xla_path():
-    """T=8 prefill through use_pallas='all' (the dequant-matmul kernel) == the
-    XLA dequant path at bf16-accumulation tolerance."""
+    """T=8 prefill through use_pallas=True (the dequant-matmul at M=8) == the
+    XLA dequant path over the same prepared weights, which off the chip
+    multiplies float32 activations by float32 weights: bf16's distance."""
     spec = _spec()
     params = init_random_params(spec, FloatType.Q40, seed=7)
     rope = RopeTables.create(spec)
@@ -86,10 +106,12 @@ def test_prefill_forward_kernel_matches_xla_path():
     tokens = jnp.asarray([[1, 5, 9, 2, 7, 4, 3, 8]])
     kc, vc = init_kv_cache(spec)
     want, _, _ = forward(pp, spec, rope, tokens, kc, vc, jnp.int32(0),
-                         use_pallas=True)
+                         use_pallas=False)
+    reset_kernel_selections()
     kc, vc = init_kv_cache(spec)
     got, _, _ = forward(pp, spec, rope, tokens, kc, vc, jnp.int32(0),
-                        use_pallas="all")
+                        use_pallas=True)
+    assert set(kernel_selections().values()) == {"q4_mm"}
     got, want = np.asarray(got), np.asarray(want)
     rel = np.abs(got - want).max() / (np.abs(want).max() + 1e-9)
     assert rel < 0.02, rel
@@ -98,18 +120,16 @@ def test_prefill_forward_kernel_matches_xla_path():
 def test_prefill_kernel_sharded_matches():
     """tp=2 shard_map prefill with the kernel (col-sharded wo/w2 localize to
     groups=1 self-contained packs) == the planar sharded step. The localized
-    shard widths must actually take the kernel (adaptive tile width), or this
-    test would pass vacuously through the XLA fallback."""
-    from distributed_llama_tpu.ops.pallas_q4_mm import _pick_bkp
+    shard widths must actually take the kernel, or this test would pass
+    vacuously through XLA."""
     from distributed_llama_tpu.parallel.mesh import make_mesh
     from distributed_llama_tpu.parallel.tp import (init_sharded_kv_cache,
                                                    make_sharded_forward,
                                                    shard_params)
 
     spec = _spec()
-    # col-sharded wo/w2 local half-plane width: (K/tp)/2 — must be tileable
-    assert _pick_bkp(spec.dim // 2 // 2) is not None
-    assert _pick_bkp(spec.hidden_dim // 2 // 2) is not None
+    # col-sharded wo/w2 local half-plane width (K/tp)/2: whole lane tiles
+    assert spec.dim // 2 // 2 % 128 == 0 and spec.hidden_dim // 2 // 2 % 128 == 0
     params = init_random_params(spec, FloatType.Q40, seed=3)
     mesh = make_mesh(tp=2)
     tokens = jnp.asarray([[1, 5, 9, 2]])
@@ -121,37 +141,41 @@ def test_prefill_kernel_sharded_matches():
     want, _, _ = step(base, rope, tokens, kc, vc, jnp.int32(0))
 
     pp = shard_params(prepare_for_pallas(params, tp=2, spec=spec), mesh, spec)
-    stepp = make_sharded_forward(spec, mesh, pp, use_pallas="all",
+    reset_kernel_selections()
+    stepp = make_sharded_forward(spec, mesh, pp, use_pallas=True,
                                  donate_cache=False)
     kc, vc = init_sharded_kv_cache(spec, mesh)
     got, _, _ = stepp(pp, rope, tokens, kc, vc, jnp.int32(0))
+    assert set(kernel_selections().values()) == {"q4_mm"}
     got, want = np.asarray(got), np.asarray(want)
     rel = np.abs(got - want).max() / (np.abs(want).max() + 1e-9)
     assert rel < 0.02, rel
 
 
-def test_engine_prefill_kernel_generation_matches():
-    """End-to-end: Engine(prefill_kernel=True) greedy tokens == baseline (the
-    kernel only changes where dequant happens; decode path identical)."""
+def test_engine_prefill_goes_through_the_kernel():
+    """End-to-end: Engine(use_pallas=True) prefills its chunk through the
+    dequant-matmul and decodes through the matvec; the prompt's greedy
+    continuation is the kernel-off engine's."""
     from distributed_llama_tpu.runtime.engine import Engine
     from distributed_llama_tpu.runtime.sampler import Sampler
 
     spec = _spec()
     params = init_random_params(spec, FloatType.Q40, seed=13)
-    base = Engine(spec, params, tp=1, use_pallas=True)
-    want, _ = base.generate([1, 7, 3, 9, 2], 6,
+    base = Engine(spec, params, tp=1, use_pallas=False)
+    prompt = [1, 7, 3, 9, 2, 11, 4, 6, 5]  # a chunk of 8 rows and a step
+    want, _ = base.generate(prompt, 6,
                             Sampler(spec.vocab_size, temperature=0.0))
-
-    eng = Engine(spec, params, tp=1, use_pallas=True, prefill_kernel=True)
-    assert eng.use_pallas == "all"
-    got, _ = eng.generate([1, 7, 3, 9, 2], 6,
+    reset_kernel_selections()
+    eng = Engine(spec, params, tp=1, use_pallas=True)
+    got, _ = eng.generate(prompt, 6,
                           Sampler(spec.vocab_size, temperature=0.0))
+    assert {"q4_mm", "q4_matvec"} <= set(kernel_selections().values())
     assert got == want
 
 
-def test_batch_engine_with_prefill_kernel_matches():
+def test_batch_engine_with_the_kernel_matches():
     """Batched decode (B=2 slots) engages the dequant-matmul at m=B>1; tokens
-    must match the non-kernel batched engine exactly."""
+    must match the kernel-off batched engine exactly."""
     from distributed_llama_tpu.runtime.batch_engine import BatchEngine
     from distributed_llama_tpu.runtime.sampler import Sampler
 
@@ -160,7 +184,7 @@ def test_batch_engine_with_prefill_kernel_matches():
     prompts = [[1, 7, 23, 5], [1, 9, 2]]
 
     def run(**kw):
-        be = BatchEngine(spec, params, slots=2, tp=2, use_pallas=True, **kw)
+        be = BatchEngine(spec, params, slots=2, tp=2, **kw)
         try:
             reqs = [be.submit(list(p), 6, Sampler(spec.vocab_size, temperature=0.0))
                     for p in prompts]
@@ -168,27 +192,53 @@ def test_batch_engine_with_prefill_kernel_matches():
         finally:
             be.close()
 
-    want = run()
-    got = run(prefill_kernel=True)
+    want = run(use_pallas=False)
+    reset_kernel_selections()
+    got = run(use_pallas=True)
+    assert "q4_mm" in set(kernel_selections().values())
     assert got == want
 
 
-def test_pick_bkp_baseline_arch_coverage():
-    """Pin exactly which BASELINE widths take the kernel and which fall back:
-    all single-chip (tp=1) in-widths are tileable — the adaptive width exists
-    because 7B's w2 half-plane (5504) is not a multiple of 512 — while the odd
-    TP-local slices of 11008-class hidden dims (2752 at tp=4, 1376 at tp=8)
-    are KNOWN fallbacks (half-plane not a multiple of 128). A new arch whose
-    hot width lands in the fallback set should move it to the tileable list or
-    widen the ladder."""
-    from distributed_llama_tpu.ops.pallas_q4_mm import _pick_bkp
+def test_a_second_step_program_traces_no_chunk_again(monkeypatch):
+    """The body is unrolled over K, and what it unrolls is one jitted chunk
+    (`_chunk_product`), traced once a process per (rows, bn, bk): a step of
+    ANOTHER attention window (another program, the same matmuls) and an
+    engine of ANOTHER depth (the output check's cuts) lower their call
+    sites (`q4_mm_bodies_lowered_total` counts them) and trace no chunk."""
+    from distributed_llama_tpu.ops import pallas_q4_mm as mm
+    from distributed_llama_tpu.parallel.mesh import make_mesh
+    from distributed_llama_tpu.parallel.tp import (init_sharded_kv_cache,
+                                                   make_sharded_forward,
+                                                   shard_params)
 
-    # tp=1 in-widths of every BASELINE arch (dim and hidden): all tileable
-    for k in (4096, 11008, 2048, 5632, 14336, 6144, 32768):
-        assert _pick_bkp(k // 2) is not None, k
-    assert _pick_bkp(5504) == 128  # 7B w2, the reason the ladder exists
-    assert _pick_bkp(2048) == 512
-    # known XLA fallbacks: odd TP-local slices of 11008/5632-class hidden dims
-    for k in (2752, 1376, 704, 1408):
-        assert _pick_bkp(k // 2) is None, k
-    assert _pick_bkp(288) is None  # K=576: untileable, gated out
+    mesh = make_mesh(tp=1)
+    rope = RopeTables.create(_spec())
+    tokens = jnp.asarray([[1, 5, 9, 2, 7]])  # five rows: no other test's
+
+    def run(window, n_layers=2):
+        import dataclasses
+
+        spec = dataclasses.replace(_spec(), n_layers=n_layers)
+        params = init_random_params(spec, FloatType.Q40, seed=17)
+        pp = shard_params(prepare_for_pallas(params, spec=spec, mesh=mesh),
+                          mesh, spec)
+        step = make_sharded_forward(spec, mesh, pp, use_pallas=True,
+                                    donate_cache=False, attn_window=window)
+        kc, vc = init_sharded_kv_cache(spec, mesh)
+        return np.asarray(step(pp, rope, tokens, kc, vc, jnp.int32(0))[0])
+
+    spreads = []  # `_spread` runs only while a chunk is being traced
+
+    def counted(*a, spread=mm._spread):
+        spreads.append(a[0].shape)
+        return spread(*a)
+
+    monkeypatch.setattr(mm, "_spread", counted)
+    first = run(16)
+    sites, chunks = mm._BODIES_LOWERED.value, len(spreads)
+    assert chunks >= 2  # a chunk's two planes
+    second = run(32)
+    run(16, n_layers=3)
+    assert mm._BODIES_LOWERED.value - sites >= 2 * 4  # wqkv, wo, w13, w2
+    assert len(spreads) == chunks
+    np.testing.assert_array_equal(first, second)

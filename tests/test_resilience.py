@@ -369,9 +369,13 @@ def test_deadline_before_first_token_errors(setup):
     try:
         _wait_until(lambda: sum(1 for s in be._slots if s.req) == 2,
                     msg="slots occupied")
-        victim = be.submit([1, 6, 7], 8, _greedy(spec), deadline=0.1)
-        with pytest.raises(DeadlineExceeded):
-            victim.wait(timeout=60)
+        # paced as its neighbour above: with warm programs the blockers'
+        # 64 tokens take under the 0.1 s and the victim is admitted in time
+        with faults.active(FaultSpec("batch.dispatch", kind="latency",
+                                     delay_ms=40)):
+            victim = be.submit([1, 6, 7], 8, _greedy(spec), deadline=0.1)
+            with pytest.raises(DeadlineExceeded):
+                victim.wait(timeout=60)
         assert victim.finish == "deadline" and victim.out == []
     finally:
         for b in blockers:

@@ -9,12 +9,16 @@ case lowers one kernel at a Llama-3-8B shape (dim 4096, hidden 14336, vocab
 it with `interpret=False`. Nothing runs: a pass says the compiler takes the
 kernel, not that its result is right (chip_smoke.py's parity phase does that).
 
-The cases marked "repaired" were refused before this file existed: the fused
-dequant-matmul family for a (256, 16) scale block and an in-kernel lane-split
-reshape, the inline-Xexp matvec at K=14336 for 1.9 MiB too much VMEM, and the
-prologue kernels for the same reshape.
+The fused dequant-matmul is held to the benchmark cells' real shapes at 8,
+64 and 512 rows (`CELL_MATMULS`): what `qmatmul` hands it in a decode step,
+an 8-token and a 64-token chunk at 8 slots. Its gate declines none of them.
+
+The cases marked "repaired" were refused before this file existed: the
+inline-Xexp matvec at K=14336 for 1.9 MiB too much VMEM, and the prologue
+kernels for an in-kernel lane-split reshape.
 """
 
+import functools
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
@@ -30,8 +34,8 @@ from distributed_llama_tpu.ops.pallas_moe_grouped import _moe_grouped_q4
 from distributed_llama_tpu.ops.pallas_paged_attention import paged_attention
 from distributed_llama_tpu.ops.pallas_prologue import _quantize, _rmsnorm_q80
 from distributed_llama_tpu.ops.pallas_q4 import _q4_matvec, _q4_matvec_inline
-from distributed_llama_tpu.ops.pallas_q4_mm import (_q4_gated_matmul,
-                                                    _q4_matmul, _q4_matmul_res)
+from distributed_llama_tpu.ops.pallas_q4_mm import q4_matmul, q4_mm_supported
+from distributed_llama_tpu.quants import FloatType, QTensor
 
 DIM, HIDDEN, VOCAB, HS, LAYERS = 4096, 14336, 128256, 128, 32
 BF16, F32, I8, U8, I16, I32 = (jnp.bfloat16, jnp.float32, jnp.int8, jnp.uint8,
@@ -88,19 +92,23 @@ def matvec_inline(n, k):
             [((1, k), I8), ((1, nb), F32), *_q4_weight(n, k)], {})
 
 
-def matmul(m, n, k):
-    return _q4_matmul, [((m, k), BF16), *_q4_weight(n, k)], {}
+@functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
+def _q4_matmul(x, wp, scales, layer, *, out_dtype, interpret):
+    return q4_matmul(x, QTensor(FloatType.Q40, wp, scales, layout="i4p"),
+                     layer=layer, out_dtype=out_dtype, interpret=interpret)
 
 
-def matmul_res(m, n, k):
-    return (_q4_matmul_res,
-            [((m, k), BF16), *_q4_weight(n, k), ((m, n), BF16)], {})
-
-
-def gated(m, n, k):
-    return (_q4_gated_matmul,
-            [((m, k), BF16), *_q4_weight(n, k), *_q4_weight(n, k)],
-            {"act": "silu"})
+def matmul(m, n, k, out=BF16):
+    """The fused dequant-matmul as `qmatmul` calls it from the layer scan:
+    a layer of the whole stack, through the module that is lowered once a
+    process and exported for the TPU. The gate has to admit the shape (a
+    case `q4_mm_supported` declined would be asserted so)."""
+    stack = [((2, n, k // 2), U8), ((2, n, k // 32), I16)]
+    w = QTensor(FloatType.Q40, *(jax.ShapeDtypeStruct(*a) for a in stack),
+                layout="i4p")
+    assert q4_mm_supported(w, m, stacked=True), (m, n, k)
+    return (_q4_matmul, [((m, k), BF16), *stack, ((), I32)],
+            {"out_dtype": jnp.dtype(out)})
 
 
 TRACED_I32 = object()  # a keyword argument that is a traced i32 scalar
@@ -138,6 +146,21 @@ def decode_attention(hk, window):
             [((hk, 4, HS), F32), cache, cache, new, new, ((), I32), ((), I32)],
             {"window": window})
 
+
+# the benchmark cells' matmuls (out rows, in columns): Mistral-7B's merged
+# wqkv, wo, merged w13 (Mixtral's expert scan slices its [up|gate] stack to
+# the same shape), w2 (and the expert's down slice) and head at 8, 64 and 512
+# rows; SmallThinker's merged wqkv, wo and ragged 151936-row head at 512
+_MISTRAL = {"wqkv": (6144, 4096), "wo": (4096, 4096), "w13": (28672, 4096),
+            "w2": (4096, 14336), "head": (32000, 4096)}
+_SMALLTHINKER = {"wqkv": (4608, 2560), "wo": (2560, 3584),
+                 "head": (151936, 2560)}
+CELL_MATMULS = {
+    **{f"matmul-mistral-m{m}-{name}": matmul(m, n, k)
+       for m in (8, 64, 512) for name, (n, k) in _MISTRAL.items()},
+    **{f"matmul-smallthinker-m512-{name}": matmul(512, n, k)
+       for name, (n, k) in _SMALLTHINKER.items()},
+}
 
 CASES = {
     # q4_matvec, single chip: wq/wo, w1/w3, w2, wcls, merged wqkv
@@ -189,12 +212,12 @@ CASES = {
     "decode-attn-w256": decode_attention(8, 256),
     "decode-attn-w2048": decode_attention(8, 2048),
     "decode-attn-tp4-w256": decode_attention(2, 256),
-    # repaired: the fused dequant-matmul family (--prefill-kernel,
-    # --fused-matmul) at the serving buckets M = B, B*(1+k), prefill chunk
-    "repaired-matmul-m8-w2": matmul(8, DIM, HIDDEN),
-    "repaired-matmul-tp4-m40-w2": matmul(40, DIM, HIDDEN // 4),
-    "repaired-matmul-res-m8-wo": matmul_res(8, DIM, DIM),
-    "repaired-gated-m8-w13": gated(8, HIDDEN, DIM),
+    # the fused dequant-matmul at Llama-3-8B widths: a verify block's
+    # B(1+k) = 40 rows on the tp=4 slice of w2, two rows, the head in f32
+    "matmul-tp4-m40-w2": matmul(40, DIM, HIDDEN // 4),
+    "matmul-m2-wo": matmul(2, DIM, DIM),
+    "matmul-m8-wcls-f32": matmul(8, VOCAB, DIM, out=F32),
+    **CELL_MATMULS,
     # repaired: inline matvec VMEM at K=14336, and the prologue kernels
     "repaired-matvec-inline-w2": matvec_inline(DIM, HIDDEN),
     "repaired-prologue-rmsnorm-dim": (
