@@ -70,8 +70,6 @@ from distributed_llama_tpu.parallel.tp import (  # noqa: E402
     init_sharded_kv_cache, make_sharded_forward, shard_params)
 from distributed_llama_tpu.obs import trace as obs_trace  # noqa: E402
 from distributed_llama_tpu.ops.matmul import kernel_selections  # noqa: E402
-from distributed_llama_tpu.ops.pallas_prologue import (  # noqa: E402
-    prologue_supported)
 from distributed_llama_tpu.fleet.client import completion_request  # noqa: E402
 from distributed_llama_tpu.quants import QK, FloatType, QTensor  # noqa: E402
 
@@ -2572,12 +2570,6 @@ def main():
     ap.add_argument("--steps", type=int, default=32)
     ap.add_argument("--tp", type=int, default=1)
     ap.add_argument("--layout", choices=("i4p", "i8"), default="i4p")
-    ap.add_argument("--cache-write", choices=("inscan", "deferred"), default="deferred",
-                    help="KV cache discipline: 'inscan' carries the caches through "
-                         "the layer scan with per-layer in-place updates; 'deferred' "
-                         "keeps them loop-invariant and commits all layers' new rows "
-                         "in one top-level write (kills the carry copies the round-4 "
-                         "trace found)")
     ap.add_argument("--window", type=int, default=256,
                     help="attention window bucket (cache positions decode reads)")
     ap.add_argument("--device-loop", type=int, default=0, metavar="N",
@@ -2707,10 +2699,6 @@ def main():
     ap.add_argument("--no-fuse", action="store_true",
                     help="keep wq/wk/wv and w1/w3 as separate kernel launches "
                          "instead of the merged wqkv/w13 groups (A/B lever)")
-    ap.add_argument("--prologue", action="store_true",
-                    help="fused rmsnorm+quantize prologue kernels "
-                         "(ops/pallas_prologue.py) feeding the inline-Xexp "
-                         "matvec variants — opt-in until the hardware A/B lands")
     ap.add_argument("--kv-paged", type=int, default=0, metavar="R",
                     help="bench the paged (out-of-core) KV cache: hot ring of "
                          "R positions + host cold store, decode timed with "
@@ -2902,8 +2890,7 @@ def main():
         kc, vc = init_ring_cache(spec, resident, dtype=dtype)
         warm_step = make_sharded_forward(spec, mesh, params, dtype=dtype,
                                          use_pallas=on_tpu, donate_cache=True,
-                                         attn_window=None,
-                                         cache_write="deferred")
+                                         attn_window=None)
         paged_step = make_paged_step(spec, store, dtype=dtype,
                                      use_pallas=on_tpu)
         toks64 = jnp.ones((1, 64), jnp.int32)
@@ -2951,7 +2938,7 @@ def main():
     # the configuration asked for, and no other: a kernel that fails to lower
     # is an error with a non-zero exit code, not a weaker rung with a number
     state.update(
-        layout=layout, cache_write=args.cache_write, prologue=args.prologue,
+        layout=layout,
         # every kernel whose gate admits the shape on the chip, XLA off it
         use_pallas=on_tpu)
 
@@ -2988,9 +2975,7 @@ def main():
             step = make_sharded_forward(spec, mesh, params, dtype=dtype,
                                         use_pallas=state["use_pallas"],
                                         donate_cache=True,
-                                        attn_window=pwindow,
-                                        cache_write=state["cache_write"],
-                                        fused_prologue=state["prologue"])
+                                        attn_window=pwindow)
             logits, kc, vc = step(params, rope, toks, kc, vc, jnp.int32(0))  # compile
             logits.block_until_ready()
             return step, params, kc, vc
@@ -3009,9 +2994,8 @@ def main():
             "metric": metric_name(args), "value": round(tok_s, 1), "unit": "tok/s",
             "vs_baseline": vs_baseline(args, tok_s),
             "chunk": t_chunk, "weight_gb": round(state["wbytes"] / 1e9, 3),
-            "layout": state["layout"], "cache_write": state["cache_write"],
+            "layout": state["layout"],
             "ms_per_chunk": round(dt_all / n_disp * 1e3, 2),
-            "prologue": False,  # prologue is decode-only (t == 1)
         }
         # report the EFFECTIVE kernel engagement: the dequant-matmul gates
         # per-weight (q4_mm_supported), so an A/B record must say how much of
@@ -3058,9 +3042,7 @@ def main():
         def warm_bloop(params, kc, vc):
             loop = make_batched_decode_loop(
                 spec, mesh, params, K, mode="greedy", dtype=dtype,
-                use_pallas=state["use_pallas"], attn_window=window,
-                cache_write=state["cache_write"],
-                fused_prologue=state["prologue"])
+                use_pallas=state["use_pallas"], attn_window=window)
             toks, _tok, _pos, _, kc, vc = loop(
                 params, rope, ones_tok, kc, vc, np.zeros((B,), np.int32),
                 rng, zeros, zeros + 0.9, full_budget)  # compile + warm
@@ -3094,7 +3076,7 @@ def main():
             "ms_per_token_per_stream": round(dt_disp / K * 1e3, 3),
             "weight_gb": round(state["wbytes"] / 1e9, 3),
             "achieved_gbps": round(state["wbytes"] / 1e9 / (dt_disp / K), 1),
-            "layout": state["layout"], "cache_write": state["cache_write"],
+            "layout": state["layout"],
             "attn_window": window or spec.seq_len, "steps": args.steps,
             # which lowering each traced dispatch shape ACTUALLY took
             # (ops/matmul.py selection registry): a record of the kernels
@@ -3117,9 +3099,7 @@ def main():
         def warm_loop(params, kc, vc):
             loop = make_decode_loop(spec, mesh, params, chunk, mode="greedy",
                                     dtype=dtype, use_pallas=state["use_pallas"],
-                                    attn_window=window,
-                                    cache_write=state["cache_write"],
-                                    fused_prologue=state["prologue"])
+                                    attn_window=window)
             toks, _, kc, vc = loop(params, rope, 1, kc, vc, 0, key)  # compile + warm
             toks.block_until_ready()
             return loop, params, kc, vc
@@ -3139,9 +3119,7 @@ def main():
             step = make_sharded_forward(spec, mesh, params, dtype=dtype,
                                         use_pallas=state["use_pallas"],
                                         donate_cache=True,
-                                        attn_window=window,
-                                        cache_write=state["cache_write"],
-                                        fused_prologue=state["prologue"])
+                                        attn_window=window)
             logits, kc, vc = step(params, rope, tok, kc, vc, jnp.int32(0))  # compile
             logits.block_until_ready()
             return step, params, kc, vc
@@ -3172,16 +3150,10 @@ def main():
         "weight_gb": round(state["wbytes"] / 1e9, 3),
         "achieved_gbps": round(state["wbytes"] / 1e9 / dt, 1),
         "layout": state["layout"],
-        "cache_write": state["cache_write"],
         "attn_window": window or spec.seq_len,
         "device_loop": args.device_loop,
         "steps": args.steps,
         "fused": not args.no_fuse,  # the merged wqkv / w13 matvec groups
-        # report the EFFECTIVE prologue state: forward() re-gates it off for
-        # non-pallas runs and unsupported dims, and an A/B record claiming a
-        # lever that never engaged would corrupt the comparison
-        "prologue": bool(state["prologue"] and on_tpu
-                         and prologue_supported(spec.dim)),
         "kernel_policy": str(state["use_pallas"]),
         "kernels": sorted(set(kernel_selections().values())),
     }

@@ -1533,8 +1533,7 @@ def main(argv=None) -> None:
             slo_ttft_batch=args.slo_ttft_batch,
             slo_tpot_interactive=args.slo_tpot,
             tp=args.tp, dp=args.dp, pod=args.pod,
-            cache_write=args.cache_write, moe_sharding=args.moe_sharding,
-            fused_prologue=args.prologue, **policy_kwargs(args),
+            moe_sharding=args.moe_sharding, **policy_kwargs(args),
             compress_collectives=args.buffer_float_type == "q80" and (args.tp or 1) > 1)
         engine = None
         sampler = make_sampler(args, batch_engine.spec)

@@ -75,17 +75,6 @@ def build_parser(include_mode: bool = True) -> argparse.ArgumentParser:
                         "'expert' shards WHOLE experts (each chip owns E/tp experts "
                         "— the capacity axis for Grok-1-314B-class expert weights; "
                         "requires n_experts %% tp == 0)")
-    p.add_argument("--cache-write", default=None,
-                   choices=["deferred", "inscan"],
-                   help="KV cache discipline (models/forward.py): 'deferred' keeps "
-                        "the caches loop-invariant in the layer scan and commits new "
-                        "rows in one top-level write (avoids XLA TPU's whole-cache "
-                        "carry copies; works with --sp too); 'inscan' is the "
-                        "per-layer in-place form")
-    p.add_argument("--prologue", action="store_true", default=None,
-                   help="fused rmsnorm+quantize prologue kernels on the decode "
-                        "path (ops/pallas_prologue.py; also DLT_PROLOGUE=1) — "
-                        "opt-in until the hardware A/B lands")
     p.add_argument("--pipeline", action=argparse.BooleanOptionalAction,
                    default=True,
                    help="pipelined super-steps for batched serving (--batch "
@@ -281,8 +270,7 @@ def make_engine(args) -> Engine:
         tp=args.tp, sp=args.sp, pod=getattr(args, "pod", False),
         **policy_kwargs(args),
         compress_collectives=args.buffer_float_type == "q80" and (args.tp or 1) > 1,
-        cache_write=args.cache_write, moe_sharding=args.moe_sharding,
-        fused_prologue=args.prologue,
+        moe_sharding=args.moe_sharding,
         kv_cache_storage=args.kv_cache_storage,
         kv_cache_resident=args.kv_cache_resident,
         kv_cache_dir=args.kv_cache_dir,

@@ -1,7 +1,7 @@
 """Logits parity between two ways of running one checkpoint on this device set.
 
     python -m distributed_llama_tpu.apps.parity --model m.m [--steps 16]
-                                                [--tp N | --policy NAME]
+                                                [--tp N]
 
 Loads the checkpoint once and builds engines ONE AFTER THE OTHER (each is
 dropped before the next is placed, so one chip holds one model at a time). Every
@@ -17,8 +17,6 @@ so an argmax would compare noise.
     --tp N         tp=1 on one device against tp=N over N devices, both at the
                    default policy; also reports the sharded step's collective
                    counts and what each device holds.
-    --policy NAME  the default kernels against one opt-in kernel family
-                   (prologue: the entry points' flag of the same name).
 
 Each pair is compared twice, because one depth cannot do both jobs:
 
@@ -57,9 +55,8 @@ import numpy as np
 # the kernels. Floors measured on v5e at Llama-3-8B widths (PERF.md, Findings,
 # PR 21), and what a wrong result reads:
 #   shallow  kernels against XLA dequant sit 0.034 (rms 0.021) apart: bf16
-#            rounding plus the Q80 activations only the kernels quantize. An
-#            opt-in family sits 0.022 to 0.031 (rms 0.013 to 0.019) from the
-#            default kernels, tp=1 0.027 (rms 0.015) from tp=4. An eighth off on
+#            rounding plus the Q80 activations only the kernels quantize;
+#            tp=1 sits 0.027 (rms 0.015) from tp=4. An eighth off on
 #            one matrix of one layer reads 0.07 (rms 0.05) for wo or wv, the
 #            weakest, up to 0.12 for wq, wk or w1 of the first layer; only wq
 #            and wk of the LAST layer stay under the floor (0.034, rms 0.024
@@ -72,7 +69,6 @@ import numpy as np
 BOUNDS = {"shallow": (0.05, 0.035), "full": (0.25, 0.2)}
 CHUNK = 64  # the largest prefill bucket (runtime/engine.py PREFILL_CHUNKS)
 CANARY = ("wo", 1.125)  # matrix, and the factor on its first layer's scales
-POLICIES = {"prologue": dict(fused_prologue=True)}
 
 
 def shallow_cut(spec, params):
@@ -198,12 +194,8 @@ def main(argv=None) -> None:
     ap.add_argument("--steps", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--max-seq-len", type=int, default=0)
-    pair = ap.add_mutually_exclusive_group()
-    pair.add_argument("--tp", type=int, default=1,
-                      help="compare tp=1 on one device against tp=N")
-    pair.add_argument("--policy", choices=sorted(POLICIES),
-                      help="compare the default kernels against this opt-in "
-                           "kernel family")
+    ap.add_argument("--tp", type=int, default=1,
+                    help="compare tp=1 on one device against tp=N")
     args = ap.parse_args(argv)
 
     from ..platform_env import start
@@ -224,10 +216,6 @@ def main(argv=None) -> None:
     # the interpret request instead of quietly comparing XLA to XLA
     if args.tp > 1:
         arms = [("tp1", dict(tp=1)), (f"tp{args.tp}", dict(tp=args.tp))]
-    elif args.policy:
-        arms = [("kernels", dict(tp=1, use_pallas=True)),
-                (args.policy, dict(tp=1, use_pallas=True,
-                                   **POLICIES[args.policy]))]
     else:
         arms = [("kernels", dict(tp=1, use_pallas=True)),
                 ("xla", dict(tp=1, use_pallas=False))]

@@ -223,8 +223,7 @@ class ModelDrafter:
                 self.spec, self.mesh, self.params, dtype=self.dtype,
                 use_pallas=self.use_pallas,
                 compress_collectives=self.compress, donate_cache=True,
-                attn_window=None, cache_write="deferred",
-                moe_sharding=self.moe_sharding)
+                attn_window=None, moe_sharding=self.moe_sharding)
         return self._step
 
     def reset_backend(self) -> None:

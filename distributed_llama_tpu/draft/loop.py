@@ -95,7 +95,7 @@ def make_draft_loop(spec: ModelSpec, mesh, params, steps: int, *,
                             sp_axis_name=None, sp_size=1,
                             use_pallas=use_pallas,
                             compress_collectives=compress_collectives,
-                            attn_window=None, cache_write="deferred")
+                            attn_window=None)
 
     # hot-path: traced
     def loop(p, rope_cos, rope_sin, catchup, kc, vc, start_pos, ncatch,

@@ -35,11 +35,10 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import gqa_attention, update_kv_cache
+from ..ops.attention import gqa_attention
 from ..ops.kernels import ACTS, rmsnorm
-from ..ops.matmul import LayerOf, qmatmul, qmatmul_q80, reads_the_stack
-from ..ops.ring_attention import (commit_kv_rows_sharded, ring_attention,
-                                  update_kv_cache_sharded)
+from ..ops.matmul import LayerOf, qmatmul, reads_the_stack
+from ..ops.ring_attention import commit_kv_rows_sharded, ring_attention
 from ..ops.rope import RopeTables, apply_rope
 from .spec import ArchType, HiddenAct, ModelSpec, RouterInput
 
@@ -95,10 +94,26 @@ def _maybe_psum(x: jax.Array, axis_name: str | None, compress: bool = False) -> 
 
 def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, start_pos,
                positions, axis_name, sp_axis_name, sp_size, use_pallas, compress,
-               window, deferred_write=False, prologue=False, paged_cold=None,
-               block_tables=None, block_tokens=0, paged_kernel=False,
-               residual=None, rope_on=None, swa=None):
-    """Sharded attention sub-block against the FULL stacked caches (L, B, hk, S, hs).
+               window, paged_cold=None, block_tables=None, block_tokens=0,
+               paged_kernel=False, residual=None, rope_on=None, swa=None):
+    """Sharded attention sub-block against the FULL stacked caches, which it
+    only READS: they are loop-invariant operands of the layer scan, the
+    chunk's own k/v are attended from registers, and the new rows
+    (k_t, v_t), each (B, hk, T, hs), are returned for forward() to commit
+    after the scan. (XLA TPU copies a scan carry that is
+    dynamic-update-sliced at a loop-varying index whole at the step
+    boundary; a read-only operand has no such hazard.)
+
+    The one `if` chain below selects by CACHE KIND:
+    - sp ring: the sequence axis is sharded over sp, STRIPED (member m's
+      slot j holds position j * sp + m), and the KV blocks rotate around
+      the ring (ops/ring_attention.py);
+    - host/disc ring (`paged_cold`): the device holds the R most recent
+      positions, the host store the rest (runtime/paged_cache.py);
+    - block pool (`block_tables`): (L, N, hk, bt, hs) blocks behind per-row
+      tables (docs/PAGED_KV.md), read by the Pallas kernel or an XLA gather;
+    - contiguous (L, B, hk, S, hs), through the fused decode kernel where
+      the dispatch is one row of one token.
 
     rope_on / swa: this layer's kind, traced scalars out of the layer scan's
     xs, or None where the model has one kind of layer (the program is then
@@ -114,41 +129,22 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
     attn_out is ALREADY residual-joined (residual + wo-projection, after the
     TP merge): callers must not re-add.
 
-    Head counts in bp may be TP-local slices; the cache sequence axis may be sp-sharded
-    (ring attention). The cache WRITE discipline depends on the caller: in-scan mode
-    updates (layer_idx, :, :, pos) in place and returns the caches; deferred mode
-    returns only the new (k_t, v_t) rows for forward() to commit after the scan.
-    Either way decode's READ is only the first `window` positions (a static bucket
-    >= pos+T chosen by the caller), so cache HBM traffic scales with the live
-    context, not the allocated seq_len. The reference gets the same effect for free
-    because its attention loop runs 0..pos (llama2-tasks.cpp:62-93); with XLA's
-    static shapes the window bucket is the equivalent lever.
+    Head counts in bp may be TP-local slices. The READ covers only the first
+    `window` positions (a static bucket >= pos+T chosen by the caller), so
+    cache HBM traffic scales with the live context, not the allocated
+    seq_len. The reference gets the same effect for free because its
+    attention loop runs 0..pos (llama2-tasks.cpp:62-93); with XLA's static
+    shapes the window bucket is the equivalent lever.
     """
     b, t, _ = x.shape
     hs = spec.head_size
     _, _, hk, s, _ = kc.shape
-    if prologue:
-        # fused rmsnorm+quantize prologue kernel (ops/pallas_prologue.py): the
-        # norm and the Q80 activation quantization every decode matvec needs
-        # collapse into one VPU pass, and the quantized row feeds the inline-Xexp
-        # matvec directly (qmatmul_q80)
-        from ..ops.pallas_prologue import rmsnorm_quantize_q80
-
-        xq, sx = rmsnorm_quantize_q80(x, bp["rms_att"], spec.norm_eps)
-
-        def project(wname):
-            return qmatmul_q80(xq, sx, bp[wname], use_pallas=use_pallas,
-                               out_dtype=x.dtype)
-    else:
-        xb = rmsnorm(x, bp["rms_att"], spec.norm_eps)
-
-        def project(wname):
-            return qmatmul(xb, bp[wname], use_pallas=use_pallas)
+    xb = rmsnorm(x, bp["rms_att"], spec.norm_eps)
     if "wqkv" in bp:
         # merged QKV (models/params.py fuse_matvec_groups): ONE kernel launch for
         # all three projections. Local row counts split proportionally to the
         # global dim : kv : kv ratio (exact — every term divides by tp).
-        qkv = project("wqkv")
+        qkv = qmatmul(xb, bp["wqkv"], use_pallas=use_pallas)
         total = qkv.shape[-1]
         lq = total * spec.q_dim // (spec.q_dim + 2 * spec.kv_dim)
         lkv = (total - lq) // 2
@@ -156,25 +152,9 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
         k = qkv[..., lq:lq + lkv]
         v = qkv[..., lq + lkv:]
     else:
-        q = project("wq")
-        k = project("wk")
-        v = project("wv")
-
-    def project_out(att):
-        """wo projection + TP merge; under the prologue the attention output is
-        quantized by the fused kernel instead of inside the matvec. The TP-local
-        row width (hq_local*hs) is re-checked — the forward()-level gate only
-        validated spec.dim."""
-        from ..ops.pallas_prologue import prologue_supported, quantize_q80_row
-
-        if prologue and prologue_supported(att.shape[-1]):
-            aq, asx = quantize_q80_row(att)
-            y = qmatmul_q80(aq, asx, bp["wo"], use_pallas=use_pallas,
-                            out_dtype=x.dtype)
-        else:
-            y = qmatmul(att, bp["wo"], use_pallas=use_pallas)
-        y = _maybe_psum(y, axis_name, compress)
-        return y if residual is None else residual + y
+        q = qmatmul(xb, bp["wq"], use_pallas=use_pallas)
+        k = qmatmul(xb, bp["wk"], use_pallas=use_pallas)
+        v = qmatmul(xb, bp["wv"], use_pallas=use_pallas)
     hq_local = q.shape[-1] // hs
     hk_local = k.shape[-1] // hs
     q = q.reshape(b, t, hq_local, hs)
@@ -192,49 +172,24 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
         sp_axis_name is not None and sp_size > 1)), (
         "a sliding window is not supported with sp (ring) sharding or "
         "host/disc KV paging")
+    k_t = jnp.swapaxes(k, 1, 2).astype(kc.dtype)  # (B, hk, T, hs)
+    v_t = jnp.swapaxes(v, 1, 2).astype(vc.dtype)
     if sp_axis_name is not None and sp_size > 1:
-        # sequence parallelism: each sp member keeps its slice of the cache and the
-        # KV blocks rotate around the ring (ops/ring_attention.py).
-        if deferred_write:
-            # deferred discipline on the sp path: the sharded caches stay
-            # loop-invariant (read-only — no full-local-slice carry copies); the
-            # ring attends COMMITTED rows only (live_end) plus the current
-            # chunk's K/V as a register block, and the new rows ride out as scan
-            # ys for forward() to commit with ONE masked window write per cache
-            # (ops/ring_attention.py commit_kv_rows_sharded).
-            #
-            # The deferred sp cache is STRIPED (member m's slot j = position
-            # j*sp + m): the live context occupies the same slot prefix on every
-            # member, so a static window bucket bounds each rotation to
-            # ceil(window/sp) columns — ICI and HBM per step track the LIVE
-            # context, not the allocated seq_len (the sp analog of attn_window;
-            # impossible under contiguous sharding, where the live prefix
-            # concentrates on low-index members).
-            k_t = jnp.swapaxes(k, 1, 2).astype(kc.dtype)  # (B, hk, T, hs)
-            v_t = jnp.swapaxes(v, 1, 2).astype(vc.dtype)
-            kl = jax.lax.dynamic_slice(kc, (layer_idx, 0, 0, 0, 0),
-                                       (1, b, hk, s, hs))[0]
-            vl = jax.lax.dynamic_slice(vc, (layer_idx, 0, 0, 0, 0),
-                                       (1, b, hk, s, hs))[0]
-            wl = (None if window is None
-                  else min((window + sp_size - 1) // sp_size, s))
-            att = ring_attention(q, kl, vl, positions, axis_name=sp_axis_name,
-                                 axis_size=sp_size, live_end=start_pos,
-                                 chunk=(k_t, v_t, start_pos), striped=True,
-                                 window_slots=wl)
-            attn_out = project_out(att)
-            return attn_out, (k_t, v_t)  # new rows only; caller commits post-scan
-        # in-scan form: layer slice out, sharded update, full-layer write-back
-        # (the ring path reads the whole local slice anyway)
-        kl = jax.lax.dynamic_slice(kc, (layer_idx, 0, 0, 0, 0), (1, b, hk, s, hs))[0]
-        vl = jax.lax.dynamic_slice(vc, (layer_idx, 0, 0, 0, 0), (1, b, hk, s, hs))[0]
-        kl, vl = update_kv_cache_sharded(kl, vl, k, v, start_pos,
-                                         axis_name=sp_axis_name)
+        # The ring attends COMMITTED rows only (live_end) plus the current
+        # chunk's K/V as a register block. Striped, the live context
+        # occupies the same slot prefix on every member, so a static window
+        # bucket bounds each rotation to ceil(window/sp) columns: ICI and
+        # HBM per step track the LIVE context, not the allocated seq_len.
+        kl = jax.lax.dynamic_slice(kc, (layer_idx, 0, 0, 0, 0),
+                                   (1, b, hk, s, hs))[0]
+        vl = jax.lax.dynamic_slice(vc, (layer_idx, 0, 0, 0, 0),
+                                   (1, b, hk, s, hs))[0]
+        wl = (None if window is None
+              else min((window + sp_size - 1) // sp_size, s))
         att = ring_attention(q, kl, vl, positions, axis_name=sp_axis_name,
-                             axis_size=sp_size)
-        kc = jax.lax.dynamic_update_slice(kc, kl[None], (layer_idx, 0, 0, 0, 0))
-        vc = jax.lax.dynamic_update_slice(vc, vl[None], (layer_idx, 0, 0, 0, 0))
-    elif deferred_write and paged_cold is not None:
+                             axis_size=sp_size, live_end=start_pos,
+                             chunk=(k_t, v_t, start_pos), window_slots=wl)
+    elif paged_cold is not None:
         # Paged (out-of-core) cache: the device cache's S axis is a RING of the
         # R most recent positions (slot = position mod R); everything older lives
         # in the host store, and its attention contribution arrives as a
@@ -244,8 +199,6 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
         # the reference's mmap'd disk KV cache (transformer.cpp:312-318): same
         # capacity valve, but the resident window stays HBM-fast and only the
         # cold history pays host bandwidth.
-        k_t = jnp.swapaxes(k, 1, 2).astype(kc.dtype)  # (B, hk, T, hs)
-        v_t = jnp.swapaxes(v, 1, 2).astype(vc.dtype)
         kl = jax.lax.dynamic_slice(kc, (layer_idx, 0, 0, 0, 0), (1, b, hk, s, hs))[0]
         vl = jax.lax.dynamic_slice(vc, (layer_idx, 0, 0, 0, 0), (1, b, hk, s, hs))[0]
         # slot j's most recent committed position: p_j = j + R*floor((pos-1-j)/R)
@@ -264,22 +217,14 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
         out_c, lse_c = paged_cold(layer_idx, q.astype(jnp.float32), start_pos)
         att = merge_attention_partials(out_h, lse_h, out_c, lse_c)
         att = att.reshape(b, t, hq_local * hs).astype(x.dtype)
-        attn_out = project_out(att)
-        return attn_out, (k_t, v_t)  # caller commits into ring slots (mod R)
-    elif deferred_write and block_tables is not None:
-        # Device-resident paged KV (docs/PAGED_KV.md): the caches are a
-        # BLOCK POOL (L, N, hk, bt, hs) and each row's block table maps
-        # virtual positions to pool blocks. Two readers, same semantics:
-        # the Pallas kernel copies the table's blocks under each row's
-        # committed length pool→VMEM, 128 keys a step
-        # (ops/pallas_paged_attention.py); the
-        # XLA fallback gathers the table into the dense window layout and
-        # runs the SAME gqa_attention as the dense deferred branch — so on
-        # the CPU mesh paged logits are bit-identical to dense logits
-        # (the paged-vs-dense token-identity bar, tests/test_paged_kv.py).
-        # Writes commit post-scan through the same table (forward() below).
-        k_t = jnp.swapaxes(k, 1, 2).astype(kc.dtype)  # (B, hk, T, hs)
-        v_t = jnp.swapaxes(v, 1, 2).astype(vc.dtype)
+    elif block_tables is not None:
+        # Two readers, same semantics: the Pallas kernel copies the table's
+        # blocks under each row's committed length pool→VMEM, 128 keys a step
+        # (ops/pallas_paged_attention.py); the XLA fallback gathers the table
+        # into the dense window layout and runs the SAME gqa_attention as
+        # the contiguous branch — so on the CPU mesh paged logits are
+        # bit-identical to dense logits (the paged-vs-dense token-identity
+        # bar, tests/test_paged_kv.py).
         w_total = block_tables.shape[1]
         win = window or (w_total * block_tokens)
         nb = min(-(-win // block_tokens), w_total)
@@ -297,7 +242,7 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
             vwin = nb * block_tokens
             slot = jnp.arange(vwin)
             # same committed-rows masking (and sentinel arithmetic) as the
-            # dense per-row deferred branch below — a table entry past the
+            # contiguous per-row branch below — a table entry past the
             # row's committed length is scratch/garbage and masks out
             slot_pos = jnp.where(slot[None, :] < start_pos[:, None],
                                  slot[None, :], spec.seq_len + 1)  # (B, vwin)
@@ -308,39 +253,22 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
                                 jnp.concatenate([vw, v_t], axis=2),
                                 positions, key_positions=key_pos,
                                 key_lo=key_lo)
-        attn_out = project_out(att)
-        return attn_out, (k_t, v_t)  # new rows only; caller commits post-scan
-    elif deferred_write:
-        # deferred-write path: the caches are loop-INVARIANT inside the layer scan —
-        # attention reads the window of COMMITTED rows (positions < start_pos) and
-        # attends to the current chunk's k/v directly from registers; the new rows
-        # ride out of the scan as stacked ys and forward() commits all layers with
-        # ONE top-level dynamic_update_slice per cache. Motivation: a scan carry
-        # that is dynamic-update-sliced at a loop-varying layer index defeats XLA
-        # TPU's in-place while-loop buffer optimization — the round-4 trace shows
-        # the full (L,B,hk,S,hs) caches being copied at the step boundary
-        # (~11.6 ms/token at 7B, a third of the step). A read-only operand has no
-        # copy-on-write hazard.
-        k_t = jnp.swapaxes(k, 1, 2).astype(kc.dtype)  # (B, hk, T, hs)
-        v_t = jnp.swapaxes(v, 1, 2).astype(vc.dtype)
-        win = window or s
-        # windows past the single-block VMEM budget take the kernel's window-
-        # tiled form (flash-attention carry in scratch, ops/pallas_attention.py)
-        # — long contexts never fall back to XLA slicing mid-generation
-        if (use_pallas and t == 1 and b == 1 and start_pos.ndim == 0
-                and key_lo is None):
-            # fused decode kernel: the cache window is DMA'd straight out of the
-            # stacked buffers inside the kernel (ops/pallas_attention.py) — no
-            # per-layer dynamic-slice materialization in XLA at all
-            from ..ops.pallas_attention import fused_decode_attention
+    elif (use_pallas and t == 1 and b == 1 and start_pos.ndim == 0
+            and key_lo is None):
+        # fused decode kernel: the cache window is DMA'd straight out of the
+        # stacked buffers inside the kernel (ops/pallas_attention.py) — no
+        # per-layer dynamic-slice materialization in XLA at all. Windows
+        # past the single-block VMEM budget take its window-tiled form, so
+        # long contexts never fall back to XLA slicing mid-generation.
+        from ..ops.pallas_attention import fused_decode_attention
 
-            g = hq_local // hk
-            out = fused_decode_attention(
-                q.reshape(hk, g, hs).astype(jnp.float32), kc, vc,
-                k_t[0], v_t[0], layer_idx, start_pos, window=win)
-            att = out.reshape(1, 1, hq_local * hs).astype(x.dtype)
-            attn_out = project_out(att)
-            return attn_out, (k_t, v_t)
+        g = hq_local // hk
+        out = fused_decode_attention(
+            q.reshape(hk, g, hs).astype(jnp.float32), kc, vc,
+            k_t[0], v_t[0], layer_idx, start_pos, window=window or s)
+        att = out.reshape(1, 1, hq_local * hs).astype(x.dtype)
+    else:
+        win = window or s
         kw = jax.lax.dynamic_slice(kc, (layer_idx, 0, 0, 0, 0), (1, b, hk, win, hs))[0]
         vw = jax.lax.dynamic_slice(vc, (layer_idx, 0, 0, 0, 0), (1, b, hk, win, hs))[0]
         # window slot j holds a committed row iff j < start_pos; stale slots get a
@@ -359,78 +287,31 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
         vfull = jnp.concatenate([vw, v_t], axis=2)
         att = gqa_attention(q, kfull, vfull, positions, key_positions=key_pos,
                             key_lo=key_lo)
-        attn_out = project_out(att)
-        return attn_out, (k_t, v_t)  # new rows only; caller commits post-scan
-    elif start_pos.ndim == 1:
-        # per-row offsets (continuous batching): vmap'd per-row write on the layer
-        # slice, then full-layer write-back
-        kl = jax.lax.dynamic_slice(kc, (layer_idx, 0, 0, 0, 0), (1, b, hk, s, hs))[0]
-        vl = jax.lax.dynamic_slice(vc, (layer_idx, 0, 0, 0, 0), (1, b, hk, s, hs))[0]
-        kl, vl = update_kv_cache(kl, vl, k, v, start_pos)
-        win = window or s
-        att = gqa_attention(q, kl[:, :, :win], vl[:, :, :win], positions,
-                            key_lo=key_lo)
-        kc = jax.lax.dynamic_update_slice(kc, kl[None], (layer_idx, 0, 0, 0, 0))
-        vc = jax.lax.dynamic_update_slice(vc, vl[None], (layer_idx, 0, 0, 0, 0))
-    else:
-        # in-scan path: tiny in-place write at (layer, :, :, pos), windowed read
-        k_t = jnp.swapaxes(k, 1, 2).astype(kc.dtype)[None]  # (1, B, hk, T, hs)
-        v_t = jnp.swapaxes(v, 1, 2).astype(vc.dtype)[None]
-        kc = jax.lax.dynamic_update_slice(kc, k_t, (layer_idx, 0, 0, start_pos, 0))
-        vc = jax.lax.dynamic_update_slice(vc, v_t, (layer_idx, 0, 0, start_pos, 0))
-        win = window or s
-        kw = jax.lax.dynamic_slice(kc, (layer_idx, 0, 0, 0, 0), (1, b, hk, win, hs))[0]
-        vw = jax.lax.dynamic_slice(vc, (layer_idx, 0, 0, 0, 0), (1, b, hk, win, hs))[0]
-        att = gqa_attention(q, kw, vw, positions, key_lo=key_lo)
     # col-parallel wo: local heads x local input slice -> partial (B, T, dim); psum merges
-    attn_out = project_out(att)
-    return attn_out, (kc, vc)
+    y = _maybe_psum(qmatmul(att, bp["wo"], use_pallas=use_pallas), axis_name,
+                    compress)
+    return (y if residual is None else residual + y), (k_t, v_t)
 
 
 def _dense_ffn(x, bp, spec: ModelSpec, axis_name, use_pallas, compress,
-               prologue=False, residual=None):
+               residual=None):
     """Dense FFN on the PRE-norm block input x (the rms_ffn norm is applied
-    here so the prologue can fuse it with the activation quantize). One body
-    for both modes — only the projection primitive differs: under the prologue
-    each activation row is quantized by a fused kernel (ops/pallas_prologue.py)
-    and qmatmul_q80 consumes the pre-quantized row; otherwise the matvecs
-    quantize internally. TP-local widths are re-checked before each prologue
-    kernel — the forward()-level gate only validated spec.dim.
+    here).
 
     residual: optional (B, T, dim); when given the return value is ALREADY
     residual + ffn(x), joined after the TP merge. Callers must not re-add."""
     act = _act(spec)
-    if prologue:
-        from ..ops.pallas_prologue import (prologue_supported, quantize_q80_row,
-                                           rmsnorm_quantize_q80)
-
-        xq, sx = rmsnorm_quantize_q80(x, bp["rms_ffn"], spec.norm_eps)
-
-        def project(wname):
-            return qmatmul_q80(xq, sx, bp[wname], use_pallas=use_pallas,
-                               out_dtype=jnp.float32)
-
-        if "w13" in bp:
-            h = _gated_split(project("w13"), act, gate_first=True)
-        else:
-            h = act(project("w1")) * project("w3")
+    xb = rmsnorm(x, bp["rms_ffn"], spec.norm_eps)
+    if "w13" in bp:
+        # merged gate+up (fuse_matvec_groups): one launch per TP group;
+        # the packed stream is already one pass, only the act·mul epilogue
+        # stays un-fused on this layout
+        h = _gated_split(qmatmul(xb, bp["w13"], use_pallas=use_pallas),
+                         act, gate_first=True)
     else:
-        xb = rmsnorm(x, bp["rms_ffn"], spec.norm_eps)
-        if "w13" in bp:
-            # merged gate+up (fuse_matvec_groups): one launch per TP group;
-            # the packed stream is already one pass, only the act·mul epilogue
-            # stays un-fused on this layout
-            h = _gated_split(qmatmul(xb, bp["w13"], use_pallas=use_pallas),
-                             act, gate_first=True)
-        else:
-            h = (act(qmatmul(xb, bp["w1"], use_pallas=use_pallas))
-                 * qmatmul(xb, bp["w3"], use_pallas=use_pallas))
-    if prologue and prologue_supported(h.shape[-1]):
-        hq, hsx = quantize_q80_row(h)
-        out = qmatmul_q80(hq, hsx, bp["w2"], use_pallas=use_pallas,
-                          out_dtype=x.dtype)
-    else:
-        out = qmatmul(h.astype(x.dtype), bp["w2"], use_pallas=use_pallas)
+        h = (act(qmatmul(xb, bp["w1"], use_pallas=use_pallas))
+             * qmatmul(xb, bp["w3"], use_pallas=use_pallas))
+    out = qmatmul(h.astype(x.dtype), bp["w2"], use_pallas=use_pallas)
     out = _maybe_psum(out, axis_name, compress)
     return out if residual is None else residual + out
 
@@ -586,29 +467,17 @@ def _moe_ffn(xb, bp, spec: ModelSpec, axis_name, use_pallas, compress,
     return _maybe_psum(out, axis_name, compress), stats
 
 
-def _block(carry, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions,
+def _block(x, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions,
            axis_name, sp_axis_name, sp_size, use_pallas, compress, window,
-           kc_ro=None, vc_ro=None, prologue=False, paged_cold=None,
-           block_tables=None, block_tokens=0, paged_kernel=False,
-           stacks=None):
-    """One transformer block as a scan step. `stacks`: the weights that stay
-    whole over the scan (forward() below), named into `bp` as `LayerOf`
-    this layer's index. Two cache disciplines:
-
-    - in-scan (kc_ro is None): caches travel in the carry and are updated in place
-      per layer — carry (x, kc, vc).
-    - deferred (kc_ro/vc_ro set): caches are read-only closures (loop invariants);
-      carry is just x and the layer's new K/V rows leave as ys for forward() to
-      commit in one top-level write.
-
-    Either way the ys carry the layer's stats of _moe_ffn (zeros where the
-    block routes nothing), last.
+           kc, vc, paged_cold=None, block_tables=None, block_tokens=0,
+           paged_kernel=False, stacks=None):
+    """One transformer block as a scan step: the carry is x, the caches kc/vc
+    are read-only closures (loop invariants), and the ys are the layer's new
+    K/V rows, for forward() to commit in one top-level write, with the
+    layer's stats of _moe_ffn last (zeros where the block routes nothing).
+    `stacks`: the weights that stay whole over the scan (forward() below),
+    named into `bp` as `LayerOf` this layer's index.
     """
-    deferred = kc_ro is not None
-    if deferred:
-        x, kc, vc = carry, kc_ro, vc_ro
-    else:
-        x, kc, vc = carry
     stats = jnp.zeros((N_MOE_STATS,), jnp.int32)  # of the expert layer: a ys
     # the layer's kind rides in the xs beside its index, where the model has
     # layers of more than one kind (forward() below)
@@ -628,15 +497,12 @@ def _block(carry, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions
     # residual is given)
     res_attn = None if spec.arch_type == ArchType.GROK1 else x
     with jax.named_scope("attn"):
-        attn_out, kvout = _attention(
+        attn_out, (k_t, v_t) = _attention(
             x, bp, layer_idx, spec, rope, kc, vc, start_pos, positions,
             axis_name, sp_axis_name, sp_size, use_pallas, compress, window,
-            deferred_write=deferred, prologue=prologue, paged_cold=paged_cold,
-            block_tables=block_tables, block_tokens=block_tokens,
-            paged_kernel=paged_kernel, residual=res_attn, rope_on=rope_on,
-            swa=swa)
-    if not deferred:
-        kc, vc = kvout
+            paged_cold=paged_cold, block_tables=block_tables,
+            block_tokens=block_tokens, paged_kernel=paged_kernel,
+            residual=res_attn, rope_on=rope_on, swa=swa)
     if spec.arch_type == ArchType.GROK1:
         # grok: residual-join the *normalized* attention output (grokRmfFfn/Norm/Join)
         x = x + rmsnorm(attn_out, bp["rms_ffn"], spec.norm_eps)
@@ -653,10 +519,8 @@ def _block(carry, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions
             x = x + moe_out
         else:
             x = _dense_ffn(x, bp, spec, axis_name, use_pallas, compress,
-                           prologue=prologue, residual=x)
-    if deferred:
-        return x, (*kvout, stats)  # ys: this layer's (k_t, v_t) new rows
-    return (x, kc, vc), stats
+                           residual=x)
+    return x, (k_t, v_t, stats)
 
 
 def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
@@ -664,8 +528,7 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
             start_pos: jax.Array, *, dtype=jnp.float32, axis_name: str | None = None,
             sp_axis_name: str | None = None, sp_size: int = 1,
             use_pallas: bool = False, compress_collectives: bool = False,
-            attn_window: int | None = None, cache_write: str = "inscan",
-            fused_prologue: bool = False, paged_cold=None,
+            attn_window: int | None = None, paged_cold=None,
             block_tables=None, block_tokens: int = 0,
             paged_kernel: bool = False, moe_stats: bool = False):
     """Run T tokens through the model against the KV cache.
@@ -691,21 +554,12 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
     later tokens overwrite them. The batched decode scan
     (runtime/device_loop.py) parks finished rows on the same invariant.
 
-    cache_write selects the cache discipline:
-    - "inscan": caches are scan CARRIES, updated in place per layer at a dynamic
-      layer index — NOT scan xs/ys, which would restack (read+write) the full
-      (L, B, hk, S, hs) buffers every step (~4 GB/token at 7B/2048, measured as
-      half the step time in round 3).
-    - "deferred": caches are loop-INVARIANT operands of the scan (read-only);
-      each layer's new K/V rows leave as ys ((L, B, hk, T, hs), tiny) and ONE
-      top-level dynamic_update_slice per cache commits them after the scan.
-      Motivation: the round-4 TPU trace shows the in-scan carries being copied
-      whole at the step boundary (~11.6 ms/token at 7B) — XLA TPU's in-place
-      while-buffer optimization does not fire for a carry that is
-      dynamic-update-sliced at a loop-varying index. Under sp the same
-      discipline applies to the sequence-sharded caches: the ring attends
-      committed rows + the chunk's K/V as a register block, and the commit is
-      a masked window write into the owning shard (commit_kv_rows_sharded).
+    The caches are loop-INVARIANT operands of the layer scan (read-only, see
+    _attention); each layer's new K/V rows leave as ys ((L, B, hk, T, hs),
+    tiny) and ONE write per cache commits them after the scan, by cache kind:
+    a scatter through the block tables, ring slots mod R (host/disc paging),
+    a masked window write into the owning sp shard
+    (commit_kv_rows_sharded), else a dynamic_update_slice at start_pos.
 
     attn_window: static bound on cache positions attention reads (must cover
     start_pos + T). None reads the full seq_len. Callers bucket it (Engine) so decode
@@ -727,29 +581,18 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
     if spec.arch_type == ArchType.GROK1:
         x = x * GROK_EMBEDDING_SCALE
 
-    assert cache_write in ("inscan", "deferred"), cache_write
-    deferred = cache_write == "deferred"
     sp_active = sp_axis_name is not None and sp_size > 1
     if paged_cold is not None:
-        assert deferred and not sp_active and start_pos.ndim == 0, (
-            "paged KV cache requires the deferred discipline, no sp sharding, "
-            "and a scalar start_pos")
+        assert not sp_active and start_pos.ndim == 0, (
+            "paged KV cache requires no sp sharding and a scalar start_pos")
         assert t <= k_cache.shape[3], (
             f"chunk {t} exceeds the {k_cache.shape[3]}-slot resident ring")
     if block_tables is not None:
-        assert deferred and not sp_active and paged_cold is None, (
-            "device-resident paged KV requires the deferred discipline and "
-            "no sp sharding / host-spill paging")
+        assert not sp_active and paged_cold is None, (
+            "device-resident paged KV requires no sp sharding / host-spill "
+            "paging")
         assert block_tokens >= 1 and start_pos.ndim == 1, (
             "paged KV needs block_tokens and per-row start_pos")
-    # fused rmsnorm+quantize prologue (ops/pallas_prologue.py): single-row decode
-    # only (the kernels take one activation row), opt-in via fused_prologue
-    if fused_prologue:
-        from ..ops.pallas_prologue import prologue_supported
-
-        fused_prologue = (use_pallas and t == 1 and tokens.shape[0] == 1
-                          and start_pos.ndim == 0
-                          and prologue_supported(spec.dim))
     # the weights the fused dequant-matmul reads stay out of the scan's
     # sliced operands: it takes its blocks from the whole stack at the
     # layer's index, and a slice would be a copy of the layer's weights
@@ -759,10 +602,8 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
                                  positions=positions, axis_name=axis_name,
                                  sp_axis_name=sp_axis_name, sp_size=sp_size,
                                  use_pallas=use_pallas, compress=compress_collectives,
-                                 window=attn_window,
-                                 kc_ro=k_cache if deferred else None,
-                                 vc_ro=v_cache if deferred else None,
-                                 prologue=fused_prologue, paged_cold=paged_cold,
+                                 window=attn_window, kc=k_cache, vc=v_cache,
+                                 paged_cold=paged_cold,
                                  block_tables=block_tables,
                                  block_tokens=block_tokens,
                                  paged_kernel=paged_kernel, stacks=stacks)
@@ -773,54 +614,50 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
         # layers of more than one kind in ONE scan: the kind is data
         xs += (jnp.asarray(spec.layer_rope(), jnp.int32),
                jnp.asarray(spec.layer_window(), jnp.int32))
-    if deferred:
-        x, (k_rows, v_rows, stats) = jax.lax.scan(block_fn, x, xs)
-        # commit all layers' new rows in one write per cache: (L, B, hk, T, hs)
-        # lands at [.., .., .., start_pos : start_pos+T, ..]
-        if block_tables is not None:
-            # paged commit: position p of row b lands in pool block
-            # tables[b, p // bt] at offset p % bt — one scatter per cache,
-            # through the same table the read path consumed. Out-of-range
-            # positions cannot occur by scheduler invariant (coverage is
-            # ensured pre-dispatch; parked rows clamp below seq_len).
-            pos_bt = positions  # (B, T) absolute positions
-            blk = jnp.take_along_axis(
-                block_tables, jnp.minimum(pos_bt // block_tokens,
-                                          block_tables.shape[1] - 1), axis=1)
-            off = pos_bt % block_tokens  # (B, T)
-            k_cache = k_cache.at[:, blk, :, off, :].set(
-                jnp.transpose(k_rows, (1, 3, 0, 2, 4)))
-            v_cache = v_cache.at[:, blk, :, off, :].set(
-                jnp.transpose(v_rows, (1, 3, 0, 2, 4)))
-        elif paged_cold is not None:
-            # ring commit: position p lands in slot p mod R (scatter — the
-            # chunk may wrap the ring boundary). The rows being overwritten
-            # need no flush: the HOST store is authoritative for every
-            # committed position (Engine writes the same rows there).
-            ring = k_cache.shape[3]
-            idx = (start_pos + jnp.arange(t)) % ring
-            k_cache = k_cache.at[:, :, :, idx, :].set(k_rows)
-            v_cache = v_cache.at[:, :, :, idx, :].set(v_rows)
-        elif sp_active:
-            # sequence-sharded caches: masked window write into the owning
-            # shards, striped layout (see the _attention sp-deferred branch)
-            k_cache, v_cache = commit_kv_rows_sharded(
-                k_cache, v_cache, k_rows, v_rows, start_pos,
-                axis_name=sp_axis_name, striped=True, axis_size=sp_size)
-        elif start_pos.ndim == 0:
-            k_cache = jax.lax.dynamic_update_slice(
-                k_cache, k_rows, (0, 0, 0, start_pos, 0))
-            v_cache = jax.lax.dynamic_update_slice(
-                v_cache, v_rows, (0, 0, 0, start_pos, 0))
-        else:  # per-row offsets: vmap the write over the batch axis
-            row_write = jax.vmap(
-                lambda c, n, p: jax.lax.dynamic_update_slice(c, n, (0, 0, p, 0)),
-                in_axes=(1, 1, 0), out_axes=1)
-            k_cache = row_write(k_cache, k_rows, start_pos)
-            v_cache = row_write(v_cache, v_rows, start_pos)
-    else:
-        (x, k_cache, v_cache), stats = jax.lax.scan(
-            block_fn, (x, k_cache, v_cache), xs)
+    x, (k_rows, v_rows, stats) = jax.lax.scan(block_fn, x, xs)
+    # commit all layers' new rows in one write per cache: (L, B, hk, T, hs)
+    # lands at [.., .., .., start_pos : start_pos+T, ..]
+    if block_tables is not None:
+        # paged commit: position p of row b lands in pool block
+        # tables[b, p // bt] at offset p % bt — one scatter per cache,
+        # through the same table the read path consumed. Out-of-range
+        # positions cannot occur by scheduler invariant (coverage is
+        # ensured pre-dispatch; parked rows clamp below seq_len).
+        pos_bt = positions  # (B, T) absolute positions
+        blk = jnp.take_along_axis(
+            block_tables, jnp.minimum(pos_bt // block_tokens,
+                                      block_tables.shape[1] - 1), axis=1)
+        off = pos_bt % block_tokens  # (B, T)
+        k_cache = k_cache.at[:, blk, :, off, :].set(
+            jnp.transpose(k_rows, (1, 3, 0, 2, 4)))
+        v_cache = v_cache.at[:, blk, :, off, :].set(
+            jnp.transpose(v_rows, (1, 3, 0, 2, 4)))
+    elif paged_cold is not None:
+        # ring commit: position p lands in slot p mod R (scatter — the
+        # chunk may wrap the ring boundary). The rows being overwritten
+        # need no flush: the HOST store is authoritative for every
+        # committed position (Engine writes the same rows there).
+        ring = k_cache.shape[3]
+        idx = (start_pos + jnp.arange(t)) % ring
+        k_cache = k_cache.at[:, :, :, idx, :].set(k_rows)
+        v_cache = v_cache.at[:, :, :, idx, :].set(v_rows)
+    elif sp_active:
+        # sequence-sharded caches: masked window write into the owning
+        # shards of the striped layout
+        k_cache, v_cache = commit_kv_rows_sharded(
+            k_cache, v_cache, k_rows, v_rows, start_pos,
+            axis_name=sp_axis_name, axis_size=sp_size)
+    elif start_pos.ndim == 0:
+        k_cache = jax.lax.dynamic_update_slice(
+            k_cache, k_rows, (0, 0, 0, start_pos, 0))
+        v_cache = jax.lax.dynamic_update_slice(
+            v_cache, v_rows, (0, 0, 0, start_pos, 0))
+    else:  # per-row offsets: vmap the write over the batch axis
+        row_write = jax.vmap(
+            lambda c, n, p: jax.lax.dynamic_update_slice(c, n, (0, 0, p, 0)),
+            in_axes=(1, 1, 0), out_axes=1)
+        k_cache = row_write(k_cache, k_rows, start_pos)
+        v_cache = row_write(v_cache, v_rows, start_pos)
 
     x = rmsnorm(x, params["rms_final"], spec.norm_eps)
     logits = qmatmul(x, params["wcls"], use_pallas=use_pallas, out_dtype=jnp.float32)

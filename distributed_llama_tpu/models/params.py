@@ -324,15 +324,12 @@ def prepare_for_pallas(params: Params, tp: int = 1,
     (`_repack_on_device`): the result's leaves are device arrays, sharded as
     `shard_params` would place them when `mesh` is given, on the default
     device otherwise. The int8 planes are made on the host."""
-    import os
-
     from jax.sharding import NamedSharding, PartitionSpec
 
     from ..parallel.sharding import param_pspecs
 
     out: Params = {"embedding": params["embedding"], "blocks": {},
                    "rms_final": params["rms_final"]}
-    fuse = fuse and not os.environ.get("DLT_NO_FUSE")  # field kill-switch
     blocks = params["blocks"]
     plan = _fuse_plan(blocks, spec, tp, moe_sharding) if fuse else {}
     merged = {m: f for f in plan for m in _FUSE_GROUPS[f]}

@@ -35,7 +35,7 @@ def gqa_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     per-row (continuous batching: each batch row decodes at its own offset).
     key_positions: absolute position of each key slot, (S,) or per-row (B, S).
     Defaults to arange(S) (slot index == position, the resident-cache layout);
-    the deferred-cache-write path passes [window slots ++ current-chunk positions]
+    models/forward.py passes [window slots ++ current-chunk positions]
     with garbage slots pushed past seq_len so the causal compare masks them.
     Returns (B, T, n_q_heads * hs)."""
     b, t, hq, hs = q.shape
@@ -118,23 +118,3 @@ def merge_attention_partials(out_a: jax.Array, lse_a: jax.Array,
     wb = jnp.exp(lse_b - m)
     den = jnp.maximum(wa + wb, 1e-30)[..., None]
     return (out_a * wa[..., None] + out_b * wb[..., None]) / den
-
-
-def update_kv_cache(k_cache: jax.Array, v_cache: jax.Array, k_new: jax.Array,
-                    v_new: jax.Array, start_pos: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Write T new kv vectors at [start_pos, start_pos+T) into head-major caches.
-
-    k_new/v_new: (B, T, n_kv_heads, hs) -> caches (B, n_kv_heads, S, hs).
-    start_pos: scalar (all rows write at the same offset) or (B,) per-row offsets
-    (continuous batching). Replaces the reference's direct in-cache matmul write
-    (llama2-tasks.cpp:38-44).
-    """
-    k_t = jnp.swapaxes(k_new, 1, 2).astype(k_cache.dtype)  # (B, hk, T, hs)
-    v_t = jnp.swapaxes(v_new, 1, 2).astype(v_cache.dtype)
-    if start_pos.ndim == 0:
-        k_cache = jax.lax.dynamic_update_slice(k_cache, k_t, (0, 0, start_pos, 0))
-        v_cache = jax.lax.dynamic_update_slice(v_cache, v_t, (0, 0, start_pos, 0))
-        return k_cache, v_cache
-    row_write = jax.vmap(
-        lambda c, n, p: jax.lax.dynamic_update_slice(c, n, (0, p, 0)))
-    return row_write(k_cache, k_t, start_pos), row_write(v_cache, v_t, start_pos)

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 
 from ..obs import metrics
-from ..platform_env import interpret_requested
 from ..quants import QTensor
 from ..resilience import faults
 from ..resilience.errors import FaultInjected, TransientDispatchError
@@ -169,37 +168,3 @@ def _qmatmul_xla(x: jax.Array, w: QTensor, *, out_dtype=None) -> jax.Array:
         preferred_element_type=jnp.float32,
     )
     return y.astype(out_dtype or x.dtype)
-
-
-def qmatmul_q80(xq: jax.Array, sx: jax.Array, w: QTensor, *,
-                use_pallas: bool = False, out_dtype=jnp.float32) -> jax.Array:
-    """Decode matvec against a PRE-QUANTIZED activation row.
-
-    xq (1, K) int8 + sx (1, K//32) f32 are the Q80 form of the activation (from
-    ops.pallas_prologue); returns (1, 1, N). Routes into the inline-Xexp matvec
-    variants so the quantized row is the only activation HBM traffic; the XLA
-    fallback dequantizes x̂ = xq·sx and runs the dense path (same numerics —
-    activation quantization already happened upstream either way).
-    """
-    from ..quants import jnp_dequantize_i8
-
-    if use_pallas:
-        if w.layout == "i4p":
-            from .pallas_q4 import _q4_matvec_inline, q4_decode_supported
-
-            if w.groups == 1 and q4_decode_supported(w):
-                y = _q4_matvec_inline(xq, sx, w.data, w.scales,
-                                      interpret=interpret_requested())
-                return y.reshape(1, 1, y.shape[0]).astype(out_dtype)
-        elif w.layout == "i8":
-            from .pallas_q8 import _q8_matvec_inline, q8_decode_supported
-
-            if q8_decode_supported(w):
-                y = _q8_matvec_inline(xq, sx, w.data, w.scales,
-                                      interpret=interpret_requested())
-                return y.reshape(1, 1, y.shape[0]).astype(out_dtype)
-    xhat = jnp_dequantize_i8(xq, sx, dtype=jnp.float32)  # (1, K)
-    wd = w.dequantize(dtype=jnp.float32)
-    y = jax.lax.dot_general(xhat, wd, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    return y.reshape(1, 1, y.shape[-1]).astype(out_dtype)

@@ -2,7 +2,7 @@
 kernel, reading the cache window STRAIGHT out of the stacked (L, B, hk, S, hs)
 buffers.
 
-The XLA path (models/forward.py deferred branch + ops/attention.py) materializes a
+The XLA path (models/forward.py contiguous branch + ops/attention.py) materializes a
 (B, hk, win, hs) dynamic-slice of each cache per layer before attention — at 7B /
 window 256 that is ~134 MB/step of slice traffic plus separate softmax fusions (the
 `dynamic-slice_bitcast_fusion` + `convert_reduce_fusion` lines in the round-4
@@ -13,7 +13,7 @@ argument, so nothing is sliced or copied in XLA.
 
 The reference's counterpart is the per-head attention loop at
 src/llama2-tasks.cpp:54-94 (dot q·k over 0..pos, softmax, weighted v sum); the
-windowed-read semantics match ops/attention.gqa_attention with the deferred-write
+windowed-read semantics match ops/attention.gqa_attention with models/forward.py's
 key layout: window slots are valid iff slot < pos, and the current token's k/v
 (not yet committed to the cache) attends from registers.
 

@@ -6,7 +6,7 @@ batch row's BLOCK TABLE maps virtual positions to pool blocks. Two readers
 live here:
 
 - `paged_gather_kv` — the XLA fallback: gather the table's blocks into a
-  contiguous (B, hk, win, hs) buffer, exactly the dense deferred-write
+  contiguous (B, hk, win, hs) buffer, exactly the contiguous caches'
   window layout. models/forward.py feeds it to the SAME gqa_attention code
   path as the dense cache, so on the CPU mesh the paged engine is
   bit-identical to the dense engine (the token-identity acceptance bar).

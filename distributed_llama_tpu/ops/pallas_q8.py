@@ -149,8 +149,7 @@ def _q8_matvec_inline(xq, sx, w8, scales, *, interpret: bool = False):
 def _quantize_blocks(g: jax.Array):
     """Q80 quantization of g (nb, QK) f32, one quant block per row -> (int8
     (nb, QK), block scales (nb, 1) f32). Exactly the reference's Q80 buffer
-    semantics (src/tasks.cpp:96-135). Pure jnp and free of reshapes, so it is
-    also the body of the prologue kernels (ops/pallas_prologue.py)."""
+    semantics (src/tasks.cpp:96-135). Pure jnp and free of reshapes."""
     absmax = jnp.max(jnp.abs(g), axis=-1, keepdims=True)
     inv = jnp.where(absmax > 0, 127.0 / absmax, 0.0)
     return jnp.round(g * inv).astype(jnp.int8), absmax / 127.0

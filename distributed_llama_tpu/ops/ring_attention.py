@@ -13,11 +13,11 @@ Every device holds the full Q (queries are small; KV is what grows with context)
 output is replicated over sp and no final gather is needed. Combines with TP head
 sharding orthogonally: cache is (B, hk/tp, S/sp, hs) on a (dp, sp, tp) mesh.
 
-Two sequence layouts (selected by the cache-write discipline, models/forward.py):
-contiguous (inscan: device i holds positions [i*Sb, (i+1)*Sb)) and STRIPED
-(deferred: device i's slot j holds position j*sp + i), which spreads the live
-context evenly so static window buckets bound each rotation to ceil(window/sp)
-columns — decode ICI/HBM then tracks the live context, not the allocated seq_len.
+The sequence layout is STRIPED (device i's slot j holds position j*sp + i), which
+spreads the live context evenly so static window buckets bound each rotation to
+ceil(window/sp) columns — decode ICI/HBM then tracks the live context, not the
+allocated seq_len (contiguous shards would concentrate the live prefix on the
+low-index devices, and every rotation would move the FULL shard).
 """
 
 from __future__ import annotations
@@ -36,11 +36,10 @@ def _block_attend(qg, k_blk, v_blk, positions, col_offset, col_stride=1,
 
     qg: (B, hk, g, T, hs) f32; k_blk/v_blk: (B, hk, Sb, hs); positions: (T,) absolute
     query positions. Block column j sits at absolute position
-    col_offset + col_stride*j — contiguous shards use (owner*Sb, 1), the striped
-    layout uses (owner, sp). live_end, if given, additionally masks columns at
-    positions >= live_end — the deferred-write discipline attends cache blocks
-    only over COMMITTED rows (the current chunk arrives as its own register
-    block instead).
+    col_offset + col_stride*j — a striped shard is (owner, sp), the chunk's
+    own block (chunk_start, 1). live_end, if given, additionally masks columns
+    at positions >= live_end — cache blocks are attended only over COMMITTED
+    rows (the current chunk arrives as its own register block instead).
     Returns (m (…, T), l (…, T), acc (…, T, hs)) partial softmax stats.
     """
     sb = k_blk.shape[2]
@@ -76,36 +75,28 @@ def ring_attention(q: jax.Array, k_shard: jax.Array, v_shard: jax.Array,
                    positions: jax.Array, *, axis_name: str, axis_size: int,
                    live_end: jax.Array | None = None,
                    chunk: tuple[jax.Array, jax.Array, jax.Array] | None = None,
-                   striped: bool = False,
                    window_slots: int | None = None) -> jax.Array:
     """Causal GQA attention of T query tokens against a sequence-sharded cache.
 
     q: (B, T, hq, hs) replicated over sp; k_shard/v_shard: (B, hk, S/sp, hs), the
-    local sequence shard. Two layouts:
+    local sequence shard, striped: device i's local slot j holds absolute position
+    j*axis_size + i. The live context occupies the first ceil(pos/sp) slots of
+    EVERY shard, so with a static window bucket W covering pos, only
+    window_slots = ceil(W/sp) slots participate — each ring rotation moves
+    W/sp columns instead of S/sp, bounding both ICI and HBM per step by the
+    LIVE context (the sp analog of the dense path's attn_window).
 
-    - contiguous (striped=False): device i holds absolute positions
-      [i*Sb, (i+1)*Sb). The live context [0, pos) is a prefix that concentrates
-      on low-index devices, so every rotation must move the FULL shard.
-    - striped (striped=True): device i's local slot j holds absolute position
-      j*axis_size + i. The live context occupies the first ceil(pos/sp) slots of
-      EVERY shard, so with a static window bucket W covering pos, only
-      window_slots = ceil(W/sp) slots participate — each ring rotation moves
-      W/sp columns instead of S/sp, bounding both ICI and HBM per step by the
-      LIVE context (the sp analog of the dense path's attn_window).
+    The cache holds only COMMITTED rows (positions < live_end == start_pos); the
+    current chunk's K/V ride in as `chunk=(k_c (B, hk, T, hs), v_c, chunk_start)`
+    and are attended as one extra register block folded into the same online
+    softmax — no cache write happens inside the step at all.
 
     Returns (B, T, hq*hs), replicated over sp.
-
-    Deferred-write mode (models/forward.py cache_write="deferred"): the cache holds
-    only COMMITTED rows (positions < live_end == start_pos); the current chunk's
-    K/V ride in as `chunk=(k_c (B, hk, T, hs), v_c, chunk_start)` and are attended
-    as one extra register block folded into the same online softmax — no cache
-    write happens inside the step at all.
     """
     b, t, hq, hs = q.shape
     _, hk, sb, _ = k_shard.shape
     g = hq // hk
     if window_slots is not None and window_slots < sb:
-        assert striped, "window_slots only bounds the striped layout"
         k_shard = k_shard[:, :, :window_slots]
         v_shard = v_shard[:, :, :window_slots]
         sb = window_slots
@@ -121,9 +112,8 @@ def ring_attention(q: jax.Array, k_shard: jax.Array, v_shard: jax.Array,
     k_blk, v_blk = k_shard, v_shard
     for r in range(axis_size):
         owner = (idx + r) % axis_size  # whose shard I currently hold
-        offset, stride = (owner, axis_size) if striped else (owner * sb, 1)
-        mb, lb, ab = _block_attend(qg, k_blk, v_blk, positions, offset, stride,
-                                   live_end=live_end)
+        mb, lb, ab = _block_attend(qg, k_blk, v_blk, positions, owner,
+                                   axis_size, live_end=live_end)
         m, l, acc = _combine(m, l, acc, mb, lb, ab)
         if r + 1 < axis_size:
             k_blk = jax.lax.ppermute(k_blk, axis_name, perm)
@@ -140,114 +130,34 @@ def ring_attention(q: jax.Array, k_shard: jax.Array, v_shard: jax.Array,
 def commit_kv_rows_sharded(k_cache: jax.Array, v_cache: jax.Array,
                            k_rows: jax.Array, v_rows: jax.Array,
                            start_pos: jax.Array, *, axis_name: str,
-                           striped: bool = False, axis_size: int | None = None
-                           ) -> tuple[jax.Array, jax.Array]:
-    """Deferred-write commit for sequence-sharded caches: write ALL layers' new
+                           axis_size: int) -> tuple[jax.Array, jax.Array]:
+    """The commit for sequence-sharded (striped) caches: write ALL layers' new
     rows in one tiny masked window write per cache.
 
     caches: (L, B, hk, Sb, hs) local shards; rows: (L, B, hk, T, hs) (every sp
-    member computed identical rows — activations are sp-replicated). The write
-    window is clipped into the shard with a per-slot hit mask so a chunk
-    straddling shard boundaries writes each member exactly its own positions.
+    member computed identical rows — activations are sp-replicated). Member m's
+    local slot j holds absolute position j*sp + m (see ring_attention), so m
+    takes the chunk positions with p % sp == m, landing in a ceil(T/sp)(+1)
+    slot window; a per-slot hit mask keeps what the window holds besides.
     Total write traffic is O(L·T) rows — the sp counterpart of forward()'s
-    top-level dynamic_update_slice, replacing the full-local-cache carry the
-    in-scan discipline pays.
-
-    striped=True uses the interleaved layout (member m's local slot j holds
-    absolute position j*sp + m — see ring_attention): member m takes the chunk
-    positions with p % sp == m, landing in a ceil(T/sp)(+1) slot window."""
+    top-level dynamic_update_slice."""
     t = k_rows.shape[3]
     sb = k_cache.shape[3]
     idx = jax.lax.axis_index(axis_name)
-
-    if striped:
-        sp = axis_size
-        assert sp is not None, "striped commit needs the static axis_size"
-        wl = min((t - 1) // sp + 2, sb)  # slot-window width (static)
-        j0 = jnp.clip(start_pos // sp, 0, sb - wl)
-        slots = j0 + jnp.arange(wl)
-        src = slots * sp + idx - start_pos  # which chunk token lands in each slot
-        hit = (src >= 0) & (src < t)
-        src_c = jnp.clip(src, 0, t - 1)
-
-        def write_striped(cache, rows):
-            rows = rows.astype(cache.dtype)
-            cur = jax.lax.dynamic_slice(
-                cache, (0, 0, 0, j0, 0), (*cache.shape[:3], wl, cache.shape[4]))
-            gathered = jnp.take(rows, src_c, axis=3)
-            val = jnp.where(hit[None, None, None, :, None], gathered, cur)
-            return jax.lax.dynamic_update_slice(cache, val, (0, 0, 0, j0, 0))
-
-        return write_striped(k_cache, k_rows), write_striped(v_cache, v_rows)
-
-    local = start_pos - idx * sb  # chunk start in MY shard coordinates (may be <0)
-
-    if t > sb:
-        # prefill chunk wider than a shard (tiny seq_len/sp): masked scatter over
-        # the whole local shard — a full-shard write, but amortized over >= sb
-        # prefill tokens and unreachable from decode (T=1)
-        slot = jnp.arange(sb)
-        src = slot - local
-        hit = (src >= 0) & (src < t)
-        src_c = jnp.clip(src, 0, t - 1)
-
-        def write_full(cache, rows):
-            gathered = jnp.take(rows.astype(cache.dtype), src_c, axis=3)
-            return jnp.where(hit[None, None, None, :, None], gathered, cache)
-
-        return write_full(k_cache, k_rows), write_full(v_cache, v_rows)
-
-    at = jnp.clip(local, 0, sb - t)
-    win_slot = at + jnp.arange(t)  # absolute local slots of the write window
-    src = win_slot - local  # which chunk token lands in each window slot
+    sp = axis_size
+    wl = min((t - 1) // sp + 2, sb)  # slot-window width (static)
+    j0 = jnp.clip(start_pos // sp, 0, sb - wl)
+    slots = j0 + jnp.arange(wl)
+    src = slots * sp + idx - start_pos  # which chunk token lands in each slot
     hit = (src >= 0) & (src < t)
     src_c = jnp.clip(src, 0, t - 1)
 
     def write(cache, rows):
         rows = rows.astype(cache.dtype)
         cur = jax.lax.dynamic_slice(
-            cache, (0, 0, 0, at, 0), (*cache.shape[:3], t, cache.shape[4]))
+            cache, (0, 0, 0, j0, 0), (*cache.shape[:3], wl, cache.shape[4]))
         gathered = jnp.take(rows, src_c, axis=3)
         val = jnp.where(hit[None, None, None, :, None], gathered, cur)
-        return jax.lax.dynamic_update_slice(cache, val, (0, 0, 0, at, 0))
+        return jax.lax.dynamic_update_slice(cache, val, (0, 0, 0, j0, 0))
 
     return write(k_cache, k_rows), write(v_cache, v_rows)
-
-
-def update_kv_cache_sharded(k_cache: jax.Array, v_cache: jax.Array, k_new: jax.Array,
-                            v_new: jax.Array, start_pos: jax.Array, *,
-                            axis_name: str) -> tuple[jax.Array, jax.Array]:
-    """Write T new kv vectors into sequence-sharded caches; each sp member keeps only
-    the positions that land in its shard.
-
-    k_new/v_new: (B, T, hk, hs); caches: (B, hk, Sb, hs) local shards. The write may
-    straddle a shard boundary, so it is a masked positional update. Replaces
-    ops.attention.update_kv_cache when the cache's S axis is sp-sharded.
-    """
-    b, t, hk, hs = k_new.shape
-    sb = k_cache.shape[2]
-    idx = jax.lax.axis_index(axis_name)
-    local = start_pos - idx * sb  # where the chunk starts in MY shard (may be <0)
-
-    if t == 1:
-        in_range = (local >= 0) & (local < sb)
-        at = jnp.clip(local, 0, sb - 1)
-        def write(cache, new):
-            new_t = jnp.swapaxes(new, 1, 2).astype(cache.dtype)  # (B, hk, 1, hs)
-            cur = jax.lax.dynamic_slice(cache, (0, 0, at, 0), new_t.shape)
-            val = jnp.where(in_range, new_t, cur)
-            return jax.lax.dynamic_update_slice(cache, val, (0, 0, at, 0))
-        return write(k_cache, k_new), write(v_cache, v_new)
-
-    # chunk write, possibly straddling shards: scatter by position mask over the shard
-    slot = jnp.arange(sb)  # local slots
-    src = slot - local  # which chunk token lands in this slot
-    hit = (src >= 0) & (src < t)  # (Sb,)
-    src_c = jnp.clip(src, 0, t - 1)
-
-    def write(cache, new):
-        new_t = jnp.swapaxes(new, 1, 2).astype(cache.dtype)  # (B, hk, T, hs)
-        gathered = jnp.take(new_t, src_c, axis=2)  # (B, hk, Sb, hs)
-        return jnp.where(hit[None, None, :, None], gathered, cache)
-
-    return write(k_cache, k_new), write(v_cache, v_new)

@@ -128,9 +128,7 @@ def make_sharded_forward(spec: ModelSpec, mesh: Mesh, params: dict[str, Any], *,
                          dtype=None, use_pallas: bool = False,
                          compress_collectives: bool = False, donate_cache: bool = True,
                          attn_window: int | None = None,
-                         cache_write: str = "inscan",
                          moe_sharding: str = "slice",
-                         fused_prologue: bool = False,
                          kv_block_tokens: int = 0,
                          paged_kernel: bool = False,
                          moe_stats: bool = False):
@@ -158,10 +156,6 @@ def make_sharded_forward(spec: ModelSpec, mesh: Mesh, params: dict[str, Any], *,
     dp = mesh.shape.get(AXIS_DP, 1)
     check_divisibility(spec, tp, sp, moe_sharding=moe_sharding)
     dtype = dtype or jnp.float32
-    if sp > 1 and cache_write != "deferred":
-        # the in-scan (contiguous) ring walks the full sharded cache; the
-        # deferred ring is STRIPED and honors the window (models/forward.py)
-        attn_window = None
 
     param_specs = _expand_pspec_tree(params, param_pspecs(params, moe_sharding))
     kv_spec = kv_cache_pspec_for_mesh(mesh)
@@ -186,8 +180,7 @@ def make_sharded_forward(spec: ModelSpec, mesh: Mesh, params: dict[str, Any], *,
                             sp_axis_name=AXIS_SP if sp > 1 else None, sp_size=sp,
                             use_pallas=use_pallas,
                             compress_collectives=compress_collectives,
-                            attn_window=attn_window, cache_write=cache_write,
-                            fused_prologue=fused_prologue,
+                            attn_window=attn_window,
                             block_tokens=kv_block_tokens,
                             paged_kernel=paged_kernel, moe_stats=moe_stats)
     rope_type = spec.rope_type

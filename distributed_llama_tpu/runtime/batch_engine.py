@@ -618,15 +618,6 @@ class BatchEngine:
         # ~0 bytes on the paged path (remap), the full fetched span dense
         self.seed_bytes = 0
         self.seed_ms = 0.0
-        # check the ENGINE's resolution (kwarg or DLT_PROLOGUE env) — warning on
-        # the kwarg alone would miss the env route the flag help advertises
-        if self._eng.fused_prologue and slots > 1:
-            import sys
-
-            print("⚠️  --prologue is inert with batched decode (the prologue "
-                  "kernels take one activation row; forward gates them off for "
-                  "B > 1) — the A/B lever will not engage", file=sys.stderr,
-                  flush=True)
         self.spec = spec
         self.tokenizer = tokenizer
         self.superstep = superstep  # K: decode steps fused per device dispatch
@@ -2799,9 +2790,7 @@ class BatchEngine:
                 self.spec, eng.mesh, eng.params, k, mode=mode, dtype=eng.dtype,
                 use_pallas=eng.use_pallas,
                 compress_collectives=eng.compress, donate_cache=True,
-                attn_window=window, cache_write=eng.cache_write,
-                moe_sharding=eng.moe_sharding,
-                fused_prologue=eng.fused_prologue,
+                attn_window=window, moe_sharding=eng.moe_sharding,
                 kv_block_tokens=self._kv_bt,
                 paged_kernel=eng.paged_kernel,
                 masked=masked, moe_stats=eng.moe_stats)
@@ -2823,9 +2812,7 @@ class BatchEngine:
                 self.spec, eng.mesh, eng.params, t, mode=mode, dtype=eng.dtype,
                 use_pallas=eng.use_pallas,
                 compress_collectives=eng.compress, donate_cache=True,
-                attn_window=window, cache_write=eng.cache_write,
-                moe_sharding=eng.moe_sharding,
-                fused_prologue=eng.fused_prologue,
+                attn_window=window, moe_sharding=eng.moe_sharding,
                 kv_block_tokens=self._kv_bt,
                 paged_kernel=eng.paged_kernel,
                 masked=masked)

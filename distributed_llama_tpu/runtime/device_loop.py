@@ -161,8 +161,8 @@ def xorshift_coin(hi: jax.Array, lo: jax.Array):
 def make_decode_loop(spec: ModelSpec, mesh, params, n_steps: int, *, mode: str = "greedy",
                      dtype=None, use_pallas: bool = False,
                      compress_collectives: bool = False, donate_cache: bool = True,
-                     attn_window: int | None = None, cache_write: str = "inscan",
-                     moe_sharding: str = "slice", fused_prologue: bool = False):
+                     attn_window: int | None = None,
+                     moe_sharding: str = "slice"):
     """Build fn(params, rope, token, kc, vc, start_pos, key, temperature, topp) ->
     (tokens (n_steps,), last_logits (vocab,), kc, vc).
 
@@ -176,10 +176,6 @@ def make_decode_loop(spec: ModelSpec, mesh, params, n_steps: int, *, mode: str =
     assert mode in ("greedy", "sample"), mode
     dtype = dtype or jnp.float32
     sp = mesh.shape.get(AXIS_SP, 1)
-    if sp > 1 and cache_write != "deferred":
-        # the in-scan (contiguous) ring walks the full sharded cache; the
-        # deferred ring is STRIPED and honors the window (models/forward.py)
-        attn_window = None
     param_specs = _expand_pspec_tree(params, param_pspecs(params, moe_sharding))
     kv_spec = kv_cache_pspec_for_mesh(mesh)
     rope_type = spec.rope_type
@@ -189,8 +185,7 @@ def make_decode_loop(spec: ModelSpec, mesh, params, n_steps: int, *, mode: str =
                             sp_axis_name=AXIS_SP if sp > 1 else None, sp_size=sp,
                             use_pallas=use_pallas,
                             compress_collectives=compress_collectives,
-                            attn_window=attn_window, cache_write=cache_write,
-                            fused_prologue=fused_prologue)
+                            attn_window=attn_window)
 
     # hot-path: traced
     def loop(p, rope_cos, rope_sin, token, kc, vc, start_pos, key, temperature, topp):
@@ -259,9 +254,7 @@ def make_batched_decode_loop(spec: ModelSpec, mesh, params, n_steps: int, *,
                              compress_collectives: bool = False,
                              donate_cache: bool = True,
                              attn_window: int | None = None,
-                             cache_write: str = "inscan",
                              moe_sharding: str = "slice",
-                             fused_prologue: bool = False,
                              kv_block_tokens: int = 0,
                              paged_kernel: bool = False,
                              masked: bool = False,
@@ -343,8 +336,7 @@ def make_batched_decode_loop(spec: ModelSpec, mesh, params, n_steps: int, *,
                             axis_name=_tp_axis(mesh, compress_collectives),
                             sp_axis_name=None, sp_size=1, use_pallas=use_pallas,
                             compress_collectives=compress_collectives,
-                            attn_window=attn_window, cache_write=cache_write,
-                            fused_prologue=fused_prologue,
+                            attn_window=attn_window,
                             block_tokens=kv_block_tokens,
                             paged_kernel=paged_kernel, moe_stats=True)
 
@@ -454,9 +446,7 @@ def make_batched_verify_loop(spec: ModelSpec, mesh, params, block: int, *,
                              compress_collectives: bool = False,
                              donate_cache: bool = True,
                              attn_window: int | None = None,
-                             cache_write: str = "inscan",
                              moe_sharding: str = "slice",
-                             fused_prologue: bool = False,
                              kv_block_tokens: int = 0,
                              paged_kernel: bool = False,
                              masked: bool = False):
@@ -526,8 +516,7 @@ def make_batched_verify_loop(spec: ModelSpec, mesh, params, block: int, *,
                             axis_name=_tp_axis(mesh, compress_collectives),
                             sp_axis_name=None, sp_size=1, use_pallas=use_pallas,
                             compress_collectives=compress_collectives,
-                            attn_window=attn_window, cache_write=cache_write,
-                            fused_prologue=fused_prologue,
+                            attn_window=attn_window,
                             block_tokens=kv_block_tokens,
                             paged_kernel=paged_kernel)
 

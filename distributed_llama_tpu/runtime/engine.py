@@ -147,8 +147,7 @@ class Engine:
                  *, tp: int | None = None, sp: int = 1, dp: int = 1, dtype=None,
                  use_pallas: bool | None = None,
                  compress_collectives: bool = False, batch: int = 1,
-                 pod: bool = False, cache_write: str | None = None,
-                 moe_sharding: str = "slice", fused_prologue: bool | None = None,
+                 pod: bool = False, moe_sharding: str = "slice",
                  kv_cache_storage: str | None = None,
                  kv_cache_resident: int = 1024,
                  kv_cache_dir: str | None = None,
@@ -216,24 +215,6 @@ class Engine:
         self.tp = self.mesh.shape[AXIS_TP]
         self.sp = sp
         self.dp = dp
-        # KV cache discipline (models/forward.py): "deferred" keeps the caches
-        # loop-invariant in the layer scan — avoids the whole-cache carry copies
-        # XLA TPU inserts for dynamically-indexed carry updates (round-4 trace:
-        # ~11.6 ms/token at 7B). Supported on every path, including sp (the ring
-        # attends committed rows + the chunk as a register block, and the commit
-        # is a masked window write — commit_kv_rows_sharded). None = auto
-        # (deferred).
-        self.cache_write = cache_write or "deferred"
-        # fused rmsnorm+quantize prologue kernels (ops/pallas_prologue.py):
-        # opt-in (flag or DLT_PROLOGUE=1) until the hardware A/B lands — the
-        # round-4 lesson is not to default to never-executed kernels
-        if fused_prologue is None:
-            import os
-
-            # parse, don't bool(): DLT_PROLOGUE=0 must mean OFF (A/B control arm)
-            fused_prologue = os.environ.get("DLT_PROLOGUE", "").lower() in (
-                "1", "true", "yes")
-        self.fused_prologue = fused_prologue
         # MoE expert placement: "slice" TP-slices every expert's hidden axis (the
         # reference's scheme); "expert" shards WHOLE experts over tp — the capacity
         # axis for Grok-1-314B-class expert weights (parallel/sharding.py)
@@ -306,8 +287,6 @@ class Engine:
         s = self.spec.seq_len
         if self.paged:
             return None  # the hot ring IS the window; cold attends on host
-        if self.sp > 1 and self.cache_write != "deferred":
-            return None  # contiguous (inscan) ring walks the full sharded cache
         if s <= self._WINDOW_MIN:
             return None  # tiny contexts: no bucketing
         w = self._WINDOW_MIN
@@ -320,7 +299,7 @@ class Engine:
             # warm phase of the paged engine: while pos + T <= resident the
             # ring layout coincides with a plain cache prefix (slot ==
             # position) and the cold segment is provably empty — run the
-            # ordinary deferred step over the ring-sized caches and skip the
+            # ordinary step over the ring-sized caches and skip the
             # n_layers host callback round-trips per step entirely
             window = None
         elif self.paged:
@@ -329,8 +308,7 @@ class Engine:
 
                 self._steps["paged"] = make_paged_step(
                     self.spec, self.store, dtype=self.dtype,
-                    use_pallas=self.use_pallas,
-                    fused_prologue=self.fused_prologue)
+                    use_pallas=self.use_pallas)
             return self._steps["paged"]
         if self.kv_pool is not None:
             # table-aware step (docs/PAGED_KV.md): same window buckets, one
@@ -343,8 +321,7 @@ class Engine:
                     use_pallas=self.use_pallas,
                     compress_collectives=self.compress,
                     donate_cache=True, attn_window=window,
-                    cache_write="deferred", moe_sharding=self.moe_sharding,
-                    fused_prologue=self.fused_prologue,
+                    moe_sharding=self.moe_sharding,
                     kv_block_tokens=self.kv_pool[1],
                     paged_kernel=self.paged_kernel, moe_stats=self.moe_stats)
             return self._steps[key]
@@ -353,8 +330,7 @@ class Engine:
                 self.spec, self.mesh, self.params, dtype=self.dtype,
                 use_pallas=self.use_pallas, compress_collectives=self.compress,
                 donate_cache=True, attn_window=window,
-                cache_write=self.cache_write, moe_sharding=self.moe_sharding,
-                fused_prologue=self.fused_prologue, moe_stats=self.moe_stats)
+                moe_sharding=self.moe_sharding, moe_stats=self.moe_stats)
         return self._steps[window]
 
     @property
@@ -442,18 +418,24 @@ class Engine:
                     self.v_cache = self.v_cache.at[:, :, :, slots, :].set(vrows)
             else:
                 # rolled back a full wrap or more: rebuild the ring outright
-                lo = max(0, pos - R)
-                kr = np.zeros((L, B, hk, R, hs), np.float32)
-                vr = np.zeros_like(kr)
-                if pos > lo:
-                    idx = np.arange(lo, pos) % R
-                    kr[:, :, :, idx] = np.asarray(self.store.k[:, :, :, lo:pos],
-                                                  np.float32)
-                    vr[:, :, :, idx] = np.asarray(self.store.v[:, :, :, lo:pos],
-                                                  np.float32)
-                self.k_cache = jnp.asarray(kr, self.dtype)
-                self.v_cache = jnp.asarray(vr, self.dtype)
+                self._rebuild_ring(pos)
         self.pos = pos
+
+    def _rebuild_ring(self, pos: int) -> None:
+        """The device ring as it stands with `pos` positions committed, from
+        the host store (authoritative for every committed position)."""
+        L, B, hk, R, hs = self.k_cache.shape
+        lo = max(0, pos - R)
+        kr = np.zeros((L, B, hk, R, hs), np.float32)
+        vr = np.zeros_like(kr)
+        if pos > lo:
+            idx = np.arange(lo, pos) % R
+            kr[:, :, :, idx] = np.asarray(self.store.k[:, :, :, lo:pos],
+                                          np.float32)
+            vr[:, :, :, idx] = np.asarray(self.store.v[:, :, :, lo:pos],
+                                          np.float32)
+        self.k_cache = jnp.asarray(kr, self.dtype)
+        self.v_cache = jnp.asarray(vr, self.dtype)
 
     def _trace_pos_args(self):
         """Trailing step args for collective-traffic tracing: start_pos
@@ -587,7 +569,7 @@ class Engine:
             logits = self.dispatch(tokens)
         elif self.pos + t <= self.kv_resident:
             # warm phase: slot == position, cold empty, so the callback-free
-            # plain deferred step runs (see _step_for; the paged step only
+            # plain step runs (see _step_for; the paged step only
             # builds once real cold history is about to exist), with the new
             # rows sliced from the committed ring for the host-store append
             # (the authoritative history the paged step's cold callbacks will
@@ -601,13 +583,21 @@ class Engine:
                 self.pos)
             self.pos += t
         else:
-            logits, self.k_cache, self.v_cache, (k_rows, v_rows) = (
-                self._step_for(None)(
-                    self.params, self.rope, self._tiled(tokens), self.k_cache,
-                    self.v_cache, self._pos_arg(self.pos)))
+            try:
+                logits, self.k_cache, self.v_cache, (k_rows, v_rows) = (
+                    self._step_for(None)(
+                        self.params, self.rope, self._tiled(tokens),
+                        self.k_cache, self.v_cache, self._pos_arg(self.pos)))
+                k_rows, v_rows = np.asarray(k_rows), np.asarray(v_rows)
+            except Exception:
+                # a cold callback raised inside the step, which had been
+                # given the ring (donated): the ring is gone with it, and
+                # the engine would be unusable even after reset()
+                self._rebuild_ring(self.pos)
+                raise
             # the host store is the authoritative history the next step's
             # cold callbacks read — append before advancing pos
-            self.store.append(np.asarray(k_rows), np.asarray(v_rows), self.pos)
+            self.store.append(k_rows, v_rows, self.pos)
             self.pos += t
         out = np.asarray(logits)[0]  # the sampler needs them on the host
         dt = time.perf_counter() - t0
@@ -744,9 +734,7 @@ class Engine:
                 self.spec, self.mesh, self.params, chunk, mode=mode, dtype=self.dtype,
                 use_pallas=self.use_pallas,
                 compress_collectives=self.compress, donate_cache=True,
-                attn_window=window, cache_write=self.cache_write,
-                moe_sharding=self.moe_sharding,
-                fused_prologue=self.fused_prologue)
+                attn_window=window, moe_sharding=self.moe_sharding)
         return self._decode_loops[chunk, mode, window]
 
     def _loop_traffic(self, chunk: int, mode: str, loop):

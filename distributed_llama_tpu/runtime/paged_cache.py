@@ -163,7 +163,7 @@ def init_ring_cache(spec: ModelSpec, resident: int, *, batch: int = 1,
 
 
 def make_paged_step(spec: ModelSpec, store: HostKVStore, *, dtype=jnp.float32,
-                    use_pallas: bool = False, fused_prologue: bool = False):
+                    use_pallas: bool = False):
     """Jitted single-device paged forward step.
 
     Returns fn(params, rope, tokens, kc, vc, start_pos) ->
@@ -181,9 +181,8 @@ def make_paged_step(spec: ModelSpec, store: HostKVStore, *, dtype=jnp.float32,
         return jax.pure_callback(cold_host, shapes, layer_idx, q, start_pos)
 
     fwd = functools.partial(forward, spec=spec, dtype=dtype, axis_name=None,
-                            use_pallas=use_pallas, cache_write="deferred",
-                            attn_window=None, paged_cold=paged_cold,
-                            fused_prologue=fused_prologue)
+                            use_pallas=use_pallas, attn_window=None,
+                            paged_cold=paged_cold)
     rope_type = spec.rope_type
 
     def step(p, rope_cos, rope_sin, tokens, kc, vc, start_pos):

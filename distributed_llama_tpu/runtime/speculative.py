@@ -15,9 +15,9 @@ first mismatch is replaced by the step's own argmax — the standard greedy
 speculative identity). Sampling (temperature > 0) is NOT supported — the
 caller falls back to the sequential loop.
 
-Rollback is free under the repo's cache disciplines: rows committed for
+Rollback is free under every cache kind: rows committed for
 rejected positions sit BEYOND the rewound start_pos, and every read path masks
-slots >= start_pos (deferred window masks, ring attention live_end, paged ring
+slots >= start_pos (the window's slot masks, ring attention live_end, paged ring
 slot formula), so the next step simply overwrites them. Engine.seek() handles
 the paged hot ring's wrapped slots.
 """

@@ -1575,59 +1575,57 @@ def run_gray_family() -> tuple[int, list[str]]:
     return cells, problems
 
 
-def run_matrix(include_paged: bool = True,
-               kinds=KINDS) -> tuple[int, list[str]]:
-    cells = 0
-    problems: list[str] = []
-    # the batch family runs TWICE — pipelined (the default: overlapped
-    # dispatches, speculative chains that faults must flush cleanly) and
-    # serialized — so every cell's invariants hold under both schedulers
-    for pipeline in (True, False):
-        bspec, be = build_batch_engine(pipeline=pipeline)
-        tag = "pipelined" if pipeline else "serialized"
-        try:
-            for point in BATCH_POINTS:
-                for kind in kinds:
-                    cells += 1
-                    problems += [f"[{tag}] {p}"
-                                 for p in run_batch_cell(bspec, be, point,
-                                                         kind)]
-        finally:
-            be.close()
-    # speculation family: same invariants with batched draft-verify
-    # super-steps engaged, plus survivor token-identity, under both
-    # schedulers (docs/SERVING.md "Speculative decoding")
-    for pipeline in (True, False):
-        bspec, be = build_batch_engine(pipeline=pipeline, speculative=4)
-        tag = "spec-pipelined" if pipeline else "spec-serialized"
-        try:
-            refs = spec_reference(bspec, be)
-            for point in SPEC_POINTS:
-                for kind in kinds:
-                    cells += 1
-                    problems += [f"[{tag}] {p}"
-                                 for p in run_spec_cell(bspec, be, point,
-                                                        kind, refs)]
-        finally:
-            be.close()
-    espec, eng = build_engine()
-    for point in ENGINE_POINTS:
-        for kind in kinds:
+def _sweep(points, cell, tag: str = "") -> tuple[int, list[str]]:
+    """`cell(point, kind)` over points x KINDS: (cells run, problems)."""
+    cells, problems = 0, []
+    for point in points:
+        for kind in KINDS:
             cells += 1
-            problems += run_engine_cell(espec, eng, point, kind)
-    if include_paged:
-        pspec, peng = build_engine(paged=True)
-        for point in PAGED_POINTS:
-            for kind in kinds:
-                cells += 1
-                problems += run_engine_cell(pspec, peng, point, kind,
-                                            paged=True)
+            problems += [f"[{tag}] {p}" if tag else p
+                         for p in cell(point, kind)]
+    return cells, problems
+
+
+def run_batch_family(pipeline: bool) -> tuple[int, list[str]]:
+    """Every batch point under one scheduler: pipelined (the default:
+    overlapped dispatches, speculative chains that faults must flush
+    cleanly) or serialized."""
+    bspec, be = build_batch_engine(pipeline=pipeline)
+    try:
+        return _sweep(BATCH_POINTS,
+                      lambda pt, kind: run_batch_cell(bspec, be, pt, kind),
+                      "pipelined" if pipeline else "serialized")
+    finally:
+        be.close()
+
+
+def run_spec_family(pipeline: bool) -> tuple[int, list[str]]:
+    """The batch invariants with batched draft-verify super-steps engaged,
+    plus survivor token-identity (docs/SERVING.md "Speculative decoding")."""
+    bspec, be = build_batch_engine(pipeline=pipeline, speculative=4)
+    try:
+        refs = spec_reference(bspec, be)
+        return _sweep(SPEC_POINTS,
+                      lambda pt, kind: run_spec_cell(bspec, be, pt, kind, refs),
+                      "spec-pipelined" if pipeline else "spec-serialized")
+    finally:
+        be.close()
+
+
+def run_engine_family(paged: bool) -> tuple[int, list[str]]:
+    """The sequential Engine, or the one over the host/disc out-of-core
+    cache (its per-layer host callbacks dominate the matrix wall time)."""
+    spec, eng = build_engine(paged=paged)
+    return _sweep(PAGED_POINTS if paged else ENGINE_POINTS,
+                  lambda pt, kind: run_engine_cell(spec, eng, pt, kind,
+                                                   paged=paged))
+
+
+def run_router_family() -> tuple[int, list[str]]:
     router, stubs = build_router_fleet()
     try:
-        for point in ROUTER_POINTS:
-            for kind in kinds:
-                cells += 1
-                problems += run_router_cell(router, point, kind)
+        return _sweep(ROUTER_POINTS,
+                      lambda pt, kind: run_router_cell(router, pt, kind))
     finally:
         from distributed_llama_tpu.fleet.router import close_router
 
@@ -1635,46 +1633,47 @@ def run_matrix(include_paged: bool = True,
         for s in stubs:
             s.shutdown()
             s.server_close()
+
+
+# The matrix, family by family: the cells each has to run, and its runner,
+# which returns (cells run, problems). main() and tests/test_fault_matrix.py
+# (one case a family) both walk this table; there is no second list.
+FAMILIES = {
+    "batch-pipelined": (len(BATCH_POINTS) * len(KINDS),
+                        lambda: run_batch_family(True)),
+    "batch-serialized": (len(BATCH_POINTS) * len(KINDS),
+                         lambda: run_batch_family(False)),
+    "spec-pipelined": (len(SPEC_POINTS) * len(KINDS),
+                       lambda: run_spec_family(True)),
+    "spec-serialized": (len(SPEC_POINTS) * len(KINDS),
+                        lambda: run_spec_family(False)),
+    "engine": (len(ENGINE_POINTS) * len(KINDS),
+               lambda: run_engine_family(paged=False)),
+    "paged": (len(PAGED_POINTS) * len(KINDS),
+              lambda: run_engine_family(paged=True)),
+    "router": (len(ROUTER_POINTS) * len(KINDS), run_router_family),
     # hung-engine supervision + durable mid-stream failover (ISSUE 9)
-    cells += SUPERVISOR_CELLS
-    problems += run_supervisor_cell()
-    d_cells, d_problems = run_durability_family()
-    cells += d_cells
-    problems += d_problems
-    # multi-tenant starvation/fairness under overload × chaos/failover
-    # (ISSUE 11, docs/SERVING.md "Multi-tenant serving")
-    f_cells, f_problems = run_fairness_family()
-    cells += f_cells
-    problems += f_problems
-    # prefill/decode disaggregation: prefill death mid-transfer must
-    # degrade to a byte-identical local prefill (ISSUE 13, docs/DISAGG.md)
-    g_cells, g_problems = run_disagg_family()
-    cells += g_cells
-    problems += g_problems
-    # gray failures: sustained-slow replica -> probation + adaptive
-    # timeouts + bounded hedging (ISSUE 14, docs/FLEET.md)
-    y_cells, y_problems = run_gray_family()
-    cells += y_cells
-    problems += y_problems
-    # model drafter: load/propose/dispatch failures degrade to n-gram
-    # then plain decode, never a client failure (ISSUE 15,
-    # docs/SERVING.md "Model-based drafting")
-    d_cells, d_problems = run_draft_family()
-    cells += d_cells
-    problems += d_problems
-    # fused dequant-matmul kernels: a failing kernel path degrades that
-    # call site to the XLA lowering, token-identical, engine intact
-    # (ISSUE 16, docs/SERVING.md "Kernel selection")
-    k_cells, k_problems = run_fused_family()
-    cells += k_cells
-    problems += k_problems
-    # grammar-constrained decoding: compile faults stop at the edge
-    # (honest 400, no queue work), mask faults degrade that row to
-    # unconstrained decoding, co-batched survivors token-identical
-    # (ISSUE 17, docs/SERVING.md "Constrained decoding")
-    c_cells, c_problems = run_constrain_family()
-    cells += c_cells
-    problems += c_problems
+    "supervisor": (SUPERVISOR_CELLS,
+                   lambda: (SUPERVISOR_CELLS, run_supervisor_cell())),
+    "durability": (DURABILITY_CELLS, run_durability_family),
+    "fairness": (FAIRNESS_CELLS, run_fairness_family),
+    "disagg": (DISAGG_CELLS, run_disagg_family),
+    "gray": (GRAY_CELLS, run_gray_family),
+    "draft": (DRAFT_CELLS, run_draft_family),
+    "fused": (FUSED_CELLS, run_fused_family),
+    "constrain": (CONSTRAIN_CELLS, run_constrain_family),
+}
+
+
+def run_matrix(include_paged: bool = True) -> tuple[int, list[str]]:
+    cells = 0
+    problems: list[str] = []
+    for name, (_, run) in FAMILIES.items():
+        if name == "paged" and not include_paged:
+            continue
+        n, found = run()
+        cells += n
+        problems += found
     return cells, problems
 
 

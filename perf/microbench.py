@@ -12,13 +12,12 @@ Measures, on whatever backend JAX resolves (designed for the single TPU chip):
                       on the Llama-2-7B hot shapes, reported as achieved GB/s
   4. prefill_mm     — fused 4-bit dequant-matmul (ops/pallas_q4_mm.py) vs the XLA
                       dequant+dot path at prefill widths (weight GB/s)
-  5. prologue       — fused rmsnorm+quantize kernels vs their XLA formulation
-  6. attention      — windowed vs full-seq_len cache read cost at 7B head geometry
+  5. attention      — windowed vs full-seq_len cache read cost at 7B head geometry
 
 Each result prints as one JSON line. Timed regions end in block_until_ready().
 
 Usage: python perf/microbench.py [--section dispatch|stream|matvec|prefill_mm|
-                                  prologue|attention|collectives] [--quick]
+                                  attention|collectives] [--quick]
 """
 
 import argparse
@@ -181,38 +180,6 @@ def sec_prefill_mm(reps):
                  weight_gbps=round(bytes_ / 1e9 / dt, 1))
 
 
-def sec_prologue(reps):
-    """Fused rmsnorm+quantize prologue kernels vs their XLA formulation at the
-    7B activation widths — the per-launch cost these kernels exist to remove."""
-    from distributed_llama_tpu.ops.kernels import rmsnorm
-    from distributed_llama_tpu.ops.pallas_prologue import (quantize_q80_row,
-                                                           rmsnorm_quantize_q80)
-    from distributed_llama_tpu.ops.pallas_q8 import _quantize_row
-
-    on_tpu = jax.default_backend() == "tpu"
-    for k in (4096, 11008):
-        x = jnp.ones((1, 1, k), jnp.bfloat16)
-        wn = jnp.ones((k,), jnp.float32)
-
-        g = jax.jit(functools.partial(rmsnorm_quantize_q80, eps=1e-5,
-                                      interpret=not on_tpu))
-        dt = timed(lambda a, b: g(a, b)[0], x, wn, reps=reps)
-        emit(section="prologue", op="rmsnorm_q80_kernel", k=k,
-             ms=round(dt * 1e3, 4))
-
-        def xla_form(a, b):
-            xb = rmsnorm(a, b, 1e-5)
-            return _quantize_row(xb.reshape(k), k // 32)[0]
-
-        dt = timed(jax.jit(xla_form), x, wn, reps=reps)
-        emit(section="prologue", op="rmsnorm_q80_xla", k=k,
-             ms=round(dt * 1e3, 4))
-
-        gq = jax.jit(functools.partial(quantize_q80_row, interpret=not on_tpu))
-        dt = timed(lambda a: gq(a)[0], x, reps=reps)
-        emit(section="prologue", op="quantize_kernel", k=k, ms=round(dt * 1e3, 4))
-
-
 def sec_attention(reps):
     """Cache read cost: full 2048-window vs 256-window at 7B geometry, per layer."""
     from distributed_llama_tpu.ops.attention import gqa_attention
@@ -271,14 +238,14 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--section", default=None,
                     choices=["dispatch", "stream", "matvec", "prefill_mm",
-                             "prologue", "attention", "collectives"])
+                             "attention", "collectives"])
     ap.add_argument("--quick", action="store_true")
     args = ap.parse_args()
     reps = 3 if args.quick else 10
     emit(section="meta", backend=jax.default_backend(),
          device=str(jax.devices()[0]))
     secs = {"dispatch": sec_dispatch, "stream": sec_stream, "matvec": sec_matvec,
-            "prefill_mm": sec_prefill_mm, "prologue": sec_prologue,
+            "prefill_mm": sec_prefill_mm,
             "attention": sec_attention, "collectives": sec_collectives}
     for name, fn in secs.items():
         if args.section in (None, name):

@@ -5,12 +5,11 @@ Runs the sharded decode step on the 8-device virtual CPU mesh (JAX_PLATFORMS=cpu
 + xla_force_host_platform_device_count=8 — set by this script) and compares:
 
     sp=1 tp=2            — baseline TP-only step
-    sp=2 tp=2, inscan    — ring path with the cache carried through the scan
-    sp=2 tp=2, deferred  — ring path with loop-invariant caches + window commit
+    sp=2|4 tp=2          — the striped ring over the whole sharded cache
+    sp=2|4 tp=2, window  — the ring bounded to a window bucket's slots
 
-CPU-mesh times are NOT hardware numbers (no ICI; ppermute is a memcpy), but the
-inscan-vs-deferred delta isolates exactly the carry-copy overhead the deferred
-discipline removes. Emits one JSON line per config.
+CPU-mesh times are NOT hardware numbers (no ICI; ppermute is a memcpy): they
+rank the configurations, no more. Emits one JSON line per config.
 
     python perf/sp_cost.py [--dim 512] [--layers 8] [--seq 1024] [--steps 20]
 """
@@ -37,12 +36,11 @@ from distributed_llama_tpu.parallel.tp import (init_sharded_kv_cache,
 from distributed_llama_tpu.quants import FloatType
 
 
-def run_config(spec, params, rope, *, sp, tp, cache_write, steps, pos0,
-               window=None):
+def run_config(spec, params, rope, *, sp, tp, steps, pos0, window=None):
     mesh = make_mesh(sp=sp, tp=tp)
     sparams = shard_params(params, mesh, spec)
     step = make_sharded_forward(spec, mesh, sparams, donate_cache=True,
-                                cache_write=cache_write, attn_window=window)
+                                attn_window=window)
     kc, vc = init_sharded_kv_cache(spec, mesh)
     tok = jnp.asarray([[1]], jnp.int32)
     # warm/compile + advance to pos0 so the ring walks a realistic live region
@@ -83,16 +81,13 @@ def main():
     pos0 = args.seq // 4
 
     configs = [
-        dict(sp=1, tp=2, cache_write="deferred"),
-        dict(sp=1, tp=2, cache_write="inscan"),
-        dict(sp=2, tp=2, cache_write="deferred"),
-        dict(sp=2, tp=2, cache_write="inscan"),
-        dict(sp=4, tp=2, cache_write="deferred"),
-        dict(sp=4, tp=2, cache_write="inscan"),
-        # windowed striped ring (deferred-only capability): rotations move
-        # ceil(window/sp) slots instead of the full shard
-        dict(sp=2, tp=2, cache_write="deferred", window=args.seq // 2),
-        dict(sp=4, tp=2, cache_write="deferred", window=args.seq // 2),
+        dict(sp=1, tp=2),
+        dict(sp=2, tp=2),
+        dict(sp=4, tp=2),
+        # windowed striped ring: rotations move ceil(window/sp) slots
+        # instead of the full shard
+        dict(sp=2, tp=2, window=args.seq // 2),
+        dict(sp=4, tp=2, window=args.seq // 2),
     ]
     for cfg in configs:
         ms = run_config(spec, params, rope, steps=args.steps, pos0=pos0, **cfg)

@@ -56,10 +56,6 @@ def main():
             notes.append(f"fallback: {rec['fallback_reason'][:50]}")
         if rec.get("provenance"):
             notes.append(f"{rec['provenance']} age={rec.get('age_s')}s")
-        if rec.get("cache_write") == "inscan":
-            notes.append("inscan")
-        if rec.get("prologue"):
-            notes.append("prologue")
         if "prefill_kernel" in rec:
             notes.append(f"prefill_kernel={rec['prefill_kernel']}"
                          + (f" cov={rec['prefill_kernel_coverage']}"

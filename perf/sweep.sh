@@ -35,14 +35,6 @@ run python bench.py --steps 64 --layout i8
 # merged projection launches A/B (wqkv/w13 fusion, default on)
 run python bench.py --steps 64 --no-fuse
 
-# fused rmsnorm+quantize prologue kernels (opt-in until this A/B lands)
-run python bench.py --steps 64 --prologue
-
-# cache-write discipline A/B (deferred = default; inscan carries the caches
-# through the layer scan — the round-4 trace blamed its carry copies for a
-# third of the step)
-run python bench.py --steps 64 --cache-write inscan
-
 # window sweep: growing live-context cost (watchdog grows the bucket as needed)
 run python bench.py --steps 64 --window 2048
 

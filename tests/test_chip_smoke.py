@@ -200,23 +200,24 @@ def test_parity_default_pair_runs_the_dequant_matmul(tiny_checkpoint):
     assert "xla-fallback" not in engaged
 
 
-# the entry points' opt-in families, and the switches that went with the
-# hardware A/B (PR 30): the dequant-matmul is the default, not a policy
-@pytest.mark.parametrize("policy, there", [("prologue", True),
-                                           ("prefill-kernel", False),
-                                           ("fused-matmul", False)])
-def test_parity_policies_are_engine_switches(policy, there):
+# the switches that went: with the hardware A/B (PR 30) the dequant-matmul
+# became the default, and PR 32 took out the prologue kernels and the in-scan
+# cache discipline with their flags. None is an Engine keyword or a flag of
+# the entry points any more, and parity has no opt-in family to compare.
+@pytest.mark.parametrize("flag", ["prologue", "cache-write",
+                                  "prefill-kernel", "fused-matmul"])
+def test_retired_switches_are_gone(flag):
     import inspect
 
+    from distributed_llama_tpu.apps.dllama import build_parser
     from distributed_llama_tpu.runtime.engine import Engine
 
-    switches = inspect.signature(Engine.__init__).parameters
-    assert (policy in parity.POLICIES) is there
-    if there:
-        assert parity.POLICIES[policy] and set(
-            parity.POLICIES[policy]) <= set(switches)
-    else:
-        assert policy.replace("-", "_") not in switches
+    word = flag.replace("-", "_")
+    assert not [n for n in inspect.signature(Engine.__init__).parameters
+                if word in n]
+    assert not [o for a in build_parser()._actions for o in a.option_strings
+                if flag in o]
+    assert not hasattr(parity, "POLICIES")
 
 
 def test_parity_shallow_cut_is_the_first_and_the_last_layer(tiny_checkpoint):

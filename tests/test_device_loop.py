@@ -99,9 +99,9 @@ def test_device_sample_greedy_and_topp():
 
 
 def test_device_loop_with_sp_striped_matches_host():
-    """Chunked device-loop generation on an sp=2 mesh (striped deferred cache)
-    must reproduce the tp-only host loop exactly — the loop carries the sharded
-    caches through its scan across both cache disciplines."""
+    """Chunked device-loop generation on an sp=2 mesh (striped cache) must
+    reproduce the tp-only host loop exactly — the loop carries the sharded
+    caches through its scan over decode steps."""
     spec = _spec()
     params = init_random_params(spec, FloatType.Q40, seed=11)
     sampler = Sampler(spec.vocab_size, temperature=0.0)
@@ -110,9 +110,8 @@ def test_device_loop_with_sp_striped_matches_host():
     ref = Engine(spec, params, tp=1)
     want, _ = ref.generate(list(prompt), 12, sampler)
 
-    for cw in (None, "inscan"):  # None = auto (deferred/striped)
-        eng = Engine(spec, params, tp=2, sp=2, cache_write=cw)
-        got, _ = eng.generate_chunked(list(prompt), 12,
-                                      Sampler(spec.vocab_size, temperature=0.0),
-                                      chunk=5)
-        assert got == want, (cw, got, want)
+    eng = Engine(spec, params, tp=2, sp=2)
+    got, _ = eng.generate_chunked(list(prompt), 12,
+                                  Sampler(spec.vocab_size, temperature=0.0),
+                                  chunk=5)
+    assert got == want, (got, want)

@@ -1,33 +1,24 @@
 """Tier-1 wiring for perf/fault_matrix.py (ISSUE 4 satellite, the
 test_smoke_lint.py pattern): the full injection-point x fault-kind matrix
-runs against the CPU-mesh engines and must produce ZERO invariant
-violations — no scheduler-thread death, no slot/lease leak, no unusable
-engine after an injected fault."""
+runs against the CPU-mesh engines, one case a family of
+`fault_matrix.FAMILIES`, and each must produce ZERO invariant violations —
+no scheduler-thread death, no slot/lease leak, no unusable engine after an
+injected fault — over exactly the cells the family declares."""
 
 import os
 import sys
+
+import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "perf"))
 
 import fault_matrix  # noqa: E402
 
 
-def test_fault_matrix_no_scheduler_death_or_slot_leak():
-    cells, problems = fault_matrix.run_matrix(include_paged=True)
-    # the batch family runs twice: pipelined AND serialized super-steps —
-    # every injection point's invariants must hold under overlapped
-    # dispatches too (docs/SERVING.md "Pipelined decode"); the speculation
-    # family likewise runs spec-enabled engines under both schedulers with
-    # survivor token-identity on its victim-only cells
-    expected = (2 * len(fault_matrix.BATCH_POINTS)
-                + 2 * len(fault_matrix.SPEC_POINTS)
-                + len(fault_matrix.ENGINE_POINTS)
-                + len(fault_matrix.PAGED_POINTS)
-                + len(fault_matrix.ROUTER_POINTS)) * len(fault_matrix.KINDS) \
-        + fault_matrix.SUPERVISOR_CELLS + fault_matrix.DURABILITY_CELLS \
-        + fault_matrix.FAIRNESS_CELLS + fault_matrix.DISAGG_CELLS \
-        + fault_matrix.GRAY_CELLS + fault_matrix.DRAFT_CELLS \
-        + fault_matrix.FUSED_CELLS + fault_matrix.CONSTRAIN_CELLS
+@pytest.mark.parametrize("family", list(fault_matrix.FAMILIES))
+def test_fault_matrix_no_scheduler_death_or_slot_leak(family):
+    expected, run = fault_matrix.FAMILIES[family]
+    cells, problems = run()
     assert cells == expected, (cells, expected)
     assert not problems, "\n".join(problems)
 

@@ -562,8 +562,12 @@ def model_files(tmp_path_factory):
 @pytest.fixture(scope="module")
 def fleet(model_files):
     """Two REAL replicas + the durable router with the gray layer armed
-    but inert (adaptive thresholds parked at never-adapt; tests flip the
-    shared GrayConfig per scenario and restore it)."""
+    but inert (adaptive thresholds AND the outlier detector parked at
+    never-adapt; tests flip the shared GrayConfig per scenario and restore
+    it). The detector judges wall-clock TTFBs: left at its default of 20
+    samples, the hammer test's slowed victim could enter probation or not
+    by how a loaded box timed its requests, and every later test on this
+    fleet inherited a rotation of one."""
     mpath, tpath = model_files
     reps = []
     for _ in range(2):
@@ -578,7 +582,7 @@ def fleet(model_files):
                           host="127.0.0.1", port=0, poll_interval=0.15,
                           block_bytes=16, retries=2, try_timeout=60.0,
                           gray=GrayConfig(min_lat_samples=10 ** 9,
-                                          hedge=False))
+                                          min_samples=10 ** 9, hedge=False))
     threading.Thread(target=router.serve_forever, daemon=True).start()
     yield {"reps": reps, "router": router,
            "port": router.server_address[1]}
@@ -831,10 +835,13 @@ def test_stalled_stream_fails_over_within_idle_gap(fleet, gray_cfg):
         "router_resumed_requests_total") or 0
     assert resumed1 > resumed0  # the durable path did the save
     # unstick the wedged engine (its scheduler sleeps in the injected
-    # dispatch) so later tests inherit a working fleet
-    for be, _srv, _p in fleet["reps"]:
-        if be.dispatch_age() > 5.0:
-            be.recover_wedged()
+    # dispatch) so later tests inherit a working fleet. The stream is over,
+    # so the survivor has nothing in flight (age 0); the victim's age is
+    # the idle gap the router waited out, and more
+    wedged = [be for be, _srv, _p in fleet["reps"]
+              if be.dispatch_age() >= gray_cfg.idle_timeout]
+    assert len(wedged) == 1, [be.dispatch_age() for be, _s, _p in fleet["reps"]]
+    wedged[0].recover_wedged()
     state = fleet["router"].router_state
     deadline = time.monotonic() + 30
     while time.monotonic() < deadline:

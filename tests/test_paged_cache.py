@@ -113,6 +113,33 @@ def test_paged_reset_discards_stale_history(spec_params):
                                rtol=2e-4, atol=2e-4)
 
 
+def test_a_failed_cold_step_leaves_the_engine_where_it_was(spec_params):
+    """A cold callback that raises takes the step's donated ring with it:
+    the engine rebuilds the ring from the host store, so the SAME chunk sent
+    again gives the logits of a run that never failed (no reset needed)."""
+    from distributed_llama_tpu.resilience import faults
+    from distributed_llama_tpu.resilience.faults import FaultSpec
+
+    spec, params = spec_params
+    ref, paged = _engines(spec, params, "host")
+    rng = np.random.default_rng(4)
+    toks = rng.integers(0, spec.vocab_size, size=150).tolist()
+    for eng in (ref, paged):  # past the cold boundary and one wrap
+        for lo, hi in ((0, 64), (64, 128), (128, 140)):
+            eng.infer_chunk(toks[lo:hi])
+    with faults.active(FaultSpec("paged.cold_attend", kind="error", count=1)):
+        with pytest.raises(Exception):
+            paged.infer_chunk(toks[140:148])
+    faults.uninstall()
+    assert paged.pos == 140
+    np.testing.assert_allclose(paged.infer_chunk(toks[140:148]),
+                               ref.infer_chunk(toks[140:148]),
+                               rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(paged.infer_chunk(toks[148:149]),
+                               ref.infer_chunk(toks[148:149]),
+                               rtol=2e-4, atol=2e-4)
+
+
 def test_warm_phase_skips_cold_callbacks(spec_params):
     """While pos + T <= resident the cold segment is provably empty: the
     engine must drive the callback-free plain step (no host round-trips), and

@@ -105,3 +105,24 @@ def test_forward_with_pallas_params():
         got, want = np.asarray(got), np.asarray(want)
         rel = np.abs(got - want).max() / (np.abs(want).max() + 1e-9)
         assert rel < rel_tol, rel
+
+
+def test_q8_inline_matvec_matches_xexp_variant():
+    """The i8 inline-Xexp matvec (scratch scatter) must reproduce the
+    Xexp-materializing variant exactly — same int8 dot, same epilogue."""
+    from distributed_llama_tpu.ops.pallas_q8 import (_q8_matvec,
+                                                     _q8_matvec_inline,
+                                                     block_diag_scatter)
+
+    rng = np.random.RandomState(3)
+    n, k = 48, 256
+    nb = k // QK
+    xq = jnp.asarray(rng.randint(-127, 128, (1, k)).astype(np.int8))
+    sx = jnp.asarray(rng.rand(1, nb).astype(np.float32) * 0.01)
+    w8 = jnp.asarray(rng.randint(-8, 8, (n, k)).astype(np.int8))
+    scales = jnp.asarray(rng.rand(n, nb).astype(np.float32) * 0.01)
+
+    xexp = block_diag_scatter(xq.reshape(k), nb)
+    want = _q8_matvec(xexp, sx, w8, scales, interpret=True)
+    got = _q8_matvec_inline(xq, sx, w8, scales, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)

@@ -64,10 +64,9 @@ def run_block(spec: ModelSpec, bp: dict, x: np.ndarray) -> np.ndarray:
     block = functools.partial(
         _block, spec=spec, rope=rope, start_pos=jnp.int32(0),
         positions=jnp.zeros((1,), jnp.int32), axis_name=None, sp_axis_name=None,
-        sp_size=1, use_pallas=False, compress=False, window=None)
+        sp_size=1, use_pallas=False, compress=False, window=None, kc=kc, vc=vc)
     bp = {k: (v if isinstance(v, QTensor) else jnp.asarray(v)) for k, v in bp.items()}
-    (x_out, _, _), _ = block((jnp.asarray(x)[None, None, :], kc, vc),
-                             (bp, jnp.int32(0)))
+    x_out, _ = block(jnp.asarray(x)[None, None, :], (bp, jnp.int32(0)))
     return np.asarray(x_out)[0, 0]
 
 

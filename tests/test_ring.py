@@ -13,11 +13,8 @@ from jax.sharding import PartitionSpec as P
 from distributed_llama_tpu.models.forward import forward, init_kv_cache
 from distributed_llama_tpu.models.params import init_random_params
 from distributed_llama_tpu.models.spec import ArchType, ModelSpec, RopeType
-from distributed_llama_tpu.ops.attention import gqa_attention, update_kv_cache
-from distributed_llama_tpu.ops.ring_attention import (
-    ring_attention,
-    update_kv_cache_sharded,
-)
+from distributed_llama_tpu.ops.attention import gqa_attention
+from distributed_llama_tpu.ops.ring_attention import ring_attention
 from distributed_llama_tpu.ops.rope import RopeTables
 from distributed_llama_tpu.parallel.mesh import make_mesh
 from distributed_llama_tpu.parallel.tp import (init_sharded_kv_cache, make_sharded_forward,
@@ -30,7 +27,8 @@ from distributed_llama_tpu.runtime.sampler import Sampler
 @pytest.mark.parametrize("sp", [2, 4])
 @pytest.mark.parametrize("t", [1, 5])
 def test_ring_attention_equals_full(sp, t):
-    """Ring attention over sp sequence shards == plain attention over the full cache."""
+    """Ring attention over sp sequence shards == plain attention over the full
+    cache. The shards are striped: member m's slot j holds position j*sp + m."""
     rng = np.random.RandomState(0)
     b, hq, hk, s, hs = 1, 8, 4, 32, 16
     pos0 = 11  # queries at positions 11..11+t
@@ -43,6 +41,9 @@ def test_ring_attention_equals_full(sp, t):
 
     mesh = make_mesh(sp=sp, tp=1)
 
+    def stripe(c):  # global index m*Sb + j <- position j*sp + m
+        return c.reshape(b, hk, s // sp, sp, hs).swapaxes(2, 3).reshape(c.shape)
+
     def f(q, kc, vc):
         return ring_attention(q, kc, vc, positions, axis_name="sp", axis_size=sp)
 
@@ -50,36 +51,8 @@ def test_ring_attention_equals_full(sp, t):
         f, mesh=mesh,
         in_specs=(P(), P(None, None, "sp", None), P(None, None, "sp", None)),
         out_specs=P(), check_vma=False))
-    got = np.asarray(sharded(q, kc, vc))
+    got = np.asarray(sharded(q, stripe(kc), stripe(vc)))
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-4)
-
-
-@pytest.mark.parametrize("t,start", [(1, 0), (1, 17), (8, 12), (8, 16)])
-def test_update_kv_cache_sharded_matches_full(t, start):
-    """Sharded cache writes (incl. chunks straddling a shard boundary) == full-cache
-    update then manual sharding."""
-    rng = np.random.RandomState(1)
-    b, hk, s, hs, sp = 1, 2, 32, 8, 4
-    kc = jnp.asarray(rng.randn(b, hk, s, hs).astype(np.float32))
-    vc = jnp.asarray(rng.randn(b, hk, s, hs).astype(np.float32))
-    k_new = jnp.asarray(rng.randn(b, t, hk, hs).astype(np.float32))
-    v_new = jnp.asarray(rng.randn(b, t, hk, hs).astype(np.float32))
-
-    kw, vw = update_kv_cache(kc, vc, k_new, v_new, jnp.int32(start))
-
-    mesh = make_mesh(sp=sp, tp=1)
-    kvp = P(None, None, "sp", None)
-
-    def f(kc, vc, k_new, v_new):
-        return update_kv_cache_sharded(kc, vc, k_new, v_new, jnp.int32(start),
-                                       axis_name="sp")
-
-    sharded = jax.jit(jax.shard_map(
-        f, mesh=mesh, in_specs=(kvp, kvp, P(), P()),
-        out_specs=(kvp, kvp), check_vma=False))
-    kg, vg = sharded(kc, vc, k_new, v_new)
-    np.testing.assert_allclose(np.asarray(kg), np.asarray(kw), atol=1e-6)
-    np.testing.assert_allclose(np.asarray(vg), np.asarray(vw), atol=1e-6)
 
 
 def _tiny_spec():
@@ -88,12 +61,11 @@ def _tiny_spec():
                      rope_type=RopeType.LLAMA).resolved()
 
 
-@pytest.mark.parametrize("cache_write", ["inscan", "deferred"])
-def test_forward_sp_tp_equals_unsharded(cache_write):
+def test_forward_sp_tp_equals_unsharded():
     """Full model on a 2x2 (sp x tp) mesh == single-device forward: prefill then a
-    decode step continuing from the sharded cache. Both cache disciplines — the
-    deferred form keeps the sequence-sharded caches loop-invariant and commits via
-    the masked window write (commit_kv_rows_sharded)."""
+    decode step continuing from the sharded cache, which stays loop-invariant in
+    the layer scan and is committed via the masked window write
+    (commit_kv_rows_sharded)."""
     spec = _tiny_spec()
     params = init_random_params(spec, FloatType.F32, seed=3)
     rope = RopeTables.create(spec)
@@ -106,8 +78,7 @@ def test_forward_sp_tp_equals_unsharded(cache_write):
 
     mesh = make_mesh(sp=2, tp=2)
     sparams = shard_params(params, mesh, spec)
-    step = make_sharded_forward(spec, mesh, sparams, donate_cache=False,
-                                cache_write=cache_write)
+    step = make_sharded_forward(spec, mesh, sparams, donate_cache=False)
     kc, vc = init_sharded_kv_cache(spec, mesh)
     got, gkc, gvc = step(sparams, rope, tokens, kc, vc, jnp.int32(0))
     got2, _, _ = step(sparams, rope, jnp.asarray([[3]]), gkc, gvc, jnp.int32(8))
@@ -130,43 +101,40 @@ def _destripe(cache: np.ndarray, sp: int) -> np.ndarray:
     return out
 
 
-def test_sp_deferred_cache_state_matches_inscan():
-    """After prefill + a boundary-straddling chunk + a decode step, the deferred
-    (striped) cache must hold the same committed rows as inscan once the stripe
-    permutation is undone."""
+def test_sp_striped_cache_state_matches_unsharded():
+    """After prefill + a chunk that straddles the contiguous shard boundary + a
+    decode step, the striped cache must hold the unsharded run's committed rows
+    once the stripe permutation is undone."""
     spec = _tiny_spec()  # seq_len=32, sp=2 -> shard size 16
     params = init_random_params(spec, FloatType.F32, seed=9)
     rope = RopeTables.create(spec)
     mesh = make_mesh(sp=2, tp=2)
     sparams = shard_params(params, mesh, spec)
+    step = make_sharded_forward(spec, mesh, sparams, donate_cache=False)
 
-    caches = {}
-    for cw in ("inscan", "deferred"):
-        step = make_sharded_forward(spec, mesh, sparams, donate_cache=False,
-                                    cache_write=cw)
-        kc, vc = init_sharded_kv_cache(spec, mesh)
-        # prefill 12, then a 8-token chunk at 12..20 (straddles the shard
-        # boundary at 16), then a decode step at 20
-        _, kc, vc = step(sparams, rope, jnp.asarray([list(range(1, 13))]), kc, vc,
-                         jnp.int32(0))
-        _, kc, vc = step(sparams, rope, jnp.asarray([list(range(20, 28))]), kc, vc,
-                         jnp.int32(12))
-        _, kc, vc = step(sparams, rope, jnp.asarray([[3]]), kc, vc, jnp.int32(20))
-        caches[cw] = (np.asarray(kc), np.asarray(vc))
+    def run(step, params, kc, vc):
+        # prefill 12, then a 8-token chunk at 12..20 (across position 16),
+        # then a decode step at 20
+        for toks, pos in ((list(range(1, 13)), 0), (list(range(20, 28)), 12),
+                          ([3], 20)):
+            _, kc, vc = step(params, rope, jnp.asarray([toks]), kc, vc,
+                             jnp.int32(pos))
+        return np.asarray(kc), np.asarray(vc)
 
-    kd = _destripe(caches["deferred"][0], sp=2)
-    vd = _destripe(caches["deferred"][1], sp=2)
-    # committed region [0, 21) must agree exactly; beyond it is unwritten scratch
-    np.testing.assert_allclose(kd[:, :, :, :21],
-                               caches["inscan"][0][:, :, :, :21], atol=1e-6)
-    np.testing.assert_allclose(vd[:, :, :, :21],
-                               caches["inscan"][1][:, :, :, :21], atol=1e-6)
+    kw, vw = run(lambda p, *a: forward(p, spec, *a), params,
+                 *init_kv_cache(spec))
+    kg, vg = run(step, sparams, *init_sharded_kv_cache(spec, mesh))
+    # committed region [0, 21) must agree; beyond it is unwritten scratch
+    np.testing.assert_allclose(_destripe(kg, sp=2)[:, :, :, :21],
+                               kw[:, :, :, :21], atol=1e-5)
+    np.testing.assert_allclose(_destripe(vg, sp=2)[:, :, :, :21],
+                               vw[:, :, :, :21], atol=1e-5)
 
 
-def test_sp_deferred_chunk_wider_than_shard():
+def test_sp_chunk_wider_than_shard():
     """sp=4 on seq_len=32 gives 8-slot shards; a 16-token prefill chunk is wider
-    than a shard — the deferred commit must scatter it across multiple shards
-    (regression: the windowed write only handles t <= shard size)."""
+    than a shard — the commit must spread it over every member's slot window
+    (regression: a window write that only handled t <= shard size)."""
     spec = _tiny_spec()  # seq_len=32 -> sb=8 at sp=4
     params = init_random_params(spec, FloatType.F32, seed=4)
     rope = RopeTables.create(spec)
@@ -179,8 +147,7 @@ def test_sp_deferred_chunk_wider_than_shard():
 
     mesh = make_mesh(sp=4, tp=2)
     sparams = shard_params(params, mesh, spec)
-    step = make_sharded_forward(spec, mesh, sparams, donate_cache=False,
-                                cache_write="deferred")
+    step = make_sharded_forward(spec, mesh, sparams, donate_cache=False)
     kc, vc = init_sharded_kv_cache(spec, mesh)
     got, gkc, gvc = step(sparams, rope, tokens, kc, vc, jnp.int32(0))
     got2, _, _ = step(sparams, rope, jnp.asarray([[3]]), gkc, gvc, jnp.int32(16))
@@ -190,7 +157,7 @@ def test_sp_deferred_chunk_wider_than_shard():
                                rtol=1e-3)
 
 
-def test_sp_deferred_windowed_ring_matches_full():
+def test_sp_windowed_ring_matches_full():
     """Striped windowed ring: with attn_window=32 on a seq_len=64 cache, only
     ceil(32/sp)=16 slots per member rotate, and results must equal the
     unsharded forward while every live position is inside the window."""
@@ -209,7 +176,7 @@ def test_sp_deferred_windowed_ring_matches_full():
     mesh = make_mesh(sp=2, tp=2)
     sparams = shard_params(params, mesh, spec)
     step = make_sharded_forward(spec, mesh, sparams, donate_cache=False,
-                                cache_write="deferred", attn_window=32)
+                                attn_window=32)
     kc, vc = init_sharded_kv_cache(spec, mesh)
     got, gkc, gvc = step(sparams, rope, tokens, kc, vc, jnp.int32(0))
     got2, _, _ = step(sparams, rope, jnp.asarray([[3]]), gkc, gvc, jnp.int32(8))

@@ -82,7 +82,7 @@ def _paged_cache(spec, bt=8):
             jnp.arange(1, w + 1, dtype=jnp.int32)[None], bt)
 
 
-@pytest.mark.parametrize("path", ["dense", "dense-deferred", "paged-gather",
+@pytest.mark.parametrize("path", ["dense", "dense-window", "paged-gather",
                                   "paged-kernel"])
 def test_prefill_then_cached_decode_matches_the_full_forward_pass(
         toy, sequence, path):
@@ -92,13 +92,14 @@ def test_prefill_then_cached_decode_matches_the_full_forward_pass(
     kw, pos = {}, (lambda p: jnp.int32(p))
     if path.startswith("paged"):
         kc, vc, tables, bt = _paged_cache(spec)
-        kw = dict(cache_write="deferred", block_tables=tables, block_tokens=bt,
+        kw = dict(block_tables=tables, block_tokens=bt,
                   paged_kernel=path == "paged-kernel")
         pos = lambda p: jnp.asarray([p], jnp.int32)  # noqa: E731
     else:
         kc, vc = init_kv_cache(spec)
-        if path == "dense-deferred":
-            kw = dict(cache_write="deferred")
+        if path == "dense-window":
+            # a static bucket over the cache read, past every position here
+            kw = dict(attn_window=spec.seq_len // 2)
     got, p = [], 0
     # the prompt in chunks of 17 and 24, then one token at a time
     for n in (17, PROMPT - 17) + (1,) * DECODE:

@@ -13,9 +13,8 @@ The fused dequant-matmul is held to the benchmark cells' real shapes at 8,
 64 and 512 rows (`CELL_MATMULS`): what `qmatmul` hands it in a decode step,
 an 8-token and a 64-token chunk at 8 slots. Its gate declines none of them.
 
-The cases marked "repaired" were refused before this file existed: the
-inline-Xexp matvec at K=14336 for 1.9 MiB too much VMEM, and the prologue
-kernels for an in-kernel lane-split reshape.
+The case marked "repaired" was refused before this file existed: the
+inline-Xexp matvec at K=14336 for 1.9 MiB too much VMEM.
 """
 
 import functools
@@ -32,7 +31,6 @@ from distributed_llama_tpu.ops.moe_grouped import capacity, row_tile
 from distributed_llama_tpu.ops.pallas_attention import fused_decode_attention
 from distributed_llama_tpu.ops.pallas_moe_grouped import _moe_grouped_q4
 from distributed_llama_tpu.ops.pallas_paged_attention import paged_attention
-from distributed_llama_tpu.ops.pallas_prologue import _quantize, _rmsnorm_q80
 from distributed_llama_tpu.ops.pallas_q4 import _q4_matvec, _q4_matvec_inline
 from distributed_llama_tpu.ops.pallas_q4_mm import q4_matmul, q4_mm_supported
 from distributed_llama_tpu.quants import FloatType, QTensor
@@ -86,7 +84,7 @@ def matvec(n, k):
 
 
 def matvec_inline(n, k):
-    """--prologue's feed: Xexp built in VMEM scratch."""
+    """The matvec with Xexp built in VMEM scratch (`inline_xexp`)."""
     nb = k // 32
     return (_q4_matvec_inline,
             [((1, k), I8), ((1, nb), F32), *_q4_weight(n, k)], {})
@@ -218,12 +216,8 @@ CASES = {
     "matmul-m2-wo": matmul(2, DIM, DIM),
     "matmul-m8-wcls-f32": matmul(8, VOCAB, DIM, out=F32),
     **CELL_MATMULS,
-    # repaired: inline matvec VMEM at K=14336, and the prologue kernels
+    # repaired: inline matvec VMEM at K=14336
     "repaired-matvec-inline-w2": matvec_inline(DIM, HIDDEN),
-    "repaired-prologue-rmsnorm-dim": (
-        _rmsnorm_q80, [((1, DIM), BF16), ((1, DIM), F32)], {"eps": 1e-5}),
-    "repaired-prologue-quantize-hidden": (
-        _quantize, [((1, HIDDEN), BF16)], {}),
 }
 
 
