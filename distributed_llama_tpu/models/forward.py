@@ -348,30 +348,42 @@ def takes_the_scan(rows: int, k: int, experts: int) -> bool:
     return rows * k >= SCAN_FROM_MEAN_RUN * experts
 
 
+def _expert_ffn(xb, bp, e, act, use_pallas):
+    """down_e(act(gate_e x) * up_e x) of every row of xb for expert e (a
+    traced index into the stacks over experts, or into a `LayerOf` a
+    layer's experts). The expert is named, not sliced: `qmatmul` reads it in
+    place where a kernel can and takes the slice where none does."""
+    def mm(v, name):
+        w = bp[name]
+        return qmatmul(v, w.of(e) if isinstance(w, LayerOf)
+                       else LayerOf(w, (e,)), use_pallas=use_pallas)
+
+    if "moe_gu" in bp:  # fused up+gate stack (fuse_matvec_groups)
+        hb = _gated_split(mm(xb, "moe_gu"), act, gate_first=False)
+    else:
+        hb = mm(xb, "moe_up") * act(mm(xb, "moe_gate"))
+    return mm(hb, "moe_down")
+
+
 def _expert_scan(xb, bp, top_i, weights, act, use_pallas, el, offset):
-    """Every held expert over every row, one expert dequantized a step, its
-    output masked by the routing weights (zero for rows that did not pick
-    it): the form for many rows an expert, where nearly every expert is read
-    anyway and a dense (B*T, d) matmul an expert costs less than sorting
-    and gathering rows. `offset`: the first expert this stack holds."""
+    """Every held expert over every row, one expert a step, its output
+    masked by the routing weights (zero for rows that did not pick it): the
+    form for many rows an expert, where nearly every expert is read anyway
+    and a dense (B*T, d) matmul an expert costs less than sorting and
+    gathering rows. The scan is over the expert's INDEX: the fused
+    dequant-matmul reads the expert's blocks out of the whole stack at
+    (layer, expert), any other lowering slices the expert out and
+    dequantizes it there. `offset`: the first expert this stack holds."""
     one_hot = jax.nn.one_hot(top_i - offset, el, dtype=xb.dtype)  # (B,T,K,el)
     combine = jnp.einsum("btke,btk->ebt", one_hot, weights.astype(xb.dtype))
-    merged = "moe_gu" in bp
 
-    def step(acc, ew):
-        *stacks, down_e, comb = ew  # QTensors (h,d) / (d,h); comb (B,T)
-        if merged:
-            hb = _gated_split(qmatmul(xb, stacks[0], use_pallas=use_pallas),
-                              act, gate_first=False)
-        else:
-            hb = qmatmul(xb, stacks[0], use_pallas=use_pallas) * act(
-                qmatmul(xb, stacks[1], use_pallas=use_pallas))
-        out_e = qmatmul(hb, down_e, use_pallas=use_pallas)
-        return acc + out_e * comb[..., None], None
+    def step(acc, ec):
+        e, comb = ec  # comb (B,T)
+        return acc + _expert_ffn(xb, bp, e, act, use_pallas) * comb[
+            ..., None], None
 
-    ups = (bp["moe_gu"],) if merged else (bp["moe_up"], bp["moe_gate"])
     out, _ = jax.lax.scan(step, jnp.zeros_like(xb),
-                          (*ups, bp["moe_down"], combine))
+                          (jnp.arange(el, dtype=jnp.int32), combine))
     return out, jnp.sum(one_hot).astype(jnp.int32)
 
 
@@ -415,8 +427,8 @@ def _moe_ffn(xb, bp, spec: ModelSpec, axis_name, use_pallas, compress,
             router_logits = _router_logits(xb, bp)
         top_i, weights = _route(router_logits, k)
 
-    merged = "moe_gu" in bp  # fused up+gate stack (fuse_matvec_groups)
-    gu_stack = bp["moe_gu"] if merged else bp["moe_up"]
+    # the fused up+gate stack (fuse_matvec_groups) where there is one
+    gu_stack = bp["moe_gu"] if "moe_gu" in bp else bp["moe_up"]
     el = gu_stack.shape[0]  # shard-local expert count
     offered, zero = jnp.int32(el), jnp.int32(0)
     offset = 0  # the first expert this stack holds
@@ -425,27 +437,14 @@ def _moe_ffn(xb, bp, spec: ModelSpec, axis_name, use_pallas, compress,
     with jax.named_scope("moe_ffn"):
         if (use_pallas and b * t == 1 and el == spec.n_experts
                 and gu_stack.layout in ("i4p", "i8")):
-            # Decode through the fused matvec kernels: dynamic_slice each active
-            # expert's packed planes out of the stacked (E, ...) QTensor (moving
+            # Decode through the fused matvec kernels: each active expert's
+            # packed planes sliced out of the stacked (E, ...) QTensor (moving
             # exactly that expert's bytes through HBM — the reference's
-            # per-active-expert matmuls, grok1-tasks.cpp:128-144) and run the
+            # per-active-expert matmuls, grok1-tasks.cpp:128-144) into the
             # same q4/q8 kernel as the dense path.
-            def expert_q(wstack, e):
-                return jax.tree_util.tree_map(
-                    lambda a: jax.lax.dynamic_slice_in_dim(a, e, 1, 0)[0], wstack)
-
             out = jnp.zeros_like(xb)
             for j in range(k):
-                e = top_i.reshape(k)[j]
-                if merged:
-                    hb = _gated_split(qmatmul(xb, expert_q(bp["moe_gu"], e),
-                                              use_pallas=True), act,
-                                      gate_first=False)
-                else:
-                    hb = qmatmul(xb, expert_q(bp["moe_up"], e),
-                                 use_pallas=True) * act(
-                        qmatmul(xb, expert_q(bp["moe_gate"], e), use_pallas=True))
-                out_e = qmatmul(hb, expert_q(bp["moe_down"], e), use_pallas=True)
+                out_e = _expert_ffn(xb, bp, top_i.reshape(k)[j], act, True)
                 out = out + out_e * weights.reshape(k)[j].astype(xb.dtype)
             stats = jnp.stack([jnp.int32(k)] * 3 + [offered, zero, zero])
         elif takes_the_scan(b * t, k, spec.n_experts):
@@ -476,7 +475,8 @@ def _block(x, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions,
     K/V rows, for forward() to commit in one top-level write, with the
     layer's stats of _moe_ffn last (zeros where the block routes nothing).
     `stacks`: the weights that stay whole over the scan (forward() below),
-    named into `bp` as `LayerOf` this layer's index.
+    named into `bp` as `LayerOf` this layer's index: a matrix of a dense
+    stack, a layer's experts of an expert stack.
     """
     stats = jnp.zeros((N_MOE_STATS,), jnp.int32)  # of the expert layer: a ys
     # the layer's kind rides in the xs beside its index, where the model has
@@ -484,7 +484,7 @@ def _block(x, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions,
     bp, layer_idx, *kind = layer
     rope_on, swa = kind if kind else (None, None)
     if stacks:
-        bp = {**bp, **{n: LayerOf(w, layer_idx) for n, w in stacks.items()}}
+        bp = {**bp, **{n: LayerOf(w, (layer_idx,)) for n, w in stacks.items()}}
     router_logits = None
     if spec.is_moe and spec.router_input == RouterInput.BLOCK_INPUT:
         # the router reads the residual stream as the block receives it,
@@ -593,9 +593,11 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
             "paging")
         assert block_tokens >= 1 and start_pos.ndim == 1, (
             "paged KV needs block_tokens and per-row start_pos")
-    # the weights the fused dequant-matmul reads stay out of the scan's
-    # sliced operands: it takes its blocks from the whole stack at the
-    # layer's index, and a slice would be a copy of the layer's weights
+    # the weights the kernels read in place stay out of the scan's sliced
+    # operands, the expert stacks (L, E, rows, K/2) among them: the fused
+    # dequant-matmul and the grouped expert kernels take their blocks from
+    # the whole stack at the layer's (and the expert's) index, and a slice
+    # would be a copy of the layer's weights, every expert touched or not
     stacks = {n: w for n, w in params["blocks"].items()
               if reads_the_stack(w, tokens.shape[0] * t, use_pallas)}
     block_fn = functools.partial(_block, spec=spec, rope=rope, start_pos=start_pos,

@@ -44,27 +44,43 @@ _selections_lock = threading.Lock()
 
 
 class LayerOf(NamedTuple):
-    """Layer `layer` (a traced index) of a weight stacked over layers, not
-    yet sliced: the fused dequant-matmul reads its blocks out of the whole
-    stack, and a slice made for it would be one more copy of every layer's
-    packed weights a dispatch (ops/pallas_q4_mm.py `_q4_matmul`). Any other
-    lowering takes `one()`."""
+    """The matrix at leading indices `at` (traced) of a weight stacked over
+    layers, or over layers and experts, not yet sliced: `(layer,)` of an
+    (L, N, K) stack, `(layer, expert)` of an (L, E, N, K) one, `(expert,)`
+    of a layer's (E, N, K). With fewer indices than leading axes it names
+    the stack under them (a layer's experts). The fused dequant-matmul and
+    the grouped expert kernels read their blocks out of the whole stack, and
+    a slice made for them would be one more copy of every layer's packed
+    weights a dispatch (ops/pallas_q4_mm.py `_q4_matmul`). Any other lowering
+    takes `one()`."""
     stack: QTensor
-    layer: jax.Array
+    at: tuple
+
+    @property
+    def shape(self):
+        return self.stack.shape[len(self.at):]
+
+    def of(self, i) -> "LayerOf":
+        """Entry i of the stack this names."""
+        return LayerOf(self.stack, (*self.at, i))
 
     def one(self) -> QTensor:
-        return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
-            a, self.layer, 0, keepdims=False), self.stack)
+        w = self.stack
+        for i in self.at:
+            w = jax.tree.map(lambda a, i=i: jax.lax.dynamic_index_in_dim(
+                a, i, 0, keepdims=False), w)
+        return w
 
 
 def reads_the_stack(w, m: int, use_pallas: bool) -> bool:
-    """Whether `qmatmul` at m rows would hand this layer-stacked weight to
-    the fused dequant-matmul, stack and all: what the layer scan
-    (models/forward.py) keeps out of its sliced operands."""
+    """Whether at m rows a kernel would read this stacked weight (one
+    leading axis or more: layers, experts) in place, stack and all: what the
+    layer scan (models/forward.py) keeps out of its sliced operands, and what
+    `qmatmul` hands the fused dequant-matmul whole."""
     from .pallas_q4_mm import q4_mm_supported
 
-    return bool(use_pallas and isinstance(w, QTensor)
-                and q4_mm_supported(w, m, stacked=True))
+    return bool(use_pallas and isinstance(w, QTensor) and w.data.ndim > 2
+                and q4_mm_supported(w, m, stacked=w.data.ndim - 2))
 
 
 def _record(kernel: str, m: int, w: QTensor) -> None:
@@ -107,14 +123,14 @@ def qmatmul(x: jax.Array, w: QTensor | LayerOf, *, use_pallas: bool = False,
     small projections at 8 and 64 rows, faster everywhere else (PERF.md
     section 6, PR 31), so the gate declines no shape for speed.
 
-    w may be a `LayerOf`: the dequant-matmul then reads the layer out of the
-    stack, every other lowering gets the slice."""
+    w may be a `LayerOf`: the dequant-matmul then reads the matrix out of
+    the stack, every other lowering gets the slice."""
     m = math.prod(x.shape[:-1])
     dt = out_dtype or x.dtype
-    layer = None
+    at = ()
     if isinstance(w, LayerOf):
         if reads_the_stack(w.stack, m, use_pallas):
-            w, layer = w.stack, w.layer
+            w, at = w
         else:
             w = w.one()
     if use_pallas and m == 1:
@@ -134,12 +150,10 @@ def qmatmul(x: jax.Array, w: QTensor | LayerOf, *, use_pallas: bool = False,
         from .pallas_q4_mm import q4_matmul, q4_mm_supported
 
         if not _kernel_select_ok(m, w):
-            return _qmatmul_xla(
-                x, w if layer is None else LayerOf(w, layer).one(),
-                out_dtype=out_dtype)
-        if q4_mm_supported(w, m, stacked=layer is not None):
+            return _qmatmul_xla(x, LayerOf(w, at).one(), out_dtype=out_dtype)
+        if q4_mm_supported(w, m, stacked=len(at)):
             _record("q4_mm", m, w)
-            return q4_matmul(x, w, layer=layer, out_dtype=dt)
+            return q4_matmul(x, w, at=at, out_dtype=dt)
     _record("xla", m, w)
     return _qmatmul_xla(x, w, out_dtype=out_dtype)
 

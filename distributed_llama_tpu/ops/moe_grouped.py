@@ -34,6 +34,7 @@ import jax.numpy as jnp
 
 from ..quants import QTensor
 from .kernels import ACTS
+from .matmul import LayerOf
 
 MIN_TILE, MAX_TILE = 16, 256
 
@@ -129,9 +130,11 @@ def grouped_expert_ffn(x, top_i, weights, bp, *, act_name: str, el: int,
     """sum_j weights[n, j] * down_e(act(gate_e x_n) * up_e x_n), e = top_i[n, j],
     over the experts this stack holds. x (N, d); top_i, weights (N, k); bp has
     `moe_gu` (the merged [up|gate] stack) or `moe_up` and `moe_gate`, and
-    `moe_down`, with `el` experts on the leading axis. Returns ((N, d) in
-    x.dtype, stats): stats int32 (3,) = assignments held here, rows computed
-    (tiles in use x tile), experts touched."""
+    `moe_down`, with `el` experts on the leading axis, or each as a `LayerOf`
+    a layer of the stack over layers: the kernels read that in place, XLA
+    its slice. Returns ((N, d) in x.dtype, stats): stats int32 (3,) =
+    assignments held here, rows computed (tiles in use x tile), experts
+    touched."""
     n, k = top_i.shape
     merged = "moe_gu" in bp
     up = bp["moe_gu"] if merged else bp["moe_up"]
@@ -157,6 +160,8 @@ def grouped_expert_ffn(x, top_i, weights, bp, *, act_name: str, el: int,
         out = moe_grouped_q4(rows, p["tile_expert"], p["n_used"], up, gate,
                              down, tile=tile, act=act_name)
     else:
+        up, gate, down = (w.one() if isinstance(w, LayerOf) else w
+                          for w in (up, gate, down))
         out = _grouped_xla(rows, p, up, gate, down, tile, ACTS[act_name],
                            merged)
     # an assignment held elsewhere has slot C: it reads a zero, not a row

@@ -11,6 +11,13 @@ nibbles and scales cross HBM once a call, at the file's 0.5625 bytes a weight;
 an unchosen one costs no bytes and no FLOPs. Tiles past the last used one
 repeat the last used tile's block indices (nothing moves) and skip their body.
 
+The weights are the WHOLE stacks over layers, (L, E, rows, K/2) packed with
+(L, E, rows, K/32) scales, and each weight's layer is one more prefetched
+scalar beside the tile's expert (a `LayerOf` from the layer scan; a layer's
+(E, rows, K/2) alone goes in as a stack of one). A layer sliced out of the
+stack for the kernel was a copy of all its experts, touched or not, two to
+three times the bytes the kernel then read (PERF.md section 6, PR 33).
+
 Two kernels. `gu`: act(x Wgate^T) * (x Wup^T) for a tile, both accumulators in
 registers/VMEM, the (rows, hidden) pre-activations never in HBM; the merged
 [up|gate] stack (models/params.py fuse_matvec_groups) is passed twice with the
@@ -37,6 +44,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..platform_env import interpret_requested
 from ..quants import QTensor
+from .matmul import LayerOf
 from .pallas_q4_mm import (VMEM_LIMIT, partial_product, pick_bk, scales_f32,
                            scales_shape)
 
@@ -58,13 +66,17 @@ def _pick_bn(n: int, kh: int) -> int | None:
     return n if n <= 1024 else None
 
 
-def grouped_supported(w: QTensor, n_out: int, interpret: bool) -> bool:
-    """Whether a stacked (E, rows, K) weight can go through these kernels:
-    split-plane Q40 in one self-contained pack, an output width that tiles,
-    and on the chip lane-aligned blocks (the interpreter takes any)."""
+def grouped_supported(w, n_out: int, interpret: bool) -> bool:
+    """Whether a stacked (E, rows, K) weight, or a `LayerOf` a layer of an
+    (L, E, rows, K) one, can go through these kernels: split-plane Q40 in
+    one self-contained pack, an output width that tiles, and on the chip
+    lane-aligned blocks (the interpreter takes any)."""
+    lead = 0
+    if isinstance(w, LayerOf):
+        w, lead = w.stack, len(w.at)
     if not isinstance(w, QTensor) or w.layout != "i4p" or w.groups != 1:
         return False
-    if w.data.ndim != 3:
+    if lead > 1 or w.data.ndim != 3 + lead:
         return False
     kh = w.data.shape[-1]
     if (bn := _pick_bn(n_out, kh)) is None:
@@ -84,8 +96,8 @@ def _act_f32(a, act: str):
     return 0.5 * a * (1.0 + jnp.tanh(c * a * (1.0 + 0.044715 * a * a)))
 
 
-def _gu_kernel(te_ref, nu_ref, xlo_ref, xhi_ref, up_ref, sup_ref, gate_ref,
-               sgate_ref, o_ref, sfu_ref, sfg_ref, *, act, bk):
+def _gu_kernel(te_ref, nu_ref, at_ref, xlo_ref, xhi_ref, up_ref, sup_ref,
+               gate_ref, sgate_ref, o_ref, sfu_ref, sfg_ref, *, act, bk):
     @pl.when(pl.program_id(1) < nu_ref[0])
     def _():
         sfu_ref[:] = scales_f32(sup_ref)
@@ -95,8 +107,8 @@ def _gu_kernel(te_ref, nu_ref, xlo_ref, xhi_ref, up_ref, sup_ref, gate_ref,
         o_ref[:] = (up * _act_f32(gate, act)).astype(o_ref.dtype)
 
 
-def _down_kernel(te_ref, nu_ref, xlo_ref, xhi_ref, w_ref, s_ref, o_ref,
-                 sf_ref, *, bk):
+def _down_kernel(te_ref, nu_ref, at_ref, xlo_ref, xhi_ref, w_ref, s_ref,
+                 o_ref, sf_ref, *, bk):
     @pl.when(pl.program_id(1) < nu_ref[0])
     def _():
         sf_ref[:] = scales_f32(s_ref)
@@ -111,28 +123,34 @@ def _row_block(i, nu_ref):
 
 
 def _x_specs(tile, kh):
-    return [pl.BlockSpec((tile, kh), lambda n, i, te, nu: (_row_block(i, nu), 0)),
-            pl.BlockSpec((tile, kh), lambda n, i, te, nu: (_row_block(i, nu), 1))]
+    return [pl.BlockSpec((tile, kh),
+                         lambda n, i, te, nu, at: (_row_block(i, nu), 0)),
+            pl.BlockSpec((tile, kh),
+                         lambda n, i, te, nu, at: (_row_block(i, nu), 1))]
 
 
-def _w_specs(bn, kh, nb, off):
-    """One expert's (bn, K/2) packed block and its (bn, K/32) scales, the
-    expert named by the tile; `off` shifts the row block (the gate half of a
-    merged [up|gate] stack)."""
-    return [pl.BlockSpec((None, bn, kh), lambda n, i, te, nu: (te[i], n + off, 0)),
-            pl.BlockSpec((None, bn, nb), lambda n, i, te, nu: (te[i], n + off, 0))]
+def _w_specs(bn, kh, nb, off, j):
+    """One expert's (bn, K/2) packed block and its (bn, K/32) scales out of
+    the (L, E, rows, ...) stack: the expert named by the tile, the layer by
+    the call's j-th prefetched layer; `off` shifts the row block (the gate
+    half of a merged [up|gate] stack)."""
+    def block(n, i, te, nu, at):
+        return (at[j], te[i], n + off, 0)
+
+    return [pl.BlockSpec((None, None, bn, kh), block),
+            pl.BlockSpec((None, None, bn, nb), block)]
 
 
 def _call(kernel, name, x, operands, w_specs, n_out, bn, tile, out_dtype,
-          tile_expert, n_used, interpret):
+          tile_expert, n_used, at, interpret):
     cap, k = x.shape
     kh = k // 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # (tile_expert, n_used)
+        num_scalar_prefetch=3,  # (tile_expert, n_used, the weights' layers)
         grid=(n_out // bn, cap // tile),
         in_specs=_x_specs(tile, kh) + w_specs,
-        out_specs=pl.BlockSpec((tile, bn),
-                               lambda n, i, te, nu: (_row_block(i, nu), n)),
+        out_specs=pl.BlockSpec(
+            (tile, bn), lambda n, i, te, nu, at: (_row_block(i, nu), n)),
         # a weight's decoded scales (pallas_q4_mm.scales_f32), one each
         scratch_shapes=[scales_shape(bn, s.shape[-1])
                         for s in operands[1::2]],
@@ -144,44 +162,54 @@ def _call(kernel, name, x, operands, w_specs, n_out, bn, tile, out_dtype,
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-    )(tile_expert, n_used, x, x, *operands)
+    )(tile_expert, n_used, at, x, x, *operands)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("tile", "act", "merged", "interpret"))
-def _moe_grouped_q4(rows, tile_expert, n_used, up, sup, gate, sgate, down,
-                    sdown, *, tile, act, merged, interpret):
+def _moe_grouped_q4(rows, tile_expert, n_used, layers, up, sup, gate, sgate,
+                    down, sdown, *, tile, act, merged, interpret):
     """rows (C, d) sorted by expert -> (C, d): down(act(gate x) * up x) of
-    each tile's expert. up/gate (E, h, d/2) packed with scales (E, h, d/32) —
-    the same array twice for a merged [up|gate] stack of 2h rows — and down
-    (E, d, h/2)."""
-    hidden = up.shape[1] // (2 if merged else 1)
-    d_out = down.shape[1]
+    each tile's expert. up/gate (L, E, h, d/2) packed with scales
+    (L, E, h, d/32) — the same array twice for a merged [up|gate] stack of
+    2h rows — and down (L, E, d, h/2); `layers` (3,): the layer to read of
+    up, gate and down."""
+    hidden = up.shape[2] // (2 if merged else 1)
+    d_out = down.shape[2]
     kh = rows.shape[1] // 2
     bn_h, bn_d = _pick_bn(hidden, kh), _pick_bn(d_out, hidden // 2)
     nu = jnp.reshape(n_used, (1,)).astype(jnp.int32)
     te = tile_expert.astype(jnp.int32)
     h = _call(functools.partial(_gu_kernel, act=act, bk=pick_bk(kh)),
               "moe_grouped_q4_gu", rows, (up, sup, gate, sgate),
-              _w_specs(bn_h, kh, sup.shape[-1], 0)
+              _w_specs(bn_h, kh, sup.shape[-1], 0, 0)
               + _w_specs(bn_h, kh, sgate.shape[-1],
-                         hidden // bn_h if merged else 0),
-              hidden, bn_h, tile, rows.dtype, te, nu, interpret)
+                         hidden // bn_h if merged else 0, 1),
+              hidden, bn_h, tile, rows.dtype, te, nu, layers[:2], interpret)
     return _call(functools.partial(_down_kernel, bk=pick_bk(hidden // 2)),
                  "moe_grouped_q4_down", h, (down, sdown),
-                 _w_specs(bn_d, hidden // 2, sdown.shape[-1], 0),
-                 d_out, bn_d, tile, rows.dtype, te, nu, interpret)
+                 _w_specs(bn_d, hidden // 2, sdown.shape[-1], 0, 0),
+                 d_out, bn_d, tile, rows.dtype, te, nu, layers[2:], interpret)
 
 
-def moe_grouped_q4(rows, tile_expert, n_used, up: QTensor, gate: QTensor,
-                   down: QTensor, *, tile: int, act: str,
-                   interpret: bool | None = None):
+def _whole(w):
+    """(packed stack, scales, layer) of a `LayerOf`; a layer's own (E, ...)
+    stack is a stack of one."""
+    if isinstance(w, LayerOf):
+        return w.stack.data, w.stack.scales, w.at[0]
+    return w.data[None], w.scales[None], 0
+
+
+def moe_grouped_q4(rows, tile_expert, n_used, up, gate, down, *, tile: int,
+                   act: str, interpret: bool | None = None):
     """The expert FFN of sorted, tile-padded rows (see the module docstring).
-    `up is gate` says the stack is the merged [up|gate] one. Rows of tiles
-    past `n_used` come back unwritten."""
+    up, gate, down: a `LayerOf` each (the stack over layers and the layer to
+    read) or a QTensor (E, rows, K). `up is gate` says the stack is the
+    merged [up|gate] one. Rows of tiles past `n_used` come back unwritten."""
     if interpret is None:
         interpret = interpret_requested()
-    return _moe_grouped_q4(rows, tile_expert, n_used, up.data, up.scales,
-                           gate.data, gate.scales, down.data, down.scales,
-                           tile=tile, act=act, merged=up is gate,
+    (u, su, lu), (g, sg, lg), (d, sd, ld) = map(_whole, (up, gate, down))
+    layers = jnp.stack([jnp.asarray(i, jnp.int32) for i in (lu, lg, ld)])
+    return _moe_grouped_q4(rows, tile_expert, n_used, layers, u, su, g, sg,
+                           d, sd, tile=tile, act=act, merged=up is gate,
                            interpret=interpret)

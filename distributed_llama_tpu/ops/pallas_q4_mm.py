@@ -174,8 +174,7 @@ def partial_product(xlo_ref, xhi_ref, wp_ref, s_ref, bk):
     return acc
 
 
-def _mm_kernel(layer_ref, xlo_ref, xhi_ref, wp_ref, s_ref, o_ref, sf_ref, *,
-               bk):
+def _mm_kernel(at_ref, xlo_ref, xhi_ref, wp_ref, s_ref, o_ref, sf_ref, *, bk):
     sf_ref[:] = scales_f32(s_ref)
     o_ref[:] = partial_product(xlo_ref, xhi_ref, wp_ref, sf_ref,
                                bk).astype(o_ref.dtype)
@@ -192,43 +191,53 @@ def _pick_bn(n: int, kh: int) -> int:
     return min(max(_MM_BLOCK_BYTES // kh // 128, 1) * 128, 512, n // 128 * 128)
 
 
-def q4_mm_supported(w: QTensor, m: int, stacked: bool = False) -> bool:
+def q4_mm_supported(w: QTensor, m: int, stacked: int = 0) -> bool:
     """Whether the fused dequant-matmul runs this weight for m activation
     rows: split-plane Q40 in one self-contained pack (`groups` folded away by
-    _localize_qtensors under TP), (N, K/2) or with `stacked` a stack of such
-    over layers, a half-plane of whole lane tiles, and no more rows than the
-    resident activations are sized for. One row is the matvec kernel's."""
+    _localize_qtensors under TP), (N, K/2) under `stacked` leading axes (a
+    stack over layers, or over layers and experts), a half-plane of whole
+    lane tiles, and no more rows than the resident activations are sized
+    for. One row is the matvec kernel's."""
     if w.layout != "i4p" or w.groups != 1 or w.data.ndim != 2 + stacked:
         return False
     return w.data.shape[-1] % 128 == 0 and 2 <= m <= _MAX_ROWS
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
-def _q4_matmul(x, wp, scales, layer, *, out_dtype, interpret: bool = False):
-    """x (M, K) -> (M, N) against layer `layer` of packed nibbles
-    (L, N, K/2) + int16 f16-bit scales (L, N, K/32).
+def _q4_matmul(x, wp, scales, at, *, out_dtype, interpret: bool = False):
+    """x (M, K) -> (M, N) against the matrix at leading indices `at` (a tuple
+    of traced scalars: the layer, or the layer and the expert) of packed
+    nibbles (L, N, K/2) or (L, E, N, K/2) + int16 f16-bit scales of the same
+    leading shape and (N, K/32).
 
-    The layer is an index into the WHOLE stack, prefetched as a scalar and
-    used by the weight blocks' index maps: a layer scan that handed the
-    kernel its slice made XLA copy every layer's packed weights to a buffer
-    of their own first (`dynamic-slice_bitcast_fusion`, `copy`: 7 s of the
+    The indices point into the WHOLE stack, prefetched as scalars and used
+    by the weight blocks' index maps: a layer scan that handed the kernel
+    its slice made XLA copy every layer's packed weights to a buffer of
+    their own first (`dynamic-slice_bitcast_fusion`, `copy`: 7 s of the
     dense cell's 45 s busy, 9 ms of every dispatch; PERF.md section 6,
-    PR 30)."""
+    PR 30), and with an expert axis every layer's experts, touched or not,
+    and in the all-experts scan each expert once more (30 to 34 % of the MoE
+    cells' busy time; PERF.md section 6, PR 33)."""
     m, k = x.shape
-    _, n, kh = wp.shape
-    assert kh * 2 == k and scales.shape == (wp.shape[0], n, k // QK), (
+    lead = len(at)
+    *_, n, kh = wp.shape
+    assert kh * 2 == k and scales.shape == (*wp.shape[:lead], n, k // QK), (
         x.shape, wp.shape, scales.shape)
     bn = _pick_bn(n, kh)
+
+    def block(i, a):
+        return (*(a[j] for j in range(lead)), i, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,  # (layer,)
+        num_scalar_prefetch=1,  # the leading indices
         grid=(pl.cdiv(n, bn),),
-        in_specs=[pl.BlockSpec((m, kh), lambda i, l: (0, 0)),
-                  pl.BlockSpec((m, kh), lambda i, l: (0, 1)),
-                  pl.BlockSpec((None, bn, kh), lambda i, l: (l[0], i, 0)),
-                  pl.BlockSpec((None, bn, scales.shape[2]),
-                               lambda i, l: (l[0], i, 0))],
-        out_specs=pl.BlockSpec((m, bn), lambda i, l: (0, i)),
-        scratch_shapes=[scales_shape(bn, scales.shape[2])])
+        in_specs=[pl.BlockSpec((m, kh), lambda i, a: (0, 0)),
+                  pl.BlockSpec((m, kh), lambda i, a: (0, 1)),
+                  pl.BlockSpec((*(None,) * lead, bn, kh), block),
+                  pl.BlockSpec((*(None,) * lead, bn, scales.shape[-1]),
+                               block)],
+        out_specs=pl.BlockSpec((m, bn), lambda i, a: (0, i)),
+        scratch_shapes=[scales_shape(bn, scales.shape[-1])])
     return pl.pallas_call(
         functools.partial(_mm_kernel, bk=pick_bk(kh)),
         grid_spec=grid_spec,
@@ -238,7 +247,8 @@ def _q4_matmul(x, wp, scales, layer, *, out_dtype, interpret: bool = False):
             vmem_limit_bytes=VMEM_LIMIT),
         name="q4_mm",
         interpret=interpret,
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), x, x, wp, scales)
+    )(jnp.concatenate([jnp.reshape(a, (1,)) for a in at]).astype(jnp.int32),
+      x, x, wp, scales)
 
 
 _BODIES_LOWERED = metrics.counter(
@@ -248,25 +258,27 @@ _BODIES_LOWERED = metrics.counter(
     "the number of programs a process brings up (counted at trace time)")
 
 
-def q4_matmul(x: jax.Array, w: QTensor, *, layer=None, out_dtype=None,
+def q4_matmul(x: jax.Array, w: QTensor, *, at=(), out_dtype=None,
               interpret: bool | None = None) -> jax.Array:
-    """x (..., K) against an i4p QTensor (N, K), or with `layer` (a traced
-    index) against that layer of one stacked (L, N, K) -> (..., N), the
-    weights streamed once at 4-bit density and decoded in VMEM."""
+    """x (..., K) against an i4p QTensor (N, K), or with `at` (traced
+    leading indices: the layer, or the layer and the expert) against that
+    matrix of one stacked (L, N, K) or (L, E, N, K) -> (..., N), the weights
+    streamed once at 4-bit density and decoded in VMEM."""
     x2 = x.reshape(-1, x.shape[-1])
-    if not q4_mm_supported(w, x2.shape[0], stacked=layer is not None):
+    if not q4_mm_supported(w, x2.shape[0], stacked=len(at)):
         raise ValueError(
             f"q4_matmul cannot run this weight (layout={w.layout}, "
             f"groups={w.groups}, shape={getattr(w.data, 'shape', None)}, "
-            f"layer={layer is not None}, M={x2.shape[0]}); gate with "
+            f"leading indices={len(at)}, M={x2.shape[0]}); gate with "
             f"q4_mm_supported")
     if interpret is None:
         interpret = interpret_requested()
     _BODIES_LOWERED.inc()
     wp, scales = w.data, w.scales
-    if layer is None:  # a stack of one
-        wp, scales, layer = wp[None], scales[None], 0
-    y = _q4_matmul(x2, wp, scales, jnp.asarray(layer, jnp.int32),
+    if not at:  # a stack of one
+        wp, scales, at = wp[None], scales[None], (0,)
+    y = _q4_matmul(x2, wp, scales,
+                   tuple(jnp.asarray(i, jnp.int32) for i in at),
                    out_dtype=jnp.dtype(out_dtype or x.dtype),
                    interpret=interpret)
     return y.reshape(*x.shape[:-1], y.shape[-1])

@@ -60,6 +60,40 @@ def test_decoded_weights_are_xlas_bf16_weights_bit_for_bit():
     np.testing.assert_array_equal(np.asarray(got), want.T)
 
 
+@pytest.mark.parametrize("at", [(0, 0), (1, 2), (2, 3), (1,)])
+def test_q4_matmul_at_layer_and_expert_of_the_stack_equals_the_slices(at):
+    """(layer, expert) of an (L, E, N, K/2) stack, or the layer of an
+    (L, N, K/2) one, read in place through the prefetched indices against
+    the slice handed in alone: the same blocks at another address, so bit
+    for bit; `qmatmul` takes the same way from a `LayerOf`."""
+    from distributed_llama_tpu.ops.matmul import LayerOf
+
+    shape = (3, 4)[:len(at)]
+    rng = np.random.RandomState(5)
+    w = QTensor.from_float(rng.randn(*shape, 300, 512).astype(np.float32)
+                           * 0.02, FloatType.Q40).to_i4p_layout()
+    assert w.data.shape == (*shape, 300, 256)
+    x = jnp.asarray(rng.randn(8, 512).astype(np.float32))
+    one = LayerOf(w, tuple(jnp.int32(i) for i in at)).one()
+    assert one.data.shape == (300, 256)
+    assert q4_mm_supported(w, 8, stacked=len(at))
+    assert not q4_mm_supported(w, 8, stacked=len(at) - 1)
+    want = np.asarray(q4_matmul(x, one, out_dtype=jnp.float32,
+                                interpret=True))
+    got = q4_matmul(x, w, at=tuple(jnp.int32(i) for i in at),
+                    out_dtype=jnp.float32, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    reset_kernel_selections()
+    got = qmatmul(x, LayerOf(w, tuple(jnp.int32(i) for i in at)),
+                  use_pallas=True, out_dtype=jnp.float32)
+    assert set(kernel_selections().values()) == {"q4_mm"}
+    np.testing.assert_array_equal(np.asarray(got), want)
+    # one row is the matvec kernel's: it takes the slice
+    y1 = qmatmul(x[:1], LayerOf(w, tuple(jnp.int32(i) for i in at)),
+                 use_pallas=False, out_dtype=jnp.float32)
+    np.testing.assert_allclose(np.asarray(y1), want[:1], atol=2e-2)
+
+
 def test_q4_mm_supported_gates():
     w = _w(64, 1024, seed=1)
     assert q4_mm_supported(w, 2) and q4_mm_supported(w, 512)

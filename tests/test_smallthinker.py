@@ -20,6 +20,8 @@ the logits here; the reference in fp8, the precision under that, reads above
 1e-2 (asserted below).
 """
 
+import functools
+
 import numpy as np
 import pytest
 import jax
@@ -415,3 +417,189 @@ def test_the_scan_holds_a_share_of_the_experts_under_expert_sharding():
     got, _, _ = step(sharded, rope, toks, kc, vc, jnp.int32(0))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4,
                                rtol=0)
+
+
+# ---- the expert stacks read in place: (L, E, rows, K/2) at prefetched indices
+
+
+def _layer_stack(layers, merged, seed=0):
+    """`layers` layers of _expert_case's experts as one stack over layers
+    in the kernels' layout, and the rows, routing and tile of one dispatch."""
+    cases = [_expert_case(40, "relu", True, merged, seed=seed + i)
+             for i in range(layers)]
+    stack = {nm: jax.tree_util.tree_map(lambda *a: jnp.stack(a),
+                                        *(c[0][nm] for c in cases))
+             for nm in cases[0][0]}
+    return stack, cases[0][1], cases[0][2], cases[0][3]
+
+
+@pytest.mark.parametrize("act", ["relu", "silu"])
+@pytest.mark.parametrize("merged", [True, False], ids=["merged", "unmerged"])
+@pytest.mark.parametrize("layer", [0, 1, 2], ids=["first", "middle", "last"])
+def test_grouped_kernels_on_a_layer_of_the_stack_equal_the_slice_bit_for_bit(
+        layer, merged, act):
+    """The same Mosaic body on the same blocks at another address: layer l
+    of (L, E, rows, K/2) through the prefetched layer index against the
+    (E, rows, K/2) slice handed in alone, with tiles left unused."""
+    from distributed_llama_tpu.ops.matmul import LayerOf
+    from distributed_llama_tpu.ops.moe_grouped import plan
+    from distributed_llama_tpu.ops.pallas_moe_grouped import (
+        grouped_supported, moe_grouped_q4)
+
+    stack, x, top_i, _ = _layer_stack(3, merged)
+    tile = row_tile(40 * 2, 8)
+    p = plan(top_i, 8, 0, tile)
+    assert int(p["n_used"]) < p["tile_expert"].shape[0]  # unused tiles
+    rows = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])[p["src"]]
+    names = ("moe_gu", "moe_gu") if merged else ("moe_up", "moe_gate")
+
+    def call(of):
+        up = of(stack[names[0]])
+        gate = up if merged else of(stack[names[1]])
+        down = of(stack["moe_down"])
+        assert all(grouped_supported(w, 64, True) for w in (up, gate, down))
+        out = moe_grouped_q4(rows, p["tile_expert"], p["n_used"], up, gate,
+                             down, tile=tile, act=act, interpret=True)
+        return np.asarray(out)[:int(p["n_used"]) * tile]
+
+    whole = call(lambda w: LayerOf(w, (jnp.int32(layer),)))
+    sliced = call(lambda w: jax.tree_util.tree_map(lambda a: a[layer], w))
+    np.testing.assert_array_equal(whole, sliced)
+    other = call(lambda w: LayerOf(w, (jnp.int32((layer + 1) % 3),)))
+    assert np.abs(whole - other).max() > 1e-3  # the index is what is read
+
+
+@functools.lru_cache(maxsize=None)
+def _wide_toy(name, hidden=256, seed=SEED):
+    """A toy MoE configuration at widths whose half-planes are whole lane
+    tiles (hidden 256, experts of `hidden`), which is what the rule that keeps
+    a stack whole asks for; at the toys' own 128 every stack is sliced.
+    Returns (spec, params in the kernels' layouts, planar params)."""
+    cfg = dict(cells.load_config(name), hidden_size=256)
+    if cfg["family"] == "smallthinker":
+        cfg.update(moe_ffn_hidden_size=hidden, num_hidden_layers=4,
+                   rope_layout=[0, 1, 1, 1], sliding_window_layout=[0, 1, 1, 1])
+    else:
+        cfg.update(intermediate_size=hidden, num_attention_heads=8,
+                   num_key_value_heads=4)
+    spec = cells.load_family(cfg["family"]).model_spec(cfg)
+    params = W.to_program_params(W.make_weights(cfg, seed))
+    return spec, prepare_for_pallas(params, spec=spec), params
+
+
+def _expert_stack_reads(spec, params, b, t):
+    """Of forward() traced with kernels on at (b, t): the shapes of the layer
+    scan's sliced operands (its xs), and of every operand of a dynamic_slice
+    outside the kernels' bodies."""
+    rope = RopeTables.create(spec)
+    kc, vc = init_kv_cache(spec, batch=b)
+    jaxpr = jax.make_jaxpr(lambda p, toks, kc, vc: forward(
+        p, spec, rope, toks, kc, vc, jnp.zeros((b,), jnp.int32),
+        use_pallas=True))(params, jnp.zeros((b, t), jnp.int32), kc, vc)
+    scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+    assert len(scans) == 1  # the layer scan
+    skip = scans[0].params["num_consts"] + scans[0].params["num_carry"]
+    xs = [v.aval.shape for v in scans[0].invars[skip:]]
+    sliced = []
+
+    def walk(j):
+        for e in j.eqns:
+            if e.primitive.name == "dynamic_slice":
+                sliced.append(e.invars[0].aval.shape)
+            if e.primitive.name != "pallas_call":
+                for sub in jax.core.jaxprs_in_params(e.params):
+                    walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return xs, sliced
+
+
+# 3 of 8 and 2 of 4 experts a row: 512 rows (8 slots x a 64-token chunk) take
+# the all-experts scan, 64 and 8 rows the grouped layer
+@pytest.mark.parametrize("name,b,t", [
+    ("tiny-smallthinker", 8, 64), ("tiny-smallthinker", 8, 8),
+    ("tiny-smallthinker", 8, 1), ("tiny-moe", 8, 64), ("tiny-moe", 8, 8),
+    ("tiny-moe", 8, 1), ("tiny-smallthinker", 1, 1), ("tiny-moe", 1, 1)])
+def test_no_step_program_slices_an_expert_stack_for_a_kernel(name, b, t):
+    """With kernels on at 2 to 512 rows the layer scan's xs hold no expert
+    stack and nothing dynamic-slices one, whole or a layer of it: the
+    kernels index it. One row (the matvec branch) slices as it always did."""
+    from distributed_llama_tpu.models import forward as F
+
+    spec, params, _ = _wide_toy(name)
+    experts = {n: w for n, w in params["blocks"].items()
+               if n.startswith("moe_")}
+    assert set(experts) == {"moe_gu", "moe_down"}
+    whole = {a.shape for w in experts.values() for a in (w.data, w.scales)}
+    layer = {s[1:] for s in whole}
+    assert F.takes_the_scan(b * t, spec.n_active_experts,
+                            spec.n_experts) == (b * t == 512)
+    xs, sliced = _expert_stack_reads(spec, params, b, t)
+    if b * t == 1:
+        assert whole <= set(xs) and layer & set(sliced)
+    else:
+        assert not whole & set(xs)
+        assert not (whole | layer) & set(sliced)
+
+
+@pytest.mark.parametrize("name,t", [("tiny-smallthinker", 8),
+                                    ("tiny-moe", 64), ("tiny-moe", 8)])
+def test_the_stack_read_in_place_gives_the_sliced_programs_logits(
+        monkeypatch, name, t):
+    """Grouped layer and all-experts scan: the program that indexes the
+    whole stacks equals, bit for bit, the one that slices every layer (the
+    rule answering no), and both stand by XLA's dequantize-then-dot."""
+    from distributed_llama_tpu.models import forward as F
+
+    spec, params, _ = _wide_toy(name)
+    rope = RopeTables.create(spec)
+    toks = jnp.asarray(np.random.default_rng(3).integers(
+        3, spec.vocab_size, (8, t)))
+
+    def run(**kw):
+        kc, vc = init_kv_cache(spec, batch=8)
+        return np.asarray(forward(params, spec, rope, toks, kc, vc,
+                                  jnp.zeros((8,), jnp.int32), **kw)[0])
+
+    whole = run(use_pallas=True)
+    monkeypatch.setattr(F, "reads_the_stack", lambda w, m, use_pallas: False)
+    np.testing.assert_array_equal(whole, run(use_pallas=True))
+    # bf16 operands in the kernels; a row whose router is undecided may pick
+    # another expert under them, so the bulk of the positions is held
+    err = np.abs(whole - run(use_pallas=False)).max(axis=-1)
+    assert np.quantile(err, 0.9) < 10 * KERNEL_TOL, np.quantile(err, 0.9)
+
+
+@pytest.mark.parametrize("moe_sharding", ["slice", "expert"])
+def test_tp2_with_the_stacks_read_in_place_matches_the_single_device_program(
+        moe_sharding):
+    """Kernels on under tp 2: the shard's (L, E / 2, ...) stack at the local
+    expert index, or every expert's hidden slice, read in place per shard,
+    against the single-device program on the kernels."""
+    from distributed_llama_tpu.parallel.mesh import make_mesh
+    from distributed_llama_tpu.parallel.tp import (init_sharded_kv_cache,
+                                                   make_sharded_forward,
+                                                   shard_params)
+
+    # experts of 512: the hidden slice's half-plane is still a lane tile
+    spec, pp1, params = _wide_toy("tiny-smallthinker", hidden=512)
+    rope = RopeTables.create(spec)
+    mesh = make_mesh(tp=2)
+    pp = shard_params(prepare_for_pallas(params, tp=2, spec=spec,
+                                         moe_sharding=moe_sharding),
+                      mesh, spec, moe_sharding=moe_sharding)
+    local = {n: tuple(s // (2 if ax == "tp" else 1) for s, ax in zip(
+        w.data.shape, w.data.sharding.spec + (None,) * 4))
+        for n, w in pp["blocks"].items() if n.startswith("moe_")}
+    assert all(s[-1] % 128 == 0 for s in local.values()), local
+    step = make_sharded_forward(spec, mesh, pp, use_pallas=True,
+                                donate_cache=False, moe_sharding=moe_sharding)
+    toks = jnp.asarray(np.random.default_rng(5).integers(
+        3, spec.vocab_size, (1, 16)))
+    kc, vc = init_sharded_kv_cache(spec, mesh)
+    got, _, _ = step(pp, rope, toks, kc, vc, jnp.int32(0))
+    kc1, vc1 = init_kv_cache(spec)
+    want, _, _ = forward(pp1, spec, rope, toks, kc1, vc1, jnp.int32(0),
+                         use_pallas=True)
+    err = np.abs(np.asarray(got) - np.asarray(want)).max(axis=-1)
+    assert np.quantile(err, 0.9) < KERNEL_TOL, err

@@ -91,21 +91,22 @@ def matvec_inline(n, k):
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
-def _q4_matmul(x, wp, scales, layer, *, out_dtype, interpret):
+def _q4_matmul(x, wp, scales, *at, out_dtype, interpret):
     return q4_matmul(x, QTensor(FloatType.Q40, wp, scales, layout="i4p"),
-                     layer=layer, out_dtype=out_dtype, interpret=interpret)
+                     at=at, out_dtype=out_dtype, interpret=interpret)
 
 
-def matmul(m, n, k, out=BF16):
+def matmul(m, n, k, out=BF16, lead=(2,)):
     """The fused dequant-matmul as `qmatmul` calls it from the layer scan:
-    a layer of the whole stack, through the module that is lowered once a
-    process and exported for the TPU. The gate has to admit the shape (a
-    case `q4_mm_supported` declined would be asserted so)."""
-    stack = [((2, n, k // 2), U8), ((2, n, k // 32), I16)]
+    a layer of the whole stack (`lead`: the stack's leading axes, layers or
+    layers and experts, each indexed by a traced scalar). The gate has to
+    admit the shape (a case `q4_mm_supported` declined would be asserted
+    so)."""
+    stack = [((*lead, n, k // 2), U8), ((*lead, n, k // 32), I16)]
     w = QTensor(FloatType.Q40, *(jax.ShapeDtypeStruct(*a) for a in stack),
                 layout="i4p")
-    assert q4_mm_supported(w, m, stacked=True), (m, n, k)
-    return (_q4_matmul, [((m, k), BF16), *stack, ((), I32)],
+    assert q4_mm_supported(w, m, stacked=len(lead)), (m, n, k)
+    return (_q4_matmul, [((m, k), BF16), *stack, *[((), I32)] * len(lead)],
             {"out_dtype": jnp.dtype(out)})
 
 
@@ -123,18 +124,20 @@ def paged(b, t, hq, hk, n_read, q=F32, window=False):
              ((b,), I32), ((), I32)], static)
 
 
-def grouped(rows, k, experts, hidden, dim, merged=True, act="relu"):
+def grouped(rows, k, experts, hidden, dim, merged=True, act="relu", layers=1):
     """The grouped expert layer's two kernels for `rows` x `k` assignments
     over `experts` experts of width `hidden`, at the tile and capacity the
-    shapes give (ops/moe_grouped.py)."""
+    shapes give (ops/moe_grouped.py), reading a layer of stacks over
+    `layers` layers."""
     tile = row_tile(rows * k, experts)
     cap = capacity(rows * k, experts, tile)
     gu_rows = 2 * hidden if merged else hidden
-    up = [((experts, gu_rows, dim // 2), U8), ((experts, gu_rows, dim // 32), I16)]
-    down = [((experts, dim, hidden // 2), U8), ((experts, dim, hidden // 32), I16)]
+    lead = (layers, experts)
+    up = [((*lead, gu_rows, dim // 2), U8), ((*lead, gu_rows, dim // 32), I16)]
+    down = [((*lead, dim, hidden // 2), U8), ((*lead, dim, hidden // 32), I16)]
     return (_moe_grouped_q4,
-            [((cap, dim), BF16), ((cap // tile,), I32), ((), I32), *up, *up,
-             *down], {"tile": tile, "act": act, "merged": merged})
+            [((cap, dim), BF16), ((cap // tile,), I32), ((), I32), ((3,), I32),
+             *up, *up, *down], {"tile": tile, "act": act, "merged": merged})
 
 
 def decode_attention(hk, window):
@@ -206,6 +209,14 @@ CASES = {
     "grouped-e8-t1": grouped(8, 2, 8, 14336, 4096, act="silu"),
     "grouped-e8-t64": grouped(512, 2, 8, 14336, 4096, act="silu"),
     "grouped-tp4-e8-t64": grouped(512, 2, 8, 14336 // 4, 4096, act="silu"),
+    # the same out of the cells' whole stacks, the layer a prefetched scalar:
+    # SmallThinker's 24 layers of 64 experts at 8, 64 and 512 rows, Mixtral's
+    # 8 layers of 8 at 8 and 64 (its 512 rows take the all-experts scan)
+    "grouped-l24-e64-t1": grouped(8, 6, 64, 768, 2560, layers=24),
+    "grouped-l24-e64-t8": grouped(64, 6, 64, 768, 2560, layers=24),
+    "grouped-l24-e64-t64": grouped(512, 6, 64, 768, 2560, layers=24),
+    "grouped-l8-e8-t1": grouped(8, 2, 8, 14336, 4096, act="silu", layers=8),
+    "grouped-l8-e8-t8": grouped(64, 2, 8, 14336, 4096, act="silu", layers=8),
     # fused decode attention: one-block and full windows
     "decode-attn-w256": decode_attention(8, 256),
     "decode-attn-w2048": decode_attention(8, 2048),
@@ -215,6 +226,10 @@ CASES = {
     "matmul-tp4-m40-w2": matmul(40, DIM, HIDDEN // 4),
     "matmul-m2-wo": matmul(2, DIM, DIM),
     "matmul-m8-wcls-f32": matmul(8, VOCAB, DIM, out=F32),
+    # the all-experts scan's step in Mixtral's 64-token chunk: (layer,
+    # expert) of the (8, 8, rows, K/2) stacks, two prefetched indices
+    "matmul-l8-e8-m512-gu": matmul(512, 28672, 4096, lead=(8, 8)),
+    "matmul-l8-e8-m512-down": matmul(512, 4096, 14336, lead=(8, 8)),
     **CELL_MATMULS,
     # repaired: inline matvec VMEM at K=14336
     "repaired-matvec-inline-w2": matvec_inline(DIM, HIDDEN),
