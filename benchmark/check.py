@@ -13,7 +13,8 @@ which carries the verdict on precision and needs no engine at full depth.
 the program's place (`fp8`, the precision below the configuration's
 bfloat16, has to fail; `q80` and `bfloat16` are for information), `--canary
 1` what an engine with one matrix's scales off by an eighth gives in the
-shallow pass. `--trace 1` keeps the profiler running meanwhile. `--dump`
+shallow pass (the matrix is `check.canary` of the configuration's file, `wo`
+where it names none). `--trace 1` keeps the profiler running meanwhile. `--dump`
 writes every position's error, row and router margin as JSON lines: the
 limits in `configs/<name>.json` are set from such a file, not from a guess.
 One JSON line per seed, then a summary; exit 1 if a sound run failed or the
@@ -120,11 +121,11 @@ def main(argv=None) -> None:
                 if c == "fp8" and name == "shallow" and within:
                     ok = False  # the precision below came out correct
         if args.canary and n < deep:
-            bad = W.mis_scaled(weights, "wo", 1.125)
+            bad = W.mis_scaled(weights, lim.get("canary", "wo"), 1.125)
             pe = probe.pass_errors(
                 cfg, weights, probes, lim["shallow"],
                 lambda cut, w, pr: probe.engine_logits(cfg)(
-                    cut, W.layer_cut(bad, cut), pr), refs["shallow"])
+                    cut, W.layer_cut(bad, cut, cfg), pr), refs["shallow"])
             record(line, seed, "canary", "shallow", pe)
         del weights, refs
         gc.collect()

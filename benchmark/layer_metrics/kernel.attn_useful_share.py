@@ -6,7 +6,7 @@ runs every row at the chunk's T against the whole bucket, parked rows and
 scratch positions too."""
 UNIT = "%"
 LAYER = "kernels"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 SOURCE = "program_counter"
 
 

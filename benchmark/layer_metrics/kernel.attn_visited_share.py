@@ -7,7 +7,7 @@ past a row's length, so this falls as rows are shorter than the bucket; a
 program without the counter (one that runs every step) reads nothing."""
 UNIT = "%"
 LAYER = "kernels"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 SOURCE = "program_counter"
 
 

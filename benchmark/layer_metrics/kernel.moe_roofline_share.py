@@ -21,7 +21,7 @@ from benchmark import moe_trace
 
 UNIT = "%"
 LAYER = "kernels"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 SOURCE = "device_trace"
 HBM_BYTES_S, PEAK_FLOP_S = 819e9, 197e12  # TPU v5e, one chip
 Q40_BYTES = 0.5625

@@ -8,14 +8,14 @@ still go through XLA's dequantize-then-dot reads nothing here, as the parent
 of the PR that made the kernel the default does), and what part of a
 dispatch is one pass over the weights at the rows it was given: the part a
 scheduler that fills its dispatches (fewer scratch rows among a 64-token
-chunk's 512) can shrink. Lower is better at a given `itl_p95_ms`: the same
+chunk's 512) can shrink. Lower is better at a given `itl_mean_ms`: the same
 weights' pass in less of the time. The grouped expert kernels
 (`moe_grouped_q4_*`, `step.moe_share`) are not counted."""
 from benchmark import moe_trace
 
 UNIT = "%"
 LAYER = "kernels"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 SOURCE = "device_trace"
 MARK = "q4_mm"
 

@@ -12,7 +12,7 @@ is the share of computed rows that no assignment asked for, padding or not.
 A program without the counters reads nothing."""
 UNIT = "%"
 LAYER = "step programs"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 SOURCE = "program_counter"
 
 

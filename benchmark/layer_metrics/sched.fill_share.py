@@ -5,7 +5,7 @@ dispatch from its shapes and live rows). A prefill dispatch computes slots x
 chunk positions for one chunk and at most slots - 1 riders."""
 UNIT = "%"
 LAYER = "scheduler"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 SOURCE = "program_counter"
 
 

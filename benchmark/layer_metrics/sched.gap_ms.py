@@ -11,7 +11,7 @@ from benchmark import host_spans
 
 UNIT = "ms"
 LAYER = "scheduler"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 SOURCE = "device_trace"
 
 

@@ -6,7 +6,7 @@ from benchmark import host_spans
 
 UNIT = "%"
 LAYER = "scheduler"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 SOURCE = "device_trace"
 
 

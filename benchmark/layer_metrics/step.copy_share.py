@@ -18,7 +18,7 @@ from benchmark.trace_reduce import op_name
 
 UNIT = "%"
 LAYER = "step programs"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 SOURCE = "device_trace"
 
 

@@ -1,4 +1,6 @@
-"""Device time of the dispatch whose duration `itl_p95_ms` is: the median over
+"""Device time of the dispatch whose duration `client.itl_rider_p75_ms`
+reads (a rider's gap across one 64-token chunk, with the host's time before
+it): the median over
 the `jit_step` executions inside a `batch.mixed_step` span with `chunk` 64 (a
 64-token prefill chunk with decode rows riding it), at the widest attention
 window among them. One program runs each (chunk, window bucket) and its time
@@ -13,7 +15,7 @@ from benchmark import host_spans
 
 UNIT = "ms"
 LAYER = "step programs"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 SOURCE = "device_trace"
 PROGRAMS = {"step": "jit_step"}
 MIN_JOINED = 0.95

@@ -21,7 +21,7 @@ from benchmark import moe_trace
 
 UNIT = "%"
 LAYER = "step programs"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 SOURCE = "device_trace"
 
 

@@ -80,8 +80,8 @@ def build_engine(cfg: dict, weights: dict, **overrides):
               kv_pool_blocks=eng["kv_pool_blocks"],
               prefix_cache=eng["prefix_cache"], tp=eng.get("tp", 1))
     spec = cells.load_family(cfg["family"]).model_spec(
-        {**cfg, "num_hidden_layers": W.depth(weights)})
-    return BatchEngine(spec, W.to_program_params(weights), None, **kw)
+        {**cfg, "num_hidden_layers": W.depth(weights, cfg)})
+    return BatchEngine(spec, W.to_program_params(weights, cfg), None, **kw)
 
 
 def probe_tokens(cfg: dict, seed: int):
@@ -180,7 +180,7 @@ def pass_errors(cfg: dict, weights: dict, probes, spec: dict, got_of,
     refs = {} if refs is None else refs
     err, row, gap = [], [], []
     for cut in spec.get("cuts") or [None]:
-        w = weights if cut is None else W.layer_cut(weights, cut)
+        w = weights if cut is None else W.layer_cut(weights, cut, cfg)
         key = None if cut is None else tuple(cut)
         if key not in refs:
             refs[key] = reference_rows(cfg, w, probes)

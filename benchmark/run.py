@@ -6,7 +6,9 @@ A new process: it makes the cell's weights from the seed on the device,
 builds `BatchEngine` with the configuration's settings, warms every shape the
 traffic can reach, decides `correct` by the output check (`probe.py`), lets
 every client complete one request, and only then opens the timed window.
-The last line of its standard output is one JSON object; every fault other
+The last line of its standard output is one JSON object (its last key,
+`compared`, holds each number the output check compared beside its limit;
+the same are the last lines on standard error); every fault other
 than "the logits disagree with the reference" is a message on stderr and a
 non-zero exit with no result line.
 
@@ -220,6 +222,9 @@ def main(argv=None) -> None:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearse", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--gaps", default="", metavar="FILE",
+                    help="write every gap the window counted to FILE "
+                         "(benchmark/gapstat.py reads such files)")
     args = ap.parse_args(argv)
 
     bench = cells.benchmark_json()
@@ -303,7 +308,9 @@ def main(argv=None) -> None:
         if args.trace:
             import jax
 
+            t_stop = time.perf_counter()
             jax.profiler.stop_trace()
+            state["stop_trace_s"] = time.perf_counter() - t_stop
 
     plans = traffic.plan(tr, cfg["vocab_size"], args.seed)
     t = time.perf_counter()
@@ -322,8 +329,29 @@ def main(argv=None) -> None:
         fault(f"{in_window} programs were compiled inside the timed window "
               f"({', '.join(compiles.names) or 'names not logged'}): a shape "
               "was not warmed up")
+    # the gap statistics are the cell's: its end-to-end entries that name
+    # one, and what its per-layer readers ask for (`GAPS`); `itl_p95_ms` is
+    # logged beside them so that the ledger's older readings stay comparable
+    readers = {m["name"]: cells.load_reader(m["name"]) for m in
+               cells.metrics_of(bench, "per_layer", cell["name"])}
+    gap_names = ["itl_p95_ms"]
+    gap_names += [m["name"] for m in
+                  cells.metrics_of(bench, "end_to_end", cell["name"])
+                  if e2e.GAP_METRIC.match(m["name"])]
+    gap_names += [n for r in readers.values() for n in getattr(r, "GAPS", ())]
+    gap_names = list(dict.fromkeys(gap_names))
     metrics, samples, counts = e2e.reduce(records, win.open_t, win.close_t,
-                                          chips)
+                                          chips, gap_names)
+    if args.gaps:
+        os.makedirs(os.path.dirname(os.path.abspath(args.gaps)), exist_ok=True)
+        with open(args.gaps, "w") as f:
+            json.dump({"workload": cell["name"], "seed": args.seed,
+                       "trace": args.trace, "rehearsal": rehearse,
+                       "superstep": cfg["engine"]["superstep"],
+                       "window_s": win.close_t - win.open_t,
+                       "columns": e2e.GAP_COLUMNS,
+                       "rows": e2e.gap_rows(records, win.open_t,
+                                            win.close_t)}, f)
     metrics["setup_s"] = state["setup_s"]
     log("samples: " + json.dumps(samples) + " requests: " + json.dumps(counts))
     log("as the clients saw it (bounded only where BENCHMARK.json says so): "
@@ -348,9 +376,8 @@ def main(argv=None) -> None:
             out_metrics[prefix + m["name"]] = {"value": metrics[m["name"]],
                                                "unit": m["unit"]}
     else:
-        readers = {m["name"]: cells.load_reader(m["name"]) for m in
-                   cells.metrics_of(bench, "per_layer", cell["name"])}
         reduced = None
+        t_read = time.perf_counter()
         if not rehearse:
             # the programs this cell's readers declare, by their jitted names
             programs = {}
@@ -368,6 +395,7 @@ def main(argv=None) -> None:
             dev["window_s"] = reduced["window_s"]
             breakdown = {"device_ops": reduced["device_ops"],
                          "idle_gaps": reduced["idle_gaps"][:10]}
+        t_readers = time.perf_counter()
         ctx = Ctx(cfg, reduced, state["before"], state["after"], metrics,
                   None if rehearse else trace_dir)
         for m in cells.metrics_of(bench, "per_layer", cell["name"]):
@@ -377,6 +405,12 @@ def main(argv=None) -> None:
             if value is not None:
                 out_metrics[prefix + m["name"]] = {"value": value,
                                                    "unit": m["unit"]}
+        # a traced run has to end within its allowance, and most of what it
+        # takes beyond an untraced one is spent here
+        log(f"after the window: stopping the profiler "
+            f"{state['stop_trace_s']:.1f} s, reading its profile "
+            f"{t_readers - t_read:.1f} s, the readers "
+            f"{time.perf_counter() - t_readers:.1f} s")
     result = {"correct": correct, "attempted": counts["attempted"],
               "failed": counts["failed"], "metrics": out_metrics,
               "device": dev}
@@ -384,6 +418,17 @@ def main(argv=None) -> None:
         result["breakdown"] = breakdown
     if rehearse:
         result["rehearsal"] = True
+    # each number the output check compared, beside its limit: last on
+    # standard error, and last in the result's line
+    # (a statistic that is not finite goes out as null: the line stays JSON)
+    result["compared"] = {
+        name: {"value": (verdict[name]["stat"]
+                         if np.isfinite(verdict[name]["stat"]) else None),
+               "limit": verdict[name]["tol"]}
+        for name in ("shallow", "full")}
+    for name, c in result["compared"].items():
+        print(f"compared {name}: {c['value']} limit {c['limit']:g}",
+              file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     os._exit(0)  # every engine is closed; daemon client threads hold nothing
 

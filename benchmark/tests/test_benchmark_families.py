@@ -120,5 +120,12 @@ def test_every_configuration_names_a_family_a_toy_and_its_probes(path):
     assert max(prompts) + decode <= cfg["context"]
     shapes = fam.tensor_shapes(cfg)
     assert set(W.NOT_BLOCKS) <= set(shapes)
-    assert all(s[0] == cfg["num_hidden_layers"] for n, (s, _) in shapes.items()
-               if n not in W.NOT_BLOCKS)
+    # every stack's tensors are as deep as the family says the stack is (one
+    # unnamed stack of every layer where it says nothing)
+    declared = (dict(fam.stacks(cfg)) if hasattr(fam, "stacks")
+                else {"": cfg["num_hidden_layers"]})
+    assert sum(declared.values()) == cfg["num_hidden_layers"]
+    in_stacks = {n: W.stack_of(n, declared) for n in shapes}
+    assert set(declared) == set(in_stacks.values()) - {None}
+    assert all(shapes[n][0][0] == declared[st]
+               for n, st in in_stacks.items() if st is not None)

@@ -11,11 +11,27 @@ rank one plus noise, and bf16 rounding alone flips the sign of the logits
 deviation near 0.02. No checkpoint file is written or read.
 
 Which tensors a model has is its family's business
-(`families/<family>.py`: `tensor_shapes`). What every family means the same
-by is here: three tensors stand outside the block stack (`NOT_BLOCKS`) and
-every other one has the layer axis first; `dequantize` is the reference's
-own reading of the blocks, sharing no code with the program's `quants.py`;
-`rounder` is how every family's reference makes its controls.
+(`families/<family>.py`: `tensor_shapes`), and so is where its layers
+stand. A family that says nothing keeps them all in ONE stack: three tensors
+stand outside it (`NOT_BLOCKS`) and every other one has the layer axis
+first. A family whose leading layers hold other tensors than the rest
+(a dense layer ahead of expert layers) defines two optional functions,
+which this module asks for by name:
+
+    stacks(cfg)                   [(prefix, depth), ...] in layer order; a
+                                  tensor named `<prefix>.<name>` lies in
+                                  that stack, layer axis first, every other
+                                  one outside all stacks; the depths add up
+                                  to `num_hidden_layers`
+    program_params(cfg, weights)  the structure the program is handed for
+                                  these weights (a cut of them too)
+
+A layer index is global over the stacks in their order: `depth`, `layer` and
+`layer_cut` take the configuration as `cfg` and read each stack's depth off
+the weights given, so a cut of a cut works; without `cfg` they mean the one
+unnamed stack, and raise on weights whose tensors name a stack. `dequantize` is the reference's own reading of the blocks,
+sharing no code with the program's `quants.py`; `rounder` is how every
+family's reference makes its controls.
 """
 
 from __future__ import annotations
@@ -69,6 +85,7 @@ def make_weights(cfg: dict, seed: int) -> dict:
     would hand `BatchEngine`, and what the reference dequantizes."""
     import jax
 
+    _stacks(cfg)  # a family whose stacks do not add up fails here
     shapes = cells.load_family(cfg["family"]).tensor_shapes(cfg)
     drawn = jax.jit(lambda k: _draw(k, shapes))(_seed_key(seed))
     host = jax.tree.map(np.asarray, drawn)
@@ -76,36 +93,103 @@ def make_weights(cfg: dict, seed: int) -> dict:
     return host
 
 
-def depth(weights: dict) -> int:
-    """The layers in `weights`: the leading axis of any block tensor."""
-    t = next(t for n, t in weights.items() if n not in NOT_BLOCKS)
+def _stacks(cfg: dict | None):
+    """The family's `stacks(cfg)`, or None where it defines none (or no
+    configuration is given): one unnamed stack of every tensor but
+    `NOT_BLOCKS`."""
+    if cfg is None:
+        return None
+    fn = getattr(cells.load_family(cfg["family"]), "stacks", None)
+    if fn is None:
+        return None
+    stacks = [(str(p), int(d)) for p, d in fn(cfg)]
+    total = sum(d for _, d in stacks)
+    if total != cfg["num_hidden_layers"]:
+        raise ValueError(
+            f"family {cfg['family']}: the stacks {stacks} hold {total} "
+            f"layers, num_hidden_layers is {cfg['num_hidden_layers']}")
+    return stacks
+
+
+def _layers_of(t) -> int:
     return (t[0] if isinstance(t, tuple) else t).shape[0]
 
 
-def layer(weights: dict, i: int) -> dict:
-    """Layer `i`'s tensors alone, without the layer axis: what a reference
-    that walks the block stack hands its block."""
-    return {n: (tuple(a[i] for a in t) if isinstance(t, tuple) else t[i])
-            for n, t in weights.items() if n not in NOT_BLOCKS}
+def stack_depths(weights: dict, cfg: dict | None = None) -> dict[str, int]:
+    """prefix -> the layers `weights` hold of that stack, in layer order
+    (read off the first tensor of each); "" is the one unnamed stack."""
+    stacks = _stacks(cfg)
+    if stacks is None:
+        named = sorted({n.split(".", 1)[0] for n in weights if "." in n})
+        if named:  # one set of indices would cut every stack alike
+            raise ValueError(
+                f"these weights lie in the stacks {named}: give the "
+                "configuration (`cfg`), whose family says how deep each is")
+        return {"": _layers_of(next(t for n, t in weights.items()
+                                    if n not in NOT_BLOCKS))}
+    return {p: _layers_of(next(t for n, t in weights.items()
+                               if n.startswith(p + ".")))
+            for p, _ in stacks}
 
 
-def layer_cut(weights: dict, layers: list[int]) -> dict:
-    """The same weights with only `layers` of the block stack."""
-    idx = np.asarray(layers)
+def stack_of(name: str, depths: dict[str, int]) -> str | None:
+    """The stack tensor `name` lies in, None for one outside all stacks."""
+    if "" in depths:
+        return None if name in NOT_BLOCKS else ""
+    prefix = name.split(".", 1)[0]
+    return prefix if "." in name and prefix in depths else None
+
+
+def depth(weights: dict, cfg: dict | None = None) -> int:
+    """The layers in `weights`, over all stacks."""
+    return sum(stack_depths(weights, cfg).values())
+
+
+def layer(weights: dict, i: int, cfg: dict | None = None) -> dict:
+    """Layer `i`'s own tensors, without the layer axis and without their
+    stack's prefix: what a reference that walks the layers hands its block."""
+    depths = stack_depths(weights, cfg)
+    start = 0
+    for prefix, n in depths.items():
+        if start <= i < start + n:
+            k, bare = i - start, len(prefix) + 1 if prefix else 0
+            return {name[bare:]: (tuple(a[k] for a in t)
+                                  if isinstance(t, tuple) else t[k])
+                    for name, t in weights.items()
+                    if stack_of(name, depths) == prefix}
+        start += n
+    raise IndexError(f"layer {i} of {start}")
+
+
+def layer_cut(weights: dict, layers: list[int],
+              cfg: dict | None = None) -> dict:
+    """The same weights with only `layers`, each stack cut by its own share
+    of the (global) indices; a stack may be left with no layer."""
+    depths = stack_depths(weights, cfg)
+    total = sum(depths.values())
+    if not all(0 <= i < total for i in layers):
+        raise IndexError(f"cut {list(layers)} of {total} layers")
+    idx, start = {}, 0
+    for prefix, n in depths.items():
+        idx[prefix] = np.asarray([i - start for i in layers
+                                  if start <= i < start + n], np.int64)
+        start += n
     out = {}
     for name, t in weights.items():
-        if name in NOT_BLOCKS:
+        stack = stack_of(name, depths)
+        if stack is None:
             out[name] = t
         elif isinstance(t, tuple):
-            out[name] = (t[0][idx], t[1][idx])
+            out[name] = (t[0][idx[stack]], t[1][idx[stack]])
         else:
-            out[name] = t[idx]
+            out[name] = t[idx[stack]]
     return out
 
 
 def mis_scaled(weights: dict, name: str, factor: float, layer: int = 0) -> dict:
-    """`weights` with the scales of matrix `name` in one layer off by
-    `factor`: what a path that decodes its scales wrongly would compute."""
+    """`weights` with the scales of matrix `name` in one layer (of the
+    tensor's own stack) off by `factor`: what a path that decodes its scales
+    wrongly would compute."""
     packed, scales = weights[name]
     scales = scales.copy()
     scales[layer] = (scales[layer].astype(np.float32) * factor).astype(
@@ -153,10 +237,18 @@ def rounder(precision: str):
                          w.astype(t).astype(jnp.float32))
 
 
-def to_program_params(weights: dict):
+def to_program_params(weights: dict, cfg: dict | None = None):
     """The drawn tensors in the structure the program's loader returns
-    (`formats.mfile.load_model`): QTensor leaves in the planar Q40 layout."""
+    (`formats.mfile.load_model`): QTensor leaves in the planar Q40 layout.
+    A family that defines `program_params(cfg, weights)` says it itself
+    (and may call this without `cfg` on the tensors re-laid into one
+    stack)."""
     from distributed_llama_tpu.quants import FloatType, QTensor
+
+    own = cfg and getattr(cells.load_family(cfg["family"]), "program_params",
+                          None)
+    if own:
+        return own(cfg, weights)
 
     def q(t):
         return QTensor(FloatType.Q40, t[0], t[1])
