@@ -132,6 +132,14 @@ class CompileAudit:
             # unchanged.
             return ",mask=1" if kw.get("masked") else ""
 
+        def _latent(spec):
+            # a latent spec's programs (models/forward.py
+            # `_latent_attention`: one cache row a token, a second side of
+            # width 0; a leading stack beside the blocks) are other
+            # lowerings from the same policy. Boolean: any other spec adds
+            # nothing, so every pre-existing pinned key is unchanged.
+            return ",latent=1" if spec.latent else ""
+
         def _static(kw):
             return (f"mode={kw.get('mode', 'greedy')},"
                     f"window={kw.get('attn_window')}"
@@ -141,7 +149,7 @@ class CompileAudit:
             engine, "make_sharded_forward",
             lambda spec, mesh, params, **kw:
                 f"forward_step[window={kw.get('attn_window')}"
-                f"{_paged(kw)}{_kern(kw)}]")
+                f"{_paged(kw)}{_kern(kw)}{_latent(spec)}]")
         self._patch_factory(
             device_loop, "make_decode_loop",
             lambda spec, mesh, params, n, **kw:
@@ -149,7 +157,7 @@ class CompileAudit:
         self._patch_factory(
             device_loop, "make_batched_decode_loop",
             lambda spec, mesh, params, n, **kw:
-                f"batched_scan[k={n},{_static(kw)}]")
+                f"batched_scan[k={n},{_static(kw)}{_latent(spec)}]")
         self._patch_factory(
             device_loop, "make_batched_verify_loop",
             lambda spec, mesh, params, t, **kw:
@@ -375,6 +383,36 @@ def run_scenario(keep_engine: bool = False):
             rm2.wait(60)
         finally:
             eng4.close()
+        # phase 10 — a latent spec (the DeepSeek-V3 graph at a toy size:
+        # one latent cache row a token, a leading dense layer in a stack of
+        # its own, 2 of 8 sigmoid-routed experts of which 4 are held, a
+        # shared expert, YaRN) on a FIFTH engine, kernels off and on: its
+        # prefill chunks and K-step scans pin under their own `latent=1`
+        # keys, and every key that was there stays as it was.
+        from ..models.spec import ArchType, ModelSpec, RopeType, RouterScore
+
+        lspec = ModelSpec(
+            arch_type=ArchType.MIXTRAL, dim=64, hidden_dim=32, n_layers=3,
+            n_heads=4, n_kv_heads=1, vocab_size=V, seq_len=64, n_experts=4,
+            n_active_experts=2, rope_type=RopeType.YARN,
+            rope_scaling_factor=4.0, rope_scaling_orig_max_seq_len=16,
+            yarn_mscale_all_dim=1.0, q_lora_rank=32, kv_lora_rank=32,
+            qk_nope_head_dim=32, qk_rope_head_dim=8, v_head_dim=32,
+            lead_layers=1, lead_hidden_dim=128, shared_hidden_dim=32,
+            router_score=RouterScore.SIGMOID, router_scale=2.5,
+            router_width=8, expert_offset=4).resolved()
+        lparams = init_random_params(lspec, FloatType.Q40, seed=11)
+        for kernels in (False, True):
+            eng5 = BatchEngine(lspec, lparams, slots=2,
+                               superstep=4, pipeline=True, tp=1,
+                               prefix_cache=True, use_pallas=kernels)
+            try:
+                rl1 = eng5.submit(p1, 12, Sampler(V))
+                rl2 = eng5.submit(p2, 12, Sampler(V))
+                rl1.wait(60)
+                rl2.wait(60)
+            finally:
+                eng5.close()
         ok = True
     finally:
         # a failed phase must not leak a live engine (scheduler thread +

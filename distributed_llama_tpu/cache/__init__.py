@@ -75,7 +75,8 @@ def warn_degraded(what: str, exc: Exception) -> None:
 
 
 def default_pool_blocks(cache_shape, itemsize: int, block_tokens: int,
-                        slots: int, byte_budget: int = 1 << 30) -> int:
+                        slots: int, byte_budget: int = 1 << 30,
+                        token_values: int | None = None) -> int:
     """Default pool capacity: 4 full contexts per slot set, hard-capped by a
     host byte budget (~1 GiB). The budget wins even when it holds less than
     one full context — a partial-prefix cache (system prompts are usually
@@ -83,6 +84,9 @@ def default_pool_blocks(cache_shape, itemsize: int, block_tokens: int,
     allocation is not. Size explicitly via prefix_cache_blocks for more."""
     n_layers, _b, hk, seq_len, hs = cache_shape
     blocks_per_seq = -(-seq_len // block_tokens)
-    block_bytes = 2 * n_layers * hk * block_tokens * hs * itemsize
+    # values a token holds a layer a kv head, both sides: keys and values of
+    # hs each unless the caller says otherwise (a latent row)
+    block_bytes = (n_layers * hk * block_tokens * (token_values or 2 * hs)
+                   * itemsize)
     cap = max(byte_budget // block_bytes, 1)
     return int(min(4 * max(slots, 1) * blocks_per_seq, cap))

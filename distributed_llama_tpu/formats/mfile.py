@@ -30,7 +30,7 @@ import numpy as np
 
 from ..models.params import Params, block_tensor_shapes
 from ..models.spec import (ArchType, HeaderKey, HiddenAct, ModelSpec, RopeType,
-                           RouterInput)
+                           RouterInput, RouterScore)
 from ..quants import (
     FloatType,
     QTensor,
@@ -49,6 +49,33 @@ LEGACY_MAGICS = {0xABCD00: ArchType.LLAMA, 0xABCD01: ArchType.GROK1}
 
 _KIND_BITS = 30  # layers a header word holds (values are int32)
 _KIND_WORDS = 8
+
+
+# this repo's later keys (latent attention, the leading stack, the shared
+# expert, the router, YaRN): field, header key, header integer -> field
+def _e6(v: int) -> float:
+    return v / 1e6
+
+
+_OWN_KEYS = (
+    ("q_lora_rank", HeaderKey.Q_LORA_RANK, int),
+    ("kv_lora_rank", HeaderKey.KV_LORA_RANK, int),
+    ("qk_nope_head_dim", HeaderKey.QK_NOPE_HEAD_DIM, int),
+    ("qk_rope_head_dim", HeaderKey.QK_ROPE_HEAD_DIM, int),
+    ("v_head_dim", HeaderKey.V_HEAD_DIM, int),
+    ("lead_layers", HeaderKey.LEAD_LAYERS, int),
+    ("lead_hidden_dim", HeaderKey.LEAD_HIDDEN_DIM, int),
+    ("shared_hidden_dim", HeaderKey.SHARED_HIDDEN_DIM, int),
+    ("router_score", HeaderKey.ROUTER_SCORE, RouterScore),
+    ("router_renorm", HeaderKey.ROUTER_RENORM, bool),
+    ("router_scale", HeaderKey.ROUTER_SCALE_E6, _e6),
+    ("router_width", HeaderKey.ROUTER_WIDTH, int),
+    ("expert_offset", HeaderKey.EXPERT_OFFSET, int),
+    ("yarn_beta_fast", HeaderKey.YARN_BETA_FAST_E6, _e6),
+    ("yarn_beta_slow", HeaderKey.YARN_BETA_SLOW_E6, _e6),
+    ("yarn_mscale", HeaderKey.YARN_MSCALE_E6, _e6),
+    ("yarn_mscale_all_dim", HeaderKey.YARN_MSCALE_ALL_DIM_E6, _e6),
+)
 
 
 def _pack_kinds(first_key: int, kinds: tuple[int, ...]) -> list[tuple[int, int]]:
@@ -129,20 +156,46 @@ def read_spec(path: str, max_seq_len: int = 0,
                                     fields.get("n_layers", 0)),
         **({"norm_eps": kv[HeaderKey.NORM_EPS_E9] / 1e9}
            if HeaderKey.NORM_EPS_E9 in kv else {}),
+        **{name: conv(kv[key]) for name, key, conv in _OWN_KEYS if key in kv},
         **fields,
     ).resolved(max_seq_len)
     return spec, weights_ftype, header_size
+
+
+_EXPERT_STACKS = ("moe_up", "moe_gate", "moe_down")
+
+
+def _stacks_of(spec: ModelSpec) -> list[tuple[bool, int]]:
+    """(is it the leading dense stack?, layers) of the file's stacks in
+    layer order: the leading layers (ModelSpec.lead_layers) come first."""
+    out = [(True, spec.lead_layers)] if spec.lead_layers else []
+    return out + [(False, spec.block_layers)]
+
+
+def _layer_order(spec: ModelSpec, lead: bool):
+    """One layer's tensors in file order, as (name, expert or None, shape,
+    quantized): the order of `block_tensor_shapes` (transformer.cpp:498-523),
+    the expert stacks written expert by expert, each expert's up, gate, down
+    together."""
+    shapes = block_tensor_shapes(spec, lead)
+    for name, (shape, quantized) in shapes.items():
+        if name == "moe_up":
+            for e in range(spec.n_experts):
+                for part in _EXPERT_STACKS:
+                    yield part, e, shapes[part][0][1:], True
+        elif name not in _EXPERT_STACKS:
+            yield name, None, shape, quantized
 
 
 def model_tensor_bytes(spec: ModelSpec, wft: FloatType) -> int:
     """Total tensor bytes after the header (mirrors the reference's missedBytes check,
     transformer.cpp:531-535)."""
     total = batch_bytes(FloatType.F32, spec.dim, spec.vocab_size)  # embedding
-    shapes = block_tensor_shapes(spec)
-    for name, (shape, quantized) in shapes.items():
-        ft = wft if quantized else FloatType.F32
-        d = int(np.prod(shape[:-1], initial=1))
-        total += spec.n_layers * batch_bytes(ft, shape[-1], d)
+    for lead, depth in _stacks_of(spec):
+        for name, (shape, quantized) in block_tensor_shapes(spec, lead).items():
+            ft = wft if quantized else FloatType.F32
+            d = int(np.prod(shape[:-1], initial=1))
+            total += depth * batch_bytes(ft, shape[-1], d)
     total += batch_bytes(FloatType.F32, spec.dim, 1)  # rms_final
     total += batch_bytes(wft, spec.dim, spec.vocab_size)  # wcls
     return total
@@ -196,34 +249,26 @@ def load_model(path: str, max_seq_len: int = 0,
     # of seq_len, so no adjustment needed.
     embedding = take((spec.vocab_size, spec.dim), FloatType.F32)
 
-    shapes = block_tensor_shapes(spec)
-    per_layer: dict[str, list[QTensor]] = {name: [] for name in shapes}
-    for _ in range(spec.n_layers):
-        layer: dict[str, QTensor] = {}
-        for name in ("wq", "wk", "wv", "wo"):
-            layer[name] = take(shapes[name][0], wft)
-        if spec.is_moe:
-            layer["router"] = take(shapes["router"][0], wft)
-            ups, gates, downs = [], [], []
-            e, h, d = spec.n_experts, spec.hidden_dim, spec.dim
-            for _e in range(e):
-                ups.append(take((h, d), wft))
-                gates.append(take((h, d), wft))
-                downs.append(take((d, h), wft))
-            layer["moe_up"] = _stack(ups)
-            layer["moe_gate"] = _stack(gates)
-            layer["moe_down"] = _stack(downs)
-        else:
-            layer["w1"] = take(shapes["w1"][0], wft)
-            layer["w2"] = take(shapes["w2"][0], wft)
-            layer["w3"] = take(shapes["w3"][0], wft)
-        layer["rms_att"] = take((spec.dim,), FloatType.F32)
-        layer["rms_ffn"] = take((spec.dim,), FloatType.F32)
-        if spec.arch_type == ArchType.GROK1:
-            layer["rms_moe"] = take((spec.dim,), FloatType.F32)
-            layer["rms_ffn2"] = take((spec.dim,), FloatType.F32)
-        for name, t in layer.items():
-            per_layer[name].append(t)
+    stacks: dict[str, Params] = {}
+    for lead, depth in _stacks_of(spec):
+        shapes = block_tensor_shapes(spec, lead)
+        per_layer: dict[str, list[QTensor]] = {name: [] for name in shapes}
+        for _ in range(depth):
+            experts: dict[str, list[QTensor]] = {}
+            for name, e, shape, quantized in _layer_order(spec, lead):
+                t = take(shape, wft if quantized else FloatType.F32)
+                if e is None:
+                    per_layer[name].append(t)
+                else:
+                    experts.setdefault(name, []).append(t)
+            for name, ts in experts.items():
+                per_layer[name].append(_stack(ts))
+        blocks: Params = {}
+        for name, tensors in per_layer.items():
+            stacked = _stack(tensors)
+            blocks[name] = (stacked if shapes[name][1] else
+                            np.asarray(stacked.data, dtype=np.float32))
+        stacks["lead" if lead else "blocks"] = blocks
 
     rms_final = take((spec.dim,), FloatType.F32)
     wcls = take((spec.vocab_size, spec.dim), wft)
@@ -232,14 +277,9 @@ def load_model(path: str, max_seq_len: int = 0,
         raise ValueError(f"model file size mismatch: consumed {off}, file {len(mm)} "
                          "(missing/extra bytes — wrong weights float type?)")
 
-    blocks: Params = {}
-    for name, tensors in per_layer.items():
-        stacked = _stack(tensors)
-        blocks[name] = (stacked if shapes[name][1] else
-                        np.asarray(stacked.data, dtype=np.float32))
     params: Params = {
         "embedding": np.asarray(embedding.data),
-        "blocks": blocks,
+        **stacks,
         "rms_final": np.asarray(rms_final.data),
         "wcls": wcls,
     }
@@ -290,6 +330,10 @@ def write_header(f: BinaryIO, spec: ModelSpec, weights_ftype: FloatType) -> None
         kv.append((HeaderKey.NORM_EPS_E9, round(spec.norm_eps * 1e9)))
     kv += _pack_kinds(HeaderKey.ROPE_LAYERS_0, spec.rope_layers)
     kv += _pack_kinds(HeaderKey.WINDOW_LAYERS_0, spec.window_layers)
+    for name, key, conv in _OWN_KEYS:
+        value = getattr(spec, name)
+        if value != getattr(ModelSpec, name):  # only where it says something
+            kv.append((key, round(value * 1e6) if conv is _e6 else int(value)))
     data = b"".join(struct.pack("<ii", k, v) for k, v in kv)
     f.write(struct.pack("<i", MAGIC))
     f.write(struct.pack("<i", 8 + len(data)))
@@ -332,7 +376,8 @@ def write_model(path: str, spec: ModelSpec, tensors_iter, weights_ftype: FloatTy
     and embedding are forced F32 regardless of weights_ftype (convert-llama.py:79-85).
     A tensor may arrive in several consecutive row chunks under the same name.
     """
-    norm_names = {"embedding", "rms_att", "rms_ffn", "rms_moe", "rms_ffn2", "rms_final"}
+    norm_names = {"embedding", "rms_att", "rms_ffn", "rms_moe", "rms_ffn2", "rms_final",
+                  "rms_q", "rms_kv"}
     with open(path, "wb") as f:
         write_header(f, spec, weights_ftype)
         for name, tensor in tensors_iter:
@@ -340,31 +385,23 @@ def write_model(path: str, spec: ModelSpec, tensors_iter, weights_ftype: FloatTy
             write_tensor(f, tensor, ftype)
 
 
-def params_file_order(spec: ModelSpec, params: Params):
-    """Yield (name, array) in `.m` order from a params dict (testing / re-export)."""
+def params_file_order(spec: ModelSpec, params: Params, as_stored: bool = False):
+    """Yield (name, array) in `.m` order from a params dict (testing / re-export).
+    `as_stored`: a planar Q40 / Q80 tensor goes out as the QTensor of its
+    blocks, which `write_tensor` writes as they stand (quantizing its values
+    again would not give the same blocks back)."""
     yield "embedding", params["embedding"]
-    blocks = params["blocks"]
-
     def as_np(t, idx):
+        if (as_stored and isinstance(t, QTensor) and t.layout == "planar"
+                and t.ftype in (FloatType.Q40, FloatType.Q80)):
+            return QTensor(t.ftype, np.asarray(t.data)[idx],
+                           np.asarray(t.scales)[idx])
         return t.to_numpy()[idx] if isinstance(t, QTensor) else np.asarray(t)[idx]
 
-    for l in range(spec.n_layers):
-        for name in ("wq", "wk", "wv", "wo"):
-            yield name, as_np(blocks[name], l)
-        if spec.is_moe:
-            yield "router", as_np(blocks["router"], l)
-            for e in range(spec.n_experts):
-                yield "moe_up", as_np(blocks["moe_up"], (l, e))
-                yield "moe_gate", as_np(blocks["moe_gate"], (l, e))
-                yield "moe_down", as_np(blocks["moe_down"], (l, e))
-        else:
-            for name in ("w1", "w2", "w3"):
-                yield name, as_np(blocks[name], l)
-        yield "rms_att", as_np(blocks["rms_att"], l)
-        yield "rms_ffn", as_np(blocks["rms_ffn"], l)
-        if spec.arch_type == ArchType.GROK1:
-            yield "rms_moe", as_np(blocks["rms_moe"], l)
-            yield "rms_ffn2", as_np(blocks["rms_ffn2"], l)
+    for lead, depth in _stacks_of(spec):
+        blocks = params["lead" if lead else "blocks"]
+        for l in range(depth):
+            for name, e, _shape, _q in _layer_order(spec, lead):
+                yield name, as_np(blocks[name], l if e is None else (l, e))
     yield "rms_final", params["rms_final"]
-    wcls = params["wcls"]
-    yield "wcls", wcls.to_numpy() if isinstance(wcls, QTensor) else np.asarray(wcls)
+    yield "wcls", as_np(params["wcls"], ())

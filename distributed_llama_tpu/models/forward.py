@@ -21,6 +21,12 @@ Arch-specific structure:
   models on this graph is data in ModelSpec: the head size, the activation (SiLU,
   tanh-GELU, ReLU), where the router reads (the second norm's output or the block's
   input), and per layer whether q and k are rotated and whether attention is windowed.
+- The DeepSeek-V3 graph (A.X-K1) is data on the MIXTRAL block too: latent
+  attention (`_latent_attention`: one cache row a token, read in the absorbed
+  form), leading dense layers in `params["lead"]` with a scan of their own, a
+  shared expert added to the routed sum, the router's score, renormalisation
+  and scale, a stack that holds a share of the experts the router scores
+  (`ModelSpec.router_width`, `expert_offset`), YaRN.
 - GROK1: embedding x78.38367176906169 (grok1-tasks.cpp:11-14); attention output is
   rmsnorm'd (rms_ffn) BEFORE the residual join (grokRmfFfn*, grok1-tasks.cpp:16-41);
   MoE input norm uses rms_moe; MoE output is rmsnorm'd with rms_ffn2 before its residual
@@ -40,7 +46,7 @@ from ..ops.kernels import ACTS, rmsnorm
 from ..ops.matmul import LayerOf, qmatmul, reads_the_stack
 from ..ops.ring_attention import commit_kv_rows_sharded, ring_attention
 from ..ops.rope import RopeTables, apply_rope
-from .spec import ArchType, HiddenAct, ModelSpec, RouterInput
+from .spec import ArchType, HiddenAct, ModelSpec, RouterInput, RouterScore
 
 GROK_EMBEDDING_SCALE = 78.38367176906169  # grok1-tasks.cpp:13
 GROK_LOGITS_SCALE = 0.5773502691896257  # grok1-tasks.cpp:272
@@ -90,6 +96,22 @@ def _maybe_psum(x: jax.Array, axis_name: str | None, compress: bool = False) -> 
     from ..parallel.collectives import psum
 
     return psum(x, axis_name, compress=compress)
+
+
+def _window_key_positions(start_pos, win: int, t: int, stale: int):
+    """Absolute position of each key a contiguous-cache read sees: window
+    slot j holds a committed row iff j < start_pos (stale slots get `stale`,
+    past every position, so the causal compare masks them), then the chunk's
+    own t keys at their true positions. start_pos scalar -> (win + t,);
+    per-row (B,) (continuous batching) -> (B, win + t)."""
+    slot = jnp.arange(win)
+    if start_pos.ndim == 0:
+        slot_pos = jnp.where(slot < start_pos, slot, stale)
+        return jnp.concatenate([slot_pos, start_pos + jnp.arange(t)])
+    slot_pos = jnp.where(slot[None, :] < start_pos[:, None], slot[None, :],
+                         stale)
+    return jnp.concatenate(
+        [slot_pos, start_pos[:, None] + jnp.arange(t)[None, :]], axis=1)
 
 
 def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, start_pos,
@@ -271,18 +293,7 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
         win = window or s
         kw = jax.lax.dynamic_slice(kc, (layer_idx, 0, 0, 0, 0), (1, b, hk, win, hs))[0]
         vw = jax.lax.dynamic_slice(vc, (layer_idx, 0, 0, 0, 0), (1, b, hk, win, hs))[0]
-        # window slot j holds a committed row iff j < start_pos; stale slots get a
-        # past-seq_len position so the causal compare masks them. Current-chunk keys
-        # carry their true absolute positions.
-        slot = jnp.arange(win)
-        if start_pos.ndim == 0:
-            slot_pos = jnp.where(slot < start_pos, slot, s + 1)  # (win,)
-            key_pos = jnp.concatenate([slot_pos, start_pos + jnp.arange(t)])
-        else:  # per-row offsets (continuous batching)
-            slot_pos = jnp.where(slot[None, :] < start_pos[:, None], slot[None, :],
-                                 s + 1)  # (B, win)
-            key_pos = jnp.concatenate(
-                [slot_pos, start_pos[:, None] + jnp.arange(t)[None, :]], axis=1)
+        key_pos = _window_key_positions(start_pos, win, t, s + 1)
         kfull = jnp.concatenate([kw, k_t], axis=2)  # (B, hk, win+T, hs)
         vfull = jnp.concatenate([vw, v_t], axis=2)
         att = gqa_attention(q, kfull, vfull, positions, key_positions=key_pos,
@@ -291,6 +302,85 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
     y = _maybe_psum(qmatmul(att, bp["wo"], use_pallas=use_pallas), axis_name,
                     compress)
     return (y if residual is None else residual + y), (k_t, v_t)
+
+
+def _latent_attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc,
+                      vc, start_pos, positions, axis_name, use_pallas,
+                      compress, window, block_tables=None, block_tokens=0,
+                      paged_kernel=False, residual=None):
+    """Latent attention (spec.latent; the DeepSeek-V3 graph) in its ABSORBED
+    form, against a cache of ONE row a token a layer, which it only reads:
+
+        q          = wq_b norm_q(wq_a h), a head [q_nope ; q_pe]
+        [c ; k_pe] = wkv_a h; c normed, k_pe (one vector for all heads) and
+                     q_pe rotated
+        row        = [c ; k_pe ; 0..] (what the cache holds, spec.cache_widths)
+        q'         = [q_nope w_uk ; q_pe ; 0..]     per head, as wide as a row
+        scores     = q' . row x spec.attn_scale, causal, softmax in float32
+        ctx        = sum_j p_j row_j[:kv_lora_rank]
+        out        = wo [ctx w_uv^T per head]
+
+    w_uk and w_uv are the two halves of the published kv_b projection by head,
+    (H, d, kv_lora_rank) each: a head's keys and values are never formed, and
+    q' . row is the same number as q . k of the unabsorbed form. Heads may be
+    a TP-local slice; the row is whole on every shard. Cache kinds: the block
+    pool (the `latent_paged_attention` kernel or its XLA twin) and the
+    contiguous cache; the engine refuses the others for a latent spec.
+    Returns as _attention: (residual-joined output, the chunk's rows for
+    forward() to commit, the second side empty)."""
+    from ..ops.attention import latent_attention
+
+    b, t, _ = x.shape
+    dn, dr, r = spec.qk_nope_head_dim, spec.qk_rope_head_dim, spec.kv_lora_rank
+    width = kc.shape[-1]
+    s = kc.shape[3]
+    xb = rmsnorm(x, bp["rms_att"], spec.norm_eps)
+    qa = rmsnorm(qmatmul(xb, bp["wq_a"], use_pallas=use_pallas), bp["rms_q"],
+                 spec.norm_eps)
+    q = qmatmul(qa, bp["wq_b"], use_pallas=use_pallas)
+    q = q.reshape(b, t, q.shape[-1] // (dn + dr), dn + dr)
+    kv = qmatmul(xb, bp["wkv_a"], use_pallas=use_pallas)  # (B, T, r + dr)
+    c = rmsnorm(kv[..., :r], bp["rms_kv"], spec.norm_eps)
+    k_pe = apply_rope(kv[..., None, r:], rope, positions)[..., 0, :]
+    q_pe = apply_rope(q[..., dn:], rope, positions)
+    q_lat = jnp.einsum("bthd,hdc->bthc", q[..., :dn],
+                       bp["w_uk"].astype(x.dtype),
+                       preferred_element_type=jnp.float32).astype(x.dtype)
+    def to_row_width(parts):  # [.. ; .. ; zeros to the cache row's lanes]
+        a = jnp.concatenate(parts, axis=-1)
+        return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, width - r - dr)])
+
+    qf = to_row_width([q_lat, q_pe])  # (B, T, H, width)
+    row = to_row_width([c, k_pe]).astype(kc.dtype)  # (B, T, width)
+    scale = spec.attn_scale
+    with jax.named_scope("latent_attn"):
+        if block_tables is not None:
+            w_total = block_tables.shape[1]
+            win = window or (w_total * block_tokens)
+            nb = min(-(-win // block_tokens), w_total)
+            if paged_kernel:
+                from ..ops.pallas_paged_attention import \
+                    latent_paged_attention as attend
+            else:
+                from ..ops.pallas_paged_attention import \
+                    latent_paged_attention_xla as attend
+            ctx = attend(qf, kc, row, block_tables, start_pos, layer_idx,
+                         n_read=nb, n_values=r, scale=scale)
+        else:
+            win = window or s
+            kw = jax.lax.dynamic_slice(
+                kc, (layer_idx, 0, 0, 0, 0), (1, b, 1, win, width))[0, :, 0]
+            ctx = latent_attention(
+                qf, jnp.concatenate([kw, row], axis=1), positions,
+                _window_key_positions(start_pos, win, t, s + 1), r, scale)
+    att = jnp.einsum("bthc,hdc->bthd", ctx.astype(x.dtype),
+                     bp["w_uv"].astype(x.dtype),
+                     preferred_element_type=jnp.float32).astype(x.dtype)
+    y = _maybe_psum(qmatmul(att.reshape(b, t, -1), bp["wo"],
+                            use_pallas=use_pallas), axis_name, compress)
+    rows_t = row[:, None]  # (B, 1, T, width): one kv head
+    return ((y if residual is None else residual + y),
+            (rows_t, jnp.zeros((b, 1, t, vc.shape[-1]), vc.dtype)))
 
 
 def _dense_ffn(x, bp, spec: ModelSpec, axis_name, use_pallas, compress,
@@ -387,13 +477,23 @@ def _expert_scan(xb, bp, top_i, weights, act, use_pallas, el, offset):
     return out, jnp.sum(one_hot).astype(jnp.int32)
 
 
-def _route(logits, k: int):
-    """The router's choice from its logits (B, T, E): softmax over ALL
-    experts, the k largest, renormalized (grokMoeRouter..grokMoeNormWeights,
-    grok1-tasks.cpp:56-115). Returns (top_i, weights), both (B, T, k)."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+def _route(logits, k: int, spec: ModelSpec):
+    """The router's choice from its logits (B, T, E): a score of every expert
+    (a softmax over ALL of them, grokMoeRouter..grokMoeNormWeights,
+    grok1-tasks.cpp:56-115, or where the spec says so a sigmoid of each), the
+    k largest, renormalized over those k unless the spec says not, times the
+    spec's scale. Returns (top_i, weights), both (B, T, k)."""
+    logits = logits.astype(jnp.float32)
+    if spec.router_score == RouterScore.SIGMOID:
+        probs = jax.nn.sigmoid(logits)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_i = jax.lax.top_k(probs, k)
-    return top_i, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    if spec.router_renorm:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    if spec.router_scale != 1.0:
+        top_p = top_p * spec.router_scale
+    return top_i, top_p
 
 
 def _router_logits(x, bp):
@@ -425,17 +525,21 @@ def _moe_ffn(xb, bp, spec: ModelSpec, axis_name, use_pallas, compress,
     with jax.named_scope("moe_route"):
         if router_logits is None:
             router_logits = _router_logits(xb, bp)
-        top_i, weights = _route(router_logits, k)
+        top_i, weights = _route(router_logits, k, spec)
 
     # the fused up+gate stack (fuse_matvec_groups) where there is one
     gu_stack = bp["moe_gu"] if "moe_gu" in bp else bp["moe_up"]
     el = gu_stack.shape[0]  # shard-local expert count
     offered, zero = jnp.int32(el), jnp.int32(0)
-    offset = 0  # the first expert this stack holds
+    # the first expert this stack holds: the checkpoint's share of the
+    # router's width (spec.expert_offset), and this shard's share of that
+    offset = spec.expert_offset
     if axis_name is not None and el != spec.n_experts:
-        offset = jax.lax.axis_index(axis_name) * el
+        offset = offset + jax.lax.axis_index(axis_name) * el
+    # every expert the router scores is held here: no assignment is dropped
+    whole = el == spec.n_router
     with jax.named_scope("moe_ffn"):
-        if (use_pallas and b * t == 1 and el == spec.n_experts
+        if (use_pallas and b * t == 1 and whole
                 and gu_stack.layout in ("i4p", "i8")):
             # Decode through the fused matvec kernels: each active expert's
             # packed planes sliced out of the stacked (E, ...) QTensor (moving
@@ -447,7 +551,7 @@ def _moe_ffn(xb, bp, spec: ModelSpec, axis_name, use_pallas, compress,
                 out_e = _expert_ffn(xb, bp, top_i.reshape(k)[j], act, True)
                 out = out + out_e * weights.reshape(k)[j].astype(xb.dtype)
             stats = jnp.stack([jnp.int32(k)] * 3 + [offered, zero, zero])
-        elif takes_the_scan(b * t, k, spec.n_experts):
+        elif takes_the_scan(b * t, k, spec.n_router):
             held = jnp.int32(el)
             out, a = _expert_scan(xb, bp, top_i, weights, act, use_pallas, el,
                                   offset)
@@ -458,25 +562,40 @@ def _moe_ffn(xb, bp, spec: ModelSpec, axis_name, use_pallas, compress,
             out, st = grouped_expert_ffn(
                 xb.reshape(b * t, d), top_i.reshape(b * t, k),
                 weights.reshape(b * t, k), bp, act_name=_act_name(spec),
-                el=el, offset=offset, use_pallas=use_pallas)
+                el=el, offset=offset, use_pallas=use_pallas,
+                # a share of the router's width sees that share of the rows
+                tile_experts=(el * spec.n_router // spec.n_experts
+                              if spec.n_router > spec.n_experts else None))
             out = out.reshape(b, t, d)
             stats = jnp.concatenate([st, offered[None], st[0:1], st[2:3]])
     if axis_name is not None and el != spec.n_experts:
         stats = jax.lax.psum(stats, axis_name)  # each shard counted its experts
+    if "sh_gate" in bp:
+        # the shared expert: a dense FFN every row goes through, its hidden
+        # axis TP-sliced like the dense FFN's, so its partial sum joins the
+        # routed one under the same psum
+        with jax.named_scope("moe_shared"):
+            h = (act(qmatmul(xb, bp["sh_gate"], use_pallas=use_pallas))
+                 * qmatmul(xb, bp["sh_up"], use_pallas=use_pallas))
+            out = out + qmatmul(h.astype(xb.dtype), bp["sh_down"],
+                                use_pallas=use_pallas)
     return _maybe_psum(out, axis_name, compress), stats
 
 
 def _block(x, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions,
            axis_name, sp_axis_name, sp_size, use_pallas, compress, window,
            kc, vc, paged_cold=None, block_tables=None, block_tokens=0,
-           paged_kernel=False, stacks=None):
+           paged_kernel=False, stacks=None, routed=None, layer_base=0):
     """One transformer block as a scan step: the carry is x, the caches kc/vc
     are read-only closures (loop invariants), and the ys are the layer's new
     K/V rows, for forward() to commit in one top-level write, with the
     layer's stats of _moe_ffn last (zeros where the block routes nothing).
     `stacks`: the weights that stay whole over the scan (forward() below),
     named into `bp` as `LayerOf` this layer's index: a matrix of a dense
-    stack, a layer's experts of an expert stack.
+    stack, a layer's experts of an expert stack. `routed`: whether this
+    stack's FFN is the expert layer (None: as the spec says; a leading dense
+    stack says False); `layer_base`: the stack's first layer, which the
+    caches are indexed from (the weights by the index within the stack).
     """
     stats = jnp.zeros((N_MOE_STATS,), jnp.int32)  # of the expert layer: a ys
     # the layer's kind rides in the xs beside its index, where the model has
@@ -485,8 +604,10 @@ def _block(x, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions,
     rope_on, swa = kind if kind else (None, None)
     if stacks:
         bp = {**bp, **{n: LayerOf(w, (layer_idx,)) for n, w in stacks.items()}}
+    routed = spec.is_moe if routed is None else routed
+    cache_idx = layer_idx + layer_base if layer_base else layer_idx
     router_logits = None
-    if spec.is_moe and spec.router_input == RouterInput.BLOCK_INPUT:
+    if routed and spec.router_input == RouterInput.BLOCK_INPUT:
         # the router reads the residual stream as the block receives it,
         # before the first norm; its logits wait for the expert layer
         with jax.named_scope("moe_route"):
@@ -497,12 +618,19 @@ def _block(x, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions,
     # residual is given)
     res_attn = None if spec.arch_type == ArchType.GROK1 else x
     with jax.named_scope("attn"):
-        attn_out, (k_t, v_t) = _attention(
-            x, bp, layer_idx, spec, rope, kc, vc, start_pos, positions,
-            axis_name, sp_axis_name, sp_size, use_pallas, compress, window,
-            paged_cold=paged_cold, block_tables=block_tables,
-            block_tokens=block_tokens, paged_kernel=paged_kernel,
-            residual=res_attn, rope_on=rope_on, swa=swa)
+        if spec.latent:
+            attn_out, (k_t, v_t) = _latent_attention(
+                x, bp, cache_idx, spec, rope, kc, vc, start_pos, positions,
+                axis_name, use_pallas, compress, window,
+                block_tables=block_tables, block_tokens=block_tokens,
+                paged_kernel=paged_kernel, residual=res_attn)
+        else:
+            attn_out, (k_t, v_t) = _attention(
+                x, bp, cache_idx, spec, rope, kc, vc, start_pos, positions,
+                axis_name, sp_axis_name, sp_size, use_pallas, compress,
+                window, paged_cold=paged_cold, block_tables=block_tables,
+                block_tokens=block_tokens, paged_kernel=paged_kernel,
+                residual=res_attn, rope_on=rope_on, swa=swa)
     if spec.arch_type == ArchType.GROK1:
         # grok: residual-join the *normalized* attention output (grokRmfFfn/Norm/Join)
         x = x + rmsnorm(attn_out, bp["rms_ffn"], spec.norm_eps)
@@ -512,7 +640,7 @@ def _block(x, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions,
         x = x + rmsnorm(moe_out, bp["rms_ffn2"], spec.norm_eps)
     else:
         x = attn_out  # residual-joined inside _attention
-        if spec.is_moe:
+        if routed:
             xb = rmsnorm(x, bp["rms_ffn"], spec.norm_eps)
             moe_out, stats = _moe_ffn(xb, bp, spec, axis_name, use_pallas,
                                       compress, router_logits=router_logits)
@@ -598,25 +726,43 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
     # dequant-matmul and the grouped expert kernels take their blocks from
     # the whole stack at the layer's (and the expert's) index, and a slice
     # would be a copy of the layer's weights, every expert touched or not
-    stacks = {n: w for n, w in params["blocks"].items()
-              if reads_the_stack(w, tokens.shape[0] * t, use_pallas)}
-    block_fn = functools.partial(_block, spec=spec, rope=rope, start_pos=start_pos,
-                                 positions=positions, axis_name=axis_name,
-                                 sp_axis_name=sp_axis_name, sp_size=sp_size,
-                                 use_pallas=use_pallas, compress=compress_collectives,
-                                 window=attn_window, kc=k_cache, vc=v_cache,
-                                 paged_cold=paged_cold,
-                                 block_tables=block_tables,
-                                 block_tokens=block_tokens,
-                                 paged_kernel=paged_kernel, stacks=stacks)
-    layer_ids = jnp.arange(spec.n_layers, dtype=jnp.int32)
-    xs = ({n: w for n, w in params["blocks"].items() if n not in stacks},
-          layer_ids)
-    if spec.rope_layers or spec.sliding_window:
-        # layers of more than one kind in ONE scan: the kind is data
-        xs += (jnp.asarray(spec.layer_rope(), jnp.int32),
-               jnp.asarray(spec.layer_window(), jnp.int32))
-    x, (k_rows, v_rows, stats) = jax.lax.scan(block_fn, x, xs)
+    def scan_stack(x, blocks, depth, **kind):
+        """One `lax.scan` over a stack of `depth` like layers."""
+        stacks = {n: w for n, w in blocks.items()
+                  if reads_the_stack(w, tokens.shape[0] * t, use_pallas)}
+        block_fn = functools.partial(
+            _block, spec=spec, rope=rope, start_pos=start_pos,
+            positions=positions, axis_name=axis_name,
+            sp_axis_name=sp_axis_name, sp_size=sp_size,
+            use_pallas=use_pallas, compress=compress_collectives,
+            window=attn_window, kc=k_cache, vc=v_cache,
+            paged_cold=paged_cold, block_tables=block_tables,
+            block_tokens=block_tokens, paged_kernel=paged_kernel,
+            stacks=stacks, **kind)
+        xs = ({n: w for n, w in blocks.items() if n not in stacks},
+              jnp.arange(depth, dtype=jnp.int32))
+        if spec.rope_layers or spec.sliding_window:
+            # layers of more than one kind in ONE scan: the kind is data
+            xs += (jnp.asarray(spec.layer_rope(), jnp.int32),
+                   jnp.asarray(spec.layer_window(), jnp.int32))
+        return jax.lax.scan(block_fn, x, xs)
+
+    if spec.lead_layers:
+        # the leading dense layers hold other tensors than the rest: a stack
+        # and a scan of their own, the caches indexed by the global layer
+        assert paged_cold is None and not sp_active, (
+            "a leading stack is not supported with sp (ring) sharding or "
+            "host/disc KV paging")
+        with jax.named_scope("lead_stack"):
+            x, lead_ys = scan_stack(x, params["lead"], spec.lead_layers,
+                                    routed=False)
+        x, ys = scan_stack(x, params["blocks"], spec.block_layers,
+                           layer_base=spec.lead_layers)
+        k_rows, v_rows, stats = (jnp.concatenate([a, b_])
+                                 for a, b_ in zip(lead_ys, ys))
+    else:
+        x, (k_rows, v_rows, stats) = scan_stack(x, params["blocks"],
+                                                spec.n_layers)
     # commit all layers' new rows in one write per cache: (L, B, hk, T, hs)
     # lands at [.., .., .., start_pos : start_pos+T, ..]
     if block_tables is not None:
@@ -679,8 +825,10 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
 
 def init_kv_cache(spec: ModelSpec, batch: int = 1, dtype=jnp.float32,
                   n_kv_heads: int | None = None, seq_len: int | None = None):
-    """Zeroed head-major KV caches (L, B, hk, S, hs); hk may be a TP-local count."""
+    """Zeroed head-major KV caches (L, B, hk, S, hs); hk may be a TP-local
+    count. A latent spec's are one row a token and an empty second side
+    (ModelSpec.cache_widths)."""
     hk = n_kv_heads if n_kv_heads is not None else spec.n_kv_heads
     s = seq_len if seq_len is not None else spec.seq_len
-    shape = (spec.n_layers, batch, hk, s, spec.head_size)
-    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+    return tuple(jnp.zeros((spec.n_layers, batch, hk, s, w), dtype)
+                 for w in spec.cache_widths)

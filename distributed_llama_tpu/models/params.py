@@ -15,7 +15,12 @@ blocks along `in`. Tensor inventory mirrors the `.m` file exactly
        moe:   router (n_experts, dim), moe_up/moe_gate (E, hidden, dim),
               moe_down (E, dim, hidden)
        norms: rms_att (dim,), rms_ffn (dim,) [+ grok1: rms_moe, rms_ffn2]
+       latent attention and the shared expert: `block_tensor_shapes`
     rms_final (dim,) f32
+
+A model with leading dense layers (ModelSpec.lead_layers) has TWO stacks,
+`params["lead"]` and `params["blocks"]` (`STACKS`), each stacked over its own
+layers: the tensors of the two kinds differ.
 """
 
 from __future__ import annotations
@@ -32,31 +37,62 @@ from .spec import ArchType, ModelSpec
 Params = dict[str, Any]
 
 
-def block_tensor_shapes(spec: ModelSpec) -> dict[str, tuple[tuple[int, ...], bool]]:
+def block_tensor_shapes(spec: ModelSpec, lead: bool = False
+                        ) -> dict[str, tuple[tuple[int, ...], bool]]:
     """Per-layer tensor name -> (shape-without-layer-axis, is_quantized_matmul).
 
     Order matters: it is the `.m` file tensor order within a layer
-    (transformer.cpp:498-523).
+    (transformer.cpp:498-523). `lead`: the tensors of a leading dense layer
+    (spec.lead_layers), which stand in `params["lead"]`.
+
+    Latent attention (spec.latent) has wq_a (q_lora_rank, dim), wq_b (q_dim,
+    q_lora_rank), wkv_a (kv_lora_rank + qk_rope_head_dim, dim), the published
+    kv_b projection as its two halves by head, w_uk (H, qk_nope_head_dim,
+    kv_lora_rank) and w_uv (H, v_head_dim, kv_lora_rank), and the norms rms_q
+    and rms_kv; a routed block with a shared expert has sh_gate, sh_down,
+    sh_up beside its stacks.
     """
     d, h, kv, e = spec.dim, spec.hidden_dim, spec.kv_dim, spec.n_experts
     qd = spec.q_dim  # n_heads x head_size: dim unless the header states head_dim
-    shapes: dict[str, tuple[tuple[int, ...], bool]] = {
-        "wq": ((qd, d), True),
-        "wk": ((kv, d), True),
-        "wv": ((kv, d), True),
-        "wo": ((d, qd), True),
-    }
-    if spec.is_moe:
-        shapes["router"] = ((e, d), True)
+    shapes: dict[str, tuple[tuple[int, ...], bool]]
+    if spec.latent:
+        r, nh = spec.kv_lora_rank, spec.n_heads
+        shapes = {
+            "wq_a": ((spec.q_lora_rank, d), True),
+            "wq_b": ((qd, spec.q_lora_rank), True),
+            "wkv_a": ((r + spec.qk_rope_head_dim, d), True),
+            "w_uk": ((nh, spec.qk_nope_head_dim, r), True),
+            "w_uv": ((nh, spec.v_head_dim, r), True),
+            "wo": ((d, spec.o_dim), True),
+        }
+    else:
+        shapes = {
+            "wq": ((qd, d), True),
+            "wk": ((kv, d), True),
+            "wv": ((kv, d), True),
+            "wo": ((d, qd), True),
+        }
+    if spec.is_moe and not lead:
+        shapes["router"] = ((spec.n_router, d), True)
         shapes["moe_up"] = ((e, h, d), True)
         shapes["moe_gate"] = ((e, h, d), True)
         shapes["moe_down"] = ((e, d, h), True)
+        if spec.shared_hidden_dim:
+            sh = spec.shared_hidden_dim
+            shapes["sh_gate"] = ((sh, d), True)
+            shapes["sh_down"] = ((d, sh), True)
+            shapes["sh_up"] = ((sh, d), True)
     else:
+        if lead:
+            h = spec.lead_hidden_dim
         shapes["w1"] = ((h, d), True)
         shapes["w2"] = ((d, h), True)
         shapes["w3"] = ((h, d), True)
     shapes["rms_att"] = ((d,), False)
     shapes["rms_ffn"] = ((d,), False)
+    if spec.latent:
+        shapes["rms_q"] = ((spec.q_lora_rank,), False)
+        shapes["rms_kv"] = ((spec.kv_lora_rank,), False)
     if spec.arch_type == ArchType.GROK1:
         shapes["rms_moe"] = ((d,), False)
         shapes["rms_ffn2"] = ((d,), False)
@@ -72,20 +108,45 @@ def init_random_params(spec: ModelSpec, weights_ftype: FloatType = FloatType.F32
     def randn(*shape):
         return (rng.randn(*shape) * scale).astype(np.float32)
 
-    L = spec.n_layers
-    blocks: Params = {}
-    for name, (shape, quantized) in block_tensor_shapes(spec).items():
-        full = randn(L, *shape)
-        if quantized:
-            blocks[name] = QTensor.from_float(full, weights_ftype)
-        else:
-            blocks[name] = full + 1.0  # norm weights around 1
-    return {
-        "embedding": randn(spec.vocab_size, spec.dim),
-        "blocks": blocks,
-        "rms_final": randn(spec.dim) + 1.0,
-        "wcls": QTensor.from_float(randn(spec.vocab_size, spec.dim), weights_ftype),
-    }
+    def stack(depth: int, lead: bool) -> Params:
+        blocks: Params = {}
+        for name, (shape, quantized) in block_tensor_shapes(spec, lead).items():
+            full = randn(depth, *shape)
+            if quantized:
+                blocks[name] = QTensor.from_float(full, weights_ftype)
+            else:
+                blocks[name] = full + 1.0  # norm weights around 1
+        return blocks
+
+    # the draws keep their order (blocks, embedding, final norm, head): a
+    # seed gives the model it always gave
+    out = {"lead": stack(spec.lead_layers, True)} if spec.lead_layers else {}
+    out["blocks"] = stack(spec.block_layers, False)
+    out["embedding"] = randn(spec.vocab_size, spec.dim)
+    out["rms_final"] = randn(spec.dim) + 1.0
+    out["wcls"] = QTensor.from_float(randn(spec.vocab_size, spec.dim),
+                                     weights_ftype)
+    return out
+
+
+STACKS = ("lead", "blocks")  # the layer stacks a params dict may hold, in order
+# the two halves of latent attention's kv_b projection: batched by head and
+# small, so they are held dense in the engine's dtype, not as Q40 blocks
+_HELD_DENSE = ("w_uk", "w_uv")
+
+
+def hold_dense(params: Params, dtype) -> Params:
+    """`params` with the tensors the program multiplies by head
+    (`_HELD_DENSE`) dequantized once into `dtype`: what a loader's QTensor of
+    them becomes before the engine places the weights."""
+    out = dict(params)
+    for st in STACKS:
+        if st in params and any(isinstance(params[st].get(n), QTensor)
+                                for n in _HELD_DENSE):
+            out[st] = {n: (t.dequantize(dtype=dtype)
+                           if n in _HELD_DENSE and isinstance(t, QTensor)
+                           else t) for n, t in params[st].items()}
+    return out
 
 
 _I8_CONVERTIBLE = (FloatType.Q40, FloatType.Q80)
@@ -96,8 +157,9 @@ _I8_CONVERTIBLE = (FloatType.Q40, FloatType.Q80)
 # (ColMatmulSlice), so the i4p split-plane pack must be applied per column group
 # (QTensor.to_i4p_layout).
 _DENSE_MATMULS = {"wq", "wk", "wv", "wo", "w1", "w2", "w3",
-                  "moe_up", "moe_gate", "moe_down"}
-_COL_SHARDED = {"wo", "w2", "moe_down"}
+                  "moe_up", "moe_gate", "moe_down",
+                  "wq_a", "wq_b", "wkv_a", "sh_gate", "sh_up", "sh_down"}
+_COL_SHARDED = {"wo", "w2", "moe_down", "sh_down"}
 
 
 def _kernel_convertible(t: QTensor, stacked: bool) -> bool:
@@ -328,20 +390,8 @@ def prepare_for_pallas(params: Params, tp: int = 1,
 
     from ..parallel.sharding import param_pspecs
 
-    out: Params = {"embedding": params["embedding"], "blocks": {},
+    out: Params = {"embedding": params["embedding"],
                    "rms_final": params["rms_final"]}
-    blocks = params["blocks"]
-    plan = _fuse_plan(blocks, spec, tp, moe_sharding) if fuse else {}
-    merged = {m: f for f in plan for m in _FUSE_GROUPS[f]}
-    work: dict[str, tuple[list[QTensor], int]] = {}  # name -> members, row groups
-    for name, t in blocks.items():
-        if name in merged:
-            work.setdefault(merged[name], (
-                [blocks[m] for m in _FUSE_GROUPS[merged[name]]],
-                plan[merged[name]]))
-        else:
-            work[name] = ([t], getattr(t, "row_groups", 1))
-    pspecs = param_pspecs({"blocks": work}, moe_sharding)
 
     def convert(members, row_groups, col_sharded, pspec, row_axis=1):
         t = members[0]
@@ -356,21 +406,40 @@ def prepare_for_pallas(params: Params, tp: int = 1,
         sharding = None if mesh is None else NamedSharding(mesh, pspec)
         return _repack_on_device(members, row_groups, col_groups, sharding)
 
-    for name, (members, row_groups) in work.items():
-        if name in _DENSE_MATMULS or name in _FUSE_GROUPS:
-            col = name in _COL_SHARDED and not (
-                moe_sharding == "expert" and name.startswith("moe_"))
-            out["blocks"][name] = convert(
-                members, row_groups, col, pspecs["blocks"][name],
-                _FUSE_ROW_AXIS.get(name, 1))
-        else:
-            out["blocks"][name] = members[0]
+    for st in STACKS:
+        if st not in params:
+            continue
+        blocks = params[st]
+        plan = _fuse_plan(blocks, spec, tp, moe_sharding) if fuse else {}
+        merged = {m: f for f in plan for m in _FUSE_GROUPS[f]}
+        # name -> members, row groups
+        work: dict[str, tuple[list[QTensor], int]] = {}
+        for name, t in blocks.items():
+            if name in merged:
+                work.setdefault(merged[name], (
+                    [blocks[m] for m in _FUSE_GROUPS[merged[name]]],
+                    plan[merged[name]]))
+            else:
+                work[name] = ([t], getattr(t, "row_groups", 1))
+        pspecs = param_pspecs({"blocks": work}, moe_sharding)
+        out[st] = {}
+        for name, (members, row_groups) in work.items():
+            if name in _DENSE_MATMULS or name in _FUSE_GROUPS:
+                col = name in _COL_SHARDED and not (
+                    moe_sharding == "expert" and name.startswith("moe_"))
+                out[st][name] = convert(
+                    members, row_groups, col, pspecs["blocks"][name],
+                    _FUSE_ROW_AXIS.get(name, 1))
+            else:
+                out[st][name] = members[0]
     wcls = params["wcls"]
     if _kernel_convertible(wcls, stacked=False):
         # the head is a stack of one
         wcls = _map_leaves(
             convert([_map_leaves(wcls, lambda a: a[None])], 1, False,
-                    PartitionSpec(None, *pspecs["wcls"])), lambda a: a[0])
+                    PartitionSpec(None, *param_pspecs(
+                        {"blocks": {}}, moe_sharding)["wcls"])),
+            lambda a: a[0])
     out["wcls"] = wcls
     return out
 
@@ -394,11 +463,13 @@ def decode_stream_bytes(params: Params, spec: ModelSpec, rows: int = 1) -> int:
     reads a chosen expert once and an unchosen one not at all). The numerator of
     the achieved-GB/s observability metric."""
     total = 0
-    for name, t in list(params["blocks"].items()) + [("wcls", params["wcls"])]:
+    leaves = [nt for st in STACKS for nt in params.get(st, {}).items()]
+    for name, t in leaves + [("wcls", params["wcls"])]:
         n = t.nbytes() if isinstance(t, QTensor) else t.nbytes
         if name.startswith("moe_") and spec.n_experts:
+            # a share of a wider router is given that share of the rows
             n = int(n * expected_experts_touched(
-                spec.n_experts, spec.n_active_experts, rows) / spec.n_experts)
+                spec.n_router, spec.n_active_experts, rows) / spec.n_router)
         total += n
     return total
 

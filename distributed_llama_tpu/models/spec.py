@@ -9,6 +9,7 @@ formats/mfile.py).
 from __future__ import annotations
 
 import enum
+import math
 from dataclasses import dataclass, replace
 
 
@@ -34,11 +35,22 @@ class RouterInput(enum.IntEnum):
     BLOCK_INPUT = 1
 
 
+class RouterScore(enum.IntEnum):
+    """What a routed block's router makes of its logits before it takes the
+    k largest: a softmax over all experts (Mixtral, Grok-1) or a sigmoid of
+    each (the DeepSeek-V3 graph)."""
+
+    SOFTMAX = 0
+    SIGMOID = 1
+
+
 class RopeType(enum.IntEnum):
     UNKNOWN = -1
     LLAMA = 0
     FALCON = 1
     LLAMA3_1 = 2
+    # interleaved pairs as LLAMA, YaRN's frequencies (ops/rope.py)
+    YARN = 3
 
 
 # .m header key ids (reference: src/transformer.hpp:10-30 / converter/writer.py:109-130)
@@ -68,10 +80,35 @@ class HeaderKey(enum.IntEnum):
     SLIDING_WINDOW = 20
     ROUTER_INPUT = 21
     NORM_EPS_E9 = 22  # rms eps in units of 1e-9 (header values are int32)
+    # latent attention (kv_lora_rank > 0), a leading dense stack, the shared
+    # expert, the router's score and a share of the experts, YaRN. Floats
+    # ride as integers in units of 1e-6
+    Q_LORA_RANK = 23
+    KV_LORA_RANK = 24
+    QK_NOPE_HEAD_DIM = 25
+    QK_ROPE_HEAD_DIM = 26
+    V_HEAD_DIM = 27
+    LEAD_LAYERS = 28
+    LEAD_HIDDEN_DIM = 29
+    SHARED_HIDDEN_DIM = 30
+    ROUTER_SCORE = 31
+    ROUTER_RENORM = 50
+    ROUTER_SCALE_E6 = 51
+    ROUTER_WIDTH = 52
+    EXPERT_OFFSET = 53
+    YARN_BETA_FAST_E6 = 54
+    YARN_BETA_SLOW_E6 = 55
+    YARN_MSCALE_E6 = 56
+    YARN_MSCALE_ALL_DIM_E6 = 57
     # per-layer kinds as bit masks, 30 layers a word: bit l of word l // 30.
     # No word present means every layer (the default of both lists)
     ROPE_LAYERS_0 = 32
     WINDOW_LAYERS_0 = 40
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor: 0.1 x mscale x ln(factor) + 1."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
 
 
 @dataclass(frozen=True)
@@ -107,11 +144,100 @@ class ModelSpec:
     rope_layers: tuple[int, ...] = ()
     window_layers: tuple[int, ...] = ()
     router_input: RouterInput = RouterInput.FFN_NORM
+    # --- latent attention (kv_lora_rank > 0; the DeepSeek-V3 graph): q through
+    # a rank-q_lora_rank pair of projections, keys and values through ONE
+    # latent row a token of kv_lora_rank values (normed) and qk_rope_head_dim
+    # rotated ones, shared by all heads; a head's q and k are qk_nope_head_dim
+    # + qk_rope_head_dim wide, its v v_head_dim. n_kv_heads is 1
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # the first lead_layers layers are dense (FFN width lead_hidden_dim) and
+    # stand in a stack and a scan of their own ahead of the n_layers -
+    # lead_layers routed ones. 0 = one stack
+    lead_layers: int = 0
+    lead_hidden_dim: int = 0
+    # a routed block's shared expert: a dense FFN of this width added to the
+    # routed sum. 0 = none
+    shared_hidden_dim: int = 0
+    router_score: RouterScore = RouterScore.SOFTMAX
+    router_renorm: bool = True  # the k taken scores divided by their sum
+    router_scale: float = 1.0  # the routing weights times this
+    # the router's width where it is wider than the n_experts held: this
+    # checkpoint holds experts [expert_offset, expert_offset + n_experts) of
+    # router_width, and a token's assignments to the others add nothing here.
+    # 0 = n_experts
+    router_width: int = 0
+    expert_offset: int = 0
+    # YaRN (rope_type YARN): rope_scaling_factor over
+    # rope_scaling_orig_max_seq_len, the correction range from the two betas
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # --- derived (reference: transformer.cpp:102-106) ---
     @property
+    def latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
     def head_size(self) -> int:
+        """Width of a head's q (and k): where attention is latent, the part
+        that is not rotated and the part that is."""
+        if self.latent:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.head_dim or self.dim // self.n_heads
+
+    @property
+    def rope_width(self) -> int:
+        """Values of a head the rotation covers (the tables' width x 2)."""
+        return self.qk_rope_head_dim if self.latent else self.head_size
+
+    @property
+    def o_dim(self) -> int:
+        """Width of wo's input: n_heads x the width of a head's v."""
+        return self.n_heads * (self.v_head_dim if self.latent
+                               else self.head_size)
+
+    @property
+    def cache_widths(self) -> tuple[int, int]:
+        """Values a token holds a layer a kv head on the cache's two sides.
+        Latent: ONE row [c (kv_lora_rank) ; k_pe (qk_rope_head_dim)], padded
+        with zeros to whole lanes of 128 (576 to 640: the chip's tiled memory
+        pads the minor axis so whatever is asked for), and no second side."""
+        if self.latent:
+            return (-(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128)
+                    * 128, 0)
+        return self.head_size, self.head_size
+
+    def cache_row_bytes(self, itemsize: int) -> int:
+        """Bytes a token holds a layer, all kv heads, both sides."""
+        return self.n_kv_heads * sum(self.cache_widths) * itemsize
+
+    @property
+    def attn_scale(self) -> float:
+        """What q . k is multiplied by before the softmax: head_size^-0.5,
+        times YaRN's mscale(factor, mscale_all_dim)^2 where the model states
+        one (the DeepSeek-V3 graph's softmax_scale)."""
+        scale = self.head_size ** -0.5
+        if (self.rope_type == RopeType.YARN and self.yarn_mscale_all_dim
+                and self.rope_scaling_factor > 1.0):
+            m = yarn_mscale(self.rope_scaling_factor, self.yarn_mscale_all_dim)
+            scale *= m * m
+        return scale
+
+    @property
+    def n_router(self) -> int:
+        """Experts the router scores."""
+        return self.router_width or self.n_experts
+
+    @property
+    def block_layers(self) -> int:
+        """Layers of the main stack, behind the leading ones."""
+        return self.n_layers - self.lead_layers
 
     @property
     def q_dim(self) -> int:
@@ -158,6 +284,16 @@ class ModelSpec:
         for name in ("rope_layers", "window_layers"):
             kinds = getattr(spec, name)
             assert len(kinds) in (0, spec.n_layers), (name, len(kinds), spec.n_layers)
+        assert 0 <= spec.lead_layers < spec.n_layers, spec.lead_layers
+        if spec.latent:
+            assert spec.n_kv_heads == 1, "a latent row is one kv head"
+            assert not (spec.rope_layers or spec.sliding_window), (
+                "latent attention has one kind of layer")
+        if spec.lead_layers:
+            assert not (spec.rope_layers or spec.sliding_window), (
+                "layers of two kinds stand in one stack")
+        assert (spec.expert_offset + spec.n_experts
+                <= max(spec.n_router, spec.n_experts))
         return spec
 
     @property

@@ -118,3 +118,27 @@ def merge_attention_partials(out_a: jax.Array, lse_a: jax.Array,
     wb = jnp.exp(lse_b - m)
     den = jnp.maximum(wa + wb, 1e-30)[..., None]
     return (out_a * wa[..., None] + out_b * wb[..., None]) / den
+
+
+def latent_attention(q: jax.Array, rows: jax.Array, positions: jax.Array,
+                     key_positions: jax.Array, n_values: int,
+                     scale: float) -> jax.Array:
+    """Causal attention of every head against ONE row a key, in the absorbed
+    form of latent attention: a head's query is as wide as the row, the score
+    is their product, and the values are the row's first `n_values` entries
+    (the latent), whichever head reads them.
+
+    q: (B, T, H, W); rows: (B, S, W) (window slots, then the chunk's own
+    rows); positions (T,) or (B, T); key_positions (S,) or (B, S), garbage
+    slots pushed past every position as for gqa_attention.
+    Returns (B, T, H, n_values) float32."""
+    scores = jnp.einsum("bthw,bsw->bhts", q.astype(jnp.float32),
+                        rows.astype(jnp.float32)) * scale
+    if positions.ndim == 1:
+        mask = (key_positions[None, :] <= positions[:, None])[None, None]
+    else:
+        kp = key_positions if key_positions.ndim == 2 else key_positions[None]
+        mask = (kp[:, None, :] <= positions[:, :, None])[:, None]
+    probs = masked_softmax(scores, mask)
+    return jnp.einsum("bhts,bsv->bthv", probs,
+                      rows[..., :n_values].astype(jnp.float32))

@@ -126,7 +126,7 @@ def _grouped_xla(rows, p, up, gate, down, tile, act, merged):
 
 
 def grouped_expert_ffn(x, top_i, weights, bp, *, act_name: str, el: int,
-                       offset=0, use_pallas=False):
+                       offset=0, use_pallas=False, tile_experts=None):
     """sum_j weights[n, j] * down_e(act(gate_e x_n) * up_e x_n), e = top_i[n, j],
     over the experts this stack holds. x (N, d); top_i, weights (N, k); bp has
     `moe_gu` (the merged [up|gate] stack) or `moe_up` and `moe_gate`, and
@@ -134,13 +134,15 @@ def grouped_expert_ffn(x, top_i, weights, bp, *, act_name: str, el: int,
     a layer of the stack over layers: the kernels read that in place, XLA
     its slice. Returns ((N, d) in x.dtype, stats): stats int32 (3,) =
     assignments held here, rows computed (tiles in use x tile), experts
-    touched."""
+    touched. `tile_experts`: the experts the N k assignments spread over,
+    where that is more than the `el` held (a share of a wider router): the
+    row tile follows the mean run that reaches a held expert."""
     n, k = top_i.shape
     merged = "moe_gu" in bp
     up = bp["moe_gu"] if merged else bp["moe_up"]
     gate = up if merged else bp["moe_gate"]
     down = bp["moe_down"]
-    tile = row_tile(n * k, el)
+    tile = row_tile(n * k, tile_experts or el)
     p = plan(top_i, el, offset, tile)
     xz = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])
     rows = xz[p["src"]]

@@ -28,6 +28,14 @@ live here:
   (bf16 x bf16 products are exact in f32); statistics, p and the
   accumulator are f32. f16 never appears (Mosaic cannot lower f16 refs).
 
+- `latent_paged_attention` (and `latent_paged_attention_xla`, its twin) — the
+  same walk for a LATENT spec's pool, (L, N, 1, bt, W): one row a token that
+  is key and value at once (the score is q . row over all W, the value the
+  row's first `n_values` entries), every head reading it. Grid (B, T / tq):
+  a step takes tq <= 8 chunk positions x all heads as one (tq*H, W) query
+  block, so a 64-token chunk of 64 heads is eight blocks of 512 rows and a
+  step's rows are copied once for all heads of a block.
+
 Numerics: the kernel's blockwise online softmax is mathematically exact but
 not bit-identical to the one-shot XLA softmax; it is the TPU path
 (`use_pallas` engines; `paged_kernel=True`), with interpret mode on CPU for
@@ -327,3 +335,184 @@ def paged_attention_xla(q, kc, vc, k_new, v_new, tables, lengths, layer_idx,
                         key_lo=(jnp.maximum(positions - window + 1, 0)
                                 if window else None))
     return out.reshape(b, t, hq, hs)
+
+
+# ---- latent attention: ONE row a token, every head reads it -----------------
+
+_LATENT_Q_TOKENS = 8  # chunk positions a grid step's query block holds
+
+
+def _latent_q_tokens(t: int) -> int:
+    """Positions of the chunk a grid step takes: the largest divisor of T up
+    to 8, so that a 64-token chunk's 64 heads x 64 positions are eight blocks
+    of 512 query rows, not one of 4096."""
+    return max(d for d in range(1, min(t, _LATENT_Q_TOKENS) + 1) if t % d == 0)
+
+
+def _latent_kernel(li_ref, tbl_ref, len_ref, q_ref, rn_ref, pool_hbm, o_ref,
+                   buf, sem, m_ref, l_ref, *, bt, nb, pp, t, tq, h, nv, scale):
+    """Grid step (b, qi): the query rows of positions qi*tq .. of row b, all
+    h heads (row r of the block: position qi*tq + r // h, head r % h),
+    against the steps of pp pool blocks that hold the row's committed rows.
+
+    Blocks: q (1, tq*h, W) | rn (1, t, W), the chunk's own rows | out
+    (1, tq*h, nv) f32, the flash accumulator until the last line. pool_hbm is
+    the whole pool (L, N, 1, bt, W), left in HBM. A row of the pool is key
+    and value at once: the score is q . row over all W, the value the row's
+    first nv entries. Scratch: buf (2, pp*bt, W) double buffer, sem (2,),
+    the flash (m, l) statistics (tq*h, 1)."""
+    b, qi = pl.program_id(0), pl.program_id(1)
+    sk = buf.shape[1]
+    length = len_ref[b]
+    li = li_ref[0]
+    n_live = jnp.minimum((length + sk - 1) // sk, -(-nb // pp))
+    dt = jnp.promote_types(q_ref.dtype, buf.dtype)
+
+    def copies(j, slot):
+        out = []
+        for i in range(pp):
+            page = tbl_ref[b * nb + jnp.minimum(j * pp + i, nb - 1)]
+            out.append(pltpu.make_async_copy(
+                pool_hbm.at[li, page, 0], buf.at[slot, pl.ds(i * bt, bt), :],
+                sem.at[slot]))
+        return out
+
+    @pl.when(n_live > 0)
+    def _first():
+        for c in copies(0, 0):
+            c.start()
+
+    m_ref[:] = jnp.full_like(m_ref, _NEG)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    o_ref[:] = jnp.zeros_like(o_ref)
+    q = q_ref[0].astype(dt)  # (tq*h, W)
+
+    def step(j, carry):
+        slot = j % 2
+
+        @pl.when(j + 1 < n_live)
+        def _next():
+            for c in copies(j + 1, 1 - slot):
+                c.start()
+
+        for c in copies(j, slot):
+            c.wait()
+        # every committed row lies before every query of the chunk: only the
+        # rows at and past the committed length (garbage) are masked
+        left = length - j * sk
+        live = jax.lax.broadcasted_iota(jnp.int32, (1, sk), 1) < left
+        live_v = jax.lax.broadcasted_iota(jnp.int32, (sk, 1), 0) < left
+        rows = buf[slot]
+        s = jax.lax.dot_general(q, rows.astype(dt), (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(live, s, _NEG)  # (tq*h, sk)
+        vb = jnp.where(live_v, rows[:, :nv].astype(jnp.float32), 0.0)
+        m_old = m_ref[:]
+        m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
+        a = jnp.exp(m_old - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[:] = l_ref[:] * a + jnp.sum(p, axis=1, keepdims=True)
+        o_ref[0] = o_ref[0] * a + jax.lax.dot_general(
+            p, vb, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[:] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n_live, step, 0)
+
+    # fold the chunk's own rows: query row r sits at chunk position
+    # qi*tq + r // h and may attend chunk row tau iff tau <= that
+    ti = qi * tq + jax.lax.broadcasted_iota(jnp.int32, (tq * h, t), 0) // h
+    tau = jax.lax.broadcasted_iota(jnp.int32, (tq * h, t), 1)
+    rn = rn_ref[0].astype(jnp.float32)  # (t, W)
+    s_new = jax.lax.dot_general(q_ref[0].astype(jnp.float32), rn,
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+    s_new = jnp.where(tau <= ti, s_new, _NEG)
+    m_old = m_ref[:]
+    m_f = jnp.maximum(m_old, jnp.max(s_new, axis=1, keepdims=True))
+    a_f = jnp.exp(m_old - m_f)
+    p_new = jnp.exp(s_new - m_f)
+    denom = l_ref[:] * a_f + jnp.sum(p_new, axis=1, keepdims=True)
+    out = o_ref[0] * a_f + jax.lax.dot_general(
+        p_new, rn[:, :nv], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    o_ref[0] = out / denom
+
+
+@functools.partial(jax.jit, static_argnames=("n_read", "n_values", "scale",
+                                             "interpret"))
+def latent_paged_attention(q, pool, rows_new, tables, lengths, layer_idx, *,
+                           n_read: int, n_values: int, scale: float,
+                           interpret: bool | None = None):
+    """Latent attention (the absorbed form, models/forward.py
+    `_latent_attention`) of T chunk queries a row against block-table rows.
+
+    q: (B, T, H, W) in the activation dtype, a head's query as wide as a
+        cache row. pool: (L, N, 1, bt, W) the FULL stacked pool, one row a
+        token a layer; only the (layer, tables[b, j]) blocks under the row's
+        length are moved, once for all H heads of a query block.
+    rows_new: (B, T, W) the chunk's uncommitted rows. tables (B, W_t) i32,
+    lengths (B,) i32, layer_idx i32 scalar, n_read static, as paged_attention.
+    n_values: the leading entries of a row that are also its value (the
+    latent); scale: what q . row is multiplied by.
+    Returns (B, T, H, n_values) f32."""
+    if interpret is None:
+        interpret = interpret_requested()
+    b, t, h, w = q.shape
+    l, n, hk, bt, w2 = pool.shape
+    assert hk == 1 and w2 == w and rows_new.shape == (b, t, w), (
+        q.shape, pool.shape, rows_new.shape)
+    nb = n_read
+    pp = pages_per_step(nb, bt)
+    tq = _latent_q_tokens(t)
+    qr = q.reshape(b, t * h, w)
+    tbl_flat = tables[:, :nb].reshape(-1).astype(jnp.int32)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,  # (layer_idx_arr, tbl_flat, lengths)
+        grid=(b, t // tq),
+        in_specs=[
+            pl.BlockSpec((1, tq * h, w), lambda bi, qi, *_: (bi, qi, 0)),
+            pl.BlockSpec((1, t, w), lambda bi, qi, *_: (bi, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, tq * h, n_values),
+                               lambda bi, qi, *_: (bi, qi, 0)),
+        scratch_shapes=[pltpu.VMEM((2, pp * bt, w), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.VMEM((tq * h, 1), jnp.float32),
+                        pltpu.VMEM((tq * h, 1), jnp.float32)],
+    )
+    body = functools.partial(_latent_kernel, bt=bt, nb=nb, pp=pp, t=t, tq=tq,
+                             h=h, nv=n_values, scale=scale)
+    out = pl.pallas_call(
+        body, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, t * h, n_values), jnp.float32),
+        interpret=interpret, name="latent_paged_attention",
+    )(jnp.asarray([layer_idx], jnp.int32), tbl_flat,
+      jnp.asarray(lengths, jnp.int32), qr, rows_new, pool)
+    return out.reshape(b, t, h, n_values)
+
+
+def latent_paged_attention_xla(q, pool, rows_new, tables, lengths, layer_idx,
+                               *, n_read: int, n_values: int, scale: float):
+    """XLA twin of `latent_paged_attention` (the CPU's path, and the kernel's
+    oracle): gather the table's blocks into the window layout and run
+    ops/attention.py `latent_attention`. Shapes as the kernel's."""
+    from .attention import latent_attention
+
+    b, t, h, w = q.shape
+    l, n, hk, bt, _ = pool.shape
+    pl_ = jax.lax.dynamic_slice(pool, (layer_idx, 0, 0, 0, 0),
+                                (1, n, 1, bt, w))[0, :, 0]  # (N, bt, W)
+    win = n_read * bt
+    kw = pl_[tables[:, :n_read]].reshape(b, win, w)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    slot = jnp.arange(win)
+    slot_pos = jnp.where(slot[None, :] < lengths[:, None], slot[None, :],
+                         jnp.int32(1 << 30))
+    positions = lengths[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+    key_pos = jnp.concatenate([slot_pos, positions], axis=1)
+    return latent_attention(
+        q, jnp.concatenate([kw, jnp.asarray(rows_new, kw.dtype)], axis=1),
+        positions, key_pos, n_values, scale)

@@ -1,4 +1,6 @@
-"""Rotary position embeddings — the three styles of the reference (src/commands.cpp:140-257).
+"""Rotary position embeddings — the three styles of the reference (src/commands.cpp:140-257),
+and YaRN (ROPE_YARN: interleaved pairs over a head's rotary part, the frequencies scaled by
+parts, `_yarn_scale_freqs`; its attention scale is `ModelSpec.attn_scale`).
 
 - ROPE_LLAMA: interleaved pairs (2k, 2k+1), freq_k = theta^(-2k/head_size), precomputed
   cos/sin tables over the full sequence (LlamaRopeCommand, commands.cpp:140-179).
@@ -43,6 +45,27 @@ def _llama31_scale_freqs(freqs: np.ndarray, factor: float, low_freq_factor: floa
     return scaled
 
 
+def _yarn_scale_freqs(freqs: np.ndarray, factor: float, orig_max_seq_len: int,
+                      beta_fast: float, beta_slow: float, theta: float
+                      ) -> np.ndarray:
+    """YaRN's frequencies over a rotary width of 2 x len(freqs): pair i keeps
+    its own frequency below the correction range, is divided by `factor`
+    above it, and is ramped between. The range is where a pair turns
+    beta_fast (its floor) and beta_slow (its ceiling) times over the original
+    context: dim x ln(orig / (beta 2 pi)) / (2 ln theta)."""
+    dim = 2 * len(freqs)
+
+    def turns_at(beta):
+        return (dim * math.log(orig_max_seq_len / (beta * 2.0 * math.pi))
+                / (2.0 * math.log(theta)))
+
+    lo = max(math.floor(turns_at(beta_fast)), 0)
+    hi = min(math.ceil(turns_at(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(len(freqs), dtype=np.float64) - lo)
+                   / max(hi - lo, 0.001), 0.0, 1.0)
+    return freqs / factor * ramp + freqs * (1.0 - ramp)
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclass
 class RopeTables:
@@ -61,18 +84,31 @@ class RopeTables:
 
     @classmethod
     def create(cls, spec: ModelSpec) -> "RopeTables":
-        hs = spec.head_size
+        hs = spec.rope_width
         k = np.arange(hs // 2, dtype=np.float64)
         freqs = 1.0 / (spec.rope_theta ** (2.0 * k / hs))
-        if spec.rope_type == RopeType.LLAMA3_1:
+        scale = 1.0
+        if spec.rope_type == RopeType.YARN:
+            from ..models.spec import yarn_mscale
+
+            freqs = _yarn_scale_freqs(
+                freqs, spec.rope_scaling_factor,
+                spec.rope_scaling_orig_max_seq_len, spec.yarn_beta_fast,
+                spec.yarn_beta_slow, spec.rope_theta)
+            # the tables' own factor: mscale(factor, mscale) over
+            # mscale(factor, mscale_all_dim), 1 where the two are equal
+            scale = (yarn_mscale(spec.rope_scaling_factor, spec.yarn_mscale)
+                     / yarn_mscale(spec.rope_scaling_factor,
+                                   spec.yarn_mscale_all_dim))
+        elif spec.rope_type == RopeType.LLAMA3_1:
             freqs = _llama31_scale_freqs(
                 freqs, spec.rope_scaling_factor, spec.rope_scaling_low_freq_factor,
                 spec.rope_scaling_high_freq_factor, spec.rope_scaling_orig_max_seq_len)
         t = np.arange(spec.seq_len, dtype=np.float64)
         angles = np.outer(t, freqs)  # (seq_len, hs//2)
         return cls(
-            cos=jnp.asarray(np.cos(angles), dtype=jnp.float32),
-            sin=jnp.asarray(np.sin(angles), dtype=jnp.float32),
+            cos=jnp.asarray(np.cos(angles) * scale, dtype=jnp.float32),
+            sin=jnp.asarray(np.sin(angles) * scale, dtype=jnp.float32),
             rope_type=spec.rope_type,
         )
 
@@ -87,7 +123,7 @@ def apply_rope(x: jax.Array, tables: RopeTables, positions: jax.Array) -> jax.Ar
     sin = tables.sin[positions][..., :, None, :]
     hs = x.shape[-1]
     xf = x.astype(jnp.float32)
-    if tables.rope_type in (RopeType.LLAMA, RopeType.LLAMA3_1):
+    if tables.rope_type in (RopeType.LLAMA, RopeType.LLAMA3_1, RopeType.YARN):
         xp = xf.reshape(*x.shape[:-1], hs // 2, 2)
         a, b = xp[..., 0], xp[..., 1]
         ra = a * cos - b * sin

@@ -50,6 +50,20 @@ _BLOCK_SPECS = {
     "rms_ffn": P(),
     "rms_moe": P(),
     "rms_ffn2": P(),
+    # latent attention: the low-rank projections' inner side is whole on every
+    # shard (and so is the latent row); heads shard where they first appear
+    "wq_a": P(),                      # (L, q_lora_rank, dim)
+    "wq_b": P(None, AXIS_TP),        # (L, q_dim->tp, q_lora_rank)
+    "wkv_a": P(),                     # (L, kv_lora_rank + rope, dim)
+    "w_uk": P(None, AXIS_TP),        # (L, heads->tp, nope, kv_lora_rank)
+    "w_uv": P(None, AXIS_TP),        # (L, heads->tp, v, kv_lora_rank)
+    "rms_q": P(),
+    "rms_kv": P(),
+    # the shared expert: sliced like the dense FFN, under moe_sharding
+    # "expert" too (its partial sum joins the routed one before the psum)
+    "sh_gate": P(None, AXIS_TP),
+    "sh_up": P(None, AXIS_TP),
+    "sh_down": P(None, None, AXIS_TP),
 }
 
 
@@ -74,15 +88,21 @@ def param_pspecs(params: dict[str, Any],
     moe_sharding: "slice" (hidden-dim TP inside every expert, the default) or
     "expert" (whole experts over tp — see _EP_SPECS)."""
     assert moe_sharding in ("slice", "expert"), moe_sharding
-    blocks = {k: _BLOCK_SPECS[k] for k in params["blocks"]}
-    if moe_sharding == "expert":
-        blocks.update({k: v for k, v in _EP_SPECS.items() if k in blocks})
-    return {
+    def stack(names):
+        blocks = {k: _BLOCK_SPECS[k] for k in names}
+        if moe_sharding == "expert":
+            blocks.update({k: v for k, v in _EP_SPECS.items() if k in blocks})
+        return blocks
+
+    out = {
         "embedding": P(),  # replicated, root-only-F32 in reference (transformer.cpp:496)
-        "blocks": blocks,
+        "blocks": stack(params["blocks"]),
         "rms_final": P(),
         "wcls": P(AXIS_TP),  # (vocab->tp, dim); logits all-gathered in forward
     }
+    if "lead" in params:  # a leading dense stack (ModelSpec.lead_layers)
+        out["lead"] = stack(params["lead"])
+    return out
 
 
 def kv_cache_pspec(seq_axis: str | None = None) -> P:
@@ -131,10 +151,13 @@ def check_divisibility(spec: ModelSpec, tp: int, sp: int = 1,
         f"tp={tp} must divide n_heads={spec.n_heads}")
     assert spec.dim % tp == 0
     assert spec.vocab_size % tp == 0
-    if (spec.dim // tp) % 32 or (spec.q_dim // tp) % 32:
+    if (spec.dim // tp) % 32 or (spec.o_dim // tp) % 32:
         # q_dim (n_heads x head_size) is wo's in-axis; it is dim unless the
         # model states its head size
         raise AssertionError("tp slice must keep 32-wide quant blocks intact")
+    for width in (spec.lead_hidden_dim, spec.shared_hidden_dim):
+        if width % tp or (width // tp) % 32:
+            raise AssertionError("tp slice must keep 32-wide quant blocks intact")
     if moe_sharding == "expert" and spec.is_moe:
         assert spec.n_experts % tp == 0, (
             f"expert sharding: tp={tp} must divide n_experts={spec.n_experts}")

@@ -80,9 +80,9 @@ def shard_params(params: dict[str, Any], mesh: Mesh,
     # fused matvec groups carry the TP-group count their rows were interleaved
     # with (models/params.py fuse_matvec_groups); placement on a mismatched
     # mesh/moe_sharding would silently scramble the member split — fail loudly
-    from ..models.params import _FUSE_GROUPS
+    from ..models.params import _FUSE_GROUPS, STACKS
 
-    for name, t in params["blocks"].items():
+    for name, t in [nt for st in STACKS for nt in params.get(st, {}).items()]:
         if name not in _FUSE_GROUPS or not isinstance(t, QTensor):
             continue
         expected = 1 if (name == "moe_gu" and moe_sharding == "expert") else tp
@@ -94,7 +94,8 @@ def shard_params(params: dict[str, Any], mesh: Mesh,
     if spec is not None:
         check_divisibility(spec, tp, moe_sharding=moe_sharding)
         hk_eff = effective_kv_heads(spec, tp)
-        if hk_eff != spec.n_kv_heads:
+        # a latent row is whole on every shard: nothing to repeat
+        if hk_eff != spec.n_kv_heads and not spec.latent:
             rep = hk_eff // spec.n_kv_heads
             params = dict(params, blocks=dict(params["blocks"]))
             for name in ("wk", "wv"):

@@ -153,6 +153,27 @@ _MOE_COUNTERS = tuple(metrics.counter(name, doc) for name, doc in (
     ("batch_moe_grouped_experts_touched_total",
      "Of batch_moe_experts_touched_total, those the grouped expert layer "
      "read")))
+_MOE_ROUTED = metrics.counter(
+    "batch_moe_routed_total",
+    "Expert assignments the routers made: dispatched rows x experts per "
+    "token, summed over the routed layers, whether this engine holds the "
+    "chosen expert or not (batch_moe_assignments_total counts those on held "
+    "experts: the two differ where the checkpoint holds a share of the "
+    "router's width)")
+_LATENT_ROWS_READ = metrics.counter(
+    "batch_latent_rows_read_total",
+    "Latent cache rows attention was asked to read: every dispatched row's "
+    "committed length, once a layer, all heads reading each row once (the "
+    "kernel's steps of 128 keys and its query blocks re-read some of them: "
+    "not counted)")
+_LATENT_DISPATCH_ROWS = metrics.counter(
+    "batch_latent_dispatch_rows_total",
+    "Query positions latent attention was run for: dispatched positions x "
+    "layers, parked rows and padding included")
+_KV_ROW_BYTES = metrics.gauge(
+    "kv_pool_row_bytes",
+    "Bytes the cache really holds a token a layer (all kv heads, both sides, "
+    "the lanes' padding of a latent row included)")
 _ATTN_PAIRS_VISITED = metrics.counter(
     "batch_attn_pairs_visited_total",
     "Query-key pairs of the window the attention kernel computed: for every "
@@ -596,9 +617,18 @@ class BatchEngine:
             kv_pool_cfg = (max(n_blocks, w + 2), bt)
         # a routed model's step programs and scans also return what their
         # expert layers did (the batch_moe_* counters)
+        if spec.latent and prefix_cache and (
+                prefix_cache_q80 or kv_pool_cfg is None):
+            raise ValueError(
+                "a latent cache row (kv_lora_rank > 0) is not supported by "
+                + ("the Q80 cold tier (prefix_cache_q80)" if prefix_cache_q80
+                   else "the dense host prefix cache (paged_kv off)")
+                + ": both hold per-head keys and values")
         self._eng = Engine(spec, params, tokenizer, batch=slots,
                            kv_pool=kv_pool_cfg, moe_stats=spec.is_moe,
                            **engine_kw)
+        _KV_ROW_BYTES.set(spec.cache_row_bytes(
+            self._eng.k_cache.dtype.itemsize))
         # attention's per-layer lower key bound, as (window, share of layers)
         wins = spec.layer_window()
         self._layer_windows = [(w, wins.count(w) / len(wins))
@@ -785,7 +815,8 @@ class BatchEngine:
                 cold = prefix_cache_blocks or default_pool_blocks(
                     (spec.n_layers, slots, hk, spec.seq_len,
                      spec.head_size),
-                    self._eng.k_cache.dtype.itemsize, self._kv_bt, slots)
+                    self._eng.k_cache.dtype.itemsize, self._kv_bt, slots,
+                    token_values=sum(spec.cache_widths))
                 self.prefix_cache = PagedPrefixCache(
                     self.kv_pool, self._kv_bt, cold_blocks=cold,
                     q80=prefix_cache_q80)
@@ -1861,6 +1892,18 @@ class BatchEngine:
         else:  # the gather path and the dense cache read the whole window
             visited = dispatched * window
         _ATTN_PAIRS_VISITED.inc(visited)
+        spec = getattr(self, "spec", None)
+        if spec is not None and spec.is_moe:
+            _MOE_ROUTED.inc(dispatched * spec.n_active_experts
+                            * spec.block_layers)
+        if spec is not None and spec.latent:
+            if budget is None:  # once a dispatched row, whatever its T
+                read = sum(starts)
+            else:
+                read = sum(st + min(i, b) for st, b in zip(starts, budget)
+                           for i in range(positions))
+            _LATENT_ROWS_READ.inc(read * spec.n_layers)
+            _LATENT_DISPATCH_ROWS.inc(dispatched * spec.n_layers)
         _POSITIONS_REAL.inc(sum(n for _, n in real))
         _ATTN_PAIRS_REAL.inc(sum(n * p + n * (n + 1) // 2 for p, n in real))
 

@@ -24,7 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.params import Params, decode_stream_bytes, prepare_for_pallas
+from ..models.params import (STACKS, Params, decode_stream_bytes, hold_dense,
+                             prepare_for_pallas)
 from ..models.spec import ModelSpec
 from ..obs import flight, metrics, trace
 from ..resilience import faults
@@ -175,6 +176,19 @@ class Engine:
         assert kv_cache_storage in (None, "ram", "host", "disc"), kv_cache_storage
         self.paged = (kv_cache_storage in ("host", "disc")
                       and spec.seq_len > self.kv_resident)
+        # cache kinds that hold per-head keys and values, or one stack of
+        # layers, say so: no silent wrong path
+        if spec.latent or spec.lead_layers:
+            what = ("a latent cache row (kv_lora_rank > 0)" if spec.latent
+                    else "a leading dense stack (lead_layers > 0)")
+            if self.paged:
+                raise ValueError(
+                    f"kv-cache-storage={kv_cache_storage}: the host-spill "
+                    f"ring does not support {what}")
+            if sp > 1:
+                raise ValueError(
+                    f"sp={sp}: the sequence-sharded (ring attention) cache "
+                    f"does not support {what}")
         if self.paged and tp is None:
             tp = 1  # paged mode is single-chip; don't let the mesh grab every device
         # Device-resident paged KV (docs/PAGED_KV.md): kv_pool=(n_blocks,
@@ -219,9 +233,10 @@ class Engine:
         # reference's scheme); "expert" shards WHOLE experts over tp — the capacity
         # axis for Grok-1-314B-class expert weights (parallel/sharding.py)
         self.moe_sharding = moe_sharding if spec.is_moe else "slice" 
+        params = hold_dense(params, self.dtype)
         has_quant = any(
             getattr(t, "ftype", None) in (FloatType.Q40, FloatType.Q80)
-            for t in params["blocks"].values())
+            for st in STACKS for t in params.get(st, {}).values())
         self.use_pallas = use_pallas and has_quant
         if use_pallas and not has_quant:
             # the start-up line named the policy before any checkpoint was
@@ -368,11 +383,12 @@ class Engine:
 
             n_blocks, bt = self.kv_pool
             hk = effective_kv_heads(self.spec, self.tp)
-            shape = (self.spec.n_layers, n_blocks, hk, bt,
-                     self.spec.head_size)
             sh = NamedSharding(self.mesh, P(None, None, _TP))
-            return (jax.device_put(jnp.zeros(shape, self.dtype), sh),
-                    jax.device_put(jnp.zeros(shape, self.dtype), sh))
+            # a latent spec: one row a token, the second side empty
+            return tuple(
+                jax.device_put(jnp.zeros(
+                    (self.spec.n_layers, n_blocks, hk, bt, w), self.dtype), sh)
+                for w in self.spec.cache_widths)
         from ..parallel.tp import init_sharded_kv_cache
 
         return init_sharded_kv_cache(self.spec, self.mesh, batch=self.batch,

@@ -30,7 +30,8 @@ from jax.sharding import SingleDeviceSharding
 from distributed_llama_tpu.ops.moe_grouped import capacity, row_tile
 from distributed_llama_tpu.ops.pallas_attention import fused_decode_attention
 from distributed_llama_tpu.ops.pallas_moe_grouped import _moe_grouped_q4
-from distributed_llama_tpu.ops.pallas_paged_attention import paged_attention
+from distributed_llama_tpu.ops.pallas_paged_attention import (
+    latent_paged_attention, paged_attention)
 from distributed_llama_tpu.ops.pallas_q4 import _q4_matvec, _q4_matvec_inline
 from distributed_llama_tpu.ops.pallas_q4_mm import q4_matmul, q4_mm_supported
 from distributed_llama_tpu.quants import FloatType, QTensor
@@ -124,6 +125,17 @@ def paged(b, t, hq, hk, n_read, q=F32, window=False):
              ((b,), I32), ((), I32)], static)
 
 
+def latent(b, t, n_read):
+    """The latent attention kernel at A.X-K1's widths: 64 heads against one
+    row of 640 values a token (512 + 64, whole lanes), the first 512 of
+    which are the values; the cell's pool of 7 layers x 2048 blocks of 16."""
+    w = 640
+    return (latent_paged_attention,
+            [((b, t, 64, w), BF16), ((7, 2048, 1, 16, w), BF16),
+             ((b, t, w), BF16), ((b, 512), I32), ((b,), I32), ((), I32)],
+            {"n_read": n_read, "n_values": 512, "scale": 0.13})
+
+
 def grouped(rows, k, experts, hidden, dim, merged=True, act="relu", layers=1):
     """The grouped expert layer's two kernels for `rows` x `k` assignments
     over `experts` experts of width `hidden`, at the tile and capacity the
@@ -138,6 +150,21 @@ def grouped(rows, k, experts, hidden, dim, merged=True, act="relu", layers=1):
     return (_moe_grouped_q4,
             [((cap, dim), BF16), ((cap // tile,), I32), ((), I32), ((3,), I32),
              *up, *up, *down], {"tile": tile, "act": act, "merged": merged})
+
+
+def grouped_share(rows, k, held, width, hidden, dim, layers=1):
+    """The same two kernels where the stack holds `held` of the `width`
+    experts the router scores: the row tile follows the mean run over the
+    router's width, the capacity the worst case over the held."""
+    tile = row_tile(rows * k, width)
+    cap = capacity(rows * k, held, tile)
+    lead = (layers, held)
+    up = [((*lead, 2 * hidden, dim // 2), U8),
+          ((*lead, 2 * hidden, dim // 32), I16)]
+    down = [((*lead, dim, hidden // 2), U8), ((*lead, dim, hidden // 32), I16)]
+    return (_moe_grouped_q4,
+            [((cap, dim), BF16), ((cap // tile,), I32), ((), I32), ((3,), I32),
+             *up, *up, *down], {"tile": tile, "act": "silu", "merged": True})
 
 
 def decode_attention(hk, window):
@@ -199,6 +226,19 @@ CASES = {
     "paged-window-g7-b8-t1-w1024": paged(8, 1, 28, 4, 64, BF16, True),
     "paged-window-g7-b8-t64-w8192": paged(8, 64, 28, 4, 512, BF16, True),
     "paged-window-b8-t8-w512": paged(8, 8, 32, 8, 32, BF16, True),
+    # latent attention: the decode step, an 8-token and a 64-token chunk
+    # (eight query blocks of 512 rows) at the 1024 bucket, and a long row's
+    # 8192
+    "latent-b8-t1-w1024": latent(8, 1, 64),
+    "latent-b8-t8-w1024": latent(8, 8, 64),
+    "latent-b8-t64-w1024": latent(8, 64, 64),
+    "latent-b8-t1-w8192": latent(8, 1, 512),
+    "latent-b8-t64-w8192": latent(8, 64, 512),
+    # A.X-K1's share of the experts: 48 held of 2048 x 7168, the tile from
+    # the router's width of 192 (a decode step and a 64-token chunk)
+    "grouped-l6-e48-t1": grouped_share(8, 8, 48, 192, 2048, 7168, layers=6),
+    "grouped-l6-e48-t64": grouped_share(512, 8, 48, 192, 2048, 7168,
+                                        layers=6),
     # the grouped expert layer: 64 ReGLU experts of 768 at 8 slots (decode
     # step, 8-token and 64-token chunks), Mixtral's 8 experts of 14336
     # (merged [up|gate] stack, SiLU), and the tp=4 hidden slice of those
