@@ -651,6 +651,51 @@ def _block(x, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions,
     return x, (k_t, v_t, stats)
 
 
+def commit_block_rows(pool, rows, block_tables, start_pos):
+    """The paged commit of one cache: position p of row b lands in pool block
+    tables[b, p // bt] at offset p % bt.
+
+    pool (L, N, hk, bt, w); rows (L, B, hk, T, w), row b's at positions
+    start_pos[b] .. start_pos[b] + T - 1; block_tables (B, W).
+
+    A loop over the (row, block) pairs the dispatch touches (T positions lie
+    in at most (T + bt - 2) // bt + 1 blocks): each reads the block's
+    (L, 1, hk, bt, w) out of the pool, takes the new rows at the offsets
+    this row writes and the block's own elsewhere, and writes it back.
+    Whole (bt, w) tiles move in and out through dynamic slices of the
+    leading axes alone, so XLA updates the donated pool in place in the
+    layout it arrives in, the one the attention kernels read: a scatter
+    through axes 1 and 3 made it re-lay the WHOLE pool before and after
+    (two copies a cache a dispatch, PERF.md section 6, PR 37). Rows that
+    collide on one (block, offset), as idle and parked rows do on the
+    scratch block, leave the later row's values there: no index is promised
+    unique."""
+    l, _, hk, bt, w = pool.shape
+    b, t = rows.shape[1], rows.shape[3]
+    if pool.size == 0:  # a latent spec's empty second side
+        return pool
+    n_blocks = (t + bt - 2) // bt + 1
+    # the rows between bt of padding in front and enough behind that every
+    # touched block's bt positions are one window of them
+    padded = jnp.pad(rows.astype(pool.dtype),
+                     ((0, 0), (0, 0), (0, 0), (bt, n_blocks * bt - t), (0, 0)))
+    offsets = jnp.arange(bt, dtype=jnp.int32)
+
+    def pair(i, pool):
+        r, j = i // n_blocks, i % n_blocks
+        first = start_pos[r] // bt + j  # the block's index in the row's table
+        t0 = first * bt - start_pos[r]  # the block's first position, in rows
+        blk = block_tables[r, jnp.minimum(first, block_tables.shape[1] - 1)]
+        new = jax.lax.dynamic_slice(padded, (0, r, 0, t0 + bt, 0),
+                                    (l, 1, hk, bt, w))
+        old = jax.lax.dynamic_slice(pool, (0, blk, 0, 0, 0), (l, 1, hk, bt, w))
+        mine = (t0 + offsets >= 0) & (t0 + offsets < t)
+        return jax.lax.dynamic_update_slice(
+            pool, jnp.where(mine[:, None], new, old), (0, blk, 0, 0, 0))
+
+    return jax.lax.fori_loop(0, b * n_blocks, pair, pool)
+
+
 def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
             tokens: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             start_pos: jax.Array, *, dtype=jnp.float32, axis_name: str | None = None,
@@ -685,8 +730,8 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
     The caches are loop-INVARIANT operands of the layer scan (read-only, see
     _attention); each layer's new K/V rows leave as ys ((L, B, hk, T, hs),
     tiny) and ONE write per cache commits them after the scan, by cache kind:
-    a scatter through the block tables, ring slots mod R (host/disc paging),
-    a masked window write into the owning sp shard
+    whole blocks through the block tables (commit_block_rows), ring slots
+    mod R (host/disc paging), a masked window write into the owning sp shard
     (commit_kv_rows_sharded), else a dynamic_update_slice at start_pos.
 
     attn_window: static bound on cache positions attention reads (must cover
@@ -766,20 +811,13 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
     # commit all layers' new rows in one write per cache: (L, B, hk, T, hs)
     # lands at [.., .., .., start_pos : start_pos+T, ..]
     if block_tables is not None:
-        # paged commit: position p of row b lands in pool block
-        # tables[b, p // bt] at offset p % bt — one scatter per cache,
-        # through the same table the read path consumed. Out-of-range
-        # positions cannot occur by scheduler invariant (coverage is
-        # ensured pre-dispatch; parked rows clamp below seq_len).
-        pos_bt = positions  # (B, T) absolute positions
-        blk = jnp.take_along_axis(
-            block_tables, jnp.minimum(pos_bt // block_tokens,
-                                      block_tables.shape[1] - 1), axis=1)
-        off = pos_bt % block_tokens  # (B, T)
-        k_cache = k_cache.at[:, blk, :, off, :].set(
-            jnp.transpose(k_rows, (1, 3, 0, 2, 4)))
-        v_cache = v_cache.at[:, blk, :, off, :].set(
-            jnp.transpose(v_rows, (1, 3, 0, 2, 4)))
+        # paged commit, through the same table the read path consumed.
+        # Out-of-range positions cannot occur by scheduler invariant
+        # (coverage is ensured pre-dispatch; parked rows clamp below
+        # seq_len).
+        assert block_tokens == k_cache.shape[3], (block_tokens, k_cache.shape)
+        k_cache = commit_block_rows(k_cache, k_rows, block_tables, start_pos)
+        v_cache = commit_block_rows(v_cache, v_rows, block_tables, start_pos)
     elif paged_cold is not None:
         # ring commit: position p lands in slot p mod R (scatter — the
         # chunk may wrap the ring boundary). The rows being overwritten

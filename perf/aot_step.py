@@ -1,16 +1,24 @@
 """What the chip's compiler makes of a whole step program, without the chip.
 
-    JAX_PLATFORMS=cpu python3 perf/aot_step.py <configuration> [--rows 8] [--chunk 64]
+    JAX_PLATFORMS=cpu python3 perf/aot_step.py <configuration> [--rows 8] [--chunk 64] [--scan K]
 
-Compiles `models/forward.forward` (kernels on, paged KV, bf16) for a described
-`v5e:2x2` chip at a benchmark configuration's widths and full depth, with a
-small vocabulary: a 4-layer model is drawn on the CPU for the parameter tree
-and its block leaves are re-shaped to the full depth as `ShapeDtypeStruct`s,
-so nothing of the model's size is ever held. Prints the program's temporaries
-and every `copy`, `dynamic-slice` or fusion that RESULTS in packed weights
-(`u8[...]`) or their scales (`s16[...]`): the copies XLA puts in front of a
-kernel show only here (the kernels alone compile in tests/test_tpu_compile.py).
-Nothing runs: no time comes out of this (PERF.md section 6, PR 33).
+Compiles `models/forward.forward` (kernels on, paged KV, bf16, the pools
+DONATED as the engines donate them: undonated, XLA copies each pool once
+more to keep the argument) for a described `v5e:2x2` chip at a benchmark
+configuration's widths and full depth, with a small vocabulary: a few layers
+are drawn on the CPU for the parameter tree (four, or one of each stack of a
+family that has `stacks`) and the leaves of each stack are re-shaped to its
+full depth as `ShapeDtypeStruct`s, so nothing of the model's size is ever
+held. `--scan K` compiles K decode steps as one `lax.scan` with the pools in
+its carry, the form of `runtime/device_loop.make_batched_decode_loop`.
+
+Prints the program's temporaries and every `copy`, `dynamic-slice` or fusion
+that RESULTS in packed weights (`u8[...]`), their scales (`s16[...]`) or an
+array of the KV pool's shape (`bf16[L,N,hk,bt,hs]`, with its layout): the
+copies XLA puts around a kernel or a scatter show only here (the kernels
+alone compile in tests/test_tpu_compile.py, which also holds the step
+programs to `pool_relayouts` being empty). Nothing runs: no time comes out
+of this (PERF.md section 6, PRs 33 and 37).
 """
 
 from __future__ import annotations
@@ -26,17 +34,149 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
-from jax.experimental import topologies  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from benchmark import cells  # noqa: E402
 from benchmark import weights as W  # noqa: E402
 from distributed_llama_tpu.models.forward import forward  # noqa: E402
-from distributed_llama_tpu.models.params import prepare_for_pallas  # noqa: E402
+from distributed_llama_tpu.models.params import (  # noqa: E402
+    STACKS, hold_dense, prepare_for_pallas)
 from distributed_llama_tpu.ops.rope import RopeTables  # noqa: E402
 
 CUT = 4  # layers drawn: one period of any per-layer pattern in the cells
-_MOVES = re.compile(r"\s*(?:ROOT )?%?([\w.\-]+) = ((?:u8|s16)\[[\d,]+\])")
+POOL_BLOCKS = 256
+_RESULT = re.compile(
+    r"\s*(?:ROOT )?%?([\w.\-]+) = ((?:u8|s16|bf16)\[[\d,]+\])(\{[^}]*\})?")
+
+
+def describe_chip():
+    """One chip of a described `v5e:2x2`, as a sharding for every shape.
+    Call it from `main` or a test's fixture, never at import: one process
+    at a time may load the TPU's library."""
+    from jax.experimental import topologies
+
+    return SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0])
+
+
+def _sds(a, chip, shape=None):
+    return jax.ShapeDtypeStruct(shape or a.shape, a.dtype, sharding=chip)
+
+
+def model_shapes(config: str, chip, **keys):
+    """(spec, parameter shapes, configuration) of `config` with vocabulary
+    512 and `keys` over the file's: the parameter tree of a drawn cut, each
+    stack's leaves given the depth the spec states."""
+    full = dict(cells.load_config(config), vocab_size=512, **keys)
+    family = cells.load_family(full["family"])
+    if hasattr(family, "stacks"):
+        # one layer of each stack: both hold the same tensors at any depth
+        drawn = [(p, min(d, 1)) for p, d in family.stacks(full)]
+        n = sum(d for _, d in drawn)
+        cut = {**full, "first_k_dense_replace": drawn[0][1],
+               "num_hidden_layers": n, "layers_here": n}
+    else:
+        n = min(CUT, full["num_hidden_layers"])
+        cut = {**full, "num_hidden_layers": n,
+               **{k: full[k][:n] for k in ("rope_layout",
+                                           "sliding_window_layout")
+                  if k in full}}
+    # as runtime/engine.py prepares a loader's parameters
+    params = prepare_for_pallas(
+        hold_dense(W.to_program_params(W.make_weights(cut, 7), cut),
+                   jnp.bfloat16), spec=family.model_spec(cut))
+    spec = family.model_spec(full)
+    depths = {"lead": spec.lead_layers, "blocks": spec.block_layers}
+    shapes = jax.tree.map(lambda a: _sds(a, chip), params)
+    for st in STACKS:
+        if st in params:
+            shapes[st] = jax.tree.map(
+                lambda a, n=depths[st]: _sds(a, chip, (n, *a.shape[1:])),
+                params[st])
+    return spec, shapes, full
+
+
+def pool_shape(spec, cfg, blocks: int = POOL_BLOCKS):
+    """The two sides of the block pool (the second is empty for a latent
+    spec), as `runtime/engine.py` builds them."""
+    return [(spec.n_layers, blocks, spec.n_kv_heads,
+             cfg["engine"]["kv_block_tokens"], w) for w in spec.cache_widths]
+
+
+def held_pools(spec, cfg, blocks: int = POOL_BLOCKS):
+    """The distinct shapes among the pool's sides that hold anything."""
+    return list(dict.fromkeys(s for s in pool_shape(spec, cfg, blocks)
+                              if s[-1]))
+
+
+def _bf16(shape) -> str:
+    return "bf16[" + ",".join(str(d) for d in shape) + "]"
+
+
+def compile_step(spec, shapes, cfg, chip, *, rows: int = 8, chunk: int = 64,
+                 scan: int = 0, blocks: int = POOL_BLOCKS):
+    """The compiled step program: `jit_step` at `rows` x `chunk`, or with
+    `scan` K > 0 a K-step greedy decode scan with the pools in its carry."""
+    bt = cfg["engine"]["kv_block_tokens"]
+    kc, vc = (jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=chip)
+              for s in pool_shape(spec, cfg, blocks))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    def fwd(p, rope, toks, kc, vc, start, tables):
+        return forward(p, spec, rope, toks, kc, vc, start, use_pallas=True,
+                       dtype=jnp.bfloat16, block_tables=tables,
+                       block_tokens=bt, paged_kernel=True, attn_window=1024,
+                       moe_stats=True)
+
+    def loop(p, rope, tok, kc, vc, start, tables):
+        def step(carry, _):
+            tok, pos, kc, vc = carry
+            logits, kc, vc, _ = fwd(p, rope, tok[:, None], kc, vc, pos, tables)
+            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            return (nxt, pos + 1, kc, vc), nxt
+
+        (tok, pos, kc, vc), out = jax.lax.scan(
+            step, (tok, start, kc, vc), None, length=scan)
+        return out, tok, pos, kc, vc
+
+    toks = i32(rows) if scan else i32(rows, chunk)
+    return jax.jit(loop if scan else fwd, donate_argnums=(3, 4)).lower(
+        shapes, jax.tree.map(lambda a: _sds(a, chip), RopeTables.create(spec)),
+        toks, kc, vc, i32(rows), i32(rows, 64)).compile()
+
+
+def results(text: str):
+    """(instruction, shape, layout, line) of every instruction of a compiled
+    program's text that results in a `u8`, `s16` or `bf16` array."""
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if m:
+            yield m.group(1), m.group(2), m.group(3) or "", line
+
+
+def _entry(text: str) -> str:
+    """The text of the program's ENTRY computation, whose parameters are the
+    program's own (a fusion's body has parameters too)."""
+    lines = text.splitlines()
+    start = next(i for i, ln in enumerate(lines) if ln.startswith("ENTRY "))
+    end = next(i for i in range(start, len(lines)) if lines[i] == "}")
+    return "\n".join(lines[start:end])
+
+
+def pool_relayouts(text: str, shape) -> list[str]:
+    """The instructions that result in an array of the pool's shape in
+    ANOTHER layout than the program's parameter of that shape has, and every
+    `copy` of one: a program that updates the donated pool in place has
+    none."""
+    want = _bf16(shape)
+    rows = [r for r in results(text) if r[1] == want]
+    own = {layout for _, shape, layout, line in results(_entry(text))
+           if shape == want and " parameter(" in line}
+    assert len(own) == 1, f"the pool's parameters lie in {own}"
+    return [f"{name} -> {want}{layout}" for name, _, layout, line in rows
+            if layout not in own or re.search(r"\bcopy\(", line)]
 
 
 def main():
@@ -44,57 +184,38 @@ def main():
     ap.add_argument("config")
     ap.add_argument("--rows", type=int, default=8)
     ap.add_argument("--chunk", type=int, default=64)
+    ap.add_argument("--scan", type=int, default=0, metavar="K",
+                    help="K decode steps as one scan, the pools in its carry")
     args = ap.parse_args()
-    full = dict(cells.load_config(args.config), vocab_size=512)
-    cut = {**full, "num_hidden_layers": CUT,
-           **{k: full[k][:CUT] for k in ("rope_layout", "sliding_window_layout")
-              if k in full}}
-    family = cells.load_family(full["family"])
-    params = prepare_for_pallas(W.to_program_params(W.make_weights(cut, 7)),
-                                spec=family.model_spec(cut))
-    spec = family.model_spec(full)
-    chip = SingleDeviceSharding(topologies.get_topology_desc(
-        platform="tpu", topology_name="v5e:2x2").devices[0])
-
-    def sds(a, shape=None):
-        return jax.ShapeDtypeStruct(shape or a.shape, a.dtype, sharding=chip)
-
-    shapes = {**jax.tree.map(sds, params), "blocks": jax.tree.map(
-        lambda a: sds(a, (spec.n_layers, *a.shape[1:])), params["blocks"])}
-    b, bt = args.rows, full["engine"]["kv_block_tokens"]
-    pool = jax.ShapeDtypeStruct(
-        (spec.n_layers, 256, spec.n_kv_heads, bt, spec.head_size),
-        jnp.bfloat16, sharding=chip)
-
-    def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
-
-    def step(p, rope, toks, kc, vc, start, tables):
-        return forward(p, spec, rope, toks, kc, vc, start, use_pallas=True,
-                       dtype=jnp.bfloat16, block_tables=tables,
-                       block_tokens=bt, paged_kernel=True, attn_window=1024,
-                       moe_stats=True)
-
+    chip = describe_chip()
+    spec, shapes, cfg = model_shapes(args.config, chip)
     t0 = time.time()
-    compiled = jax.jit(step).lower(
-        shapes, jax.tree.map(sds, RopeTables.create(spec)),
-        i32(b, args.chunk), pool, pool, i32(b), i32(b, 64)).compile()
+    compiled = compile_step(spec, shapes, cfg, chip, rows=args.rows,
+                            chunk=args.chunk, scan=args.scan)
     text, mem = compiled.as_text(), compiled.memory_analysis()
-    print(f"{args.config}: {spec.n_layers} layers, {b} x {args.chunk} rows, "
+    what = (f"a scan of {args.scan} steps" if args.scan
+            else f"{args.rows} x {args.chunk} rows")
+    print(f"{args.config}: {spec.n_layers} layers, {what}, "
           f"compiled in {time.time() - t0:.1f} s; temporaries "
           f"{mem.temp_size_in_bytes / 1e9:.3f} GB, arguments "
           f"{mem.argument_size_in_bytes / 1e9:.3f} GB, "
           f"{text.count('tpu_custom_call')} kernels")
+    pools = {_bf16(side) for side in held_pools(spec, cfg)}
     seen: dict[tuple[str, str], int] = {}
-    for line in text.splitlines():
-        m = _MOVES.match(line)
-        if not m or "custom-call" in line or "parameter(" in line:
+    for name, shape, layout, line in results(text):
+        pool = shape in pools
+        if (shape.startswith("bf16") and not pool) or "custom-call" in line \
+                or " parameter(" in line:
             continue
-        name = re.sub(r"\.\d+$", "", m.group(1))
+        name = re.sub(r"\.\d+$", "", name)
         if "dynamic-slice" in line or name.startswith("copy") or "fusion" in name:
-            seen[name, m.group(2)] = seen.get((name, m.group(2)), 0) + 1
+            key = name, shape + (layout if pool else "")
+            seen[key] = seen.get(key, 0) + 1
     for (name, shape), n in sorted(seen.items()):
         print(f"  {name} -> {shape} x {n}")
+    for side in held_pools(spec, cfg):
+        print(f"  pool {side}: re-laid or copied by "
+              f"{pool_relayouts(text, side) or 'nothing'}")
 
 
 if __name__ == "__main__":

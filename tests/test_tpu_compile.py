@@ -15,10 +15,17 @@ an 8-token and a 64-token chunk at 8 slots. Its gate declines none of them.
 
 The case marked "repaired" was refused before this file existed: the
 inline-Xexp matvec at K=14336 for 1.9 MiB too much VMEM.
+
+Whether XLA puts a copy AROUND a kernel or a scatter shows only in a whole
+step program: `STEP_POOLS` x `STEP_PROGRAMS` compile `forward()` as
+`perf/aot_step.py` does (four layers drawn at the cells' widths and re-shaped
+to their depth, 256 blocks of the pool, the pools donated)
+and hold the compiled text to updating the KV pool in place.
 """
 
 import functools
 import os
+import sys
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
 
@@ -35,6 +42,10 @@ from distributed_llama_tpu.ops.pallas_paged_attention import (
 from distributed_llama_tpu.ops.pallas_q4 import _q4_matvec, _q4_matvec_inline
 from distributed_llama_tpu.ops.pallas_q4_mm import q4_matmul, q4_mm_supported
 from distributed_llama_tpu.quants import FloatType, QTensor
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "perf"))
+
+import aot_step  # noqa: E402
 
 DIM, HIDDEN, VOCAB, HS, LAYERS = 4096, 14336, 128256, 128, 32
 BF16, F32, I8, U8, I16, I32 = (jnp.bfloat16, jnp.float32, jnp.int8, jnp.uint8,
@@ -285,3 +296,43 @@ def test_kernel_compiles_for_v5e(chip, case):
                   if v is TRACED_I32 else v) for k, v in static.items()}
     compiled = fn.lower(*args, interpret=False, **static).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the three kinds of block pool the cells hold, each at its configuration's
+# widths and depth (fewer experts than the files hold: an expert's width is
+# kept, and the commit never sees their count)
+STEP_POOLS = {
+    "hk8": ("mistral-7b", {}),
+    "hk4": ("smallthinker-21b-a3b", {"moe_num_primary_experts": 8}),
+    "latent": ("ax-k1-ep4-l7", {"n_routed_experts": 8}),
+}
+# `jit_step` at T = 1 and at a 64-token chunk, and a 2-step decode scan with
+# the pools in its carry (`make_batched_decode_loop`'s form)
+STEP_PROGRAMS = {"t1": {"chunk": 1}, "t64": {"chunk": 64}, "scan2": {"scan": 2}}
+
+
+@pytest.fixture(scope="module")
+def step_model(chip):
+    """(spec, parameter shapes, configuration) of a `STEP_POOLS` entry,
+    drawn once a module."""
+    return functools.cache(lambda pool: aot_step.model_shapes(
+        STEP_POOLS[pool][0], chip, **STEP_POOLS[pool][1]))
+
+
+@pytest.mark.parametrize("program", list(STEP_PROGRAMS))
+@pytest.mark.parametrize("pool", list(STEP_POOLS))
+def test_step_program_updates_the_pool_in_place(chip, step_model, pool,
+                                                program, monkeypatch):
+    """No instruction of the compiled step program results in an array of
+    the pool's shape in another layout than the donated parameter's, and
+    none copies one: the commit writes the new rows where the pool lies
+    (until PR 37 a scatter made XLA re-lay the whole K and V pool before
+    and after it, four copies a dispatch and two more a scan step)."""
+    spec, shapes, cfg = step_model(pool)
+    # conftest.py asks for interpret mode; the chip's programs hold kernels
+    monkeypatch.delenv("DLT_PALLAS_INTERPRET")
+    text = aot_step.compile_step(spec, shapes, cfg, chip,
+                                 **STEP_PROGRAMS[program]).as_text()
+    assert "tpu_custom_call" in text
+    for side in aot_step.held_pools(spec, cfg):
+        assert aot_step.pool_relayouts(text, side) == []
