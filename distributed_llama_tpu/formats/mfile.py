@@ -29,8 +29,8 @@ from typing import BinaryIO
 import numpy as np
 
 from ..models.params import Params, block_tensor_shapes
-from ..models.spec import (ArchType, HeaderKey, HiddenAct, ModelSpec, RopeType,
-                           RouterInput, RouterScore)
+from ..models.spec import (ArchType, HeaderKey, HiddenAct, LayerKind, ModelSpec,
+                           RopeType, RouterInput, RouterScore)
 from ..quants import (
     FloatType,
     QTensor,
@@ -75,7 +75,58 @@ _OWN_KEYS = (
     ("yarn_beta_slow", HeaderKey.YARN_BETA_SLOW_E6, _e6),
     ("yarn_mscale", HeaderKey.YARN_MSCALE_E6, _e6),
     ("yarn_mscale_all_dim", HeaderKey.YARN_MSCALE_ALL_DIM_E6, _e6),
+    ("rotary_dim", HeaderKey.ROTARY_DIM, int),
+    ("rope_table_scale", HeaderKey.ROPE_TABLE_SCALE_E6, _e6),
+    ("attn_gate", HeaderKey.ATTN_GATE, bool),
 )
+
+# kinds of attention layer (ModelSpec.kinds): kind k's field at header key
+# KIND_0 + _KIND_STRIDE x k + its index here; a kind's name is not stored
+# (a header holds integers): a loaded kind is named "kind<k>"
+_KIND_FIELDS = (
+    ("n_heads", int), ("sliding_window", int), ("rope_type", RopeType),
+    ("rope_theta", int), ("rotary_dim", int), ("rope_scaling_factor", _e6),
+    ("rope_scaling_orig_max_seq_len", int), ("yarn_beta_fast", _e6),
+    ("yarn_beta_slow", _e6), ("rope_table_scale", _e6),
+)
+_KIND_STRIDE = 16
+_MAX_KINDS = 4  # two bits a layer
+_KIND_LAYERS_A_WORD = 15
+
+
+def _pack_layer_kinds(spec: ModelSpec) -> list[tuple[int, int]]:
+    """The kinds and each layer's as (key, value) pairs; [] without kinds."""
+    if not spec.kinds:
+        return []
+    assert len(spec.kinds) <= _MAX_KINDS, len(spec.kinds)
+    assert spec.n_layers <= _KIND_LAYERS_A_WORD * 16, spec.n_layers
+    kv = [(int(HeaderKey.N_KINDS), len(spec.kinds))]
+    for w in range(0, spec.n_layers, _KIND_LAYERS_A_WORD):
+        word = sum(k << (2 * i) for i, k in enumerate(
+            spec.layer_kinds[w:w + _KIND_LAYERS_A_WORD]))
+        kv.append((HeaderKey.LAYER_KINDS_0 + w // _KIND_LAYERS_A_WORD, word))
+    for k, kind in enumerate(spec.kinds):
+        for i, (name, conv) in enumerate(_KIND_FIELDS):
+            value = getattr(kind, name)
+            kv.append((HeaderKey.KIND_0 + _KIND_STRIDE * k + i,
+                       round(value * 1e6) if conv is _e6 else int(value)))
+    return kv
+
+
+def _unpack_layer_kinds(kv: dict[int, int], n_layers: int) -> dict:
+    """ModelSpec's `kinds` and `layer_kinds` from a header; {} without."""
+    n = kv.get(HeaderKey.N_KINDS, 0)
+    if not n:
+        return {}
+    kinds = tuple(
+        LayerKind(name=f"kind{k}", **{
+            name: conv(kv[HeaderKey.KIND_0 + _KIND_STRIDE * k + i])
+            for i, (name, conv) in enumerate(_KIND_FIELDS)})
+        for k in range(n))
+    layer_kinds = tuple(
+        (kv[HeaderKey.LAYER_KINDS_0 + l // _KIND_LAYERS_A_WORD]
+         >> (2 * (l % _KIND_LAYERS_A_WORD))) & 3 for l in range(n_layers))
+    return {"kinds": kinds, "layer_kinds": layer_kinds}
 
 
 def _pack_kinds(first_key: int, kinds: tuple[int, ...]) -> list[tuple[int, int]]:
@@ -157,6 +208,7 @@ def read_spec(path: str, max_seq_len: int = 0,
         **({"norm_eps": kv[HeaderKey.NORM_EPS_E9] / 1e9}
            if HeaderKey.NORM_EPS_E9 in kv else {}),
         **{name: conv(kv[key]) for name, key, conv in _OWN_KEYS if key in kv},
+        **_unpack_layer_kinds(kv, fields.get("n_layers", 0)),
         **fields,
     ).resolved(max_seq_len)
     return spec, weights_ftype, header_size
@@ -165,11 +217,13 @@ def read_spec(path: str, max_seq_len: int = 0,
 _EXPERT_STACKS = ("moe_up", "moe_gate", "moe_down")
 
 
-def _stacks_of(spec: ModelSpec) -> list[tuple[bool, int]]:
-    """(is it the leading dense stack?, layers) of the file's stacks in
-    layer order: the leading layers (ModelSpec.lead_layers) come first."""
-    out = [(True, spec.lead_layers)] if spec.lead_layers else []
-    return out + [(False, spec.block_layers)]
+def _stacks_of(spec: ModelSpec) -> list[tuple[str, ModelSpec, bool, int]]:
+    """(name in `params`, the spec its layers' tensors take their shapes
+    from, is it a leading dense stack?, layers) of the file's stacks in
+    layer order (ModelSpec.runs): the leading layers come first, and a model
+    with kinds of layer has a stack a run of like layers."""
+    return [(run.name, spec.of_kind(run.kind), run.lead, run.depth)
+            for run in spec.runs()]
 
 
 def _layer_order(spec: ModelSpec, lead: bool):
@@ -191,8 +245,8 @@ def model_tensor_bytes(spec: ModelSpec, wft: FloatType) -> int:
     """Total tensor bytes after the header (mirrors the reference's missedBytes check,
     transformer.cpp:531-535)."""
     total = batch_bytes(FloatType.F32, spec.dim, spec.vocab_size)  # embedding
-    for lead, depth in _stacks_of(spec):
-        for name, (shape, quantized) in block_tensor_shapes(spec, lead).items():
+    for _, ks, lead, depth in _stacks_of(spec):
+        for name, (shape, quantized) in block_tensor_shapes(ks, lead).items():
             ft = wft if quantized else FloatType.F32
             d = int(np.prod(shape[:-1], initial=1))
             total += depth * batch_bytes(ft, shape[-1], d)
@@ -250,12 +304,12 @@ def load_model(path: str, max_seq_len: int = 0,
     embedding = take((spec.vocab_size, spec.dim), FloatType.F32)
 
     stacks: dict[str, Params] = {}
-    for lead, depth in _stacks_of(spec):
-        shapes = block_tensor_shapes(spec, lead)
+    for stack_name, ks, lead, depth in _stacks_of(spec):
+        shapes = block_tensor_shapes(ks, lead)
         per_layer: dict[str, list[QTensor]] = {name: [] for name in shapes}
         for _ in range(depth):
             experts: dict[str, list[QTensor]] = {}
-            for name, e, shape, quantized in _layer_order(spec, lead):
+            for name, e, shape, quantized in _layer_order(ks, lead):
                 t = take(shape, wft if quantized else FloatType.F32)
                 if e is None:
                     per_layer[name].append(t)
@@ -268,7 +322,7 @@ def load_model(path: str, max_seq_len: int = 0,
             stacked = _stack(tensors)
             blocks[name] = (stacked if shapes[name][1] else
                             np.asarray(stacked.data, dtype=np.float32))
-        stacks["lead" if lead else "blocks"] = blocks
+        stacks[stack_name] = blocks
 
     rms_final = take((spec.dim,), FloatType.F32)
     wcls = take((spec.vocab_size, spec.dim), wft)
@@ -330,6 +384,7 @@ def write_header(f: BinaryIO, spec: ModelSpec, weights_ftype: FloatType) -> None
         kv.append((HeaderKey.NORM_EPS_E9, round(spec.norm_eps * 1e9)))
     kv += _pack_kinds(HeaderKey.ROPE_LAYERS_0, spec.rope_layers)
     kv += _pack_kinds(HeaderKey.WINDOW_LAYERS_0, spec.window_layers)
+    kv += _pack_layer_kinds(spec)
     for name, key, conv in _OWN_KEYS:
         value = getattr(spec, name)
         if value != getattr(ModelSpec, name):  # only where it says something
@@ -398,10 +453,10 @@ def params_file_order(spec: ModelSpec, params: Params, as_stored: bool = False):
                            np.asarray(t.scales)[idx])
         return t.to_numpy()[idx] if isinstance(t, QTensor) else np.asarray(t)[idx]
 
-    for lead, depth in _stacks_of(spec):
-        blocks = params["lead" if lead else "blocks"]
+    for stack_name, ks, lead, depth in _stacks_of(spec):
+        blocks = params[stack_name]
         for l in range(depth):
-            for name, e, _shape, _q in _layer_order(spec, lead):
+            for name, e, _shape, _q in _layer_order(ks, lead):
                 yield name, as_np(blocks[name], l if e is None else (l, e))
     yield "rms_final", params["rms_final"]
     yield "wcls", as_np(params["wcls"], ())

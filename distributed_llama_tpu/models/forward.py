@@ -27,6 +27,13 @@ Arch-specific structure:
   shared expert added to the routed sum, the router's score, renormalisation
   and scale, a stack that holds a share of the experts the router scores
   (`ModelSpec.router_width`, `expert_offset`), YaRN.
+- Kinds of attention layer (`ModelSpec.kinds`; Laguna: window layers of 72
+  query heads and full layers of 48, each with a rotation of its own, part of
+  a head rotated, a per-head sigmoid gate on the attention output) are data
+  too: a layer's tensors take their shape from its kind, so every RUN of like
+  layers (`ModelSpec.runs`) is a stack of `params` and a scan of its own,
+  traced with that kind's spec (`ModelSpec.of_kind`) and rotation table; the
+  caches and the commit go by the global layer as ever.
 - GROK1: embedding x78.38367176906169 (grok1-tasks.cpp:11-14); attention output is
   rmsnorm'd (rms_ffn) BEFORE the residual join (grokRmfFfn*, grok1-tasks.cpp:16-41);
   MoE input norm uses rms_moe; MoE output is rmsnorm'd with rms_ffn2 before its residual
@@ -35,6 +42,7 @@ Arch-specific structure:
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any
 
@@ -117,7 +125,8 @@ def _window_key_positions(start_pos, win: int, t: int, stale: int):
 def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, start_pos,
                positions, axis_name, sp_axis_name, sp_size, use_pallas, compress,
                window, paged_cold=None, block_tables=None, block_tokens=0,
-               paged_kernel=False, residual=None, rope_on=None, swa=None):
+               paged_kernel=False, residual=None, rope_on=None, swa=None,
+               kind_name=None):
     """Sharded attention sub-block against the FULL stacked caches, which it
     only READS: they are loop-invariant operands of the layer scan, the
     chunk's own k/v are attended from registers, and the new rows
@@ -145,7 +154,12 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
     projections and the attention that XLA cannot fuse across. swa > 0 is a
     sliding window: query i reads keys j with i - swa < j <= i, as a lower
     key bound on the mask (dense, gather) and in the paged kernel, which
-    skips the 128-key steps wholly behind it.
+    skips the 128-key steps wholly behind it. `kind_name`: the layer's kind
+    by name where the model states kinds (`ModelSpec.kinds`): `spec` and
+    `rope` are then that kind's, its window (spec.sliding_window, static)
+    holds for every layer of the scan and rides in as `swa`, a table narrower
+    than the head rotates the head's first values, and with a `wg` in bp
+    every head's output is multiplied by its gate before wo.
 
     residual: optional (B, T, dim) block input; when given the returned
     attn_out is ALREADY residual-joined (residual + wo-projection, after the
@@ -162,6 +176,13 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
     hs = spec.head_size
     _, _, hk, s, _ = kc.shape
     xb = rmsnorm(x, bp["rms_att"], spec.norm_eps)
+    gate = None
+    if "wg" in bp:
+        # the per-head output gate, from the same normed input as q: one
+        # value a head a token (heads may be a TP-local slice, as q's)
+        with jax.named_scope("attn_gate"):
+            gate = jax.nn.sigmoid(qmatmul(
+                xb, bp["wg"], use_pallas=use_pallas).astype(jnp.float32))
     if "wqkv" in bp:
         # merged QKV (models/params.py fuse_matvec_groups): ONE kernel launch for
         # all three projections. Local row counts split proportionally to the
@@ -196,108 +217,119 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
         "host/disc KV paging")
     k_t = jnp.swapaxes(k, 1, 2).astype(kc.dtype)  # (B, hk, T, hs)
     v_t = jnp.swapaxes(v, 1, 2).astype(vc.dtype)
-    if sp_axis_name is not None and sp_size > 1:
-        # The ring attends COMMITTED rows only (live_end) plus the current
-        # chunk's K/V as a register block. Striped, the live context
-        # occupies the same slot prefix on every member, so a static window
-        # bucket bounds each rotation to ceil(window/sp) columns: ICI and
-        # HBM per step track the LIVE context, not the allocated seq_len.
-        kl = jax.lax.dynamic_slice(kc, (layer_idx, 0, 0, 0, 0),
-                                   (1, b, hk, s, hs))[0]
-        vl = jax.lax.dynamic_slice(vc, (layer_idx, 0, 0, 0, 0),
-                                   (1, b, hk, s, hs))[0]
-        wl = (None if window is None
-              else min((window + sp_size - 1) // sp_size, s))
-        att = ring_attention(q, kl, vl, positions, axis_name=sp_axis_name,
-                             axis_size=sp_size, live_end=start_pos,
-                             chunk=(k_t, v_t, start_pos), window_slots=wl)
-    elif paged_cold is not None:
-        # Paged (out-of-core) cache: the device cache's S axis is a RING of the
-        # R most recent positions (slot = position mod R); everything older lives
-        # in the host store, and its attention contribution arrives as a
-        # (normalized output, lse) partial from the per-layer host callback —
-        # merged with the hot segment by the flash-attention segment identity
-        # (ops/attention.py merge_attention_partials). TPU-native equivalent of
-        # the reference's mmap'd disk KV cache (transformer.cpp:312-318): same
-        # capacity valve, but the resident window stays HBM-fast and only the
-        # cold history pays host bandwidth.
-        kl = jax.lax.dynamic_slice(kc, (layer_idx, 0, 0, 0, 0), (1, b, hk, s, hs))[0]
-        vl = jax.lax.dynamic_slice(vc, (layer_idx, 0, 0, 0, 0), (1, b, hk, s, hs))[0]
-        # slot j's most recent committed position: p_j = j + R*floor((pos-1-j)/R)
-        # (< start_pos by construction; negative = never written = masked). The
-        # committed ring covers exactly [max(0, start_pos-R), start_pos) — the
-        # host cold segment covers [0, max(0, start_pos-R)) with no overlap.
-        slot = jnp.arange(s)
-        p_j = slot + s * jnp.floor_divide(start_pos - 1 - slot, s)
-        slot_pos = jnp.where(p_j >= 0, p_j, jnp.int32(1 << 30))
-        key_pos = jnp.concatenate([slot_pos, start_pos + jnp.arange(t)])
-        from ..ops.attention import gqa_attention_lse, merge_attention_partials
+    # a run of one stated kind of layer: the scope and the paged kernel's
+    # name tell the kinds apart in a device trace
+    scope = kernel_name = None
+    if kind_name is not None:
+        scope = "attn_window" if spec.sliding_window else "attn_full"
+        kernel_name = "paged_" + scope
+    with jax.named_scope(scope) if scope else contextlib.nullcontext():
+        if sp_axis_name is not None and sp_size > 1:
+            # The ring attends COMMITTED rows only (live_end) plus the current
+            # chunk's K/V as a register block. Striped, the live context
+            # occupies the same slot prefix on every member, so a static window
+            # bucket bounds each rotation to ceil(window/sp) columns: ICI and
+            # HBM per step track the LIVE context, not the allocated seq_len.
+            kl = jax.lax.dynamic_slice(kc, (layer_idx, 0, 0, 0, 0),
+                                       (1, b, hk, s, hs))[0]
+            vl = jax.lax.dynamic_slice(vc, (layer_idx, 0, 0, 0, 0),
+                                       (1, b, hk, s, hs))[0]
+            wl = (None if window is None
+                  else min((window + sp_size - 1) // sp_size, s))
+            att = ring_attention(q, kl, vl, positions, axis_name=sp_axis_name,
+                                 axis_size=sp_size, live_end=start_pos,
+                                 chunk=(k_t, v_t, start_pos), window_slots=wl)
+        elif paged_cold is not None:
+            # Paged (out-of-core) cache: the device cache's S axis is a RING of the
+            # R most recent positions (slot = position mod R); everything older lives
+            # in the host store, and its attention contribution arrives as a
+            # (normalized output, lse) partial from the per-layer host callback —
+            # merged with the hot segment by the flash-attention segment identity
+            # (ops/attention.py merge_attention_partials). TPU-native equivalent of
+            # the reference's mmap'd disk KV cache (transformer.cpp:312-318): same
+            # capacity valve, but the resident window stays HBM-fast and only the
+            # cold history pays host bandwidth.
+            kl = jax.lax.dynamic_slice(kc, (layer_idx, 0, 0, 0, 0), (1, b, hk, s, hs))[0]
+            vl = jax.lax.dynamic_slice(vc, (layer_idx, 0, 0, 0, 0), (1, b, hk, s, hs))[0]
+            # slot j's most recent committed position: p_j = j + R*floor((pos-1-j)/R)
+            # (< start_pos by construction; negative = never written = masked). The
+            # committed ring covers exactly [max(0, start_pos-R), start_pos) — the
+            # host cold segment covers [0, max(0, start_pos-R)) with no overlap.
+            slot = jnp.arange(s)
+            p_j = slot + s * jnp.floor_divide(start_pos - 1 - slot, s)
+            slot_pos = jnp.where(p_j >= 0, p_j, jnp.int32(1 << 30))
+            key_pos = jnp.concatenate([slot_pos, start_pos + jnp.arange(t)])
+            from ..ops.attention import gqa_attention_lse, merge_attention_partials
 
-        out_h, lse_h = gqa_attention_lse(
-            q, jnp.concatenate([kl, k_t], axis=2),
-            jnp.concatenate([vl, v_t], axis=2), positions, key_positions=key_pos)
-        out_c, lse_c = paged_cold(layer_idx, q.astype(jnp.float32), start_pos)
-        att = merge_attention_partials(out_h, lse_h, out_c, lse_c)
-        att = att.reshape(b, t, hq_local * hs).astype(x.dtype)
-    elif block_tables is not None:
-        # Two readers, same semantics: the Pallas kernel copies the table's
-        # blocks under each row's committed length pool→VMEM, 128 keys a step
-        # (ops/pallas_paged_attention.py); the XLA fallback gathers the table
-        # into the dense window layout and runs the SAME gqa_attention as
-        # the contiguous branch — so on the CPU mesh paged logits are
-        # bit-identical to dense logits (the paged-vs-dense token-identity
-        # bar, tests/test_paged_kv.py).
-        w_total = block_tables.shape[1]
-        win = window or (w_total * block_tokens)
-        nb = min(-(-win // block_tokens), w_total)
-        if paged_kernel:
-            from ..ops.pallas_paged_attention import paged_attention
+            out_h, lse_h = gqa_attention_lse(
+                q, jnp.concatenate([kl, k_t], axis=2),
+                jnp.concatenate([vl, v_t], axis=2), positions, key_positions=key_pos)
+            out_c, lse_c = paged_cold(layer_idx, q.astype(jnp.float32), start_pos)
+            att = merge_attention_partials(out_h, lse_h, out_c, lse_c)
+            att = att.reshape(b, t, hq_local * hs)
+        elif block_tables is not None:
+            # Two readers, same semantics: the Pallas kernel copies the table's
+            # blocks under each row's committed length pool→VMEM, 128 keys a step
+            # (ops/pallas_paged_attention.py); the XLA fallback gathers the table
+            # into the dense window layout and runs the SAME gqa_attention as
+            # the contiguous branch — so on the CPU mesh paged logits are
+            # bit-identical to dense logits (the paged-vs-dense token-identity
+            # bar, tests/test_paged_kv.py).
+            w_total = block_tables.shape[1]
+            win = window or (w_total * block_tokens)
+            nb = min(-(-win // block_tokens), w_total)
+            if paged_kernel:
+                from ..ops.pallas_paged_attention import paged_attention
 
-            out = paged_attention(q, kc, vc, k_t, v_t, block_tables,
-                                  start_pos, layer_idx, n_read=nb,
-                                  window=swa)
-            att = out.reshape(b, t, hq_local * hs).astype(x.dtype)
+                out = paged_attention(q, kc, vc, k_t, v_t, block_tables,
+                                      start_pos, layer_idx, n_read=nb,
+                                      window=swa, name=kernel_name)
+                att = out.reshape(b, t, hq_local * hs)
+            else:
+                from ..ops.pallas_paged_attention import paged_gather_kv
+
+                kw, vw = paged_gather_kv(kc, vc, layer_idx, block_tables, nb)
+                vwin = nb * block_tokens
+                slot = jnp.arange(vwin)
+                # same committed-rows masking (and sentinel arithmetic) as the
+                # contiguous per-row branch below — a table entry past the
+                # row's committed length is scratch/garbage and masks out
+                slot_pos = jnp.where(slot[None, :] < start_pos[:, None],
+                                     slot[None, :], spec.seq_len + 1)  # (B, vwin)
+                key_pos = jnp.concatenate(
+                    [slot_pos, start_pos[:, None] + jnp.arange(t)[None, :]],
+                    axis=1)
+                att = gqa_attention(q, jnp.concatenate([kw, k_t], axis=2),
+                                    jnp.concatenate([vw, v_t], axis=2),
+                                    positions, key_positions=key_pos,
+                                    key_lo=key_lo)
+        elif (use_pallas and t == 1 and b == 1 and start_pos.ndim == 0
+                and key_lo is None):
+            # fused decode kernel: the cache window is DMA'd straight out of the
+            # stacked buffers inside the kernel (ops/pallas_attention.py) — no
+            # per-layer dynamic-slice materialization in XLA at all. Windows
+            # past the single-block VMEM budget take its window-tiled form, so
+            # long contexts never fall back to XLA slicing mid-generation.
+            from ..ops.pallas_attention import fused_decode_attention
+
+            g = hq_local // hk
+            out = fused_decode_attention(
+                q.reshape(hk, g, hs).astype(jnp.float32), kc, vc,
+                k_t[0], v_t[0], layer_idx, start_pos, window=window or s)
+            att = out.reshape(1, 1, hq_local * hs)
         else:
-            from ..ops.pallas_paged_attention import paged_gather_kv
-
-            kw, vw = paged_gather_kv(kc, vc, layer_idx, block_tables, nb)
-            vwin = nb * block_tokens
-            slot = jnp.arange(vwin)
-            # same committed-rows masking (and sentinel arithmetic) as the
-            # contiguous per-row branch below — a table entry past the
-            # row's committed length is scratch/garbage and masks out
-            slot_pos = jnp.where(slot[None, :] < start_pos[:, None],
-                                 slot[None, :], spec.seq_len + 1)  # (B, vwin)
-            key_pos = jnp.concatenate(
-                [slot_pos, start_pos[:, None] + jnp.arange(t)[None, :]],
-                axis=1)
-            att = gqa_attention(q, jnp.concatenate([kw, k_t], axis=2),
-                                jnp.concatenate([vw, v_t], axis=2),
-                                positions, key_positions=key_pos,
+            win = window or s
+            kw = jax.lax.dynamic_slice(kc, (layer_idx, 0, 0, 0, 0), (1, b, hk, win, hs))[0]
+            vw = jax.lax.dynamic_slice(vc, (layer_idx, 0, 0, 0, 0), (1, b, hk, win, hs))[0]
+            key_pos = _window_key_positions(start_pos, win, t, s + 1)
+            kfull = jnp.concatenate([kw, k_t], axis=2)  # (B, hk, win+T, hs)
+            vfull = jnp.concatenate([vw, v_t], axis=2)
+            att = gqa_attention(q, kfull, vfull, positions, key_positions=key_pos,
                                 key_lo=key_lo)
-    elif (use_pallas and t == 1 and b == 1 and start_pos.ndim == 0
-            and key_lo is None):
-        # fused decode kernel: the cache window is DMA'd straight out of the
-        # stacked buffers inside the kernel (ops/pallas_attention.py) — no
-        # per-layer dynamic-slice materialization in XLA at all. Windows
-        # past the single-block VMEM budget take its window-tiled form, so
-        # long contexts never fall back to XLA slicing mid-generation.
-        from ..ops.pallas_attention import fused_decode_attention
-
-        g = hq_local // hk
-        out = fused_decode_attention(
-            q.reshape(hk, g, hs).astype(jnp.float32), kc, vc,
-            k_t[0], v_t[0], layer_idx, start_pos, window=window or s)
-        att = out.reshape(1, 1, hq_local * hs).astype(x.dtype)
-    else:
-        win = window or s
-        kw = jax.lax.dynamic_slice(kc, (layer_idx, 0, 0, 0, 0), (1, b, hk, win, hs))[0]
-        vw = jax.lax.dynamic_slice(vc, (layer_idx, 0, 0, 0, 0), (1, b, hk, win, hs))[0]
-        key_pos = _window_key_positions(start_pos, win, t, s + 1)
-        kfull = jnp.concatenate([kw, k_t], axis=2)  # (B, hk, win+T, hs)
-        vfull = jnp.concatenate([vw, v_t], axis=2)
-        att = gqa_attention(q, kfull, vfull, positions, key_positions=key_pos,
-                            key_lo=key_lo)
+    att = att.astype(x.dtype)
+    if gate is not None:
+        att = (att.reshape(b, t, hq_local, hs).astype(jnp.float32)
+               * gate[..., None]).astype(x.dtype).reshape(b, t, hq_local * hs)
     # col-parallel wo: local heads x local input slice -> partial (B, T, dim); psum merges
     y = _maybe_psum(qmatmul(att, bp["wo"], use_pallas=use_pallas), axis_name,
                     compress)
@@ -585,7 +617,8 @@ def _moe_ffn(xb, bp, spec: ModelSpec, axis_name, use_pallas, compress,
 def _block(x, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions,
            axis_name, sp_axis_name, sp_size, use_pallas, compress, window,
            kc, vc, paged_cold=None, block_tables=None, block_tokens=0,
-           paged_kernel=False, stacks=None, routed=None, layer_base=0):
+           paged_kernel=False, stacks=None, routed=None, layer_base=0,
+           kind_name=None):
     """One transformer block as a scan step: the carry is x, the caches kc/vc
     are read-only closures (loop invariants), and the ys are the layer's new
     K/V rows, for forward() to commit in one top-level write, with the
@@ -602,6 +635,8 @@ def _block(x, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions,
     # layers of more than one kind (forward() below)
     bp, layer_idx, *kind = layer
     rope_on, swa = kind if kind else (None, None)
+    if kind_name is not None:  # a run of one stated kind: its window is static
+        swa = spec.sliding_window or None
     if stacks:
         bp = {**bp, **{n: LayerOf(w, (layer_idx,)) for n, w in stacks.items()}}
     routed = spec.is_moe if routed is None else routed
@@ -630,7 +665,8 @@ def _block(x, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions,
                 axis_name, sp_axis_name, sp_size, use_pallas, compress,
                 window, paged_cold=paged_cold, block_tables=block_tables,
                 block_tokens=block_tokens, paged_kernel=paged_kernel,
-                residual=res_attn, rope_on=rope_on, swa=swa)
+                residual=res_attn, rope_on=rope_on, swa=swa,
+                kind_name=kind_name)
     if spec.arch_type == ArchType.GROK1:
         # grok: residual-join the *normalized* attention output (grokRmfFfn/Norm/Join)
         x = x + rmsnorm(attn_out, bp["rms_ffn"], spec.norm_eps)
@@ -771,7 +807,7 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
     # dequant-matmul and the grouped expert kernels take their blocks from
     # the whole stack at the layer's (and the expert's) index, and a slice
     # would be a copy of the layer's weights, every expert touched or not
-    def scan_stack(x, blocks, depth, **kind):
+    def scan_stack(x, blocks, depth, spec=spec, rope=rope, **kind):
         """One `lax.scan` over a stack of `depth` like layers."""
         stacks = {n: w for n, w in blocks.items()
                   if reads_the_stack(w, tokens.shape[0] * t, use_pallas)}
@@ -786,28 +822,37 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
             stacks=stacks, **kind)
         xs = ({n: w for n, w in blocks.items() if n not in stacks},
               jnp.arange(depth, dtype=jnp.int32))
-        if spec.rope_layers or spec.sliding_window:
+        if "kind_name" not in kind and (spec.rope_layers
+                                        or spec.sliding_window):
             # layers of more than one kind in ONE scan: the kind is data
             xs += (jnp.asarray(spec.layer_rope(), jnp.int32),
                    jnp.asarray(spec.layer_window(), jnp.int32))
         return jax.lax.scan(block_fn, x, xs)
 
-    if spec.lead_layers:
-        # the leading dense layers hold other tensors than the rest: a stack
-        # and a scan of their own, the caches indexed by the global layer
-        assert paged_cold is None and not sp_active, (
-            "a leading stack is not supported with sp (ring) sharding or "
-            "host/disc KV paging")
-        with jax.named_scope("lead_stack"):
-            x, lead_ys = scan_stack(x, params["lead"], spec.lead_layers,
-                                    routed=False)
-        x, ys = scan_stack(x, params["blocks"], spec.block_layers,
-                           layer_base=spec.lead_layers)
-        k_rows, v_rows, stats = (jnp.concatenate([a, b_])
-                                 for a, b_ in zip(lead_ys, ys))
-    else:
-        x, (k_rows, v_rows, stats) = scan_stack(x, params["blocks"],
-                                                spec.n_layers)
+    # every run of like layers (`ModelSpec.runs`) is a stack of `params` and
+    # a scan of its own: the leading dense layers hold other tensors than
+    # the rest, and where the model states kinds of attention layer a run's
+    # tensors, spec and rotation table are its kind's; the caches go by the
+    # global layer. One stack is the one scan it always was, and the program
+    # grows with the runs, not with the layers
+    runs = spec.runs()
+    assert len(runs) == 1 or (paged_cold is None and not sp_active), (
+        "a leading stack and kinds of attention layer are not supported "
+        "with sp (ring) sharding or host/disc KV paging")
+    ys = []
+    for run in runs:
+        kind = spec.kinds[run.kind] if spec.kinds else None
+        scope = (f"run_{run.name}_{kind.name}" if kind
+                 else "lead_stack" if run.lead else None)
+        with jax.named_scope(scope) if scope else contextlib.nullcontext():
+            x, run_ys = scan_stack(
+                x, params[run.name], run.depth, spec=spec.of_kind(run.kind),
+                rope=rope.of_kind(spec, run.kind),
+                routed=False if run.lead else None, layer_base=run.first,
+                **({"kind_name": kind.name} if kind else {}))
+        ys.append(run_ys)
+    k_rows, v_rows, stats = ys[0] if len(ys) == 1 else (
+        jnp.concatenate(a) for a in zip(*ys))
     # commit all layers' new rows in one write per cache: (L, B, hk, T, hs)
     # lands at [.., .., .., start_pos : start_pos+T, ..]
     if block_tables is not None:

@@ -19,8 +19,11 @@ blocks along `in`. Tensor inventory mirrors the `.m` file exactly
     rms_final (dim,) f32
 
 A model with leading dense layers (ModelSpec.lead_layers) has TWO stacks,
-`params["lead"]` and `params["blocks"]` (`STACKS`), each stacked over its own
-layers: the tensors of the two kinds differ.
+`params["lead"]` and `params["blocks"]`, each stacked over its own layers: the
+tensors of the two kinds differ. A model with kinds of attention layer
+(ModelSpec.kinds: another head count, so another shape of wq and wo) has one
+stack a RUN of like layers, under the names `ModelSpec.runs()` gives them.
+`stack_names(params)` lists the stacks a params dict holds, in layer order.
 """
 
 from __future__ import annotations
@@ -51,7 +54,11 @@ def block_tensor_shapes(spec: ModelSpec, lead: bool = False
     kv_lora_rank) and w_uv (H, v_head_dim, kv_lora_rank), and the norms rms_q
     and rms_kv; a routed block with a shared expert has sh_gate, sh_down,
     sh_up beside its stacks.
+    A spec with kinds of layer is asked for one kind's shapes:
+    `block_tensor_shapes(spec.of_kind(run.kind), run.lead)`. The per-head
+    output gate (spec.attn_gate) is `wg` (n_heads, dim), behind wo.
     """
+    assert not spec.kinds, "ask with spec.of_kind(kind): shapes differ by kind"
     d, h, kv, e = spec.dim, spec.hidden_dim, spec.kv_dim, spec.n_experts
     qd = spec.q_dim  # n_heads x head_size: dim unless the header states head_dim
     shapes: dict[str, tuple[tuple[int, ...], bool]]
@@ -72,6 +79,8 @@ def block_tensor_shapes(spec: ModelSpec, lead: bool = False
             "wv": ((kv, d), True),
             "wo": ((d, qd), True),
         }
+    if spec.attn_gate:
+        shapes["wg"] = ((spec.n_heads, d), True)
     if spec.is_moe and not lead:
         shapes["router"] = ((spec.n_router, d), True)
         shapes["moe_up"] = ((e, h, d), True)
@@ -108,9 +117,11 @@ def init_random_params(spec: ModelSpec, weights_ftype: FloatType = FloatType.F32
     def randn(*shape):
         return (rng.randn(*shape) * scale).astype(np.float32)
 
-    def stack(depth: int, lead: bool) -> Params:
+    def stack(run) -> Params:
         blocks: Params = {}
-        for name, (shape, quantized) in block_tensor_shapes(spec, lead).items():
+        depth = run.depth
+        for name, (shape, quantized) in block_tensor_shapes(
+                spec.of_kind(run.kind), run.lead).items():
             full = randn(depth, *shape)
             if quantized:
                 blocks[name] = QTensor.from_float(full, weights_ftype)
@@ -118,10 +129,9 @@ def init_random_params(spec: ModelSpec, weights_ftype: FloatType = FloatType.F32
                 blocks[name] = full + 1.0  # norm weights around 1
         return blocks
 
-    # the draws keep their order (blocks, embedding, final norm, head): a
-    # seed gives the model it always gave
-    out = {"lead": stack(spec.lead_layers, True)} if spec.lead_layers else {}
-    out["blocks"] = stack(spec.block_layers, False)
+    # the draws keep their order (the stacks in layer order, embedding,
+    # final norm, head): a seed gives the model it always gave
+    out = {run.name: stack(run) for run in spec.runs()}
     out["embedding"] = randn(spec.vocab_size, spec.dim)
     out["rms_final"] = randn(spec.dim) + 1.0
     out["wcls"] = QTensor.from_float(randn(spec.vocab_size, spec.dim),
@@ -129,7 +139,13 @@ def init_random_params(spec: ModelSpec, weights_ftype: FloatType = FloatType.F32
     return out
 
 
-STACKS = ("lead", "blocks")  # the layer stacks a params dict may hold, in order
+def stack_names(params: Params) -> list[str]:
+    """The layer stacks a params dict holds ("lead", "blocks", and a stack a
+    run where the model has kinds of layer: `ModelSpec.runs`), as inserted:
+    in layer order from every loader of this repo."""
+    return [k for k, v in params.items() if isinstance(v, dict)]
+
+
 # the two halves of latent attention's kv_b projection: batched by head and
 # small, so they are held dense in the engine's dtype, not as Q40 blocks
 _HELD_DENSE = ("w_uk", "w_uv")
@@ -140,9 +156,8 @@ def hold_dense(params: Params, dtype) -> Params:
     (`_HELD_DENSE`) dequantized once into `dtype`: what a loader's QTensor of
     them becomes before the engine places the weights."""
     out = dict(params)
-    for st in STACKS:
-        if st in params and any(isinstance(params[st].get(n), QTensor)
-                                for n in _HELD_DENSE):
+    for st in stack_names(params):
+        if any(isinstance(params[st].get(n), QTensor) for n in _HELD_DENSE):
             out[st] = {n: (t.dequantize(dtype=dtype)
                            if n in _HELD_DENSE and isinstance(t, QTensor)
                            else t) for n, t in params[st].items()}
@@ -156,7 +171,7 @@ _I8_CONVERTIBLE = (FloatType.Q40, FloatType.Q80)
 # forward — it is tiny). Tensors in _COL_SHARDED get their in-axis TP-sliced
 # (ColMatmulSlice), so the i4p split-plane pack must be applied per column group
 # (QTensor.to_i4p_layout).
-_DENSE_MATMULS = {"wq", "wk", "wv", "wo", "w1", "w2", "w3",
+_DENSE_MATMULS = {"wq", "wk", "wv", "wo", "wg", "w1", "w2", "w3",
                   "moe_up", "moe_gate", "moe_down",
                   "wq_a", "wq_b", "wkv_a", "sh_gate", "sh_up", "sh_down"}
 _COL_SHARDED = {"wo", "w2", "moe_down", "sh_down"}
@@ -390,8 +405,7 @@ def prepare_for_pallas(params: Params, tp: int = 1,
 
     from ..parallel.sharding import param_pspecs
 
-    out: Params = {"embedding": params["embedding"],
-                   "rms_final": params["rms_final"]}
+    out: Params = {}
 
     def convert(members, row_groups, col_sharded, pspec, row_axis=1):
         t = members[0]
@@ -406,9 +420,7 @@ def prepare_for_pallas(params: Params, tp: int = 1,
         sharding = None if mesh is None else NamedSharding(mesh, pspec)
         return _repack_on_device(members, row_groups, col_groups, sharding)
 
-    for st in STACKS:
-        if st not in params:
-            continue
+    for st in stack_names(params):
         blocks = params[st]
         plan = _fuse_plan(blocks, spec, tp, moe_sharding) if fuse else {}
         merged = {m: f for f in plan for m in _FUSE_GROUPS[f]}
@@ -440,8 +452,8 @@ def prepare_for_pallas(params: Params, tp: int = 1,
                     PartitionSpec(None, *param_pspecs(
                         {"blocks": {}}, moe_sharding)["wcls"])),
             lambda a: a[0])
-    out["wcls"] = wcls
-    return out
+    return {**out, "embedding": params["embedding"],
+            "rms_final": params["rms_final"], "wcls": wcls}
 
 
 def _map_leaves(t: QTensor, fn) -> QTensor:
@@ -463,7 +475,7 @@ def decode_stream_bytes(params: Params, spec: ModelSpec, rows: int = 1) -> int:
     reads a chosen expert once and an unchosen one not at all). The numerator of
     the achieved-GB/s observability metric."""
     total = 0
-    leaves = [nt for st in STACKS for nt in params.get(st, {}).items()]
+    leaves = [nt for st in stack_names(params) for nt in params[st].items()]
     for name, t in leaves + [("wcls", params["wcls"])]:
         n = t.nbytes() if isinstance(t, QTensor) else t.nbytes
         if name.startswith("moe_") and spec.n_experts:

@@ -10,7 +10,8 @@ from __future__ import annotations
 
 import enum
 import math
-from dataclasses import dataclass, replace
+from dataclasses import dataclass, fields, replace
+from typing import NamedTuple
 
 
 class ArchType(enum.IntEnum):
@@ -51,6 +52,8 @@ class RopeType(enum.IntEnum):
     LLAMA3_1 = 2
     # interleaved pairs as LLAMA, YaRN's frequencies (ops/rope.py)
     YARN = 3
+    # half-split pairs as FALCON, YaRN's frequencies
+    YARN_NEOX = 4
 
 
 # .m header key ids (reference: src/transformer.hpp:10-30 / converter/writer.py:109-130)
@@ -104,11 +107,53 @@ class HeaderKey(enum.IntEnum):
     # No word present means every layer (the default of both lists)
     ROPE_LAYERS_0 = 32
     WINDOW_LAYERS_0 = 40
+    # a rotary width under the head size, the tables' own factor, the
+    # per-head output gate
+    ROTARY_DIM = 58
+    ROPE_TABLE_SCALE_E6 = 59
+    ATTN_GATE = 60
+    # kinds of attention layer (ModelSpec.kinds): how many, then which kind
+    # each layer is, 15 layers a word at two bits a layer (up to four kinds),
+    # then kind k's fields at KIND_0 + KIND_STRIDE x k + its offset
+    # (formats/mfile.py _KIND_FIELDS)
+    N_KINDS = 61
+    LAYER_KINDS_0 = 64  # ..79
+    KIND_0 = 100
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
     """YaRN's attention factor: 0.1 x mscale x ln(factor) + 1."""
     return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+@dataclass(frozen=True)
+class LayerKind:
+    """One kind of attention layer of a model whose layers differ in more
+    than a 0/1 switch (ModelSpec.kinds): its query heads, its window, its
+    rotation. Every field overrides the ModelSpec field of the same name for
+    the layers of this kind (`ModelSpec.of_kind`)."""
+
+    name: str
+    n_heads: int
+    sliding_window: int = 0  # 0: the layer attends every key
+    rope_type: RopeType = RopeType.FALCON
+    rope_theta: float = 10000.0
+    rotary_dim: int = 0  # values of a head that are rotated; 0: all of them
+    rope_scaling_factor: float = 0.0
+    rope_scaling_orig_max_seq_len: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    rope_table_scale: float = 0.0  # cos and sin times this; 0: derived
+
+
+class Run(NamedTuple):
+    """Consecutive like layers: one stack of `params` and one `lax.scan`."""
+
+    name: str  # the stack's key in `params`
+    first: int  # its first layer's index in the model
+    depth: int
+    kind: int | None  # index into ModelSpec.kinds; None: the model has none
+    lead: bool  # leading dense layers (ModelSpec.lead_layers)
 
 
 @dataclass(frozen=True)
@@ -177,6 +222,25 @@ class ModelSpec:
     yarn_beta_slow: float = 1.0
     yarn_mscale: float = 1.0
     yarn_mscale_all_dim: float = 0.0
+    # values of a head the rotation covers, from its first: the rest pass
+    # unrotated (a partial rotary factor). 0 = the whole head
+    rotary_dim: int = 0
+    # what the rotation's cos and sin are multiplied by (YaRN's attention
+    # factor where the model states it as a number). 0 = derived: the ratio
+    # of the two mscales under YaRN, else 1
+    rope_table_scale: float = 0.0
+    # a per-head output gate: every head's attention output times
+    # sigmoid(wg h) of the normed block input, one value a head a token,
+    # before wo (tensor `wg`, (n_heads, dim))
+    attn_gate: bool = False
+    # --- kinds of attention layer. Where layers differ in head count or
+    # rotation their tensors differ in shape, so each RUN of like layers
+    # stands in a stack and a scan of its own (`runs`): `kinds` lists the
+    # kinds, `layer_kinds` names each layer's by index. () = one kind, stated
+    # by the fields above; rope_layers / window_layers are then the 0/1
+    # switches within it, and are not used together with kinds
+    kinds: tuple[LayerKind, ...] = ()
+    layer_kinds: tuple[int, ...] = ()
 
     # --- derived (reference: transformer.cpp:102-106) ---
     @property
@@ -194,7 +258,9 @@ class ModelSpec:
     @property
     def rope_width(self) -> int:
         """Values of a head the rotation covers (the tables' width x 2)."""
-        return self.qk_rope_head_dim if self.latent else self.head_size
+        if self.latent:
+            return self.qk_rope_head_dim
+        return self.rotary_dim or self.head_size
 
     @property
     def o_dim(self) -> int:
@@ -223,7 +289,8 @@ class ModelSpec:
         times YaRN's mscale(factor, mscale_all_dim)^2 where the model states
         one (the DeepSeek-V3 graph's softmax_scale)."""
         scale = self.head_size ** -0.5
-        if (self.rope_type == RopeType.YARN and self.yarn_mscale_all_dim
+        if (self.rope_type in (RopeType.YARN, RopeType.YARN_NEOX)
+                and self.yarn_mscale_all_dim
                 and self.rope_scaling_factor > 1.0):
             m = yarn_mscale(self.rope_scaling_factor, self.yarn_mscale_all_dim)
             scale *= m * m
@@ -252,8 +319,44 @@ class ModelSpec:
         """1 where layer l rotates q and k, one entry a layer."""
         return tuple(self.rope_layers) or (1,) * self.n_layers
 
+    def of_kind(self, kind: int | None) -> "ModelSpec":
+        """The spec the layers of one kind are computed with: this one with
+        the kind's fields in place of its own, and one kind of layer."""
+        if kind is None:
+            return self
+        over = {f.name: getattr(self.kinds[kind], f.name)
+                for f in fields(LayerKind) if f.name != "name"}
+        return replace(self, kinds=(), layer_kinds=(), **over)
+
+    def kind_specs(self) -> tuple["ModelSpec", ...]:
+        """The spec of each kind of layer; this one where it states none."""
+        return tuple(self.of_kind(k) for k in range(len(self.kinds))) or (
+            self,)
+
+    def runs(self) -> tuple[Run, ...]:
+        """The model's layers as runs of like layers, in layer order: the
+        leading dense layers ("lead") and the rest ("blocks"), each cut
+        further wherever the kind of layer changes ("blocks", "blocks1", ..:
+        a model of one kind keeps the two names it always had)."""
+        kinds = self.layer_kinds or (None,) * self.n_layers
+        out: list[Run] = []
+        count = {True: 0, False: 0}
+        for l in range(self.n_layers):
+            lead = l < self.lead_layers
+            if out and (out[-1].kind, out[-1].lead) == (kinds[l], lead):
+                out[-1] = out[-1]._replace(depth=out[-1].depth + 1)
+                continue
+            name = ("lead" if lead else "blocks") + (
+                str(count[lead]) if count[lead] else "")
+            count[lead] += 1
+            out.append(Run(name, l, 1, kinds[l], lead))
+        return tuple(out)
+
     def layer_window(self) -> tuple[int, ...]:
         """The window of layer l in keys, 0 where it attends every key."""
+        if self.kinds:
+            return tuple(self.kinds[k].sliding_window
+                         for k in self.layer_kinds)
         if not self.sliding_window:
             return (0,) * self.n_layers
         on = tuple(self.window_layers) or (1,) * self.n_layers
@@ -290,8 +393,31 @@ class ModelSpec:
             assert not (spec.rope_layers or spec.sliding_window), (
                 "latent attention has one kind of layer")
         if spec.lead_layers:
+            # the 0/1 switches ride in ONE scan's xs; a model whose leading
+            # layers differ from the rest states its kinds (runs of their own)
             assert not (spec.rope_layers or spec.sliding_window), (
                 "layers of two kinds stand in one stack")
+        assert 0 <= spec.rotary_dim <= spec.head_size and not (
+            spec.rotary_dim % 2), spec.rotary_dim
+        if spec.kinds:
+            assert len(spec.layer_kinds) == spec.n_layers and all(
+                0 <= k < len(spec.kinds) for k in spec.layer_kinds), (
+                f"layer_kinds {spec.layer_kinds} names {spec.n_layers} "
+                f"layers' kinds among {len(spec.kinds)}")
+            assert not (spec.latent or spec.rope_layers or spec.window_layers
+                        or spec.sliding_window), (
+                "kinds of layer state their own windows and rotation")
+            assert spec.arch_type != ArchType.GROK1 and spec.head_dim, (
+                "kinds of layer share a stated head size")
+            for k in spec.kinds:
+                assert k.n_heads % spec.n_kv_heads == 0, (k, spec.n_kv_heads)
+                assert (0 <= k.rotary_dim <= spec.head_size
+                        and not k.rotary_dim % 2), k
+                assert k.rope_type != RopeType.UNKNOWN, k
+        else:
+            assert not spec.layer_kinds, "layer_kinds without kinds"
+        assert not (spec.attn_gate and spec.latent), (
+            "the per-head gate is not stated for latent attention")
         assert (spec.expert_offset + spec.n_experts
                 <= max(spec.n_router, spec.n_experts))
         return spec

@@ -237,9 +237,10 @@ def _kernel(li_ref, tbl_ref, len_ref, win_ref, q_ref, kn_ref, vn_ref, k_hbm,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n_read", "interpret"))
+                   static_argnames=("n_read", "interpret", "name"))
 def paged_attention(q, kc, vc, k_new, v_new, tables, lengths, layer_idx, *,
-                    n_read: int, interpret: bool | None = None, window=None):
+                    n_read: int, interpret: bool | None = None, window=None,
+                    name: str | None = None):
     """Paged attention of T chunk queries per row against block-table KV.
 
     q: (B, T, hq, hs) in the activation dtype — T = 1 (decode scan step),
@@ -255,6 +256,9 @@ def paged_attention(q, kc, vc, k_new, v_new, tables, lengths, layer_idx, *,
         request, analysis/compile_audit.py).
     window: None, or this layer's sliding window as an i32 scalar (traced:
         it differs by layer inside one scan; 0 = this layer has none).
+    name: the `pallas_call`'s name, which a device trace shows: a model with
+        kinds of layer names the kernel by kind (`paged_attn_window`,
+        `paged_attn_full`); None keeps the kernel's own.
     Returns (B, T, hq, hs) f32.
     """
     if interpret is None:
@@ -297,7 +301,7 @@ def paged_attention(q, kc, vc, k_new, v_new, tables, lengths, layer_idx, *,
         body,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hk, t * g, hs), jnp.float32),
-        interpret=interpret,
+        interpret=interpret, name=name,
     )(jnp.asarray([layer_idx], jnp.int32), tbl_flat,
       jnp.asarray(lengths, jnp.int32),
       jnp.reshape(jnp.asarray(window if windowed else 0, jnp.int32), (1,)),

@@ -1,6 +1,12 @@
 """Rotary position embeddings — the three styles of the reference (src/commands.cpp:140-257),
 and YaRN (ROPE_YARN: interleaved pairs over a head's rotary part, the frequencies scaled by
-parts, `_yarn_scale_freqs`; its attention scale is `ModelSpec.attn_scale`).
+parts, `_yarn_scale_freqs`; its attention scale is `ModelSpec.attn_scale`; ROPE_YARN_NEOX: the
+same frequencies over half-split pairs).
+
+A table is as wide as the rotary part (`ModelSpec.rope_width`): where that is under the head
+size (`rotary_dim`, a partial rotary factor) `apply_rope` rotates a head's first values and
+passes the rest. A model with kinds of layer (`ModelSpec.kinds`) has a table a kind, stacked
+on a leading axis and padded to the widest (`RopeTables.of_kind` cuts one out).
 
 - ROPE_LLAMA: interleaved pairs (2k, 2k+1), freq_k = theta^(-2k/head_size), precomputed
   cos/sin tables over the full sequence (LlamaRopeCommand, commands.cpp:140-179).
@@ -69,7 +75,8 @@ def _yarn_scale_freqs(freqs: np.ndarray, factor: float, orig_max_seq_len: int,
 @jax.tree_util.register_pytree_node_class
 @dataclass
 class RopeTables:
-    """Precomputed per-position cos/sin, shape (seq_len, head_size // 2)."""
+    """Precomputed per-position cos/sin, shape (seq_len, rope_width // 2);
+    (kinds, seq_len, widest // 2) for a model with kinds of layer."""
 
     cos: jax.Array
     sin: jax.Array
@@ -82,13 +89,32 @@ class RopeTables:
     def tree_unflatten(cls, aux, children):
         return cls(children[0], children[1], aux[0])
 
+    def of_kind(self, spec: ModelSpec, kind: int | None) -> "RopeTables":
+        """The table of one kind's layers, for `spec.of_kind(kind)`."""
+        if kind is None:
+            return self
+        ks = spec.of_kind(kind)
+        half = ks.rope_width // 2
+        return RopeTables(self.cos[kind, :, :half], self.sin[kind, :, :half],
+                          ks.rope_type)
+
     @classmethod
     def create(cls, spec: ModelSpec) -> "RopeTables":
+        if spec.kinds:
+            each = [cls.create(ks) for ks in spec.kind_specs()]
+            widest = max(t.cos.shape[1] for t in each)
+
+            def stack(tables):
+                return jnp.stack([jnp.pad(a, ((0, 0), (0, widest - a.shape[1])))
+                                  for a in tables])
+
+            return cls(stack([t.cos for t in each]),
+                       stack([t.sin for t in each]), spec.rope_type)
         hs = spec.rope_width
         k = np.arange(hs // 2, dtype=np.float64)
         freqs = 1.0 / (spec.rope_theta ** (2.0 * k / hs))
         scale = 1.0
-        if spec.rope_type == RopeType.YARN:
+        if spec.rope_type in (RopeType.YARN, RopeType.YARN_NEOX):
             from ..models.spec import yarn_mscale
 
             freqs = _yarn_scale_freqs(
@@ -104,6 +130,8 @@ class RopeTables:
             freqs = _llama31_scale_freqs(
                 freqs, spec.rope_scaling_factor, spec.rope_scaling_low_freq_factor,
                 spec.rope_scaling_high_freq_factor, spec.rope_scaling_orig_max_seq_len)
+        if spec.rope_table_scale:  # stated as a number: nothing to derive
+            scale = spec.rope_table_scale
         t = np.arange(spec.seq_len, dtype=np.float64)
         angles = np.outer(t, freqs)  # (seq_len, hs//2)
         return cls(
@@ -117,11 +145,16 @@ def apply_rope(x: jax.Array, tables: RopeTables, positions: jax.Array) -> jax.Ar
     """Rotate q or k. x: (..., T, n_heads, head_size); positions: (T,) int32.
 
     Both interleaved (llama) and half-rotation (neox/falcon) layouts rotate pair
-    (a, b) -> (a*cos - b*sin, a*sin + b*cos); only the pairing differs.
+    (a, b) -> (a*cos - b*sin, a*sin + b*cos); only the pairing differs. Tables
+    narrower than the head rotate its first 2 x width values (pairs within
+    them) and pass the rest as they are.
     """
     cos = tables.cos[positions][..., :, None, :]  # (..., T, 1, hs//2)
     sin = tables.sin[positions][..., :, None, :]
-    hs = x.shape[-1]
+    hs = 2 * cos.shape[-1]
+    if hs < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :hs], tables, positions), x[..., hs:]], axis=-1)
     xf = x.astype(jnp.float32)
     if tables.rope_type in (RopeType.LLAMA, RopeType.LLAMA3_1, RopeType.YARN):
         xp = xf.reshape(*x.shape[:-1], hs // 2, 2)
@@ -129,7 +162,7 @@ def apply_rope(x: jax.Array, tables: RopeTables, positions: jax.Array) -> jax.Ar
         ra = a * cos - b * sin
         rb = a * sin + b * cos
         out = jnp.stack([ra, rb], axis=-1).reshape(x.shape)
-    elif tables.rope_type == RopeType.FALCON:
+    elif tables.rope_type in (RopeType.FALCON, RopeType.YARN_NEOX):
         a, b = xf[..., : hs // 2], xf[..., hs // 2 :]
         ra = a * cos - b * sin
         rb = a * sin + b * cos

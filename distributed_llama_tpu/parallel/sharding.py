@@ -38,6 +38,7 @@ _BLOCK_SPECS = {
     "wqkv": P(None, AXIS_TP),        # (L, (dim+2kv)->tp, dim)
     "w13": P(None, AXIS_TP),         # (L, 2*hidden->tp, dim)
     "wo": P(None, None, AXIS_TP),    # (L, dim, q_dim->tp) partial-sum
+    "wg": P(None, AXIS_TP),          # (L, heads->tp, dim): a head's gate with its q
     "w1": P(None, AXIS_TP),          # (L, hidden->tp, dim)
     "w3": P(None, AXIS_TP),
     "w2": P(None, None, AXIS_TP),    # (L, dim, hidden->tp) partial-sum
@@ -94,15 +95,15 @@ def param_pspecs(params: dict[str, Any],
             blocks.update({k: v for k, v in _EP_SPECS.items() if k in blocks})
         return blocks
 
-    out = {
+    from ..models.params import stack_names
+
+    return {
         "embedding": P(),  # replicated, root-only-F32 in reference (transformer.cpp:496)
-        "blocks": stack(params["blocks"]),
+        # "blocks", a leading dense stack, a stack a run of like layers
+        **{st: stack(params[st]) for st in stack_names(params)},
         "rms_final": P(),
         "wcls": P(AXIS_TP),  # (vocab->tp, dim); logits all-gathered in forward
     }
-    if "lead" in params:  # a leading dense stack (ModelSpec.lead_layers)
-        out["lead"] = stack(params["lead"])
-    return out
 
 
 def kv_cache_pspec(seq_axis: str | None = None) -> P:
@@ -147,14 +148,17 @@ def check_divisibility(spec: ModelSpec, tp: int, sp: int = 1,
     assert hk % tp == 0, (
         f"tp={tp} must divide n_kv_heads={spec.n_kv_heads} (or be a multiple of it "
         "for KV-head replication)")
-    assert spec.n_heads % tp == 0, (
-        f"tp={tp} must divide n_heads={spec.n_heads}")
     assert spec.dim % tp == 0
     assert spec.vocab_size % tp == 0
-    if (spec.dim // tp) % 32 or (spec.o_dim // tp) % 32:
-        # q_dim (n_heads x head_size) is wo's in-axis; it is dim unless the
-        # model states its head size
-        raise AssertionError("tp slice must keep 32-wide quant blocks intact")
+    # every kind of layer's heads (the spec's own where it states no kinds)
+    for ks in spec.kind_specs():
+        assert ks.n_heads % tp == 0, (
+            f"tp={tp} must divide n_heads={ks.n_heads}")
+        if (spec.dim // tp) % 32 or (ks.o_dim // tp) % 32:
+            # q_dim (n_heads x head_size) is wo's in-axis; it is dim unless
+            # the model states its head size
+            raise AssertionError(
+                "tp slice must keep 32-wide quant blocks intact")
     for width in (spec.lead_hidden_dim, spec.shared_hidden_dim):
         if width % tp or (width // tp) % 32:
             raise AssertionError("tp slice must keep 32-wide quant blocks intact")

@@ -80,9 +80,10 @@ def shard_params(params: dict[str, Any], mesh: Mesh,
     # fused matvec groups carry the TP-group count their rows were interleaved
     # with (models/params.py fuse_matvec_groups); placement on a mismatched
     # mesh/moe_sharding would silently scramble the member split — fail loudly
-    from ..models.params import _FUSE_GROUPS, STACKS
+    from ..models.params import _FUSE_GROUPS, stack_names
 
-    for name, t in [nt for st in STACKS for nt in params.get(st, {}).items()]:
+    for name, t in [nt for st in stack_names(params)
+                    for nt in params[st].items()]:
         if name not in _FUSE_GROUPS or not isinstance(t, QTensor):
             continue
         expected = 1 if (name == "moe_gu" and moe_sharding == "expert") else tp
@@ -97,10 +98,12 @@ def shard_params(params: dict[str, Any], mesh: Mesh,
         # a latent row is whole on every shard: nothing to repeat
         if hk_eff != spec.n_kv_heads and not spec.latent:
             rep = hk_eff // spec.n_kv_heads
-            params = dict(params, blocks=dict(params["blocks"]))
-            for name in ("wk", "wv"):
-                params["blocks"][name] = _repeat_kv_rows(
-                    params["blocks"][name], spec.n_kv_heads, rep)
+            params = dict(params)
+            for st in stack_names(params):
+                params[st] = dict(params[st])
+                for name in ("wk", "wv"):
+                    params[st][name] = _repeat_kv_rows(
+                        params[st][name], spec.n_kv_heads, rep)
     pspec_tree = _expand_pspec_tree(params, param_pspecs(params, moe_sharding))
 
     def put(leaf, spec):

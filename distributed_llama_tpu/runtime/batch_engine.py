@@ -181,6 +181,22 @@ _ATTN_PAIRS_VISITED = metrics.counter(
     "hold its committed length (ops/pallas_paged_attention.visited_keys; "
     "the chunk's own T x T fold is in neither this nor the dispatched "
     "count). Equal to the dispatched count where the kernel does not run")
+_ATTN_WINDOW_PAIRS_VISITED = metrics.counter(
+    "batch_attn_window_pairs_visited_total",
+    "batch_attn_pairs_visited_total's count for the layers with a sliding "
+    "window alone, summed over those layers (not averaged): T x the keys of "
+    "the steps the kernel runs for each row, none wholly behind the window's "
+    "lower bound. Nothing where no layer has a window")
+_ATTN_WINDOW_PAIRS_UNWINDOWED = metrics.counter(
+    "batch_attn_window_pairs_unwindowed_total",
+    "What the same layers would have visited with no window: the steps up "
+    "to the row's committed length. The ratio of the two is the share of "
+    "the window layers' key traffic that the skip leaves")
+_ATTN_HEADS = metrics.gauge(
+    "batch_attn_heads",
+    "Query heads of an attention layer by kind of layer ('window' where it "
+    "has a sliding window, 'full' where not; a model of one head count "
+    "reports it under the kinds it has)", labelnames=("kind",))
 _ATTN_PAIRS_REAL = metrics.counter(
     "batch_attn_pairs_real_total",
     "Query-key pairs causal attention needs: for each real position, its "
@@ -633,6 +649,13 @@ class BatchEngine:
         wins = spec.layer_window()
         self._layer_windows = [(w, wins.count(w) / len(wins))
                                for w in sorted(set(wins))]
+        # the layers with a window, as (window, how many layers have it)
+        self._window_layers = [(w, wins.count(w))
+                               for w in sorted(set(wins)) if w]
+        heads = ([spec.kinds[k].n_heads for k in spec.layer_kinds]
+                 or [spec.n_heads] * spec.n_layers)
+        for h, w in zip(heads, wins):
+            _ATTN_HEADS.labels(kind="window" if w else "full").set(h)
         self.kv_pool = None  # DeviceKVPool metadata (None = dense layout)
         self._kv_bt = 0
         if self._eng.kv_pool is not None:
@@ -1883,12 +1906,23 @@ class BatchEngine:
                     for w, share in getattr(self, "_layer_windows",
                                             ((0, 1.0),)))
 
+            # the committed lengths the kernel sees, each `times` over: a
+            # row's own, once a position; in a scan, one a (row, step)
             if budget is None:
-                visited = positions * sum(keys(st) for st in starts)
+                lengths, times = starts, positions
             else:
-                visited = sum(keys(st + min(i, b))
-                              for st, b in zip(starts, budget)
-                              for i in range(positions))
+                lengths, times = [st + min(i, b)
+                                  for st, b in zip(starts, budget)
+                                  for i in range(positions)], 1
+            visited = times * sum(keys(n) for n in lengths)
+            # the layers with a window alone, summed over them: what the
+            # skip leaves, and what they would visit without it
+            for w, layers in getattr(self, "_window_layers", ()):
+                _ATTN_WINDOW_PAIRS_VISITED.inc(layers * times * sum(
+                    visited_keys(n, n_read, bt, max(n - w + 1, 0))
+                    for n in lengths))
+                _ATTN_WINDOW_PAIRS_UNWINDOWED.inc(layers * times * sum(
+                    visited_keys(n, n_read, bt) for n in lengths))
         else:  # the gather path and the dense cache read the whole window
             visited = dispatched * window
         _ATTN_PAIRS_VISITED.inc(visited)

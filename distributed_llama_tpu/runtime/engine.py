@@ -24,8 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.params import (STACKS, Params, decode_stream_bytes, hold_dense,
-                             prepare_for_pallas)
+from ..models.params import (Params, decode_stream_bytes, hold_dense,
+                             prepare_for_pallas, stack_names)
 from ..models.spec import ModelSpec
 from ..obs import flight, metrics, trace
 from ..resilience import faults
@@ -178,8 +178,10 @@ class Engine:
                       and spec.seq_len > self.kv_resident)
         # cache kinds that hold per-head keys and values, or one stack of
         # layers, say so: no silent wrong path
-        if spec.latent or spec.lead_layers:
+        if spec.latent or spec.lead_layers or spec.kinds:
             what = ("a latent cache row (kv_lora_rank > 0)" if spec.latent
+                    else "kinds of attention layer (ModelSpec.kinds)"
+                    if spec.kinds
                     else "a leading dense stack (lead_layers > 0)")
             if self.paged:
                 raise ValueError(
@@ -236,7 +238,7 @@ class Engine:
         params = hold_dense(params, self.dtype)
         has_quant = any(
             getattr(t, "ftype", None) in (FloatType.Q40, FloatType.Q80)
-            for st in STACKS for t in params.get(st, {}).values())
+            for st in stack_names(params) for t in params[st].values())
         self.use_pallas = use_pallas and has_quant
         if use_pallas and not has_quant:
             # the start-up line named the policy before any checkpoint was

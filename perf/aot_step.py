@@ -40,7 +40,7 @@ from benchmark import cells  # noqa: E402
 from benchmark import weights as W  # noqa: E402
 from distributed_llama_tpu.models.forward import forward  # noqa: E402
 from distributed_llama_tpu.models.params import (  # noqa: E402
-    STACKS, hold_dense, prepare_for_pallas)
+    hold_dense, prepare_for_pallas, stack_names)
 from distributed_llama_tpu.ops.rope import RopeTables  # noqa: E402
 
 CUT = 4  # layers drawn: one period of any per-layer pattern in the cells
@@ -69,7 +69,11 @@ def model_shapes(config: str, chip, **keys):
     stack's leaves given the depth the spec states."""
     full = dict(cells.load_config(config), vocab_size=512, **keys)
     family = cells.load_family(full["family"])
-    if hasattr(family, "stacks"):
+    if hasattr(family, "one_layer_a_stack"):
+        # the family cuts itself: a layer of each stack, two experts a layer
+        # (the expert axis is re-shaped below, as the depth is)
+        cut = family.one_layer_a_stack(full, experts=2)
+    elif hasattr(family, "stacks"):
         # one layer of each stack: both hold the same tensors at any depth
         drawn = [(p, min(d, 1)) for p, d in family.stacks(full)]
         n = sum(d for _, d in drawn)
@@ -86,13 +90,17 @@ def model_shapes(config: str, chip, **keys):
         hold_dense(W.to_program_params(W.make_weights(cut, 7), cut),
                    jnp.bfloat16), spec=family.model_spec(cut))
     spec = family.model_spec(full)
-    depths = {"lead": spec.lead_layers, "blocks": spec.block_layers}
+    depths = {run.name: run.depth for run in spec.runs()}
     shapes = jax.tree.map(lambda a: _sds(a, chip), params)
-    for st in STACKS:
-        if st in params:
-            shapes[st] = jax.tree.map(
-                lambda a, n=depths[st]: _sds(a, chip, (n, *a.shape[1:])),
-                params[st])
+    for st in stack_names(params):
+        for name, t in params[st].items():
+            # the full depth, and where fewer experts were drawn than the
+            # file holds every expert (the router's rows with them)
+            wide = {"router": spec.n_router}.get(
+                name, spec.n_experts if name.startswith("moe_") else None)
+            shapes[st][name] = jax.tree.map(
+                lambda a, n=depths[st], e=wide: _sds(a, chip, (
+                    n, *((e,) if e else a.shape[1:2]), *a.shape[2:])), t)
     return spec, shapes, full
 
 

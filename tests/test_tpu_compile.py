@@ -298,13 +298,17 @@ def test_kernel_compiles_for_v5e(chip, case):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-# the three kinds of block pool the cells hold, each at its configuration's
+# the kinds of block pool the cells hold (and a fourth model on the first), each at its configuration's
 # widths and depth (fewer experts than the files hold: an expert's width is
 # kept, and the commit never sees their count)
 STEP_POOLS = {
     "hk8": ("mistral-7b", {}),
     "hk4": ("smallthinker-21b-a3b", {"moe_num_primary_experts": 8}),
     "latent": ("ax-k1-ep4-l7", {"n_routed_experts": 8}),
+    # three stacks of two kinds of layer: groups of 9 behind a window of 512
+    # and of 6 without, each kernel under its kind's name, the gate's
+    # (72, 3072) and (48, 3072) matrices through the dequant-matmul
+    "kinds": ("laguna-s-2.1-l5", {"num_experts": 16}),
 }
 # `jit_step` at T = 1 and at a 64-token chunk, and a 2-step decode scan with
 # the pools in its carry (`make_batched_decode_loop`'s form)
