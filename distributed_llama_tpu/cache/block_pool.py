@@ -23,6 +23,13 @@ the hot tier overflows its budget, the LRU hot blocks are demoted to Q80
 element count is not a multiple of the Q80 block size stay hot (never true
 for even head sizes).
 
+A block may be committed while its rows are still ON THEIR WAY from the
+device (`put_pending`: the device pool's demotion issues one batched read a
+reclaim and does not wait for it, docs/PAGED_KV.md "Eviction"). Such a block
+holds a `PendingRows` in place of arrays, counts against capacity at once and
+becomes plain hot arrays, with no further copy, at the first of `settle`,
+`get` or a Q80 compression that picks it.
+
 The pool never evicts on its own: cache/prefix_cache.py drives eviction
 through the radix index (which knows refcounts and LRU order) and calls
 `free` with the handles the tree surrenders. No internal lock for the same
@@ -37,7 +44,7 @@ import numpy as np
 
 from .wire import q80_compress, q80_compressible, q80_restore
 
-__all__ = ["HostKVArena", "KVBlockPool"]
+__all__ = ["HostKVArena", "KVBlockPool", "PendingRows"]
 
 
 class HostKVArena:
@@ -93,26 +100,62 @@ class HostKVArena:
         return self.k.nbytes + self.v.nbytes
 
 
-class _Block:
-    __slots__ = ("k", "v", "kq", "vq", "shape", "dtype", "seq")
+class PendingRows:
+    """One block's (K, V) rows read off the device and not yet waited for:
+    what `KVBlockPool.put_pending` takes in place of arrays. The reader that
+    issued the read implements it (runtime/batch_engine.py: one gather a
+    reclaim, each victim a row of its result)."""
 
-    def __init__(self, k: np.ndarray, v: np.ndarray, seq: int):
+    shape: tuple   # of the K side, (L, hk, block_tokens, hs)
+    dtype: np.dtype
+    nbytes: int    # both sides, once settled
+
+    def ready(self) -> bool:
+        """False while settle() would wait for the device."""
+        raise NotImplementedError
+
+    def settle(self) -> tuple[np.ndarray, np.ndarray]:
+        """The rows as host arrays the caller may keep (no copy is taken of
+        them); waits for the read if it has to. Raises what the read raised,
+        every time it is asked."""
+        raise NotImplementedError
+
+
+class _Block:
+    __slots__ = ("k", "v", "kq", "vq", "pending", "shape", "dtype", "seq")
+
+    def __init__(self, k: np.ndarray | None, v: np.ndarray | None, seq: int,
+                 pending: PendingRows | None = None):
         self.k = k            # hot: ndarray (L, hk, N, hs); None when cold
-        self.v = v
+        self.v = v            # or while `pending`
         self.kq = None        # cold: (values int8, scales f16) of the flat rows
         self.vq = None
-        self.shape = k.shape
-        self.dtype = k.dtype
+        self.pending = pending  # the rows' read, until it is settled
+        src = k if pending is None else pending
+        self.shape = src.shape
+        self.dtype = src.dtype
         self.seq = seq        # hot-LRU clock value of the last touch
 
     @property
     def cold(self) -> bool:
-        return self.k is None
+        return self.k is None and self.pending is None
 
     def nbytes(self) -> int:
+        if self.pending is not None:
+            return self.pending.nbytes
         if self.cold:
             return sum(q[0].nbytes + q[1].nbytes for q in (self.kq, self.vq))
         return self.k.nbytes + self.v.nbytes
+
+    def settle(self) -> None:
+        """Pending rows -> hot arrays; idempotent, and safe against a racing
+        second caller (both are handed the same arrays)."""
+        rows = self.pending
+        if rows is not None:
+            k, v = rows.settle()
+            if self.pending is rows:  # not compressed by a racing put()
+                self.k, self.v = k, v
+                self.pending = None
 
 
 class KVBlockPool:
@@ -155,7 +198,11 @@ class KVBlockPool:
         pool is at capacity (caller evicts via the radix index and retries)."""
         if self.full:
             return None
-        assert k.shape == v.shape
+        # the sides differ in their last axis alone: a latent row's second
+        # side is empty (the equal-shape assertion that stood here refused
+        # every latent pair, so a latent model's demotions ended as
+        # evictions until ISSUE 39)
+        assert k.shape[:-1] == v.shape[:-1]
         h = self._next_handle
         self._next_handle += 1
         self._blocks[h] = _Block(np.array(k, copy=True), np.array(v, copy=True),
@@ -163,9 +210,34 @@ class KVBlockPool:
         self._maybe_demote()
         return h
 
+    def put_pending(self, rows: PendingRows) -> int | None:
+        """put() for a block whose rows are still being read off the device:
+        the handle is valid at once, no copy is ever taken (the read's own
+        host arrays become the block's). None at capacity, as put()."""
+        if self.full:
+            return None
+        h = self._next_handle
+        self._next_handle += 1
+        self._blocks[h] = _Block(None, None, next(self._seq), pending=rows)
+        self._maybe_demote()
+        return h
+
+    def pending(self, handle: int) -> PendingRows | None:
+        """The block's unsettled read; None once its rows are host arrays
+        (or the handle was freed)."""
+        b = self._blocks.get(handle)
+        return b.pending if b is not None else None
+
+    def settle(self, handle: int) -> None:
+        """Make a pending block's rows host arrays now (waits for the read
+        if it must). Raises what the read raised; the block then stays
+        pending and its owner drops it. KeyError for a freed handle."""
+        self._blocks[handle].settle()
+
     def get(self, handle: int) -> tuple[np.ndarray, np.ndarray]:
         """Block data in its original dtype/shape; a cold block dequantizes
-        (Q80 round-trip precision, not bit-exact — see module docstring).
+        (Q80 round-trip precision, not bit-exact — see module docstring); a
+        pending one settles first (and raises what its read raised).
 
         Callers may read outside the facade lock (prefix_cache.lookup), so a
         concurrent demotion can clear b.k between a tier check and the read —
@@ -173,6 +245,7 @@ class KVBlockPool:
         they vanished (demotion assigns kq/vq BEFORE clearing k/v)."""
         b = self._blocks[handle]
         b.seq = next(self._seq)
+        b.settle()
         k, v = b.k, b.v
         if k is not None and v is not None:  # demotion may land between reads
             return k, v
@@ -202,6 +275,10 @@ class KVBlockPool:
         # tier over budget by at most one
         compressible = (b for b in hot if q80_compressible(b.shape))
         for b in heapq.nsmallest(excess, compressible, key=lambda b: b.seq):
+            try:
+                b.settle()  # a pending block's rows have to be here first
+            except Exception:
+                continue  # a failed read: its owner drops the block
             # cache/wire.py owns the round trip (shared with the disagg
             # wire codec so the tiers can never drift apart)
             b.kq = q80_compress(b.k)

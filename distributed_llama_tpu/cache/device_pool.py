@@ -25,6 +25,10 @@ the dense caches were); this module owns only the HOST metadata:
   (the same hot/Q80 tier + LRU the host prefix cache already had — one
   unified spill path, docs/PAGED_KV.md "Eviction"); a later hit on a
   ("cold", handle) node pays one host→device upload and promotes back.
+  A demotion does not wait for its device read: the node turns cold at
+  once over a PENDING payload (block_pool.PendingRows) that `settle()`
+  makes host arrays where the scheduler only waits. Victims come off two
+  LRU heaps kept as nodes are touched, so a reclaim costs what it frees.
 
 Locking: `DeviceKVPool` has its own lock (alloc/free/refs are touched from
 the scheduler thread and close()); the directory keeps the PrefixCache
@@ -33,6 +37,8 @@ convention of one lock over tree + tier state.
 
 from __future__ import annotations
 
+import heapq
+import itertools
 import threading
 
 import numpy as np
@@ -61,6 +67,15 @@ _DEMOTED = metrics.counter(
     "paged_kv_demoted_blocks_total",
     "Directory blocks demoted device->host under pool pressure (into the "
     "unified cache/block_pool.py tier)")
+_DEMOTE_READS = metrics.counter(
+    "paged_kv_demote_reads_total",
+    "Device reads issued for demotions (one batched gather a reclaim: reads "
+    "over paged_kv_demoted_blocks_total is 1/n for a deficit of n)")
+_SETTLE_WAITS = metrics.counter(
+    "paged_kv_demote_settle_waits_total",
+    "Demotion reads the device had not finished when their rows were needed "
+    "as host arrays (a hit, a Q80 compression, close): the settle waited for "
+    "the device. The scheduler's own settles take finished reads only")
 _PROMOTED = metrics.counter(
     "paged_kv_promoted_blocks_total",
     "Cold directory blocks promoted host->device on a prefix hit")
@@ -199,7 +214,17 @@ class PagedPrefixCache:
         self.radix = RadixIndex(block_tokens)
         self.cold = (KVBlockPool(cold_blocks, q80=q80)
                      if cold_blocks > 0 else None)
-        self._lock = threading.Lock()  # guards: radix, hits, misses, unused_hits, hit_tokens, resident_tokens, evicted_blocks, demoted, promoted, prompt_tokens
+        self._lock = threading.Lock()  # guards: radix, _lru, _unsettled, hits, misses, unused_hits, hit_tokens, resident_tokens, evicted_blocks, demoted, promoted, prompt_tokens
+        # LRU order without a tree walk: one heap a tier of (stamp, depth,
+        # tick, node), entered whenever an unreferenced node is stamped,
+        # released or changes tier, and checked against the node when popped
+        # (a re-stamped, pinned, moved or dropped node's entry is stale). One
+        # touch stamps one root path, so (stamp, depth) is the order the old
+        # whole-tree walk and sort gave: oldest stamp first, root first.
+        self._lru: dict[str, list] = {"dev": [], "cold": []}
+        self._tick = itertools.count()
+        # cold handle -> node, for demotions whose read is not settled yet
+        self._unsettled: dict[int, RadixNode] = {}
         self.hits = 0
         self.misses = 0
         self.unused_hits = 0
@@ -228,12 +253,15 @@ class PagedPrefixCache:
             if cap is not None:
                 n = min(n, cap)
             if n < 1:
+                self._queue(nodes)
                 self.misses += 1
                 from .prefix_cache import _MISSES
 
                 _MISSES.inc()
                 return None
-            nodes = nodes[:(n + self.block_tokens - 1) // self.block_tokens]
+            keep = (n + self.block_tokens - 1) // self.block_tokens
+            self._queue(nodes[keep:])  # matched (stamped) beyond the lease
+            nodes = nodes[:keep]
             self.radix.acquire(nodes)
         return PagedLease(nodes, n)
 
@@ -273,6 +301,7 @@ class PagedPrefixCache:
             lease.tokens = 0
             if nodes:
                 self.radix.release(nodes)
+                self._queue(nodes)
 
     def shrink(self, lease: PagedLease, n_tokens: int) -> None:
         if n_tokens >= lease.tokens:
@@ -283,6 +312,7 @@ class PagedPrefixCache:
             lease.tokens = max(n_tokens, 0)
             if drop:
                 self.radix.release(drop)
+                self._queue(drop)
 
     # ------------------------------------------------------------------
     # directory mutation
@@ -311,7 +341,7 @@ class PagedPrefixCache:
             return ("dev", block_ids[i])
 
         with self._lock:
-            self.radix.insert(blocked, make_handle)
+            self._queue(self.radix.insert(blocked, make_handle))
         _INSERTED.inc(created)
         return created
 
@@ -354,6 +384,7 @@ class PagedPrefixCache:
 
         with self._lock:
             chain = self.radix.insert(blocked, make_handle)
+            self._queue(chain)
         if dev_freed:
             # dev-tier descendants dropped with an evicted cold subtree
             # surrender their pool refs (same contract as reclaim())
@@ -372,60 +403,85 @@ class PagedPrefixCache:
             node.handle = ("dev", new_bid)
             if self.cold is not None:
                 self.cold.free(h)
+            self._unsettled.pop(h, None)
+            self._queue([node])
             self.promoted += 1
         _PROMOTED.inc()
 
+    def _queue(self, nodes) -> None:  # holds: self._lock
+        """Enter the unreferenced ones of `nodes` into their tier's LRU heap
+        under their current stamp (after a touch, a release, a tier change).
+        Stale entries go when popped, or here once they outnumber the tree
+        four to one: a filter of the heap itself, never a walk of the tree."""
+        for n in nodes:
+            heap = self._lru.get(n.handle[0])
+            if n.refs == 0 and heap is not None:
+                heapq.heappush(heap, (n.stamp, n.depth, next(self._tick), n))
+        for tier, heap in self._lru.items():
+            if len(heap) > 64 + 4 * self.radix.nodes:
+                heap[:] = [e for e in heap if self._current(tier, e)]
+                heapq.heapify(heap)
+
+    @staticmethod
+    def _current(tier: str, entry) -> bool:
+        stamp, _depth, _tick, node = entry
+        return (node.handle[0] == tier and node.stamp == stamp
+                and node.refs == 0)
+
+    def _pop_lru(self, tier: str, seen: set) -> RadixNode | None:  # holds: self._lock
+        """The least recently used unreferenced node of `tier` that this
+        call has not been handed yet; the caller re-queues what it leaves
+        in the tree."""
+        heap = self._lru[tier]
+        while heap:
+            entry = heapq.heappop(heap)
+            node = entry[3]
+            if self._current(tier, entry) and id(node) not in seen:
+                seen.add(id(node))
+                return node
+        return None
+
     def reclaim(self, n_blocks: int, read_block) -> int:
         """Free up to n_blocks device blocks by demoting (or, with no cold
-        tier, evicting) LRU UNREFERENCED device-tier nodes. `read_block(bid)
-        -> (k, v)` host arrays (L, hk, bt, hs) performs the device→host copy
-        for demotion. Returns how many device blocks were released to the
-        pool's free list (shared blocks drop the directory's ref but stay
-        alive for the slots still holding them)."""
+        tier, evicting) LRU UNREFERENCED device-tier nodes. `read_block(bid)`
+        gives the block's rows for demotion: a block_pool.PendingRows (the
+        engine's: the read is issued after this returns, one gather for all
+        the victims, and nobody waits for it here) or a (k, v) pair of host
+        arrays (L, hk, bt, hs). The cold tier's room is made BEFORE a block
+        is read, so each victim is read once. Returns how many device blocks
+        were released to the pool's free list (shared blocks drop the
+        directory's ref but stay alive for the slots still holding them)."""
         with self._lock:
-            victims = []
-            stack = [self.radix.root]
-            while stack:
-                node = stack.pop()
-                stack.extend(node.children.values())
-                if (node is not self.radix.root and node.refs == 0
-                        and isinstance(node.handle, tuple)
-                        and node.handle[0] == "dev"):
-                    victims.append(node)
-            victims.sort(key=lambda v: v.stamp)
-            released = []
-            for node in victims:
-                if len(released) >= n_blocks:
+            released: list[int] = []
+            popped: list[RadixNode] = []
+            seen: set = set()
+            # keep going past victims that release nothing (a subtree drop
+            # aborted by a lease pin): stopping at the first n_blocks LRU
+            # nodes would let reclaimable younger nodes starve an allocation
+            # into a spurious KVPoolExhausted
+            while len(released) < n_blocks:
+                node = self._pop_lru("dev", seen)
+                if node is None:
                     break
-                # keep walking past victims that release nothing (a block
-                # still shared with a slot's table, or a subtree drop
-                # aborted by a lease pin) — slicing the LRU list up front
-                # would let reclaimable younger nodes starve an allocation
-                # into a spurious KVPoolExhausted
-                if node.handle[0] != "dev":
-                    continue  # already detached/demoted via an ancestor drop
+                popped.append(node)
                 bid = node.handle[1]
                 if self.cold is not None:
-                    try:
-                        k, v = read_block(bid)
-                        h = self.cold.put(k, v)
-                    except Exception:
-                        h = None  # demotion is best-effort; evict instead
-                    if h is None and len(self.cold) > 0:
+                    if self.cold.full:
                         # cold tier full: evict ITS LRU content first by
-                        # dropping the oldest cold-tier nodes outright (any
-                        # dev-tier descendants dropped with them surrender
+                        # dropping the oldest cold-tier node outright (any
+                        # dev-tier descendants dropped with it surrender
                         # their pool refs through `released` like every
                         # other eviction)
                         released.extend(self._evict_cold_locked(1))
                         if node.handle[0] != "dev":
                             continue  # the victim itself rode out with the
                             # dropped cold subtree (its ref is in released)
+                    h = None
+                    if not self.cold.full:
                         try:
-                            k, v = read_block(bid)
-                            h = self.cold.put(k, v)
+                            h = self._put_cold(read_block(bid), node)
                         except Exception:
-                            h = None
+                            h = None  # demotion is best-effort; evict instead
                     if h is not None:
                         node.handle = ("cold", h)
                         self.demoted += 1
@@ -437,10 +493,69 @@ class PagedPrefixCache:
                 # TREE, so drop this node and its whole subtree (descendants
                 # without this block are unreachable prefixes anyway).
                 released.extend(self._drop_subtree_locked(node))
+            self._queue(popped)  # demoted: the cold heap; left as it was
+            # (an aborted drop): the device heap again
             freed = 0
         if released:
             freed = self.pool.decref(released)
         return freed
+
+    def _put_cold(self, rows, node: RadixNode) -> int | None:  # holds: self._lock
+        from .block_pool import PendingRows
+
+        if not isinstance(rows, PendingRows):
+            return self.cold.put(*rows)
+        h = self.cold.put_pending(rows)
+        if h is not None:
+            self._unsettled[h] = node
+        return h
+
+    @property
+    def unsettled(self) -> int:
+        """Demoted blocks whose rows are not host arrays yet."""
+        with self._lock:
+            return len(self._unsettled)
+
+    def settle(self, force: bool = False) -> tuple[int, int]:
+        """Make pending demotions' rows host arrays: the ones whose read has
+        finished, or with `force` (close) all of them, waiting where it
+        must. Called where the scheduler only waits (between a dispatch's
+        launch and its fetch, and when idle); fetch_cold and the Q80 tier
+        settle a block themselves when they need it first. A read that
+        FAILED drops its node and the subtree under it: demotion is
+        best-effort, and the eviction it stood in for is what is left.
+        Returns (blocks settled, of which had to wait for the device)."""
+        with self._lock:
+            todo = [(h, self.cold.pending(h))
+                    for h in self._unsettled] if self._unsettled else []
+        done, failed, waited = [], [], 0
+        for h, rows in todo:
+            if rows is not None:
+                ready = rows.ready()
+                if not (ready or force):
+                    continue
+                try:
+                    self.cold.settle(h)
+                    waited += not ready
+                except KeyError:
+                    pass  # freed by another thread since the snapshot
+                except Exception:
+                    failed.append(h)
+                    continue
+            done.append(h)
+        released: list[int] = []
+        with self._lock:
+            for h in done:
+                self._unsettled.pop(h, None)
+            for h in failed:
+                node = self._unsettled.get(h)
+                if node is not None and node.handle == ("cold", h):
+                    # a pinned subtree aborts the drop: the entry stays and
+                    # the next settle tries again
+                    released.extend(self._drop_subtree_locked(node))
+        if released:
+            self.pool.decref(released)
+        return len(done), waited
 
     def _drop_subtree_locked(self, node: RadixNode) -> list[int]:  # holds: self._lock
         """Remove `node` and every descendant from the tree; returns the
@@ -466,8 +581,9 @@ class PagedPrefixCache:
                 dev_ids.append(h)
             elif tier == "cold" and self.cold is not None:
                 self.cold.free(h)
-            n.handle = ("dropped", None)  # a stale victims-list entry must
-            # not double-release this block (reclaim skips non-dev handles)
+                self._unsettled.pop(h, None)
+            n.handle = ("dropped", None)  # its LRU entries are stale now: a
+            # block must not be released twice
         return dev_ids
 
     def _evict_cold_locked(self, n: int) -> list[int]:  # holds: self._lock
@@ -476,26 +592,25 @@ class PagedPrefixCache:
         dev-tier descendants dropped with them — the caller must decref
         those into the pool, or the blocks leak (their directory refs die
         with the nodes)."""
-        cold_nodes = []
-        stack = [self.radix.root]
-        while stack:
-            node = stack.pop()
-            stack.extend(node.children.values())
-            if (node is not self.radix.root and node.refs == 0
-                    and isinstance(node.handle, tuple)
-                    and node.handle[0] == "cold"):
-                cold_nodes.append(node)
-        cold_nodes.sort(key=lambda v: v.stamp)
+        popped: list[RadixNode] = []
+        seen: set = set()
         dev_ids: list[int] = []
-        for node in cold_nodes[:n]:
-            if node.handle[0] == "cold":  # not already dropped via ancestor
-                dev_ids.extend(self._drop_subtree_locked(node))
+        while len(popped) < n:
+            node = self._pop_lru("cold", seen)
+            if node is None:
+                break
+            popped.append(node)
+            dev_ids.extend(self._drop_subtree_locked(node))
+        self._queue(popped)  # a drop a lease aborted stays the LRU node
         return dev_ids
 
     def fetch_cold(self, handle: int):
         """Host rows of a cold block (dequantized when Q80) — the upload
         payload for promotion. Outside the lock (Q80 dequantize must not
-        stall lookups; the caller's lease pins the node)."""
+        stall lookups; the caller's lease pins the node). A block whose
+        demotion is still pending settles here, waiting for its read if it
+        must; a read that failed raises (the caller falls back to prefill,
+        the next settle() drops the node)."""
         assert self.cold is not None
         return self.cold.get(handle)
 
@@ -504,6 +619,8 @@ class PagedPrefixCache:
         pool was rebuilt, every dev handle is stale)."""
         with self._lock:
             self.radix = RadixIndex(self.block_tokens)
+            self._lru = {"dev": [], "cold": []}
+            self._unsettled.clear()  # pending reads are of the old arrays
             if self.cold is not None:
                 for h in list(self.cold._blocks):
                     self.cold.free(h)
@@ -549,6 +666,7 @@ class PagedPrefixCache:
                 "promoted_blocks": self.promoted,
                 "tree_nodes": self.radix.nodes,
                 "dev_blocks": dev_nodes, "cold_blocks": cold_nodes,
+                "unsettled_blocks": len(self._unsettled),
                 "pool_blocks": self.pool.n_blocks,
                 "pool_free_blocks": self.pool.free_blocks(),
                 "block_tokens": self.block_tokens,

@@ -31,7 +31,8 @@ __all__ = ["RadixIndex", "RadixNode"]
 
 
 class RadixNode:
-    __slots__ = ("key", "parent", "children", "handle", "refs", "stamp")
+    __slots__ = ("key", "parent", "children", "handle", "refs", "stamp",
+                 "depth")
 
     def __init__(self, key: tuple[int, ...] | None, parent: "RadixNode | None",
                  handle: int | None = None):
@@ -41,6 +42,9 @@ class RadixNode:
         self.handle = handle    # opaque block-pool handle (None only at the root)
         self.refs = 0           # in-flight leases pinning this block
         self.stamp = 0          # LRU clock value of the last touch
+        # blocks from the root. One touch stamps one root path, so nodes of
+        # equal stamp differ in depth: (stamp, depth) is a total LRU order
+        self.depth = parent.depth + 1 if parent is not None else 0
 
 
 class RadixIndex:

@@ -69,6 +69,7 @@ from typing import Callable
 import jax.numpy as jnp
 import numpy as np
 
+from ..cache.block_pool import PendingRows
 from ..models.spec import ModelSpec
 from ..obs import flight, metrics, reqctx, trace
 from ..ops.pallas_paged_attention import visited_keys
@@ -368,6 +369,137 @@ _pool_block_copy = jax.jit(lambda c, src, dst: c.at[:, dst].set(c[:, src]),
 _pool_block_set = jax.jit(lambda c, dst, rows: c.at[:, dst].set(rows),
                           donate_argnums=(0,))
 
+# The prefix cache's demotion reads a reclaim's victims with ONE gather from
+# both sides of the pool, (n, L, hk, bt, w) a side, block-major so that a
+# block's rows are one contiguous piece of the host copy. n is one of a few
+# fixed sizes (a shorter list of ids is filled with the scratch block, a
+# longer one is cut into eights), so every program it can need is known
+# beforehand: BatchEngine._read_block compiles them all.
+_DEMOTE_SIZES = (1, 2, 4, 8)
+
+
+@jax.jit
+def _pool_gather(sides, ids):
+    return tuple(jnp.swapaxes(c[:, ids], 0, 1) for c in sides)
+
+
+def _start_host_copy(*arrays) -> None:
+    """Begin the arrays' device->host copies without waiting for them, so
+    that a later np.asarray picks the buffers up. A hint only: e.g. a
+    sharded array may refuse the whole-array async copy."""
+    for a in arrays:
+        try:
+            a.copy_to_host_async()
+        except Exception:
+            pass
+
+
+class _DemoteRead:
+    """One reclaim's read of its victims off the device (docs/PAGED_KV.md
+    "Eviction"). The directory asks for a block at a time while it chooses
+    (`block`), every answer a pending row of this read; `issue` then
+    enqueues the gather, before the dispatch that will write the freed
+    blocks, so the device's own order keeps the rows intact, and starts the
+    copy to the host without waiting for it; `settle` makes host arrays of
+    it, once, wherever the rows are first needed."""
+
+    def __init__(self, pool):
+        """`pool`: the engine's (K, V) arrays, (L, N, hk, bt, w) a side; what
+        a block of them looks like is kept, the arrays are not (the next
+        dispatch donates them)."""
+        k = pool[0]
+        self.shape = k.shape[:1] + k.shape[2:]  # a block's K side
+        self.dtype = np.dtype(k.dtype)
+        self.nbytes = sum(c.nbytes // c.shape[1] for c in pool)  # a block's
+        self._widths = tuple(c.shape[-1] for c in pool)  # 0: an empty side
+        self.bids: list[int] = []
+        self._parts = None  # a gather each: the non-empty sides, on the device
+        self._host = None  # a gather each: (k, v) host arrays (n, L, hk, bt, w)
+        self._error: Exception | None = None
+        self._lock = threading.Lock()  # guards: _host, _error, _parts (two threads may settle: the scheduler, and an importer's whose Q80 put compresses a pending block)
+
+    def block(self, bid: int) -> "_DemotedRows":
+        self.bids.append(bid)
+        return _DemotedRows(self, len(self.bids) - 1)
+
+    def issue(self, pool) -> int:
+        """Enqueue the gathers (one, unless there are more than eight
+        victims) over `pool`, the engine's (K, V) arrays, and start their
+        host copies; returns how many. A failure here is the read's: every
+        block of it is dropped when settled."""
+        top = _DEMOTE_SIZES[-1]
+        sides = tuple(c for c in pool if c.shape[-1])
+        parts, error = [], None
+        try:
+            for lo in range(0, len(self.bids), top):
+                ids = self.bids[lo:lo + top]
+                n = next(z for z in _DEMOTE_SIZES if z >= len(ids))
+                out = _pool_gather(
+                    sides, np.asarray(ids + [0] * (n - len(ids)), np.int32))
+                _start_host_copy(*out)
+                parts.append(out)
+        except Exception as e:
+            error = e
+        with self._lock:
+            self._parts, self._error = parts, error
+        return len(parts)
+
+    def ready(self) -> bool:
+        with self._lock:
+            parts = self._parts
+        if parts is None:
+            return False  # not issued yet
+        return all(a.is_ready() for out in parts for a in out)
+
+    def settle(self) -> list:
+        ready = self.ready()
+        with self._lock:
+            if self._error is not None:
+                raise self._error
+            if self._host is None:
+                if self._parts is None:
+                    raise RuntimeError("demotion read settled before issue")
+                if not ready:  # whoever asks (a hit, Q80, close) waits
+                    from ..cache.device_pool import _SETTLE_WAITS
+
+                    _SETTLE_WAITS.inc()
+                try:
+                    host = []
+                    for out in self._parts:
+                        # the demotion's one device->host copy, started
+                        # at issue: picked up here, where the host waits
+                        got = [np.asarray(a) for a in out]
+                        void = np.zeros(got[0].shape[:-1] + (0,), got[0].dtype)
+                        rest = iter(got)
+                        host.append(tuple(next(rest) if w else void
+                                          for w in self._widths))
+                except Exception as e:
+                    self._error = e
+                    raise
+                finally:
+                    self._parts = []  # the device copies can go
+                self._host = host
+            return self._host
+
+
+class _DemotedRows(PendingRows):
+    """Row `i` of a _DemoteRead: one block's pending (K, V) rows, what the
+    cold tier holds in place of arrays until they are settled."""
+
+    __slots__ = ("read", "i", "shape", "dtype", "nbytes")
+
+    def __init__(self, read: _DemoteRead, i: int):
+        self.read, self.i = read, i
+        self.shape, self.dtype, self.nbytes = read.shape, read.dtype, read.nbytes
+
+    def ready(self) -> bool:
+        return self.read.ready()
+
+    def settle(self):
+        part, row = divmod(self.i, _DEMOTE_SIZES[-1])
+        k, v = self.read.settle()[part]
+        return k[row], v[row]
+
 
 class _StaleEpoch(BaseException):
     """Raised inside an ABANDONED scheduler thread (recover_wedged bumped the
@@ -658,6 +790,7 @@ class BatchEngine:
             _ATTN_HEADS.labels(kind="window" if w else "full").set(h)
         self.kv_pool = None  # DeviceKVPool metadata (None = dense layout)
         self._kv_bt = 0
+        self._demote_warm = False  # _read_block compiled the gather's sizes
         if self._eng.kv_pool is not None:
             from ..cache.device_pool import DeviceKVPool
 
@@ -1324,6 +1457,11 @@ class BatchEngine:
             t = self._thread
         if t is not None:
             t.join(timeout=30)
+        if t is None or not t.is_alive():
+            # the directory outlives the device arrays: what it holds of
+            # them as pending reads becomes host arrays now (not behind a
+            # scheduler that is still stuck in a dispatch)
+            self._settle_demotions(force=True)
         # detach the watchdog callback IF it is still ours (a later engine
         # may have claimed the gauge): a bound method left on the
         # module-global gauge would pin this engine's params + KV caches
@@ -1662,7 +1800,7 @@ class BatchEngine:
         if deficit <= 0:
             return
         if self.prefix_cache is not None:
-            self.prefix_cache.reclaim(deficit, self._read_block)
+            self._demote(deficit)
         if self.kv_pool.free_blocks() >= need:
             return
         for sl in self._slots:
@@ -1671,11 +1809,58 @@ class BatchEngine:
                 if self.kv_pool.free_blocks() >= need:
                     return
 
+    def _demote(self, deficit: int) -> None:
+        """Have the directory demote (or evict) `deficit` blocks. The
+        victims' rows are read by ONE gather, enqueued here, ahead of
+        whatever dispatch will write the freed blocks, and NOT waited for:
+        the cold tier holds the pending read and _settle_demotions makes
+        host arrays of it while a later dispatch runs."""
+        pool = (self._eng.k_cache, self._eng.v_cache)
+        read = _DemoteRead(pool)
+        with trace.span("batch.demote") as sp:
+            self.prefix_cache.reclaim(deficit, read.block)
+            reads = read.issue(pool) if read.bids else 0
+            sp.add(blocks=len(read.bids), reads=reads)
+        if reads:
+            from ..cache.device_pool import _DEMOTE_READS
+
+            _DEMOTE_READS.inc(reads)
+
+    def _settle_demotions(self, force: bool = False) -> None:
+        """Where the scheduler only waits (a dispatch launched and not yet
+        fetched; idle; close): pending demotions whose read has finished
+        become host arrays. Never raises: the cache is an optimization."""
+        pc = self.prefix_cache
+        if self.kv_pool is None or pc is None or not pc.unsettled:
+            return
+        with trace.span("batch.demote_settle") as sp:
+            try:
+                blocks, waited = pc.settle(force)
+                sp.add(blocks=blocks, waited=waited)
+            except Exception as e:
+                from ..cache import warn_degraded
+
+                warn_degraded("demotion", e)
+
     def _read_block(self, bid: int):
-        """Device→host copy of one pool block's rows (L, hk, bt, hs) — the
-        directory's demotion payload."""
-        eng = self._eng
-        return np.asarray(eng.k_cache[:, bid]), np.asarray(eng.v_cache[:, bid])
+        """Device→host copy of one pool block's rows (L, hk, bt, hs), waited
+        for: the disaggregation export's read. It goes through the
+        demotion's gather, and its first call on an engine compiles that
+        gather at every size a reclaim can issue, so that no eviction ever
+        compiles in the middle of serving (the benchmark's warm-up calls it
+        for that)."""
+        pool = (self._eng.k_cache, self._eng.v_cache)
+        if not self._demote_warm:
+            self._demote_warm = True
+            for n in _DEMOTE_SIZES[1:]:
+                warm = _DemoteRead(pool)
+                warm.bids = [0] * n
+                warm.issue(pool)
+                warm.settle()
+        read = _DemoteRead(pool)
+        rows = read.block(bid)
+        read.issue(pool)
+        return rows.settle()
 
     def _paged_ensure(self, slot: _Slot, upto: int) -> None:
         """Grow the slot's table so every position < upto has a real block
@@ -1988,6 +2173,7 @@ class BatchEngine:
                     *args, *(() if tables is None else (tables,)))
                 if logits.shape[1] > 2:
                     logits = _first_last(logits)  # [:, 0] and [:, -1] hold
+            self._settle_demotions()  # the host only waits from here on
             # the wait for the device and the copy of every row's logits
             with trace.span("batch.fetch", {"bytes": logits.nbytes}):
                 out = np.asarray(logits)
@@ -2544,6 +2730,7 @@ class BatchEngine:
                 # enqueue latency is set by the notify, not this number.
                 # 0.1 s also bounds queue-TTL/deadline detection while idle.
                 self._gap_t = None  # an idle device is not a starved one
+                self._settle_demotions()
                 with self._cond, trace.span("batch.wait"):
                     if self._queue.empty() and not self._shutdown:
                         self._cond.wait(timeout=0.1)
@@ -3201,6 +3388,7 @@ class BatchEngine:
                     slot.clamp_pos = self.spec.seq_len - 1
                 nxt = self._issue_super_step(rows, self.superstep, budget,
                                              starts, chain=fl)
+        self._settle_demotions()  # the host waits for `fl` from here on
         with trace.span("batch.deliver"):
             try:
                 status = self._deliver_super_step(fl)
@@ -3352,11 +3540,7 @@ class BatchEngine:
                  eng.v_cache, *moe) = self._dispatched("super_step", call)
                 cst = None
         _PIPELINE_DEPTH.set(2 if chain is not None else 1)
-        for a in (toks, rng_out):
-            try:  # start the non-blocking host copy now; delivery's
-                a.copy_to_host_async()  # np.asarray picks the buffer up
-            except Exception:  # an optimization hint only — e.g. dp-sharded
-                pass  # outputs may refuse the whole-array async copy
+        _start_host_copy(toks, rng_out)  # delivery's np.asarray picks them up
         return _InflightStep(rows, k, starts, budget, temps, toks, tok, pos,
                              rng_out, t_issue, chain is not None, window,
                              cstate=cst, moe=moe[0] if moe else None)

@@ -20,6 +20,7 @@ import time
 import numpy as np
 import pytest
 
+from distributed_llama_tpu.cache.block_pool import PendingRows
 from distributed_llama_tpu.cache.device_pool import (DeviceKVPool,
                                                      KVPoolExhausted,
                                                      PagedPrefixCache,
@@ -438,3 +439,496 @@ def test_paged_attn_bench_parity_gate():
         assert r["xla_vs_dense_bit_exact"]
         assert r["kernel_max_abs_err"] < 2e-5
         assert r["greedy_pick_agree"]
+
+
+# ------------------------------------------- the deferred demotion (ISSUE 39)
+#
+# A reclaim's victims are read by ONE gather that nobody waits for; the cold
+# tier holds the pending read and it is settled where the scheduler only
+# waits. Held here to the per-block path this replaced, which is kept below
+# as the reference: WHICH blocks are demoted, in which order, when a node
+# turns cold and what rows a later hit uploads must not have changed.
+
+TOYS = ("tiny-dense", "tiny-axk1", "tiny-laguna")
+
+
+@pytest.fixture(scope="module", params=TOYS)
+def toy(request):
+    """(name, spec, params) of a toy configuration: dense keys and values,
+    a latent row with an empty second side, two kinds of layer."""
+    from benchmark import cells
+    from benchmark import weights as W
+
+    # (a context of 256: the pool's floor is a whole context of blocks)
+    cfg = {**cells.load_config(request.param), "context": 256}
+    weights = W.make_weights(cfg, 2**31 + 39)
+    spec = cells.load_family(cfg["family"]).model_spec(cfg)
+    assert spec.seq_len == 256
+    return request.param, spec, W.to_program_params(weights, cfg)
+
+
+def _engine(toy, **kw):
+    import jax.numpy as jnp
+
+    _, spec, params = toy
+    kw = {"slots": 2, "superstep": 4, "kv_block_tokens": 16,
+          "kv_pool_blocks": 20, "prefix_cache_blocks": 5, "tp": 1,
+          "dtype": jnp.float32, **kw}
+    return BatchEngine(spec, params, None, **kw)
+
+
+def _reference_reclaim(pc, n_blocks, read_block):
+    """PagedPrefixCache.reclaim as it was before ISSUE 39, kept as the
+    reference: walk and sort the whole tree, read a block, put it, and on a
+    full cold tier evict one cold subtree and read the block AGAIN."""
+    from distributed_llama_tpu.cache.device_pool import _DEMOTED
+
+    def walk(tier):
+        out, stack = [], [pc.radix.root]
+        while stack:
+            node = stack.pop()
+            stack.extend(node.children.values())
+            if (node is not pc.radix.root and node.refs == 0
+                    and node.handle[0] == tier):
+                out.append(node)
+        out.sort(key=lambda v: v.stamp)
+        return out
+
+    def evict_cold(n):
+        dev_ids = []
+        for node in walk("cold")[:n]:
+            if node.handle[0] == "cold":
+                dev_ids.extend(pc._drop_subtree_locked(node))
+        return dev_ids
+
+    with pc._lock:
+        released = []
+        for node in walk("dev"):
+            if len(released) >= n_blocks:
+                break
+            if node.handle[0] != "dev":
+                continue
+            bid = node.handle[1]
+            if pc.cold is not None:
+                h = pc.cold.put(*read_block(bid))
+                if h is None and len(pc.cold) > 0:
+                    released.extend(evict_cold(1))
+                    if node.handle[0] != "dev":
+                        continue
+                    h = pc.cold.put(*read_block(bid))
+                if h is not None:
+                    node.handle = ("cold", h)
+                    pc.demoted += 1
+                    _DEMOTED.inc()
+                    released.append(bid)
+                    continue
+            released.extend(pc._drop_subtree_locked(node))
+    return pc.pool.decref(released) if released else 0
+
+
+def _as_reference(be, reads):
+    """Put the per-block path into `be`: the old reclaim over the old
+    synchronous reader, two slices and two copies a block."""
+    eng, pc = be._eng, be.prefix_cache
+
+    def read_block(bid):
+        reads.append(bid)
+        return np.asarray(eng.k_cache[:, bid]), np.asarray(eng.v_cache[:, bid])
+
+    be._demote = lambda deficit: _reference_reclaim(pc, deficit, read_block)
+
+
+def _directory(pc):
+    """Every node in LRU order: (chain of keys, tier, stamp, rows)."""
+    out, stack = [], [(pc.radix.root, ())]
+    while stack:
+        node, chain = stack.pop()
+        for key, child in node.children.items():
+            stack.append((child, chain + (key,)))
+        if node is not pc.radix.root:
+            tier, h = node.handle
+            rows = pc.cold.get(h) if tier == "cold" else None
+            out.append((node.stamp, node.depth, chain, tier, rows))
+    out.sort(key=lambda e: e[:2])
+    return out
+
+
+def _traffic(vocab):
+    """A seeded sequence that fills a pool of 20 blocks several times over:
+    distinct prompts of 3 to 6 blocks, and repeats of two earlier ones so
+    that demoted blocks are hit and promoted."""
+    rng = np.random.default_rng(39)
+    prompts = [rng.integers(3, vocab, int(n)).tolist()
+               for n in rng.integers(50, 100, 8)]
+    order = [0, 1, 2, 3, 0, 4, 5, 1, 6, 7, 4]
+    return [prompts[i] + [5 + j] for j, i in enumerate(order)]
+
+
+def test_demotion_leaves_the_reference_directory(toy):
+    """(a) The same requests through the deferred path and through the
+    per-block reference leave the same tokens, the same pool and the same
+    directory: every node's tier and LRU place, every cold block's rows bit
+    for bit the device rows the reference read synchronously."""
+    from distributed_llama_tpu.obs import metrics
+
+    outs, dirs, refs, reads = [], [], [], []
+    for reference in (False, True):
+        be = _engine(toy)
+        if reference:
+            _as_reference(be, reads)
+        before = metrics.snapshot()
+        try:
+            outs.append([_run(be, p, 6, vocab=toy[1].vocab_size)
+                         for p in _traffic(toy[1].vocab_size)])
+            _settle(lambda: be.prefix_cache.total_refs() == 0)
+            dirs.append(_directory(be.prefix_cache))
+            refs.append(be.kv_pool.refcounts())
+            st = be.prefix_cache.stats()
+        finally:
+            be.close()
+        moved = {k: v - before.get(k, 0) for k, v in metrics.snapshot().items()
+                 if k.startswith("paged_kv_demote")}
+        if not reference:
+            assert st["demoted_blocks"] >= 20 and st["promoted_blocks"] >= 3
+            # one read a reclaim: fewer reads than blocks, never more
+            assert 0 < moved["paged_kv_demote_reads_total"] \
+                < moved["paged_kv_demoted_blocks_total"], moved
+    assert outs[0] == outs[1]
+    assert np.array_equal(refs[0], refs[1])
+    assert len(dirs[0]) == len(dirs[1]) > 0
+    for got, want in zip(dirs[0], dirs[1]):
+        assert got[:4] == want[:4]
+        if want[4] is not None:
+            for g, w in zip(got[4], want[4]):
+                assert g.dtype == w.dtype and np.array_equal(g, w)
+    # the reference read a victim twice whenever the cold tier was full
+    assert len(reads) > len(set(reads))
+
+
+def test_hit_on_a_pending_payload_promotes_the_right_rows(toy):
+    """(b) A prompt whose blocks were demoted and NOT yet settled: the hit
+    settles them, uploads the rows the device held, and the tokens are
+    those of a run without the cache."""
+    vocab = toy[1].vocab_size
+    prompt = np.random.default_rng(7).integers(3, vocab, 70).tolist()
+    plain = _engine(toy, prefix_cache=False)
+    try:
+        want = [_run(plain, prompt + [9, 8], 6, vocab=vocab)]
+    finally:
+        plain.close()
+    be = _engine(toy, prefix_cache_blocks=8)
+    try:
+        _run(be, prompt, 4, vocab=vocab)
+        _settle(lambda: be.prefix_cache.total_refs() == 0)
+        pc, eng = be.prefix_cache, be._eng
+        lease = pc.lookup(prompt + [9])
+        held = {n.handle[1]: None for n in lease.nodes}
+        pc.release(lease)
+        assert len(held) == 4
+        for bid in held:
+            held[bid] = (np.asarray(eng.k_cache[:, bid]),
+                         np.asarray(eng.v_cache[:, bid]))
+        be._settle_demotions = lambda force=False: None  # nobody settles
+        be._paged_reclaim(be.kv_pool.n_blocks)
+        assert pc.unsettled == 4 and pc.stats()["cold_blocks"] == 4
+        got = [_run(be, prompt + [9, 8], 6, vocab=vocab)]
+        assert got == want
+        assert pc.stats()["promoted_blocks"] == 4
+        lease = pc.lookup(prompt + [9])
+        for node, (k, v) in zip(lease.nodes, held.values()):
+            tier, bid = node.handle
+            assert tier == "dev"
+            assert np.array_equal(np.asarray(eng.k_cache[:, bid]), k)
+            assert np.array_equal(np.asarray(eng.v_cache[:, bid]), v)
+        pc.release(lease)
+    finally:
+        be.close()
+
+
+def _fill_directory(be, n_nodes, seed=0):
+    """n_nodes unreferenced device-tier nodes, one chain of one block each,
+    over freshly written pool blocks."""
+    import jax.numpy as jnp
+
+    eng, bt = be._eng, be._kv_bt
+    ids = be.kv_pool.alloc(n_nodes)
+    rng = np.random.default_rng(seed)
+    eng.k_cache = eng.k_cache.at[:, np.asarray(ids)].set(jnp.asarray(
+        rng.standard_normal((eng.k_cache.shape[0], n_nodes)
+                            + eng.k_cache.shape[2:]), eng.k_cache.dtype))
+    for i, b in enumerate(ids):
+        be.prefix_cache.insert_blocks([1000 * (i + 1) + j
+                                       for j in range(bt)], [b])
+    be.kv_pool.decref(ids)
+    return ids
+
+
+def test_no_program_compiles_for_reclaims_of_1_to_8_blocks(toy):
+    """(e) After the constructor and `_read_block(0)`, which is what the
+    benchmark's warm-up calls, a reclaim of any size compiles nothing
+    (counted as benchmark/run.py counts)."""
+    import jax
+
+    be = _engine(toy, kv_pool_blocks=64, prefix_cache_blocks=64)
+    compiled = []
+
+    def on(event, seconds, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(seconds)
+
+    try:
+        _fill_directory(be, 50)
+        be._read_block(0)
+        jax.monitoring.register_event_duration_secs_listener(on)
+        for n in range(1, 9):
+            free = be.kv_pool.free_blocks()
+            be._demote(n)
+            assert be.kv_pool.free_blocks() == free + n
+        be._demote(11)  # more than the largest size: two gathers
+        assert be.prefix_cache.settle(force=True)[0] == 47
+        assert not compiled
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on)
+        be.close()
+
+
+def test_reset_and_close_with_payloads_pending(toy):
+    """(g) close() leaves the cold tier holding host arrays alone, equal to
+    the device rows; reset() forgets pending payloads with the directory."""
+    be = _engine(toy, kv_pool_blocks=32, prefix_cache_blocks=16)
+    try:
+        ids = _fill_directory(be, 6)
+        pc, eng = be.prefix_cache, be._eng
+        want = {b: (np.asarray(eng.k_cache[:, b]), np.asarray(eng.v_cache[:, b]))
+                for b in ids}
+        nodes = {n.handle[1]: n for n in pc.radix.root.children.values()}
+        be._demote(6)
+        assert pc.unsettled == 6
+        be.close()
+        assert pc.unsettled == 0
+        for b, node in nodes.items():
+            tier, h = node.handle
+            assert tier == "cold" and pc.cold.pending(h) is None
+            k, v = pc.fetch_cold(h)
+            assert np.array_equal(k, want[b][0]) and k.shape == want[b][0].shape
+            assert np.array_equal(v, want[b][1]) and v.shape == want[b][1].shape
+    finally:
+        be.close()
+    be = _engine(toy, kv_pool_blocks=32, prefix_cache_blocks=16)
+    try:
+        _fill_directory(be, 6)
+        be._demote(4)
+        assert be.prefix_cache.unsettled == 4
+        be.kv_pool.reset()
+        be.prefix_cache.reset()
+        pc = be.prefix_cache
+        assert pc.unsettled == 0 and len(pc.cold) == 0 and pc.radix.nodes == 0
+        assert pc.settle(force=True) == (0, 0)
+        assert be.kv_pool.free_blocks() == 31
+    finally:
+        be.close()
+
+
+class _Rows(PendingRows):
+    """A block's pending rows as the engine's reader would hand them out."""
+
+    def __init__(self, k, v, fail=False):
+        self.k, self.v, self.fail = k, v, fail
+        self.shape, self.dtype = k.shape, k.dtype
+        self.nbytes = k.nbytes + v.nbytes
+        self.settled = 0
+
+    def ready(self):
+        return True
+
+    def settle(self):
+        self.settled += 1
+        if self.fail:
+            raise OSError("the device read failed")
+        return self.k, self.v
+
+
+def _chains(pc, pool, n_chains, depth, bt=4):
+    """n_chains x depth unreferenced device nodes; returns {bid: chain}."""
+    where = {}
+    for c in range(n_chains):
+        ids = pool.alloc(depth)
+        toks = [c * 100000 + i for i in range(depth * bt)]
+        pc.insert_blocks(toks, ids)
+        pool.decref(ids)
+        where.update({b: c for b in ids})
+    return where
+
+
+def test_full_cold_tier_reads_each_victim_once():
+    """(c) With the cold tier full, room is made BEFORE a victim is read:
+    one read a demoted block (the per-block path read it, was refused,
+    evicted and read it again)."""
+    pool = DeviceKVPool(64, 4)
+    pc = PagedPrefixCache(pool, 4, cold_blocks=3, q80=False)
+    _chains(pc, pool, 10, 2)
+    reads = []
+
+    def read_block(bid):
+        reads.append(bid)
+        return _Rows(np.full((1, 1, 4, 8), float(bid), np.float32),
+                     np.full((1, 1, 4, 8), -float(bid), np.float32))
+
+    for _ in range(6):
+        assert pc.reclaim(2, read_block) >= 2
+    st = pc.stats()
+    assert st["demoted_blocks"] == len(reads) == len(set(reads)) == 12
+    # (an evicted cold root takes its cold child with it: 2 or 3 are left)
+    left = st["cold_blocks"]
+    assert 2 <= left == len(pc.cold) == pc.unsettled <= 3
+    assert pc.settle() == (left, 0) and pc.unsettled == 0
+    for h in list(pc.cold._blocks):
+        k, v = pc.cold.get(h)
+        assert k[0, 0, 0, 0] == -v[0, 0, 0, 0] and k[0, 0, 0, 0] in reads
+    # device blocks: used + free conserved, nothing leaked
+    assert pool.used_blocks() + pool.free_blocks() == 63
+    assert pool.used_blocks() == st["dev_blocks"] == 20 - 12
+    assert st["evicted_blocks"] == 12 - left
+
+
+def test_failed_read_at_settle_evicts_the_subtree_and_leaks_nothing():
+    """(d) A read that raises when it is settled drops its node and the
+    subtree under it, device-tier descendants included, as the eviction it
+    stood in for would have; a lease on the subtree only postpones it."""
+    pool = DeviceKVPool(32, 4)
+    pc = PagedPrefixCache(pool, 4, cold_blocks=8, q80=False)
+    where = _chains(pc, pool, 2, 3)
+    made = {}
+
+    def read_block(bid):
+        made[bid] = _Rows(np.zeros((1, 1, 4, 8), np.float32),
+                          np.zeros((1, 1, 4, 8), np.float32),
+                          fail=where[bid] == 0)
+        return made[bid]
+
+    # LRU order is chain 0 root-first, then chain 1: demote the two roots'
+    # worth (chain 0's first block, whose read will fail, and its child)
+    assert pc.reclaim(2, read_block) == 2
+    assert [where[b] for b in made] == [0, 0] and pc.unsettled == 2
+    lease = pc.lookup(list(range(12)) + [0])  # pins the chain: the drop waits
+    assert [n.handle[0] for n in lease.nodes] == ["cold", "cold", "dev"]
+    assert pc.settle() == (0, 0) and pc.unsettled == 2 and pc.radix.nodes == 6
+    with pytest.raises(OSError):
+        pc.fetch_cold(lease.nodes[1].handle[1])  # the hit falls back to prefill
+    pc.mark_unused(lease)
+    assert pc.settle() == (0, 0)
+    assert pc.unsettled == 0 and pc.radix.nodes == 3 and len(pc.cold) == 0
+    assert pool.used_blocks() == 3 and pool.free_blocks() == 31 - 3
+    assert pc.reclaim(3, read_block) == 3 and pc.settle() == (3, 0)
+    assert pool.used_blocks() == 0 and pc.stats()["cold_blocks"] == 3
+
+
+def test_reclaim_of_one_does_not_walk_the_tree():
+    """(f) Victims come off an LRU kept as nodes change: reclaim(1) on a
+    tree of 5000 nodes enumerates no node's children (counted, not timed),
+    and still takes the least recently used block."""
+    pool = DeviceKVPool(5002, 4)
+    pc = PagedPrefixCache(pool, 4, cold_blocks=64, q80=False)
+    where = _chains(pc, pool, 50, 100)
+    assert pc.radix.nodes == 5000
+    visits = []
+
+    class Counting(dict):
+        def values(self):
+            visits.append(1)
+            return dict.values(self)
+
+    stack = [pc.radix.root]
+    while stack:
+        node = stack.pop()
+        stack.extend(node.children.values())
+        node.children = Counting(node.children)
+    taken = []
+
+    def read_block(bid):
+        taken.append(bid)
+        return (np.zeros((1, 1, 4, 8), np.float32),) * 2
+
+    for _ in range(20):
+        assert pc.reclaim(1, read_block) == 1
+    assert not visits
+    # chain 0 is the oldest touch; root first within it
+    first = [b for b, c in where.items() if c == 0][:20]
+    assert taken == first
+    # a lookup of chain 0 makes chain 1 the oldest; the heaps stay bounded
+    for _ in range(200):
+        pc.release(pc.lookup([i for i in range(400)] + [7]))
+    assert pc.reclaim(1, read_block) == 1 and where[taken[-1]] == 1
+    assert not visits
+    assert all(len(h) <= 64 + 4 * pc.radix.nodes for h in pc._lru.values())
+
+
+def test_q80_tier_settles_a_pending_block_before_it_compresses_it():
+    """The Q80 tier compresses at put(): a pending block it picks is settled
+    first, and a block whose read FAILED is passed over and dropped by the
+    next settle()."""
+    pool = DeviceKVPool(16, 4)
+    pc = PagedPrefixCache(pool, 4, cold_blocks=8, q80=True)  # 2 stay hot
+    _chains(pc, pool, 5, 1)
+    made = []
+
+    def read_block(bid):
+        rng = np.random.default_rng(bid)
+        made.append(_Rows(rng.standard_normal((1, 2, 4, 16), np.float32),
+                          rng.standard_normal((1, 2, 4, 16), np.float32),
+                          fail=len(made) == 1))
+        return made[-1]
+
+    assert pc.reclaim(5, read_block) == 5
+    # five puts over a hot budget of two: the three oldest were picked, the
+    # second of them failed its read and stays pending
+    assert [r.settled for r in made] == [1, 2, 1, 0, 0]
+    assert pc.cold.demoted_blocks == 2 and pc.cold.hot_count() == 3
+    handles = sorted(pc.cold._blocks)
+    assert [pc.cold.pending(h) is not None for h in handles] == [
+        False, True, False, True, True]
+    k, v = pc.cold.get(handles[0])
+    assert np.allclose(k, made[0].k, atol=0.05) and not np.array_equal(k, made[0].k)
+    assert pc.settle() == (4, 0)  # the failed one is dropped, not settled
+    assert pc.unsettled == 0 and pc.radix.nodes == 4 and len(pc.cold) == 4
+    k, v = pc.cold.get(handles[4])
+    assert np.array_equal(k, made[4].k) and np.array_equal(v, made[4].v)
+    assert pool.used_blocks() == 0
+
+
+def test_demotion_with_the_pool_sharded_over_kv_heads():
+    """tp = 2: the pool is sharded over hk and so is the gather's result;
+    a hit on the pending payload uploads the rows the device held."""
+    spec = _spec(seq_len=128)
+    params = init_random_params(spec, FloatType.Q40, seed=11)
+    prompt = SHARED[:33]
+    plain = BatchEngine(spec, params, slots=2, tp=2, superstep=4,
+                        kv_block_tokens=8, prefix_cache=False)
+    try:
+        want = _run(plain, prompt + [77], 6)
+    finally:
+        plain.close()
+    be = BatchEngine(spec, params, slots=2, tp=2, superstep=4,
+                     kv_block_tokens=8, kv_pool_blocks=20,
+                     prefix_cache_blocks=8)
+    try:
+        _run(be, prompt, 4)
+        _settle(lambda: be.prefix_cache.total_refs() == 0)
+        pc, eng = be.prefix_cache, be._eng
+        assert len(eng.k_cache.sharding.device_set) == 2
+        lease = pc.lookup(prompt + [77])
+        held = [(np.asarray(eng.k_cache[:, n.handle[1]]),
+                 np.asarray(eng.v_cache[:, n.handle[1]])) for n in lease.nodes]
+        pc.release(lease)
+        be._settle_demotions = lambda force=False: None
+        be._paged_reclaim(be.kv_pool.n_blocks)
+        assert pc.unsettled == len(held) == 4
+        assert _run(be, prompt + [77], 6) == want
+        lease = pc.lookup(prompt + [77])
+        for node, (k, v) in zip(lease.nodes, held):
+            assert node.handle[0] == "dev"
+            assert np.array_equal(np.asarray(eng.k_cache[:, node.handle[1]]), k)
+            assert np.array_equal(np.asarray(eng.v_cache[:, node.handle[1]]), v)
+        pc.release(lease)
+    finally:
+        be.close()
