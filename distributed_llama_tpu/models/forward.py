@@ -44,7 +44,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -122,11 +122,75 @@ def _window_key_positions(start_pos, win: int, t: int, stale: int):
         [slot_pos, start_pos[:, None] + jnp.arange(t)[None, :]], axis=1)
 
 
+# A compact residual stream is whole row tiles of the weight kernels
+ROW_TILE = 8
+
+
+def compact_rows(t: int, slots: int) -> int:
+    """Rows of the compact residual stream of a mixed (slots, t) dispatch:
+    one slot's t tokens and one row a slot, rounded up to whole tiles."""
+    return -(-(t + slots) // ROW_TILE) * ROW_TILE
+
+
+class RowMap(NamedTuple):
+    """Which rows of a mixed (slots, t) dispatch hold a token, t > 1: ONE
+    slot (`lead`) prefills t tokens, every other slot has at most a token at
+    index 0 (a decode row riding the chunk; a parked row's is scratch). The
+    residual stream is then COMPACT, (1, R, dim) with R = compact_rows(t,
+    slots): rows [0, t) the lead slot's chunk, row t + b slot b's index 0
+    (the lead's own is its first token once more), zero rows up to R. Every
+    weight runs over R rows; attention and the commit alone see the
+    (slots, t) rectangle, laid out with a zero row wherever a position holds
+    nothing. forward() without a map is the rectangle throughout."""
+    slots: int
+    t: int
+    lead: jax.Array  # int32 scalar: the prefilling slot's index
+    positions: jax.Array  # (1, R): each compact row's position
+
+    @classmethod
+    def of(cls, lead, positions):
+        """The map of a dispatch from its (slots, t) rectangle of positions."""
+        rows = cls(*positions.shape, lead, None)
+        return rows._replace(positions=rows.compact(positions))
+
+    def compact(self, a):
+        """(slots, t, ...) of the rectangle -> (1, R, ...)."""
+        pad = compact_rows(self.t, self.slots) - self.t - self.slots
+        chunk = jax.lax.dynamic_index_in_dim(a, self.lead, 0, keepdims=False)
+        return jnp.concatenate(
+            [chunk, a[:, 0], jnp.zeros((pad, *a.shape[2:]), a.dtype)])[None]
+
+    def lay_out(self, a):
+        """(1, R, ...) -> the (slots, t, ...) rectangle, zeros elsewhere: a
+        select between the chunk, broadcast over the slots, the rows of
+        index 0 and zero, ONE elementwise pass that writes the layout the
+        attention kernel reads. (Neither a dynamic-update-slice of the
+        chunk at the lead slot nor a pad of the rows of index 0: XLA gave
+        either a layout with the slots minor-most, 0.36 ms a
+        dynamic-update-slice and a copy of the rectangle behind it, 38 of a
+        dense chunk's 60 ms; PERF.md section 6, PR 41.)"""
+        a = a[0]
+        chunk, first = a[:self.t], a[self.t:self.t + self.slots]
+        is_lead = jnp.arange(self.slots, dtype=jnp.int32) == self.lead
+        at_0 = jnp.arange(self.t, dtype=jnp.int32) == 0
+        return jnp.where(
+            is_lead.reshape(-1, *[1] * a.ndim), chunk[None],
+            jnp.where(at_0.reshape(1, -1, *[1] * (a.ndim - 1)),
+                      first[:, None], jnp.zeros((), a.dtype)))
+
+    def sampled(self, x):
+        """(1, R, dim) -> (slots, 1, dim): the one position a slot the host
+        samples from, the lead slot's last and every other slot's index 0."""
+        at = jnp.arange(self.slots, dtype=jnp.int32)
+        at = jnp.where(at == self.lead, self.t - 1, self.t + at)
+        return jnp.take(x[0], at, axis=0)[:, None]
+
+
 def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, start_pos,
                positions, axis_name, sp_axis_name, sp_size, use_pallas, compress,
                window, paged_cold=None, block_tables=None, block_tokens=0,
                paged_kernel=False, residual=None, rope_on=None, swa=None,
-               kind_name=None):
+               kind_name=None, rows=None):
     """Sharded attention sub-block against the FULL stacked caches, which it
     only READS: they are loop-invariant operands of the layer scan, the
     chunk's own k/v are attended from registers, and the new rows
@@ -165,6 +229,12 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
     attn_out is ALREADY residual-joined (residual + wo-projection, after the
     TP merge): callers must not re-add.
 
+    rows: the `RowMap` of a compact stream. x, the projections, the rotation,
+    the gate and wo are then on its (1, R) rows; q, k and v are laid out to
+    the (slots, t) rectangle for the cache read below and for the commit,
+    which are what they are without a map, and the attention output is taken
+    back to R rows. `positions` and `start_pos` are the rectangle's always.
+
     Head counts in bp may be TP-local slices. The READ covers only the first
     `window` positions (a static bucket >= pos+T chosen by the caller), so
     cache HBM traffic scales with the live context, not the allocated
@@ -172,7 +242,7 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
     attention loop runs 0..pos (llama2-tasks.cpp:62-93); with XLA's static
     shapes the window bucket is the equivalent lever.
     """
-    b, t, _ = x.shape
+    cb, ct, _ = x.shape  # the stream's rows: (1, R) under a row map
     hs = spec.head_size
     _, _, hk, s, _ = kc.shape
     xb = rmsnorm(x, bp["rms_att"], spec.norm_eps)
@@ -200,14 +270,18 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
         v = qmatmul(xb, bp["wv"], use_pallas=use_pallas)
     hq_local = q.shape[-1] // hs
     hk_local = k.shape[-1] // hs
-    q = q.reshape(b, t, hq_local, hs)
-    k = k.reshape(b, t, hk_local, hs)
+    q = q.reshape(cb, ct, hq_local, hs)
+    k = k.reshape(cb, ct, hk_local, hs)
+    at = positions if rows is None else rows.positions
     if rope_on is None:
-        q, k = apply_rope(q, rope, positions), apply_rope(k, rope, positions)
+        q, k = apply_rope(q, rope, at), apply_rope(k, rope, at)
     else:
-        q = jnp.where(rope_on > 0, apply_rope(q, rope, positions), q)
-        k = jnp.where(rope_on > 0, apply_rope(k, rope, positions), k)
-    v = v.reshape(b, t, hk_local, hs)
+        q = jnp.where(rope_on > 0, apply_rope(q, rope, at), q)
+        k = jnp.where(rope_on > 0, apply_rope(k, rope, at), k)
+    v = v.reshape(cb, ct, hk_local, hs)
+    if rows is not None:
+        q, k, v = (rows.lay_out(a) for a in (q, k, v))
+    b, t = q.shape[:2]
     # lowest key position each query reads: 0 on a layer without a window
     key_lo = None if swa is None else jnp.where(
         swa > 0, jnp.maximum(positions - swa + 1, 0), 0)
@@ -327,9 +401,11 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
             att = gqa_attention(q, kfull, vfull, positions, key_positions=key_pos,
                                 key_lo=key_lo)
     att = att.astype(x.dtype)
+    if rows is not None:
+        att = rows.compact(att)
     if gate is not None:
-        att = (att.reshape(b, t, hq_local, hs).astype(jnp.float32)
-               * gate[..., None]).astype(x.dtype).reshape(b, t, hq_local * hs)
+        att = (att.reshape(cb, ct, hq_local, hs).astype(jnp.float32)
+               * gate[..., None]).astype(x.dtype).reshape(cb, ct, -1)
     # col-parallel wo: local heads x local input slice -> partial (B, T, dim); psum merges
     y = _maybe_psum(qmatmul(att, bp["wo"], use_pallas=use_pallas), axis_name,
                     compress)
@@ -339,7 +415,7 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
 def _latent_attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc,
                       vc, start_pos, positions, axis_name, use_pallas,
                       compress, window, block_tables=None, block_tokens=0,
-                      paged_kernel=False, residual=None):
+                      paged_kernel=False, residual=None, rows=None):
     """Latent attention (spec.latent; the DeepSeek-V3 graph) in its ABSORBED
     form, against a cache of ONE row a token a layer, which it only reads:
 
@@ -359,10 +435,13 @@ def _latent_attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc,
     pool (the `latent_paged_attention` kernel or its XLA twin) and the
     contiguous cache; the engine refuses the others for a latent spec.
     Returns as _attention: (residual-joined output, the chunk's rows for
-    forward() to commit, the second side empty)."""
+    forward() to commit, the second side empty). `rows`, as there: the
+    projections, w_uk, w_uv and wo on the compact rows, q' and the cache row
+    laid out to the rectangle for the read and the commit, the context taken
+    back."""
     from ..ops.attention import latent_attention
 
-    b, t, _ = x.shape
+    cb, ct, _ = x.shape  # the stream's rows: (1, R) under a row map
     dn, dr, r = spec.qk_nope_head_dim, spec.qk_rope_head_dim, spec.kv_lora_rank
     width = kc.shape[-1]
     s = kc.shape[3]
@@ -370,11 +449,12 @@ def _latent_attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc,
     qa = rmsnorm(qmatmul(xb, bp["wq_a"], use_pallas=use_pallas), bp["rms_q"],
                  spec.norm_eps)
     q = qmatmul(qa, bp["wq_b"], use_pallas=use_pallas)
-    q = q.reshape(b, t, q.shape[-1] // (dn + dr), dn + dr)
+    q = q.reshape(cb, ct, q.shape[-1] // (dn + dr), dn + dr)
     kv = qmatmul(xb, bp["wkv_a"], use_pallas=use_pallas)  # (B, T, r + dr)
     c = rmsnorm(kv[..., :r], bp["rms_kv"], spec.norm_eps)
-    k_pe = apply_rope(kv[..., None, r:], rope, positions)[..., 0, :]
-    q_pe = apply_rope(q[..., dn:], rope, positions)
+    at = positions if rows is None else rows.positions
+    k_pe = apply_rope(kv[..., None, r:], rope, at)[..., 0, :]
+    q_pe = apply_rope(q[..., dn:], rope, at)
     q_lat = jnp.einsum("bthd,hdc->bthc", q[..., :dn],
                        bp["w_uk"].astype(x.dtype),
                        preferred_element_type=jnp.float32).astype(x.dtype)
@@ -384,6 +464,9 @@ def _latent_attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc,
 
     qf = to_row_width([q_lat, q_pe])  # (B, T, H, width)
     row = to_row_width([c, k_pe]).astype(kc.dtype)  # (B, T, width)
+    if rows is not None:
+        qf, row = rows.lay_out(qf), rows.lay_out(row)
+    b, t = row.shape[:2]
     scale = spec.attn_scale
     with jax.named_scope("latent_attn"):
         if block_tables is not None:
@@ -405,10 +488,12 @@ def _latent_attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc,
             ctx = latent_attention(
                 qf, jnp.concatenate([kw, row], axis=1), positions,
                 _window_key_positions(start_pos, win, t, s + 1), r, scale)
+    if rows is not None:
+        ctx = rows.compact(ctx)
     att = jnp.einsum("bthc,hdc->bthd", ctx.astype(x.dtype),
                      bp["w_uv"].astype(x.dtype),
                      preferred_element_type=jnp.float32).astype(x.dtype)
-    y = _maybe_psum(qmatmul(att.reshape(b, t, -1), bp["wo"],
+    y = _maybe_psum(qmatmul(att.reshape(cb, ct, -1), bp["wo"],
                             use_pallas=use_pallas), axis_name, compress)
     rows_t = row[:, None]  # (B, 1, T, width): one kv head
     return ((y if residual is None else residual + y),
@@ -618,7 +703,7 @@ def _block(x, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions,
            axis_name, sp_axis_name, sp_size, use_pallas, compress, window,
            kc, vc, paged_cold=None, block_tables=None, block_tokens=0,
            paged_kernel=False, stacks=None, routed=None, layer_base=0,
-           kind_name=None):
+           kind_name=None, rows=None):
     """One transformer block as a scan step: the carry is x, the caches kc/vc
     are read-only closures (loop invariants), and the ys are the layer's new
     K/V rows, for forward() to commit in one top-level write, with the
@@ -629,6 +714,9 @@ def _block(x, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions,
     stack's FFN is the expert layer (None: as the spec says; a leading dense
     stack says False); `layer_base`: the stack's first layer, which the
     caches are indexed from (the weights by the index within the stack).
+    `rows`: the `RowMap` of a compact stream x, which the attention call
+    alone lays out to the rectangle; the K/V rows of the ys are the
+    rectangle's.
     """
     stats = jnp.zeros((N_MOE_STATS,), jnp.int32)  # of the expert layer: a ys
     # the layer's kind rides in the xs beside its index, where the model has
@@ -658,7 +746,7 @@ def _block(x, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions,
                 x, bp, cache_idx, spec, rope, kc, vc, start_pos, positions,
                 axis_name, use_pallas, compress, window,
                 block_tables=block_tables, block_tokens=block_tokens,
-                paged_kernel=paged_kernel, residual=res_attn)
+                paged_kernel=paged_kernel, residual=res_attn, rows=rows)
         else:
             attn_out, (k_t, v_t) = _attention(
                 x, bp, cache_idx, spec, rope, kc, vc, start_pos, positions,
@@ -666,7 +754,7 @@ def _block(x, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions,
                 window, paged_cold=paged_cold, block_tables=block_tables,
                 block_tokens=block_tokens, paged_kernel=paged_kernel,
                 residual=res_attn, rope_on=rope_on, swa=swa,
-                kind_name=kind_name)
+                kind_name=kind_name, rows=rows)
     if spec.arch_type == ArchType.GROK1:
         # grok: residual-join the *normalized* attention output (grokRmfFfn/Norm/Join)
         x = x + rmsnorm(attn_out, bp["rms_ffn"], spec.norm_eps)
@@ -763,6 +851,19 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
     later tokens overwrite them. The batched decode scan
     (runtime/device_loop.py) parks finished rows on the same invariant.
 
+    A caller that knows ONE row prefills and every other row holds at most
+    its index 0 says so with one entry more: start_pos (B + 1,), the last the
+    prefilling row's index (T > 1). The residual stream is then compact
+    (`RowMap`): the embedding, the norms, every weight, the router and the
+    expert layer run over compact_rows(T, B) rows, 72 for (8, 64), and only
+    the attention call and the commit see the (B, T) rectangle, a zero row
+    wherever a position holds nothing, so a real position's K/V and output
+    are what the rectangle computes. The head runs on ONE position a row,
+    the prefilling row's last and every other row's index 0: logits
+    (B, 1, vocab). Without the entry every position is taken to be real
+    (the T = 1 step, the scan, a verify block, one sequence): those programs
+    have no map in them.
+
     The caches are loop-INVARIANT operands of the layer scan (read-only, see
     _attention); each layer's new K/V rows leave as ys ((L, B, hk, T, hs),
     tiny) and ONE write per cache commits them after the scan, by cache kind:
@@ -781,9 +882,16 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
     if axis_name is not None:
         params = _localize_qtensors(params)
     start_pos = jnp.asarray(start_pos)
+    rows = lead = None  # the prefilling row of a mixed dispatch, if it says
+    if start_pos.ndim == 1 and start_pos.shape[0] == tokens.shape[0] + 1:
+        assert t > 1, "a row map is for a chunk: at T = 1 every row is whole"
+        lead, start_pos = start_pos[-1], start_pos[:-1]
     if start_pos.ndim == 1:
         assert sp_size == 1, "per-row start_pos is not supported with sp (ring) sharding"
         positions = start_pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]  # (B, T)
+        if lead is not None:
+            rows = RowMap.of(lead, positions)
+            tokens = rows.compact(tokens)
     else:
         positions = start_pos + jnp.arange(t, dtype=jnp.int32)
     x = jnp.take(params["embedding"], tokens, axis=0).astype(dtype)
@@ -810,7 +918,7 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
     def scan_stack(x, blocks, depth, spec=spec, rope=rope, **kind):
         """One `lax.scan` over a stack of `depth` like layers."""
         stacks = {n: w for n, w in blocks.items()
-                  if reads_the_stack(w, tokens.shape[0] * t, use_pallas)}
+                  if reads_the_stack(w, tokens.size, use_pallas)}
         block_fn = functools.partial(
             _block, spec=spec, rope=rope, start_pos=start_pos,
             positions=positions, axis_name=axis_name,
@@ -819,7 +927,7 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
             window=attn_window, kc=k_cache, vc=v_cache,
             paged_cold=paged_cold, block_tables=block_tables,
             block_tokens=block_tokens, paged_kernel=paged_kernel,
-            stacks=stacks, **kind)
+            stacks=stacks, rows=rows, **kind)
         xs = ({n: w for n, w in blocks.items() if n not in stacks},
               jnp.arange(depth, dtype=jnp.int32))
         if "kind_name" not in kind and (spec.rope_layers
@@ -890,6 +998,8 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
         k_cache = row_write(k_cache, k_rows, start_pos)
         v_cache = row_write(v_cache, v_rows, start_pos)
 
+    if rows is not None:
+        x = rows.sampled(x)  # the head at the positions that are sampled
     x = rmsnorm(x, params["rms_final"], spec.norm_eps)
     logits = qmatmul(x, params["wcls"], use_pallas=use_pallas, out_dtype=jnp.float32)
     if axis_name is not None:

@@ -22,7 +22,10 @@ Design:
   chunk real tokens, each decode row carries its next token at index 0 (its
   remaining positions are scratch writes on masked future slots), and each
   decode row's logits read from index 0. One dispatch advances the prefill
-  chunk AND every active sequence by one token.
+  chunk AND every active sequence by one token. The program is told which row
+  prefills and runs its weights over the chunk and one row a slot, not over
+  the (B, chunk) rectangle (models/forward.py RowMap); its head runs at the
+  one position a row that is sampled.
 - Idle rows ride along with their start_pos parked at their current position: their
   cache writes land at future positions that are masked now and overwritten when those
   positions actually decode, so no masking program is needed.
@@ -70,6 +73,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..cache.block_pool import PendingRows
+from ..models.forward import compact_rows
 from ..models.spec import ModelSpec
 from ..obs import flight, metrics, process, reqctx, trace
 from ..ops.pallas_paged_attention import visited_keys
@@ -122,16 +126,18 @@ _PARKED_ROW_STEPS = metrics.counter(
 # window bucket; real ones hold a token of a request, with its causal length.
 _POSITIONS_DISPATCHED = metrics.counter(
     "batch_positions_dispatched_total",
-    "Token positions the dispatched programs computed: slots x T of a step "
-    "or verify block, slots x K of a scan, parked rows and padding included")
+    "Rows the dispatched programs ran their weights over: slots x T of a "
+    "step or verify block, slots x K of a scan, a prefill chunk's T + slots "
+    "up to whole row tiles; parked rows and padding included")
 _POSITIONS_REAL = metrics.counter(
     "batch_positions_real_total",
     "Dispatched positions that held a token of a request: a prefill chunk's "
     "tokens, one per rider or active row, a row's budget in a scan or verify")
 _ATTN_PAIRS_DISPATCHED = metrics.counter(
     "batch_attn_pairs_dispatched_total",
-    "Query-key pairs the attention kernel was asked for: dispatched "
-    "positions x the window bucket (the context length where unbucketed)")
+    "Query-key pairs the attention kernel was asked for: slots x T "
+    "positions, a prefill chunk's too, x the window bucket (the context "
+    "length where unbucketed)")
 _MOE_COUNTERS = tuple(metrics.counter(name, doc) for name, doc in (
     ("batch_moe_assignments_total",
      "Expert assignments the routed layers were given: dispatched rows x "
@@ -385,13 +391,6 @@ _CONSTRAIN_DEGRADED = metrics.counter(
 # whole new pool array per block touched — O(pool) HBM traffic and 2x peak
 # memory. Donating the pool lets XLA update the one block in place.
 import jax  # noqa: E402  (after the module docstring's import block)
-
-# The host reads two positions of a (B, T, vocab) block of logits: a prefill
-# row's last and a rider's first. They are sliced out on the device and only
-# they are fetched: a 64-token chunk's block is 65.5 MB at a vocabulary of
-# 32000 (20 to 23 ms to copy) and 311 MB at 151936 (110 to 136 ms, as long as
-# the dispatch: PERF.md section 6, PR 29).
-_first_last = jax.jit(lambda l: jnp.stack([l[:, 0], l[:, -1]], axis=1))
 
 _pool_block_copy = jax.jit(lambda c, src, dst: c.at[:, dst].set(c[:, src]),
                            donate_argnums=(0,))
@@ -2110,16 +2109,22 @@ class BatchEngine:
                 time.sleep(min(delay, 1.0))
                 delay *= 2
 
-    def _stage(self, tokens_rows: list[list[int]], starts: list[int], t: int):
+    def _stage(self, tokens_rows: list[list[int]], starts: list[int], t: int,
+               lead: int | None = None):
         """Host half of one batched (B, t) step, under the caller's
         `batch.build` span: the window bucket, its program, and the inputs
         on the device. Returns the keys attention runs against (the bucket,
-        or the context length where there is none) and what _step takes."""
+        or the context length where there is none) and what _step takes.
+        `lead`: the one row that prefills, where every other row holds at
+        most its index 0; it rides behind the rows' positions, and the
+        program then runs its weights over the rows that can hold a token
+        (models/forward.py `RowMap`)."""
         eng = self._eng
         window = eng._window_for(max(s + t for s in starts))
         with trace.span("batch.stage") as sp:
             toks = _upload(np.asarray(tokens_rows, dtype=np.int32))
-            start_pos = _upload(np.asarray(starts, dtype=np.int32))
+            start_pos = _upload(np.asarray(
+                starts if lead is None else starts + [lead], dtype=np.int32))
             tables, resent = None, False
             if self.kv_pool is not None:
                 resent = self._tables_dev is None
@@ -2132,15 +2137,19 @@ class BatchEngine:
 
     def _count_work(self, positions: int, window: int,
                     real: list[tuple[int, int]], starts: list[int],
-                    budget: list[int] | None = None) -> None:
+                    budget: list[int] | None = None,
+                    computed: int | None = None) -> None:
         """One dispatch's useful-work counters: `positions` per row were
         dispatched against `window`; `real` lists (start, n) for each run of
         n request tokens from position start (causal length start + i + 1).
         `starts` is every row's committed length as the device was given it;
         `budget` (a K-step scan only: `positions` steps of one token) the
-        steps each row advances, its length growing by one a step."""
+        steps each row advances, its length growing by one a step.
+        `computed`: the rows the weight kernels ran over where the program's
+        residual stream was compact; attention runs the rectangle always."""
         dispatched = self.slots_n * positions
-        _POSITIONS_DISPATCHED.inc(dispatched)
+        computed = dispatched if computed is None else computed
+        _POSITIONS_DISPATCHED.inc(computed)
         _ATTN_PAIRS_DISPATCHED.inc(dispatched * window)
         if self._eng.paged_kernel:
             bt = self._kv_bt
@@ -2176,7 +2185,7 @@ class BatchEngine:
         _ATTN_PAIRS_VISITED.inc(visited)
         spec = getattr(self, "spec", None)
         if spec is not None and spec.is_moe:
-            _MOE_ROUTED.inc(dispatched * spec.n_active_experts
+            _MOE_ROUTED.inc(computed * spec.n_active_experts
                             * spec.block_layers)
         if spec is not None and spec.latent:
             if budget is None:  # once a dispatched row, whatever its T
@@ -2218,8 +2227,10 @@ class BatchEngine:
 
     def _step(self, staged, kind: str = "step"):
         """Dispatch one staged (B, t) step and wait for it; returns the
-        logits of the block's first and last positions, np.ndarray
-        (B, min(t, 2), vocab): callers read [:, 0] and [:, -1].
+        logits of the one position a row that is sampled, np.ndarray
+        (B, 1, vocab): the row's only position at t = 1, and of a chunk the
+        prefilling row's last and every other row's index 0 (the program's
+        head runs there alone). Callers read [row, 0] or [row, -1].
 
         Three phases, a span and an observation of
         batch_dispatch_phase_seconds each. `batch.launch`: the host calls
@@ -2247,8 +2258,6 @@ class BatchEngine:
                 # a routed model's program returns one value more
                 logits, kc, vc, *moe = step(
                     *args, *(() if tables is None else (tables,)))
-                if logits.shape[1] > 2:
-                    logits = _first_last(logits)  # [:, 0] and [:, -1] hold
                 # behind the program in the device's own order, where the
                 # fetch's np.asarray alone would have put it
                 _start_host_copy(logits, *moe)
@@ -3004,7 +3013,11 @@ class BatchEngine:
                             raise
                         self._fail_request(r, e)
                         riders.remove(r)
-            window, staged = self._stage(rows, starts, t)
+            # a chunk with scratch in it: the program is told which row
+            # prefills and runs its weights over the chunk and one row a slot
+            # (rows sharded over dp have no one stream to be compacted into)
+            lead = slot.index if t > 1 and self._eng.dp == 1 else None
+            window, staged = self._stage(rows, starts, t, lead)
         # the dispatch belongs to the prefilling request: bind its context
         # so the span (and any dispatch fault) carries its trace id
         with reqctx.use(slot.req.ctx), \
@@ -3024,7 +3037,9 @@ class BatchEngine:
             # rows neither prefilling nor riding spent this dispatch parked
             _PARKED_ROW_STEPS.inc(self.slots_n - 1 - len(riders))
             self._count_work(t, window, [(slot.pos, t)]
-                             + [(r.pos, 1) for r in riders], starts)
+                             + [(r.pos, 1) for r in riders], starts,
+                             computed=None if lead is None
+                             else compact_rows(t, self.slots_n))
             self.prefilled_tokens += t
             slot.pos += t
             slot.history.extend(piece)

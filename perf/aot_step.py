@@ -1,6 +1,6 @@
 """What the chip's compiler makes of a whole step program, without the chip.
 
-    JAX_PLATFORMS=cpu python3 perf/aot_step.py <configuration> [--rows 8] [--chunk 64] [--scan K]
+    JAX_PLATFORMS=cpu python3 perf/aot_step.py <configuration> [--rows 8] [--chunk 64] [--scan K] [--rectangle 1]
 
 Compiles `models/forward.forward` (kernels on, paged KV, bf16, the pools
 DONATED as the engines donate them: undonated, XLA copies each pool once
@@ -10,7 +10,11 @@ are drawn on the CPU for the parameter tree (four, or one of each stack of a
 family that has `stacks`) and the leaves of each stack are re-shaped to its
 full depth as `ShapeDtypeStruct`s, so nothing of the model's size is ever
 held. `--scan K` compiles K decode steps as one `lax.scan` with the pools in
-its carry, the form of `runtime/device_loop.make_batched_decode_loop`.
+its carry, the form of `runtime/device_loop.make_batched_decode_loop`. A
+chunk (`--chunk` > 1) is compiled as the scheduler dispatches one, told which
+row prefills, so that the weights run over `forward.compact_rows` rows (72 of
+8 x 64) and the head over one position a row; `--rectangle 1` leaves that
+entry out and compiles the program of a block whose every position is real.
 
 Prints the program's temporaries and every `copy`, `dynamic-slice` or fusion
 that RESULTS in packed weights (`u8[...]`), their scales (`s16[...]`) or an
@@ -38,13 +42,13 @@ from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from benchmark import cells  # noqa: E402
 from benchmark import weights as W  # noqa: E402
-from distributed_llama_tpu.models.forward import forward  # noqa: E402
+from distributed_llama_tpu.models.forward import (  # noqa: E402
+    compact_rows, forward)
 from distributed_llama_tpu.models.params import (  # noqa: E402
     hold_dense, prepare_for_pallas, stack_names)
 from distributed_llama_tpu.ops.rope import RopeTables  # noqa: E402
 
 CUT = 4  # layers drawn: one period of any per-layer pattern in the cells
-POOL_BLOCKS = 256
 _RESULT = re.compile(
     r"\s*(?:ROOT )?%?([\w.\-]+) = ((?:u8|s16|bf16)\[[\d,]+\])(\{[^}]*\})?")
 
@@ -104,17 +108,19 @@ def model_shapes(config: str, chip, **keys):
     return spec, shapes, full
 
 
-def pool_shape(spec, cfg, blocks: int = POOL_BLOCKS):
+def pool_shape(spec, cfg):
     """The two sides of the block pool (the second is empty for a latent
-    spec), as `runtime/engine.py` builds them."""
-    return [(spec.n_layers, blocks, spec.n_kv_heads,
+    spec), as `runtime/engine.py` builds them, of the cell's own blocks: a
+    pool of a few MB XLA moves to another memory space (`S(1)` in its
+    layout, Laguna's at 256 blocks under a compact chunk's smaller
+    temporaries) and the text reads as a copy the cell's program has not."""
+    return [(spec.n_layers, cfg["engine"]["kv_pool_blocks"], spec.n_kv_heads,
              cfg["engine"]["kv_block_tokens"], w) for w in spec.cache_widths]
 
 
-def held_pools(spec, cfg, blocks: int = POOL_BLOCKS):
+def held_pools(spec, cfg):
     """The distinct shapes among the pool's sides that hold anything."""
-    return list(dict.fromkeys(s for s in pool_shape(spec, cfg, blocks)
-                              if s[-1]))
+    return list(dict.fromkeys(s for s in pool_shape(spec, cfg) if s[-1]))
 
 
 def _bf16(shape) -> str:
@@ -122,12 +128,14 @@ def _bf16(shape) -> str:
 
 
 def compile_step(spec, shapes, cfg, chip, *, rows: int = 8, chunk: int = 64,
-                 scan: int = 0, blocks: int = POOL_BLOCKS):
+                 scan: int = 0, rectangle: bool = False):
     """The compiled step program: `jit_step` at `rows` x `chunk`, or with
-    `scan` K > 0 a K-step greedy decode scan with the pools in its carry."""
+    `scan` K > 0 a K-step greedy decode scan with the pools in its carry. A
+    chunk is a prefill dispatch's, one entry behind the rows' positions
+    naming the row that prefills, unless `rectangle`."""
     bt = cfg["engine"]["kv_block_tokens"]
     kc, vc = (jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=chip)
-              for s in pool_shape(spec, cfg, blocks))
+              for s in pool_shape(spec, cfg))
 
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
@@ -150,9 +158,10 @@ def compile_step(spec, shapes, cfg, chip, *, rows: int = 8, chunk: int = 64,
         return out, tok, pos, kc, vc
 
     toks = i32(rows) if scan else i32(rows, chunk)
+    lead = not scan and chunk > 1 and not rectangle
     return jax.jit(loop if scan else fwd, donate_argnums=(3, 4)).lower(
         shapes, jax.tree.map(lambda a: _sds(a, chip), RopeTables.create(spec)),
-        toks, kc, vc, i32(rows), i32(rows, 64)).compile()
+        toks, kc, vc, i32(rows + lead), i32(rows, 64)).compile()
 
 
 def results(text: str):
@@ -162,6 +171,14 @@ def results(text: str):
         m = _RESULT.match(line)
         if m:
             yield m.group(1), m.group(2), m.group(3) or "", line
+
+
+def logits_blocks(text: str, rows: int, chunk: int, vocab: int) -> list[str]:
+    """The instructions that result in a float32 (rows, chunk, vocab) or
+    (rows x chunk, vocab) array, the head's logits of every position: a
+    chunk whose head runs at the sampled positions alone has none."""
+    return re.findall(rf"%([\w.\-]+) = \(?f32\[(?:{rows},{chunk}|{rows * chunk})"
+                      rf",{vocab}\]", text)
 
 
 def _entry(text: str) -> str:
@@ -194,20 +211,32 @@ def main():
     ap.add_argument("--chunk", type=int, default=64)
     ap.add_argument("--scan", type=int, default=0, metavar="K",
                     help="K decode steps as one scan, the pools in its carry")
+    ap.add_argument("--rectangle", type=int, default=0,
+                    help="1: a chunk whose every position is real (no row "
+                    "map), not a prefill dispatch's")
     args = ap.parse_args()
     chip = describe_chip()
     spec, shapes, cfg = model_shapes(args.config, chip)
     t0 = time.time()
     compiled = compile_step(spec, shapes, cfg, chip, rows=args.rows,
-                            chunk=args.chunk, scan=args.scan)
+                            chunk=args.chunk, scan=args.scan,
+                            rectangle=bool(args.rectangle))
     text, mem = compiled.as_text(), compiled.memory_analysis()
     what = (f"a scan of {args.scan} steps" if args.scan
             else f"{args.rows} x {args.chunk} rows")
+    if not args.scan and args.chunk > 1:
+        what += (", every position real" if args.rectangle else
+                 f", the weights over {compact_rows(args.chunk, args.rows)} "
+                 "compact rows")
     print(f"{args.config}: {spec.n_layers} layers, {what}, "
           f"compiled in {time.time() - t0:.1f} s; temporaries "
           f"{mem.temp_size_in_bytes / 1e9:.3f} GB, arguments "
           f"{mem.argument_size_in_bytes / 1e9:.3f} GB, "
           f"{text.count('tpu_custom_call')} kernels")
+    if not args.scan and args.chunk > 1:
+        left = logits_blocks(text, args.rows, args.chunk, cfg["vocab_size"])
+        print(f"  float32 ({args.rows}, {args.chunk}, vocabulary) logits: "
+              f"{left or 'none left'}")
     pools = {_bf16(side) for side in held_pools(spec, cfg)}
     seen: dict[tuple[str, str], int] = {}
     for name, shape, layout, line in results(text):

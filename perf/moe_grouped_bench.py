@@ -6,7 +6,9 @@ bytes over 819 GB/s, at the cells' dispatch shapes.
 One layer's call of `ops/pallas_moe_grouped.moe_grouped_q4` (gate/up with
 the activation fused, then down) on seeded Q40 stacks and uniformly routed
 rows: 8 slots x T of 1, 8 and 64 for 6 of 64 experts of 768 (hidden 2560) and
-2 of 8 experts of 14336 (hidden 4096). Beside it the XLA form of the same
+2 of 8 experts of 14336 (hidden 4096), and the 16 and 72 rows (cases `t2`, `t9`)
+that a prefill chunk of 8 and of 64 tokens computes since PR 41 (its compact
+stream, `models/forward.compact_rows`). Beside it the XLA form of the same
 tiles (`ops/moe_grouped._grouped_xla`, the stacks handed in as arguments: as
 constants of the jitted function XLA folds their dequantization away), whose
 result the kernel's is compared with. One JSON line a case.
@@ -79,7 +81,9 @@ def timed(fn, reps):
     return (time.perf_counter() - t0) / reps * 1e3
 
 
-LAYER_T = {"e64": (1, 8, 64), "e8": (1, 2, 4, 8, 64)}
+# rows are 8 x T: T of 2 and 9 stand for a chunk's 16 and 72 compact rows
+KERNEL_T = (1, 2, 8, 9, 64)
+LAYER_T = {"e64": (1, 2, 8, 9, 64), "e8": (1, 2, 4, 8, 9, 64)}
 
 
 def layer_arms(reps):
@@ -198,7 +202,7 @@ def main():
         key = jax.random.key(7)
         gu = stack(jax.random.fold_in(key, 0), (e,), 2 * width, d)
         down = stack(jax.random.fold_in(key, 1), (e,), d, width)
-        for t in (1, 8, 64):
+        for t in KERNEL_T:
             n = 8 * t
             rows, p, tile, touched, call_bytes = routed(key, t, e, k, width, d)
             floor_ms = call_bytes / HBM * 1e3

@@ -10,8 +10,10 @@ A call should move only
 never a dequantized (n, k) bf16 image, which alone is 3.56x the packed bytes.
 
 `--cells` (the chip): every matmul shape of the benchmark's three
-configurations at M = 8, 64 and 512 rows (a decode step or T = 1 dispatch, an
-8-token and a 64-token chunk at 8 slots), the kernel and the XLA
+configurations at M = 8, 64 and 512 rows (a decode step or T = 1 dispatch, a
+verify block, the rectangle a 64-token chunk was until PR 41) and at the 16
+and 72 compact rows an 8-token and a 64-token chunk computes since
+(`models/forward.compact_rows`; 80 beside 72: whole bf16 tiles), the kernel and the XLA
 dequantize-then-dot oracle (`qmatmul(use_pallas=False)`), four calls on four
 weights chained inside one jit so that a call's launch does not hide its
 time: ms a call, beside the bytes' time at 819 GB/s and the FLOP's at 197
@@ -41,7 +43,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 from distributed_llama_tpu.quants import QK, FloatType, QTensor  # noqa: E402
 
 HBM_BYTES_S, BF16_FLOP_S = 819e9, 197e12  # one v5e chip
-ROWS = (8, 64, 512)
+ROWS = (8, 16, 64, 72, 80, 512)
 # (configuration, matrix, out rows, in columns)
 CELL_SHAPES = (
     ("mistral-7b", "wqkv", 6144, 4096),

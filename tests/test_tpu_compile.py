@@ -196,7 +196,7 @@ _SMALLTHINKER = {"wqkv": (4608, 2560), "wo": (2560, 3584),
                  "head": (151936, 2560)}
 CELL_MATMULS = {
     **{f"matmul-mistral-m{m}-{name}": matmul(m, n, k)
-       for m in (8, 64, 512) for name, (n, k) in _MISTRAL.items()},
+       for m in (8, 16, 64, 72, 512) for name, (n, k) in _MISTRAL.items()},
     **{f"matmul-smallthinker-m512-{name}": matmul(512, n, k)
        for name, (n, k) in _SMALLTHINKER.items()},
 }
@@ -268,6 +268,13 @@ CASES = {
     "grouped-l24-e64-t64": grouped(512, 6, 64, 768, 2560, layers=24),
     "grouped-l8-e8-t1": grouped(8, 2, 8, 14336, 4096, act="silu", layers=8),
     "grouped-l8-e8-t8": grouped(64, 2, 8, 14336, 4096, act="silu", layers=8),
+    # a prefill chunk's compact rows since PR 41 (`forward.compact_rows`): 72
+    # of a 64-token chunk, 16 of an 8-token one; Mixtral's 72 x 2 of 8 take
+    # the grouped layer where 512 rows took the all-experts scan
+    "grouped-l24-e64-r72": grouped(72, 6, 64, 768, 2560, layers=24),
+    "grouped-l24-e64-r16": grouped(16, 6, 64, 768, 2560, layers=24),
+    "grouped-l8-e8-r72": grouped(72, 2, 8, 14336, 4096, act="silu", layers=8),
+    "grouped-l8-e8-r16": grouped(16, 2, 8, 14336, 4096, act="silu", layers=8),
     # fused decode attention: one-block and full windows
     "decode-attn-w256": decode_attention(8, 256),
     "decode-attn-w2048": decode_attention(8, 2048),
@@ -310,8 +317,9 @@ STEP_POOLS = {
     # (72, 3072) and (48, 3072) matrices through the dequant-matmul
     "kinds": ("laguna-s-2.1-l5", {"num_experts": 16}),
 }
-# `jit_step` at T = 1 and at a 64-token chunk, and a 2-step decode scan with
-# the pools in its carry (`make_batched_decode_loop`'s form)
+# `jit_step` at T = 1 and at a 64-token chunk as the scheduler dispatches it
+# (told which row prefills: 72 compact rows, `forward.RowMap`), and a 2-step
+# decode scan with the pools in its carry (`make_batched_decode_loop`'s form)
 STEP_PROGRAMS = {"t1": {"chunk": 1}, "t64": {"chunk": 64}, "scan2": {"scan": 2}}
 
 
@@ -340,3 +348,6 @@ def test_step_program_updates_the_pool_in_place(chip, step_model, pool,
     assert "tpu_custom_call" in text
     for side in aot_step.held_pools(spec, cfg):
         assert aot_step.pool_relayouts(text, side) == []
+    chunk = STEP_PROGRAMS[program].get("chunk", 1)
+    if chunk > 1:  # the head ran at the one sampled position a row
+        assert aot_step.logits_blocks(text, 8, chunk, cfg["vocab_size"]) == []

@@ -288,13 +288,18 @@ def test_useful_work_counters_equal_the_hand_computed_values(scenario):
     # single step: A alone at position 3. Mixed: B's chunk of 8 from 0, A
     # riding at 4. Scan of K=4: A from 5, B from 8. No window bucket under
     # 256 positions of context: attention runs against all SEQ keys.
+    # The chunk's weights run over its 8 tokens and one row a slot, rounded
+    # to a row tile of 8 (16 rows); its attention over the (slots, 8) rectangle.
+    from distributed_llama_tpu.models.forward import compact_rows
+
     single = (SLOTS * 1, 1, 3 + 1)
     mixed = (SLOTS * 8, 8 + 1, sum(range(1, 9)) + (4 + 1))
     scan = (SLOTS * K, 4 + 4,
             sum(5 + i + 1 for i in range(4)) + sum(8 + i + 1 for i in range(4)))
     steps = (single, mixed, scan)
     assert got == {
-        "batch_positions_dispatched_total": sum(s[0] for s in steps),
+        "batch_positions_dispatched_total": (
+            single[0] + compact_rows(8, SLOTS) + scan[0]),
         "batch_positions_real_total": sum(s[1] for s in steps),
         "batch_attn_pairs_dispatched_total": SEQ * sum(s[0] for s in steps),
         "batch_attn_pairs_real_total": sum(s[2] for s in steps)}
@@ -403,7 +408,9 @@ def test_stage_lies_inside_build_and_says_what_it_sent(scenario):
         {"transfers": 2, "table": 0, "bytes": 2 * row},
         {"transfers": 2, "table": 0, "bytes": 2 * row},
         {"transfers": 2, "table": 0, "bytes": 2 * row},
-        {"transfers": 3, "table": 1, "bytes": 8 * row + row + TABLE_BYTES}]
+        # (behind the chunk's positions, which row prefills: one int32)
+        {"transfers": 3, "table": 1,
+         "bytes": 8 * row + row + 4 + TABLE_BYTES}]
 
 
 def test_uploads_and_fetched_bytes_equal_the_hand_computed_values(scenario):
@@ -415,17 +422,17 @@ def test_uploads_and_fetched_bytes_equal_the_hand_computed_values(scenario):
         "batch_h2d_bytes_total": 3 * 2 * row + TABLE_BYTES,
         "batch_table_uploads_total": 1,
         "batch_d2h_bytes_total": 3 * logits}
-    # the single step; the mixed step (a chunk of 8, two positions fetched);
+    # the single step; the mixed step (a chunk of 8 and which row prefills
+    # up, the one sampled position a row fetched);
     # the scan from host state: tokens, positions, rng (2 words a row),
     # temperatures, top-p and budgets up, K x slots tokens and the rng back
     got = {n: _delta(scenario["done"], scenario["held"], n) for n in H2D}
     assert got == {
         "batch_h2d_transfers_total": 2 + 3 + 6,
-        "batch_h2d_bytes_total": (2 * row) + (8 * row + row + TABLE_BYTES)
-        + (2 * row + 2 * row + 3 * row),
+        "batch_h2d_bytes_total": (2 * row)
+        + (8 * row + row + 4 + TABLE_BYTES) + (2 * row + 2 * row + 3 * row),
         "batch_table_uploads_total": 1,
-        "batch_d2h_bytes_total": logits + 2 * logits
-        + (K * SLOTS * 4 + 2 * row)}
+        "batch_d2h_bytes_total": logits + logits + (K * SLOTS * 4 + 2 * row)}
 
 
 def test_each_synchronous_dispatch_observes_its_three_phases(scenario):
