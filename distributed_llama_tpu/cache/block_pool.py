@@ -23,6 +23,12 @@ the hot tier overflows its budget, the LRU hot blocks are demoted to Q80
 element count is not a multiple of the Q80 block size stay hot (never true
 for even head sizes).
 
+A block may carry a second, typed payload beside its rows (`state`): the
+device pool's blocks of a model with state layers hold, for each such layer,
+the layer's state at the block's last position (docs/PAGED_KV.md "Typed
+block payload"), which is demoted and promoted with the block's keys and
+values, stays uncompressed, and comes back from `get` as a third array.
+
 A block may be committed while its rows are still ON THEIR WAY from the
 device (`put_pending`: the device pool's demotion issues one batched read a
 reclaim and does not wait for it, docs/PAGED_KV.md "Eviction"). Such a block
@@ -114,20 +120,23 @@ class PendingRows:
         """False while settle() would wait for the device."""
         raise NotImplementedError
 
-    def settle(self) -> tuple[np.ndarray, np.ndarray]:
+    def settle(self) -> tuple[np.ndarray, ...]:
         """The rows as host arrays the caller may keep (no copy is taken of
-        them); waits for the read if it has to. Raises what the read raised,
-        every time it is asked."""
+        them), (k, v) or (k, v, state); waits for the read if it has to.
+        Raises what the read raised, every time it is asked."""
         raise NotImplementedError
 
 
 class _Block:
-    __slots__ = ("k", "v", "kq", "vq", "pending", "shape", "dtype", "seq")
+    __slots__ = ("k", "v", "state", "kq", "vq", "pending", "shape", "dtype",
+                 "seq")
 
     def __init__(self, k: np.ndarray | None, v: np.ndarray | None, seq: int,
-                 pending: PendingRows | None = None):
+                 pending: PendingRows | None = None,
+                 state: np.ndarray | None = None):
         self.k = k            # hot: ndarray (L, hk, N, hs); None when cold
         self.v = v            # or while `pending`
+        self.state = state    # the typed payload, never compressed; or None
         self.kq = None        # cold: (values int8, scales f16) of the flat rows
         self.vq = None
         self.pending = pending  # the rows' read, until it is settled
@@ -143,18 +152,21 @@ class _Block:
     def nbytes(self) -> int:
         if self.pending is not None:
             return self.pending.nbytes
+        more = 0 if self.state is None else self.state.nbytes
         if self.cold:
-            return sum(q[0].nbytes + q[1].nbytes for q in (self.kq, self.vq))
-        return self.k.nbytes + self.v.nbytes
+            return more + sum(q[0].nbytes + q[1].nbytes
+                              for q in (self.kq, self.vq))
+        return more + self.k.nbytes + self.v.nbytes
 
     def settle(self) -> None:
         """Pending rows -> hot arrays; idempotent, and safe against a racing
         second caller (both are handed the same arrays)."""
         rows = self.pending
         if rows is not None:
-            k, v = rows.settle()
+            k, v, *state = rows.settle()
             if self.pending is rows:  # not compressed by a racing put()
                 self.k, self.v = k, v
+                self.state = state[0] if state else None
                 self.pending = None
 
 
@@ -193,9 +205,11 @@ class KVBlockPool:
 
     # ------------------------------------------------------------------
 
-    def put(self, k: np.ndarray, v: np.ndarray) -> int | None:
-        """Commit one block (copies taken); returns a handle, or None when the
-        pool is at capacity (caller evicts via the radix index and retries)."""
+    def put(self, k: np.ndarray, v: np.ndarray,
+            state: np.ndarray | None = None) -> int | None:
+        """Commit one block (copies taken), with its typed payload where it
+        has one; returns a handle, or None when the pool is at capacity
+        (caller evicts via the radix index and retries)."""
         if self.full:
             return None
         # the sides differ in their last axis alone: a latent row's second
@@ -205,8 +219,9 @@ class KVBlockPool:
         assert k.shape[:-1] == v.shape[:-1]
         h = self._next_handle
         self._next_handle += 1
-        self._blocks[h] = _Block(np.array(k, copy=True), np.array(v, copy=True),
-                                 next(self._seq))
+        self._blocks[h] = _Block(
+            np.array(k, copy=True), np.array(v, copy=True), next(self._seq),
+            state=None if state is None else np.array(state, copy=True))
         self._maybe_demote()
         return h
 
@@ -234,8 +249,9 @@ class KVBlockPool:
         pending and its owner drops it. KeyError for a freed handle."""
         self._blocks[handle].settle()
 
-    def get(self, handle: int) -> tuple[np.ndarray, np.ndarray]:
-        """Block data in its original dtype/shape; a cold block dequantizes
+    def get(self, handle: int) -> tuple[np.ndarray, ...]:
+        """Block data in its original dtype/shape, (k, v) and behind them the
+        block's typed payload where it has one; a cold block dequantizes
         (Q80 round-trip precision, not bit-exact — see module docstring); a
         pending one settles first (and raises what its read raised).
 
@@ -247,11 +263,12 @@ class KVBlockPool:
         b.seq = next(self._seq)
         b.settle()
         k, v = b.k, b.v
+        more = () if b.state is None else (b.state,)
         if k is not None and v is not None:  # demotion may land between reads
-            return k, v
+            return (k, v, *more)
         k = q80_restore(b.kq, b.shape, b.dtype)
         v = q80_restore(b.vq, b.shape, b.dtype)
-        return k, v
+        return (k, v, *more)
 
     def is_cold(self, handle: int) -> bool:
         return self._blocks[handle].cold

@@ -25,6 +25,13 @@ the dense caches were); this module owns only the HOST metadata:
   (the same hot/Q80 tier + LRU the host prefix cache already had — one
   unified spill path, docs/PAGED_KV.md "Eviction"); a later hit on a
   ("cold", handle) node pays one host→device upload and promotes back.
+  A block of a model with state layers carries a second, TYPED payload,
+  each such layer's state at the block's last position: it lies in an array
+  of the engine's indexed by the same block ids, so the allocator, the
+  refcounts, this directory and a remap serve it untouched, and only what
+  moves a block's bytes (copy-on-write, demotion, promotion) moves it with
+  them, as a third array beside (k, v) (docs/PAGED_KV.md "Typed block
+  payload").
   A demotion does not wait for its device read: the node turns cold at
   once over a PENDING payload (block_pool.PendingRows) that `settle()`
   makes host arrays where the scheduler only waits. Victims come off two
@@ -605,8 +612,9 @@ class PagedPrefixCache:
         return dev_ids
 
     def fetch_cold(self, handle: int):
-        """Host rows of a cold block (dequantized when Q80) — the upload
-        payload for promotion. Outside the lock (Q80 dequantize must not
+        """Host rows of a cold block (dequantized when Q80), (k, v) and the
+        block's state snapshot where it has one — the upload payload for
+        promotion. Outside the lock (Q80 dequantize must not
         stall lookups; the caller's lease pins the node). A block whose
         demotion is still pending settles here, waiting for its read if it
         must; a read that failed raises (the caller falls back to prefill,

@@ -28,7 +28,8 @@ from typing import BinaryIO
 
 import numpy as np
 
-from ..models.params import Params, block_tensor_shapes
+from ..models.params import (MIXER, Params, block_tensor_shapes,
+                             layer_tensor_shapes)
 from ..models.spec import (ArchType, HeaderKey, HiddenAct, LayerKind, ModelSpec,
                            RopeType, RouterInput, RouterScore)
 from ..quants import (
@@ -78,6 +79,8 @@ _OWN_KEYS = (
     ("rotary_dim", HeaderKey.ROTARY_DIM, int),
     ("rope_table_scale", HeaderKey.ROPE_TABLE_SCALE_E6, _e6),
     ("attn_gate", HeaderKey.ATTN_GATE, bool),
+    ("qk_norm", HeaderKey.QK_NORM, bool),
+    ("router_bias", HeaderKey.ROUTER_BIAS, bool),
 )
 
 # kinds of attention layer (ModelSpec.kinds): kind k's field at header key
@@ -88,6 +91,9 @@ _KIND_FIELDS = (
     ("rope_theta", int), ("rotary_dim", int), ("rope_scaling_factor", _e6),
     ("rope_scaling_orig_max_seq_len", int), ("yarn_beta_fast", _e6),
     ("yarn_beta_slow", _e6), ("rope_table_scale", _e6),
+    # taps of a convolution kind; a file written before the field has no
+    # such key and reads 0, an attention kind
+    ("conv_kernel", int),
 )
 _KIND_STRIDE = 16
 _MAX_KINDS = 4  # two bits a layer
@@ -120,7 +126,7 @@ def _unpack_layer_kinds(kv: dict[int, int], n_layers: int) -> dict:
         return {}
     kinds = tuple(
         LayerKind(name=f"kind{k}", **{
-            name: conv(kv[HeaderKey.KIND_0 + _KIND_STRIDE * k + i])
+            name: conv(kv.get(HeaderKey.KIND_0 + _KIND_STRIDE * k + i, 0))
             for i, (name, conv) in enumerate(_KIND_FIELDS)})
         for k in range(n))
     layer_kinds = tuple(
@@ -217,21 +223,13 @@ def read_spec(path: str, max_seq_len: int = 0,
 _EXPERT_STACKS = ("moe_up", "moe_gate", "moe_down")
 
 
-def _stacks_of(spec: ModelSpec) -> list[tuple[str, ModelSpec, bool, int]]:
-    """(name in `params`, the spec its layers' tensors take their shapes
-    from, is it a leading dense stack?, layers) of the file's stacks in
-    layer order (ModelSpec.runs): the leading layers come first, and a model
-    with kinds of layer has a stack a run of like layers."""
-    return [(run.name, spec.of_kind(run.kind), run.lead, run.depth)
-            for run in spec.runs()]
-
-
-def _layer_order(spec: ModelSpec, lead: bool):
+def _layer_order(spec: ModelSpec, lead: bool, shapes=None):
     """One layer's tensors in file order, as (name, expert or None, shape,
     quantized): the order of `block_tensor_shapes` (transformer.cpp:498-523),
     the expert stacks written expert by expert, each expert's up, gate, down
-    together."""
-    shapes = block_tensor_shapes(spec, lead)
+    together. `shapes`: the layer's own, where a run's layers differ in kind
+    (`layer_tensor_shapes`)."""
+    shapes = block_tensor_shapes(spec, lead) if shapes is None else shapes
     for name, (shape, quantized) in shapes.items():
         if name == "moe_up":
             for e in range(spec.n_experts):
@@ -245,11 +243,11 @@ def model_tensor_bytes(spec: ModelSpec, wft: FloatType) -> int:
     """Total tensor bytes after the header (mirrors the reference's missedBytes check,
     transformer.cpp:531-535)."""
     total = batch_bytes(FloatType.F32, spec.dim, spec.vocab_size)  # embedding
-    for _, ks, lead, depth in _stacks_of(spec):
-        for name, (shape, quantized) in block_tensor_shapes(ks, lead).items():
+    for l in range(spec.n_layers):  # layer by layer: kinds differ in tensors
+        for name, (shape, quantized) in layer_tensor_shapes(spec, l).items():
             ft = wft if quantized else FloatType.F32
             d = int(np.prod(shape[:-1], initial=1))
-            total += depth * batch_bytes(ft, shape[-1], d)
+            total += batch_bytes(ft, shape[-1], d)
     total += batch_bytes(FloatType.F32, spec.dim, 1)  # rms_final
     total += batch_bytes(wft, spec.dim, spec.vocab_size)  # wcls
     return total
@@ -303,26 +301,33 @@ def load_model(path: str, max_seq_len: int = 0,
     # of seq_len, so no adjustment needed.
     embedding = take((spec.vocab_size, spec.dim), FloatType.F32)
 
+    # a run's layers in file order, each with its own kind's tensors: a run
+    # of one kind stacks every tensor over all its layers, a run of a model
+    # with state layers each mixer's tensors over that kind's layers
+    # (models/params.py run_tensor_shapes)
     stacks: dict[str, Params] = {}
-    for stack_name, ks, lead, depth in _stacks_of(spec):
-        shapes = block_tensor_shapes(ks, lead)
-        per_layer: dict[str, list[QTensor]] = {name: [] for name in shapes}
-        for _ in range(depth):
+    for run in spec.runs():
+        per_layer: dict[str, list[QTensor]] = {}
+        quant: dict[str, bool] = {}
+        for l in range(run.first, run.first + run.depth):
+            shapes = layer_tensor_shapes(spec, l)
+            quant.update({n: q for n, (_, q) in shapes.items()})
             experts: dict[str, list[QTensor]] = {}
-            for name, e, shape, quantized in _layer_order(ks, lead):
+            for name, e, shape, quantized in _layer_order(spec, run.lead,
+                                                          shapes):
                 t = take(shape, wft if quantized else FloatType.F32)
                 if e is None:
-                    per_layer[name].append(t)
+                    per_layer.setdefault(name, []).append(t)
                 else:
                     experts.setdefault(name, []).append(t)
             for name, ts in experts.items():
-                per_layer[name].append(_stack(ts))
+                per_layer.setdefault(name, []).append(_stack(ts))
         blocks: Params = {}
         for name, tensors in per_layer.items():
             stacked = _stack(tensors)
-            blocks[name] = (stacked if shapes[name][1] else
+            blocks[name] = (stacked if quant[name] else
                             np.asarray(stacked.data, dtype=np.float32))
-        stacks[stack_name] = blocks
+        stacks[run.name] = blocks
 
     rms_final = take((spec.dim,), FloatType.F32)
     wcls = take((spec.vocab_size, spec.dim), wft)
@@ -432,7 +437,8 @@ def write_model(path: str, spec: ModelSpec, tensors_iter, weights_ftype: FloatTy
     A tensor may arrive in several consecutive row chunks under the same name.
     """
     norm_names = {"embedding", "rms_att", "rms_ffn", "rms_moe", "rms_ffn2", "rms_final",
-                  "rms_q", "rms_kv"}
+                  "rms_q", "rms_kv", "rms_qh", "rms_kh", "conv_w",
+                  "router_bias"}
     with open(path, "wb") as f:
         write_header(f, spec, weights_ftype)
         for name, tensor in tensors_iter:
@@ -453,10 +459,16 @@ def params_file_order(spec: ModelSpec, params: Params, as_stored: bool = False):
                            np.asarray(t.scales)[idx])
         return t.to_numpy()[idx] if isinstance(t, QTensor) else np.asarray(t)[idx]
 
-    for stack_name, ks, lead, depth in _stacks_of(spec):
-        blocks = params[stack_name]
-        for l in range(depth):
-            for name, e, _shape, _q in _layer_order(ks, lead):
-                yield name, as_np(blocks[name], l if e is None else (l, e))
+    for run in spec.runs():
+        blocks = params[run.name]
+        own: dict[str, int] = {}  # a mixer's tensors count their kind's layers
+        for l in range(run.depth):
+            shapes = layer_tensor_shapes(spec, run.first + l)
+            for name, e, _shape, _q in _layer_order(spec, run.lead, shapes):
+                i = own.get(name, 0) if spec.mixed and name in MIXER else l
+                yield name, as_np(blocks[name], i if e is None else (i, e))
+            for name in shapes:
+                if name in MIXER:
+                    own[name] = own.get(name, 0) + 1
     yield "rms_final", params["rms_final"]
     yield "wcls", as_np(params["wcls"], ())

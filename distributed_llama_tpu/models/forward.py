@@ -34,6 +34,16 @@ Arch-specific structure:
   layers (`ModelSpec.runs`) is a stack of `params` and a scan of its own,
   traced with that kind's spec (`ModelSpec.of_kind`) and rotation table; the
   caches and the commit go by the global layer as ever.
+- Layers whose mixer is a gated short convolution (`LayerKind.conv_kernel`;
+  LFM2: 18 of 24 layers) are data as well, but not a stack of their own: a
+  model with such layers (`ModelSpec.mixed`) has ONE scan behind its leading
+  layers, whose body picks the mixer by a per-layer flag (`lax.cond`) and
+  indexes the convolution tensors and the attention tensors, each stacked
+  over its own layers, by per-kind counters (`_Mixers`), so that the expert
+  layer, most of the program, is traced once and not once a run of like
+  layers. Such a layer holds no keys and values but a STATE, the last
+  conv_kernel - 1 rows of v = B * u, which is not a list of positions: it
+  stands beside the caches as a `StateCache` in the second cache's place.
 - GROK1: embedding x78.38367176906169 (grok1-tasks.cpp:11-14); attention output is
   rmsnorm'd (rms_ffn) BEFORE the residual join (grokRmfFfn*, grok1-tasks.cpp:16-41);
   MoE input norm uses rms_moe; MoE output is rmsnorm'd with rms_ffn2 before its residual
@@ -48,12 +58,15 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops.attention import gqa_attention
 from ..ops.kernels import ACTS, rmsnorm
 from ..ops.matmul import LayerOf, qmatmul, reads_the_stack
 from ..ops.ring_attention import commit_kv_rows_sharded, ring_attention
 from ..ops.rope import RopeTables, apply_rope
+from ..quants import QTensor
+from .params import MIXER
 from .spec import ArchType, HiddenAct, ModelSpec, RouterInput, RouterScore
 
 GROK_EMBEDDING_SCALE = 78.38367176906169  # grok1-tasks.cpp:13
@@ -68,8 +81,6 @@ def _localize_qtensors(params):
     QTensor therefore has groups=1 physically, but the aux metadata (static through
     device_put/tree ops) still says groups=tp. Fix it up so dequantize/kernels see the
     local truth."""
-    from ..quants import QTensor
-
     def fix(t):
         if isinstance(t, QTensor) and t.layout == "i4p" and t.groups != 1:
             return QTensor(t.ftype, t.data, t.scales, layout="i4p", groups=1,
@@ -186,6 +197,245 @@ class RowMap(NamedTuple):
         return jnp.take(x[0], at, axis=0)[:, None]
 
 
+# Positions of a sequence whose v rows a state layer's ring holds: a row
+# that is rolled back (a parked row's scratch write, a row over-decoded in a
+# K-step scan, a flushed chained super-step: at most two scans ahead of the
+# accepted frontier) finds the rows behind its frontier still there, as it
+# finds its keys, while STATE_RING >= the positions written ahead + the taps
+STATE_RING = 64
+
+
+class StateCache(NamedTuple):
+    """What stands in the SECOND cache's place (`v_cache`) for a model with
+    state layers (`ModelSpec.mixed`): the values side of the attention
+    layers' cache as every model has it, and the state of the convolution
+    layers in two kinds.
+
+    `ring` (slots, STATE_RING, state layers + padding, dim) is the RUNNING
+    state: a layer's v = B * u at position p of slot b stands at
+    [b, p % STATE_RING],
+    so the state a row continues from at position s is rows s - 1, s - 2, ..
+    (zeros before position 0), a write at or past a row's frontier (scratch,
+    over-decode, a flushed dispatch) leaves that state as it was, and what
+    is written there is written again when the position is decoded for good:
+    the free rollback keys and values have, for a short horizon.
+
+    `snaps` (1, pool blocks, (conv_kernel - 1) x state layers + padding, dim)
+    is the POOL's kind of state (docs/PAGED_KV.md "Typed block payload"): for
+    block n, each layer's state at the block's LAST position (row i x state
+    layers + l is layer l's v at that position - (conv_kernel - 2 - i)),
+    indexed on axis 1 by the block ids keys and values are, so that one
+    allocator, one radix directory, one copy-on-write and one demotion
+    gather serve both. A
+    dispatch writes the snapshot of every block end it crosses; a prefix hit
+    or a slot rewind lands on a block end and seeds the ring from it
+    (`seed_state`). No blocks (a contiguous cache): N = 0, nothing is
+    snapshot.
+
+    The layers are the SECOND-minor axis of both, padded to whole tiles of
+    16 rows (`_tile_rows`): what one position writes, every layer's row, is
+    then one run of whole tiles, and a dynamic slice of the leading axes
+    updates the donated array in place. With the layers first XLA re-laid
+    both arrays so, a copy in and a copy out of 38 and 302 MB a program, and
+    with 18 layers unpadded on that axis the chip's default layout of the
+    ARGUMENT put another axis there (the compiled text of
+    `perf/aot_step.py`, PERF.md section 6, PR 42)."""
+    rows: jax.Array
+    ring: jax.Array
+    snaps: jax.Array
+
+
+def _tile_rows(n: int) -> int:
+    """n rows in whole tiles of 16 (a bfloat16 tile's sublanes)."""
+    return -(-n // 16) * 16
+
+
+def init_state(spec: ModelSpec, slots: int, n_blocks: int, dtype):
+    """Zeroed (ring, snaps) of a model with state layers."""
+    n = len(spec.state_layers)
+    return (jnp.zeros((slots, STATE_RING, _tile_rows(n), spec.dim), dtype),
+            jnp.zeros((1, n_blocks, _tile_rows(spec.state_rows * n),
+                       spec.dim), dtype))
+
+
+@functools.partial(jax.jit, donate_argnums=(0,), static_argnums=(5, 6))
+def _seed_ring(ring, snaps, slot, block, pos, n: int, k1: int):
+    _, w, np_, d = ring.shape
+    snap = jax.lax.dynamic_slice(snaps, (0, block, 0, 0),
+                                 (1, 1, snaps.shape[2], d))
+    for j in range(1, k1 + 1):  # rows [(k1 - j) n, ..) are v at pos - j
+        held = jnp.pad(snap[:, :, (k1 - j) * n:(k1 - j + 1) * n],
+                       ((0, 0), (0, 0), (0, np_ - n), (0, 0)))
+        ring = jax.lax.dynamic_update_slice(
+            ring, held, (slot, (pos - j) % w, 0, 0))
+    return ring
+
+
+def seed_state(state: StateCache, slot, block, pos, n: int,
+               k1: int) -> StateCache:
+    """`state` with slot `slot`'s running state at position `pos` (a block
+    boundary > 0) taken from pool block `block`'s snapshot, the block that
+    ends at pos - 1: what a prefix hit and a slot rewind continue from, one
+    small jitted copy on the device into the donated ring (the pool's sides
+    are read, not donated). `n`, `k1`: the model's state layers and the rows
+    of a layer's state."""
+    return state._replace(ring=_seed_ring(state.ring, state.snaps, slot,
+                                          block, pos, n, k1))
+
+
+class _Stream(NamedTuple):
+    """Where each row of the residual stream (cb, ct) stands for a state
+    layer: its slot, its position, how many rows before it in the stream
+    are its own sequence's previous positions (`run`, static: a chunk row's
+    index in the chunk, 0 for a lone token), the last position its sequence
+    has in this stream (`last`), and whether it holds a position at all
+    (`live`: not the padding of a compact stream, nor the lead slot's second
+    copy of its first token)."""
+    slot: jax.Array
+    pos: jax.Array
+    run: "np.ndarray"
+    last: jax.Array
+    live: jax.Array
+
+    @classmethod
+    def of(cls, rows: "RowMap | None", positions, b: int, t: int):
+        if rows is None:  # the rectangle: every row is whole
+            at = jnp.broadcast_to(positions, (b, t)).astype(jnp.int32)
+            return cls(jnp.broadcast_to(jnp.arange(b, dtype=jnp.int32)[:, None],
+                                        (b, t)), at,
+                       np.broadcast_to(np.arange(t, dtype=np.int32), (b, t)),
+                       jnp.broadcast_to(at[:, -1:], (b, t)),
+                       jnp.ones((b, t), bool))
+        r = rows.positions.shape[1]
+        i = np.arange(r, dtype=np.int32)
+        chunk, rider = i < rows.t, (i >= rows.t) & (i < rows.t + rows.slots)
+        slot = jnp.where(chunk, rows.lead, jnp.where(rider, i - rows.t, 0))
+        live = chunk | (rider & (slot != rows.lead))
+        at = rows.positions.astype(jnp.int32)
+        return cls(slot[None], at, np.where(chunk, i, 0)[None],
+                   jnp.where(chunk, at[0, rows.t - 1], at[0])[None],
+                   live[None])
+
+
+def _state_prev(v, ring, state_idx, stream: _Stream, j: int):
+    """v (cb, ct, d) of state layer `state_idx` -> the same layer's v at each
+    row's position - j: the stream's own row j before where that is the
+    row's sequence, else the slot's ring (zeros before position 0)."""
+    w = ring.shape[1]
+    p = stream.pos - j
+    held = jnp.where((p >= 0)[..., None],
+                     ring[stream.slot, p % w, state_idx], 0).astype(v.dtype)
+    if v.shape[1] <= j:
+        return held
+    shifted = jnp.pad(v[:, :-j], ((0, 0), (j, 0), (0, 0)))
+    return jnp.where((stream.run >= j)[..., None], shifted, held)
+
+
+def _short_conv(x, bp, state_idx, spec: ModelSpec, ring, stream: _Stream,
+                use_pallas, residual):
+    """A gated short convolution in attention's place (LFM2's `conv` layers):
+
+        [B, C, u] = conv_in h            h the normed block input, each dim wide
+        v   = B * u
+        c_p = sum_j conv_w[:, j] v_{p - k + 1 + j}     depthwise, causal, k taps,
+                                                     zeros before position 0
+        out = conv_out (C * c)
+
+    on whatever rows the stream has (the rectangle, or a compact stream's
+    chunk and one row a slot: `_Stream`). A row's earlier v come from the
+    stream where its sequence's previous positions stand there, else from
+    the slot's ring of the state layer `state_idx`, which is only READ here:
+    the new rows v (cb, ct, dim) are returned for forward() to commit, as
+    the new keys and values are. v is rounded to the stream's type before it
+    is used, so a position reads the same neighbours from the stream as from
+    the ring."""
+    d = x.shape[-1]
+    xb = rmsnorm(x, bp["rms_att"], spec.norm_eps)
+    with jax.named_scope("short_conv"):
+        bcu = qmatmul(xb, bp["conv_in"], use_pallas=use_pallas,
+                      name="q4_mm_conv_in")
+        gate_b, gate_c, u = bcu[..., :d], bcu[..., d:2 * d], bcu[..., 2 * d:]
+        v = gate_b * u
+        taps = bp["conv_w"].astype(jnp.float32)  # (d, k)
+        k = taps.shape[-1]
+        acc = taps[:, k - 1] * v.astype(jnp.float32)
+        for j in range(1, k):
+            acc = acc + taps[:, k - 1 - j] * _state_prev(
+                v, ring, state_idx, stream, j).astype(jnp.float32)
+        y = (gate_c.astype(jnp.float32) * acc).astype(x.dtype)
+        out = qmatmul(y, bp["conv_out"], use_pallas=use_pallas,
+                      name="q4_mm_conv_out")
+    return residual + out, v
+
+
+def commit_state(state: StateCache, v_rows, stream: _Stream, block_tables,
+                 block_tokens: int, k1: int) -> StateCache:
+    """The state layers' commit of one dispatch: v_rows (state layers, cb,
+    ct, dim), each stream row's new v; `k1` the rows of a layer's state.
+
+    First the snapshots, against the ring as the dispatch found it: a live
+    row at a block's last position writes the layers' state there, its own v
+    and the taps - 2 before it, into the snapshot of the block its slot's
+    table names (a parked row's lands in its own unfinished block or the
+    scratch block, as its keys do). Then the ring: each live row's v at its
+    position mod STATE_RING, the riders before the chunk and of a sequence
+    longer than the ring its last rows alone. Both are loops of dynamic
+    slices of the leading axes, which update the donated arrays in place."""
+    ring0, snaps = state.ring, state.snaps
+    _, w, np_, d = ring0.shape  # the layers' axis with its padding
+    n = v_rows.shape[0]
+    cb, ct = stream.pos.shape
+    flat = jnp.pad(jnp.swapaxes(v_rows.reshape(n, cb * ct, d), 0, 1).astype(
+        ring0.dtype), ((0, 0), (0, np_ - n), (0, 0)))  # (stream rows, np_, d)
+    slot, pos, run, last, live = (a.reshape(-1) for a in stream)
+    rows = snaps.shape[2]
+    in_stream = jnp.asarray(run)
+
+    def v_at(r, j):  # (1, np_, d): v at pos[r] - j of row r's sequence
+        if j == 0:
+            return jax.lax.dynamic_slice(flat, (r, 0, 0), (1, np_, d))
+        p = pos[r] - j
+        held = jax.lax.dynamic_slice(
+            ring0, (slot[r], p % w, 0, 0), (1, 1, np_, d))[0]
+        held = jnp.where(p >= 0, held, jnp.zeros((), held.dtype))
+        streamed = jax.lax.dynamic_slice(
+            flat, (jnp.maximum(r - j, 0), 0, 0), (1, np_, d))
+        return jnp.where(in_stream[r] >= j, streamed, held)
+
+    if block_tables is not None and snaps.shape[1]:
+        last_entry = block_tables.shape[1] - 1
+
+        def snap(r, snaps):
+            ends = live[r] & ((pos[r] + 1) % block_tokens == 0)
+            blk = block_tables[slot[r], jnp.minimum(pos[r] // block_tokens,
+                                                    last_entry)]
+            new = jnp.concatenate(
+                [v_at(r, k1 - 1 - i)[:, :n] for i in range(k1)], axis=1)
+            new = jnp.pad(new, ((0, 0), (0, rows - k1 * n), (0, 0)))
+            at = (0, blk, 0, 0)
+            old = jax.lax.dynamic_slice(snaps, at, (1, 1, rows, d))
+            return jax.lax.dynamic_update_slice(
+                snaps, jnp.where(ends, new[None], old), at)
+
+        snaps = jax.lax.fori_loop(0, cb * ct, snap, snaps)
+
+    # the stream rows in the order they are written: lone tokens (a compact
+    # stream's riders) first, then each sequence's rows, so that of two rows
+    # on one ring slot the later position stays
+    order = jnp.asarray(np.argsort(run, kind="stable"), jnp.int32)
+    fits = live & (pos > last - w)  # a sequence's last STATE_RING rows
+
+    def put(i, ring):
+        r = order[i]
+        at = (slot[r], pos[r] % w, 0, 0)
+        old = jax.lax.dynamic_slice(ring, at, (1, 1, np_, d))
+        return jax.lax.dynamic_update_slice(
+            ring, jnp.where(fits[r], v_at(r, 0)[None], old), at)
+
+    ring = jax.lax.fori_loop(0, cb * ct, put, ring0)
+    return state._replace(ring=ring, snaps=snaps)
+
+
 def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, start_pos,
                positions, axis_name, sp_axis_name, sp_size, use_pallas, compress,
                window, paged_cold=None, block_tables=None, block_tokens=0,
@@ -272,6 +522,9 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
     hk_local = k.shape[-1] // hs
     q = q.reshape(cb, ct, hq_local, hs)
     k = k.reshape(cb, ct, hk_local, hs)
+    if "rms_qh" in bp:  # QK-norm: each head's q and k, before the rotation
+        q = rmsnorm(q, bp["rms_qh"], spec.norm_eps)
+        k = rmsnorm(k, bp["rms_kh"], spec.norm_eps)
     at = positions if rows is None else rows.positions
     if rope_on is None:
         q, k = apply_rope(q, rope, at), apply_rope(k, rope, at)
@@ -291,6 +544,14 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
         "host/disc KV paging")
     k_t = jnp.swapaxes(k, 1, 2).astype(kc.dtype)  # (B, hk, T, hs)
     v_t = jnp.swapaxes(v, 1, 2).astype(vc.dtype)
+    # a pool whose rows are wider than a head (heads of 64 in whole lanes of
+    # 128, runtime/engine.py): the rows are committed with zeros behind them
+    lanes = kc.shape[-1] if block_tables is not None else hs
+
+    def widen(a):
+        if lanes == hs:
+            return a
+        return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, lanes - hs)])
     # a run of one stated kind of layer: the scope and the paged kernel's
     # name tell the kinds apart in a device trace
     scope = kernel_name = None
@@ -355,14 +616,20 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
             if paged_kernel:
                 from ..ops.pallas_paged_attention import paged_attention
 
-                out = paged_attention(q, kc, vc, k_t, v_t, block_tables,
-                                      start_pos, layer_idx, n_read=nb,
-                                      window=swa, name=kernel_name)
+                out = paged_attention(
+                    widen(q), kc, vc, widen(k_t), widen(v_t), block_tables,
+                    start_pos, layer_idx, n_read=nb, window=swa,
+                    name=kernel_name,
+                    **({"head_size": hs} if lanes != hs else {}))
+                if lanes != hs:
+                    out = out[..., :hs]
                 att = out.reshape(b, t, hq_local * hs)
             else:
                 from ..ops.pallas_paged_attention import paged_gather_kv
 
                 kw, vw = paged_gather_kv(kc, vc, layer_idx, block_tables, nb)
+                if lanes != hs:
+                    kw, vw = kw[..., :hs], vw[..., :hs]
                 vwin = nb * block_tokens
                 slot = jnp.arange(vwin)
                 # same committed-rows masking (and sentinel arithmetic) as the
@@ -409,7 +676,8 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
     # col-parallel wo: local heads x local input slice -> partial (B, T, dim); psum merges
     y = _maybe_psum(qmatmul(att, bp["wo"], use_pallas=use_pallas), axis_name,
                     compress)
-    return (y if residual is None else residual + y), (k_t, v_t)
+    return (y if residual is None else residual + y), (widen(k_t),
+                                                       widen(v_t))
 
 
 def _latent_attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc,
@@ -594,18 +862,24 @@ def _expert_scan(xb, bp, top_i, weights, act, use_pallas, el, offset):
     return out, jnp.sum(one_hot).astype(jnp.int32)
 
 
-def _route(logits, k: int, spec: ModelSpec):
+def _route(logits, k: int, spec: ModelSpec, bias=None):
     """The router's choice from its logits (B, T, E): a score of every expert
     (a softmax over ALL of them, grokMoeRouter..grokMoeNormWeights,
     grok1-tasks.cpp:56-115, or where the spec says so a sigmoid of each), the
     k largest, renormalized over those k unless the spec says not, times the
-    spec's scale. Returns (top_i, weights), both (B, T, k)."""
+    spec's scale. `bias` (E,), a selection bias: the k largest are taken over
+    score + bias and weighed by their scores alone, the bias left out.
+    Returns (top_i, weights), both (B, T, k)."""
     logits = logits.astype(jnp.float32)
     if spec.router_score == RouterScore.SIGMOID:
         probs = jax.nn.sigmoid(logits)
     else:
         probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, k)
+    if bias is None:
+        top_p, top_i = jax.lax.top_k(probs, k)
+    else:
+        _, top_i = jax.lax.top_k(probs + bias.astype(jnp.float32), k)
+        top_p = jnp.take_along_axis(probs, top_i, axis=-1)
     if spec.router_renorm:
         top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
     if spec.router_scale != 1.0:
@@ -642,7 +916,8 @@ def _moe_ffn(xb, bp, spec: ModelSpec, axis_name, use_pallas, compress,
     with jax.named_scope("moe_route"):
         if router_logits is None:
             router_logits = _router_logits(xb, bp)
-        top_i, weights = _route(router_logits, k, spec)
+        top_i, weights = _route(router_logits, k, spec,
+                                bp.get("router_bias"))
 
     # the fused up+gate stack (fuse_matvec_groups) where there is one
     gu_stack = bp["moe_gu"] if "moe_gu" in bp else bp["moe_up"]
@@ -703,7 +978,7 @@ def _block(x, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions,
            axis_name, sp_axis_name, sp_size, use_pallas, compress, window,
            kc, vc, paged_cold=None, block_tables=None, block_tokens=0,
            paged_kernel=False, stacks=None, routed=None, layer_base=0,
-           kind_name=None, rows=None):
+           kind_name=None, rows=None, mixed=None):
     """One transformer block as a scan step: the carry is x, the caches kc/vc
     are read-only closures (loop invariants), and the ys are the layer's new
     K/V rows, for forward() to commit in one top-level write, with the
@@ -716,12 +991,18 @@ def _block(x, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions,
     caches are indexed from (the weights by the index within the stack).
     `rows`: the `RowMap` of a compact stream x, which the attention call
     alone lays out to the rectangle; the K/V rows of the ys are the
-    rectangle's.
+    rectangle's. `mixed`: the `_Mixers` of a run whose layers' mixer is
+    attention or a convolution by layer (`ModelSpec.mixed`): the xs then
+    carry each layer's flag and counters, and the ys one entry more, the
+    layer's new state rows (zeros of an attention layer, as a convolution
+    layer's K/V rows are zeros).
     """
     stats = jnp.zeros((N_MOE_STATS,), jnp.int32)  # of the expert layer: a ys
     # the layer's kind rides in the xs beside its index, where the model has
     # layers of more than one kind (forward() below)
     bp, layer_idx, *kind = layer
+    if mixed is not None:
+        at, kind = kind, []
     rope_on, swa = kind if kind else (None, None)
     if kind_name is not None:  # a run of one stated kind: its window is static
         swa = spec.sliding_window or None
@@ -740,14 +1021,21 @@ def _block(x, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions,
     # the block input down (contract: attn_out returns already joined when
     # residual is given)
     res_attn = None if spec.arch_type == ArchType.GROK1 else x
-    with jax.named_scope("attn"):
-        if spec.latent:
+    if mixed is not None:  # the layer's own mixer, under its own scope
+        attn_out, k_t, v_t, s_t = mixed.mix(
+            x, bp, at, start_pos=start_pos, positions=positions,
+            axis_name=axis_name, use_pallas=use_pallas, compress=compress,
+            window=window, kc=kc, vc=vc, block_tables=block_tables,
+            block_tokens=block_tokens, paged_kernel=paged_kernel, rows=rows)
+    elif spec.latent:
+        with jax.named_scope("attn"):
             attn_out, (k_t, v_t) = _latent_attention(
                 x, bp, cache_idx, spec, rope, kc, vc, start_pos, positions,
                 axis_name, use_pallas, compress, window,
                 block_tables=block_tables, block_tokens=block_tokens,
                 paged_kernel=paged_kernel, residual=res_attn, rows=rows)
-        else:
+    else:
+        with jax.named_scope("attn"):
             attn_out, (k_t, v_t) = _attention(
                 x, bp, cache_idx, spec, rope, kc, vc, start_pos, positions,
                 axis_name, sp_axis_name, sp_size, use_pallas, compress,
@@ -772,7 +1060,80 @@ def _block(x, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions,
         else:
             x = _dense_ffn(x, bp, spec, axis_name, use_pallas, compress,
                            residual=x)
+    if mixed is not None:
+        return x, (k_t, v_t, s_t, stats)
     return x, (k_t, v_t, stats)
+
+
+class _Mixers(NamedTuple):
+    """The mixers of one run of a model with state layers: the attention
+    kind's and the convolution kind's specs and tensors, each tensor stacked
+    over ITS kind's layers of the run and read at the layer's own counter.
+    Per layer (the scan's xs, `xs_of`): whether its mixer is the
+    convolution, its index among its kind's layers of the run, and its index
+    among the model's cache layers or state layers (the caches' layer
+    axes)."""
+    attn: ModelSpec | None  # the attention kind's spec, None: the run has none
+    conv: ModelSpec | None
+    rope: RopeTables | None  # the attention kind's table
+    tensors: dict  # the run's MIXER tensors, whole
+    ring: jax.Array  # the state layers' ring (read only)
+    stream: _Stream
+
+    @staticmethod
+    def xs_of(spec: ModelSpec, run):
+        kinds = spec.layer_kinds[run.first:run.first + run.depth]
+        conv = [bool(spec.kinds[k].conv_kernel) for k in kinds]
+        own, seen = [], {True: 0, False: 0}
+        for c in conv:
+            own.append(seen[c])
+            seen[c] += 1
+        cache, state = spec.cache_layers, spec.state_layers
+        at = [state.index(l) if c else cache.index(l)
+              for l, c in zip(range(run.first, run.first + run.depth), conv)]
+        return tuple(jnp.asarray(a, jnp.int32) for a in (conv, own, at))
+
+    def mix(self, x, bp, at, *, start_pos, positions, axis_name, use_pallas,
+            compress, window, kc, vc, block_tables, block_tokens,
+            paged_kernel, rows):
+        """The layer's mixer on x, residual-joined: (out, k_t, v_t, s_t)."""
+        is_conv, own, idx = at
+
+        def pick(w):
+            if isinstance(w, QTensor):  # named, and read in place
+                return LayerOf(w, (own,))
+            return jax.lax.dynamic_index_in_dim(w, own, 0, keepdims=False)
+
+        b, t = positions.shape if positions.ndim == 2 else (
+            x.shape[0], positions.shape[0])
+        hk = kc.shape[2]
+        no_kv = tuple(jnp.zeros((b, hk, t, c.shape[-1]), c.dtype)
+                      for c in (kc, vc))
+        no_state = jnp.zeros(x.shape, self.ring.dtype)
+
+        def attend(x):
+            names = [n for n in self.tensors if not n.startswith("conv_")]
+            with jax.named_scope("attn"):
+                out, kv = _attention(
+                    x, {**bp, **{n: pick(self.tensors[n]) for n in names}},
+                    idx, self.attn, self.rope, kc, vc, start_pos, positions,
+                    axis_name, None, 1, use_pallas, compress, window,
+                    block_tables=block_tables, block_tokens=block_tokens,
+                    paged_kernel=paged_kernel, residual=x, rows=rows)
+            return (out, *kv, no_state)
+
+        def convolve(x):
+            names = [n for n in self.tensors if n.startswith("conv_")]
+            out, v = _short_conv(
+                x, {**bp, **{n: pick(self.tensors[n]) for n in names}}, idx,
+                self.conv, self.ring, self.stream, use_pallas, residual=x)
+            return (out, *no_kv, v.astype(no_state.dtype))
+
+        if self.attn is None:
+            return convolve(x)
+        if self.conv is None:
+            return attend(x)
+        return jax.lax.cond(is_conv > 0, convolve, attend, x)
 
 
 def commit_block_rows(pool, rows, block_tables, start_pos):
@@ -881,6 +1242,11 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
     t = tokens.shape[1]
     if axis_name is not None:
         params = _localize_qtensors(params)
+    state = None  # a model with state layers: they ride in v_cache's place
+    if isinstance(v_cache, StateCache):
+        state, v_cache = v_cache, v_cache.rows
+    assert (state is not None) == spec.mixed, (
+        "a model with state layers takes a StateCache as its second cache")
     start_pos = jnp.asarray(start_pos)
     rows = lead = None  # the prefilling row of a mixed dispatch, if it says
     if start_pos.ndim == 1 and start_pos.shape[0] == tokens.shape[0] + 1:
@@ -915,8 +1281,12 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
     # dequant-matmul and the grouped expert kernels take their blocks from
     # the whole stack at the layer's (and the expert's) index, and a slice
     # would be a copy of the layer's weights, every expert touched or not
-    def scan_stack(x, blocks, depth, spec=spec, rope=rope, **kind):
-        """One `lax.scan` over a stack of `depth` like layers."""
+    def scan_stack(x, blocks, depth, spec=spec, rope=rope, at=(), **kind):
+        """One `lax.scan` over a stack of `depth` like layers. `at`: what a
+        run of mixed layers adds to the xs (`_Mixers.xs_of`), its mixers'
+        tensors staying out of them."""
+        if at:
+            blocks = {n: w for n, w in blocks.items() if n not in MIXER}
         stacks = {n: w for n, w in blocks.items()
                   if reads_the_stack(w, tokens.size, use_pallas)}
         block_fn = functools.partial(
@@ -929,7 +1299,7 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
             block_tokens=block_tokens, paged_kernel=paged_kernel,
             stacks=stacks, rows=rows, **kind)
         xs = ({n: w for n, w in blocks.items() if n not in stacks},
-              jnp.arange(depth, dtype=jnp.int32))
+              jnp.arange(depth, dtype=jnp.int32), *at)
         if "kind_name" not in kind and (spec.rope_layers
                                         or spec.sliding_window):
             # layers of more than one kind in ONE scan: the kind is data
@@ -948,19 +1318,45 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
         "a leading stack and kinds of attention layer are not supported "
         "with sp (ring) sharding or host/disc KV paging")
     ys = []
+    stream = None
+    if state is not None:
+        stream = _Stream.of(rows, positions, *(
+            positions.shape if positions.ndim == 2
+            else (tokens.shape[0], t)))
     for run in runs:
-        kind = spec.kinds[run.kind] if spec.kinds else None
+        kind = spec.kinds[run.kind] if spec.kinds and not spec.mixed else None
         scope = (f"run_{run.name}_{kind.name}" if kind
                  else "lead_stack" if run.lead else None)
+        more = {"kind_name": kind.name} if kind else {}
+        if state is not None:
+            # ONE scan whatever the layers' mixers: the body picks
+            of_run = set(spec.layer_kinds[run.first:run.first + run.depth])
+            attn = [k for k in of_run if not spec.kinds[k].conv_kernel]
+            conv = [k for k in of_run if spec.kinds[k].conv_kernel]
+            more = {"at": _Mixers.xs_of(spec, run), "mixed": _Mixers(
+                spec.of_kind(attn[0]) if attn else None,
+                spec.of_kind(conv[0]) if conv else None,
+                rope.of_kind(spec, attn[0]) if attn else None,
+                {n: w for n, w in params[run.name].items() if n in MIXER},
+                state.ring, stream)}
         with jax.named_scope(scope) if scope else contextlib.nullcontext():
             x, run_ys = scan_stack(
-                x, params[run.name], run.depth, spec=spec.of_kind(run.kind),
-                rope=rope.of_kind(spec, run.kind),
+                x, params[run.name], run.depth,
+                spec=spec if spec.mixed else spec.of_kind(run.kind),
+                rope=rope if spec.mixed else rope.of_kind(spec, run.kind),
                 routed=False if run.lead else None, layer_base=run.first,
-                **({"kind_name": kind.name} if kind else {}))
+                **more)
         ys.append(run_ys)
-    k_rows, v_rows, stats = ys[0] if len(ys) == 1 else (
+    k_rows, v_rows, *s_rows, stats = ys[0] if len(ys) == 1 else (
         jnp.concatenate(a) for a in zip(*ys))
+    if state is not None:
+        # every layer left rows of both kinds, zeros of the kind it is not:
+        # the caches take their own layers' (static indices)
+        k_rows, v_rows = (a[np.asarray(spec.cache_layers)]
+                          for a in (k_rows, v_rows))
+        state = commit_state(
+            state, s_rows[0][np.asarray(spec.state_layers)], stream,
+            block_tables, block_tokens, spec.state_rows)
     # commit all layers' new rows in one write per cache: (L, B, hk, T, hs)
     # lands at [.., .., .., start_pos : start_pos+T, ..]
     if block_tables is not None:
@@ -1011,6 +1407,8 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
         # the new rows ride out so the caller can append them to the host
         # store — the step's one extra device->host payload (L, B, hk, T, hs)
         return logits, k_cache, v_cache, (k_rows, v_rows)
+    if state is not None:
+        v_cache = state._replace(rows=v_cache)
     if moe_stats:
         return logits, k_cache, v_cache, jnp.sum(stats, axis=0)
     return logits, k_cache, v_cache
@@ -1023,5 +1421,9 @@ def init_kv_cache(spec: ModelSpec, batch: int = 1, dtype=jnp.float32,
     (ModelSpec.cache_widths)."""
     hk = n_kv_heads if n_kv_heads is not None else spec.n_kv_heads
     s = seq_len if seq_len is not None else spec.seq_len
-    return tuple(jnp.zeros((spec.n_layers, batch, hk, s, w), dtype)
-                 for w in spec.cache_widths)
+    layers = len(spec.cache_layers) if spec.mixed else spec.n_layers
+    kc, vc = (jnp.zeros((layers, batch, hk, s, w), dtype)
+              for w in spec.cache_widths)
+    if spec.mixed:  # the state layers' running state, and no pool to snapshot
+        vc = StateCache(vc, *init_state(spec, batch, 0, dtype))
+    return kc, vc

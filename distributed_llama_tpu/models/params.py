@@ -57,12 +57,23 @@ def block_tensor_shapes(spec: ModelSpec, lead: bool = False
     A spec with kinds of layer is asked for one kind's shapes:
     `block_tensor_shapes(spec.of_kind(run.kind), run.lead)`. The per-head
     output gate (spec.attn_gate) is `wg` (n_heads, dim), behind wo.
+    A convolution kind (spec.conv_kernel > 0) has conv_in (3 dim, dim: the
+    gates B and C and the input u, in that order), the taps conv_w (dim,
+    conv_kernel) and conv_out (dim, dim) in attention's place (`MIXER`);
+    QK-norm (spec.qk_norm) adds rms_qh and rms_kh (head_size,), a selection
+    bias (spec.router_bias) router_bias (n_router,) behind the router.
     """
     assert not spec.kinds, "ask with spec.of_kind(kind): shapes differ by kind"
     d, h, kv, e = spec.dim, spec.hidden_dim, spec.kv_dim, spec.n_experts
     qd = spec.q_dim  # n_heads x head_size: dim unless the header states head_dim
     shapes: dict[str, tuple[tuple[int, ...], bool]]
-    if spec.latent:
+    if spec.conv_kernel:
+        shapes = {
+            "conv_in": ((3 * d, d), True),
+            "conv_w": ((d, spec.conv_kernel), False),
+            "conv_out": ((d, d), True),
+        }
+    elif spec.latent:
         r, nh = spec.kv_lora_rank, spec.n_heads
         shapes = {
             "wq_a": ((spec.q_lora_rank, d), True),
@@ -81,8 +92,13 @@ def block_tensor_shapes(spec: ModelSpec, lead: bool = False
         }
     if spec.attn_gate:
         shapes["wg"] = ((spec.n_heads, d), True)
+    if spec.qk_norm and not spec.conv_kernel:
+        shapes["rms_qh"] = ((spec.head_size,), False)
+        shapes["rms_kh"] = ((spec.head_size,), False)
     if spec.is_moe and not lead:
         shapes["router"] = ((spec.n_router, d), True)
+        if spec.router_bias:
+            shapes["router_bias"] = ((spec.n_router,), False)
         shapes["moe_up"] = ((e, h, d), True)
         shapes["moe_gate"] = ((e, h, d), True)
         shapes["moe_down"] = ((e, d, h), True)
@@ -108,6 +124,42 @@ def block_tensor_shapes(spec: ModelSpec, lead: bool = False
     return shapes
 
 
+# a layer's tensors that belong to its MIXER (attention, or a convolution):
+# in a run of layers of several kinds (`ModelSpec.mixed`) each kind's stand
+# stacked over THAT kind's layers of the run, every other tensor over all of
+# the run's (`run_tensor_shapes`)
+MIXER = frozenset({"wq", "wk", "wv", "wqkv", "wo", "rms_qh", "rms_kh",
+                   "conv_in", "conv_w", "conv_out"})
+
+
+def run_tensor_shapes(spec: ModelSpec, run) -> dict[
+        str, tuple[tuple[int, ...], bool]]:
+    """One run's tensors as `params[run.name]` holds them: name -> (shape
+    WITH the leading stack axis, quantized). A run of one kind: every tensor
+    `run.depth` deep. A run of a model with convolution layers: the mixers'
+    tensors as deep as their kind has layers in the run (none of a kind that
+    has none), everything else `run.depth` deep."""
+    if not spec.mixed:
+        return {n: ((run.depth, *shape), q) for n, (shape, q) in
+                block_tensor_shapes(spec.of_kind(run.kind), run.lead).items()}
+    out: dict[str, tuple[tuple[int, ...], bool]] = {}
+    of_run = spec.layer_kinds[run.first:run.first + run.depth]
+    for k in sorted(set(of_run)):
+        for n, (shape, q) in block_tensor_shapes(spec.of_kind(k),
+                                                 run.lead).items():
+            depth = of_run.count(k) if n in MIXER else run.depth
+            out.setdefault(n, ((depth, *shape), q))
+    return out
+
+
+def layer_tensor_shapes(spec: ModelSpec, layer: int) -> dict[
+        str, tuple[tuple[int, ...], bool]]:
+    """Layer `layer`'s own tensors in `.m` file order, without a stack axis:
+    `block_tensor_shapes` of the layer's kind."""
+    kind = spec.layer_kinds[layer] if spec.kinds else None
+    return block_tensor_shapes(spec.of_kind(kind), layer < spec.lead_layers)
+
+
 def init_random_params(spec: ModelSpec, weights_ftype: FloatType = FloatType.F32,
                        seed: int = 0, scale: float = 0.02) -> Params:
     """Random-weight model for tests/benchmarks (the reference's golden-test pattern:
@@ -119,12 +171,14 @@ def init_random_params(spec: ModelSpec, weights_ftype: FloatType = FloatType.F32
 
     def stack(run) -> Params:
         blocks: Params = {}
-        depth = run.depth
-        for name, (shape, quantized) in block_tensor_shapes(
-                spec.of_kind(run.kind), run.lead).items():
-            full = randn(depth, *shape)
+        for name, (shape, quantized) in run_tensor_shapes(spec, run).items():
+            full = randn(*shape)
             if quantized:
                 blocks[name] = QTensor.from_float(full, weights_ftype)
+            elif name in ("conv_w", "router_bias"):
+                # taps and a selection bias that do something: of the size
+                # of the values they meet, not norm weights around 1
+                blocks[name] = full * (25.0 if name == "conv_w" else 5.0)
             else:
                 blocks[name] = full + 1.0  # norm weights around 1
         return blocks
@@ -172,6 +226,7 @@ _I8_CONVERTIBLE = (FloatType.Q40, FloatType.Q80)
 # (ColMatmulSlice), so the i4p split-plane pack must be applied per column group
 # (QTensor.to_i4p_layout).
 _DENSE_MATMULS = {"wq", "wk", "wv", "wo", "wg", "w1", "w2", "w3",
+                  "conv_in", "conv_out",
                   "moe_up", "moe_gate", "moe_down",
                   "wq_a", "wq_b", "wkv_a", "sh_gate", "sh_up", "sh_down"}
 _COL_SHARDED = {"wo", "w2", "moe_down", "sh_down"}

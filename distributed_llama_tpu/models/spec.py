@@ -117,6 +117,12 @@ class HeaderKey(enum.IntEnum):
     # then kind k's fields at KIND_0 + KIND_STRIDE x k + its offset
     # (formats/mfile.py _KIND_FIELDS)
     N_KINDS = 61
+    # QK-norm (an RMS norm of each head's q and k ahead of the rotation,
+    # tensors rms_qh and rms_kh) and the router's selection bias (tensor
+    # router_bias: added to the scores for the choice, left out of the
+    # weights)
+    QK_NORM = 62
+    ROUTER_BIAS = 63
     LAYER_KINDS_0 = 64  # ..79
     KIND_0 = 100
 
@@ -128,10 +134,12 @@ def yarn_mscale(factor: float, mscale: float) -> float:
 
 @dataclass(frozen=True)
 class LayerKind:
-    """One kind of attention layer of a model whose layers differ in more
-    than a 0/1 switch (ModelSpec.kinds): its query heads, its window, its
-    rotation. Every field overrides the ModelSpec field of the same name for
-    the layers of this kind (`ModelSpec.of_kind`)."""
+    """One kind of layer of a model whose layers differ in more than a 0/1
+    switch (ModelSpec.kinds): an attention layer's query heads, its window,
+    its rotation, or (conv_kernel > 0) a layer whose mixer is a gated short
+    convolution and holds no keys and values at all. Every field overrides
+    the ModelSpec field of the same name for the layers of this kind
+    (`ModelSpec.of_kind`)."""
 
     name: str
     n_heads: int
@@ -144,6 +152,13 @@ class LayerKind:
     yarn_beta_fast: float = 32.0
     yarn_beta_slow: float = 1.0
     rope_table_scale: float = 0.0  # cos and sin times this; 0: derived
+    # taps of a gated short convolution: the layer's mixer is then
+    #   [B, C, u] = conv_in h;  v = B * u;  c_p = sum_j conv_w[:, j] v_{p-k+1+j}
+    #   (depthwise, causal, zeros before position 0);  out = conv_out (C * c)
+    # and its state after position p is v's last conv_kernel - 1 rows, NOT a
+    # list of positions: it stands beside the keys and values of the other
+    # layers (models/forward.py StateCache). 0: an attention layer
+    conv_kernel: int = 0
 
 
 class Run(NamedTuple):
@@ -152,7 +167,9 @@ class Run(NamedTuple):
     name: str  # the stack's key in `params`
     first: int  # its first layer's index in the model
     depth: int
-    kind: int | None  # index into ModelSpec.kinds; None: the model has none
+    # index into ModelSpec.kinds; None: the model has none, or the run holds
+    # layers of several (a model with state layers: `ModelSpec.mixed`)
+    kind: int | None
     lead: bool  # leading dense layers (ModelSpec.lead_layers)
 
 
@@ -241,6 +258,15 @@ class ModelSpec:
     # switches within it, and are not used together with kinds
     kinds: tuple[LayerKind, ...] = ()
     layer_kinds: tuple[int, ...] = ()
+    # a kind's convolution (LayerKind.conv_kernel), on the spec `of_kind`
+    # makes of it; 0 on a model's own spec
+    conv_kernel: int = 0
+    # QK-norm: q and k RMS-normed over each head's values (one weight vector
+    # of head_size each a layer, rms_qh and rms_kh) before the rotation
+    qk_norm: bool = False
+    # the router takes its k largest over score + router_bias (a tensor, one
+    # value an expert a layer) and its weights from the scores alone
+    router_bias: bool = False
 
     # --- derived (reference: transformer.cpp:102-106) ---
     @property
@@ -282,6 +308,38 @@ class ModelSpec:
     def cache_row_bytes(self, itemsize: int) -> int:
         """Bytes a token holds a layer, all kv heads, both sides."""
         return self.n_kv_heads * sum(self.cache_widths) * itemsize
+
+    @property
+    def mixed(self) -> bool:
+        """Whether some kind of layer is a convolution: such a model's layers
+        stand in TWO runs at most (`runs`), each one scan whose body picks
+        the mixer by a per-layer flag."""
+        return any(k.conv_kernel for k in self.kinds)
+
+    @property
+    def state_layers(self) -> tuple[int, ...]:
+        """The layers that hold a state and no keys and values."""
+        return tuple(l for l, k in enumerate(self.layer_kinds)
+                     if self.kinds[k].conv_kernel)
+
+    @property
+    def cache_layers(self) -> tuple[int, ...]:
+        """The layers that own rows of the key-value cache, in layer order:
+        the cache's layer axis is as deep as this is long."""
+        state = set(self.state_layers)
+        return tuple(l for l in range(self.n_layers) if l not in state)
+
+    @property
+    def state_rows(self) -> int:
+        """Rows of `dim` values a state layer holds a sequence: the taps
+        less one (every convolution kind of a model has the same kernel)."""
+        return max([k.conv_kernel for k in self.kinds]
+                   + [self.conv_kernel, 1]) - 1
+
+    def state_block_bytes(self, itemsize: int) -> int:
+        """Bytes of the snapshot a block of the pool holds beside its keys
+        and values: every state layer's state at the block's last position."""
+        return len(self.state_layers) * self.state_rows * self.dim * itemsize
 
     @property
     def attn_scale(self) -> float:
@@ -339,6 +397,10 @@ class ModelSpec:
         further wherever the kind of layer changes ("blocks", "blocks1", ..:
         a model of one kind keeps the two names it always had)."""
         kinds = self.layer_kinds or (None,) * self.n_layers
+        if self.mixed:
+            # one run behind the leading layers whatever the kinds: a scan
+            # a run of like layers would be 13 scans for LFM2's 24 layers
+            kinds = (None,) * self.n_layers
         out: list[Run] = []
         count = {True: 0, False: 0}
         for l in range(self.n_layers):
@@ -409,6 +471,17 @@ class ModelSpec:
                 "kinds of layer state their own windows and rotation")
             assert spec.arch_type != ArchType.GROK1 and spec.head_dim, (
                 "kinds of layer share a stated head size")
+            assert not (spec.mixed and spec.attn_gate), (
+                "the per-head gate is not stated beside convolution layers")
+            if spec.mixed:
+                # the two mixers' tensors stand in one stack a run under
+                # their own names, and the pool has a layer to page
+                assert (len([k for k in spec.kinds if k.conv_kernel]) == 1
+                        and len(spec.kinds) == 2), (
+                    "a model with state layers has one convolution kind "
+                    "and one attention kind")
+                assert spec.cache_layers, (
+                    "a model with state layers has an attention layer too")
             for k in spec.kinds:
                 assert k.n_heads % spec.n_kv_heads == 0, (k, spec.n_kv_heads)
                 assert (0 <= k.rotary_dim <= spec.head_size

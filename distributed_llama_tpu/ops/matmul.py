@@ -107,7 +107,7 @@ def reset_kernel_selections() -> None:
 
 
 def qmatmul(x: jax.Array, w: QTensor | LayerOf, *, use_pallas: bool = False,
-            out_dtype=None) -> jax.Array:
+            out_dtype=None, name: str | None = None) -> jax.Array:
     """y = x @ W^T for W of logical shape (out, in); x: (..., in) -> (..., out).
 
     use_pallas: False = XLA everywhere (the tests' oracle); truthy = every
@@ -124,7 +124,9 @@ def qmatmul(x: jax.Array, w: QTensor | LayerOf, *, use_pallas: bool = False,
     section 6, PR 31), so the gate declines no shape for speed.
 
     w may be a `LayerOf`: the dequant-matmul then reads the matrix out of
-    the stack, every other lowering gets the slice."""
+    the stack, every other lowering gets the slice. `name`: what a device
+    trace shows the dequant-matmul as where a caller tells its projections
+    apart (`q4_mm_conv_in`; "q4_mm" otherwise)."""
     m = math.prod(x.shape[:-1])
     dt = out_dtype or x.dtype
     at = ()
@@ -153,7 +155,8 @@ def qmatmul(x: jax.Array, w: QTensor | LayerOf, *, use_pallas: bool = False,
             return _qmatmul_xla(x, LayerOf(w, at).one(), out_dtype=out_dtype)
         if q4_mm_supported(w, m, stacked=len(at)):
             _record("q4_mm", m, w)
-            return q4_matmul(x, w, at=at, out_dtype=dt)
+            return q4_matmul(x, w, at=at, out_dtype=dt,
+                             **({"name": name} if name else {}))
     _record("xla", m, w)
     return _qmatmul_xla(x, w, out_dtype=out_dtype)
 

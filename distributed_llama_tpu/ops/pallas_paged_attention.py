@@ -101,7 +101,7 @@ def visited_keys(length: int, n_read: int, bt: int, lo: int = 0) -> int:
 
 def _kernel(li_ref, tbl_ref, len_ref, win_ref, q_ref, kn_ref, vn_ref, k_hbm,
             v_hbm, o_ref, kbuf, vbuf, sem, m_ref, l_ref, *, bt, nb, pp, t, g,
-            windowed):
+            windowed, head_size=None):
     """Grid step b: one row's queries, all hk kv heads, against the steps
     of pp pool blocks that hold its committed keys.
 
@@ -118,7 +118,7 @@ def _kernel(li_ref, tbl_ref, len_ref, win_ref, q_ref, kn_ref, vn_ref, k_hbm,
     hk, sk, hs = kbuf.shape[1:]
     length = len_ref[b]
     li = li_ref[0]
-    scale = jnp.float32(1.0 / math.sqrt(hs))
+    scale = jnp.float32(1.0 / math.sqrt(head_size or hs))
     # steps that hold a committed key; a row of length 0 runs none
     n_live = jnp.minimum((length + sk - 1) // sk, -(-nb // pp))
     j0 = 0
@@ -236,11 +236,11 @@ def _kernel(li_ref, tbl_ref, len_ref, win_ref, q_ref, kn_ref, vn_ref, k_hbm,
     jax.lax.fori_loop(0, hk, fold, 0)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("n_read", "interpret", "name"))
+@functools.partial(jax.jit, static_argnames=("n_read", "interpret", "name",
+                                             "head_size"))
 def paged_attention(q, kc, vc, k_new, v_new, tables, lengths, layer_idx, *,
                     n_read: int, interpret: bool | None = None, window=None,
-                    name: str | None = None):
+                    name: str | None = None, head_size: int | None = None):
     """Paged attention of T chunk queries per row against block-table KV.
 
     q: (B, T, hq, hs) in the activation dtype — T = 1 (decode scan step),
@@ -259,6 +259,11 @@ def paged_attention(q, kc, vc, k_new, v_new, tables, lengths, layer_idx, *,
     name: the `pallas_call`'s name, which a device trace shows: a model with
         kinds of layer names the kernel by kind (`paged_attn_window`,
         `paged_attn_full`); None keeps the kernel's own.
+    head_size: the values of a head that are real where hs is a head padded
+        with zeros to whole lanes (Mosaic moves a pool block in tiles of 128
+        lanes and refuses a slice of 64: heads of 64 lie in a pool 128 wide,
+        which is what the chip's tiled memory holds of them anyway): the
+        scores' scale is head_size^-0.5. None: hs.
     Returns (B, T, hq, hs) f32.
     """
     if interpret is None:
@@ -296,7 +301,7 @@ def paged_attention(q, kc, vc, k_new, v_new, tables, lengths, layer_idx, *,
                         pltpu.VMEM((hk, t * g, 1), jnp.float32)],
     )
     body = functools.partial(_kernel, bt=bt, nb=nb, pp=pp, t=t, g=g,
-                             windowed=windowed)
+                             windowed=windowed, head_size=head_size)
     out = pl.pallas_call(
         body,
         grid_spec=grid_spec,

@@ -203,8 +203,10 @@ def q4_mm_supported(w: QTensor, m: int, stacked: int = 0) -> bool:
     return w.data.shape[-1] % 128 == 0 and 2 <= m <= _MAX_ROWS
 
 
-@functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
-def _q4_matmul(x, wp, scales, at, *, out_dtype, interpret: bool = False):
+@functools.partial(jax.jit,
+                   static_argnames=("out_dtype", "interpret", "name"))
+def _q4_matmul(x, wp, scales, at, *, out_dtype, interpret: bool = False,
+               name: str = "q4_mm"):
     """x (M, K) -> (M, N) against the matrix at leading indices `at` (a tuple
     of traced scalars: the layer, or the layer and the expert) of packed
     nibbles (L, N, K/2) or (L, E, N, K/2) + int16 f16-bit scales of the same
@@ -245,7 +247,7 @@ def _q4_matmul(x, wp, scales, at, *, out_dtype, interpret: bool = False):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=VMEM_LIMIT),
-        name="q4_mm",
+        name=name,
         interpret=interpret,
     )(jnp.concatenate([jnp.reshape(a, (1,)) for a in at]).astype(jnp.int32),
       x, x, wp, scales)
@@ -259,7 +261,8 @@ _BODIES_LOWERED = metrics.counter(
 
 
 def q4_matmul(x: jax.Array, w: QTensor, *, at=(), out_dtype=None,
-              interpret: bool | None = None) -> jax.Array:
+              interpret: bool | None = None,
+              name: str = "q4_mm") -> jax.Array:
     """x (..., K) against an i4p QTensor (N, K), or with `at` (traced
     leading indices: the layer, or the layer and the expert) against that
     matrix of one stacked (L, N, K) or (L, E, N, K) -> (..., N), the weights
@@ -280,5 +283,5 @@ def q4_matmul(x: jax.Array, w: QTensor, *, at=(), out_dtype=None,
     y = _q4_matmul(x2, wp, scales,
                    tuple(jnp.asarray(i, jnp.int32) for i in at),
                    out_dtype=jnp.dtype(out_dtype or x.dtype),
-                   interpret=interpret)
+                   interpret=interpret, name=name)
     return y.reshape(*x.shape[:-1], y.shape[-1])

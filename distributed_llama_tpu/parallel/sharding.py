@@ -65,6 +65,16 @@ _BLOCK_SPECS = {
     "sh_gate": P(None, AXIS_TP),
     "sh_up": P(None, AXIS_TP),
     "sh_down": P(None, None, AXIS_TP),
+    # QK-norm's weights are a head's and the same on every shard; the
+    # selection bias rides with the replicated router
+    "rms_qh": P(),
+    "rms_kh": P(),
+    "router_bias": P(),
+    # a convolution layer is whole on every shard: the engine refuses a model
+    # with state layers over more than one tp member (runtime/engine.py)
+    "conv_in": P(),
+    "conv_w": P(),
+    "conv_out": P(),
 }
 
 

@@ -52,6 +52,24 @@ Design:
   bit-exactly the host loop's tokens.
 - Per-slot NaiveCache prefix reuse (dllama-api.cpp:187-232): a new request lands on the
   free slot sharing the longest token prefix and rewinds instead of re-prefilling.
+- A MODEL WITH STATE (ModelSpec.mixed: layers whose mixer is a gated short
+  convolution hold their last two rows of v and no keys) gets the same free
+  rollback for a short horizon and snapshots beyond it (models/forward.py
+  StateCache, docs/PAGED_KV.md "Typed block payload"). The running state is
+  a ring of v rows a slot by position mod 64, so each "free rollback" above
+  holds as written while the writes ahead of a row's frontier stay inside
+  the ring: a parked or idle row's scratch write lands AT its frontier and
+  the two rows it continues from are untouched; a row that stops mid-block,
+  and the rows of a flushed chained super-step (at most two scans past the
+  accepted frontier: the constructor refuses a superstep that does not
+  fit), wrote ring rows and block snapshots at positions that are written
+  again when they are decoded for good. What the ring cannot give is a jump
+  further back: a slot rewind and a prefix hit land on a BLOCK END, where
+  every state layer's state was snapshot into the pool beside the block's
+  keys and values, and the admission seeds the ring from it; a clamped park
+  truncates the reusable history as it does for keys, and the next
+  admission rewinds to a snapshot under it. Speculative verify, the dense
+  per-slot caches, the Q80 tier and KV-block streaming refuse such a model.
 - CROSS-REQUEST prefix reuse (cache/, docs/PREFIX_CACHE.md): a finished slot's
   committed prefix is harvested into a radix-indexed block pool; a new request
   whose prompt shares cached blocks — on ANY slot — seeds its cache rows + pos
@@ -73,7 +91,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..cache.block_pool import PendingRows
-from ..models.forward import compact_rows
+from ..models.forward import (STATE_RING, StateCache, compact_rows,
+                              seed_state)
 from ..models.spec import ModelSpec
 from ..obs import flight, metrics, process, reqctx, trace
 from ..ops.pallas_paged_attention import visited_keys
@@ -120,6 +139,35 @@ _ROLLBACK_TOKENS = metrics.counter(
 _PARKED_ROW_STEPS = metrics.counter(
     "batch_parked_row_steps_total",
     "Row-steps spent parked (rows riding a dispatch without advancing)")
+# The second kind of cached state (a model with state layers, ModelSpec.mixed:
+# models/forward.py StateCache), from the shapes and the live rows like the
+# counters below: nothing is read from the device.
+_STATE_ROWS = metrics.counter(
+    "batch_state_rows_advanced_total",
+    "Rows a dispatch wrote into the state layers' rings for a token of a "
+    "request: real positions x state layers")
+_STATE_SNAPSHOTS = metrics.counter(
+    "batch_state_snapshots_total",
+    "Block snapshots of the state layers' state a dispatch wrote for a "
+    "token of a request: real positions that end a pool block x state layers")
+_BLOCK_ENDS = metrics.counter(
+    "batch_block_ends_total",
+    "Real dispatched positions that end a pool block (position + 1 a "
+    "multiple of the block's tokens), whatever the model: what "
+    "batch_state_snapshots_total is held against, a state layer")
+_STATE_BYTES = metrics.counter(
+    "batch_state_bytes_written_total",
+    "Bytes a dispatch writes into the state layers' rings and snapshots, "
+    "parked rows' scratch writes included: what the second kind of state "
+    "costs a step in HBM writes")
+_STATE_RESTORES = metrics.counter(
+    "paged_kv_state_restores_total",
+    "Admissions (prefix hits and slot rewinds past position 0) that seeded a "
+    "slot's running state from a block's snapshot")
+_STATE_BLOCK_BYTES = metrics.gauge(
+    "kv_pool_state_block_bytes",
+    "Bytes of state snapshot a pool block holds beside its keys and values "
+    "(0: the model has no state layers)")
 # What a dispatch is given against what it needs, from the shapes and the
 # live rows (host integers, nothing read from the device): positions are
 # rows x T (or rows x K of a scan), attention pairs are positions x the
@@ -397,8 +445,27 @@ _pool_block_copy = jax.jit(lambda c, src, dst: c.at[:, dst].set(c[:, src]),
 _pool_block_set = jax.jit(lambda c, dst, rows: c.at[:, dst].set(rows),
                           donate_argnums=(0,))
 
+
+
+def _pool_sides(eng) -> tuple:
+    """The engine's arrays that are indexed by pool block on axis 1: keys,
+    values and, of a model with state layers, the blocks' state snapshots
+    (the typed block payload, docs/PAGED_KV.md)."""
+    vc = eng.v_cache
+    if isinstance(vc, StateCache):
+        return eng.k_cache, vc.rows, vc.snaps
+    return eng.k_cache, vc
+
+
+def _set_pool_sides(eng, sides) -> None:
+    eng.k_cache = sides[0]
+    vc = eng.v_cache
+    eng.v_cache = (vc._replace(rows=sides[1], snaps=sides[2])
+                   if isinstance(vc, StateCache) else sides[1])
+
+
 # The prefix cache's demotion reads a reclaim's victims with ONE gather from
-# both sides of the pool, (n, L, hk, bt, w) a side, block-major so that a
+# every side of the pool, (n, L, hk, bt, w) a side, block-major so that a
 # block's rows are one contiguous piece of the host copy. n is one of a few
 # fixed sizes (a shorter list of ids is filled with the scratch block, a
 # longer one is cut into eights), so every program it can need is known
@@ -441,8 +508,9 @@ class _DemoteRead:
     it, once, wherever the rows are first needed."""
 
     def __init__(self, pool):
-        """`pool`: the engine's (K, V) arrays, (L, N, hk, bt, w) a side; what
-        a block of them looks like is kept, the arrays are not (the next
+        """`pool`: the engine's arrays by block (`_pool_sides`: K, V and a
+        model with state layers' snapshots), (L, N, ...) a side; what a
+        block of them looks like is kept, the arrays are not (the next
         dispatch donates them)."""
         k = pool[0]
         self.shape = k.shape[:1] + k.shape[2:]  # a block's K side
@@ -506,6 +574,7 @@ class _DemoteRead:
                         # the demotion's one device->host copy, started
                         # at issue: picked up here, where the host waits
                         got = [np.asarray(a) for a in out]
+                        # a latent row's empty second side, by K's shape
                         void = np.zeros(got[0].shape[:-1] + (0,), got[0].dtype)
                         rest = iter(got)
                         host.append(tuple(next(rest) if w else void
@@ -534,8 +603,14 @@ class _DemotedRows(PendingRows):
 
     def settle(self):
         part, row = divmod(self.i, _DEMOTE_SIZES[-1])
-        k, v = self.read.settle()[part]
-        return k[row], v[row]
+        # (k, v), and the block's state snapshot where the pool has one
+        return tuple(a[row] for a in self.read.settle()[part])
+
+
+_NO_KV_STREAM = (
+    "a model with state layers (a gated short convolution) is not supported "
+    "by KV-block streaming between replicas (cache/wire.py): a block's "
+    "state snapshot does not travel with its keys and values")
 
 
 class _StaleEpoch(BaseException):
@@ -809,9 +884,34 @@ class BatchEngine:
                 + ("the Q80 cold tier (prefix_cache_q80)" if prefix_cache_q80
                    else "the dense host prefix cache (paged_kv off)")
                 + ": both hold per-head keys and values")
+        if spec.mixed:
+            # what cannot carry a state that is not a list of positions says
+            # so here, in one sentence, before anything is built
+            why = (
+                "the dense per-slot caches (paged_kv off, or a dense "
+                "PrefixCache instance) keep no snapshot a rewind could land "
+                "on" if kv_pool_cfg is None else
+                "speculative verify (speculative > 0, draft_model) rejects "
+                "a suffix it has already written snapshots for"
+                if speculative or draft_model is not None else
+                "the Q80 cold tier (prefix_cache_q80) holds per-head keys "
+                "and values" if prefix_cache_q80 else
+                f"superstep {superstep}: a flushed chained scan rolls back "
+                f"{2 * superstep} positions, and a state layer's ring keeps "
+                f"{STATE_RING - spec.state_rows}"
+                if 2 * superstep + spec.state_rows > STATE_RING else None)
+            if why:
+                raise ValueError(
+                    "a model with state layers (a gated short convolution) "
+                    f"is not supported by {why}")
         self._eng = Engine(spec, params, tokenizer, batch=slots,
                            kv_pool=kv_pool_cfg, moe_stats=spec.is_moe,
                            **engine_kw)
+        if spec.mixed and self._eng.kv_pool is None:
+            raise ValueError(
+                "a model with state layers (a gated short convolution) is "
+                "served from the device block pool, which this engine's "
+                "sharding or KV storage turned off")
         _KV_ROW_BYTES.set(spec.cache_row_bytes(
             self._eng.k_cache.dtype.itemsize))
         # attention's per-layer lower key bound, as (window, share of layers)
@@ -838,8 +938,9 @@ class BatchEngine:
             self._tables_dev = None  # rebuilt lazily after table edits
             # what a demoted block's rows weigh on the way to the host
             self._kv_block_bytes = sum(
-                c.nbytes // c.shape[1]
-                for c in (self._eng.k_cache, self._eng.v_cache))
+                c.nbytes // c.shape[1] for c in _pool_sides(self._eng))
+            _STATE_BLOCK_BYTES.set(spec.state_block_bytes(
+                self._eng.k_cache.dtype.itemsize) if spec.mixed else 0)
         # admission seeding cost readout (bench.py shared-prefix columns):
         # host→device KV bytes moved and wall time spent seeding slots —
         # ~0 bytes on the paged path (remap), the full fetched span dense
@@ -1087,6 +1188,8 @@ class BatchEngine:
         if klass not in CLASSES:
             raise InvalidRequest(
                 f"unknown scheduling class {klass!r} (want one of {CLASSES})")
+        if export_kv and self.spec.mixed:
+            raise InvalidRequest(_NO_KV_STREAM)
         c = ctx if ctx is not None else reqctx.current()
         tenant = tenant or (c.tenant if c is not None else "") \
             or DEFAULT_TENANT
@@ -1587,6 +1690,11 @@ class BatchEngine:
             return min(n, len(full) - 1)
         best = max(free, key=common)
         rewind = common(best)
+        if self.spec.mixed:
+            # a state layer's state is not a list of positions: a rewind
+            # lands where a snapshot exists, on a block end, and prefill
+            # goes on from there
+            rewind -= rewind % self._kv_bt
         reuse = rewind
         if self.kv_pool is not None:
             # paged admission (docs/PAGED_KV.md): the radix directory hit
@@ -1594,6 +1702,12 @@ class BatchEngine:
             # request's context so the batch.prefix_seed span attributes
             with reqctx.use(req.ctx):
                 reuse = self._paged_adopt(best, req, rewind, full)
+                if self.spec.mixed and reuse:
+                    # continue from the snapshot of the block that ends there
+                    self._seed_state(
+                        best.index, best.blocks[reuse // self._kv_bt - 1],
+                        reuse)
+                    _STATE_RESTORES.inc()
         elif self.prefix_cache is not None:
             # [0, reuse) is served by the slot's own resident rows; anything
             # the radix seed adds on top is counted as hit_tokens inside.
@@ -1870,12 +1984,15 @@ class BatchEngine:
         whatever dispatch will write the freed blocks, and NOT waited for:
         the cold tier holds the pending read and _settle_demotions makes
         host arrays of it while a later dispatch runs."""
-        pool = (self._eng.k_cache, self._eng.v_cache)
+        pool = _pool_sides(self._eng)
         read = _DemoteRead(pool)
         with trace.span("batch.demote") as sp:
             self.prefix_cache.reclaim(deficit, read.block)
             reads = read.issue(pool) if read.bids else 0
             sp.add(blocks=len(read.bids), reads=reads)
+            if self.spec.mixed:  # of the bytes read, the snapshots'
+                sp.add(state_bytes=len(read.bids) * pool[2].nbytes
+                       // pool[2].shape[1])
         if reads:
             from ..cache.device_pool import _DEMOTE_READS
 
@@ -1904,7 +2021,7 @@ class BatchEngine:
         gather at every size a reclaim can issue, so that no eviction ever
         compiles in the middle of serving (the benchmark's warm-up calls it
         for that)."""
-        pool = (self._eng.k_cache, self._eng.v_cache)
+        pool = _pool_sides(self._eng)
         if not self._demote_warm:
             self._demote_warm = True
             for n in _DEMOTE_SIZES[1:]:
@@ -1912,6 +2029,13 @@ class BatchEngine:
                 warm.bids = [0] * n
                 warm.issue(pool)
                 warm.settle()
+            if self.spec.mixed:
+                # an admission's seed of a slot's running state, compiled
+                # here too: what it writes (the scratch block's snapshot
+                # behind slot 0's position bt) no sequence reads, a slot's
+                # ring being trusted only where its own sequence wrote it
+                # or an admission seeded it
+                self._seed_state(0, 0, self._kv_bt)
         read = _DemoteRead(pool)
         rows = read.block(bid)
         read.issue(pool)
@@ -1944,8 +2068,8 @@ class BatchEngine:
             if not self.kv_pool.shared(bid):
                 continue
             nb = self._paged_alloc(1, exclude=slot)[0]
-            eng.k_cache = _pool_block_copy(eng.k_cache, bid, nb)
-            eng.v_cache = _pool_block_copy(eng.v_cache, bid, nb)
+            _set_pool_sides(eng, [_pool_block_copy(c, bid, nb)
+                                  for c in _pool_sides(eng)])
             self.kv_pool.decref([bid])
             self.kv_pool.note_cow()
             slot.blocks[idx] = nb
@@ -1973,6 +2097,9 @@ class BatchEngine:
             try:
                 faults.fire("batch.cache_seed", slot=slot.index)
                 lease = pc.lookup(full, cap=self.spec.seq_len - 1)
+                if lease is not None and self.spec.mixed:
+                    # whole blocks alone: a hit lands on a block's snapshot
+                    pc.shrink(lease, lease.tokens - lease.tokens % bt)
                 if lease is not None and lease.tokens <= rewind:
                     pc.mark_unused(lease)
                     lease = None
@@ -2004,13 +2131,14 @@ class BatchEngine:
                         # promote() takes the DIRECTORY's own ref — drop
                         # the allocation ref right after, or every
                         # promotion leaks one never-freeable block
-                        k, v = pc.fetch_cold(h)
+                        # (k, v), and the block's state snapshot with them
+                        rows = pc.fetch_cold(h)
                         nb = self._paged_alloc(1, exclude=slot)[0]
-                        eng.k_cache = _pool_block_set(
-                            eng.k_cache, nb, _upload(k, eng.dtype))
-                        eng.v_cache = _pool_block_set(
-                            eng.v_cache, nb, _upload(v, eng.dtype))
-                        moved += k.nbytes + v.nbytes
+                        _set_pool_sides(eng, [
+                            _pool_block_set(c, nb, _upload(a, eng.dtype))
+                            for c, a in zip(_pool_sides(eng), rows,
+                                            strict=True)])
+                        moved += sum(a.nbytes for a in rows)
                         pc.promote(node, nb)
                         self.kv_pool.decref([nb])
                         tier, h = node.handle
@@ -2022,8 +2150,8 @@ class BatchEngine:
                         # slot can append into without touching the
                         # directory's committed rows
                         nb = self._paged_alloc(1, exclude=slot)[0]
-                        eng.k_cache = _pool_block_copy(eng.k_cache, h, nb)
-                        eng.v_cache = _pool_block_copy(eng.v_cache, h, nb)
+                        _set_pool_sides(eng, [_pool_block_copy(c, h, nb)
+                                              for c in _pool_sides(eng)])
                         self.kv_pool.note_cow()
                         blocks.append(nb)
         except Exception as e:
@@ -2061,6 +2189,16 @@ class BatchEngine:
         if rewind % bt:
             self._paged_cow(slot, rewind, rewind + 1)
         return rewind
+
+    def _seed_state(self, slot: int, bid: int, pos: int) -> None:
+        """A model with state layers: slot `slot`'s running state at position
+        `pos` (a block boundary > 0: a prefix hit's or a rewind's) becomes
+        what block `bid`, which ends there, snapshot: one small jitted copy
+        on the device (models/forward.py seed_state)."""
+        eng = self._eng
+        eng.v_cache = seed_state(eng.v_cache, np.int32(slot), np.int32(bid),
+                                 np.int32(pos), len(self.spec.state_layers),
+                                 self.spec.state_rows)
 
     def _dispatched(self, kind: str, call):
         """Run one device dispatch with transient-fault retry: classify()
@@ -2197,6 +2335,20 @@ class BatchEngine:
             _LATENT_DISPATCH_ROWS.inc(dispatched * spec.n_layers)
         _POSITIONS_REAL.inc(sum(n for _, n in real))
         _ATTN_PAIRS_REAL.inc(sum(n * p + n * (n + 1) // 2 for p, n in real))
+        bt = getattr(self, "_kv_bt", 0)
+        if bt:
+            ends = sum((p + n) // bt - p // bt for p, n in real)
+            _BLOCK_ENDS.inc(ends)
+            if spec is not None and spec.mixed:
+                layers = len(spec.state_layers)
+                _STATE_ROWS.inc(layers * sum(n for _, n in real))
+                _STATE_SNAPSHOTS.inc(layers * ends)
+                # what the program writes, scratch included: a ring row a
+                # computed row a layer, and a block's snapshot a block end
+                # (every row's of a scan step or a lone token, the chunk's)
+                row = spec.dim * self._eng.k_cache.dtype.itemsize
+                _STATE_BYTES.inc(layers * row * (
+                    computed + spec.state_rows * ends))
 
     @staticmethod
     def _count_moe(stats) -> int:
@@ -2655,7 +2807,7 @@ class BatchEngine:
         same way it truncates the harvest."""
         bt = self._kv_bt or (self.prefix_cache.block_tokens
                              if self.prefix_cache is not None else 0)
-        if bt <= 0:
+        if bt <= 0 or self.spec.mixed:  # submit() refused export_kv
             return None
         p = min(prompt_len, len(slot.history))
         if slot.clamp_pos is not None:
@@ -2688,6 +2840,8 @@ class BatchEngine:
         pc = self.prefix_cache
         if pc is None:
             return 0
+        if self.spec.mixed:
+            raise ValueError(_NO_KV_STREAM)
         bt = pc.block_tokens
         n = min(len(tokens) // bt, len(blocks))
         if n <= 0:

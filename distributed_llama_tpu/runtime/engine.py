@@ -180,6 +180,8 @@ class Engine:
         # layers, say so: no silent wrong path
         if spec.latent or spec.lead_layers or spec.kinds:
             what = ("a latent cache row (kv_lora_rank > 0)" if spec.latent
+                    else "layers that hold a state and no keys and values"
+                    if spec.mixed
                     else "kinds of attention layer (ModelSpec.kinds)"
                     if spec.kinds
                     else "a leading dense stack (lead_layers > 0)")
@@ -191,6 +193,15 @@ class Engine:
                 raise ValueError(
                     f"sp={sp}: the sequence-sharded (ring attention) cache "
                     f"does not support {what}")
+        if spec.mixed:
+            # a state that is not a list of positions (models/forward.py
+            # StateCache): whole on one tp member, rows unsharded
+            if (tp or 1) > 1 or dp > 1:
+                raise ValueError(
+                    f"tp={tp}, dp={dp}: a model with state layers (a gated "
+                    "short convolution) runs whole on one chip; its state is "
+                    "not sharded")
+            tp = 1
         if self.paged and tp is None:
             tp = 1  # paged mode is single-chip; don't let the mesh grab every device
         # Device-resident paged KV (docs/PAGED_KV.md): kv_pool=(n_blocks,
@@ -386,11 +397,29 @@ class Engine:
             n_blocks, bt = self.kv_pool
             hk = effective_kv_heads(self.spec, self.tp)
             sh = NamedSharding(self.mesh, P(None, None, _TP))
-            # a latent spec: one row a token, the second side empty
-            return tuple(
+            # a latent spec: one row a token, the second side empty; a spec
+            # with state layers: the pool's layer axis holds the layers that
+            # own rows, and the state stands beside the second side
+            layers = len(self.spec.cache_layers)
+            widths = self.spec.cache_widths
+            from ..platform_env import interpret_requested
+
+            if self.paged_kernel and not interpret_requested():
+                # Mosaic moves a pool block in tiles of 128 lanes and refuses
+                # a slice of 64 (heads of 64): the pool's rows are whole
+                # lanes, zeros behind a head's values, which is what the
+                # chip's tiled memory holds of a narrower row anyway
+                widths = tuple(-(-w // 128) * 128 for w in widths)
+            kc, vc = (
                 jax.device_put(jnp.zeros(
-                    (self.spec.n_layers, n_blocks, hk, bt, w), self.dtype), sh)
-                for w in self.spec.cache_widths)
+                    (layers, n_blocks, hk, bt, w), self.dtype), sh)
+                for w in widths)
+            if self.spec.mixed:
+                from ..models.forward import StateCache, init_state
+
+                vc = StateCache(vc, *init_state(self.spec, self.batch,
+                                                n_blocks, self.dtype))
+            return kc, vc
         from ..parallel.tp import init_sharded_kv_cache
 
         return init_sharded_kv_cache(self.spec, self.mesh, batch=self.batch,
@@ -409,6 +438,14 @@ class Engine:
         committed rows — restore the ring from the authoritative host store
         (zeros for never-written slots are masked arithmetically)."""
         assert 0 <= pos <= self.pos, f"seek({pos}) past live context {self.pos}"
+        if self.spec.mixed and 0 < pos < self.pos:
+            from ..models.forward import STATE_RING
+
+            if self.pos - pos > STATE_RING - self.spec.state_rows - 1:
+                raise ValueError(
+                    f"seek({pos}) from {self.pos}: a state layer's running "
+                    f"state reaches {STATE_RING} positions back and this "
+                    "cache keeps no snapshot; rewind to 0 and prefill")
         if self.paged and pos < self.pos:
             L, B, hk, R, hs = self.k_cache.shape
             n_stale = self.pos - pos

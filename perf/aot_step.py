@@ -43,9 +43,10 @@ from jax.sharding import SingleDeviceSharding  # noqa: E402
 from benchmark import cells  # noqa: E402
 from benchmark import weights as W  # noqa: E402
 from distributed_llama_tpu.models.forward import (  # noqa: E402
-    compact_rows, forward)
+    StateCache, compact_rows, forward, init_state)
 from distributed_llama_tpu.models.params import (  # noqa: E402
-    hold_dense, prepare_for_pallas, stack_names)
+    _FUSE_GROUPS, hold_dense, prepare_for_pallas, run_tensor_shapes,
+    stack_names)
 from distributed_llama_tpu.ops.rope import RopeTables  # noqa: E402
 
 CUT = 4  # layers drawn: one period of any per-layer pattern in the cells
@@ -94,16 +95,26 @@ def model_shapes(config: str, chip, **keys):
         hold_dense(W.to_program_params(W.make_weights(cut, 7), cut),
                    jnp.bfloat16), spec=family.model_spec(cut))
     spec = family.model_spec(full)
-    depths = {run.name: run.depth for run in spec.runs()}
+    # a tensor is as deep as its run, a mixer's of a model with state layers
+    # as its kind's layers of the run (a fused group as its first member)
+    by_run = {run.name: (run, run_tensor_shapes(spec, run))
+              for run in spec.runs()}
+
+    def depth(st, name):
+        run, own = by_run[st]
+        name = _FUSE_GROUPS.get(name, (name,))[0]
+        return own[name][0][0] if name in own else run.depth
+
     shapes = jax.tree.map(lambda a: _sds(a, chip), params)
     for st in stack_names(params):
         for name, t in params[st].items():
             # the full depth, and where fewer experts were drawn than the
             # file holds every expert (the router's rows with them)
-            wide = {"router": spec.n_router}.get(
+            wide = {"router": spec.n_router,
+                    "router_bias": spec.n_router}.get(
                 name, spec.n_experts if name.startswith("moe_") else None)
             shapes[st][name] = jax.tree.map(
-                lambda a, n=depths[st], e=wide: _sds(a, chip, (
+                lambda a, n=depth(st, name), e=wide: _sds(a, chip, (
                     n, *((e,) if e else a.shape[1:2]), *a.shape[2:])), t)
     return spec, shapes, full
 
@@ -114,8 +125,10 @@ def pool_shape(spec, cfg):
     pool of a few MB XLA moves to another memory space (`S(1)` in its
     layout, Laguna's at 256 blocks under a compact chunk's smaller
     temporaries) and the text reads as a copy the cell's program has not."""
-    return [(spec.n_layers, cfg["engine"]["kv_pool_blocks"], spec.n_kv_heads,
-             cfg["engine"]["kv_block_tokens"], w) for w in spec.cache_widths]
+    return [(len(spec.cache_layers), cfg["engine"]["kv_pool_blocks"],
+             spec.n_kv_heads, cfg["engine"]["kv_block_tokens"],
+             -(-w // 128) * 128)  # whole lanes, as runtime/engine.py pads
+            for w in spec.cache_widths]
 
 
 def held_pools(spec, cfg):
@@ -136,6 +149,11 @@ def compile_step(spec, shapes, cfg, chip, *, rows: int = 8, chunk: int = 64,
     bt = cfg["engine"]["kv_block_tokens"]
     kc, vc = (jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=chip)
               for s in pool_shape(spec, cfg))
+    if spec.mixed:  # the state layers' ring and the blocks' snapshots
+        vc = StateCache(vc, *(
+            jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)
+            for a in jax.eval_shape(lambda: init_state(
+                spec, rows, cfg["engine"]["kv_pool_blocks"], jnp.bfloat16))))
 
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)

@@ -101,6 +101,67 @@ def test_prefill_then_decode_equals_one_pass(window, kind):
     _check(chunked, 0, one, 6, got[0, -1], want[0, -1])
 
 
+@pytest.mark.parametrize("kind", KINDS)
+def test_prefill_then_decode_equals_one_pass_with_state_layers(kind):
+    """Layers whose mixer is a gated short convolution (LFM2): a prefill in
+    chunks of 64, 8 and 1 and a decode step against ONE pass over the 74
+    tokens. A chunk's first positions read the v rows behind them from the
+    slot's ring, which the chunk before committed; the pool also gets each
+    block end's snapshot, which is that state."""
+    from distributed_llama_tpu.models.forward import (STATE_RING, StateCache,
+                                                      init_state)
+    from distributed_llama_tpu.models.spec import LayerKind, RouterScore
+
+    spec = _spec(ArchType.MIXTRAL, n_layers=5, seq_len=128, head_dim=16,
+                 rope_type=RopeType.FALCON, n_experts=4, n_active_experts=2,
+                 hidden_dim=32, qk_norm=True, router_bias=True,
+                 router_score=RouterScore.SIGMOID, lead_layers=1,
+                 lead_hidden_dim=96,
+                 kinds=(LayerKind("conv", 4, conv_kernel=3),
+                        LayerKind("full", 4)), layer_kinds=(0, 1, 0, 0, 1))
+    params = init_random_params(spec, FloatType.F32, seed=13)
+    rope = RopeTables.create(spec)
+    row = np.random.default_rng(2).integers(3, 128, 74).tolist()
+
+    def caches():
+        c = Cache(spec, kind)
+        if kind == "pool":
+            shape = (2,) + c.k.shape[1:]  # the two attention layers own rows
+            c.k, v = jnp.zeros(shape), jnp.zeros(shape)
+            c.v = StateCache(v, *init_state(spec, 1, shape[1], jnp.float32))
+        assert isinstance(c.v, StateCache) and c.k.shape[0] == 2
+        return c
+
+    def fwd(*a, **kw):
+        return forward(params, spec, rope, *a, **kw)
+
+    one, chunked = caches(), caches()
+    want = one.step(fwd, [row], 0)
+    got, at = [], 0
+    for n in (64, 8, 1, 1):
+        got.append(chunked.step(fwd, [row[at:at + n]], at))
+        at += n
+    np.testing.assert_allclose(np.concatenate(got, axis=1), want, atol=1e-5,
+                               rtol=1e-5)
+    # the ring: v at the last STATE_RING positions, the same either way
+    live = [p % STATE_RING for p in range(74 - STATE_RING, 74)]
+    np.testing.assert_allclose(np.asarray(chunked.v.ring)[0, live, :3],
+                               np.asarray(one.v.ring)[0, live, :3], atol=1e-6)
+    np.testing.assert_allclose(np.asarray(chunked.k), np.asarray(one.k),
+                               atol=1e-6)
+    if kind == "pool":
+        # blocks of 8: the snapshots of the nine finished blocks agree, and
+        # block 8's (positions 64..71) is the ring's rows 70 and 71
+        snaps_c, snaps_1 = (np.asarray(c.v.snaps)[0] for c in (chunked, one))
+        np.testing.assert_allclose(snaps_c, snaps_1, atol=1e-6)
+        blk = int(np.asarray(chunked.tables)[0, 8])
+        ring = np.asarray(chunked.v.ring)[0]
+        np.testing.assert_allclose(
+            snaps_c[blk, :6].reshape(2, 3, -1),
+            np.stack([ring[70 % STATE_RING, :3], ring[71 % STATE_RING, :3]]),
+            atol=1e-6)
+
+
 def test_fused_decode_kernel_equals_one_pass():
     """The one-row decode glue: use_pallas + T = 1 + a scalar position routes
     through the fused decode-attention kernel (interpret off-TPU). Pins the

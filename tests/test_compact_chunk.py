@@ -57,7 +57,7 @@ CASES = [
     # every family: a 64-token chunk, every other slot riding
     *[(toy, 64, 3, 4, 1, True, False, False) for toy in (
         "tiny-dense", "tiny-moe", "tiny-gelu-moe", "tiny-smallthinker",
-        "tiny-lead", "tiny-axk1", "tiny-laguna")],
+        "tiny-lead", "tiny-axk1", "tiny-laguna", "tiny-lfm2")],
     # chunks of 64 and 8 with 0, 1 and slots - 1 riders, at the cells' 8 slots
     ("tiny-dense", 64, 0, 8, 1, True, False, False),
     ("tiny-dense", 64, 1, 8, 1, True, False, False),
@@ -69,6 +69,9 @@ CASES = [
     ("tiny-dense", 5, 1, 4, 1, True, False, True),
     ("tiny-dense", 5, 1, 4, 1, False, False, True),
     ("tiny-axk1", 64, 1, 4, 1, True, False, True),
+    ("tiny-lfm2", 64, 1, 4, 1, True, False, True),
+    # layers with a state: a chunk of 8 with every other slot riding
+    ("tiny-lfm2", 8, 3, 4, 1, True, False, False),
     # the dense per-row cache
     ("tiny-dense", 64, 3, 4, 1, False, False, False),
     ("tiny-moe", 8, 1, 4, 1, False, False, False),
@@ -84,6 +87,7 @@ CASES = [
     ("tiny-dense", 8, 3, 4, 1, True, True, False),
     ("tiny-moe", 8, 1, 4, 1, True, True, False),
     ("tiny-axk1", 8, 1, 4, 1, True, True, False),
+    ("tiny-lfm2", 8, 1, 4, 1, True, True, False),
 ]
 
 
@@ -109,7 +113,8 @@ def test_a_compact_chunk_is_the_rectangle_at_every_real_position(
 
         def run(tokens, starts, kc, vc):
             args = (eng.params, eng.rope, jnp.asarray(tokens, jnp.int32),
-                    jnp.copy(kc), jnp.copy(vc), jnp.asarray(starts, jnp.int32))
+                    jnp.copy(kc), jax.tree.map(jnp.copy, vc),
+                    jnp.asarray(starts, jnp.int32))
             logits, kc, vc, *_ = step(*args, *(() if tables is None
                                                else (tables,)))
             return np.asarray(logits), kc, vc
@@ -134,6 +139,13 @@ def test_a_compact_chunk_is_the_rectangle_at_every_real_position(
         np.testing.assert_allclose(got[lead, 0], want[lead, -1], **tol)
         for b in ride:
             np.testing.assert_allclose(got[b, 0], want[b, 0], **tol)
+        if isinstance(vc_r, F.StateCache):
+            # layers with a state: the ring rows every real position wrote
+            # and the snapshots of the block ends the chunk crossed are the
+            # rectangle's; what lay below a row's start is untouched
+            _same_state(be, vc_c, vc_r, vc0, tables_np, lead, ride, chunk,
+                        slots, tol)
+            vc_c, vc_r, vc0 = vc_c.rows, vc_r.rows, vc0.rows
         for c_got, c_want in ((kc_c, kc_r), (vc_c, vc_r)):
             if c_want.size == 0:  # a latent spec's empty second side
                 continue
@@ -149,6 +161,29 @@ def test_a_compact_chunk_is_the_rectangle_at_every_real_position(
                              b, 0, HISTORY))
     finally:
         be.close()
+
+
+def _same_state(be, got, want, before, tables_np, lead, ride, chunk, slots,
+                tol):
+    n = len(be.spec.state_layers)
+    ring_g, ring_w, ring_0 = (np.asarray(c.ring) for c in (got, want, before))
+    w = ring_g.shape[1]
+    for b, m in [(lead, chunk)] + [(b, 1) for b in ride]:
+        at = [(HISTORY + i) % w for i in range(m)][-w:]
+        np.testing.assert_allclose(ring_g[b, at, :n], ring_w[b, at, :n], **tol)
+    for b in range(slots):
+        if b == lead and chunk + 2 > w:
+            continue  # the lead's chunk went once round its ring
+        at = [p % w for p in range(max(HISTORY - 2, 0), HISTORY)]
+        np.testing.assert_array_equal(ring_g[b, at], ring_0[b, at])
+    snaps_g, snaps_w = np.asarray(got.snaps)[0], np.asarray(want.snaps)[0]
+    bt = be._kv_bt
+    ends = [p for p in range(HISTORY, HISTORY + chunk) if (p + 1) % bt == 0]
+    assert chunk < bt or ends
+    for p in ends:
+        blk = tables_np[lead, p // bt]
+        assert np.abs(snaps_w[blk]).max() > 0
+        np.testing.assert_allclose(snaps_g[blk], snaps_w[blk], **tol)
 
 
 def test_programs_without_a_lead_row_hold_no_row_map(monkeypatch):

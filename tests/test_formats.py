@@ -52,6 +52,42 @@ def test_mfile_roundtrip(tmp_path, arch, ftype):
         np.testing.assert_allclose(a, b, atol=1e-6, err_msg=name)
 
 
+@pytest.mark.parametrize("ftype", [FloatType.F32, FloatType.Q40])
+def test_mfile_roundtrip_of_a_model_with_state_layers(tmp_path, ftype):
+    """The header keys and tensors LFM2 brought: a convolution kind
+    (`LayerKind.conv_kernel`), QK-norm, the selection bias; in the file a
+    layer's own kind's tensors in layer order, in `params` each mixer's
+    stacked over its kind's layers of the run."""
+    from distributed_llama_tpu.models.spec import LayerKind, RouterScore
+
+    spec = tiny_spec(ArchType.MIXTRAL, n_layers=5, head_dim=16, qk_norm=True,
+                     router_bias=True, router_score=RouterScore.SIGMOID,
+                     lead_layers=1, lead_hidden_dim=64,
+                     kinds=(LayerKind("conv", 4, conv_kernel=3),
+                            LayerKind("full", 4, rope_theta=1e6)),
+                     layer_kinds=(0, 1, 0, 0, 1))
+    params = init_random_params(spec, ftype, seed=4)
+    assert params["blocks"]["conv_in"].shape[0] == 2
+    assert params["blocks"]["wq"].shape[0] == 2
+    assert params["blocks"]["router_bias"].shape[0] == 4
+    path = str(tmp_path / "mixed.m")
+    write_model(path, spec, params_file_order(spec, params), ftype)
+    spec2, params2 = load_model(path)
+    assert (spec2.qk_norm, spec2.router_bias, spec2.layer_kinds) == (
+        True, True, (0, 1, 0, 0, 1))
+    assert [k.conv_kernel for k in spec2.kinds] == [3, 0]
+    assert spec2.kinds[1].rope_theta == 1e6 and spec2.mixed
+    assert sorted(params2) == sorted(params)
+    for st in ("lead", "blocks"):
+        assert set(params2[st]) == set(params[st])
+        for name in params[st]:
+            a, b = params[st][name], params2[st][name]
+            a = a.to_numpy() if hasattr(a, "to_numpy") else np.asarray(a)
+            b = b.to_numpy() if hasattr(b, "to_numpy") else np.asarray(b)
+            assert a.shape == b.shape, name
+            np.testing.assert_allclose(a, b, atol=1e-6, err_msg=name)
+
+
 def test_mfile_seq_len_clamp(tmp_path):
     spec = tiny_spec()
     params = init_random_params(spec, FloatType.F32, seed=2)

@@ -449,13 +449,14 @@ def test_paged_attn_bench_parity_gate():
 # as the reference: WHICH blocks are demoted, in which order, when a node
 # turns cold and what rows a later hit uploads must not have changed.
 
-TOYS = ("tiny-dense", "tiny-axk1", "tiny-laguna")
+TOYS = ("tiny-dense", "tiny-axk1", "tiny-laguna", "tiny-lfm2")
 
 
 @pytest.fixture(scope="module", params=TOYS)
 def toy(request):
     """(name, spec, params) of a toy configuration: dense keys and values,
-    a latent row with an empty second side, two kinds of layer."""
+    a latent row with an empty second side, two kinds of layer, and layers
+    whose state rides the blocks as a third, typed payload."""
     from benchmark import cells
     from benchmark import weights as W
 
@@ -475,6 +476,14 @@ def _engine(toy, **kw):
           "kv_pool_blocks": 20, "prefix_cache_blocks": 5, "tp": 1,
           "dtype": jnp.float32, **kw}
     return BatchEngine(spec, params, None, **kw)
+
+
+def _sides(eng, bid):
+    """What the pool holds of block `bid`, a host array a side: keys, values
+    and, of a model with state layers, the block's state snapshot."""
+    from distributed_llama_tpu.runtime.batch_engine import _pool_sides
+
+    return tuple(np.asarray(c[:, bid]) for c in _pool_sides(eng))
 
 
 def _reference_reclaim(pc, n_blocks, read_block):
@@ -533,7 +542,7 @@ def _as_reference(be, reads):
 
     def read_block(bid):
         reads.append(bid)
-        return np.asarray(eng.k_cache[:, bid]), np.asarray(eng.v_cache[:, bid])
+        return _sides(eng, bid)
 
     be._demote = lambda deficit: _reference_reclaim(pc, deficit, read_block)
 
@@ -626,8 +635,7 @@ def test_hit_on_a_pending_payload_promotes_the_right_rows(toy):
         pc.release(lease)
         assert len(held) == 4
         for bid in held:
-            held[bid] = (np.asarray(eng.k_cache[:, bid]),
-                         np.asarray(eng.v_cache[:, bid]))
+            held[bid] = _sides(eng, bid)
         be._settle_demotions = lambda force=False: None  # nobody settles
         be._paged_reclaim(be.kv_pool.n_blocks)
         assert pc.unsettled == 4 and pc.stats()["cold_blocks"] == 4
@@ -635,11 +643,11 @@ def test_hit_on_a_pending_payload_promotes_the_right_rows(toy):
         assert got == want
         assert pc.stats()["promoted_blocks"] == 4
         lease = pc.lookup(prompt + [9])
-        for node, (k, v) in zip(lease.nodes, held.values()):
+        for node, rows in zip(lease.nodes, held.values()):
             tier, bid = node.handle
             assert tier == "dev"
-            assert np.array_equal(np.asarray(eng.k_cache[:, bid]), k)
-            assert np.array_equal(np.asarray(eng.v_cache[:, bid]), v)
+            for got, want_rows in zip(_sides(eng, bid), rows, strict=True):
+                assert np.array_equal(got, want_rows)
         pc.release(lease)
     finally:
         be.close()
@@ -699,8 +707,7 @@ def test_reset_and_close_with_payloads_pending(toy):
     try:
         ids = _fill_directory(be, 6)
         pc, eng = be.prefix_cache, be._eng
-        want = {b: (np.asarray(eng.k_cache[:, b]), np.asarray(eng.v_cache[:, b]))
-                for b in ids}
+        want = {b: _sides(eng, b) for b in ids}
         nodes = {n.handle[1]: n for n in pc.radix.root.children.values()}
         be._demote(6)
         assert pc.unsettled == 6
@@ -709,9 +716,8 @@ def test_reset_and_close_with_payloads_pending(toy):
         for b, node in nodes.items():
             tier, h = node.handle
             assert tier == "cold" and pc.cold.pending(h) is None
-            k, v = pc.fetch_cold(h)
-            assert np.array_equal(k, want[b][0]) and k.shape == want[b][0].shape
-            assert np.array_equal(v, want[b][1]) and v.shape == want[b][1].shape
+            for got, rows in zip(pc.fetch_cold(h), want[b], strict=True):
+                assert np.array_equal(got, rows) and got.shape == rows.shape
     finally:
         be.close()
     be = _engine(toy, kv_pool_blocks=32, prefix_cache_blocks=16)

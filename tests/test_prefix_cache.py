@@ -259,6 +259,48 @@ def test_cache_on_off_token_identical_greedy_and_stochastic(engines):
     _settle(lambda: be_on.prefix_cache.total_refs() == 0)  # every lease released
 
 
+def test_a_prefix_hit_on_a_model_with_state_layers_equals_cold_prefill():
+    """Layers that hold a state (LFM2's convolutions): a hit of n whole
+    blocks continues from the n-th block's snapshot and gives the tokens a
+    cold prefill gives, greedy and seeded-stochastic; the hit is whole blocks
+    alone (32 of the 34 shared tokens), and the engine without the paged
+    pool refuses the model."""
+    import jax.numpy as jnp
+
+    from distributed_llama_tpu.models.spec import LayerKind, RouterScore
+    from distributed_llama_tpu.runtime.batch_engine import BatchEngine
+
+    spec = ModelSpec(
+        arch_type=ArchType.MIXTRAL, dim=64, hidden_dim=32, n_layers=4,
+        n_heads=4, n_kv_heads=2, vocab_size=256, seq_len=128, n_experts=4,
+        n_active_experts=2, head_dim=16, rope_type=RopeType.FALCON,
+        qk_norm=True, router_bias=True, router_score=RouterScore.SIGMOID,
+        kinds=(LayerKind("conv", 4, conv_kernel=3), LayerKind("full", 4)),
+        layer_kinds=(0, 1, 0, 0)).resolved()
+    params = init_random_params(spec, FloatType.Q40, seed=19)
+    kw = dict(slots=2, tp=1, kv_block_tokens=8, dtype=jnp.float32)
+    with pytest.raises(ValueError, match="dense per-slot caches"):
+        BatchEngine(spec, params, paged_kv=False, **kw)
+    be_off = BatchEngine(spec, params, prefix_cache=False, **kw)
+    be_on = BatchEngine(spec, params, prefix_cache=True, **kw)
+    try:
+        prompts = [SHARED + [200 + i] for i in range(3)] + [[1, 99, 98]]
+        plans = [(0.0, 0), (0.8, 7), (0.8, 7), (0.0, 0)]
+        wants = [_run(be_off, p, 8, t, s) for p, (t, s) in zip(prompts, plans)]
+        got = [_run(be_on, prompts[0], 8, *plans[0])]
+        unrelated = _run(be_on, prompts[3], 8, *plans[3])
+        _run(be_on, [7, 8, 9, 10], 4)  # dirties the other slot's history too
+        mid = be_on.prefilled_tokens
+        got.append(_run(be_on, prompts[1], 8, *plans[1]))
+        assert be_on.prefilled_tokens - mid == len(prompts[1]) - 32
+        got.append(_run(be_on, prompts[2], 8, *plans[2]))
+        assert got + [unrelated] == wants
+        assert be_on.prefix_cache.stats()["hit_tokens"] >= 32
+    finally:
+        be_on.close()
+        be_off.close()
+
+
 def test_concurrent_shared_prefix_requests_identical(engines):
     spec, be_off, be_on = engines
     prompts = [SHARED + [150 + i] for i in range(4)]

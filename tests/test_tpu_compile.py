@@ -316,6 +316,10 @@ STEP_POOLS = {
     # and of 6 without, each kernel under its kind's name, the gate's
     # (72, 3072) and (48, 3072) matrices through the dequant-matmul
     "kinds": ("laguna-s-2.1-l5", {"num_experts": 16}),
+    # layers that hold a state and no keys (LFM2): ONE scan whose body picks
+    # the mixer, a pool of 6 of 24 layers with heads of 64 in lanes of 128,
+    # and beside it the slots' rings and the blocks' state snapshots
+    "state": ("lfm2-8b-a1b", {"num_experts": 8}),
 }
 # `jit_step` at T = 1 and at a 64-token chunk as the scheduler dispatches it
 # (told which row prefills: 72 compact rows, `forward.RowMap`), and a 2-step
@@ -348,6 +352,20 @@ def test_step_program_updates_the_pool_in_place(chip, step_model, pool,
     assert "tpu_custom_call" in text
     for side in aot_step.held_pools(spec, cfg):
         assert aot_step.pool_relayouts(text, side) == []
+    if spec.mixed:
+        # the second kind of state is updated where it lies too: with the
+        # layers as the arrays' leading axis, or 18 of them unpadded on the
+        # second-minor one, XLA re-laid ring and snapshots a program (302 MB
+        # in and out; PERF.md section 6, PR 42). A scan may keep the ring in
+        # the faster memory space for its steps (a copy in and out a scan)
+        from distributed_llama_tpu.models.forward import init_state
+
+        ring, snaps = jax.eval_shape(lambda: init_state(
+            spec, 8, cfg["engine"]["kv_pool_blocks"], jnp.bfloat16))
+        assert aot_step.pool_relayouts(text, snaps.shape) == []
+        if "scan" not in STEP_PROGRAMS[program]:
+            assert aot_step.pool_relayouts(text, ring.shape) == []
+        assert text.count("tpu_custom_call") <= 16  # one scan, not thirteen
     chunk = STEP_PROGRAMS[program].get("chunk", 1)
     if chunk > 1:  # the head ran at the one sampled position a row
         assert aot_step.logits_blocks(text, 8, chunk, cfg["vocab_size"]) == []
