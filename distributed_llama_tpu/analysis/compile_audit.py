@@ -20,7 +20,8 @@ factories (`make_sharded_forward`, `make_decode_loop`,
 
 `run_scenario` drives the real BatchEngine through a fixed tiny-model
 script: prefill (8+1 chunks), K-step scans, pipelined chaining, draft-verify
-blocks, a stochastic row, and a durable-resume admission. The observed
+blocks, a stochastic row, a durable-resume admission, and steps issued
+ahead from the token carry. The observed
 manifest is diffed against the pinned ``perf/compile_manifest.json``:
 
   - a program key absent from the pin  -> finding (new compiled program)
@@ -413,6 +414,24 @@ def run_scenario(keep_engine: bool = False):
                 rl2.wait(60)
             finally:
                 eng5.close()
+        # phase 11 — steps issued ahead (docs/SERVING.md "Pipelined
+        # decode"): a SIXTH engine with speculation off, whose greedy rows
+        # are sampled in the step program: a prefill chunk, a mixed step and
+        # a single step issued from the token carry of the step before, a
+        # scan from host state behind them. The carry is an argument of EVERY step
+        # dispatch (zeros where nothing is carried), so these ride the
+        # forward_step and batched_scan signatures the first engine pinned:
+        # a run-ahead-only key or signature here is the defect.
+        eng6 = BatchEngine(spec, params, slots=2, superstep=4, pipeline=True,
+                           tp=1, prefix_cache=True)
+        try:
+            ra1 = eng6.submit(p1, 12, Sampler(V))
+            ra2 = eng6.submit([(5 * i + 2) % V for i in range(19)], 9,
+                              Sampler(V))
+            ra1.wait(60)
+            ra2.wait(60)
+        finally:
+            eng6.close()
         ok = True
     finally:
         # a failed phase must not leak a live engine (scheduler thread +

@@ -143,6 +143,21 @@ def make_sharded_forward(spec: ModelSpec, mesh: Mesh, params: dict[str, Any], *,
 
     Returns fn(params, rope, tokens, k_cache, v_cache, start_pos) ->
     (logits, k_cache, v_cache). Cache buffers are donated (in-place update in HBM).
+
+    THE TOKEN CARRY (docs/SERVING.md "Pipelined decode"). Every program also
+    samples what can be sampled on the device: `tok`, int32 (B,), the arg-max
+    (first index on ties, over the float32 logits, as np.argmax in
+    runtime/sampler.Sampler) of each row's last returned position, which is
+    the one position a row of a step or of a compact chunk. And it takes the
+    `tok` of the dispatch before it: a row whose index-0 token is NEGATIVE
+    holds no token yet and is given its carry entry inside the program, so
+    the scheduler can issue a dispatch before the one that samples its input
+    has been fetched. One executable either way: fn(...) with no `carry`
+    runs it with a carry of zeros (no token may then be negative) and
+    returns what it always did; fn(..., tables, carry) returns `tok` as one
+    value more, last. jit keeps an executable a placement of its arguments:
+    whoever hands a carry of its own making places it as a returned `tok`
+    is, NamedSharding(mesh, P()) (rows sharded over dp: P('dp')).
     attn_window statically bounds the cache positions attention reads (see
     models.forward.forward); callers must keep start_pos + T <= attn_window.
 
@@ -152,6 +167,7 @@ def make_sharded_forward(spec: ModelSpec, mesh: Mesh, params: dict[str, Any], *,
     fn(params, rope, tokens, k_cache, v_cache, start_pos, tables).
     """
     import jax.numpy as jnp
+    import numpy as np
 
     from .mesh import AXIS_DP
 
@@ -188,45 +204,43 @@ def make_sharded_forward(spec: ModelSpec, mesh: Mesh, params: dict[str, Any], *,
                             block_tokens=kv_block_tokens,
                             paged_kernel=paged_kernel, moe_stats=moe_stats)
     rope_type = spec.rope_type
-    out_specs = (tok_spec, kv_spec, kv_spec) + ((P(),) if moe_stats else ())
+    out_specs = ((tok_spec, kv_spec, kv_spec) + ((P(),) if moe_stats else ())
+                 + (tok_spec,))
 
-    if paged:
-        def step(p, rope_cos, rope_sin, tokens, kc, vc, start_pos, tables):
-            rope = RopeTables(rope_cos, rope_sin, rope_type)
-            return fwd(p, rope=rope, tokens=tokens, k_cache=kc, v_cache=vc,
-                       start_pos=start_pos, block_tables=tables)
-
-        sharded = jax.shard_map(
-            step, mesh=mesh,
-            in_specs=(param_specs, P(), P(), tok_spec, kv_spec, kv_spec,
-                      pos_spec, P()),
-            out_specs=out_specs,
-            check_vma=False,
-        )
-        donate = (4, 5) if donate_cache else ()
-        jitted = jax.jit(sharded, donate_argnums=donate)
-
-        def run(p, rope: RopeTables, tokens, kc, vc, start_pos, tables):
-            return jitted(p, rope.cos, rope.sin, tokens, kc, vc, start_pos,
-                          tables)
-
-        return run
-
-    def step(p, rope_cos, rope_sin, tokens, kc, vc, start_pos):
+    def step(p, rope_cos, rope_sin, tokens, kc, vc, start_pos, carry,
+             tables=None):
         rope = RopeTables(rope_cos, rope_sin, rope_type)
-        return fwd(p, rope=rope, tokens=tokens, k_cache=kc, v_cache=vc,
-                   start_pos=start_pos)
+        first = tokens[:, 0]
+        tokens = tokens.at[:, 0].set(jnp.where(first < 0, carry, first))
+        more = {} if tables is None else {"block_tables": tables}
+        out = fwd(p, rope=rope, tokens=tokens, k_cache=kc, v_cache=vc,
+                  start_pos=start_pos, **more)
+        return *out, jnp.argmax(out[0][:, -1], axis=-1).astype(jnp.int32)
 
     sharded = jax.shard_map(
         step, mesh=mesh,
-        in_specs=(param_specs, P(), P(), tok_spec, kv_spec, kv_spec, pos_spec),
+        in_specs=(param_specs, P(), P(), tok_spec, kv_spec, kv_spec, pos_spec,
+                  tok_spec) + ((P(),) if paged else ()),
         out_specs=out_specs,
         check_vma=False,
     )
-    donate = (4, 5) if donate_cache else ()
-    jitted = jax.jit(sharded, donate_argnums=donate)
+    jitted = jax.jit(sharded, donate_argnums=(4, 5) if donate_cache else ())
+    zeros = {}  # rows -> the carry of a caller that hands none
 
-    def run(p, rope: RopeTables, tokens, kc, vc, start_pos):
-        return jitted(p, rope.cos, rope.sin, tokens, kc, vc, start_pos)
+    def run(p, rope: RopeTables, tokens, kc, vc, start_pos, tables=None,
+            carry=None):
+        given = carry is not None
+        rows = len(tokens)
+        if not given and isinstance(tokens, jax.core.Tracer):
+            carry = jnp.zeros((rows,), jnp.int32)  # traced: nothing to keep
+        elif not given:
+            if rows not in zeros:  # a host array put there: nothing compiles
+                zeros[rows] = jax.device_put(
+                    np.zeros((rows,), np.int32),
+                    NamedSharding(mesh, tok_spec))
+            carry = zeros[rows]
+        out = jitted(p, rope.cos, rope.sin, tokens, kc, vc, start_pos, carry,
+                     *((tables,) if paged else ()))
+        return out if given else out[:-1]
 
     return run

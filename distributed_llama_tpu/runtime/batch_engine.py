@@ -80,6 +80,7 @@ Design:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import queue
 import threading
 import time
@@ -103,6 +104,7 @@ from ..resilience.errors import (DeadlineExceeded, EngineClosed,
 from ..resilience.tenancy import (CLASSES, DEFAULT_TENANT, DrainRate,
                                   TenantRegistry, WeightedFairQueue)
 from .engine import PREFILL_CHUNKS, GenerationStats
+from .sampler import Sampler
 from .speculative import (AdaptiveK, NgramProposer, ProposerMux,
                           verify_block_bucket)
 
@@ -336,16 +338,26 @@ _PHASE_LAUNCH, _PHASE_WAIT, _PHASE_COPY = (
     _PHASE_SECONDS.labels(phase=p) for p in ("launch", "wait", "copy"))
 _PIPELINE_DEPTH = metrics.gauge(
     "batch_pipeline_depth",
-    "Decode super-steps currently in flight on device (2 = overlapped: one "
-    "executing while its predecessor's block is delivered host-side)")
+    "Dispatches (steps, super-steps, verify blocks) issued and not yet "
+    "delivered (2 = overlapped: one executing while its predecessor's "
+    "results are delivered host-side)")
 _PIPELINE_FLUSHES = metrics.counter(
     "batch_pipeline_flushes_total",
     "Pipeline breaks by reason: an eagerly chained super-step was discarded "
     "before delivery (stop/cancel/error/finish — its rows diverged from the "
     "speculated schedule) or chaining was declined (admission/close, or "
     "'spec': the accept-aware policy preferred a host-drafted verify "
-    "dispatch over extending the scan chain)",
+    "dispatch over extending the scan chain); 'row': ONE row's result of a "
+    "step issued ahead was dropped, its request having left the slot by "
+    "what the host alone could see (a stop_check hit, a cancel, a fault), "
+    "the dispatch's other rows standing",
     labelnames=("reason",))
+_STEP_CHAINED = metrics.counter(
+    "batch_step_chained_total",
+    "jit_step dispatches (prefill, mixed, single_step) issued before their "
+    "predecessor's results were fetched, the rows' next tokens taken from "
+    "its device-resident carry: of batch_dispatch_seconds' counts of those "
+    "kinds, the ones the device did not wait for the host before")
 # Batched speculative decoding (docs/SERVING.md "Speculative decoding"):
 # per-engine spec telemetry next to the sequential path's spec_* family —
 # drafted/accepted volumes and the verify-dispatch count are THE health
@@ -439,6 +451,7 @@ _CONSTRAIN_DEGRADED = metrics.counter(
 # whole new pool array per block touched — O(pool) HBM traffic and 2x peak
 # memory. Donating the pool lets XLA update the one block in place.
 import jax  # noqa: E402  (after the module docstring's import block)
+from jax.sharding import NamedSharding, PartitionSpec  # noqa: E402
 
 _pool_block_copy = jax.jit(lambda c, src, dst: c.at[:, dst].set(c[:, src]),
                            donate_argnums=(0,))
@@ -489,10 +502,17 @@ def _start_host_copy(*arrays) -> None:
             pass
 
 
-def _upload(host, dtype=None):
+def _upload(host, dtype=None, sharding=None):
     """A host value onto the device for a dispatch, counted: every copy the
-    scheduler makes towards the device goes through here."""
-    a = jnp.asarray(host, dtype)
+    scheduler makes towards the device goes through here. `sharding`: the
+    copy is committed to it. jit keeps an executable a placement of its
+    arguments, so a program that is handed now a host value and now another
+    program's result takes ONE executable only if the host value is placed
+    as that result is."""
+    if sharding is None:
+        a = jnp.asarray(host, dtype)
+    else:
+        a = jax.device_put(np.asarray(host, dtype), sharding)
     _H2D_TRANSFERS.inc()
     _H2D_BYTES.inc(a.nbytes)
     return a
@@ -755,6 +775,10 @@ class _Slot:
         # re-advance (and spuriously finish) the row on retry — _advance_row
         # is a no-op while armed; the successful ingesting dispatch clears it
         self.armed = False
+        # positions a step that is issued and not yet delivered advances
+        # this row by (its chunk, or 1): pos + ahead is where the row stands
+        # on the device, what the dispatch after it is planned from
+        self.ahead = 0
         # set BEFORE a super-step's delivery loop when the scan will park
         # this row clamped at seq_len-1 (destroying that history row): a
         # mid-loop _finish must harvest the TRUNCATED history, not the
@@ -774,7 +798,8 @@ class _Slot:
 
 
 class _InflightStep:
-    """An issued-but-undelivered K-step super-step OR draft-verify dispatch.
+    """An issued-but-undelivered K-step super-step, draft-verify dispatch or
+    `jit_step` dispatch.
 
     Holds the DEVICE arrays the dispatch will produce (`toks` the (K, B)
     token block, plus the (last_tok, pos, rng) carry the next dispatch can
@@ -789,15 +814,26 @@ class _InflightStep:
     (-1 = parked), `acc` the device (B,) accepted lengths, and `budget` the
     per-row MAXIMUM emit (ndraft+1) — delivery reads the actual emit, acc+1,
     from the device. The carry is rewound to each row's verified frontier on
-    device, so a chained scan consumes it soundly for any accept outcome."""
+    device, so a chained scan consumes it soundly for any accept outcome.
+
+    kind "step" (docs/SERVING.md "Pipelined decode"): one (B, T) `jit_step`
+    dispatch, a prefill chunk with its riders or a single step. `k` is T,
+    `budget` what each row advances (T the prefilling row `lead`, 1 a
+    rider), `piece` the chunk's tokens, `toks` the logits (fetched only
+    where a row is sampled on the host) and `tok` each row's arg-max, which
+    the step after it takes as its flagged rows' input. Nothing of its
+    schedule is speculative but that its rows stay: a row that left its
+    slot meanwhile has its one result dropped."""
 
     __slots__ = ("rows", "k", "starts", "budget", "temps", "toks", "tok",
                  "pos", "rng", "t_issue", "chained", "window", "kind",
-                 "ndraft", "acc", "cstate", "moe")
+                 "ndraft", "acc", "cstate", "moe", "lead", "piece", "span",
+                 "computed")
 
     def __init__(self, rows, k, starts, budget, temps, toks, tok, pos, rng,
                  t_issue, chained, window, kind="scan", ndraft=None,
-                 acc=None, cstate=None, moe=None):
+                 acc=None, cstate=None, moe=None, lead=None, piece=(),
+                 span=None, computed=None):
         self.rows = rows  # list[(slot, request)] for budget > 0 rows
         self.k = k
         self.starts = starts  # expected per-row device start positions
@@ -810,7 +846,7 @@ class _InflightStep:
         self.t_issue = t_issue
         self.chained = chained
         self.window = window  # keys attention ran against (bucket or context)
-        self.kind = kind  # "scan" | "verify"
+        self.kind = kind  # "scan" | "verify" | "step"
         self.ndraft = ndraft  # verify: per-row draft counts (-1 = parked)
         self.acc = acc  # verify: device (B,) accepted draft lengths
         # masked dispatch only: device (B,) GLOBAL constraint states after
@@ -819,6 +855,25 @@ class _InflightStep:
         # a routed model's scan only: device int32 vector, what the expert
         # layers did over the K steps (_count_moe reads it at delivery)
         self.moe = moe
+        # a step only: the slot that prefills (None: a single step), its
+        # chunk's tokens, the dispatch span's (name, args), and the rows its
+        # weights ran over where the stream was compact
+        self.lead = lead
+        self.piece = piece
+        self.span = span
+        self.computed = computed
+
+    @classmethod
+    def step(cls, rows, t, starts, t0, chained, window, span, lead=None,
+             piece=(), computed=None):
+        """A planned `jit_step` dispatch, nothing launched yet: `rows` its
+        (slot, request) pairs, the prefilling one first."""
+        budget = [0] * len(starts)
+        for slot, _req in rows:
+            budget[slot.index] = t if slot is lead else 1
+        return cls(rows, t, starts, budget, None, None, None, None, None, t0,
+                   chained, window, kind="step", lead=lead, piece=piece,
+                   span=span, computed=computed)
 
 
 class BatchEngine:
@@ -954,6 +1009,16 @@ class BatchEngine:
         # delivered host-side. K=1 has no block to overlap; keep it off there.
         self.pipeline = pipeline and superstep >= 2
         self._inflight: _InflightStep | None = None
+        # where a program's returned carry lies (a step's `tok`, a scan's
+        # tokens, positions and sampler states): one entry a row, replicated
+        # over tp. The token carry of a step that continues from none is
+        # zeros placed there, so that a step takes one executable whether
+        # its carry is this or the step's before it (parallel/tp.py)
+        self._carry_sharding = NamedSharding(
+            self._eng.mesh,
+            PartitionSpec("dp") if self._eng.dp > 1 else PartitionSpec())
+        self._no_carry = jax.device_put(np.zeros((slots,), np.int32),
+                                        self._carry_sharding)
         self._gc_watched = False  # holds obs.process's collector watcher
         self._last_ready_t: float | None = None  # perf_counter of last results
         self._gap_t: float | None = None  # last dispatch-ready time, gap metric
@@ -2377,23 +2442,40 @@ class BatchEngine:
         if self._gap_t is not None:
             _DISPATCH_GAP.observe(max(time.perf_counter() - self._gap_t, 0.0))
 
-    def _step(self, staged, kind: str = "step"):
-        """Dispatch one staged (B, t) step and wait for it; returns the
-        logits of the one position a row that is sampled, np.ndarray
-        (B, 1, vocab): the row's only position at t = 1, and of a chunk the
-        prefilling row's last and every other row's index 0 (the program's
-        head runs there alone). Callers read [row, 0] or [row, -1].
+    def _samples_here(self, slot: _Slot) -> bool:
+        """Whether the row's next token may be the step program's own
+        arg-max, so that its logits need never reach the host: its sampler
+        is the engine's `Sampler` itself (not a subclass, not a look-alike
+        that carries `temperature = 0.0` and wants to be shown the logits)
+        at temperature 0 over the whole vocabulary, and no grammar
+        constrains the row. Asked a dispatch, of the rows it samples."""
+        req = slot.req
+        smp, sc = req.sampler, slot.constraint
+        return (type(smp) is Sampler and smp.temperature == 0.0
+                and smp.vocab_size >= self.spec.vocab_size
+                and req.max_tokens > 0 and (sc is None or sc.degraded))
 
-        Three phases, a span and an observation of
-        batch_dispatch_phase_seconds each. `batch.launch`: the host calls
-        the jitted step until it returns its futures, and enqueues the
-        results' copy to the host behind the program. `batch.fetch_wait`
-        (inside `batch.fetch`): the host waits for the DEVICE, the inputs'
-        arrival, the program's start and the program; its end is the host's
-        timestamp of "the program is done". `batch.fetch_copy` (the rest of
-        `batch.fetch`): the host waits for the TRANSFER of every row's
-        logits (and a routed model's stats). Between launch and fetch,
-        pending demotions settle."""
+    def _runs_ahead(self) -> bool:
+        """Whether a step may be issued without waiting for it (and the one
+        after it from its carry): what the `pipeline` switch governs, as it
+        governs the scan's chain. Not while the engine drains or closes (the
+        in-flight one is delivered, the rest run one by one), not where rows
+        shard over dp, not beside speculative drafting, whose proposals are
+        read from delivered tokens."""
+        return (self.pipeline and not self._shutdown and not self._draining
+                and self._eng.dp == 1 and not self.spec_k)
+
+    def _launch_step(self, staged, kind: str,
+                     chain: _InflightStep | None = None):
+        """Hand one staged (B, t) step to the device: `batch.launch`, the
+        host calls the jitted step until it returns its futures. `chain`:
+        the step whose `tok` the rows with a negative token continue from
+        (None: every token is the host's). Returns the logits of the one
+        position a row that is sampled, (B, 1, vocab): the row's only
+        position at t = 1, and of a chunk the prefilling row's last and
+        every other row's index 0 (the program's head runs there alone);
+        their arg-max `tok` (B,); a routed model's stats vector or None.
+        All still on the device."""
         eng = self._eng
         step, toks, start_pos, tables = staged
         # snapshot the cache refs NOW and rebind only after _dispatched's
@@ -2401,48 +2483,66 @@ class BatchEngine:
         # neither donate the re-initialized backend's fresh cache arrays nor
         # rebind its stale outputs over them
         kc_in, vc_in = eng.k_cache, eng.v_cache
-        self._observe_gap()
+        if chain is None:
+            self._observe_gap()
+            carry = self._no_carry
+        else:
+            _DISPATCH_GAP.observe(0.0)  # chained: the device never went idle
+            _STEP_CHAINED.inc()
+            carry = chain.tok
 
         def call():
             t0 = time.perf_counter()
             with trace.span("batch.launch"):
-                args = (eng.params, eng.rope, toks, kc_in, vc_in, start_pos)
                 # a routed model's program returns one value more
-                logits, kc, vc, *moe = step(
-                    *args, *(() if tables is None else (tables,)))
-                # behind the program in the device's own order, where the
-                # fetch's np.asarray alone would have put it
-                _start_host_copy(logits, *moe)
-            t1 = time.perf_counter()
-            self._settle_demotions()  # the host only waits from here on
-            # demotion reads issued and not yet host arrays: what else is
-            # on the way to the host when this dispatch's results are
-            pending = 0
-            if self.kv_pool is not None and self.prefix_cache is not None:
-                pending = self.prefix_cache.unsettled * self._kv_block_bytes
-            with trace.span("batch.fetch", {"bytes": logits.nbytes}):
-                t2 = time.perf_counter()
-                with trace.span("batch.fetch_wait"):
-                    for a in (logits, *moe):
-                        a.block_until_ready()
-                t3 = time.perf_counter()
-                with trace.span("batch.fetch_copy", {
-                        "bytes": logits.nbytes,
-                        "demote_pending_bytes": pending}) as sp:
-                    out = np.asarray(logits)
-                    if moe:  # known only now: added to the open span
-                        sp.add(experts_touched=self._count_moe(moe[0]))
-                t4 = time.perf_counter()
-            _D2H_BYTES.inc(logits.nbytes)
-            _PHASE_LAUNCH.observe(t1 - t0)
-            _PHASE_WAIT.observe(t3 - t2)
-            _PHASE_COPY.observe(t4 - t3)
-            return out, kc, vc
+                logits, kc, vc, *moe, tok = step(
+                    eng.params, eng.rope, toks, kc_in, vc_in, start_pos,
+                    tables, carry)
+            _PHASE_LAUNCH.observe(time.perf_counter() - t0)
+            return logits, kc, vc, moe, tok
 
-        out, eng.k_cache, eng.v_cache = self._dispatched(kind, call)
-        # sync dispatch: results are host-side now, the reference point of
-        # the next dispatch's batch_dispatch_gap_seconds
-        self._gap_t = time.perf_counter()
+        logits, eng.k_cache, eng.v_cache, moe, tok = self._dispatched(
+            kind, call)
+        return logits, tok, moe[0] if moe else None
+
+    def _fetch_step(self, fl: _InflightStep, sampled_here: bool):
+        """Wait for a launched step and bring its results to the host, under
+        the caller's dispatch span: every row's logits where a row is
+        sampled on the host, else the program's own samples `tok`, 4 bytes a
+        row; a routed model's stats either way. Two phases, a span and an
+        observation of batch_dispatch_phase_seconds each. `batch.fetch_wait`
+        (inside `batch.fetch`): the host waits for the DEVICE, the inputs'
+        arrival, the program's start and the program; its end is the host's
+        timestamp of "the program is done". `batch.fetch_copy` (the rest of
+        `batch.fetch`): the host waits for the TRANSFER."""
+        what = fl.tok if sampled_here else fl.toks
+        moe = () if fl.moe is None else (fl.moe,)
+        epoch = getattr(self._tls, "epoch", self._epoch)
+        # demotion reads issued and not yet host arrays: what else is
+        # on the way to the host when this dispatch's results are
+        pending = 0
+        if self.kv_pool is not None and self.prefix_cache is not None:
+            pending = self.prefix_cache.unsettled * self._kv_block_bytes
+        with trace.span("batch.fetch", {"bytes": what.nbytes}):
+            t2 = time.perf_counter()
+            with trace.span("batch.fetch_wait"):
+                for a in (what, *moe):
+                    a.block_until_ready()
+            t3 = time.perf_counter()
+            with trace.span("batch.fetch_copy", {
+                    "bytes": what.nbytes,
+                    "demote_pending_bytes": pending}) as sp:
+                out = np.asarray(what)
+                if moe:  # known only now: added to the open span
+                    sp.add(experts_touched=self._count_moe(moe[0]))
+            t4 = time.perf_counter()
+        if self._epoch != epoch:
+            # abandoned by recover_wedged while it waited: the slots these
+            # results belong to are the replacement epoch's now
+            raise _StaleEpoch()
+        _D2H_BYTES.inc(what.nbytes)
+        _PHASE_WAIT.observe(t3 - t2)
+        _PHASE_COPY.observe(t4 - t3)
         return out
 
     def _finish(self, slot: _Slot, finish: str) -> None:
@@ -2458,6 +2558,7 @@ class BatchEngine:
         slot.req = None
         slot.pending = []
         slot.next_token = None
+        slot.ahead = 0  # a step in flight drops this row's result
         self.proposer.detach(slot.index)
         if self.adaptive is not None:
             self.adaptive.detach(slot.index)
@@ -2560,8 +2661,11 @@ class BatchEngine:
         s = self.spec.seq_len
         starts = []
         for sl in self._slots:
-            p = min(sl.pos, max(s - t, 0))
-            if p < sl.pos:
+            # where the row stands once a step in flight has run (a plan made
+            # ahead never comes here with a row that would be clamped)
+            pos = sl.pos + sl.ahead
+            p = min(pos, max(s - t, 0))
+            if p < pos:
                 if self.kv_pool is not None and sl.req is None:
                     # paged idle slot: a clamped park would scribble into
                     # possibly directory-shared tail blocks — drop the
@@ -2732,6 +2836,7 @@ class BatchEngine:
         slot.req = None
         slot.pending = []
         slot.next_token = None
+        slot.ahead = 0  # a step in flight drops this row's result
         self.proposer.detach(slot.index)
         if self.adaptive is not None:
             self.adaptive.detach(slot.index)
@@ -2949,21 +3054,24 @@ class BatchEngine:
             sp.add(admitted=admitted, queued=queued)
         try:
             if self._inflight is not None:
-                # a chained super-step is running on device: deliver it (and
-                # maybe chain its successor) before any new dispatch shape —
+                # a dispatch is running on device: deliver it (and maybe
+                # chain its successor) before any dispatch from host state —
                 # every later device op already depends on its cache writes
                 fl = self._inflight
-                self._inflight = None
-                self._pipeline_advance(fl)
+                if fl.kind == "step":
+                    # a step is running: plan and issue the one after it
+                    # from where its rows will stand, then deliver it
+                    self._step_ahead(fl)
+                else:
+                    self._inflight = None
+                    self._pipeline_advance(fl)
             elif prefill:
                 # class-aware prefill order (docs/SERVING.md "Multi-tenant
                 # serving"): an interactive row's prefill goes first — with
                 # slot-order FIFO an interactive admission could wait
                 # behind several batch rows' long prompts, unbounding the
                 # TTFT the preemption path just bounded
-                victim = min(prefill,
-                             key=lambda s: (s.req.klass != "interactive",
-                                            s.index))
+                victim = self._prefill_victim(prefill)
                 try:
                     # mixed step: active decode rows ride the prefill dispatch
                     # at T=1 instead of stalling behind it
@@ -2997,6 +3105,13 @@ class BatchEngine:
             with self._cond:
                 if not self._shutdown:
                     self._cond.wait(timeout=0.05)
+
+    @staticmethod
+    def _prefill_victim(prefill: list[_Slot]) -> _Slot:
+        """Which of the rows with prompt left prefills next: an interactive
+        row first, then by slot."""
+        return min(prefill, key=lambda s: (s.req.klass != "interactive",
+                                           s.index))
 
     def _emit(self, slot: _Slot, token: int) -> bool:  # hot-path
         """Deliver one sampled token to the request (output list, stats,
@@ -3086,8 +3201,19 @@ class BatchEngine:
             except Exception:
                 self._degrade_constraint(slot, "mask")
                 logits = slot.last_logits
+        return self._take_token(slot, lambda: req.sampler.sample(logits))
+
+    def _take_token(self, slot: _Slot, sample) -> bool:  # hot-path
+        """Deliver the row's next token, `sample()` (its sampler shown the
+        logits, or the step program's own arg-max already on the host), and
+        leave it as the row's next un-ingested one. False when the request
+        finished instead."""
+        req = slot.req
+        if req.cancelled:
+            self._finish(slot, "cancelled")
+            return False
         try:
-            token = req.sampler.sample(logits)
+            token = sample()
             alive = self._emit(slot, token)
         except Exception as e:
             # a broken callback (e.g. client disconnect mid-stream) fails ONLY
@@ -3104,6 +3230,8 @@ class BatchEngine:
         return True
 
     def _prefill_step(self, slot: _Slot, riders: list[_Slot] = ()) -> None:
+        """One prefill chunk of `slot`, the decoding rows `riders` riding it,
+        from host state (nothing in flight)."""
         # request-scope injection point: fires BEFORE the rider advance and
         # the device dispatch, so an injected error is attributable to the
         # prefilling request alone (_loop_once fails only it); bound to the
@@ -3112,9 +3240,7 @@ class BatchEngine:
             faults.fire("batch.prefill", slot=slot.index,
                         pending=len(slot.pending))
         t0 = time.perf_counter()
-        s = self.spec.seq_len
-        room = s - slot.pos
-        if room <= 0:
+        if self.spec.seq_len - slot.pos <= 0:
             slot.last_logits = None
             slot.pending = []
             return
@@ -3124,93 +3250,344 @@ class BatchEngine:
         with trace.span("batch.advance", {"rows": len(riders)}):
             riders = [r for r in riders if self._advance_row(r)]
         with trace.span("batch.build"):
-            chunk = next((c for c in PREFILL_CHUNKS
-                          if len(slot.pending) >= c), 1)
-            chunk = min(chunk, room)
-            # keep parked rows' scratch writes inside the cache without
-            # touching history: a parked row writes [pos, pos+chunk) which
-            # must fit under seq_len; shrink the chunk when any OTHER row
-            # sits too close to the end (its history would be corrupted by a
-            # clamped write below its pos)
-            for other in self._slots:
-                if other is not slot and other.req is not None:
-                    chunk = min(chunk, max(s - other.pos, 1))
-            piece = slot.pending[:chunk]
-            t = len(piece)
-            starts = self._park_positions(t)
-            if slot.req is None:  # reaped by a clamp-park CoW exhaustion
-                return
-            riders = [r for r in riders if r.req is not None]
-            starts[slot.index] = slot.pos
-            rows = [[0] * t for _ in self._slots]
-            rows[slot.index] = piece
-            for r in riders:
-                # real token at index 0, scratch beyond: the rider's
-                # positions pos+1..pos+t-1 are masked future slots its own
-                # later decodes overwrite (in-bounds by the chunk shrink
-                # above)
-                starts[r.index] = r.pos
-                rows[r.index] = [r.last_token] + [0] * (t - 1)
-            if self.kv_pool is not None:
-                # block coverage for every committed write this dispatch
-                # makes (the prefill chunk, each rider's one real token);
-                # scratch beyond coverage lands in the scratch block by
-                # design. A RIDER's exhaustion fails the rider, not the
-                # innocent prefill (the victim's own failure propagates and
-                # is attributed to it by _loop_once's request-scope handler)
-                self._paged_ensure(slot, slot.pos + t)
-                for r in riders[:]:
-                    try:
-                        self._paged_ensure(r, r.pos + 1)
-                    except Exception as e:
-                        if classify(e) != "request":
-                            raise
-                        self._fail_request(r, e)
-                        riders.remove(r)
-            # a chunk with scratch in it: the program is told which row
-            # prefills and runs its weights over the chunk and one row a slot
-            # (rows sharded over dp have no one stream to be compacted into)
-            lead = slot.index if t > 1 and self._eng.dp == 1 else None
-            window, staged = self._stage(rows, starts, t, lead)
-        # the dispatch belongs to the prefilling request: bind its context
-        # so the span (and any dispatch fault) carries its trace id
-        with reqctx.use(slot.req.ctx), \
-                trace.span("batch.mixed_step" if riders else "batch.prefill",
-                           {"chunk": t, "riders": len(riders),
-                            "window": window, "slots": self.slots_n}):
-            logits = self._step(staged,
-                                kind="mixed" if riders else "prefill")
+            plan = self._plan_chunk(slot, riders, t0)
+        if plan is not None:
+            self._run_step(*plan)
+
+    def _plan_chunk(self, slot: _Slot, riders: list[_Slot], t0: float,
+                    chain: _InflightStep | None = None):
+        """Under `batch.build`: the (B, t) step in which `slot` prefills its
+        next chunk and each of `riders` advances by one token, staged.
+        Positions are where the rows stand on the device (`pos + ahead`:
+        once a step that is in flight has run); with `chain`, that step, the
+        riders' tokens are its to give and ride as -1. Returns what
+        `_run_step` takes, or None where the prefilling request was
+        reaped."""
+        s = self.spec.seq_len
+        pos = slot.pos + slot.ahead
+        pending = slot.pending[slot.ahead:]
+        chunk = next((c for c in PREFILL_CHUNKS if len(pending) >= c), 1)
+        chunk = min(chunk, s - pos)
+        # keep parked rows' scratch writes inside the cache without
+        # touching history: a parked row writes [pos, pos+chunk) which
+        # must fit under seq_len; shrink the chunk when any OTHER row
+        # sits too close to the end (its history would be corrupted by a
+        # clamped write below its pos)
+        for other in self._slots:
+            if other is not slot and other.req is not None:
+                chunk = min(chunk, max(s - other.pos - other.ahead, 1))
+        piece = pending[:chunk]
+        t = len(piece)
+        starts = self._park_positions(t)
+        if slot.req is None:  # reaped by a clamp-park CoW exhaustion
+            return None
+        riders = [r for r in riders if r.req is not None]
+        starts[slot.index] = pos
+        rows = [[0] * t for _ in self._slots]
+        rows[slot.index] = piece
+        for r in riders:
+            # real token at index 0, scratch beyond: the rider's
+            # positions pos+1..pos+t-1 are masked future slots its own
+            # later decodes overwrite (in-bounds by the chunk shrink
+            # above)
+            starts[r.index] = r.pos + r.ahead
+            rows[r.index] = ([r.last_token if chain is None else -1]
+                             + [0] * (t - 1))
+        if self.kv_pool is not None:
+            # block coverage for every committed write this dispatch
+            # makes (the prefill chunk, each rider's one real token);
+            # scratch beyond coverage lands in the scratch block by
+            # design. A RIDER's exhaustion fails the rider, not the
+            # innocent prefill (the victim's own failure propagates and
+            # is attributed to it by _loop_once's request-scope handler)
+            self._paged_ensure(slot, pos + t)
+            for r in riders[:]:
+                try:
+                    self._paged_ensure(r, starts[r.index] + 1)
+                except Exception as e:
+                    if classify(e) != "request":
+                        raise
+                    self._fail_request(r, e)
+                    riders.remove(r)
+                    starts[r.index] = r.pos  # an idle row's park
+                    rows[r.index] = [0] * t
+        # a chunk with scratch in it: the program is told which row
+        # prefills and runs its weights over the chunk and one row a slot
+        # (rows sharded over dp have no one stream to be compacted into)
+        lead = slot.index if t > 1 and self._eng.dp == 1 else None
+        window, staged = self._stage(rows, starts, t, lead)
+        fl = _InflightStep.step(
+            [(slot, slot.req)] + [(r, r.req) for r in riders], t, starts,
+            t0, chain is not None, window,
+            ("batch.mixed_step" if riders else "batch.prefill",
+             {"chunk": t, "riders": len(riders), "window": window,
+              "slots": self.slots_n}),
+            lead=slot, piece=piece,
+            computed=None if lead is None else compact_rows(t, self.slots_n))
+        return fl, staged, chain
+
+    def _plan_single(self, active: list[_Slot], t0: float,
+                     chain: _InflightStep | None = None):
+        """Under `batch.build`: one batched T=1 step of `active`, staged (the
+        admission-latency and tail path); positions and `chain` as
+        `_plan_chunk` takes them. None where no row is left."""
+        starts = self._park_positions(1)
+        # a clamp-park CoW under pool exhaustion may have reaped a row
+        active = [s for s in active if s.req is not None]
+        if not active:
+            return None
+        rows = [[0]] * self.slots_n
+        for slot in active:
+            starts[slot.index] = slot.pos + slot.ahead
+            rows[slot.index] = [slot.last_token if chain is None else -1]
+        window, staged = self._stage(rows, starts, 1)
+        fl = _InflightStep.step(
+            [(s, s.req) for s in active], 1, starts, t0, chain is not None,
+            window, ("batch.single_step", {"rows": len(active),
+                                           "window": window,
+                                           "slots": self.slots_n}))
+        return fl, staged, chain
+
+    def _run_step(self, fl: _InflightStep, staged,
+                  chain: _InflightStep | None = None) -> None:
+        """Dispatch a planned step. Where every row it samples may be
+        sampled by the program itself (`_samples_here`) and the scheduler
+        runs ahead, it is ISSUED: launched under `batch.step_issue`, left in
+        `self._inflight`, and delivered by the pass after this one, once the
+        dispatch after it has been planned and issued from its carry
+        (`_step_ahead`). Else it is synchronous, as every step was: launched
+        and waited for under its own span, every row's logits fetched."""
+        lead = fl.lead
+        ctx = fl.rows[0][1].ctx if lead is not None else None
+        kind = ("single_step" if lead is None
+                else "mixed" if len(fl.rows) > 1 else "prefill")
+        sampled = [s for s, _req in fl.rows
+                   if s is not lead or len(s.pending) - s.ahead == fl.k]
+        ahead = (chain is not None or self._runs_ahead()) and all(
+            self._samples_here(s) for s in sampled)
+        name, args = fl.span
+
+        def launch():
+            fl.toks, fl.tok, fl.moe = self._launch_step(staged, kind, chain)
+            # what the host will fetch, behind the program in the device's
+            # own order, where the fetch's np.asarray alone would have put it
+            _start_host_copy(fl.tok if ahead else fl.toks,
+                             *(() if fl.moe is None else (fl.moe,)))
+
+        if not ahead:
+            assert chain is None  # _step_ahead asked the same of its rows
+            # the dispatch belongs to the prefilling request: bind its
+            # context so the span (and any dispatch fault) carries its id
+            with reqctx.use(ctx), trace.span(name, args):
+                launch()
+                self._settle_demotions()  # the host only waits from here on
+                out = self._fetch_step(fl, sampled_here=False)
+            with trace.span("batch.deliver"):
+                self._settle_step(fl, out, sampled_here=False)
+            return
+        with reqctx.use(ctx), trace.span(
+                "batch.step_issue", {**args, "kind": kind,
+                                     "chained": chain is not None}):
+            launch()
+            fl.toks = None  # the logits are never fetched: let them go
+        for slot, _req in fl.rows:
+            slot.ahead += fl.budget[slot.index]
+        self._inflight = fl
+        _PIPELINE_DEPTH.set(1 if chain is None else 2)
+
+    def _step_ahead(self, fl: _InflightStep) -> None:
+        """With the step `fl` in flight: plan the dispatch AFTER it from
+        where its rows will stand, issue that one chained from `fl`'s token
+        carry, then deliver `fl`. What the plan takes for granted is host
+        knowledge: which slot prefills and its chunk (`pending`), that a row
+        rides while it has tokens to go (a finish by length is known a
+        dispatch ahead: the row is simply absent), that a row whose prompt
+        ends in `fl` rides with its first token from the carry, positions and
+        block coverage from the expected lengths. Only a finish the host
+        could not foresee (a `stop_check` hit, a cancel, a request-scope
+        fault) leaves a row in the next dispatch that is gone at delivery:
+        that row's one result is dropped there (its write sits past the
+        request's frontier, the scan's free rollback) and every other row's
+        stands, so nothing is flushed. Whatever the plan cannot take for
+        granted (a row that needs its logits on the host, a row at the
+        context's end, a slot's state the synchronous path left) is not
+        planned: `fl` is delivered and the next pass dispatches from host
+        state."""
+        with trace.span("batch.build"):
+            issued = self._runs_ahead() and self._issue_after(fl)
+        self._inflight = self._inflight if issued else None
+        _PIPELINE_DEPTH.set(2 if issued else 1)
+        self._deliver_step(fl)
+        _PIPELINE_DEPTH.set(1 if self._inflight is not None else 0)
+
+    def _issue_after(self, fl: _InflightStep) -> bool:
+        """Plan and issue the dispatch after the in-flight step `fl` (under
+        `batch.build`); False where it has to wait for `fl`'s delivery."""
+        s = self.spec.seq_len
+        now = time.perf_counter()
+        t0 = now
+        mine = {slot.index: req for slot, req in fl.rows}
+        prefill: list[_Slot] = []
+        riders: list[_Slot] = []
+        for sl in self._slots:
+            req = sl.req
+            if req is None:
+                continue
+            if req.cancelled or (req.deadline_t and now >= req.deadline_t):
+                return False  # _reap_slots fires next pass: don't outrun it
+            if len(sl.pending) > sl.ahead:
+                prefill.append(sl)
+            elif mine.get(sl.index) is not req:
+                return False  # its next token is host state, not `fl`'s
+            elif len(req.out) + 1 < req.max_tokens and sl.pos + sl.ahead < s:
+                # else it ends by length with the token `fl` gives it
+                if not self._samples_here(sl):
+                    return False
+                riders.append(sl)
+        if not prefill and not riders:
+            return False
+        # no row may stand so near the context's end that a park is clamped
+        # or a chunk shrunk: those edit host state the delivery still needs
+        reach = max(sl.pos + sl.ahead for sl in self._slots)
+        if prefill:
+            victim = self._prefill_victim(prefill)
+            left = len(victim.pending) - victim.ahead
+            t = next((c for c in PREFILL_CHUNKS if left >= c), 1)
+            if reach + t > s or (t == left
+                                 and not self._samples_here(victim)):
+                return False
+            try:
+                # the same request-scope injection point, before anything
+                # of the dispatch exists (_prefill_step)
+                with reqctx.use(victim.req.ctx):
+                    faults.fire("batch.prefill", slot=victim.index,
+                                pending=left)
+                plan = self._plan_chunk(victim, riders, t0, chain=fl)
+            except Exception as e:
+                # a request-scope fault while the next dispatch is planned
+                # (batch.prefill, a pool that cannot cover the chunk) is the
+                # prefilling request's alone: `fl` stands and is delivered
+                if classify(e) != "request" or victim.req is None:
+                    raise
+                self._fail_request(victim, e)
+                return False
+            if plan is None:
+                return False
+            self._run_step(*plan)
+            return True
+        with self._plock:
+            waiting = bool(self._pending) or not self._queue.empty()
+        if self.superstep > 1 and not waiting and max(
+                sl.req.max_tokens - len(sl.req.out) - 1 for sl in riders) >= 2:
+            # nobody waits for a slot: the next dispatch is the K-step scan
+            # (_decode_step), which leaves from host state once `fl` is
+            # delivered: one synchronous gap a step-to-scan transition. Its
+            # issue takes the host 5 to 6 ms (six uploads and the scan's
+            # call), and made while `fl` runs it would open `fl`'s dispatch
+            # span past the middle of a 15 ms execution
+            return False
+        if reach + 1 > s:
+            return False
+        if self.kv_pool is not None:
+            try:
+                for sl in riders:
+                    self._paged_ensure(sl, sl.pos + sl.ahead + 1)
+            except Exception:
+                return False  # the synchronous path fails the row it is
+        plan = self._plan_single(riders, t0, chain=fl)
+        if plan is None:
+            return False
+        self._run_step(*plan)
+        return True
+
+    def _deliver_step(self, fl: _InflightStep) -> None:
+        """Deliver an issued step under ITS OWN dispatch span (the name and
+        the chunk, riders, window it was planned with): the wait for its
+        results and their copy lie inside it, as a synchronous dispatch's
+        do, and its program's execution mostly under it."""
+        name, args = fl.span
+        # the dispatch belongs to the request that prefilled in it
+        with reqctx.use(fl.rows[0][1].ctx if fl.lead is not None else None), \
+                trace.span(name, args):
+            # the host only waits from here on: pending demotions settle
+            # inside the span, as between a synchronous dispatch's launch
+            # and its fetch (a reclaim's 10 to 20 MB take milliseconds, and
+            # a span opened after them would miss its own execution)
+            self._settle_demotions()
+            out = self._fetch_step(fl, sampled_here=True)
         with trace.span("batch.deliver"):
+            self._settle_step(fl, out, sampled_here=True)
+
+    def _settle_step(self, fl: _InflightStep, out: np.ndarray,
+                     sampled_here: bool) -> None:
+        """A step's results are on the host: counters, the rows' positions
+        and histories, and what each sampled row continues with. `out` is
+        every row's logits (B, 1 or T, vocab), left as `last_logits` for the
+        row's sampler, or (`sampled_here`) the program's own samples (B,),
+        delivered now: a row that lives on holds its token as one whose KV
+        is not written yet (`armed`), which the dispatch after this one, if
+        it is already in flight, is writing."""
+        t, lead = fl.k, fl.lead
+        riders = len(fl.rows) - (lead is not None)
+        t_ready = time.perf_counter()
+        # sampled here, the dispatch was issued while its predecessor ran:
+        # its own share of the wall time starts where that one's ended
+        base = fl.t_issue
+        if (sampled_here and self._last_ready_t is not None
+                and self._last_ready_t > base):
+            base = self._last_ready_t
+        dt_ms = (t_ready - base) * 1000.0
+        self._last_ready_t = self._gap_t = t_ready
+        self._last_dispatch_t = time.monotonic()
+        if lead is None:
+            self.decode_steps += 1
+            _DISP_SINGLE.observe(dt_ms / 1000.0)
+            _PARKED_ROW_STEPS.inc(self.slots_n - riders)
+        else:
             if riders:
                 self.mixed_steps += 1
-            dt_ms = (time.perf_counter() - t0) * 1000.0
-            flight.event(slot.req.rid, "prefill_chunk", chunk=t,
-                         riders=len(riders), ms=round(dt_ms, 3))
             (_DISP_MIXED if riders else _DISP_PREFILL).observe(dt_ms / 1000.0)
             _PREFILL_TOKENS.inc(t)
             # rows neither prefilling nor riding spent this dispatch parked
-            _PARKED_ROW_STEPS.inc(self.slots_n - 1 - len(riders))
-            self._count_work(t, window, [(slot.pos, t)]
-                             + [(r.pos, 1) for r in riders], starts,
-                             computed=None if lead is None
-                             else compact_rows(t, self.slots_n))
+            _PARKED_ROW_STEPS.inc(self.slots_n - 1 - riders)
             self.prefilled_tokens += t
-            slot.pos += t
-            slot.history.extend(piece)
-            slot.pending = slot.pending[t:]
-            if not slot.pending:
-                slot.last_logits = logits[slot.index, -1]
+        self._count_work(t, fl.window,
+                         [(fl.starts[s.index], fl.budget[s.index])
+                          for s, _req in fl.rows], fl.starts,
+                         computed=fl.computed)
+        for slot, req in fl.rows:
+            if slot.req is not req or req.done.is_set():
+                # left its slot while the dispatch ran (reaped, preempted,
+                # or stopped at the delivery before by what the host alone
+                # could see): this row's result is dropped, no other's
+                _PIPELINE_FLUSHES.labels(reason="row").inc()
+                _ROLLBACK_TOKENS.inc(1)
+                flight.event(req.rid, "rollback", tokens=1, where="step")
+                continue
+            if sampled_here:  # it was issued ahead: no longer in flight
+                slot.ahead -= fl.budget[slot.index]
+            req.stats.dispatch_ms.append(dt_ms)
+            if slot is lead:
+                flight.event(req.rid, "prefill_chunk", chunk=t,
+                             riders=riders, ms=round(dt_ms, 3))
+                slot.pos += t
+                slot.history.extend(fl.piece)
+                slot.pending = slot.pending[t:]
+                req.stats.prefill_ms += dt_ms
+                if slot.pending:
+                    continue
                 slot.last_token = slot.history[-1]
-            slot.req.stats.prefill_ms += dt_ms
-            slot.req.stats.dispatch_ms.append(dt_ms)
-            for r in riders:  # each rider decoded one token in this dispatch
-                r.last_logits = logits[r.index, 0]
-                r.history.append(r.last_token)
-                r.pos += 1
-                r.armed = False  # the dispatch ingested last_token's KV
-                r.req.stats.token_ms.append(dt_ms)
-                r.req.stats.infer_ms.append(dt_ms)
-                r.req.stats.dispatch_ms.append(dt_ms)
+            else:  # decoded one token in this dispatch
+                slot.history.append(slot.last_token)
+                slot.pos += 1
+                slot.armed = False  # the dispatch ingested last_token's KV
+                req.stats.token_ms.append(dt_ms)
+                req.stats.infer_ms.append(dt_ms)
+            if sampled_here:
+                self._take_token(slot, lambda i=slot.index: int(out[i]))
+            else:
+                slot.last_logits = out[slot.index,
+                                       -1 if slot is lead or t == 1 else 0]
 
     def _decode_step(self, active: list[_Slot]) -> None:
         # bring every row to its next un-ingested token (host-samples rows at a
@@ -3266,35 +3643,9 @@ class BatchEngine:
         tail) path."""
         t0 = time.perf_counter()
         with trace.span("batch.build"):
-            starts = self._park_positions(1)
-            # a clamp-park CoW under pool exhaustion may have reaped a row
-            active = [s for s in active if s.req is not None]
-            if not active:
-                return
-            rows = [[0]] * self.slots_n
-            for slot in active:
-                starts[slot.index] = slot.pos
-                rows[slot.index] = [slot.last_token]
-            window, staged = self._stage(rows, starts, 1)
-        with trace.span("batch.single_step",
-                        {"rows": len(active), "window": window,
-                         "slots": self.slots_n}):
-            logits = self._step(staged, kind="single_step")
-        with trace.span("batch.deliver"):
-            self.decode_steps += 1
-            dt_ms = (time.perf_counter() - t0) * 1000.0
-            _DISP_SINGLE.observe(dt_ms / 1000.0)
-            _PARKED_ROW_STEPS.inc(self.slots_n - len(active))
-            self._count_work(1, window, [(s.pos, 1) for s in active],
-                             starts)
-            for slot in active:
-                slot.last_logits = logits[slot.index, -1]
-                slot.history.append(slot.last_token)
-                slot.pos += 1
-                slot.armed = False  # the dispatch ingested last_token's KV
-                slot.req.stats.token_ms.append(dt_ms)
-                slot.req.stats.infer_ms.append(dt_ms)
-                slot.req.stats.dispatch_ms.append(dt_ms)
+            plan = self._plan_single(active, t0)
+        if plan is not None:
+            self._run_step(*plan)
 
     def _batched_loop(self, k: int, mode: str, window: int | None,
                       masked: bool = False):
@@ -3791,10 +4142,17 @@ class BatchEngine:
             # its own jnp.asarray for callers with host values, a no-op on
             # these); a chained one's tokens, positions and rng are on the
             # device
+            # a carry that comes from the host is placed as a returned carry
+            # is (replicated over the mesh), so that a scan from host state
+            # and one chained from a scan are ONE executable (rows sharded
+            # over dp keep their two)
             if chain is None:
-                tok_in, pos_in, rng_in = (_upload(tok_in, jnp.int32),
-                                          _upload(pos_in, jnp.int32),
-                                          _upload(rng_in, jnp.uint32))
+                up = functools.partial(
+                    _upload, sharding=self._carry_sharding if eng.dp == 1
+                    else None)
+                tok_in, pos_in, rng_in = (up(tok_in, jnp.int32),
+                                          up(pos_in, jnp.int32),
+                                          up(rng_in, jnp.uint32))
             host = (pos_in, rng_in, _upload(temps, jnp.float32),
                     _upload(topps, jnp.float32), _upload(budget, jnp.int32),
                     tables)

@@ -176,8 +176,18 @@ def test_admission_breaks_the_chain(params):
         for label, be in (("off", off), ("on", on)):
             before = _flushes()
             started = threading.Event()
+            seen = []
+
+            def on_token(t):
+                # the second token is a scan block's: from there on the
+                # pipeline is full (the first one comes from the prompt's
+                # last step, behind which nothing is in flight)
+                seen.append(t)
+                if len(seen) == 2:
+                    started.set()
+
             r1 = be.submit([1, 7, 23, 5], 40, _greedy(spec),
-                           on_token=lambda _t: started.set())
+                           on_token=on_token)
             assert started.wait(timeout=120)
             r2 = be.submit([1, 9, 2, 40, 41, 42, 43, 44], 12, _greedy(spec))
             outs[label] = (r1.wait(timeout=120), r2.wait(timeout=120))
