@@ -93,6 +93,107 @@ _SEED_BYTES = metrics.counter(
     "nonzero only when a cold block is promoted)")
 
 
+_SNAPSHOT_EVICTIONS = metrics.counter(
+    "paged_kv_ssm_snapshot_evictions_total",
+    "Entries of the state snapshot pool given up for another block's: the "
+    "least recently used snapshot that no dispatch in flight is writing")
+_SNAPSHOT_BYTES = metrics.gauge(
+    "kv_pool_ssm_snapshot_bytes",
+    "Bytes of the state snapshot pool that blocks hold an entry of (entries "
+    "held x a snapshot's bytes; 0: the model has no state-space layers)")
+
+
+class SnapshotPool:
+    """Which blocks of the device pool carry a STATE SNAPSHOT, for a model
+    whose state layers hold a matrix a head (docs/PAGED_KV.md "Typed block
+    payload"): one snapshot is tens of MB, so the pool of them has a few
+    dozen entries and not one a block. Entry 0 is scratch (what a dispatch
+    writes where it was given none); entries 1..n belong to at most one
+    block each. An entry is ALLOTTED to a block before the dispatch that
+    will cross the block's last position, CONFIRMED when that dispatch's
+    tokens were accepted (only then can a prefix hit, a rewind or a resume
+    land on it), DROPPED if they were not, and RELEASED with the block
+    (`DeviceKVPool.decref`, so a demoted block gives its snapshot up). With
+    no entry free the least recently used confirmed one is given up.
+    `serial` tells an allotment from a later one of the same block."""
+
+    def __init__(self, entries: int, entry_bytes: int = 0):
+        self.entries = entries
+        self.entry_bytes = entry_bytes
+        self._lock = threading.Lock()  # guards: everything below
+        self._free = list(range(entries, 0, -1))
+        self._of: dict[int, int] = {}  # block -> entry
+        self._serial: dict[int, int] = {}  # block -> its allotment's number
+        self._valid: set[int] = set()  # blocks whose snapshot was confirmed
+        self._used: dict[int, int] = {}  # block -> when it was last wanted
+        self._tick = 0
+        self.evictions = 0
+        _SNAPSHOT_BYTES.set(0)
+
+    def _give_up(self, bid: int) -> None:  # holds: self._lock
+        self._free.append(self._of.pop(bid))
+        self._serial.pop(bid, None)
+        self._used.pop(bid, None)
+        self._valid.discard(bid)
+        _SNAPSHOT_BYTES.set(len(self._of) * self.entry_bytes)
+
+    def allot(self, bid: int) -> tuple[int, int]:
+        """(entry, serial) for the dispatch that will write block `bid`'s
+        snapshot; entry 0 where none can be had (every entry is being
+        written)."""
+        with self._lock:
+            self._tick += 1
+            self._valid.discard(bid)
+            if bid not in self._of:
+                if not self._free:
+                    victim = min(self._valid, key=self._used.get,
+                                 default=None)
+                    if victim is None:
+                        return 0, 0
+                    self._give_up(victim)
+                    self.evictions += 1
+                    _SNAPSHOT_EVICTIONS.inc()
+                self._of[bid] = self._free.pop()
+                _SNAPSHOT_BYTES.set(len(self._of) * self.entry_bytes)
+            self._serial[bid] = self._used[bid] = self._tick
+            return self._of[bid], self._tick
+
+    def settle(self, bid: int, serial: int, accepted: bool) -> None:
+        """The dispatch that wrote allotment `serial` was delivered: the
+        snapshot counts from now on, or (its tokens were not accepted) the
+        entry goes back. A later allotment of the block is left alone."""
+        with self._lock:
+            if self._serial.get(bid) != serial:
+                return
+            if accepted:
+                self._valid.add(bid)
+            else:
+                self._give_up(bid)
+
+    def entry(self, bid: int) -> int | None:
+        """The entry of block `bid`'s confirmed snapshot, None without."""
+        with self._lock:
+            if bid not in self._valid:
+                return None
+            self._tick += 1
+            self._used[bid] = self._tick
+            return self._of[bid]
+
+    def release(self, bid: int) -> None:
+        with self._lock:
+            if bid in self._of:
+                self._give_up(bid)
+
+    def reset(self) -> None:
+        with self._lock:
+            for bid in list(self._of):
+                self._give_up(bid)
+
+    def held(self) -> int:
+        with self._lock:
+            return len(self._of)
+
+
 class KVPoolExhausted(RuntimeError):
     """The device block pool could not serve an allocation even after
     reclaiming the directory and idle slots. Attributable to the request
@@ -114,6 +215,10 @@ class DeviceKVPool:
         self._refs = np.zeros(n_blocks, np.int32)
         self._refs[SCRATCH_BLOCK] = 1  # permanently pinned, never allocatable
         self._free = list(range(n_blocks - 1, 0, -1))  # stack, low ids first out
+        # the snapshot pool of a model whose state is a matrix a head (set
+        # by the engine that owns the arrays); None: a block's typed
+        # payload, if any, lies in the block itself
+        self.snapshots: SnapshotPool | None = None
         _POOL_BLOCKS.set(n_blocks)
         _POOL_FREE.set(len(self._free))
 
@@ -150,6 +255,8 @@ class DeviceKVPool:
                 if self._refs[b] == 0:
                     self._free.append(b)
                     freed += 1
+                    if self.snapshots is not None:
+                        self.snapshots.release(b)  # freed with the block
             _POOL_FREE.set(len(self._free))
         return freed
 
@@ -175,6 +282,8 @@ class DeviceKVPool:
             self._refs[SCRATCH_BLOCK] = 1
             self._free = list(range(self.n_blocks - 1, 0, -1))
             _POOL_FREE.set(len(self._free))
+        if self.snapshots is not None:
+            self.snapshots.reset()
 
     def refcounts(self) -> np.ndarray:
         """Snapshot for tests/stats."""

@@ -28,7 +28,7 @@ from typing import BinaryIO
 
 import numpy as np
 
-from ..models.params import (MIXER, Params, block_tensor_shapes,
+from ..models.params import (MIXER, SSM, Params, block_tensor_shapes,
                              layer_tensor_shapes)
 from ..models.spec import (ArchType, HeaderKey, HiddenAct, LayerKind, ModelSpec,
                            RopeType, RouterInput, RouterScore)
@@ -58,6 +58,21 @@ def _e6(v: int) -> float:
     return v / 1e6
 
 
+def _e9(v: int) -> float:
+    return v / 1e9
+
+
+def _or_one(v: int) -> int:
+    return int(v) or 1
+
+
+_UNITS = {_e6: 1e6, _e9: 1e9}  # what a float field is written in
+
+
+def _as_int(value, conv) -> int:
+    return round(value * _UNITS[conv]) if conv in _UNITS else int(value)
+
+
 _OWN_KEYS = (
     ("q_lora_rank", HeaderKey.Q_LORA_RANK, int),
     ("kv_lora_rank", HeaderKey.KV_LORA_RANK, int),
@@ -81,6 +96,11 @@ _OWN_KEYS = (
     ("attn_gate", HeaderKey.ATTN_GATE, bool),
     ("qk_norm", HeaderKey.QK_NORM, bool),
     ("router_bias", HeaderKey.ROUTER_BIAS, bool),
+    ("embedding_multiplier", HeaderKey.EMBEDDING_MULTIPLIER_E6, _e6),
+    ("residual_multiplier", HeaderKey.RESIDUAL_MULTIPLIER_E6, _e6),
+    ("logits_scaling", HeaderKey.LOGITS_SCALING_E6, _e6),
+    ("attn_multiplier", HeaderKey.ATTN_SCALE_E9, _e9),
+    ("state_snapshots", HeaderKey.STATE_SNAPSHOTS, int),
 )
 
 # kinds of attention layer (ModelSpec.kinds): kind k's field at header key
@@ -94,6 +114,10 @@ _KIND_FIELDS = (
     # taps of a convolution kind; a file written before the field has no
     # such key and reads 0, an attention kind
     ("conv_kernel", int),
+    # a state-space kind's heads, head size, state size and groups (0, 0, 0
+    # and 1 of every other kind, and of a file written before them)
+    ("ssm_heads", int), ("ssm_head_dim", int), ("ssm_state", int),
+    ("ssm_groups", _or_one),
 )
 _KIND_STRIDE = 16
 _MAX_KINDS = 4  # two bits a layer
@@ -115,7 +139,7 @@ def _pack_layer_kinds(spec: ModelSpec) -> list[tuple[int, int]]:
         for i, (name, conv) in enumerate(_KIND_FIELDS):
             value = getattr(kind, name)
             kv.append((HeaderKey.KIND_0 + _KIND_STRIDE * k + i,
-                       round(value * 1e6) if conv is _e6 else int(value)))
+                       _as_int(value, conv)))
     return kv
 
 
@@ -393,7 +417,7 @@ def write_header(f: BinaryIO, spec: ModelSpec, weights_ftype: FloatType) -> None
     for name, key, conv in _OWN_KEYS:
         value = getattr(spec, name)
         if value != getattr(ModelSpec, name):  # only where it says something
-            kv.append((key, round(value * 1e6) if conv is _e6 else int(value)))
+            kv.append((key, _as_int(value, conv)))
     data = b"".join(struct.pack("<ii", k, v) for k, v in kv)
     f.write(struct.pack("<i", MAGIC))
     f.write(struct.pack("<i", 8 + len(data)))
@@ -438,7 +462,8 @@ def write_model(path: str, spec: ModelSpec, tensors_iter, weights_ftype: FloatTy
     """
     norm_names = {"embedding", "rms_att", "rms_ffn", "rms_moe", "rms_ffn2", "rms_final",
                   "rms_q", "rms_kv", "rms_qh", "rms_kh", "conv_w",
-                  "router_bias"}
+                  "router_bias",
+                  *(n for n in SSM if n not in ("ssm_in", "ssm_out"))}
     with open(path, "wb") as f:
         write_header(f, spec, weights_ftype)
         for name, tensor in tensors_iter:

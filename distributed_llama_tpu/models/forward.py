@@ -44,6 +44,15 @@ Arch-specific structure:
   layers. Such a layer holds no keys and values but a STATE, the last
   conv_kernel - 1 rows of v = B * u, which is not a list of positions: it
   stands beside the caches as a `StateCache` in the second cache's place.
+- A state-space mixer (`LayerKind.ssm_state`; granite-4.0-h-small's Mamba-2
+  layers, `_ssm_mixer`) is such a state layer too, picked by the same
+  flag: its tail of convolution inputs rides the same ring at the kind's
+  own width, and its running MATRIX a head (`StateCache.h`), which sums
+  every earlier position, is the layer scan's carry beside x, updated in
+  place by `ops/pallas_ssd.py`. The graph's stated multipliers (embedding,
+  residual branches, logits), a stated attention scale and a kind without
+  a rotation are data on `ModelSpec` as well and absent from every other
+  model's program.
 - GROK1: embedding x78.38367176906169 (grok1-tasks.cpp:11-14); attention output is
   rmsnorm'd (rms_ffn) BEFORE the residual join (grokRmfFfn*, grok1-tasks.cpp:16-41);
   MoE input norm uses rms_moe; MoE output is rmsnorm'd with rms_ffn2 before its residual
@@ -66,7 +75,7 @@ from ..ops.matmul import LayerOf, qmatmul, reads_the_stack
 from ..ops.ring_attention import commit_kv_rows_sharded, ring_attention
 from ..ops.rope import RopeTables, apply_rope
 from ..quants import QTensor
-from .params import MIXER
+from .params import MIXER, is_state_tensor
 from .spec import ArchType, HiddenAct, ModelSpec, RouterInput, RouterScore
 
 GROK_EMBEDDING_SCALE = 78.38367176906169  # grok1-tasks.cpp:13
@@ -203,16 +212,25 @@ class RowMap(NamedTuple):
 # accepted frontier) finds the rows behind its frontier still there, as it
 # finds its keys, while STATE_RING >= the positions written ahead + the taps
 STATE_RING = 64
+# Positions between two snapshots of a model whose state layers hold a matrix
+# a head (`ModelSpec.ssm`): the block that ends at a position p with
+# (p + 1) % STATE_STRIDE == 0 is the one that may carry a snapshot (the
+# published kernel's `mamba_chunk_size`, 256, is only where the number comes
+# from: any chunking gives the same state). A convolution's two rows are
+# snapshot at every block end: its stride is the pool's block
+STATE_STRIDE = 256
 
 
 class StateCache(NamedTuple):
     """What stands in the SECOND cache's place (`v_cache`) for a model with
     state layers (`ModelSpec.mixed`): the values side of the attention
-    layers' cache as every model has it, and the state of the convolution
-    layers in two kinds.
+    layers' cache as every model has it, and the state of the state layers
+    (a gated short convolution's, a state-space mixer's) in two kinds, and
+    of the second a third.
 
-    `ring` (slots, STATE_RING, state layers + padding, dim) is the RUNNING
-    state: a layer's v = B * u at position p of slot b stands at
+    `ring` (slots, STATE_RING, state layers + padding, state_width) is the
+    RUNNING state's tail: a layer's v = B * u (a state-space layer's
+    convolution input u) at position p of slot b stands at
     [b, p % STATE_RING],
     so the state a row continues from at position s is rows s - 1, s - 2, ..
     (zeros before position 0), a write at or past a row's frontier (scratch,
@@ -220,8 +238,8 @@ class StateCache(NamedTuple):
     is written there is written again when the position is decoded for good:
     the free rollback keys and values have, for a short horizon.
 
-    `snaps` (1, pool blocks, (conv_kernel - 1) x state layers + padding, dim)
-    is the POOL's kind of state (docs/PAGED_KV.md "Typed block payload"): for
+    `snaps` (1, pool blocks, (conv_kernel - 1) x state layers + padding,
+    state_width) is the POOL's kind of state (docs/PAGED_KV.md "Typed block payload"): for
     block n, each layer's state at the block's LAST position (row i x state
     layers + l is layer l's v at that position - (conv_kernel - 2 - i)),
     indexed on axis 1 by the block ids keys and values are, so that one
@@ -239,10 +257,36 @@ class StateCache(NamedTuple):
     both arrays so, a copy in and a copy out of 38 and 302 MB a program, and
     with 18 layers unpadded on that axis the chip's default layout of the
     ARGUMENT put another axis there (the compiled text of
-    `perf/aot_step.py`, PERF.md section 6, PR 42)."""
+    `perf/aot_step.py`, PERF.md section 6, PR 42).
+
+    Both are as wide as a row of the kind's tail (`ModelSpec.state_width`:
+    `dim` of a gated short convolution, the convolution's input [x | B | C]
+    of a state-space mixer). A model whose state layers are state-space
+    mixers (`ModelSpec.ssm`) holds a THIRD kind of state, the running matrix
+    H of every head, which sums all earlier positions and has no ring:
+
+    `h` (slots, state layers, heads, P, N) float32: slot b's H after the
+    last position a dispatch computed for it, updated IN PLACE by the SSD
+    kernels (ops/pallas_ssd.py) as the layer scan's carry. A row that is not
+    live in a dispatch (`ctl`) leaves its H bit for bit; a row at position 0
+    starts from zeros whatever `h` holds. `held`: H as the K-step scan that
+    ran last FOUND it, which a flushed chained scan's survivors go back to
+    (the host swaps the two; runtime/batch_engine.py `_flush_inflight`).
+    `snap_h` (entries, state layers, heads, P, N) float32, and `snaps` on
+    axis 1, are then indexed by an ENTRY of the snapshot pool, not by a
+    block: which blocks carry one is the cache manager's decision
+    (docs/PAGED_KV.md "Typed block payload"), entry 0 is scratch. `ctl`
+    (2, slots, 1) int32, the host's word to a dispatch: whether each slot is
+    live in it, and the entry its snapshot goes to if one of its positions
+    ends a stride (0: none kept). None of the four where the model has no
+    such layer (or, `held`, `snap_h`, `ctl`, no pool)."""
     rows: jax.Array
     ring: jax.Array
     snaps: jax.Array
+    h: jax.Array | None = None
+    held: jax.Array | None = None
+    snap_h: jax.Array | None = None
+    ctl: jax.Array | None = None
 
 
 def _tile_rows(n: int) -> int:
@@ -251,11 +295,27 @@ def _tile_rows(n: int) -> int:
 
 
 def init_state(spec: ModelSpec, slots: int, n_blocks: int, dtype):
-    """Zeroed (ring, snaps) of a model with state layers."""
+    """Zeroed (ring, snaps, ...) of a model with state layers: what follows
+    `rows` in its StateCache. `n_blocks`: the pool's blocks (0: a contiguous
+    cache, nothing is snapshot)."""
     n = len(spec.state_layers)
-    return (jnp.zeros((slots, STATE_RING, _tile_rows(n), spec.dim), dtype),
-            jnp.zeros((1, n_blocks, _tile_rows(spec.state_rows * n),
-                       spec.dim), dtype))
+    entries = n_blocks
+    if spec.ssm and n_blocks:
+        entries = spec.state_snapshots + 1  # entry 0 is scratch
+    out = (jnp.zeros((slots, STATE_RING, _tile_rows(n), spec.state_width),
+                     dtype),
+           jnp.zeros((1, entries, _tile_rows(spec.state_rows * n),
+                      spec.state_width), dtype))
+    if not spec.ssm:
+        return out
+    matrix = (n, *spec.state_matrix)
+    h = jnp.zeros((slots, *matrix), jnp.float32)
+    if not n_blocks:
+        return (*out, h)
+    return (*out, h, jnp.zeros_like(h),
+            jnp.zeros((entries, *matrix), jnp.float32),
+            jnp.concatenate([jnp.ones((1, slots, 1), jnp.int32),
+                             jnp.zeros((1, slots, 1), jnp.int32)]))
 
 
 @functools.partial(jax.jit, donate_argnums=(0,), static_argnums=(5, 6))
@@ -271,6 +331,13 @@ def _seed_ring(ring, snaps, slot, block, pos, n: int, k1: int):
     return ring
 
 
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _seed_matrix(h, snap_h, slot, entry):
+    held = jax.lax.dynamic_slice(snap_h, (entry, 0, 0, 0, 0),
+                                 (1, *snap_h.shape[1:]))
+    return jax.lax.dynamic_update_slice(h, held, (slot, 0, 0, 0, 0))
+
+
 def seed_state(state: StateCache, slot, block, pos, n: int,
                k1: int) -> StateCache:
     """`state` with slot `slot`'s running state at position `pos` (a block
@@ -278,9 +345,14 @@ def seed_state(state: StateCache, slot, block, pos, n: int,
     ends at pos - 1: what a prefix hit and a slot rewind continue from, one
     small jitted copy on the device into the donated ring (the pool's sides
     are read, not donated). `n`, `k1`: the model's state layers and the rows
-    of a layer's state."""
-    return state._replace(ring=_seed_ring(state.ring, state.snaps, slot,
-                                          block, pos, n, k1))
+    of a layer's state. Of a state-space model `block` is the block's ENTRY
+    of the snapshot pool, and the slot's matrices are seeded with the rows."""
+    state = state._replace(ring=_seed_ring(state.ring, state.snaps, slot,
+                                           block, pos, n, k1))
+    if state.h is not None:
+        state = state._replace(h=_seed_matrix(state.h, state.snap_h, slot,
+                                              block))
+    return state
 
 
 class _Stream(NamedTuple):
@@ -298,7 +370,13 @@ class _Stream(NamedTuple):
     live: jax.Array
 
     @classmethod
-    def of(cls, rows: "RowMap | None", positions, b: int, t: int):
+    def of(cls, rows: "RowMap | None", positions, b: int, t: int,
+           slot_live=None):
+        """`slot_live` (slots,) bool: the slots the host says are live in
+        this dispatch (`StateCache.ctl`); None: all of them."""
+        if slot_live is not None:
+            plain = cls.of(rows, positions, b, t)
+            return plain._replace(live=plain.live & slot_live[plain.slot])
         if rows is None:  # the rectangle: every row is whole
             at = jnp.broadcast_to(positions, (b, t)).astype(jnp.int32)
             return cls(jnp.broadcast_to(jnp.arange(b, dtype=jnp.int32)[:, None],
@@ -368,10 +446,105 @@ def _short_conv(x, bp, state_idx, spec: ModelSpec, ring, stream: _Stream,
     return residual + out, v
 
 
+def _silu(v):
+    return v * jax.nn.sigmoid(v)
+
+
+def _ssm_mixer(x, bp, state_idx, spec: ModelSpec, ring, h, stream: _Stream,
+               rows: "RowMap | None", use_pallas, residual):
+    """A state-space mixer (Mamba-2) in attention's place, on whatever rows
+    the stream has, h the normed block input:
+
+        [z | u | dt] = ssm_in h           widths inner | state_width | heads
+        u'_p = silu(bias + sum_j w[:, j] u_{p-k+1+j})  depthwise, causal, k
+                                          taps, zeros before position 0
+        [x | B | C] = u'                  x as heads of P; B, C one group's
+        d_p  = softplus(dt_p + dt_bias);  A = -exp(a_log)     a scalar a head
+        H_p  = exp(d_p A) H_{p-1} + d_p x_p B_p^T             float32
+        y_p  = H_p C_p + D x_p
+        out  = ssm_out RMSNorm(y * silu(z); ssm_norm)         over all inner
+
+    A row's earlier u come from the stream or the slot's ring, exactly as a
+    gated short convolution's v do (`_state_prev`), and the new rows u are
+    returned for forward() to commit. H is the layer scan's carry `h`,
+    updated in place (ops/pallas_ssd.py): a lone token of every slot through
+    `ssd_step` (the T = 1 step, the scan, a chunk's riders), the T tokens of
+    one slot through `ssd_chunk` (a compact stream's lead, each row of a
+    rectangle). Returns (residual-joined output, u, h)."""
+    from ..ops.pallas_ssd import ssd_chunk, ssd_step
+
+    heads, p, n = spec.state_matrix
+    inner, cw = spec.ssm_inner, spec.state_width
+    xb = rmsnorm(x, bp["rms_att"], spec.norm_eps)
+    with jax.named_scope("ssm_mixer"):
+        zud = qmatmul(xb, bp["ssm_in"], use_pallas=use_pallas,
+                      name="q4_mm_ssm_in")
+        z, u, dt = (zud[..., :inner], zud[..., inner:inner + cw],
+                    zud[..., inner + cw:])
+        taps = bp["ssm_conv_w"].astype(jnp.float32)  # (cw, k)
+        k = taps.shape[-1]
+        acc = (bp["ssm_conv_b"].astype(jnp.float32)
+               + taps[:, k - 1] * u.astype(jnp.float32))
+        for j in range(1, k):
+            acc = acc + taps[:, k - 1 - j] * _state_prev(
+                u, ring, state_idx, stream, j).astype(jnp.float32)
+        xbc = _silu(acc)
+        cb, ct = xbc.shape[:2]
+        xs = xbc[..., :inner].reshape(cb, ct, heads, p)
+        b_, c_ = xbc[..., inner:inner + n], xbc[..., inner + n:]
+        dt = jax.nn.softplus(dt.astype(jnp.float32)
+                             + bp["ssm_dt_bias"].astype(jnp.float32))
+        a = -jnp.exp(bp["ssm_a_log"].astype(jnp.float32))
+        fresh, live = stream.pos == 0, stream.live
+        kw = {"use_pallas": use_pallas}
+        if rows is not None:  # a compact stream: the lead's chunk, riders
+            t, slots = rows.t, rows.slots
+            y0, h = ssd_chunk(h, state_idx, rows.lead, xs[0, :t], dt[0, :t],
+                              a, b_[0, :t], c_[0, :t], live[0, 0],
+                              fresh[0, 0], **kw)
+            rid = slice(t, t + slots)
+            y1, h = ssd_step(h, state_idx, xs[0, rid], dt[0, rid], a,
+                             b_[0, rid], c_[0, rid], live[0, rid],
+                             fresh[0, rid], **kw)
+            y = jnp.concatenate([y0, y1, jnp.zeros(
+                (ct - t - slots, heads, p), jnp.float32)])[None]
+        elif ct == 1:
+            y, h = ssd_step(h, state_idx, xs[:, 0], dt[:, 0], a, b_[:, 0],
+                            c_[:, 0], live[:, 0], fresh[:, 0], **kw)
+            y = y[:, None]
+        else:  # a rectangle: every row is a chunk of its own slot
+            ys = []
+            for i in range(cb):
+                yi, h = ssd_chunk(h, state_idx, i, xs[i], dt[i], a, b_[i],
+                                  c_[i], live[i, 0], fresh[i, 0], **kw)
+                ys.append(yi)
+            y = jnp.stack(ys)
+        y = y + bp["ssm_d"].astype(jnp.float32)[:, None] * xs
+        g = rmsnorm(y.reshape(cb, ct, inner) * _silu(z.astype(jnp.float32)),
+                    bp["ssm_norm"], spec.norm_eps).astype(x.dtype)
+        out = qmatmul(g, bp["ssm_out"], use_pallas=use_pallas,
+                      name="q4_mm_ssm_out")
+    return residual + _times(out, spec.residual_multiplier), u, h
+
+
+def _times(y, m: float):
+    """y times a stated multiplier, in float32; y itself where it is 1."""
+    if m == 1.0:
+        return y
+    return (y.astype(jnp.float32) * m).astype(y.dtype)
+
+
 def commit_state(state: StateCache, v_rows, stream: _Stream, block_tables,
                  block_tokens: int, k1: int) -> StateCache:
     """The state layers' commit of one dispatch: v_rows (state layers, cb,
-    ct, dim), each stream row's new v; `k1` the rows of a layer's state.
+    ct, width), each stream row's new v (a state-space mixer's u); `k1` the
+    rows of a layer's state. A state-space model's snapshots go by STRIDE and
+    by the host's word (`StateCache.ctl`), not by block: a live row at a
+    stride's last position writes the layers' tails into the entry its slot
+    was given, and the slot's matrices `h`, which the layer scan has left at
+    that position (a chunk never runs past a stride end: the scheduler cuts
+    it there), go into the same entry of `snap_h`, under a `lax.cond` that
+    costs nothing in the dispatches that end no stride.
 
     First the snapshots, against the ring as the dispatch found it: a live
     row at a block's last position writes the layers' state there, its own v
@@ -402,7 +575,8 @@ def commit_state(state: StateCache, v_rows, stream: _Stream, block_tables,
             flat, (jnp.maximum(r - j, 0), 0, 0), (1, np_, d))
         return jnp.where(in_stream[r] >= j, streamed, held)
 
-    if block_tables is not None and snaps.shape[1]:
+    by_entry = state.ctl is not None  # a state-space model's snapshot pool
+    if not by_entry and block_tables is not None and snaps.shape[1]:
         last_entry = block_tables.shape[1] - 1
 
         def snap(r, snaps):
@@ -433,7 +607,42 @@ def commit_state(state: StateCache, v_rows, stream: _Stream, block_tables,
             ring, jnp.where(fits[r], v_at(r, 0)[None], old), at)
 
     ring = jax.lax.fori_loop(0, cb * ct, put, ring0)
-    return state._replace(ring=ring, snaps=snaps)
+    state = state._replace(ring=ring, snaps=snaps)
+    if by_entry and state.snap_h is not None:
+        # by stride, a slot at a time, from the ring and the matrices as
+        # this dispatch LEAVES them (a stride's end is a slot's last
+        # position here), and only in a dispatch that ends a stride: the
+        # ring is then written before it is read, in place, where a loop
+        # over the stream's rows that read the ring as the dispatch found
+        # it made XLA copy the ring in and out (138 MB twice a dispatch in
+        # the compiled text of `perf/aot_step.py`, PR 44)
+        slots = state.h.shape[0]
+        ended = jnp.full((slots,), -1, jnp.int32).at[slot].max(
+            jnp.where(live, last, -1))
+        slot_ends = (ended >= 0) & ((ended + 1) % STATE_STRIDE == 0)
+        entry = jnp.where(slot_ends, state.ctl[1, :, 0], 0)  # 0: scratch
+
+        def keep(held):
+            def one(b, held):
+                snaps, snap_h = held
+                tails = jnp.concatenate([jax.lax.dynamic_slice(
+                    ring, (b, (ended[b] - (k1 - 1 - i)) % w, 0, 0),
+                    (1, 1, np_, d))[:, :, :n] for i in range(k1)], axis=2)
+                tails = jnp.pad(tails, ((0, 0), (0, 0), (0, rows - k1 * n),
+                                        (0, 0)))
+                matrix = jax.lax.dynamic_slice(
+                    state.h, (b, 0, 0, 0, 0), (1, *state.h.shape[1:]))
+                return (jax.lax.dynamic_update_slice(
+                            snaps, tails, (0, entry[b], 0, 0)),
+                        jax.lax.dynamic_update_slice(
+                            snap_h, matrix, (entry[b], 0, 0, 0, 0)))
+
+            return jax.lax.fori_loop(0, slots, one, held)
+
+        snaps, snap_h = jax.lax.cond(jnp.any(slot_ends), keep, lambda a: a,
+                                     (snaps, state.snap_h))
+        state = state._replace(snaps=snaps, snap_h=snap_h)
+    return state
 
 
 def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, start_pos,
@@ -520,6 +729,9 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
         v = qmatmul(xb, bp["wv"], use_pallas=use_pallas)
     hq_local = q.shape[-1] // hs
     hk_local = k.shape[-1] // hs
+    if spec.attn_multiplier:
+        # a stated scale: the readers multiply q . k by head_size^-0.5
+        q = _times(q, spec.attn_multiplier * hs ** 0.5)
     q = q.reshape(cb, ct, hq_local, hs)
     k = k.reshape(cb, ct, hk_local, hs)
     if "rms_qh" in bp:  # QK-norm: each head's q and k, before the rotation
@@ -676,6 +888,7 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
     # col-parallel wo: local heads x local input slice -> partial (B, T, dim); psum merges
     y = _maybe_psum(qmatmul(att, bp["wo"], use_pallas=use_pallas), axis_name,
                     compress)
+    y = _times(y, spec.residual_multiplier)
     return (y if residual is None else residual + y), (widen(k_t),
                                                        widen(v_t))
 
@@ -787,7 +1000,8 @@ def _dense_ffn(x, bp, spec: ModelSpec, axis_name, use_pallas, compress,
         h = (act(qmatmul(xb, bp["w1"], use_pallas=use_pallas))
              * qmatmul(xb, bp["w3"], use_pallas=use_pallas))
     out = qmatmul(h.astype(x.dtype), bp["w2"], use_pallas=use_pallas)
-    out = _maybe_psum(out, axis_name, compress)
+    out = _times(_maybe_psum(out, axis_name, compress),
+                 spec.residual_multiplier)
     return out if residual is None else residual + out
 
 
@@ -998,6 +1212,9 @@ def _block(x, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions,
     layer's K/V rows are zeros).
     """
     stats = jnp.zeros((N_MOE_STATS,), jnp.int32)  # of the expert layer: a ys
+    h = None  # the state-space layers' matrices ride in the carry beside x
+    if isinstance(x, tuple):
+        x, h = x
     # the layer's kind rides in the xs beside its index, where the model has
     # layers of more than one kind (forward() below)
     bp, layer_idx, *kind = layer
@@ -1022,8 +1239,8 @@ def _block(x, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions,
     # residual is given)
     res_attn = None if spec.arch_type == ArchType.GROK1 else x
     if mixed is not None:  # the layer's own mixer, under its own scope
-        attn_out, k_t, v_t, s_t = mixed.mix(
-            x, bp, at, start_pos=start_pos, positions=positions,
+        attn_out, k_t, v_t, s_t, h = mixed.mix(
+            x, h, bp, at, start_pos=start_pos, positions=positions,
             axis_name=axis_name, use_pallas=use_pallas, compress=compress,
             window=window, kc=kc, vc=vc, block_tables=block_tables,
             block_tokens=block_tokens, paged_kernel=paged_kernel, rows=rows)
@@ -1056,12 +1273,12 @@ def _block(x, layer, spec: ModelSpec, rope: RopeTables, start_pos, positions,
             xb = rmsnorm(x, bp["rms_ffn"], spec.norm_eps)
             moe_out, stats = _moe_ffn(xb, bp, spec, axis_name, use_pallas,
                                       compress, router_logits=router_logits)
-            x = x + moe_out
+            x = x + _times(moe_out, spec.residual_multiplier)
         else:
             x = _dense_ffn(x, bp, spec, axis_name, use_pallas, compress,
                            residual=x)
     if mixed is not None:
-        return x, (k_t, v_t, s_t, stats)
+        return (x if h is None else (x, h)), (k_t, v_t, s_t, stats)
     return x, (k_t, v_t, stats)
 
 
@@ -1093,10 +1310,12 @@ class _Mixers(NamedTuple):
               for l, c in zip(range(run.first, run.first + run.depth), conv)]
         return tuple(jnp.asarray(a, jnp.int32) for a in (conv, own, at))
 
-    def mix(self, x, bp, at, *, start_pos, positions, axis_name, use_pallas,
-            compress, window, kc, vc, block_tables, block_tokens,
+    def mix(self, x, h, bp, at, *, start_pos, positions, axis_name,
+            use_pallas, compress, window, kc, vc, block_tables, block_tokens,
             paged_kernel, rows):
-        """The layer's mixer on x, residual-joined: (out, k_t, v_t, s_t)."""
+        """The layer's mixer on x, residual-joined: (out, k_t, v_t, s_t, h),
+        h the state-space layers' matrices (None: the model has none), which
+        an attention layer hands on as it got them."""
         is_conv, own, idx = at
 
         def pick(w):
@@ -1109,10 +1328,11 @@ class _Mixers(NamedTuple):
         hk = kc.shape[2]
         no_kv = tuple(jnp.zeros((b, hk, t, c.shape[-1]), c.dtype)
                       for c in (kc, vc))
-        no_state = jnp.zeros(x.shape, self.ring.dtype)
+        no_state = jnp.zeros((*x.shape[:2], self.ring.shape[-1]),
+                             self.ring.dtype)
 
-        def attend(x):
-            names = [n for n in self.tensors if not n.startswith("conv_")]
+        def attend(x, h):
+            names = [n for n in self.tensors if not is_state_tensor(n)]
             with jax.named_scope("attn"):
                 out, kv = _attention(
                     x, {**bp, **{n: pick(self.tensors[n]) for n in names}},
@@ -1120,20 +1340,25 @@ class _Mixers(NamedTuple):
                     axis_name, None, 1, use_pallas, compress, window,
                     block_tables=block_tables, block_tokens=block_tokens,
                     paged_kernel=paged_kernel, residual=x, rows=rows)
-            return (out, *kv, no_state)
+            return (out, *kv, no_state, h)
 
-        def convolve(x):
-            names = [n for n in self.tensors if n.startswith("conv_")]
-            out, v = _short_conv(
-                x, {**bp, **{n: pick(self.tensors[n]) for n in names}}, idx,
-                self.conv, self.ring, self.stream, use_pallas, residual=x)
-            return (out, *no_kv, v.astype(no_state.dtype))
+        def convolve(x, h):
+            names = [n for n in self.tensors if is_state_tensor(n)]
+            own = {**bp, **{n: pick(self.tensors[n]) for n in names}}
+            if self.conv.ssm_state:
+                out, v, h = _ssm_mixer(x, own, idx, self.conv, self.ring, h,
+                                       self.stream, rows, use_pallas,
+                                       residual=x)
+            else:
+                out, v = _short_conv(x, own, idx, self.conv, self.ring,
+                                     self.stream, use_pallas, residual=x)
+            return (out, *no_kv, v.astype(no_state.dtype), h)
 
         if self.attn is None:
-            return convolve(x)
+            return convolve(x, h)
         if self.conv is None:
-            return attend(x)
-        return jax.lax.cond(is_conv > 0, convolve, attend, x)
+            return attend(x, h)
+        return jax.lax.cond(is_conv > 0, convolve, attend, x, h)
 
 
 def commit_block_rows(pool, rows, block_tables, start_pos):
@@ -1260,7 +1485,10 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
             tokens = rows.compact(tokens)
     else:
         positions = start_pos + jnp.arange(t, dtype=jnp.int32)
-    x = jnp.take(params["embedding"], tokens, axis=0).astype(dtype)
+    x = jnp.take(params["embedding"], tokens, axis=0)
+    if spec.embedding_multiplier != 1.0:
+        x = x * spec.embedding_multiplier
+    x = x.astype(dtype)
     if spec.arch_type == ArchType.GROK1:
         x = x * GROK_EMBEDDING_SCALE
 
@@ -1281,7 +1509,8 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
     # dequant-matmul and the grouped expert kernels take their blocks from
     # the whole stack at the layer's (and the expert's) index, and a slice
     # would be a copy of the layer's weights, every expert touched or not
-    def scan_stack(x, blocks, depth, spec=spec, rope=rope, at=(), **kind):
+    def scan_stack(x, blocks, depth, spec=spec, rope=rope, at=(), h=None,
+                   **kind):
         """One `lax.scan` over a stack of `depth` like layers. `at`: what a
         run of mixed layers adds to the xs (`_Mixers.xs_of`), its mixers'
         tensors staying out of them."""
@@ -1305,7 +1534,7 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
             # layers of more than one kind in ONE scan: the kind is data
             xs += (jnp.asarray(spec.layer_rope(), jnp.int32),
                    jnp.asarray(spec.layer_window(), jnp.int32))
-        return jax.lax.scan(block_fn, x, xs)
+        return jax.lax.scan(block_fn, x if h is None else (x, h), xs)
 
     # every run of like layers (`ModelSpec.runs`) is a stack of `params` and
     # a scan of its own: the leading dense layers hold other tensors than
@@ -1322,7 +1551,8 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
     if state is not None:
         stream = _Stream.of(rows, positions, *(
             positions.shape if positions.ndim == 2
-            else (tokens.shape[0], t)))
+            else (tokens.shape[0], t)),
+            slot_live=None if state.ctl is None else state.ctl[0, :, 0] > 0)
     for run in runs:
         kind = spec.kinds[run.kind] if spec.kinds and not spec.mixed else None
         scope = (f"run_{run.name}_{kind.name}" if kind
@@ -1338,7 +1568,7 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
                 spec.of_kind(conv[0]) if conv else None,
                 rope.of_kind(spec, attn[0]) if attn else None,
                 {n: w for n, w in params[run.name].items() if n in MIXER},
-                state.ring, stream)}
+                state.ring, stream), "h": state.h}
         with jax.named_scope(scope) if scope else contextlib.nullcontext():
             x, run_ys = scan_stack(
                 x, params[run.name], run.depth,
@@ -1346,6 +1576,9 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
                 rope=rope if spec.mixed else rope.of_kind(spec, run.kind),
                 routed=False if run.lead else None, layer_base=run.first,
                 **more)
+        if isinstance(x, tuple):  # the state-space layers' matrices, updated
+            x, h = x
+            state = state._replace(h=h)
         ys.append(run_ys)
     k_rows, v_rows, *s_rows, stats = ys[0] if len(ys) == 1 else (
         jnp.concatenate(a) for a in zip(*ys))
@@ -1403,6 +1636,8 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
         logits = jax.lax.all_gather(logits, axis_name, axis=-1, tiled=True)
     if spec.arch_type == ArchType.GROK1:
         logits = logits * GROK_LOGITS_SCALE
+    if spec.logits_scaling != 1.0:
+        logits = logits / spec.logits_scaling
     if paged_cold is not None:
         # the new rows ride out so the caller can append them to the host
         # store — the step's one extra device->host payload (L, B, hk, T, hs)

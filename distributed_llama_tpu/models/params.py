@@ -60,6 +60,11 @@ def block_tensor_shapes(spec: ModelSpec, lead: bool = False
     A convolution kind (spec.conv_kernel > 0) has conv_in (3 dim, dim: the
     gates B and C and the input u, in that order), the taps conv_w (dim,
     conv_kernel) and conv_out (dim, dim) in attention's place (`MIXER`);
+    a state-space kind (spec.ssm_state > 0; Mamba-2) has ssm_in (inner +
+    state_width + heads, dim: the gate z, the convolution's input [x | B | C]
+    and dt, in that order), the taps ssm_conv_w (state_width, conv_kernel)
+    with their bias ssm_conv_b, a head's ssm_dt_bias, ssm_a_log and ssm_d,
+    the gated norm's weight ssm_norm (inner,) and ssm_out (dim, inner).
     QK-norm (spec.qk_norm) adds rms_qh and rms_kh (head_size,), a selection
     bias (spec.router_bias) router_bias (n_router,) behind the router.
     """
@@ -67,7 +72,20 @@ def block_tensor_shapes(spec: ModelSpec, lead: bool = False
     d, h, kv, e = spec.dim, spec.hidden_dim, spec.kv_dim, spec.n_experts
     qd = spec.q_dim  # n_heads x head_size: dim unless the header states head_dim
     shapes: dict[str, tuple[tuple[int, ...], bool]]
-    if spec.conv_kernel:
+    if spec.ssm_state:
+        inner, cw = spec.ssm_inner, spec.state_width
+        nh = spec.ssm_heads
+        shapes = {
+            "ssm_in": ((inner + cw + nh, d), True),
+            "ssm_conv_w": ((cw, spec.conv_kernel), False),
+            "ssm_conv_b": ((cw,), False),
+            "ssm_dt_bias": ((nh,), False),
+            "ssm_a_log": ((nh,), False),
+            "ssm_d": ((nh,), False),
+            "ssm_norm": ((inner,), False),
+            "ssm_out": ((d, inner), True),
+        }
+    elif spec.conv_kernel:
         shapes = {
             "conv_in": ((3 * d, d), True),
             "conv_w": ((d, spec.conv_kernel), False),
@@ -128,8 +146,16 @@ def block_tensor_shapes(spec: ModelSpec, lead: bool = False
 # in a run of layers of several kinds (`ModelSpec.mixed`) each kind's stand
 # stacked over THAT kind's layers of the run, every other tensor over all of
 # the run's (`run_tensor_shapes`)
+SSM = ("ssm_in", "ssm_conv_w", "ssm_conv_b", "ssm_dt_bias", "ssm_a_log",
+       "ssm_d", "ssm_norm", "ssm_out")
 MIXER = frozenset({"wq", "wk", "wv", "wqkv", "wo", "rms_qh", "rms_kh",
-                   "conv_in", "conv_w", "conv_out"})
+                   "conv_in", "conv_w", "conv_out", *SSM})
+
+
+def is_state_tensor(name: str) -> bool:
+    """Whether a MIXER tensor is the state kind's (a convolution's, a
+    state-space mixer's) and not attention's."""
+    return name.startswith(("conv_", "ssm_"))
 
 
 def run_tensor_shapes(spec: ModelSpec, run) -> dict[
@@ -160,6 +186,19 @@ def layer_tensor_shapes(spec: ModelSpec, layer: int) -> dict[
     return block_tensor_shapes(spec.of_kind(kind), layer < spec.lead_layers)
 
 
+# what a state-space mixer's unquantized tensors are drawn AROUND, as
+# (layer's width, last axis) -> values: four different taps with the newest
+# the largest, a bias, steps of 0.001 to 0.1 ahead of the softplus, A between
+# 1 and 16 over the heads, a skip of 1
+_SSM_DRAWN = {
+    "ssm_conv_w": lambda n, k: np.asarray([0.25, -0.5, 0.75, 1.0][-k:]),
+    "ssm_conv_b": lambda n, k: 0.1,
+    "ssm_dt_bias": lambda n, k: np.log(np.expm1(np.geomspace(1e-3, 1e-1, n))),
+    "ssm_a_log": lambda n, k: np.log(np.linspace(1.0, 16.0, n)),
+    "ssm_d": lambda n, k: 1.0,
+}
+
+
 def init_random_params(spec: ModelSpec, weights_ftype: FloatType = FloatType.F32,
                        seed: int = 0, scale: float = 0.02) -> Params:
     """Random-weight model for tests/benchmarks (the reference's golden-test pattern:
@@ -179,6 +218,11 @@ def init_random_params(spec: ModelSpec, weights_ftype: FloatType = FloatType.F32
                 # taps and a selection bias that do something: of the size
                 # of the values they meet, not norm weights around 1
                 blocks[name] = full * (25.0 if name == "conv_w" else 5.0)
+            elif name in _SSM_DRAWN:
+                # taps, decays and steps that do something, with the draw's
+                # noise around them
+                blocks[name] = (_SSM_DRAWN[name](shape[1], shape[-1])
+                                + full * 5.0).astype(np.float32)
             else:
                 blocks[name] = full + 1.0  # norm weights around 1
         return blocks
@@ -226,7 +270,7 @@ _I8_CONVERTIBLE = (FloatType.Q40, FloatType.Q80)
 # (ColMatmulSlice), so the i4p split-plane pack must be applied per column group
 # (QTensor.to_i4p_layout).
 _DENSE_MATMULS = {"wq", "wk", "wv", "wo", "wg", "w1", "w2", "w3",
-                  "conv_in", "conv_out",
+                  "conv_in", "conv_out", "ssm_in", "ssm_out",
                   "moe_up", "moe_gate", "moe_down",
                   "wq_a", "wq_b", "wkv_a", "sh_gate", "sh_up", "sh_down"}
 _COL_SHARDED = {"wo", "w2", "moe_down", "sh_down"}

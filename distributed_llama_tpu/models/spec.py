@@ -54,6 +54,9 @@ class RopeType(enum.IntEnum):
     YARN = 3
     # half-split pairs as FALCON, YaRN's frequencies
     YARN_NEOX = 4
+    # no rotation at all (a kind of layer without positions, "nope"): q and k
+    # pass as projected, the tables are made and not read
+    NONE = 5
 
 
 # .m header key ids (reference: src/transformer.hpp:10-30 / converter/writer.py:109-130)
@@ -123,6 +126,15 @@ class HeaderKey(enum.IntEnum):
     # weights)
     QK_NORM = 62
     ROUTER_BIAS = 63
+    # the multipliers of a graph that states them (embedding, each residual
+    # branch, a divisor of the logits), a stated attention scale, and the
+    # snapshot pool of a model whose state is a matrix a head, in units of
+    # 1e-6 where they are floats (0: the key's default)
+    EMBEDDING_MULTIPLIER_E6 = 80
+    RESIDUAL_MULTIPLIER_E6 = 81
+    LOGITS_SCALING_E6 = 82
+    ATTN_SCALE_E9 = 83
+    STATE_SNAPSHOTS = 84
     LAYER_KINDS_0 = 64  # ..79
     KIND_0 = 100
 
@@ -136,10 +148,10 @@ def yarn_mscale(factor: float, mscale: float) -> float:
 class LayerKind:
     """One kind of layer of a model whose layers differ in more than a 0/1
     switch (ModelSpec.kinds): an attention layer's query heads, its window,
-    its rotation, or (conv_kernel > 0) a layer whose mixer is a gated short
-    convolution and holds no keys and values at all. Every field overrides
-    the ModelSpec field of the same name for the layers of this kind
-    (`ModelSpec.of_kind`)."""
+    its rotation, or (conv_kernel > 0) a layer that holds a STATE and no
+    keys and values at all: a gated short convolution, or (ssm_state > 0) a
+    state-space mixer (Mamba-2). Every field overrides the ModelSpec field
+    of the same name for the layers of this kind (`ModelSpec.of_kind`)."""
 
     name: str
     n_heads: int
@@ -159,6 +171,18 @@ class LayerKind:
     # list of positions: it stands beside the keys and values of the other
     # layers (models/forward.py StateCache). 0: an attention layer
     conv_kernel: int = 0
+    # a state-space mixer (Mamba-2; models/forward.py _ssm_mixer): ssm_heads
+    # heads of ssm_head_dim values, each with a running MATRIX of
+    # ssm_head_dim x ssm_state that sums every earlier position, B and C of
+    # ssm_groups x ssm_state shared by a group's heads, ahead of them a
+    # depthwise causal convolution of conv_kernel taps (with a bias) over
+    # the ssm_heads x ssm_head_dim + 2 x ssm_groups x ssm_state values of
+    # [x | B | C]. Its state after position p: the matrices, and the
+    # convolution's last conv_kernel - 1 input rows. 0: no such mixer
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
 
 
 class Run(NamedTuple):
@@ -267,6 +291,26 @@ class ModelSpec:
     # the router takes its k largest over score + router_bias (a tensor, one
     # value an expert a layer) and its weights from the scores alone
     router_bias: bool = False
+    # a kind's state-space mixer (LayerKind.ssm_*), on the spec `of_kind`
+    # makes of it; 0 on a model's own spec
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    # the graph's stated multipliers: the embedding's rows times the first,
+    # each residual branch (mixer, FFN) times the second before it joins the
+    # stream, the logits DIVIDED by the third. 1.0: not in the program
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    # what q . k is multiplied by where the model states it as a number
+    # (an `attention_multiplier`). 0: derived (`attn_scale`)
+    attn_multiplier: float = 0.0
+    # entries of the snapshot pool of a model whose state layers hold a
+    # matrix a head: which blocks carry a snapshot is then the cache
+    # manager's decision (docs/PAGED_KV.md "Typed block payload"). 0: every
+    # block carries its own (a convolution's state is two rows)
+    state_snapshots: int = 0
 
     # --- derived (reference: transformer.cpp:102-106) ---
     @property
@@ -311,16 +355,53 @@ class ModelSpec:
 
     @property
     def mixed(self) -> bool:
-        """Whether some kind of layer is a convolution: such a model's layers
-        stand in TWO runs at most (`runs`), each one scan whose body picks
-        the mixer by a per-layer flag."""
+        """Whether some kind of layer holds a state and no keys and values
+        (a convolution, a state-space mixer): such a model's layers stand in
+        TWO runs at most (`runs`), each one scan whose body picks the mixer
+        by a per-layer flag."""
         return any(k.conv_kernel for k in self.kinds)
+
+    @property
+    def ssm(self) -> bool:
+        """Whether the state layers are state-space mixers: their state is a
+        matrix a head beside the convolution's rows (`state_matrix`)."""
+        return any(k.ssm_state for k in self.kinds) or self.ssm_state > 0
 
     @property
     def state_layers(self) -> tuple[int, ...]:
         """The layers that hold a state and no keys and values."""
         return tuple(l for l, k in enumerate(self.layer_kinds)
                      if self.kinds[k].conv_kernel)
+
+    def _state_kind(self):
+        """The state kind's fields: the kind of a model's own spec, or this
+        spec where `of_kind` made it of one."""
+        return next((k for k in self.kinds if k.conv_kernel), self)
+
+    @property
+    def ssm_inner(self) -> int:
+        """Width of a state-space mixer's x and gate: heads x head size."""
+        k = self._state_kind()
+        return k.ssm_heads * k.ssm_head_dim
+
+    @property
+    def state_width(self) -> int:
+        """Values of one row of a state layer's tail: `dim` of a gated short
+        convolution (its v), and of a state-space mixer the convolution's
+        input [x | B | C]."""
+        k = self._state_kind()
+        if not k.ssm_state:
+            return self.dim
+        return k.ssm_heads * k.ssm_head_dim + 2 * k.ssm_groups * k.ssm_state
+
+    @property
+    def state_matrix(self) -> tuple[int, int, int] | None:
+        """(heads, head size, state size) of the running matrix a state
+        layer holds a sequence in float32; None: a convolution holds none."""
+        k = self._state_kind()
+        if not k.ssm_state:
+            return None
+        return k.ssm_heads, k.ssm_head_dim, k.ssm_state
 
     @property
     def cache_layers(self) -> tuple[int, ...]:
@@ -331,21 +412,29 @@ class ModelSpec:
 
     @property
     def state_rows(self) -> int:
-        """Rows of `dim` values a state layer holds a sequence: the taps
-        less one (every convolution kind of a model has the same kernel)."""
+        """Rows of `state_width` values a state layer holds a sequence, its
+        tail: the taps less one (a model has one state kind)."""
         return max([k.conv_kernel for k in self.kinds]
                    + [self.conv_kernel, 1]) - 1
 
     def state_block_bytes(self, itemsize: int) -> int:
-        """Bytes of the snapshot a block of the pool holds beside its keys
-        and values: every state layer's state at the block's last position."""
-        return len(self.state_layers) * self.state_rows * self.dim * itemsize
+        """Bytes of one snapshot: every state layer's state at a block's last
+        position, the tail's rows at `itemsize` and the matrix, where the
+        kind has one, in float32. A convolution's lies in every block of the
+        pool; a state-space model's in the blocks the cache manager gives an
+        entry of the snapshot pool (`state_snapshots`)."""
+        matrix = math.prod(self.state_matrix or (0,)) * 4
+        return len(self.state_layers) * (
+            self.state_rows * self.state_width * itemsize + matrix)
 
     @property
     def attn_scale(self) -> float:
         """What q . k is multiplied by before the softmax: head_size^-0.5,
         times YaRN's mscale(factor, mscale_all_dim)^2 where the model states
-        one (the DeepSeek-V3 graph's softmax_scale)."""
+        one (the DeepSeek-V3 graph's softmax_scale); the stated number where
+        the model gives one (`attn_multiplier`)."""
+        if self.attn_multiplier:
+            return self.attn_multiplier
         scale = self.head_size ** -0.5
         if (self.rope_type in (RopeType.YARN, RopeType.YARN_NEOX)
                 and self.yarn_mscale_all_dim
@@ -479,16 +568,25 @@ class ModelSpec:
                 assert (len([k for k in spec.kinds if k.conv_kernel]) == 1
                         and len(spec.kinds) == 2), (
                     "a model with state layers has one convolution kind "
-                    "and one attention kind")
+                    "(a gated short convolution or a state-space mixer) and "
+                    "one attention kind")
                 assert spec.cache_layers, (
                     "a model with state layers has an attention layer too")
             for k in spec.kinds:
+                assert not k.ssm_state or (
+                    k.conv_kernel > 1 and k.ssm_heads and k.ssm_head_dim
+                    and k.ssm_groups == 1), (
+                    "a state-space kind states its heads, head size, state "
+                    "size and taps, and one group of B and C", k)
                 assert k.n_heads % spec.n_kv_heads == 0, (k, spec.n_kv_heads)
                 assert (0 <= k.rotary_dim <= spec.head_size
                         and not k.rotary_dim % 2), k
                 assert k.rope_type != RopeType.UNKNOWN, k
         else:
             assert not spec.layer_kinds, "layer_kinds without kinds"
+        assert spec.ssm == bool(spec.state_snapshots) or not spec.kinds, (
+            "a model whose state is a matrix a head states its snapshot "
+            "pool (state_snapshots), and no other model does")
         assert not (spec.attn_gate and spec.latent), (
             "the per-head gate is not stated for latent attention")
         assert (spec.expert_offset + spec.n_experts

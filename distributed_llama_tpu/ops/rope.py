@@ -149,6 +149,8 @@ def apply_rope(x: jax.Array, tables: RopeTables, positions: jax.Array) -> jax.Ar
     narrower than the head rotate its first 2 x width values (pairs within
     them) and pass the rest as they are.
     """
+    if tables.rope_type == RopeType.NONE:
+        return x
     cos = tables.cos[positions][..., :, None, :]  # (..., T, 1, hs//2)
     sin = tables.sin[positions][..., :, None, :]
     hs = 2 * cos.shape[-1]

@@ -23,6 +23,7 @@ from typing import Any
 
 from jax.sharding import PartitionSpec as P
 
+from ..models.params import SSM
 from ..models.spec import ModelSpec
 from .mesh import AXIS_TP
 
@@ -75,6 +76,8 @@ _BLOCK_SPECS = {
     "conv_in": P(),
     "conv_w": P(),
     "conv_out": P(),
+    # and so is a state-space mixer
+    **{n: P() for n in SSM},
 }
 
 

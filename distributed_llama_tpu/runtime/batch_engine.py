@@ -70,6 +70,16 @@ Design:
   truncates the reusable history as it does for keys, and the next
   admission rewinds to a snapshot under it. Speculative verify, the dense
   per-slot caches, the Q80 tier and KV-block streaming refuse such a model.
+  A STATE-SPACE layer (ModelSpec.ssm) holds besides such a tail a running
+  MATRIX a head that sums every earlier position: no ring holds it, so it is
+  CARRIED. A dispatch is told which slots are live (`_state_word`) and leaves
+  every other slot's matrices bit for bit; a row at position 0 starts from
+  zeros; a row over-decoded in a scan is gone with its request; a flushed
+  chained scan's survivors go back to the matrices as that scan found them
+  (`_flush_inflight`); and snapshots are taken every 256 positions into a
+  pool of `ModelSpec.state_snapshots` entries that blocks are given and lose
+  (cache/device_pool.py SnapshotPool): a prefix hit, a rewind and a resume
+  land on the newest block under them that carries one.
 - CROSS-REQUEST prefix reuse (cache/, docs/PREFIX_CACHE.md): a finished slot's
   committed prefix is harvested into a radix-indexed block pool; a new request
   whose prompt shares cached blocks — on ANY slot — seeds its cache rows + pos
@@ -92,8 +102,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..cache.block_pool import PendingRows
-from ..models.forward import (STATE_RING, StateCache, compact_rows,
-                              seed_state)
+from ..models.forward import (STATE_RING, STATE_STRIDE, StateCache,
+                              compact_rows, seed_state)
 from ..models.spec import ModelSpec
 from ..obs import flight, metrics, process, reqctx, trace
 from ..ops.pallas_paged_attention import visited_keys
@@ -162,6 +172,31 @@ _STATE_BYTES = metrics.counter(
     "Bytes a dispatch writes into the state layers' rings and snapshots, "
     "parked rows' scratch writes included: what the second kind of state "
     "costs a step in HBM writes")
+# The third kind (ModelSpec.ssm: a state-space layer's running matrix a head,
+# updated in place by ops/pallas_ssd.py), counted apart so that the counters
+# above keep reading what they read
+_SSM_ROWS = metrics.counter(
+    "batch_ssm_rows_stepped_total",
+    "Live rows a dispatch took through ssd_step (a T = 1 step, each step of "
+    "the K-step scan, a chunk's riders) x state layers")
+_SSM_CHUNK_TOKENS = metrics.counter(
+    "batch_ssm_chunk_tokens_total",
+    "Tokens a dispatch took through ssd_chunk (the prefilling row's chunk) "
+    "x state layers")
+_SSM_BYTES = metrics.counter(
+    "batch_ssm_state_bytes_total",
+    "Bytes of running matrices H a dispatch read and wrote: two a live row "
+    "a layer a step, two a chunk's slot a layer, and a snapshot's read and "
+    "write where a row ended a stride")
+_SSM_STRIDE_ENDS = metrics.counter(
+    "batch_ssm_stride_ends_total",
+    "Stride ends (a position p with (p + 1) % 256 == 0) the rows of the "
+    "dispatches issued crossed: what batch_ssm_snapshots_total is held "
+    "against")
+_SSM_SNAPSHOTS = metrics.counter(
+    "batch_ssm_snapshots_total",
+    "Stride ends for which the dispatch was given an entry of the snapshot "
+    "pool to write the row's state into")
 _STATE_RESTORES = metrics.counter(
     "paged_kv_state_restores_total",
     "Admissions (prefix hits and slot rewinds past position 0) that seeded a "
@@ -466,15 +501,21 @@ def _pool_sides(eng) -> tuple:
     (the typed block payload, docs/PAGED_KV.md)."""
     vc = eng.v_cache
     if isinstance(vc, StateCache):
-        return eng.k_cache, vc.rows, vc.snaps
+        # a state-space model's snapshots lie in a pool of their own, by
+        # entry and not by block (cache/device_pool.py SnapshotPool)
+        return (eng.k_cache, vc.rows) + (() if vc.h is not None
+                                         else (vc.snaps,))
     return eng.k_cache, vc
 
 
 def _set_pool_sides(eng, sides) -> None:
     eng.k_cache = sides[0]
     vc = eng.v_cache
-    eng.v_cache = (vc._replace(rows=sides[1], snaps=sides[2])
-                   if isinstance(vc, StateCache) else sides[1])
+    if isinstance(vc, StateCache):
+        eng.v_cache = vc._replace(rows=sides[1], **(
+            {"snaps": sides[2]} if len(sides) > 2 else {}))
+    else:
+        eng.v_cache = sides[1]
 
 
 # The prefix cache's demotion reads a reclaim's victims with ONE gather from
@@ -828,7 +869,7 @@ class _InflightStep:
     __slots__ = ("rows", "k", "starts", "budget", "temps", "toks", "tok",
                  "pos", "rng", "t_issue", "chained", "window", "kind",
                  "ndraft", "acc", "cstate", "moe", "lead", "piece", "span",
-                 "computed")
+                 "computed", "snaps")
 
     def __init__(self, rows, k, starts, budget, temps, toks, tok, pos, rng,
                  t_issue, chained, window, kind="scan", ndraft=None,
@@ -862,6 +903,9 @@ class _InflightStep:
         self.piece = piece
         self.span = span
         self.computed = computed
+        # a state-space model only: the snapshot entries this dispatch was
+        # given, (slot, request, block, allotment, stride's last position)
+        self.snaps: list = []
 
     @classmethod
     def step(cls, rows, t, starts, t0, chained, window, span, lead=None,
@@ -957,16 +1001,16 @@ class BatchEngine:
                 if 2 * superstep + spec.state_rows > STATE_RING else None)
             if why:
                 raise ValueError(
-                    "a model with state layers (a gated short convolution) "
-                    f"is not supported by {why}")
+                    "a model with state layers (a gated short convolution, "
+                    f"a state-space mixer) is not supported by {why}")
         self._eng = Engine(spec, params, tokenizer, batch=slots,
                            kv_pool=kv_pool_cfg, moe_stats=spec.is_moe,
                            **engine_kw)
         if spec.mixed and self._eng.kv_pool is None:
             raise ValueError(
-                "a model with state layers (a gated short convolution) is "
-                "served from the device block pool, which this engine's "
-                "sharding or KV storage turned off")
+                "a model with state layers (a gated short convolution, a "
+                "state-space mixer) is served from the device block pool, "
+                "which this engine's sharding or KV storage turned off")
         _KV_ROW_BYTES.set(spec.cache_row_bytes(
             self._eng.k_cache.dtype.itemsize))
         # attention's per-layer lower key bound, as (window, share of layers)
@@ -996,6 +1040,18 @@ class BatchEngine:
                 c.nbytes // c.shape[1] for c in _pool_sides(self._eng))
             _STATE_BLOCK_BYTES.set(spec.state_block_bytes(
                 self._eng.k_cache.dtype.itemsize) if spec.mixed else 0)
+            # positions between two snapshots of the state layers: a
+            # convolution's at every block end, a state-space model's where
+            # the cache manager gives the block an entry of its pool
+            self._stride = 0 if not spec.mixed else (
+                STATE_STRIDE if spec.ssm else self._kv_bt)
+            if spec.ssm:
+                from ..cache.device_pool import SnapshotPool
+
+                assert STATE_STRIDE % self._kv_bt == 0, self._kv_bt
+                self.kv_pool.snapshots = SnapshotPool(
+                    spec.state_snapshots, spec.state_block_bytes(
+                        self._eng.k_cache.dtype.itemsize))
         # admission seeding cost readout (bench.py shared-prefix columns):
         # host→device KV bytes moved and wall time spent seeding slots —
         # ~0 bytes on the paged path (remap), the full fetched span dense
@@ -1757,9 +1813,12 @@ class BatchEngine:
         rewind = common(best)
         if self.spec.mixed:
             # a state layer's state is not a list of positions: a rewind
-            # lands where a snapshot exists, on a block end, and prefill
-            # goes on from there
-            rewind -= rewind % self._kv_bt
+            # lands where a snapshot exists (a block end; of a state-space
+            # model the newest block under it that carries one), and
+            # prefill goes on from there
+            rewind = self._state_landing(
+                rewind, lambda i: best.blocks[i] if i < len(best.blocks)
+                else None)
         reuse = rewind
         if self.kv_pool is not None:
             # paged admission (docs/PAGED_KV.md): the radix directory hit
@@ -1769,10 +1828,13 @@ class BatchEngine:
                 reuse = self._paged_adopt(best, req, rewind, full)
                 if self.spec.mixed and reuse:
                     # continue from the snapshot of the block that ends there
-                    self._seed_state(
-                        best.index, best.blocks[reuse // self._kv_bt - 1],
-                        reuse)
-                    _STATE_RESTORES.inc()
+                    try:
+                        self._seed_state(
+                            best.index,
+                            best.blocks[reuse // self._kv_bt - 1], reuse)
+                        _STATE_RESTORES.inc()
+                    except LookupError:  # its entry went meanwhile: cold
+                        reuse = self._paged_adopt_rewind_only(best, 0)
         elif self.prefix_cache is not None:
             # [0, reuse) is served by the slot's own resident rows; anything
             # the radix seed adds on top is counted as hit_tokens inside.
@@ -2055,7 +2117,7 @@ class BatchEngine:
             self.prefix_cache.reclaim(deficit, read.block)
             reads = read.issue(pool) if read.bids else 0
             sp.add(blocks=len(read.bids), reads=reads)
-            if self.spec.mixed:  # of the bytes read, the snapshots'
+            if len(pool) > 2:  # of the bytes read, the snapshots'
                 sp.add(state_bytes=len(read.bids) * pool[2].nbytes
                        // pool[2].shape[1])
         if reads:
@@ -2100,7 +2162,7 @@ class BatchEngine:
                 # behind slot 0's position bt) no sequence reads, a slot's
                 # ring being trusted only where its own sequence wrote it
                 # or an admission seeded it
-                self._seed_state(0, 0, self._kv_bt)
+                self._seed_state(0, 0, self._stride, entry=0)
         read = _DemoteRead(pool)
         rows = read.block(bid)
         read.issue(pool)
@@ -2164,7 +2226,9 @@ class BatchEngine:
                 lease = pc.lookup(full, cap=self.spec.seq_len - 1)
                 if lease is not None and self.spec.mixed:
                     # whole blocks alone: a hit lands on a block's snapshot
-                    pc.shrink(lease, lease.tokens - lease.tokens % bt)
+                    pc.shrink(lease, self._state_landing(
+                        lease.tokens, lambda i: lease.nodes[i].handle[1]
+                        if lease.nodes[i].handle[0] == "dev" else None))
                 if lease is not None and lease.tokens <= rewind:
                     pc.mark_unused(lease)
                     lease = None
@@ -2255,15 +2319,90 @@ class BatchEngine:
             self._paged_cow(slot, rewind, rewind + 1)
         return rewind
 
-    def _seed_state(self, slot: int, bid: int, pos: int) -> None:
+    def _seed_state(self, slot: int, bid: int, pos: int,
+                    entry: int | None = None) -> None:
         """A model with state layers: slot `slot`'s running state at position
         `pos` (a block boundary > 0: a prefix hit's or a rewind's) becomes
         what block `bid`, which ends there, snapshot: one small jitted copy
-        on the device (models/forward.py seed_state)."""
+        on the device (models/forward.py seed_state). A state-space model's
+        snapshot lies at the block's ENTRY of the snapshot pool (`entry`:
+        given by the warm-up alone); LookupError where the block has none."""
         eng = self._eng
+        if self.spec.ssm:
+            bid = (entry if entry is not None
+                   else self.kv_pool.snapshots.entry(bid))
+            if bid is None:
+                raise LookupError("the block carries no snapshot")
         eng.v_cache = seed_state(eng.v_cache, np.int32(slot), np.int32(bid),
                                  np.int32(pos), len(self.spec.state_layers),
                                  self.spec.state_rows)
+
+    def _state_landing(self, tokens: int, block_at) -> int:
+        """The longest prefix of `tokens` positions a state model can
+        continue from: a multiple of the stride whose last block carries a
+        snapshot; 0 with none. `block_at(i)`: the device block that holds
+        positions [i bt, (i + 1) bt) of the match, None where it has none."""
+        n = tokens - tokens % self._stride
+        if not self.spec.ssm:
+            return n  # a convolution's state lies in every block
+        while n > 0:
+            bid = block_at(n // self._kv_bt - 1)
+            if bid is not None and self.kv_pool.snapshots.entry(
+                    bid) is not None:
+                return n
+            n -= self._stride
+        return 0
+
+    def _state_word(self, rows, starts: list[int], budget: list[int],
+                    chunk: int = 0) -> tuple[list, dict]:
+        """A state-space model's word to the dispatch about to be issued
+        (`StateCache.ctl`): which slots are live in it, and for each row that
+        will end a stride the entry of the snapshot pool its state goes to,
+        allotted here. `rows` its (slot, request) pairs, `starts` and
+        `budget` every slot's position and the tokens it advances; `chunk`:
+        the prefilling row's tokens (0: every row steps). Returns the
+        allotments (`_InflightStep.snaps`) and the dispatch span's args
+        (the work of exactly this dispatch, for a reader that joins it to
+        its execution)."""
+        spec, eng = self.spec, self._eng
+        word = np.zeros((2, self.slots_n, 1), np.int32)
+        snaps = []
+        layers = len(spec.state_layers)
+        stepped = tokens = 0
+        for slot, req in rows:
+            i, n = slot.index, budget[slot.index]
+            word[0, i, 0] = 1
+            if chunk and n == chunk and n > 1:
+                tokens += n
+            else:
+                stepped += n
+            if (starts[i] + n) // self._stride > starts[i] // self._stride:
+                last = (starts[i] + n) // self._stride * self._stride - 1
+                bid = slot.blocks[last // self._kv_bt]
+                entry, serial = self.kv_pool.snapshots.allot(bid)
+                word[1, i, 0] = entry
+                _SSM_STRIDE_ENDS.inc()
+                if entry:
+                    _SSM_SNAPSHOTS.inc()
+                    snaps.append((slot, req, bid, serial, last))
+        eng.v_cache = eng.v_cache._replace(ctl=_upload(word))
+        matrix = 4 * int(np.prod(spec.state_matrix)) * layers
+        nbytes = 2 * matrix * (stepped + (1 if tokens else 0) + len(snaps))
+        _SSM_ROWS.inc(stepped * layers)
+        _SSM_CHUNK_TOKENS.inc(tokens * layers)
+        _SSM_BYTES.inc(nbytes)
+        return snaps, {"ssm_rows": stepped * layers,
+                       "ssm_chunk": tokens * layers, "ssm_bytes": nbytes}
+
+    def _settle_snapshots(self, fl: _InflightStep, accepted: bool) -> None:
+        """A dispatch's snapshot allotments once it is delivered (or
+        flushed, `accepted` False): an entry counts where its row's request
+        got past the stride's last position, else it goes back."""
+        for slot, req, bid, serial, last in fl.snaps:
+            ok = accepted and (req.done.is_set() if slot.req is not req
+                               else slot.pos > last)
+            self.kv_pool.snapshots.settle(bid, serial, ok)
+        fl.snaps = []
 
     def _dispatched(self, kind: str, call):
         """Run one device dispatch with transient-fault retry: classify()
@@ -2406,12 +2545,15 @@ class BatchEngine:
             _BLOCK_ENDS.inc(ends)
             if spec is not None and spec.mixed:
                 layers = len(spec.state_layers)
+                if spec.ssm:  # the tails are snapshot where a stride ends
+                    ends = sum((p + n) // self._stride - p // self._stride
+                               for p, n in real)
                 _STATE_ROWS.inc(layers * sum(n for _, n in real))
                 _STATE_SNAPSHOTS.inc(layers * ends)
                 # what the program writes, scratch included: a ring row a
                 # computed row a layer, and a block's snapshot a block end
                 # (every row's of a scan step or a lone token, the chunk's)
-                row = spec.dim * self._eng.k_cache.dtype.itemsize
+                row = spec.state_width * self._eng.k_cache.dtype.itemsize
                 _STATE_BYTES.inc(layers * row * (
                     computed + spec.state_rows * ends))
 
@@ -3268,6 +3410,11 @@ class BatchEngine:
         pending = slot.pending[slot.ahead:]
         chunk = next((c for c in PREFILL_CHUNKS if len(pending) >= c), 1)
         chunk = min(chunk, s - pos)
+        if self.spec.ssm and chunk > self._stride - pos % self._stride:
+            # a chunk never runs past a stride's end: the slot's running
+            # matrix is snapshot as the chunk leaves it
+            chunk = next(c for c in PREFILL_CHUNKS
+                         if c <= self._stride - pos % self._stride)
         # keep parked rows' scratch writes inside the cache without
         # touching history: a parked row writes [pos, pos+chunk) which
         # must fit under seq_len; shrink the chunk when any OTHER row
@@ -3366,6 +3513,11 @@ class BatchEngine:
         ahead = (chain is not None or self._runs_ahead()) and all(
             self._samples_here(s) for s in sampled)
         name, args = fl.span
+        if self.spec.ssm:
+            fl.snaps, work = self._state_word(
+                fl.rows, fl.starts, fl.budget,
+                chunk=fl.k if lead is not None else 0)
+            args.update(work)
 
         def launch():
             fl.toks, fl.tok, fl.moe = self._launch_step(staged, kind, chain)
@@ -3588,6 +3740,7 @@ class BatchEngine:
             else:
                 slot.last_logits = out[slot.index,
                                        -1 if slot is lead or t == 1 else 0]
+        self._settle_snapshots(fl, True)
 
     def _decode_step(self, active: list[_Slot]) -> None:
         # bring every row to its next un-ingested token (host-samples rows at a
@@ -3858,6 +4011,8 @@ class BatchEngine:
         window = window or self.spec.seq_len
         self._observe_gap()
         t_issue = time.perf_counter()
+        snaps, work = (self._state_word(rows, starts, budget)
+                       if self.spec.ssm else ([], {}))
         kc_in, vc_in = eng.k_cache, eng.v_cache  # same stale-epoch discipline
         tables = self._tables() if self.kv_pool is not None else None
         constrain = None
@@ -4119,6 +4274,8 @@ class BatchEngine:
             tok_in, pos_in, rng_in = chain.tok, chain.pos, chain.rng
             _DISPATCH_GAP.observe(0.0)  # chained: the device never went idle
         t_issue = time.perf_counter()
+        snaps, work = (self._state_word(rows, starts, budget)
+                       if self.spec.ssm else ([], {}))
         kc_in, vc_in = eng.k_cache, eng.v_cache  # same stale-epoch discipline
         tables = self._tables() if self.kv_pool is not None else None
         constrain = None
@@ -4136,7 +4293,8 @@ class BatchEngine:
             _CONSTRAIN_DISPATCHES.inc()
         with trace.span("batch.super_step_issue",
                         {"k": k, "rows": len(rows),
-                         "chained": chain is not None, "window": window}):
+                         "chained": chain is not None, "window": window,
+                         **work}):
             # the scan's host inputs, uploaded HERE so that the copies are
             # counted, in the dtypes device_loop's run() states (run() keeps
             # its own jnp.asarray for callers with host values, a no-op on
@@ -4174,9 +4332,11 @@ class BatchEngine:
                 cst = None
         _PIPELINE_DEPTH.set(2 if chain is not None else 1)
         _start_host_copy(toks, rng_out)  # delivery's np.asarray picks them up
-        return _InflightStep(rows, k, starts, budget, temps, toks, tok, pos,
-                             rng_out, t_issue, chain is not None, window,
-                             cstate=cst, moe=moe[0] if moe else None)
+        fl = _InflightStep(rows, k, starts, budget, temps, toks, tok, pos,
+                           rng_out, t_issue, chain is not None, window,
+                           cstate=cst, moe=moe[0] if moe else None)
+        fl.snaps = snaps
+        return fl
 
     # hot-path
     def _deliver_super_step(self, fl: _InflightStep) -> dict[int, str]:
@@ -4400,6 +4560,7 @@ class BatchEngine:
                 # without these rows drafting
                 for slot, _req in fl.rows:
                     self.adaptive.tick(slot.index)
+        self._settle_snapshots(fl, True)
         return status
 
     def _chain_divergence(self, nxt: _InflightStep,
@@ -4422,10 +4583,20 @@ class BatchEngine:
         writes), context-end parks were flagged via clamp_pos at issue, and
         the next dispatch re-uploads tokens/positions/RNG from host state —
         which delivery kept bit-exact (the xorshift* stream never advances
-        for discarded tokens)."""
+        for discarded tokens). What is NOT free is put back here: a
+        state-space model's running matrices, and the snapshot entries the
+        flushed scan was given."""
         _PIPELINE_FLUSHES.labels(reason=reason).inc()
         _ROLLBACK_TOKENS.inc(sum(fl.budget))
         self._count_inflight(fl, [])  # ran on the device for nothing
+        self._settle_snapshots(fl, False)
+        vc = self._eng.v_cache
+        if fl.kind == "scan" and isinstance(vc, StateCache) and (
+                vc.h is not None):
+            # a running matrix sums every earlier position and cannot be
+            # written over: the survivors go back to H as the flushed scan
+            # FOUND it, their accepted frontier (the scan kept it: `held`)
+            self._eng.v_cache = vc._replace(h=vc.held, held=vc.h)
         for slot, req in fl.rows:
             flight.event(req.rid, "pipeline_flush", reason=reason,
                          tokens=fl.budget[slot.index])

@@ -344,10 +344,20 @@ def make_batched_decode_loop(spec: ModelSpec, mesh, params, n_steps: int, *,
     def loop(p, rope_cos, rope_sin, tokens, kc, vc, start_pos, rng_hi, rng_lo,
              temperature, topp, budget, tables, cstate, cmask, cdelta):
         rope = RopeTables(rope_cos, rope_sin, rope_type)
+        # a state-space model's running matrices as this scan found them:
+        # what a flush of it puts back (models/forward.py StateCache.held).
+        # They ride out beside the carry, not in it
+        found = getattr(vc, "h", None)
+        if found is not None:
+            vc = vc._replace(held=None)
 
         def step(carry, i):
             tok, pos, sh, sl, cst, kc, vc, moe = carry
             live = i < budget  # (B,)
+            if found is not None:
+                # a parked row's matrices stay bit for bit: the step is told
+                vc = vc._replace(ctl=vc.ctl.at[0, :, 0].set(
+                    live.astype(jnp.int32)))
             # parked rows write scratch at their current position (clamped to
             # stay in-cache); reads mask slots >= start_pos so it is invisible,
             # and the row's next real decode overwrites it
@@ -381,6 +391,8 @@ def make_batched_decode_loop(spec: ModelSpec, mesh, params, n_steps: int, *,
             step, (tokens, start_pos, rng_hi, rng_lo, cstate, kc, vc,
                    jnp.zeros((N_MOE_STATS,), jnp.int32)),
             jnp.arange(n_steps, dtype=jnp.int32))
+        if found is not None:
+            vc = vc._replace(held=found)
         return (toks, tok, pos, sh, sl, cst, kc, vc) + (
             (moe,) if moe_stats else ())
 

@@ -199,8 +199,8 @@ class Engine:
             if (tp or 1) > 1 or dp > 1:
                 raise ValueError(
                     f"tp={tp}, dp={dp}: a model with state layers (a gated "
-                    "short convolution) runs whole on one chip; its state is "
-                    "not sharded")
+                    "short convolution, a state-space mixer) runs whole on "
+                    "one chip; its state is not sharded")
             tp = 1
         if self.paged and tp is None:
             tp = 1  # paged mode is single-chip; don't let the mesh grab every device
@@ -441,6 +441,11 @@ class Engine:
         if self.spec.mixed and 0 < pos < self.pos:
             from ..models.forward import STATE_RING
 
+            if self.spec.ssm:
+                raise ValueError(
+                    f"seek({pos}) from {self.pos}: a state-space layer's "
+                    "running matrix sums every earlier position and this "
+                    "cache keeps no snapshot; rewind to 0 and prefill")
             if self.pos - pos > STATE_RING - self.spec.state_rows - 1:
                 raise ValueError(
                     f"seek({pos}) from {self.pos}: a state layer's running "

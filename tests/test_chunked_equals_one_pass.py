@@ -162,6 +162,64 @@ def test_prefill_then_decode_equals_one_pass_with_state_layers(kind):
             atol=1e-6)
 
 
+@pytest.mark.parametrize("kind", KINDS)
+def test_prefill_then_decode_equals_one_pass_with_state_space_layers(kind):
+    """Layers whose mixer is a state-space recurrence (granite-4.0-h-small's
+    Mamba-2 layers): a prefill in chunks of 64, 8 and 1 and a decode step
+    against ONE pass over the 74 tokens. Each chunk continues the slot's
+    running matrices from where the one before left them and reads the
+    convolution's three earlier u rows from the slot's ring; the logits, the
+    matrices and the ring agree, and so do the multipliers and the stated
+    attention scale, which both runs go through."""
+    from distributed_llama_tpu.models.forward import (STATE_RING, StateCache,
+                                                      init_state)
+    from distributed_llama_tpu.models.spec import LayerKind
+
+    spec = _spec(ArchType.MIXTRAL, n_layers=4, seq_len=128, head_dim=16,
+                 n_experts=4, n_active_experts=2, hidden_dim=32,
+                 shared_hidden_dim=64, embedding_multiplier=12.0,
+                 residual_multiplier=0.22, logits_scaling=16.0,
+                 attn_multiplier=0.5, state_snapshots=3,
+                 kinds=(LayerKind("mamba", 4, conv_kernel=4, ssm_heads=4,
+                                  ssm_head_dim=32, ssm_state=16),
+                        LayerKind("attention", 4, rope_type=RopeType.NONE)),
+                 layer_kinds=(0, 0, 1, 0))
+    params = init_random_params(spec, FloatType.F32, seed=17)
+    rope = RopeTables.create(spec)
+    row = np.random.default_rng(2).integers(3, 128, 74).tolist()
+
+    def caches():
+        c = Cache(spec, kind)
+        if kind == "pool":
+            shape = (1,) + c.k.shape[1:]  # the one attention layer owns rows
+            c.k, v = jnp.zeros(shape), jnp.zeros(shape)
+            c.v = StateCache(v, *init_state(spec, 1, shape[1], jnp.float32))
+            assert c.v.snap_h.shape[0] == 4 and c.v.ctl.shape == (2, 1, 1)
+        assert isinstance(c.v, StateCache) and c.k.shape[0] == 1
+        assert c.v.h.shape == (1, 3, 4, 32, 16)
+        return c
+
+    def fwd(*a, **kw):
+        return forward(params, spec, rope, *a, **kw)
+
+    one, chunked = caches(), caches()
+    want = one.step(fwd, [row], 0)
+    got, at = [], 0
+    for n in (64, 8, 1, 1):
+        got.append(chunked.step(fwd, [row[at:at + n]], at))
+        at += n
+    np.testing.assert_allclose(np.concatenate(got, axis=1), want, atol=2e-6,
+                               rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(chunked.v.h), np.asarray(one.v.h),
+                               atol=1e-5)
+    assert np.abs(np.asarray(one.v.h)).max() > 0
+    live = [p % STATE_RING for p in range(74 - STATE_RING, 74)]
+    np.testing.assert_allclose(np.asarray(chunked.v.ring)[0, live, :3],
+                               np.asarray(one.v.ring)[0, live, :3], atol=1e-6)
+    np.testing.assert_allclose(np.asarray(chunked.k), np.asarray(one.k),
+                               atol=1e-6)
+
+
 def test_fused_decode_kernel_equals_one_pass():
     """The one-row decode glue: use_pallas + T = 1 + a scalar position routes
     through the fused decode-attention kernel (interpret off-TPU). Pins the

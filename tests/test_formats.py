@@ -88,6 +88,49 @@ def test_mfile_roundtrip_of_a_model_with_state_layers(tmp_path, ftype):
             np.testing.assert_allclose(a, b, atol=1e-6, err_msg=name)
 
 
+@pytest.mark.parametrize("ftype", [FloatType.F32, FloatType.Q40])
+def test_mfile_roundtrip_of_a_state_space_model(tmp_path, ftype):
+    """The header keys and tensors granite-4.0-h-small brought: a kind's
+    state-space fields (heads, head size, state size, groups), a kind
+    without a rotation, the three multipliers, the stated attention scale
+    (in units of 1e-9: 1/128 is no whole number of 1e-6), the shared expert
+    at its own width and the snapshot pool's entries."""
+    from distributed_llama_tpu.models.spec import LayerKind, RopeType
+
+    spec = tiny_spec(ArchType.MIXTRAL, n_layers=4, head_dim=16,
+                     shared_hidden_dim=96, embedding_multiplier=12.0,
+                     residual_multiplier=0.22, logits_scaling=16.0,
+                     attn_multiplier=1 / 128, state_snapshots=6,
+                     kinds=(LayerKind("mamba", 4, conv_kernel=4, ssm_heads=4,
+                                      ssm_head_dim=32, ssm_state=16),
+                            LayerKind("attention", 4,
+                                      rope_type=RopeType.NONE)),
+                     layer_kinds=(0, 0, 1, 0))
+    params = init_random_params(spec, ftype, seed=5)
+    assert params["blocks"]["ssm_in"].shape == (3, 128 + 160 + 4, 64)
+    assert params["blocks"]["wq"].shape[0] == 1
+    path = str(tmp_path / "ssm.m")
+    write_model(path, spec, params_file_order(spec, params), ftype)
+    spec2, params2 = load_model(path)
+    assert (spec2.embedding_multiplier, spec2.residual_multiplier,
+            spec2.logits_scaling, spec2.attn_multiplier,
+            spec2.state_snapshots, spec2.shared_hidden_dim) == (
+        12.0, 0.22, 16.0, 1 / 128, 6, 96)
+    mamba, attn = spec2.kinds
+    assert (mamba.conv_kernel, mamba.ssm_heads, mamba.ssm_head_dim,
+            mamba.ssm_state, mamba.ssm_groups) == (4, 4, 32, 16, 1)
+    assert attn.rope_type == RopeType.NONE and attn.ssm_groups == 1
+    assert spec2.ssm and spec2.state_matrix == (4, 32, 16)
+    assert spec2.state_width == 160 and spec2.layer_kinds == (0, 0, 1, 0)
+    assert set(params2["blocks"]) == set(params["blocks"])
+    for name in params["blocks"]:
+        a, b = params["blocks"][name], params2["blocks"][name]
+        a = a.to_numpy() if hasattr(a, "to_numpy") else np.asarray(a)
+        b = b.to_numpy() if hasattr(b, "to_numpy") else np.asarray(b)
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(a, b, atol=1e-6, err_msg=name)
+
+
 def test_mfile_seq_len_clamp(tmp_path):
     spec = tiny_spec()
     params = init_random_params(spec, FloatType.F32, seed=2)

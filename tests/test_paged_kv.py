@@ -938,3 +938,46 @@ def test_demotion_with_the_pool_sharded_over_kv_heads():
         pc.release(lease)
     finally:
         be.close()
+
+
+def test_a_demoted_block_of_a_state_space_model_gives_its_snapshot_up():
+    """granite-4.0-h-small's toy: a snapshot lies in a pool of its own, by
+    stride, and is FREED with its block (38.7 MB a snapshot at the published
+    widths would be most of a demotion's bytes): two sides travel to the
+    host tier, the demoted blocks' entries are gone, and the same prompt
+    again finds its keys and values cold but no snapshot under them,
+    prefills from 0 and gives the same tokens."""
+    import jax.numpy as jnp
+
+    from benchmark import cells
+    from benchmark import weights as W
+    from distributed_llama_tpu.runtime.batch_engine import _pool_sides
+
+    cfg = {**cells.load_config("tiny-granite-hybrid"), "context": 512}
+    weights = W.make_weights(cfg, 2**31 + 39)
+    spec = cells.load_family(cfg["family"]).model_spec(cfg)
+    be = BatchEngine(spec, W.to_program_params(weights, cfg), None, slots=2,
+                     superstep=4, kv_block_tokens=16, kv_pool_blocks=80,
+                     tp=1, dtype=jnp.float32)
+    try:
+        assert len(_pool_sides(be._eng)) == 2  # keys and values alone
+        prompt = np.random.default_rng(9).integers(3, 512, 300).tolist()
+        want = _run(be, prompt, 6, vocab=512)
+        _settle(lambda: be.prefix_cache.total_refs() == 0)
+        snaps = be.kv_pool.snapshots
+        assert snaps.held() == 1  # the block that ends at position 255
+        again = be.submit(list(prompt), 6, Sampler(512, temperature=0.0))
+        assert again.wait(timeout=180) == want
+        assert again.stats.reused_tokens == 256
+        _settle(lambda: be.prefix_cache.total_refs() == 0)
+        for sl in be._slots:
+            be._paged_release_slot(sl)
+        be._demote(be.prefix_cache.stats()["dev_blocks"])
+        be._settle_demotions(force=True)
+        assert be.prefix_cache.stats()["cold_blocks"] >= 16
+        assert snaps.held() == 0
+        cold = be.submit(list(prompt), 6, Sampler(512, temperature=0.0))
+        assert cold.wait(timeout=180) == want
+        assert cold.stats.reused_tokens == 0
+    finally:
+        be.close()

@@ -301,6 +301,56 @@ def test_a_prefix_hit_on_a_model_with_state_layers_equals_cold_prefill():
         be_off.close()
 
 
+def test_a_prefix_hit_on_a_state_space_model_lands_on_a_stride_snapshot():
+    """Layers whose state is a matrix a head (granite-4.0-h-small's Mamba-2
+    layers): which blocks carry a snapshot is the cache manager's decision,
+    one every 256 positions. A hit on 300 shared tokens continues from the
+    block that ends at 255 (256 tokens, not the 296 of whole blocks) and
+    gives the tokens a cold prefill gives, greedy and seeded-stochastic; a
+    hit on 200 shared tokens has no snapshot under it and prefills from 0."""
+    import jax.numpy as jnp
+
+    from distributed_llama_tpu.models.spec import LayerKind
+    from distributed_llama_tpu.runtime.batch_engine import BatchEngine
+
+    spec = ModelSpec(
+        arch_type=ArchType.MIXTRAL, dim=64, hidden_dim=32, n_layers=4,
+        n_heads=4, n_kv_heads=2, vocab_size=256, seq_len=512, n_experts=4,
+        n_active_experts=2, head_dim=16, rope_type=RopeType.FALCON,
+        shared_hidden_dim=64, embedding_multiplier=12.0,
+        residual_multiplier=0.22, logits_scaling=16.0, attn_multiplier=0.5,
+        state_snapshots=4,
+        kinds=(LayerKind("mamba", 4, conv_kernel=4, ssm_heads=4,
+                         ssm_head_dim=32, ssm_state=16),
+               LayerKind("attention", 4, rope_type=RopeType.NONE)),
+        layer_kinds=(0, 1, 0, 0)).resolved()
+    params = init_random_params(spec, FloatType.Q40, seed=23)
+    kw = dict(slots=2, tp=1, kv_block_tokens=8, dtype=jnp.float32)
+    shared = np.random.default_rng(6).integers(3, 256, 300).tolist()
+    be_off = BatchEngine(spec, params, prefix_cache=False, **kw)
+    be_on = BatchEngine(spec, params, prefix_cache=True, **kw)
+    try:
+        prompts = [shared + [200 + i] for i in range(3)] + [
+            shared[:200] + [7, 7]]
+        plans = [(0.0, 0), (0.8, 7), (0.8, 7), (0.0, 0)]
+        wants = [_run(be_off, p, 8, t, s) for p, (t, s) in zip(prompts, plans)]
+        got = [_run(be_on, prompts[0], 8, *plans[0])]
+        _run(be_on, [7, 8, 9, 10], 4)  # dirties the other slot's history too
+        mid = be_on.prefilled_tokens
+        got.append(_run(be_on, prompts[1], 8, *plans[1]))
+        assert be_on.prefilled_tokens - mid == len(prompts[1]) - 256
+        got.append(_run(be_on, prompts[2], 8, *plans[2]))
+        mid = be_on.prefilled_tokens
+        got.append(_run(be_on, prompts[3], 8, *plans[3]))
+        assert be_on.prefilled_tokens - mid == len(prompts[3])
+        assert got == wants
+        assert be_on.prefix_cache.stats()["hit_tokens"] >= 256
+        assert be_on.kv_pool.snapshots.held() >= 1
+    finally:
+        be_on.close()
+        be_off.close()
+
+
 def test_concurrent_shared_prefix_requests_identical(engines):
     spec, be_off, be_on = engines
     prompts = [SHARED + [150 + i] for i in range(4)]

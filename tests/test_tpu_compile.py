@@ -369,3 +369,34 @@ def test_step_program_updates_the_pool_in_place(chip, step_model, pool,
     chunk = STEP_PROGRAMS[program].get("chunk", 1)
     if chunk > 1:  # the head ran at the one sampled position a row
         assert aot_step.logits_blocks(text, 8, chunk, cfg["vocab_size"]) == []
+
+
+def test_step_program_carries_the_running_matrices_in_place(chip, monkeypatch):
+    """granite-4.0-h-small's T = 1 step for a described v5e: the SSD kernels
+    are in it under their names, the running matrices (8 slots x 9 layers x
+    128 heads x 64 x 128 float32, 302 MB) ride the layer scan and its
+    `lax.cond` without a copy, the snapshot pool is written in place, and
+    the slots' rings of u rows (8448 wide) are neither copied nor re-laid: a
+    commit that read the ring as the dispatch found it while another loop
+    wrote it made XLA copy 138 MB in and out (PERF.md section 6, PR 44)."""
+    import re
+
+    spec, shapes, cfg = aot_step.model_shapes(
+        "granite-4.0-h-small-l10", chip, num_local_experts=16)
+    monkeypatch.delenv("DLT_PALLAS_INTERPRET")
+    text = aot_step.compile_step(spec, shapes, cfg, chip, chunk=1).as_text()
+    assert "ssd_step" in text and text.count("tpu_custom_call") <= 16
+    for side in aot_step.held_pools(spec, cfg):
+        assert aot_step.pool_relayouts(text, side) == []
+    from distributed_llama_tpu.models.forward import init_state
+
+    ring, snaps, h, _, snap_h, _ = jax.eval_shape(lambda: init_state(
+        spec, 8, cfg["engine"]["kv_pool_blocks"], jnp.bfloat16))
+    assert h.shape == (8, 9, 128, 64, 128) and snap_h.shape[0] == 49
+    assert aot_step.pool_relayouts(text, ring.shape) == []
+    assert aot_step.pool_relayouts(text, snaps.shape) == []
+    for a in (h, snap_h):
+        shape = "f32[" + ",".join(str(d) for d in a.shape) + "]"
+        copies = [ln for ln in text.splitlines()
+                  if re.search(r"= " + re.escape(shape) + r"\S* copy\(", ln)]
+        assert copies == [], copies[:2]
