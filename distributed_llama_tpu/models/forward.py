@@ -159,9 +159,12 @@ class RowMap(NamedTuple):
     residual stream is then COMPACT, (1, R, dim) with R = compact_rows(t,
     slots): rows [0, t) the lead slot's chunk, row t + b slot b's index 0
     (the lead's own is its first token once more), zero rows up to R. Every
-    weight runs over R rows; attention and the commit alone see the
-    (slots, t) rectangle, laid out with a zero row wherever a position holds
-    nothing. forward() without a map is the rectangle throughout."""
+    weight runs over R rows, and a block pool is READ over them too, in two
+    calls of one reader (`attend`): the lead's t queries against the lead's
+    table, then one query a slot against every slot's. The commit alone
+    (and the read of a cache that is no block pool) sees the (slots, t)
+    rectangle, laid out with a zero row wherever a position holds nothing.
+    forward() without a map is the rectangle throughout."""
     slots: int
     t: int
     lead: jax.Array  # int32 scalar: the prefilling slot's index
@@ -197,6 +200,29 @@ class RowMap(NamedTuple):
             is_lead.reshape(-1, *[1] * a.ndim), chunk[None],
             jnp.where(at_0.reshape(1, -1, *[1] * (a.ndim - 1)),
                       first[:, None], jnp.zeros((), a.dtype)))
+
+    def attend(self, read, new, block_tables, start_pos):
+        """The block pool read over the compact rows and never over the
+        rectangle: `read(new, block_tables, start_pos, positions)` is the
+        branch's own reader of a (B, T) dispatch, `new` the stream's
+        (1, R, ...) arrays (queries, and the rows not committed yet).
+        Called twice: B = 1, T = t, rows [0, t) against the lead slot's
+        table and committed length; B = slots, T = 1, rows [t, t + slots)
+        against every slot's (a parked row's is scratch, the lead's own its
+        first token once more). Returns (1, R, ...), zero rows behind."""
+        t, n = self.t, self.t + self.slots
+
+        def of_lead(a):
+            return jax.lax.dynamic_index_in_dim(a, self.lead, 0)
+
+        chunk = read([a[:, :t] for a in new], of_lead(block_tables),
+                     of_lead(start_pos), self.positions[:, :t])
+        first = read([a[0, t:n, None] for a in new], block_tables,
+                     start_pos, self.positions[0, t:n, None])
+        pad = self.positions.shape[1] - n
+        return jnp.concatenate(
+            [chunk[0], first[:, 0],
+             jnp.zeros((pad, *chunk.shape[2:]), chunk.dtype)])[None]
 
     def sampled(self, x):
         """(1, R, dim) -> (slots, 1, dim): the one position a slot the host
@@ -689,10 +715,13 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
     TP merge): callers must not re-add.
 
     rows: the `RowMap` of a compact stream. x, the projections, the rotation,
-    the gate and wo are then on its (1, R) rows; q, k and v are laid out to
-    the (slots, t) rectangle for the cache read below and for the commit,
-    which are what they are without a map, and the attention output is taken
-    back to R rows. `positions` and `start_pos` are the rectangle's always.
+    the gate and wo are then on its (1, R) rows. A block pool is read over
+    those rows too, by the branch's own reader called twice
+    (`RowMap.attend`: the lead slot's t queries, then one query a slot), and
+    its output is the compact stream's; k and v alone are laid out to the
+    (slots, t) rectangle, for the commit. Any other cache is read as without
+    a map: q laid out too, the output taken back to R rows. `positions` and
+    `start_pos` are the rectangle's always.
 
     Head counts in bp may be TP-local slices. The READ covers only the first
     `window` positions (a static bucket >= pos+T chosen by the caller), so
@@ -744,18 +773,32 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
         q = jnp.where(rope_on > 0, apply_rope(q, rope, at), q)
         k = jnp.where(rope_on > 0, apply_rope(k, rope, at), k)
     v = v.reshape(cb, ct, hk_local, hs)
+    # a block pool is read over the compact rows (`RowMap.attend`); the
+    # commit, and the read of any other cache, take the rectangle
+    split = rows is not None and block_tables is not None
+    stream = (q, k, v)
     if rows is not None:
-        q, k, v = (rows.lay_out(a) for a in (q, k, v))
-    b, t = q.shape[:2]
-    # lowest key position each query reads: 0 on a layer without a window
-    key_lo = None if swa is None else jnp.where(
-        swa > 0, jnp.maximum(positions - swa + 1, 0), 0)
-    assert key_lo is None or (paged_cold is None and not (
+        k, v = rows.lay_out(k), rows.lay_out(v)
+        q = q if split else rows.lay_out(q)
+    b, t = k.shape[:2]
+
+    def lo_of(positions):
+        """Lowest key position each query reads: 0 on a layer without a
+        window."""
+        return None if swa is None else jnp.where(
+            swa > 0, jnp.maximum(positions - swa + 1, 0), 0)
+
+    key_lo = None if split else lo_of(positions)
+    assert swa is None or (paged_cold is None and not (
         sp_axis_name is not None and sp_size > 1)), (
         "a sliding window is not supported with sp (ring) sharding or "
         "host/disc KV paging")
-    k_t = jnp.swapaxes(k, 1, 2).astype(kc.dtype)  # (B, hk, T, hs)
-    v_t = jnp.swapaxes(v, 1, 2).astype(vc.dtype)
+
+    def cache_rows(k, v):  # (B, T, hk, hs) each -> (B, hk, T, hs)
+        return (jnp.swapaxes(k, 1, 2).astype(kc.dtype),
+                jnp.swapaxes(v, 1, 2).astype(vc.dtype))
+
+    k_t, v_t = cache_rows(k, v)
     # a pool whose rows are wider than a head (heads of 64 in whole lanes of
     # 128, runtime/engine.py): the rows are committed with zeros behind them
     lanes = kc.shape[-1] if block_tables is not None else hs
@@ -825,21 +868,23 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
             w_total = block_tables.shape[1]
             win = window or (w_total * block_tokens)
             nb = min(-(-win // block_tokens), w_total)
-            if paged_kernel:
-                from ..ops.pallas_paged_attention import paged_attention
 
-                out = paged_attention(
-                    widen(q), kc, vc, widen(k_t), widen(v_t), block_tables,
-                    start_pos, layer_idx, n_read=nb, window=swa,
-                    name=kernel_name,
-                    **({"head_size": hs} if lanes != hs else {}))
-                if lanes != hs:
-                    out = out[..., :hs]
-                att = out.reshape(b, t, hq_local * hs)
-            else:
+            def read(q, k_t, v_t, tables, start_pos, positions, key_lo):
+                b, t = q.shape[:2]
+                if paged_kernel:
+                    from ..ops.pallas_paged_attention import paged_attention
+
+                    out = paged_attention(
+                        widen(q), kc, vc, widen(k_t), widen(v_t), tables,
+                        start_pos, layer_idx, n_read=nb, window=swa,
+                        name=kernel_name,
+                        **({"head_size": hs} if lanes != hs else {}))
+                    if lanes != hs:
+                        out = out[..., :hs]
+                    return out.reshape(b, t, hq_local * hs)
                 from ..ops.pallas_paged_attention import paged_gather_kv
 
-                kw, vw = paged_gather_kv(kc, vc, layer_idx, block_tables, nb)
+                kw, vw = paged_gather_kv(kc, vc, layer_idx, tables, nb)
                 if lanes != hs:
                     kw, vw = kw[..., :hs], vw[..., :hs]
                 vwin = nb * block_tokens
@@ -852,10 +897,21 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
                 key_pos = jnp.concatenate(
                     [slot_pos, start_pos[:, None] + jnp.arange(t)[None, :]],
                     axis=1)
-                att = gqa_attention(q, jnp.concatenate([kw, k_t], axis=2),
-                                    jnp.concatenate([vw, v_t], axis=2),
-                                    positions, key_positions=key_pos,
-                                    key_lo=key_lo)
+                return gqa_attention(q, jnp.concatenate([kw, k_t], axis=2),
+                                     jnp.concatenate([vw, v_t], axis=2),
+                                     positions, key_positions=key_pos,
+                                     key_lo=key_lo)
+
+            if split:
+                # the lead slot's chunk, then one query a slot
+                att = rows.attend(
+                    lambda new, tables, start_pos, at: read(
+                        new[0], *cache_rows(*new[1:]), tables, start_pos,
+                        at, lo_of(at)),
+                    stream, block_tables, start_pos)
+            else:
+                att = read(q, k_t, v_t, block_tables, start_pos, positions,
+                           key_lo)
         elif (use_pallas and t == 1 and b == 1 and start_pos.ndim == 0
                 and key_lo is None):
             # fused decode kernel: the cache window is DMA'd straight out of the
@@ -880,7 +936,7 @@ def _attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc, vc, star
             att = gqa_attention(q, kfull, vfull, positions, key_positions=key_pos,
                                 key_lo=key_lo)
     att = att.astype(x.dtype)
-    if rows is not None:
+    if rows is not None and not split:
         att = rows.compact(att)
     if gate is not None:
         att = (att.reshape(cb, ct, hq_local, hs).astype(jnp.float32)
@@ -917,9 +973,10 @@ def _latent_attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc,
     contiguous cache; the engine refuses the others for a latent spec.
     Returns as _attention: (residual-joined output, the chunk's rows for
     forward() to commit, the second side empty). `rows`, as there: the
-    projections, w_uk, w_uv and wo on the compact rows, q' and the cache row
-    laid out to the rectangle for the read and the commit, the context taken
-    back."""
+    projections, w_uk, w_uv and wo on the compact rows, the block pool read
+    over them in two calls (`RowMap.attend`), the cache row alone laid out to
+    the rectangle for the commit (the contiguous cache: q' too, the context
+    taken back)."""
     from ..ops.attention import latent_attention
 
     cb, ct, _ = x.shape  # the stream's rows: (1, R) under a row map
@@ -945,8 +1002,12 @@ def _latent_attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc,
 
     qf = to_row_width([q_lat, q_pe])  # (B, T, H, width)
     row = to_row_width([c, k_pe]).astype(kc.dtype)  # (B, T, width)
+    # as in _attention: a block pool is read over the compact rows
+    split = rows is not None and block_tables is not None
+    stream = (qf, row)
     if rows is not None:
-        qf, row = rows.lay_out(qf), rows.lay_out(row)
+        row = rows.lay_out(row)
+        qf = qf if split else rows.lay_out(qf)
     b, t = row.shape[:2]
     scale = spec.attn_scale
     with jax.named_scope("latent_attn"):
@@ -960,8 +1021,16 @@ def _latent_attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc,
             else:
                 from ..ops.pallas_paged_attention import \
                     latent_paged_attention_xla as attend
-            ctx = attend(qf, kc, row, block_tables, start_pos, layer_idx,
-                         n_read=nb, n_values=r, scale=scale)
+
+            def read(new, tables, start_pos, _positions=None):
+                qf, row = new
+                return attend(qf, kc, row, tables, start_pos, layer_idx,
+                              n_read=nb, n_values=r, scale=scale)
+
+            if split:
+                ctx = rows.attend(read, stream, block_tables, start_pos)
+            else:
+                ctx = read((qf, row), block_tables, start_pos)
         else:
             win = window or s
             kw = jax.lax.dynamic_slice(
@@ -969,7 +1038,7 @@ def _latent_attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc,
             ctx = latent_attention(
                 qf, jnp.concatenate([kw, row], axis=1), positions,
                 _window_key_positions(start_pos, win, t, s + 1), r, scale)
-    if rows is not None:
+    if rows is not None and not split:
         ctx = rows.compact(ctx)
     att = jnp.einsum("bthc,hdc->bthd", ctx.astype(x.dtype),
                      bp["w_uv"].astype(x.dtype),
@@ -1441,8 +1510,10 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
     its index 0 says so with one entry more: start_pos (B + 1,), the last the
     prefilling row's index (T > 1). The residual stream is then compact
     (`RowMap`): the embedding, the norms, every weight, the router and the
-    expert layer run over compact_rows(T, B) rows, 72 for (8, 64), and only
-    the attention call and the commit see the (B, T) rectangle, a zero row
+    expert layer run over compact_rows(T, B) rows, 72 for (8, 64); a block
+    pool is read over them as well (the prefilling row's T queries, then
+    every row's index 0: `RowMap.attend`), and only the commit (and the
+    read of a contiguous cache) sees the (B, T) rectangle, a zero row
     wherever a position holds nothing, so a real position's K/V and output
     are what the rectangle computes. The head runs on ONE position a row,
     the prefilling row's last and every other row's index 0: logits

@@ -221,8 +221,9 @@ _POSITIONS_REAL = metrics.counter(
 _ATTN_PAIRS_DISPATCHED = metrics.counter(
     "batch_attn_pairs_dispatched_total",
     "Query-key pairs the attention kernel was asked for: slots x T "
-    "positions, a prefill chunk's too, x the window bucket (the context "
-    "length where unbucketed)")
+    "positions (a prefill chunk that names its prefilling row: T + slots, "
+    "that row's chunk and one query a slot) x the window bucket (the "
+    "context length where unbucketed)")
 _MOE_COUNTERS = tuple(metrics.counter(name, doc) for name, doc in (
     ("batch_moe_assignments_total",
      "Expert assignments the routed layers were given: dispatched rows x "
@@ -257,11 +258,13 @@ _LATENT_ROWS_READ = metrics.counter(
     "Latent cache rows attention was asked to read: every dispatched row's "
     "committed length, once a layer, all heads reading each row once (the "
     "kernel's steps of 128 keys and its query blocks re-read some of them: "
-    "not counted)")
+    "not counted; a chunk's prefilling row twice, once in each of the two "
+    "reads a chunk makes)")
 _LATENT_DISPATCH_ROWS = metrics.counter(
     "batch_latent_dispatch_rows_total",
     "Query positions latent attention was run for: dispatched positions x "
-    "layers, parked rows and padding included")
+    "layers, parked rows and padding included (a chunk that names its "
+    "prefilling row: the chunk's positions and one a slot)")
 _KV_ROW_BYTES = metrics.gauge(
     "kv_pool_row_bytes",
     "Bytes the cache really holds a token a layer (all kv heads, both sides, "
@@ -272,7 +275,9 @@ _ATTN_PAIRS_VISITED = metrics.counter(
     "row of the dispatch, parked ones too, T x the keys of the steps that "
     "hold its committed length (ops/pallas_paged_attention.visited_keys; "
     "the chunk's own T x T fold is in neither this nor the dispatched "
-    "count). Equal to the dispatched count where the kernel does not run")
+    "count; a chunk that names its prefilling row: T x that row's keys and "
+    "every row's once). Equal to the dispatched count where the kernel does "
+    "not run")
 _ATTN_WINDOW_PAIRS_VISITED = metrics.counter(
     "batch_attn_window_pairs_visited_total",
     "batch_attn_pairs_visited_total's count for the layers with a sliding "
@@ -869,12 +874,12 @@ class _InflightStep:
     __slots__ = ("rows", "k", "starts", "budget", "temps", "toks", "tok",
                  "pos", "rng", "t_issue", "chained", "window", "kind",
                  "ndraft", "acc", "cstate", "moe", "lead", "piece", "span",
-                 "computed", "snaps")
+                 "mapped", "snaps")
 
     def __init__(self, rows, k, starts, budget, temps, toks, tok, pos, rng,
                  t_issue, chained, window, kind="scan", ndraft=None,
                  acc=None, cstate=None, moe=None, lead=None, piece=(),
-                 span=None, computed=None):
+                 span=None, mapped=False):
         self.rows = rows  # list[(slot, request)] for budget > 0 rows
         self.k = k
         self.starts = starts  # expected per-row device start positions
@@ -897,19 +902,19 @@ class _InflightStep:
         # layers did over the K steps (_count_moe reads it at delivery)
         self.moe = moe
         # a step only: the slot that prefills (None: a single step), its
-        # chunk's tokens, the dispatch span's (name, args), and the rows its
-        # weights ran over where the stream was compact
+        # chunk's tokens, the dispatch span's (name, args), and whether the
+        # program was told of it (a compact stream: `forward.RowMap`)
         self.lead = lead
         self.piece = piece
         self.span = span
-        self.computed = computed
+        self.mapped = mapped
         # a state-space model only: the snapshot entries this dispatch was
         # given, (slot, request, block, allotment, stride's last position)
         self.snaps: list = []
 
     @classmethod
     def step(cls, rows, t, starts, t0, chained, window, span, lead=None,
-             piece=(), computed=None):
+             piece=(), mapped=False):
         """A planned `jit_step` dispatch, nothing launched yet: `rows` its
         (slot, request) pairs, the prefilling one first."""
         budget = [0] * len(starts)
@@ -917,7 +922,7 @@ class _InflightStep:
             budget[slot.index] = t if slot is lead else 1
         return cls(rows, t, starts, budget, None, None, None, None, None, t0,
                    chained, window, kind="step", lead=lead, piece=piece,
-                   span=span, computed=computed)
+                   span=span, mapped=mapped)
 
 
 class BatchEngine:
@@ -2480,17 +2485,26 @@ class BatchEngine:
     def _count_work(self, positions: int, window: int,
                     real: list[tuple[int, int]], starts: list[int],
                     budget: list[int] | None = None,
-                    computed: int | None = None) -> None:
+                    lead: int | None = None) -> None:
         """One dispatch's useful-work counters: `positions` per row were
         dispatched against `window`; `real` lists (start, n) for each run of
         n request tokens from position start (causal length start + i + 1).
         `starts` is every row's committed length as the device was given it;
         `budget` (a K-step scan only: `positions` steps of one token) the
         steps each row advances, its length growing by one a step.
-        `computed`: the rows the weight kernels ran over where the program's
-        residual stream was compact; attention runs the rectangle always."""
-        dispatched = self.slots_n * positions
-        computed = dispatched if computed is None else computed
+        `lead`: the prefilling row where the program held a row map
+        (`forward.RowMap`): its weights ran over the compact stream's rows,
+        and a block pool was read for the lead's `positions` queries and for
+        one query a row (the lead's own among them), not for the rectangle
+        (the contiguous per-row cache still is)."""
+        dispatched = self.slots_n * positions  # query rows attention ran
+        computed = dispatched  # rows the weight kernels ran over
+        if lead is not None:
+            computed = compact_rows(positions, self.slots_n)
+            if getattr(self, "_kv_bt", 0):
+                dispatched = positions + self.slots_n
+            else:
+                lead = None  # every read below is the rectangle's
         _POSITIONS_DISPATCHED.inc(computed)
         _ATTN_PAIRS_DISPATCHED.inc(dispatched * window)
         if self._eng.paged_kernel:
@@ -2506,8 +2520,11 @@ class BatchEngine:
                                             ((0, 1.0),)))
 
             # the committed lengths the kernel sees, each `times` over: a
-            # row's own, once a position; in a scan, one a (row, step)
-            if budget is None:
+            # row's own, once a position (under a row map the lead's once a
+            # position and every row's once); in a scan, one a (row, step)
+            if lead is not None:
+                lengths, times = [starts[lead]] * positions + starts, 1
+            elif budget is None:
                 lengths, times = starts, positions
             else:
                 lengths, times = [st + min(i, b)
@@ -2530,7 +2547,9 @@ class BatchEngine:
             _MOE_ROUTED.inc(computed * spec.n_active_experts
                             * spec.block_layers)
         if spec is not None and spec.latent:
-            if budget is None:  # once a dispatched row, whatever its T
+            if lead is not None:  # the lead's rows in both of the two reads
+                read = sum(starts) + starts[lead]
+            elif budget is None:  # once a dispatched row, whatever its T
                 read = sum(starts)
             else:
                 read = sum(st + min(i, b) for st, b in zip(starts, budget)
@@ -3469,8 +3488,7 @@ class BatchEngine:
             ("batch.mixed_step" if riders else "batch.prefill",
              {"chunk": t, "riders": len(riders), "window": window,
               "slots": self.slots_n}),
-            lead=slot, piece=piece,
-            computed=None if lead is None else compact_rows(t, self.slots_n))
+            lead=slot, piece=piece, mapped=lead is not None)
         return fl, staged, chain
 
     def _plan_single(self, active: list[_Slot], t0: float,
@@ -3706,7 +3724,7 @@ class BatchEngine:
         self._count_work(t, fl.window,
                          [(fl.starts[s.index], fl.budget[s.index])
                           for s, _req in fl.rows], fl.starts,
-                         computed=fl.computed)
+                         lead=lead.index if fl.mapped else None)
         for slot, req in fl.rows:
             if slot.req is not req or req.done.is_set():
                 # left its slot while the dispatch ran (reaped, preempted,

@@ -22,7 +22,10 @@ result row to a perf/r*_hw_results.jsonl-style artifact with --json.
 `--cells` times the shapes the benchmark's cells dispatch instead (B 8, hk 8,
 g 4, hs 128, bt 16, bf16; T in {1, 8, 64} against the 512 and 1024 window
 buckets, rows' lengths read from a run of `chat-closed`; and against 2048
-and 4096 keys with illustrative long rows), the kernel beside
+and 4096 keys with illustrative long rows; and, since a prefill dispatch
+reads the pool in two calls (`models/forward.py RowMap.attend`, PR 45), the
+lead's call B 1, T in {8, 64} at the longest of those rows beside the riders'
+call, which is the B 8, T 1 row), the kernel beside
 the XLA gather path (an engine's `paged_kernel=False`), one call a layer of a
 scan inside one jit, with the bytes and FLOP a call needs over the chip's
 peaks. It uses only `paged_attention` and `paged_attention_xla`, so a copy
@@ -171,10 +174,12 @@ CELL_LENGTHS = {512: (0, 0, 101, 145, 186, 221, 275, 332),
 
 
 def bench_cell(t: int, window: int, *, layers=8, reps=4, seed=0,
-               pool_blocks=1280):
+               pool_blocks=1280, lead=False):
     """One (T, window bucket) of the cells' dispatches: ms a call of the
     kernel and of the XLA gather path, against what causal attention over
-    the rows' lengths needs. The pool has the dense cell's 1280 blocks: the
+    the rows' lengths needs. `lead`: the ONE row that prefills alone (B 1,
+    the longest of the bucket's rows), the first of the two calls a chunk
+    makes; the second is the B 8, T 1 dispatch. The pool has the dense cell's 1280 blocks: the
     gather path's time grows with the pool it slices a layer from (0.25 ms
     at T=64 and 1024 keys from 1025 blocks, 0.30 from 1280: PR 27)."""
     import jax
@@ -183,7 +188,10 @@ def bench_cell(t: int, window: int, *, layers=8, reps=4, seed=0,
     from distributed_llama_tpu.ops.pallas_paged_attention import (
         paged_attention, paged_attention_xla)
 
-    B, hk, g, hs, bt = 8, 8, 4, 128, 16
+    lens = np.asarray(CELL_LENGTHS[window], np.int32)
+    if lead:
+        lens = lens.max(keepdims=True)
+    B, hk, g, hs, bt = len(lens), 8, 4, 128, 16
     nb = window // bt
     n = max(B * nb + 1, pool_blocks)  # the dense cell's pool by default
     rng = np.random.default_rng(seed)
@@ -197,7 +205,6 @@ def bench_cell(t: int, window: int, *, layers=8, reps=4, seed=0,
     ids = np.arange(1, n)
     rng.shuffle(ids)
     tables = jnp.asarray(ids[:B * nb].reshape(B, nb).astype(np.int32))
-    lens = np.asarray(CELL_LENGTHS[window], np.int32)
     lengths = jnp.asarray(lens)
 
     def timed(attend):
@@ -227,7 +234,7 @@ def bench_cell(t: int, window: int, *, layers=8, reps=4, seed=0,
     bytes_s, flop_s = need_bytes / V5E_HBM_BPS, need_flop / V5E_FLOPS
     floor_s = max(bytes_s, flop_s)
     return {
-        "T": t, "window": window, "lengths": lens.tolist(),
+        "B": B, "T": t, "window": window, "lengths": lens.tolist(),
         "kernel_ms": round(dt_k * 1e3, 4), "xla_ms": round(dt_x * 1e3, 4),
         "need_bytes": need_bytes, "need_flop": need_flop,
         "floor_ms": round(floor_s * 1e3, 5),
@@ -240,8 +247,9 @@ def bench_cell(t: int, window: int, *, layers=8, reps=4, seed=0,
 
 
 def run_cells(layers=8, reps=4):
-    return [bench_cell(t, w, layers=layers, reps=reps)
-            for t in (1, 8, 64) for w in CELL_LENGTHS]
+    return [bench_cell(t, w, layers=layers, reps=reps, lead=lead)
+            for t, lead in ((1, False), (8, False), (64, False), (8, True),
+                            (64, True)) for w in CELL_LENGTHS]
 
 
 def main(argv=None):

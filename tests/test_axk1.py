@@ -364,11 +364,12 @@ def test_batch_engine_scan_tokens_and_counters(toy, engine):
     assert routed == positions * spec.n_active_experts * spec.block_layers
     assert 0 < held < routed  # 4 of 16 held: about a quarter
     # the weights ran over the compact rows of the prompt's two chunks of 8
-    # (20 tokens: 8 + 8 + 4 x 1), attention over their (slots, 8) rectangles
+    # (20 tokens: 8 + 8 + 4 x 1), attention over each chunk's 8 queries and
+    # one a slot (ISSUE 45: no longer their (slots, 8) rectangles)
     from distributed_llama_tpu.models.forward import compact_rows
 
     slots = len(engine._slots)
-    queries = positions + 2 * (slots * 8 - compact_rows(8, slots))
+    queries = positions + 2 * (8 + slots - compact_rows(8, slots))
     assert moved["batch_latent_dispatch_rows_total"] == queries * spec.n_layers
     assert moved["batch_latent_rows_read_total"] > 0
     assert moved["batch_attn_pairs_real_total"] > 0

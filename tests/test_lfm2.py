@@ -509,7 +509,7 @@ def test_the_state_counters_of_one_dispatch(toy):
         # a chunk of 64 from position 8 (block ends 15, 31, 47, 63) and two
         # riders at 30 and 31 (31 ends a block): 66 real positions, 5 ends
         be._count_work(64, 256, [(8, 64), (30, 1), (31, 1)], [8, 30, 31, 0],
-                       computed=72)
+                       lead=0)
         after = metrics.snapshot()
         d = [after[k] - before.get(k, 0) for k in names]
         assert d[:3] == [3 * 66, 3 * 5, 5]

@@ -371,6 +371,42 @@ def test_step_program_updates_the_pool_in_place(chip, step_model, pool,
         assert aot_step.logits_blocks(text, 8, chunk, cfg["vocab_size"]) == []
 
 
+@pytest.mark.parametrize("pool", ["hk8", "kinds"])
+def test_chunk_program_builds_no_rectangle_of_q(chip, step_model, pool,
+                                                monkeypatch):
+    """A 64-token chunk as the scheduler dispatches it reads the pool over
+    the compact rows, the lead slot's 64 queries in one call of the kernel
+    and one query a slot in a second (`forward.RowMap.attend`; ISSUE 45):
+    no instruction of the compiled program results in an array of the
+    rectangle's q, as the projections leave it (8, 64, heads, 128) or as
+    the kernel takes it (8, kv heads, 64 x group, 128), so none is copied
+    either; the program of a block whose every position is real holds it,
+    and one kernel a run of like layers fewer."""
+    import re
+
+    spec, shapes, cfg = step_model(pool)
+    monkeypatch.delenv("DLT_PALLAS_INTERPRET")
+    text, rect = (aot_step.compile_step(
+        spec, shapes, cfg, chip, chunk=64, rectangle=r).as_text()
+        for r in (False, True))
+    kinds = ([spec.of_kind(i) for i in range(len(spec.kinds))]
+             if spec.kinds else [spec])
+    for kind in kinds:
+        hq, hk, hs = kind.n_heads, kind.n_kv_heads, kind.head_size
+        g = hq // hk
+        q = re.compile(rf"= \(?(?:bf16|f32)\[8,(?:64,{hq}|{hk},{64 * g}|"
+                       rf"64,{hk},{g}),{hs}\]")
+        assert q.search(rect), "the rectangular program's own q"
+        found = [ln.strip()[:120] for ln in text.splitlines() if q.search(ln)]
+        assert found == [], found[:3]
+        # the two calls' q in its place: (1, hk, 64 x g, hs), (8, hk, g, hs)
+        for b, rows in ((1, 64 * g), (8, g)):
+            assert re.search(rf"bf16\[{b},{hk},{rows},{hs}\]", text)
+    runs = len(spec.runs())
+    assert (text.count("tpu_custom_call") - rect.count("tpu_custom_call")
+            == runs)
+
+
 def test_step_program_carries_the_running_matrices_in_place(chip, monkeypatch):
     """granite-4.0-h-small's T = 1 step for a described v5e: the SSD kernels
     are in it under their names, the running matrices (8 slots x 9 layers x

@@ -297,17 +297,18 @@ def test_useful_work_counters_equal_the_hand_computed_values(scenario):
     # riding at 4. Scan of K=4: A from 5, B from 8. No window bucket under
     # 256 positions of context: attention runs against all SEQ keys.
     # The chunk's weights run over its 8 tokens and one row a slot, rounded
-    # to a row tile of 8 (16 rows); its attention over the (slots, 8) rectangle.
+    # to a row tile of 8 (16 rows); its attention over the chunk's 8 queries
+    # and one a slot (ISSUE 45: no longer the (slots, 8) rectangle).
     from distributed_llama_tpu.models.forward import compact_rows
 
     single = (SLOTS * 1, 1, 3 + 1)
-    mixed = (SLOTS * 8, 8 + 1, sum(range(1, 9)) + (4 + 1))
+    mixed = (8 + SLOTS, 8 + 1, sum(range(1, 9)) + (4 + 1))
     scan = (SLOTS * K, 4 + 4,
             sum(5 + i + 1 for i in range(4)) + sum(8 + i + 1 for i in range(4)))
     steps = (single, mixed, scan)
     assert got == {
         "batch_positions_dispatched_total": (
-            single[0] + compact_rows(8, SLOTS) + scan[0]),
+            single[0] + compact_rows(8, SLOTS) + scan[0]),  # not mixed[0]
         "batch_positions_real_total": sum(s[1] for s in steps),
         "batch_attn_pairs_dispatched_total": SEQ * sum(s[0] for s in steps),
         "batch_attn_pairs_real_total": sum(s[2] for s in steps)}
@@ -330,20 +331,24 @@ def test_without_the_kernel_every_dispatched_pair_is_visited(scenario):
             scenario[b], scenario[a], "batch_attn_pairs_dispatched_total") > 0
 
 
-@pytest.mark.parametrize("positions,starts,budget,want", [
+@pytest.mark.parametrize("positions,starts,budget,lead,want", [
     # a 64-token chunk, 8 rows, the 1024 bucket: whole 128-key steps up to
     # each row's committed length, nothing for a row at 0
-    (64, [0, 75, 190, 260, 330, 410, 520, 640], None,
+    (64, [0, 75, 190, 260, 330, 410, 520, 640], None, None,
      64 * (0 + 128 + 256 + 384 + 384 + 512 + 640 + 640)),
+    # the same chunk told which row prefills (ISSUE 45): that row's keys
+    # once a position, then every row's once
+    (64, [0, 75, 190, 260, 330, 410, 520, 640], None, 4,
+     64 * 384 + (0 + 128 + 256 + 384 + 384 + 512 + 640 + 640)),
     # a decode step: every row under one step
-    (1, [5, 100, 128, 0, 0, 0, 0, 0], None, 3 * 128),
+    (1, [5, 100, 128, 0, 0, 0, 0, 0], None, None, 3 * 128),
     # a K=4 scan: row 0 crosses a step boundary after its first token, row
     # 1 stops growing after its budget of 2, the parked rows stay at 0
-    (4, [128, 254, 0, 0, 0, 0, 0, 0], [4, 2, 0, 0, 0, 0, 0, 0],
+    (4, [128, 254, 0, 0, 0, 0, 0, 0], [4, 2, 0, 0, 0, 0, 0, 0], None,
      (128 + 3 * 256) + (256 + 256 + 256 + 256)),
 ])
 def test_visited_pairs_follow_each_rows_length(positions, starts, budget,
-                                               want):
+                                               lead, want):
     import types
 
     be = types.SimpleNamespace(slots_n=len(starts), _kv_bt=16,
@@ -351,11 +356,14 @@ def test_visited_pairs_follow_each_rows_length(positions, starts, budget,
     names = ("batch_attn_pairs_visited_total",
              "batch_attn_pairs_dispatched_total")
     before = metrics.snapshot()
-    BatchEngine._count_work(be, positions, 1024, [], starts, budget)
+    BatchEngine._count_work(be, positions, 1024, [], starts, budget,
+                            lead=lead)
     after = metrics.snapshot()
     visited, dispatched = (_delta(after, before, n) for n in names)
     assert visited == want
-    assert dispatched == len(starts) * positions * 1024
+    rows = (len(starts) * positions if lead is None
+            else positions + len(starts))
+    assert dispatched == rows * 1024
 
 
 def test_dispatch_gap_is_observed_once_per_dispatch_after_the_first(scenario):
