@@ -71,7 +71,8 @@ from distributed_llama_tpu.parallel.tp import (  # noqa: E402
 from distributed_llama_tpu.obs import trace as obs_trace  # noqa: E402
 from distributed_llama_tpu.ops.matmul import kernel_selections  # noqa: E402
 from distributed_llama_tpu.fleet.client import completion_request  # noqa: E402
-from distributed_llama_tpu.quants import QK, FloatType, QTensor  # noqa: E402
+from distributed_llama_tpu.quants import (QK, FloatType, QTensor,  # noqa: E402
+                                          to_scale_plane)
 
 BASELINE_TOK_S = 1000.0 / 101.81  # Llama-2-7B, 1x GCP c3d VM (reference README.md:131)
 
@@ -174,7 +175,7 @@ def synth_q40(key, shape, layout: str):
         scales = jax.lax.bitcast_convert_type(
             (jax.random.uniform(k2, (*lead, out, in_ // QK), jnp.float32) * 0.01
              + 0.001).astype(jnp.float16), jnp.int16)  # i4p carries f16 BIT PATTERNS
-        return QTensor(FloatType.Q40, data, scales, layout="i4p")
+        return QTensor(FloatType.Q40, data, to_scale_plane(scales), layout="i4p")
     if layout == "i8":
         vals = _randint_chunked(k1, (*lead, out, in_), -8, 8, jnp.int8)
         scales = (jax.random.uniform(k2, (*lead, out, in_ // QK), jnp.float32) * 0.01

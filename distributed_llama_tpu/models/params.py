@@ -34,7 +34,7 @@ from typing import Any, Callable
 import numpy as np
 
 from ..obs import metrics
-from ..quants import QK, FloatType, QTensor
+from ..quants import QK, FloatType, QTensor, scale_plane_cols
 from .spec import ArchType, ModelSpec
 
 Params = dict[str, Any]
@@ -295,6 +295,23 @@ _REPACKED = metrics.counter(
     labelnames=("where",))
 
 
+_SCALE_PLANE_BYTES = metrics.gauge(
+    "weights_scale_plane_bytes",
+    "resident bytes of the Q40 weights' scale planes (int16 f16 bits, K/32 "
+    "a row in whole lane tiles: quants.to_scale_plane) of the engine built "
+    "last; the file's own scales are 2 bytes a block of 32 weights")
+
+
+def scale_plane_bytes(params: Params) -> int:
+    """Bytes the i4p weights' scale planes hold on the device(s), padding
+    included; sets the `weights_scale_plane_bytes` gauge."""
+    leaves = [t for st in stack_names(params) for t in params[st].values()]
+    n = sum(t.scales.nbytes for t in leaves + [params["wcls"]]
+            if isinstance(t, QTensor) and t.layout == "i4p")
+    _SCALE_PLANE_BYTES.set(n)
+    return n
+
+
 def _i4p_groups(t: QTensor, tp: int, col_sharded: bool) -> int | None:
     """The column groups a Q40 weight's split-plane pack needs, None where the
     i4p alignment does not hold (the weight then takes int8 planes)."""
@@ -413,7 +430,8 @@ def _repack_step(row_groups: int, col_groups: int, row_axis: int, sharding):
     """The jitted program that repacks ONE slice of the leading (layer) axis
     and writes it into the donated result: rows of the members concatenated
     per TP group (`_concat_rows_grouped`), nibbles re-paired within each
-    column group (`quants.jnp_to_i4p`), f16 scales to their bit patterns."""
+    column group (`quants.jnp_to_i4p`), f16 scales to their bit patterns in
+    the plane the kernels read (`quants.to_scale_plane`)."""
     import jax
 
     from ..quants import jnp_to_i4p
@@ -471,7 +489,8 @@ def _repack_on_device(members: list[QTensor], row_groups: int,
                                  PartitionSpec(*sharding.spec[1:]))
     out_d = jnp.zeros((*lead, rows, nb * (QK // 2)), jnp.uint8,
                       device=sharding)
-    out_s = jnp.zeros((*lead, rows, nb), jnp.int16, device=sharding)
+    out_s = jnp.zeros((*lead, rows, scale_plane_cols(nb, col_groups)),
+                      jnp.int16, device=sharding)
     step = _repack_step(row_groups, col_groups, row_axis, sharding)
     for i in range(n):
         up = [[jax.device_put(a[i], slice_sh) for a in leaves]

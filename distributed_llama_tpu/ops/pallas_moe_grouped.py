@@ -7,16 +7,19 @@ innermost, and the tile's expert rides in as a scalar-prefetch argument that
 the weight BlockSpecs index with (`PrefetchScalarGridSpec`): consecutive tiles
 of one expert keep the weight block's index, so the pipeline does not fetch it
 again, and an expert nobody chose is never named. A chosen expert's packed
-nibbles and scales cross HBM once a call, at the file's 0.5625 bytes a weight;
+nibbles (0.5 bytes a weight) and its scales' plane cross HBM once a call;
 an unchosen one costs no bytes and no FLOPs. Tiles past the last used one
 repeat the last used tile's block indices (nothing moves) and skip their body.
 
 The weights are the WHOLE stacks over layers, (L, E, rows, K/2) packed with
-(L, E, rows, K/32) scales, and each weight's layer is one more prefetched
-scalar beside the tile's expert (a `LayerOf` from the layer scan; a layer's
-(E, rows, K/2) alone goes in as a stack of one). A layer sliced out of the
-stack for the kernel was a copy of all its experts, touched or not, two to
-three times the bytes the kernel then read (PERF.md section 6, PR 33).
+the scales' plane (L, E, rows, C), C = K/32 in whole 128-lane tiles as
+`quants.to_scale_plane` stores it (the chip keeps that row-major and a block
+reads it in place; see ops/pallas_q4_mm.py), and each weight's layer is one
+more prefetched scalar beside the tile's expert (a `LayerOf` from the layer
+scan; a layer's (E, rows, K/2) alone goes in as a stack of one). A layer
+sliced out of the stack for the kernel was a copy of all its experts, touched
+or not, two to three times the bytes the kernel then read (PERF.md section 6,
+PR 33).
 
 Two kernels. `gu`: act(x Wgate^T) * (x Wup^T) for a tile, both accumulators in
 registers/VMEM, the (rows, hidden) pre-activations never in HBM; the merged
@@ -129,16 +132,17 @@ def _x_specs(tile, kh):
                          lambda n, i, te, nu, at: (_row_block(i, nu), 1))]
 
 
-def _w_specs(bn, kh, nb, off, j):
-    """One expert's (bn, K/2) packed block and its (bn, K/32) scales out of
-    the (L, E, rows, ...) stack: the expert named by the tile, the layer by
+def _w_specs(bn, kh, cols, off, j):
+    """One expert's (bn, K/2) packed block and the (bn, cols) block of its
+    scales' plane (cols: K/32 in whole lane tiles, as stored) out of the
+    (L, E, rows, ...) stack: the expert named by the tile, the layer by
     the call's j-th prefetched layer; `off` shifts the row block (the gate
     half of a merged [up|gate] stack)."""
     def block(n, i, te, nu, at):
         return (at[j], te[i], n + off, 0)
 
     return [pl.BlockSpec((None, None, bn, kh), block),
-            pl.BlockSpec((None, None, bn, nb), block)]
+            pl.BlockSpec((None, None, bn, cols), block)]
 
 
 def _call(kernel, name, x, operands, w_specs, n_out, bn, tile, out_dtype,
@@ -170,10 +174,10 @@ def _call(kernel, name, x, operands, w_specs, n_out, bn, tile, out_dtype,
 def _moe_grouped_q4(rows, tile_expert, n_used, layers, up, sup, gate, sgate,
                     down, sdown, *, tile, act, merged, interpret):
     """rows (C, d) sorted by expert -> (C, d): down(act(gate x) * up x) of
-    each tile's expert. up/gate (L, E, h, d/2) packed with scales
-    (L, E, h, d/32) — the same array twice for a merged [up|gate] stack of
-    2h rows — and down (L, E, d, h/2); `layers` (3,): the layer to read of
-    up, gate and down."""
+    each tile's expert. up/gate (L, E, h, d/2) packed with the scales'
+    plane (L, E, h, d/32 in whole lane tiles) — the same array twice for a
+    merged [up|gate] stack of 2h rows — and down (L, E, d, h/2); `layers`
+    (3,): the layer to read of up, gate and down."""
     hidden = up.shape[2] // (2 if merged else 1)
     d_out = down.shape[2]
     kh = rows.shape[1] // 2

@@ -8,8 +8,10 @@ unpacks in VMEM with zero cross-lane shuffles:
 
 Layout "i4p" (split-plane packing, `QTensor.to_i4p_layout`):
     data   uint8 (out, K/2):  byte j = q[j] | (q[j + K/2] << 4),  q = nibble+8 in [0,16)
-    scales int16 (out, K/32): the reference's per-block f16 deltas as raw BIT PATTERNS
-                              (bit-exact, same 2 B/block). Mosaic on this toolchain
+    scales int16 (out, C):    the reference's per-block f16 deltas as raw BIT PATTERNS
+                              (bit-exact), K/32 of them a row with zero columns behind
+                              up to C, whole 128-lane tiles (`quants.to_scale_plane`:
+                              the form the chip keeps row-major). Mosaic on this toolchain
                               cannot lower f16 refs ("Unsupported type in mosaic
                               dialect: 'f16'"), so the kernel ships the bits as int16
                               and decodes f16->f32 in-kernel with exact integer math
@@ -39,7 +41,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..platform_env import interpret_requested
-from ..quants import QK, QTensor
+from ..quants import QK, QTensor, scale_plane_cols
 
 
 def _f16_bits_to_f32(h16):
@@ -80,7 +82,8 @@ def _unpack_dot_epilogue(xexp_ref, sx_ref, ssum_ref, wp_ref, s_ref, o_ref):
     p += jax.lax.dot_general(hi, xexp_ref[kh:], (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.int32)
     p -= ssum_ref[:] * 8  # remove the nibble offset per block (broadcast over rows)
-    y = (_f16_bits_to_f32(s_ref[:]) * sx_ref[:]) * p.astype(jnp.float32)
+    nb = sx_ref.shape[1]  # the plane's own columns, less its padding
+    y = (_f16_bits_to_f32(s_ref[:, :nb]) * sx_ref[:]) * p.astype(jnp.float32)
     o_ref[:] = jnp.sum(y, axis=1, keepdims=True)
 
 
@@ -131,10 +134,12 @@ def q4_decode_supported(w: QTensor) -> bool:
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _q4_matvec(xexp, sx, wp, scales, *, interpret: bool = False):
     """y (n, 1) f32 from block-diagonal Xexp (K, nb) int8, sx (1, nb) f32,
-    packed nibbles (n, K/2) uint8, scales (n, nb) int16 f16-bit-patterns."""
+    packed nibbles (n, K/2) uint8, the scales' plane (n, scale_plane_cols(nb))
+    int16 f16-bit-patterns."""
     k, nb = xexp.shape
     n, kh = wp.shape
-    assert kh * 2 == k and scales.shape == (n, nb) and nb * QK == k, (
+    cols = scale_plane_cols(nb)
+    assert kh * 2 == k and scales.shape == (n, cols) and nb * QK == k, (
         xexp.shape, wp.shape, scales.shape)
     # activation block sums for the nibble-offset correction (colsum works because
     # Xexp's column b is exactly block b's xq values scattered along its rows)
@@ -148,7 +153,7 @@ def _q4_matvec(xexp, sx, wp, scales, *, interpret: bool = False):
             pl.BlockSpec((1, nb), lambda i: (0, 0), memory_space=pltpu.VMEM),
             pl.BlockSpec((1, nb), lambda i: (0, 0), memory_space=pltpu.VMEM),
             pl.BlockSpec((bn, kh), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((bn, nb), lambda i: (i, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((bn, cols), lambda i: (i, 0), memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec((bn, 1), lambda i: (i, 0), memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
@@ -163,7 +168,9 @@ def _q4_matvec_inline(xq, sx, wp, scales, *, interpret: bool = False):
     _, k = xq.shape
     n, kh = wp.shape
     nb = k // QK
-    assert kh * 2 == k and scales.shape == (n, nb), (xq.shape, wp.shape, scales.shape)
+    cols = scale_plane_cols(nb)
+    assert kh * 2 == k and scales.shape == (n, cols), (
+        xq.shape, wp.shape, scales.shape)
     ssum = jnp.sum(xq.reshape(nb, QK), axis=1, dtype=jnp.int32)[None, :]
     # the (k, nb) Xexp scratch (lanes padded to 128) shares the chip's 16 MiB
     # scoped VMEM with the double-buffered weight block and its two unpacked
@@ -178,7 +185,7 @@ def _q4_matvec_inline(xq, sx, wp, scales, *, interpret: bool = False):
             pl.BlockSpec((1, nb), lambda i: (0, 0), memory_space=pltpu.VMEM),
             pl.BlockSpec((1, nb), lambda i: (0, 0), memory_space=pltpu.VMEM),
             pl.BlockSpec((bn, kh), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((bn, nb), lambda i: (i, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((bn, cols), lambda i: (i, 0), memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec((bn, 1), lambda i: (i, 0), memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),

@@ -7,8 +7,9 @@ per-expert slices) used to go through XLA's dequantize-then-dot, which wrote
 the dequantized model to HBM every dispatch: the block scales spread to every
 weight as an array, the packed planes copied to another layout, 7.8 GB of
 temporaries for a 4 GB model (PERF.md section 5). Here the packed nibbles and
-the f16-bit scales cross HBM once a call at the file's 0.5625 bytes a weight,
-become bf16 in VMEM and go to the MXU with float32 accumulation. The decoded
+the f16-bit scales cross HBM once a call (the nibbles at the file's 0.5
+bytes a weight; the scales as the plane they are stored in, below), become
+bf16 in VMEM and go to the MXU with float32 accumulation. The decoded
 weight is bit for bit XLA's `QTensor.dequantize(dtype=bf16)`:
 bf16((q - 8) * bf16(scale)).
 
@@ -16,6 +17,15 @@ Split-plane addressing: i4p byte column c holds the LOW nibble of element c
 and the HIGH nibble of element K/2 + c (QTensor.to_i4p_layout), so a packed
 (bn, K/2) block covers all of K; the activations come in as two (M, K/2)
 blocks of the same array, the planes' halves of K.
+
+The operands: packed nibbles (..., N, K/2) uint8 and the scales' plane
+(..., N, C) int16, C = K/32 rounded up to whole 128-lane tiles with zero
+columns behind the real ones (`quants.to_scale_plane`, laid out once when the
+weights are repacked). A block of it is (bn, C), read in place: the chip keeps
+an array whose minor dimension is whole lane tiles row-major as stored. A
+plane of K/32 columns (24, 80, 448 in the cells) it kept with the ROWS minor,
+and XLA re-laid the whole stack to this very form at the head of every step
+program, once a dispatch (PERF.md section 6, PR 46).
 
 Blocks follow the shapes (`_pick_bn`): the rows' whole K is in every block,
 so the activations cross HBM once a call and the grid has N / bn steps of up
@@ -43,6 +53,7 @@ dense cell `itl_p95_ms` 110.92 against the plain kernel's 110.62; they went.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -52,7 +63,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..obs import metrics
 from ..platform_env import interpret_requested
-from ..quants import QK, QTensor
+from ..quants import QK, QTensor, scale_plane_cols
 from .pallas_q4 import _f16_bits_to_f32
 
 VMEM_LIMIT = 64 << 20  # of the chip's 128 MiB; the default scope is 16
@@ -76,20 +87,16 @@ def pick_bk(kh: int) -> int:
 
 
 def scales_f32(s_ref):
-    """A block's f16-bit scales rounded to bf16, which is what XLA's
-    `dequantize(dtype=bf16)` multiplies by, as the float32 the VPU computes
-    in; padded with zero columns to whole lane tiles for `_spread`. The
-    kernels keep it in a VMEM scratch of `scales_shape`."""
-    s = _f16_bits_to_f32(s_ref[:]).astype(jnp.bfloat16).astype(jnp.float32)
-    pad = -s.shape[1] % 128
-    if pad:
-        s = jnp.concatenate(
-            [s, jnp.zeros((s.shape[0], pad), jnp.float32)], axis=1)
-    return s
+    """A block of the scales' plane, (bn, whole lane tiles) f16 bits, rounded
+    to bf16, which is what XLA's `dequantize(dtype=bf16)` multiplies by, as
+    the float32 the VPU computes in (`_spread` takes it a lane tile at a
+    time; the plane's zero columns decode to zeros nobody reads). The kernels
+    keep it in a VMEM scratch of `scales_shape`."""
+    return _f16_bits_to_f32(s_ref[:]).astype(jnp.bfloat16).astype(jnp.float32)
 
 
-def scales_shape(bn: int, nb: int):
-    return pltpu.VMEM((bn, -(-nb // 128) * 128), jnp.float32)
+def scales_shape(bn: int, cols: int):
+    return pltpu.VMEM((bn, cols), jnp.float32)
 
 
 _GATHER_LANES = jax.lax.GatherDimensionNumbers(
@@ -180,15 +187,27 @@ def _mm_kernel(at_ref, xlo_ref, xhi_ref, wp_ref, s_ref, o_ref, sf_ref, *, bk):
                                bk).astype(o_ref.dtype)
 
 
-def _pick_bn(n: int, kh: int) -> int:
+_VMEM_BYTES = 128 << 20  # the chip's faster memory, all of it
+
+
+def _pick_bn(n: int, kh: int, stacked: int = 1) -> int:
     """Weight rows a grid step: as many whole lane tiles as keep the packed
     (bn, K/2) block under _MM_BLOCK_BYTES, at most 512 (the accumulator is
     (M, bn) float32); n itself where it is smaller. The grid is cdiv(n, bn):
     a ragged last block (the 151936-row head) reads past the array and its
-    surplus columns are never written."""
+    surplus columns are never written. That is harmless in HBM and NOT where
+    XLA keeps the whole stack (`stacked` matrices) in the faster memory
+    space, which it does to a stack small enough (`S(1)` in the compiled
+    text): a step program of granite-4.0-h-small's two-layer cut, whose one
+    16768-row `ssm_in` lay there, never came back from the chip (PERF.md
+    section 6, PR 46). A stack that fits that memory therefore gets the
+    largest lane-aligned block that divides n, where there is one."""
     if n <= 128:
         return n
-    return min(max(_MM_BLOCK_BYTES // kh // 128, 1) * 128, 512, n // 128 * 128)
+    bn = min(max(_MM_BLOCK_BYTES // kh // 128, 1) * 128, 512, n // 128 * 128)
+    if n % bn and stacked * n * kh <= _VMEM_BYTES:
+        bn = next((b for b in range(bn, 0, -128) if n % b == 0), bn)
+    return bn
 
 
 def q4_mm_supported(w: QTensor, m: int, stacked: int = 0) -> bool:
@@ -210,7 +229,7 @@ def _q4_matmul(x, wp, scales, at, *, out_dtype, interpret: bool = False,
     """x (M, K) -> (M, N) against the matrix at leading indices `at` (a tuple
     of traced scalars: the layer, or the layer and the expert) of packed
     nibbles (L, N, K/2) or (L, E, N, K/2) + int16 f16-bit scales of the same
-    leading shape and (N, K/32).
+    leading shape and (N, scale_plane_cols(K/32)): the plane as stored.
 
     The indices point into the WHOLE stack, prefetched as scalars and used
     by the weight blocks' index maps: a layer scan that handed the kernel
@@ -223,9 +242,10 @@ def _q4_matmul(x, wp, scales, at, *, out_dtype, interpret: bool = False,
     m, k = x.shape
     lead = len(at)
     *_, n, kh = wp.shape
-    assert kh * 2 == k and scales.shape == (*wp.shape[:lead], n, k // QK), (
+    assert kh * 2 == k and scales.shape == (
+        *wp.shape[:lead], n, scale_plane_cols(k // QK)), (
         x.shape, wp.shape, scales.shape)
-    bn = _pick_bn(n, kh)
+    bn = _pick_bn(n, kh, math.prod(wp.shape[:lead]))
 
     def block(i, a):
         return (*(a[j] for j in range(lead)), i, 0)

@@ -213,11 +213,42 @@ def jnp_quantize_q80(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     return q, deltas
 
 
+SCALE_LANES = 128  # columns of the chip's lane tile
+
+
+def scale_plane_cols(nb: int, groups: int = 1) -> int:
+    """Columns of the i4p scale plane of nb blocks a row: each of `groups`
+    column groups' own nb / groups padded to whole lane tiles."""
+    per = nb // groups
+    return groups * (per + -per % SCALE_LANES)
+
+
+def to_scale_plane(scales, groups: int = 1):
+    """(..., nb) block scales -> the plane the kernels read, (...,
+    scale_plane_cols(nb, groups)): zero columns behind each column group's
+    own, so that the minor dimension is whole lane tiles. The chip keeps
+    such an array row-major as stored and a kernel's (bn, columns) block
+    reads it in place; a narrower plane (K/32 of 24, 80, 448) it keeps with
+    the ROWS minor, and every step program then re-laid the whole stack,
+    padded just so, before its first layer (PERF.md section 6, PR 46).
+    NumPy in, NumPy out; a device array stays on the device. A plane that is
+    whole lane tiles already comes back as it is."""
+    nb = scales.shape[-1]
+    cols = scale_plane_cols(nb, groups)
+    if cols == nb:
+        return scales
+    xp = np if isinstance(scales, np.ndarray) else jnp
+    s = scales.reshape(*scales.shape[:-1], groups, nb // groups)
+    s = xp.pad(s, [(0, 0)] * (s.ndim - 1) + [(0, (cols - nb) // groups)])
+    return s.reshape(*scales.shape[:-1], cols)
+
+
 def jnp_to_i4p(packed: jax.Array, scales: jax.Array, col_groups: int = 1
                ) -> tuple[jax.Array, jax.Array]:
     """`QTensor.to_i4p_layout` on the device, bit for bit: planar Q40 bytes
     (..., nb, 16), or the same flattened to (..., K/2), with f16 scales ->
-    split-plane nibbles (..., K/2) and the scales' int16 bit patterns.
+    split-plane nibbles (..., K/2) and the scales' int16 bit patterns as the
+    plane the kernels read (`to_scale_plane`).
 
     Within a column group the low plane is blocks 0 .. nbg/2 and the high
     plane the rest. Output block b of a group (32 bytes) takes its low
@@ -236,7 +267,8 @@ def jnp_to_i4p(packed: jax.Array, scales: jax.Array, col_groups: int = 1
     lo = (a & 0x0F) | ((b & 0x0F) << 4)
     hi = (a >> 4) | (b & 0xF0)
     data = jnp.stack([lo, hi], axis=-2).reshape(*lead, k2)
-    return data, jax.lax.bitcast_convert_type(scales, jnp.int16)
+    return data, to_scale_plane(
+        jax.lax.bitcast_convert_type(scales, jnp.int16), col_groups)
 
 
 # ---------------------------------------------------------------------------
@@ -258,7 +290,8 @@ class QTensor:
 
     ftype: FloatType
     data: jax.Array | np.ndarray  # dense values, Q40 packed u8, or Q80 int8
-    # per-block scales for Q40/Q80: f16 (planar), f32 (i8), int16 f16-bit-patterns (i4p)
+    # per-block scales for Q40/Q80: f16 (planar), f32 (i8), int16 f16-bit-patterns
+    # in whole lane tiles a column group (i4p, `to_scale_plane`)
     scales: jax.Array | np.ndarray | None = None
     # "planar" | "i8" (int8 planes, to_i8_layout) | "i4p" (split-plane packed nibbles,
     # to_i4p_layout — true Q40 HBM density for the pallas_q4 decode kernel)
@@ -342,6 +375,11 @@ class QTensor:
         deltas (bit-exact, same 2 B/block) because Mosaic on this toolchain cannot
         lower f16 refs — the kernel decodes f16-bits -> f32 with exact integer math
         (pallas_q4._f16_bits_to_f32) and dequantize()/to_numpy() bitcast back.
+        They are STORED as the plane the kernels' blocks read in place,
+        (..., N, scale_plane_cols(K/32, col_groups)): each column group's K/32
+        scales with zero columns behind them up to whole 128-lane tiles
+        (`to_scale_plane`; K/32 = 128 stays 128, 24 and 80 become 128, 448
+        becomes 512). `block_scales` gives the file's (..., N, K/32) back.
 
         Both unpacked planes land in natural element order, so the kernel needs no
         cross-lane shuffles. Same HBM bytes as the reference's BlockQ40 stream
@@ -358,8 +396,9 @@ class QTensor:
         packed = np.asarray(self.data)  # (..., nb, 16)
         from . import native
 
-        scales16 = np.ascontiguousarray(
-            np.asarray(self.scales, dtype=np.float16)).view(np.int16)
+        scales16 = to_scale_plane(np.ascontiguousarray(
+            np.asarray(self.scales, dtype=np.float16)).view(np.int16),
+            col_groups)
         nat = native.q40_to_i4p(packed, col_groups)
         if nat is not None:
             return QTensor(self.ftype, nat, scales16, layout="i4p",
@@ -376,6 +415,18 @@ class QTensor:
         data = data.reshape(*lead, k // 2)
         return QTensor(self.ftype, data, scales16, layout="i4p",
                        groups=col_groups, row_groups=self.row_groups)
+
+    def block_scales(self):
+        """An i4p tensor's scales less the plane's padding: (..., N, K/32)
+        int16 bit patterns in the file's block order."""
+        assert self.layout == "i4p", self.layout
+        nb = self.data.shape[-1] * 2 // QK
+        s, g = self.scales, self.groups
+        assert s.shape[-1] == scale_plane_cols(nb, g), (s.shape, nb, g)
+        if s.shape[-1] == nb:
+            return s
+        s = s.reshape(*s.shape[:-1], g, s.shape[-1] // g)[..., :nb // g]
+        return s.reshape(*s.shape[:-2], nb)
 
     def _i4p_unpack(self, xp):
         """Split-plane nibbles -> natural-order values (..., K) minus the 8 offset."""
@@ -412,9 +463,9 @@ class QTensor:
                                      dtype)
         if self.layout == "i4p":
             vals = self._i4p_unpack(jnp)
-            nb = self.scales.shape[-1]
-            g = vals.reshape(*vals.shape[:-1], nb, QK)
-            scales = jax.lax.bitcast_convert_type(jnp.asarray(self.scales), jnp.float16)
+            scales = jax.lax.bitcast_convert_type(
+                jnp.asarray(self.block_scales()), jnp.float16)
+            g = vals.reshape(*vals.shape[:-1], scales.shape[-1], QK)
             return jnp_dequantize_q80(g, scales, dtype)
         if self.ftype == FloatType.Q40:
             return jnp_dequantize_q40(jnp.asarray(self.data), jnp.asarray(self.scales), dtype)
@@ -431,9 +482,9 @@ class QTensor:
             return dequantize_q80(g, np.asarray(self.scales))
         if self.layout == "i4p":
             vals = self._i4p_unpack(np)
-            nb = self.scales.shape[-1]
-            g = vals.reshape(*vals.shape[:-1], nb, QK)
-            return dequantize_q80(g, np.asarray(self.scales).view(np.float16))
+            scales = np.ascontiguousarray(self.block_scales())
+            g = vals.reshape(*vals.shape[:-1], scales.shape[-1], QK)
+            return dequantize_q80(g, scales.view(np.float16))
         if self.ftype == FloatType.Q40:
             return dequantize_q40(np.asarray(self.data), np.asarray(self.scales))
         if self.ftype == FloatType.Q80:

@@ -25,7 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.params import (Params, decode_stream_bytes, hold_dense,
-                             prepare_for_pallas, stack_names)
+                             prepare_for_pallas, scale_plane_bytes,
+                             stack_names)
 from ..models.spec import ModelSpec
 from ..obs import flight, metrics, trace
 from ..resilience import faults
@@ -272,6 +273,7 @@ class Engine:
         # global (all-shard) weight bytes one decode step streams — per-chip traffic
         # divides by tp; used for the achieved-GB/s printout
         self.decode_weight_bytes = decode_stream_bytes(self.params, spec, batch)
+        scale_plane_bytes(self.params)  # the gauge beside the memory's peak
         self.rope = RopeTables.create(spec)
         self.batch = batch
         # Paged (out-of-core) KV cache — the reference's --kv-cache-storage

@@ -21,8 +21,8 @@ that RESULTS in packed weights (`u8[...]`), their scales (`s16[...]`) or an
 array of the KV pool's shape (`bf16[L,N,hk,bt,hs]`, with its layout): the
 copies XLA puts around a kernel or a scatter show only here (the kernels
 alone compile in tests/test_tpu_compile.py, which also holds the step
-programs to `pool_relayouts` being empty). Nothing runs: no time comes out
-of this (PERF.md section 6, PRs 33 and 37).
+programs to `pool_relayouts` and `scale_relayouts` being empty). Nothing
+runs: no time comes out of this (PERF.md section 6, PRs 33, 37 and 46).
 """
 
 from __future__ import annotations
@@ -72,7 +72,7 @@ def model_shapes(config: str, chip, **keys):
     """(spec, parameter shapes, configuration) of `config` with vocabulary
     512 and `keys` over the file's: the parameter tree of a drawn cut, each
     stack's leaves given the depth the spec states."""
-    full = dict(cells.load_config(config), vocab_size=512, **keys)
+    full = dict(cells.load_config(config), **{"vocab_size": 512, **keys})
     family = cells.load_family(full["family"])
     if hasattr(family, "one_layer_a_stack"):
         # the family cuts itself: a layer of each stack, two experts a layer
@@ -226,6 +226,30 @@ def pool_relayouts(text: str, shape) -> list[str]:
             if layout not in own or re.search(r"\bcopy\(", line)]
 
 
+# a scale plane brought into the faster memory space as it is stored,
+# row-major in (8,128)(2,1) tiles: XLA's prefetch of a plane small enough for
+# that space (a 512-row head, Mixtral's `s16[8,4096,128]`, the dense layers'
+# planes of Laguna and Granite), overlapped with the work before its use. It
+# pads and transposes nothing
+PLANE_PREFETCH = re.compile(
+    r"copy-done -> s16\[[\d,]+\]\{(\d+,)*1,0:T\(8,128\)\(2,1\)S\(1\)\}")
+
+
+def scale_relayouts(text: str) -> list[str]:
+    """Every `copy`, `copy-done` or fusion of a compiled program that results
+    in an `s16[...]` array, a Q40 weight's scale plane, as "operation ->
+    shape and layout", but for the prefetches (`PLANE_PREFETCH`): a program
+    whose kernels read the planes where and as they are stored has none.
+    (Until PR 46 a plane of K/32 columns that were not whole lane tiles lay
+    on the chip with its ROWS minor, and every program re-laid each stack,
+    padded to 128 lanes, before its layer scan.)"""
+    found = [f"{re.sub(r'[.][0-9]+$', '', name)} -> {shape}{layout}"
+             for name, shape, layout, line in results(text)
+             if shape.startswith("s16")
+             and re.search(r" (?:copy|copy-done|fusion)\(", line)]
+    return [r for r in found if not PLANE_PREFETCH.fullmatch(r)]
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("config")
@@ -275,6 +299,8 @@ def main():
     for side in held_pools(spec, cfg):
         print(f"  pool {side}: re-laid or copied by "
               f"{pool_relayouts(text, side) or 'nothing'}")
+    print(f"  scale planes (s16): re-laid or copied by "
+          f"{scale_relayouts(text) or 'nothing'} (prefetches to S(1) apart)")
 
 
 if __name__ == "__main__":

@@ -49,7 +49,8 @@ from distributed_llama_tpu.ops import moe_grouped as G  # noqa: E402
 from distributed_llama_tpu.ops.matmul import LayerOf  # noqa: E402
 from distributed_llama_tpu.ops.pallas_moe_grouped import (  # noqa: E402
     moe_grouped_q4)
-from distributed_llama_tpu.quants import FloatType, QTensor  # noqa: E402
+from distributed_llama_tpu.quants import (FloatType, QTensor,  # noqa: E402
+                                          to_scale_plane)
 
 HBM = 819e9
 SHAPES = {"e64": (64, 6, 768, 2560, "relu"), "e8": (8, 2, 14336, 4096, "silu")}
@@ -67,9 +68,8 @@ def stack(key, lead, out, k):
     data = data | (((data & 0xF0) == 0).astype(jnp.uint8) << 7)
     scales = ((jax.random.uniform(ks, (*lead, out, k // 32)) + 0.5) * 0.02 / 4.3
               ).astype(jnp.float16)
-    return QTensor(FloatType.Q40, data,
-                   jax.lax.bitcast_convert_type(scales, jnp.int16),
-                   layout="i4p")
+    return QTensor(FloatType.Q40, data, to_scale_plane(
+        jax.lax.bitcast_convert_type(scales, jnp.int16)), layout="i4p")
 
 
 def timed(fn, reps):

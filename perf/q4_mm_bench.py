@@ -40,7 +40,8 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from distributed_llama_tpu.quants import QK, FloatType, QTensor  # noqa: E402
+from distributed_llama_tpu.quants import (QK, FloatType, QTensor,  # noqa: E402
+                                          to_scale_plane)
 
 HBM_BYTES_S, BF16_FLOP_S = 819e9, 197e12  # one v5e chip
 ROWS = (8, 16, 64, 72, 80, 512)
@@ -130,7 +131,8 @@ def _drawn(n, k, seed):
     data = jax.random.bits(k1, (n, k // 2), jnp.uint8)
     scales = jax.random.uniform(k2, (n, k // QK), jnp.float32, 0.005,
                                 0.02).astype(jnp.float16)
-    return data, jax.lax.bitcast_convert_type(scales, jnp.int16)
+    return data, to_scale_plane(
+        jax.lax.bitcast_convert_type(scales, jnp.int16))
 
 
 def _chained(call, m, n):

@@ -16,7 +16,7 @@ from distributed_llama_tpu.models.params import init_random_params, prepare_for_
 from distributed_llama_tpu.models.spec import ArchType, ModelSpec, RopeType
 from distributed_llama_tpu.ops.pallas_q4 import q4_matvec
 from distributed_llama_tpu.ops.rope import RopeTables
-from distributed_llama_tpu.quants import QK, FloatType, QTensor
+from distributed_llama_tpu.quants import QK, FloatType, QTensor, scale_plane_cols
 
 
 def _to_jnp(t: QTensor) -> QTensor:
@@ -58,10 +58,13 @@ def test_i4p_col_groups_make_shards_self_contained():
     w = QTensor.from_float(rng.randn(n, k).astype(np.float32), FloatType.Q40)
     grouped = w.to_i4p_layout(col_groups=g)
     full = w.to_numpy()
-    kl, khl, nbl = k // g, k // (2 * g), (k // QK) // g
+    # a shard's part of the scales' plane: its own K/32 columns in whole
+    # lane tiles (quants.to_scale_plane pads within each column group)
+    kl, khl, cols = k // g, k // (2 * g), grouped.scales.shape[1] // g
+    assert cols == scale_plane_cols(k // QK // g) == 128
     for s in range(g):
         shard = QTensor(grouped.ftype, grouped.data[:, s * khl:(s + 1) * khl],
-                        grouped.scales[:, s * nbl:(s + 1) * nbl], layout="i4p")
+                        grouped.scales[:, s * cols:(s + 1) * cols], layout="i4p")
         np.testing.assert_array_equal(shard.to_numpy(), full[:, s * kl:(s + 1) * kl])
 
 
@@ -75,6 +78,38 @@ def test_q4_matvec_matches_oracle():
     got = np.asarray(q4_matvec(x, wi, interpret=True), np.float32)
     rel = np.abs(got - want).max() / (np.abs(want).max() + 1e-9)
     assert rel < 0.02, rel  # Q80 activation quantization error scale
+
+
+@pytest.mark.parametrize("inline", [False, True], ids=["xexp", "inline"])
+@pytest.mark.parametrize("k,nb", [(768, 24), (1792, 56), (2560, 80),
+                                  (3584, 112), (14336, 448), (4096, 128)])
+def test_q4_matvec_reads_the_planes_own_columns_bit_for_bit(k, nb, inline):
+    """The one-row kernel on the scales' plane as stored, K/32 columns in
+    whole lane tiles: an activation row that lives in ONE quant block leaves
+    one term a weight row, scale x activation scale x integer dot in
+    float32, which NumPy gives bit for bit from the file's own scales; the
+    first and last block of each half-plane, where the plane's padding and
+    the halves meet. 160 rows: a ragged second row block."""
+    from distributed_llama_tpu.ops.pallas_q8 import _quantize_row
+
+    rng = np.random.RandomState(nb)
+    w = QTensor.from_float((rng.randn(160, k) * 0.05).astype(np.float32),
+                           FloatType.Q40)
+    wi = _to_jnp(w.to_i4p_layout())
+    assert wi.scales.shape == (160, scale_plane_cols(nb))
+    q = np.concatenate([(w.data & 0x0F), (w.data >> 4)], axis=-1).astype(
+        np.int32).reshape(160, k) - 8  # the file's nibbles, natural order
+    for b in (0, nb // 2 - 1, nb // 2, nb - 1):
+        x = np.zeros((1, k), np.float32)
+        x[0, b * QK:(b + 1) * QK] = rng.randn(QK)
+        xq, sx = _quantize_row(jnp.asarray(x[0]), nb)
+        p = q[:, b * QK:(b + 1) * QK] @ np.asarray(xq, np.int32)[
+            b * QK:(b + 1) * QK]
+        want = (w.scales[:, b].astype(np.float32) * np.asarray(sx)[0, b]
+                ) * p.astype(np.float32)
+        got = q4_matvec(jnp.asarray(x), wi, out_dtype=jnp.float32,
+                        interpret=True, inline_xexp=inline)
+        np.testing.assert_array_equal(np.asarray(got)[0], want)
 
 
 def test_q4_matvec_agrees_with_q8_kernel():

@@ -21,7 +21,7 @@ from distributed_llama_tpu.ops.pallas_q4_mm import (_pick_bn, pick_bk,
                                                     q4_matmul,
                                                     q4_mm_supported)
 from distributed_llama_tpu.ops.rope import RopeTables
-from distributed_llama_tpu.quants import FloatType, QTensor
+from distributed_llama_tpu.quants import FloatType, QTensor, scale_plane_cols
 
 
 def _w(n, k, seed=0):
@@ -58,6 +58,70 @@ def test_decoded_weights_are_xlas_bf16_weights_bit_for_bit():
                     out_dtype=jnp.float32, interpret=True)
     want = np.asarray(w.dequantize(dtype=jnp.bfloat16).astype(jnp.float32))
     np.testing.assert_array_equal(np.asarray(got), want.T)
+
+
+def _columns(k, n=96, seed=0):
+    """Columns of K to read back: the first and the last quant block of each
+    half-plane (where a scale's lane tile begins and where the plane's
+    padding begins) and a draw of the rest."""
+    edge = np.r_[0:32, k // 2 - 32:k // 2 + 32, k - 32:k]
+    rest = np.random.RandomState(seed).choice(k, n, replace=False)
+    return np.unique(np.r_[edge, rest])
+
+
+def _decoded(w, cols, **kw):
+    """Columns `cols` of the weights the kernel decodes, (len(cols), N): a
+    one-hot row reads one column back through the MXU unchanged."""
+    k = w.shape[-1]
+    x = np.zeros((len(cols), k), np.float32)
+    x[np.arange(len(cols)), cols] = 1.0
+    return np.asarray(q4_matmul(jnp.asarray(x, jnp.bfloat16), w,
+                                out_dtype=jnp.float32, interpret=True, **kw))
+
+
+# K/32, the file's scales a row, at the cells' widths: an expert's down
+# (K 768), LFM2's and SmallThinker's (1792, 2560, 3584), w2's 448; the plane
+# the kernel reads holds them in whole lane tiles, zero columns behind them.
+# 4096 / 32 is a lane tile as it is
+@pytest.mark.parametrize("k,nb,cols", [(768, 24, 128), (1792, 56, 128),
+                                       (2560, 80, 128), (3584, 112, 128),
+                                       (14336, 448, 512), (4096, 128, 128)])
+def test_decode_is_xlas_bit_for_bit_at_every_plane_width(k, nb, cols):
+    """The scales are stored as the plane the kernel's block reads in place
+    (`quants.to_scale_plane`): what it decodes is still `dequantize(dtype=
+    bf16)` bit for bit, over a ragged second row block too."""
+    w = _w(160, k, seed=nb)
+    assert w.scales.shape == (160, cols) == (160, scale_plane_cols(nb))
+    assert w.block_scales().shape == (160, nb)
+    assert not np.asarray(w.scales)[:, nb:].any()  # the padding is zeros
+    at = _columns(k, seed=nb)
+    want = np.asarray(w.dequantize(dtype=jnp.bfloat16).astype(jnp.float32))
+    np.testing.assert_array_equal(_decoded(w, at), want[:, at].T)
+
+
+def test_decode_of_a_ragged_head_bit_for_bit():
+    """SmallThinker's head cut small: 1187 rows of K 2560 in blocks of 384,
+    the last one 35 rows that end before the block does."""
+    w = _w(1187, 2560, seed=11)
+    assert _pick_bn(1187, 1280) == 384 and w.scales.shape == (1187, 128)
+    at = _columns(2560, n=32, seed=2)
+    want = np.asarray(w.dequantize(dtype=jnp.bfloat16).astype(jnp.float32))
+    np.testing.assert_array_equal(_decoded(w, at), want[:, at].T)
+
+
+def test_decode_at_a_layer_and_an_expert_of_a_padded_stack_bit_for_bit():
+    """(layer 1, expert 2) of a (2, 3, 256, 768) stack, whose planes are
+    (2, 3, 256, 128) for 24 scales a row: the block index maps name the
+    same matrix in the nibbles and in the plane."""
+    rng = np.random.RandomState(9)
+    w = QTensor.from_float(rng.randn(2, 3, 256, 768).astype(np.float32)
+                           * 0.02, FloatType.Q40).to_i4p_layout()
+    assert w.scales.shape == (2, 3, 256, 128)
+    at = _columns(768, n=32, seed=3)
+    want = np.asarray(w.dequantize(dtype=jnp.bfloat16).astype(jnp.float32))
+    got = _decoded(w, at, at=(jnp.int32(1), jnp.int32(2)))
+    np.testing.assert_array_equal(got, want[1, 2][:, at].T)
+    assert np.abs(got - want[0, 2][:, at].T).max() > 1e-3
 
 
 @pytest.mark.parametrize("at", [(0, 0), (1, 2), (2, 3), (1,)])
@@ -104,7 +168,7 @@ def test_q4_mm_supported_gates():
                             FloatType.Q80).to_i8_layout()
     assert not q4_mm_supported(w8, 8)  # another layout
     stacked = QTensor(FloatType.Q40, np.zeros((2, 64, 512), np.uint8),
-                      np.zeros((2, 64, 32), np.int16), layout="i4p")
+                      np.zeros((2, 64, 128), np.int16), layout="i4p")
     assert not q4_mm_supported(stacked, 8)  # the grouped kernels' stacks
     with pytest.raises(ValueError, match="q4_mm_supported"):
         q4_matmul(jnp.ones((1, 1024), jnp.bfloat16), w, interpret=True)
@@ -120,6 +184,25 @@ def test_q4_mm_supported_gates():
 def test_blocks_follow_the_shapes(n, k, bn, bk):
     assert (_pick_bn(n, k // 2), pick_bk(k // 2)) == (bn, bk)
     assert bn * (k // 2) <= 1 << 20
+
+
+def test_a_stack_that_fits_the_faster_memory_gets_no_ragged_block():
+    """granite-4.0-h-small's `ssm_in` (16768 rows, K/2 2048): nine layers
+    of it are 309 MB and keep blocks of 256 with a ragged 66th; the check's
+    one-layer cut is 34 MB, which XLA may keep in VMEM, where a block that
+    ends past the array is not safe: 128 rows, 131 whole blocks. A.X-K1's
+    576-row `wkv_a` has no lane-aligned divisor and stays as it was."""
+    assert _pick_bn(16768, 2048, 9) == 256 and 16768 % 256
+    assert _pick_bn(16768, 2048, 1) == 128
+    assert _pick_bn(151936, 1280) == 384  # SmallThinker's head: 194 MB
+    assert _pick_bn(576, 3584, 6) == 128 and _pick_bn(4096, 2048, 1) == 256
+    w = _w(384, 8192, seed=4)  # bn 128 of 384 either way
+    x = jnp.asarray(np.random.RandomState(1).randn(8, 8192).astype(np.float32))
+    want = qmatmul(x.astype(jnp.bfloat16), w, use_pallas=False,
+                   out_dtype=jnp.float32)
+    got = q4_matmul(x, w, out_dtype=jnp.float32, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-4, rtol=1e-4)
 
 
 def _spec():

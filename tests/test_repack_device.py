@@ -14,7 +14,8 @@ from distributed_llama_tpu.models.params import (_COL_SHARDED, _DENSE_MATMULS,
                                                  init_random_params,
                                                  prepare_for_pallas)
 from distributed_llama_tpu.models.spec import ArchType, ModelSpec, RopeType
-from distributed_llama_tpu.quants import FloatType, QTensor, jnp_to_i4p
+from distributed_llama_tpu.quants import (FloatType, QTensor, jnp_to_i4p,
+                                          q40_to_bytes, scale_plane_cols)
 
 
 def _dense_spec():
@@ -66,6 +67,104 @@ def test_jnp_to_i4p_is_to_i4p_layout(col_groups, flat):
     d, s = jax.jit(jnp_to_i4p, static_argnums=2)(data, w.scales, col_groups)
     np.testing.assert_array_equal(np.asarray(d), want.data)
     np.testing.assert_array_equal(np.asarray(s), want.scales)
+
+
+# K and the column groups it is packed in: K/32 a group of 24, 12 and 6
+# (an expert's down at tp 1, 2, 4), 80 and 40, 112, and a lane tile as it is
+@pytest.mark.parametrize("k,col_groups", [(768, 1), (768, 2), (768, 4),
+                                          (2560, 1), (2560, 2), (3584, 1),
+                                          (4096, 1), (8192, 2)])
+@pytest.mark.parametrize("device", [False, True], ids=["host", "device"])
+def test_the_scale_plane_gives_the_files_blocks_back(k, col_groups, device):
+    """`to_i4p_layout` and `jnp_to_i4p` store the scales as the plane the
+    kernels read, each column group's K/32 in whole lane tiles; `to_numpy`,
+    `dequantize` and `block_scales` read the file's blocks out of it in the
+    file's order, and the `.m` writer then writes the file's bytes."""
+    import io
+
+    import jax.numpy as jnp
+
+    from distributed_llama_tpu.formats.mfile import write_tensor
+
+    rng = np.random.RandomState(k // 32 + col_groups)
+    w = QTensor.from_float(rng.randn(2, 24, k).astype(np.float32),
+                           FloatType.Q40)
+    wi = w.to_i4p_layout(col_groups=col_groups)
+    if device:
+        d, s = jax.jit(jnp_to_i4p, static_argnums=2)(w.data, w.scales,
+                                                     col_groups)
+        wi = QTensor(FloatType.Q40, d, s, layout="i4p", groups=col_groups)
+    nb = k // 32
+    per = -(-(nb // col_groups) // 128) * 128
+    assert wi.scales.shape == (2, 24, col_groups * per) == (
+        2, 24, scale_plane_cols(nb, col_groups))
+    np.testing.assert_array_equal(np.asarray(wi.block_scales()),
+                                  w.scales.view(np.int16))
+    np.testing.assert_array_equal(wi.to_numpy(), w.to_numpy())
+    np.testing.assert_array_equal(
+        np.asarray(wi.dequantize(jnp.float32)),
+        np.asarray(w.dequantize(jnp.float32)))
+    # what a writer makes of the layout's values is the file's own stream
+    buf = io.BytesIO()
+    write_tensor(buf, wi.to_numpy(), FloatType.Q40)
+    assert buf.getvalue() == q40_to_bytes(w.data, w.scales)
+
+
+@pytest.mark.parametrize("name", ["wo", "w2"])
+def test_tp2_shards_hold_their_own_plane(name):
+    """A column-sharded weight on a tp = 2 mesh: each shard's part of the
+    scales' plane is the plane of its own K/32 columns (4 of wo's 8 here, in
+    one lane tile), so what `_localize_qtensors` hands the kernels inside
+    `shard_map`, the shard's leaves with one column group, decodes to the
+    shard's columns of the file's weights."""
+    from distributed_llama_tpu.models.forward import _localize_qtensors
+    from distributed_llama_tpu.parallel.mesh import make_mesh
+    from distributed_llama_tpu.parallel.tp import shard_params
+
+    spec = _dense_spec()
+    params = init_random_params(spec, FloatType.Q40, seed=11)
+    mesh = make_mesh(tp=2)
+    placed = shard_params(
+        prepare_for_pallas(params, 2, spec=spec, mesh=mesh), mesh, spec)
+    t = placed["blocks"][name]
+    full = params["blocks"][name].to_numpy()
+    k = full.shape[-1]
+    assert t.groups == 2 and t.scales.shape[-1] == 2 * 128
+    np.testing.assert_array_equal(t.to_numpy(), full)
+    # one distinct shard a tp index (the mesh may replicate over other axes)
+    planes = {s.device: s.data for s in t.scales.addressable_shards}
+    shards = {d.index[-1].start or 0: (d.data, planes[d.device])
+              for d in t.data.addressable_shards}
+    assert len(shards) == 2
+    for i, (_, (data, scales)) in enumerate(sorted(shards.items())):
+        assert scales.shape[-1] == 128 == scale_plane_cols(k // 32 // 2)
+        local = _localize_qtensors({"w": QTensor(
+            t.ftype, data, scales, layout="i4p", groups=2)})["w"]
+        assert local.groups == 1
+        np.testing.assert_array_equal(
+            local.to_numpy(), full[..., i * k // 2:(i + 1) * k // 2])
+
+
+def test_an_engine_states_its_scale_planes_bytes(monkeypatch):
+    """`weights_scale_plane_bytes` is set when an engine is built: the
+    planes' resident bytes, padding included, against the file's 2 bytes a
+    block (here every K/32 is 8, stored as one lane tile: 16 times)."""
+    from distributed_llama_tpu.obs import metrics
+    from distributed_llama_tpu.runtime.engine import Engine
+
+    monkeypatch.setenv("DLT_PALLAS_INTERPRET", "1")
+    spec = _dense_spec()
+    params = init_random_params(spec, FloatType.Q40, seed=5)
+    file_bytes = sum(
+        t.scales.nbytes for t in [*params["blocks"].values(), params["wcls"]]
+        if isinstance(t, QTensor) and t.ftype == FloatType.Q40)
+    eng = Engine(spec, params, tp=1, use_pallas=True)
+    held = [t for t in [*eng.params["blocks"].values(), eng.params["wcls"]]
+            if isinstance(t, QTensor) and t.ftype == FloatType.Q40]
+    assert len(held) == 5 and all(t.layout == "i4p" and t.scales.shape[-1] == 128
+                        for t in held)
+    got = metrics.snapshot()["weights_scale_plane_bytes"]
+    assert got == sum(t.scales.nbytes for t in held) == 16 * file_bytes
 
 
 CASES = [  # arch, tp, moe_sharding, on a mesh

@@ -469,6 +469,52 @@ def test_grouped_kernels_on_a_layer_of_the_stack_equal_the_slice_bit_for_bit(
     assert np.abs(whole - other).max() > 1e-3  # the index is what is read
 
 
+@pytest.mark.parametrize("k,nb", [(768, 24), (1792, 56), (2560, 80),
+                                  (3584, 112), (14336, 448), (4096, 128)])
+def test_grouped_kernels_decode_the_stored_planes_bit_for_bit(k, nb):
+    """Both grouped kernels on (L, E, rows, K/2) stacks whose scales are the
+    stored plane, (L, E, rows, K/32 in whole lane tiles): one-hot rows read
+    the decoded weights of (layer 1, expert 2) back through the MXU, and
+    they are `dequantize(dtype=bf16)` bit for bit. `down` gives the weights
+    themselves; `gu` with the same stack as up and gate gives w relu(w),
+    float32 products of bf16 values, which are exact."""
+    from distributed_llama_tpu.ops import pallas_moe_grouped as G
+    from distributed_llama_tpu.ops.pallas_q4_mm import pick_bk
+    from distributed_llama_tpu.quants import scale_plane_cols
+
+    rng = np.random.default_rng(nb)
+    n, tile = 256, 16
+    w = QTensor.from_float(rng.standard_normal((2, 3, n, k)).astype(
+        np.float32) * 0.02, FloatType.Q40).to_i4p_layout()
+    cols = scale_plane_cols(nb)
+    assert w.scales.shape == (2, 3, n, cols)
+    assert w.block_scales().shape == (2, 3, n, nb)
+    # the first and last quant block of each half-plane: 64 rows, 4 tiles
+    at = np.r_[0:16, k // 2 - 16:k // 2 + 16, k - 16:k]
+    x = np.zeros((len(at), k), np.float32)
+    x[np.arange(len(at)), at] = 1.0
+    te = jnp.full((len(at) // tile,), 2, jnp.int32)
+    nu = jnp.asarray([len(at) // tile], jnp.int32)
+    kh, bn = k // 2, G._pick_bn(n, k // 2)
+    want = np.asarray(w.dequantize(dtype=jnp.bfloat16).astype(
+        jnp.float32))[1, 2][:, at].T
+
+    def call(kernel, name, operands, specs, layers):
+        return np.asarray(G._call(
+            functools.partial(kernel, bk=pick_bk(kh)), name, jnp.asarray(x),
+            operands, specs, n, bn, tile, jnp.float32, te, nu,
+            jnp.asarray(layers, jnp.int32), True))
+
+    got = call(G._down_kernel, "moe_grouped_q4_down", (w.data, w.scales),
+               G._w_specs(bn, kh, cols, 0, 0), [1])
+    np.testing.assert_array_equal(got, want)
+    got = call(functools.partial(G._gu_kernel, act="relu"),
+               "moe_grouped_q4_gu", (w.data, w.scales) * 2,
+               G._w_specs(bn, kh, cols, 0, 0) + G._w_specs(bn, kh, cols, 0, 1),
+               [1, 1])
+    np.testing.assert_array_equal(got, want * np.maximum(want, 0.0))
+
+
 @functools.lru_cache(maxsize=None)
 def _wide_toy(name, hidden=256, seed=SEED):
     """A toy MoE configuration at widths whose half-planes are whole lane

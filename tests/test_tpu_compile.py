@@ -41,7 +41,7 @@ from distributed_llama_tpu.ops.pallas_paged_attention import (
     latent_paged_attention, paged_attention)
 from distributed_llama_tpu.ops.pallas_q4 import _q4_matvec, _q4_matvec_inline
 from distributed_llama_tpu.ops.pallas_q4_mm import q4_matmul, q4_mm_supported
-from distributed_llama_tpu.quants import FloatType, QTensor
+from distributed_llama_tpu.quants import FloatType, QTensor, scale_plane_cols
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "perf"))
 
@@ -85,8 +85,14 @@ def _as_the_entry_points_compile():
     compilation_cache.reset_cache()
 
 
+def _plane(k):
+    """Columns of the scales' plane as the weights are stored: K/32 in whole
+    lane tiles (`quants.to_scale_plane`)."""
+    return scale_plane_cols(k // 32)
+
+
 def _q4_weight(n, k):
-    return [((n, k // 2), U8), ((n, k // 32), I16)]
+    return [((n, k // 2), U8), ((n, _plane(k)), I16)]
 
 
 def matvec(n, k):
@@ -114,7 +120,7 @@ def matmul(m, n, k, out=BF16, lead=(2,)):
     layers and experts, each indexed by a traced scalar). The gate has to
     admit the shape (a case `q4_mm_supported` declined would be asserted
     so)."""
-    stack = [((*lead, n, k // 2), U8), ((*lead, n, k // 32), I16)]
+    stack = [((*lead, n, k // 2), U8), ((*lead, n, _plane(k)), I16)]
     w = QTensor(FloatType.Q40, *(jax.ShapeDtypeStruct(*a) for a in stack),
                 layout="i4p")
     assert q4_mm_supported(w, m, stacked=len(lead)), (m, n, k)
@@ -156,8 +162,8 @@ def grouped(rows, k, experts, hidden, dim, merged=True, act="relu", layers=1):
     cap = capacity(rows * k, experts, tile)
     gu_rows = 2 * hidden if merged else hidden
     lead = (layers, experts)
-    up = [((*lead, gu_rows, dim // 2), U8), ((*lead, gu_rows, dim // 32), I16)]
-    down = [((*lead, dim, hidden // 2), U8), ((*lead, dim, hidden // 32), I16)]
+    up = [((*lead, gu_rows, dim // 2), U8), ((*lead, gu_rows, _plane(dim)), I16)]
+    down = [((*lead, dim, hidden // 2), U8), ((*lead, dim, _plane(hidden)), I16)]
     return (_moe_grouped_q4,
             [((cap, dim), BF16), ((cap // tile,), I32), ((), I32), ((3,), I32),
              *up, *up, *down], {"tile": tile, "act": act, "merged": merged})
@@ -171,8 +177,8 @@ def grouped_share(rows, k, held, width, hidden, dim, layers=1):
     cap = capacity(rows * k, held, tile)
     lead = (layers, held)
     up = [((*lead, 2 * hidden, dim // 2), U8),
-          ((*lead, 2 * hidden, dim // 32), I16)]
-    down = [((*lead, dim, hidden // 2), U8), ((*lead, dim, hidden // 32), I16)]
+          ((*lead, 2 * hidden, _plane(dim)), I16)]
+    down = [((*lead, dim, hidden // 2), U8), ((*lead, dim, _plane(hidden)), I16)]
     return (_moe_grouped_q4,
             [((cap, dim), BF16), ((cap // tile,), I32), ((), I32), ((3,), I32),
              *up, *up, *down], {"tile": tile, "act": "silu", "merged": True})
@@ -321,6 +327,15 @@ STEP_POOLS = {
     # and beside it the slots' rings and the blocks' state snapshots
     "state": ("lfm2-8b-a1b", {"num_experts": 8}),
 }
+# every configuration of the benchmark, for what holds of all their weights:
+# the pools' five and the two whose pools are of a kind already there
+# (Mixtral's four experts keep a 64-token chunk's 72 rows x 2 in the grouped
+# layer, as its eight do)
+STEP_MODELS = {
+    **STEP_POOLS,
+    "e8": ("mixtral-8x7b-l8", {"num_local_experts": 4}),
+    "ssm": ("granite-4.0-h-small-l10", {"num_local_experts": 16}),
+}
 # `jit_step` at T = 1 and at a 64-token chunk as the scheduler dispatches it
 # (told which row prefills: 72 compact rows, `forward.RowMap`), and a 2-step
 # decode scan with the pools in its carry (`make_batched_decode_loop`'s form)
@@ -329,10 +344,10 @@ STEP_PROGRAMS = {"t1": {"chunk": 1}, "t64": {"chunk": 64}, "scan2": {"scan": 2}}
 
 @pytest.fixture(scope="module")
 def step_model(chip):
-    """(spec, parameter shapes, configuration) of a `STEP_POOLS` entry,
+    """(spec, parameter shapes, configuration) of a `STEP_MODELS` entry,
     drawn once a module."""
     return functools.cache(lambda pool: aot_step.model_shapes(
-        STEP_POOLS[pool][0], chip, **STEP_POOLS[pool][1]))
+        STEP_MODELS[pool][0], chip, **STEP_MODELS[pool][1]))
 
 
 @pytest.mark.parametrize("program", list(STEP_PROGRAMS))
@@ -369,6 +384,43 @@ def test_step_program_updates_the_pool_in_place(chip, step_model, pool,
     chunk = STEP_PROGRAMS[program].get("chunk", 1)
     if chunk > 1:  # the head ran at the one sampled position a row
         assert aot_step.logits_blocks(text, 8, chunk, cfg["vocab_size"]) == []
+
+
+@pytest.mark.parametrize("program", ["t1", "t8", "t64", "scan8"])
+@pytest.mark.parametrize("model", list(STEP_MODELS))
+def test_step_program_reads_the_scale_planes_as_stored(chip, step_model,
+                                                       model, program,
+                                                       monkeypatch):
+    """No step program of any configuration (the decode step, an 8-token
+    and a 64-token chunk as the scheduler dispatches them, the K-step scan)
+    copies or re-lays a Q40 weight's scale plane: the weights' repack stores
+    it in whole lane tiles (`quants.to_scale_plane`), which the chip keeps
+    row-major and the kernels' blocks read in place. Until PR 46 a plane of
+    K/32 = 24, 80 or 448 columns lay with its rows minor and every program
+    copied each stack (`copy -> s16[24,64,1536,80]`, 1.0 to 1.7 GB of
+    temporaries a program in three configurations) before its layer scan.
+
+    What may remain is named (`aot_step.PLANE_PREFETCH`): a `copy-done`
+    whose result lies exactly as the plane is stored, row-major in
+    (8,128)(2,1) tiles, but in the faster memory space `S(1)`. That is XLA's
+    prefetch of a plane small enough for that space (the 512-row head of
+    these compiles, `s16[8,4096,128]` in Mixtral, the dense layers' planes
+    of Laguna and Granite), overlapped with the work before its use; it pads
+    and transposes nothing, and every program but a few has one. A `copy`,
+    a fusion, or a `copy-done` in any other layout is a re-layout."""
+    spec, shapes, cfg = step_model(model)
+    monkeypatch.delenv("DLT_PALLAS_INTERPRET")
+    how = {"t1": {"chunk": 1}, "t8": {"chunk": 8}, "t64": {"chunk": 64},
+           "scan8": {"scan": 8}}[program]
+    text = aot_step.compile_step(spec, shapes, cfg, chip, **how).as_text()
+    assert "tpu_custom_call" in text and "s16[" in text
+    assert aot_step.scale_relayouts(text) == []
+    # the reader sees what it is there to see: the same plane, rows minor
+    assert aot_step.scale_relayouts(
+        "  %copy.61 = s16[24,64,1536,80]{3,2,1,0:T(8,128)(2,1)} copy(%p.1)\n"
+        "  %copy-done.9 = s16[512,80]{0,1:T(8,128)(2,1)S(1)} copy-done(%c)"
+    ) == ["copy -> s16[24,64,1536,80]{3,2,1,0:T(8,128)(2,1)}",
+          "copy-done -> s16[512,80]{0,1:T(8,128)(2,1)S(1)}"]
 
 
 @pytest.mark.parametrize("pool", ["hk8", "kinds"])
