@@ -275,7 +275,7 @@ def run_scenario(keep_engine: bool = False):
 
             from ..cache.wire import decode_blocks, encode_blocks
 
-            bt = eng._kv_bt
+            bt = eng.slot_cache.block_tokens
             p3 = [(13 * i + 2) % V for i in range(bt + 1)]  # 1 full block
             L, _n, hk, _bt, hs = eng._eng.k_cache.shape
             rng = _np.random.default_rng(3)

@@ -58,11 +58,14 @@ __all__ = ["DeviceKVPool", "PagedPrefixCache", "PagedLease",
 
 SCRATCH_BLOCK = 0  # permanent garbage target; never allocated, never read
 
+# REMAPPED, SEED_BYTES, DEMOTE_READS and SETTLE_WAITS are bumped by the cache
+# manager that drives the pool (runtime/slot_cache.py), the rest here
+
 _POOL_BLOCKS = metrics.gauge(
     "paged_kv_pool_blocks", "Device KV pool capacity in blocks (--kv-pool-blocks)")
 _POOL_FREE = metrics.gauge(
     "paged_kv_free_blocks", "Device KV pool blocks currently unallocated")
-_REMAPPED = metrics.counter(
+REMAPPED = metrics.counter(
     "paged_kv_remapped_blocks_total",
     "Directory blocks remapped into a slot's table at admission "
     "(zero-copy prefix reuse — no KV bytes moved)")
@@ -74,11 +77,11 @@ _DEMOTED = metrics.counter(
     "paged_kv_demoted_blocks_total",
     "Directory blocks demoted device->host under pool pressure (into the "
     "unified cache/block_pool.py tier)")
-_DEMOTE_READS = metrics.counter(
+DEMOTE_READS = metrics.counter(
     "paged_kv_demote_reads_total",
     "Device reads issued for demotions (one batched gather a reclaim: reads "
     "over paged_kv_demoted_blocks_total is 1/n for a deficit of n)")
-_SETTLE_WAITS = metrics.counter(
+SETTLE_WAITS = metrics.counter(
     "paged_kv_demote_settle_waits_total",
     "Demotion reads the device had not finished when their rows were needed "
     "as host arrays (a hit, a Q80 compression, close): the settle waited for "
@@ -86,7 +89,7 @@ _SETTLE_WAITS = metrics.counter(
 _PROMOTED = metrics.counter(
     "paged_kv_promoted_blocks_total",
     "Cold directory blocks promoted host->device on a prefix hit")
-_SEED_BYTES = metrics.counter(
+SEED_BYTES = metrics.counter(
     "paged_kv_seed_bytes_total",
     "KV bytes moved host->device at admission seeding (0 for device-tier "
     "hits — the zero-copy remap claim, asserted by the shared-prefix bench; "

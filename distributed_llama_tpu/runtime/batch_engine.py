@@ -72,19 +72,19 @@ Design:
   per-slot caches, the Q80 tier and KV-block streaming refuse such a model.
   A STATE-SPACE layer (ModelSpec.ssm) holds besides such a tail a running
   MATRIX a head that sums every earlier position: no ring holds it, so it is
-  CARRIED. A dispatch is told which slots are live (`_state_word`) and leaves
+  CARRIED. A dispatch is told which slots are live (runtime/slot_cache.py
+  `state_word`) and leaves
   every other slot's matrices bit for bit; a row at position 0 starts from
   zeros; a row over-decoded in a scan is gone with its request; a flushed
   chained scan's survivors go back to the matrices as that scan found them
   (`_flush_inflight`); and snapshots are taken every 256 positions into a
-  pool of `ModelSpec.state_snapshots` entries that blocks are given and lose
-  (cache/device_pool.py SnapshotPool): a prefix hit, a rewind and a resume
-  land on the newest block under them that carries one.
-- CROSS-REQUEST prefix reuse (cache/, docs/PREFIX_CACHE.md): a finished slot's
-  committed prefix is harvested into a radix-indexed block pool; a new request
-  whose prompt shares cached blocks — on ANY slot — seeds its cache rows + pos
-  from the pool and prefills only the uncached suffix. The same-slot rewind
-  above remains as the token-granular (and copy-free) fast path.
+  pool of entries (cache/device_pool.py SnapshotPool): a prefix hit, a rewind
+  and a resume land on the newest block under them that carries one.
+- CROSS-REQUEST prefix reuse (runtime/slot_cache.py, docs/PREFIX_CACHE.md): a
+  finished slot's committed prefix is harvested into a radix-indexed block
+  pool; a new request whose prompt shares cached blocks — on ANY slot —
+  starts from them and prefills only the uncached suffix. The same-slot
+  rewind above remains as the token-granular (and copy-free) fast path.
 """
 
 from __future__ import annotations
@@ -98,12 +98,13 @@ import uuid
 from dataclasses import dataclass, field
 from typing import Callable
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
-from ..cache.block_pool import PendingRows
-from ..models.forward import (STATE_RING, STATE_STRIDE, StateCache,
-                              compact_rows, seed_state)
+from ..cache import warn_degraded
+from ..models.forward import STATE_RING, compact_rows
 from ..models.spec import ModelSpec
 from ..obs import flight, metrics, process, reqctx, trace
 from ..ops.pallas_paged_attention import visited_keys
@@ -115,6 +116,7 @@ from ..resilience.tenancy import (CLASSES, DEFAULT_TENANT, DrainRate,
                                   TenantRegistry, WeightedFairQueue)
 from .engine import PREFILL_CHUNKS, GenerationStats
 from .sampler import Sampler
+from .slot_cache import make_slot_cache, start_host_copy
 from .speculative import (AdaptiveK, NgramProposer, ProposerMux,
                           verify_block_bucket)
 
@@ -172,39 +174,6 @@ _STATE_BYTES = metrics.counter(
     "Bytes a dispatch writes into the state layers' rings and snapshots, "
     "parked rows' scratch writes included: what the second kind of state "
     "costs a step in HBM writes")
-# The third kind (ModelSpec.ssm: a state-space layer's running matrix a head,
-# updated in place by ops/pallas_ssd.py), counted apart so that the counters
-# above keep reading what they read
-_SSM_ROWS = metrics.counter(
-    "batch_ssm_rows_stepped_total",
-    "Live rows a dispatch took through ssd_step (a T = 1 step, each step of "
-    "the K-step scan, a chunk's riders) x state layers")
-_SSM_CHUNK_TOKENS = metrics.counter(
-    "batch_ssm_chunk_tokens_total",
-    "Tokens a dispatch took through ssd_chunk (the prefilling row's chunk) "
-    "x state layers")
-_SSM_BYTES = metrics.counter(
-    "batch_ssm_state_bytes_total",
-    "Bytes of running matrices H a dispatch read and wrote: two a live row "
-    "a layer a step, two a chunk's slot a layer, and a snapshot's read and "
-    "write where a row ended a stride")
-_SSM_STRIDE_ENDS = metrics.counter(
-    "batch_ssm_stride_ends_total",
-    "Stride ends (a position p with (p + 1) % 256 == 0) the rows of the "
-    "dispatches issued crossed: what batch_ssm_snapshots_total is held "
-    "against")
-_SSM_SNAPSHOTS = metrics.counter(
-    "batch_ssm_snapshots_total",
-    "Stride ends for which the dispatch was given an entry of the snapshot "
-    "pool to write the row's state into")
-_STATE_RESTORES = metrics.counter(
-    "paged_kv_state_restores_total",
-    "Admissions (prefix hits and slot rewinds past position 0) that seeded a "
-    "slot's running state from a block's snapshot")
-_STATE_BLOCK_BYTES = metrics.gauge(
-    "kv_pool_state_block_bytes",
-    "Bytes of state snapshot a pool block holds beside its keys and values "
-    "(0: the model has no state layers)")
 # What a dispatch is given against what it needs, from the shapes and the
 # live rows (host integers, nothing read from the device): positions are
 # rows x T (or rows x K of a scan), attention pairs are positions x the
@@ -305,10 +274,6 @@ _DECODE_TOKENS = metrics.counter(
 _REQUESTS = metrics.counter(
     "batch_requests_total", "Completed requests by finish reason",
     labelnames=("finish",))
-_PREFIX_SEEDED = metrics.counter(
-    "batch_prefix_seeded_tokens_total",
-    "Cache rows copied from the prefix-cache pool at admission "
-    "(prompt tokens whose prefill was skipped beyond the same-slot rewind)")
 # Resilience telemetry (docs/ROBUSTNESS.md): every unhappy-path decision the
 # scheduler makes — error blast radius, transient retries, shed admissions,
 # expired deadlines — is a counter, and scheduler liveness is a gauge pair
@@ -358,10 +323,6 @@ _H2D_TRANSFERS = metrics.counter(
     "seeded prefix rows)")
 _H2D_BYTES = metrics.counter(
     "batch_h2d_bytes_total", "Bytes of those host-to-device copies")
-_TABLE_UPLOADS = metrics.counter(
-    "batch_table_uploads_total",
-    "Dispatches that re-sent the whole (slots, blocks a context) block "
-    "table because a row of it was edited since the last one")
 _D2H_BYTES = metrics.counter(
     "batch_d2h_bytes_total",
     "Bytes of dispatch results fetched to the host: a step's logits (two "
@@ -486,68 +447,6 @@ _CONSTRAIN_DEGRADED = metrics.counter(
     labelnames=("reason",))
 
 
-# Donated single-block pool updates (docs/PAGED_KV.md copy-on-write and
-# cold promotion): an eager `pool.at[:, b].set(...)` would materialize a
-# whole new pool array per block touched — O(pool) HBM traffic and 2x peak
-# memory. Donating the pool lets XLA update the one block in place.
-import jax  # noqa: E402  (after the module docstring's import block)
-from jax.sharding import NamedSharding, PartitionSpec  # noqa: E402
-
-_pool_block_copy = jax.jit(lambda c, src, dst: c.at[:, dst].set(c[:, src]),
-                           donate_argnums=(0,))
-_pool_block_set = jax.jit(lambda c, dst, rows: c.at[:, dst].set(rows),
-                          donate_argnums=(0,))
-
-
-
-def _pool_sides(eng) -> tuple:
-    """The engine's arrays that are indexed by pool block on axis 1: keys,
-    values and, of a model with state layers, the blocks' state snapshots
-    (the typed block payload, docs/PAGED_KV.md)."""
-    vc = eng.v_cache
-    if isinstance(vc, StateCache):
-        # a state-space model's snapshots lie in a pool of their own, by
-        # entry and not by block (cache/device_pool.py SnapshotPool)
-        return (eng.k_cache, vc.rows) + (() if vc.h is not None
-                                         else (vc.snaps,))
-    return eng.k_cache, vc
-
-
-def _set_pool_sides(eng, sides) -> None:
-    eng.k_cache = sides[0]
-    vc = eng.v_cache
-    if isinstance(vc, StateCache):
-        eng.v_cache = vc._replace(rows=sides[1], **(
-            {"snaps": sides[2]} if len(sides) > 2 else {}))
-    else:
-        eng.v_cache = sides[1]
-
-
-# The prefix cache's demotion reads a reclaim's victims with ONE gather from
-# every side of the pool, (n, L, hk, bt, w) a side, block-major so that a
-# block's rows are one contiguous piece of the host copy. n is one of a few
-# fixed sizes (a shorter list of ids is filled with the scratch block, a
-# longer one is cut into eights), so every program it can need is known
-# beforehand: BatchEngine._read_block compiles them all.
-_DEMOTE_SIZES = (1, 2, 4, 8)
-
-
-@jax.jit
-def _pool_gather(sides, ids):
-    return tuple(jnp.swapaxes(c[:, ids], 0, 1) for c in sides)
-
-
-def _start_host_copy(*arrays) -> None:
-    """Begin the arrays' device->host copies without waiting for them, so
-    that a later np.asarray picks the buffers up. A hint only: e.g. a
-    sharded array may refuse the whole-array async copy."""
-    for a in arrays:
-        try:
-            a.copy_to_host_async()
-        except Exception:
-            pass
-
-
 def _upload(host, dtype=None, sharding=None):
     """A host value onto the device for a dispatch, counted: every copy the
     scheduler makes towards the device goes through here. `sharding`: the
@@ -562,121 +461,6 @@ def _upload(host, dtype=None, sharding=None):
     _H2D_TRANSFERS.inc()
     _H2D_BYTES.inc(a.nbytes)
     return a
-
-
-class _DemoteRead:
-    """One reclaim's read of its victims off the device (docs/PAGED_KV.md
-    "Eviction"). The directory asks for a block at a time while it chooses
-    (`block`), every answer a pending row of this read; `issue` then
-    enqueues the gather, before the dispatch that will write the freed
-    blocks, so the device's own order keeps the rows intact, and starts the
-    copy to the host without waiting for it; `settle` makes host arrays of
-    it, once, wherever the rows are first needed."""
-
-    def __init__(self, pool):
-        """`pool`: the engine's arrays by block (`_pool_sides`: K, V and a
-        model with state layers' snapshots), (L, N, ...) a side; what a
-        block of them looks like is kept, the arrays are not (the next
-        dispatch donates them)."""
-        k = pool[0]
-        self.shape = k.shape[:1] + k.shape[2:]  # a block's K side
-        self.dtype = np.dtype(k.dtype)
-        self.nbytes = sum(c.nbytes // c.shape[1] for c in pool)  # a block's
-        self._widths = tuple(c.shape[-1] for c in pool)  # 0: an empty side
-        self.bids: list[int] = []
-        self._parts = None  # a gather each: the non-empty sides, on the device
-        self._host = None  # a gather each: (k, v) host arrays (n, L, hk, bt, w)
-        self._error: Exception | None = None
-        self._lock = threading.Lock()  # guards: _host, _error, _parts (two threads may settle: the scheduler, and an importer's whose Q80 put compresses a pending block)
-
-    def block(self, bid: int) -> "_DemotedRows":
-        self.bids.append(bid)
-        return _DemotedRows(self, len(self.bids) - 1)
-
-    def issue(self, pool) -> int:
-        """Enqueue the gathers (one, unless there are more than eight
-        victims) over `pool`, the engine's (K, V) arrays, and start their
-        host copies; returns how many. A failure here is the read's: every
-        block of it is dropped when settled."""
-        top = _DEMOTE_SIZES[-1]
-        sides = tuple(c for c in pool if c.shape[-1])
-        parts, error = [], None
-        try:
-            for lo in range(0, len(self.bids), top):
-                ids = self.bids[lo:lo + top]
-                n = next(z for z in _DEMOTE_SIZES if z >= len(ids))
-                out = _pool_gather(
-                    sides, np.asarray(ids + [0] * (n - len(ids)), np.int32))
-                _start_host_copy(*out)
-                parts.append(out)
-        except Exception as e:
-            error = e
-        with self._lock:
-            self._parts, self._error = parts, error
-        return len(parts)
-
-    def ready(self) -> bool:
-        with self._lock:
-            parts = self._parts
-        if parts is None:
-            return False  # not issued yet
-        return all(a.is_ready() for out in parts for a in out)
-
-    def settle(self) -> list:
-        ready = self.ready()
-        with self._lock:
-            if self._error is not None:
-                raise self._error
-            if self._host is None:
-                if self._parts is None:
-                    raise RuntimeError("demotion read settled before issue")
-                if not ready:  # whoever asks (a hit, Q80, close) waits
-                    from ..cache.device_pool import _SETTLE_WAITS
-
-                    _SETTLE_WAITS.inc()
-                try:
-                    host = []
-                    for out in self._parts:
-                        # the demotion's one device->host copy, started
-                        # at issue: picked up here, where the host waits
-                        got = [np.asarray(a) for a in out]
-                        # a latent row's empty second side, by K's shape
-                        void = np.zeros(got[0].shape[:-1] + (0,), got[0].dtype)
-                        rest = iter(got)
-                        host.append(tuple(next(rest) if w else void
-                                          for w in self._widths))
-                except Exception as e:
-                    self._error = e
-                    raise
-                finally:
-                    self._parts = []  # the device copies can go
-                self._host = host
-            return self._host
-
-
-class _DemotedRows(PendingRows):
-    """Row `i` of a _DemoteRead: one block's pending (K, V) rows, what the
-    cold tier holds in place of arrays until they are settled."""
-
-    __slots__ = ("read", "i", "shape", "dtype", "nbytes")
-
-    def __init__(self, read: _DemoteRead, i: int):
-        self.read, self.i = read, i
-        self.shape, self.dtype, self.nbytes = read.shape, read.dtype, read.nbytes
-
-    def ready(self) -> bool:
-        return self.read.ready()
-
-    def settle(self):
-        part, row = divmod(self.i, _DEMOTE_SIZES[-1])
-        # (k, v), and the block's state snapshot where the pool has one
-        return tuple(a[row] for a in self.read.settle()[part])
-
-
-_NO_KV_STREAM = (
-    "a model with state layers (a gated short convolution) is not supported "
-    "by KV-block streaming between replicas (cache/wire.py): a block's "
-    "state snapshot does not travel with its keys and values")
 
 
 class _StaleEpoch(BaseException):
@@ -810,10 +594,8 @@ class _Slot:
         # prefix-cache lease pinning the blocks this slot was seeded from
         # (released at _finish; shrunk when history is truncated)
         self.lease = None
-        # device-pool block table (paged KV, docs/PAGED_KV.md): pool block
-        # ids backing virtual positions [0, len(blocks)*bt); one pool ref
-        # held per entry. Retained across requests like `history` — the
-        # same-slot rewind's backing store.
+        # device-pool block table (slot_cache.PoolSlotCache): the pool block
+        # ids backing positions [0, len(blocks)*bt), one pool ref an entry
         self.blocks: list[int] = []
         self.admit_t = 0.0  # monotonic admission time (dispatch watchdog)
         # last_token is sampled/delivered but its KV not yet written: a
@@ -1029,39 +811,6 @@ class BatchEngine:
                  or [spec.n_heads] * spec.n_layers)
         for h, w in zip(heads, wins):
             _ATTN_HEADS.labels(kind="window" if w else "full").set(h)
-        self.kv_pool = None  # DeviceKVPool metadata (None = dense layout)
-        self._kv_bt = 0
-        self._demote_warm = False  # _read_block compiled the gather's sizes
-        if self._eng.kv_pool is not None:
-            from ..cache.device_pool import DeviceKVPool
-
-            n_blocks, self._kv_bt = self._eng.kv_pool
-            self.kv_pool = DeviceKVPool(n_blocks, self._kv_bt)
-            self._kv_w = spec.seq_len // self._kv_bt
-            self._tables_np = np.zeros((slots, self._kv_w), np.int32)
-            self._tables_dev = None  # rebuilt lazily after table edits
-            # what a demoted block's rows weigh on the way to the host
-            self._kv_block_bytes = sum(
-                c.nbytes // c.shape[1] for c in _pool_sides(self._eng))
-            _STATE_BLOCK_BYTES.set(spec.state_block_bytes(
-                self._eng.k_cache.dtype.itemsize) if spec.mixed else 0)
-            # positions between two snapshots of the state layers: a
-            # convolution's at every block end, a state-space model's where
-            # the cache manager gives the block an entry of its pool
-            self._stride = 0 if not spec.mixed else (
-                STATE_STRIDE if spec.ssm else self._kv_bt)
-            if spec.ssm:
-                from ..cache.device_pool import SnapshotPool
-
-                assert STATE_STRIDE % self._kv_bt == 0, self._kv_bt
-                self.kv_pool.snapshots = SnapshotPool(
-                    spec.state_snapshots, spec.state_block_bytes(
-                        self._eng.k_cache.dtype.itemsize))
-        # admission seeding cost readout (bench.py shared-prefix columns):
-        # host→device KV bytes moved and wall time spent seeding slots —
-        # ~0 bytes on the paged path (remap), the full fetched span dense
-        self.seed_bytes = 0
-        self.seed_ms = 0.0
         self.spec = spec
         self.tokenizer = tokenizer
         self.superstep = superstep  # K: decode steps fused per device dispatch
@@ -1084,6 +833,14 @@ class BatchEngine:
         self._last_ready_t: float | None = None  # perf_counter of last results
         self._gap_t: float | None = None  # last dispatch-ready time, gap metric
         self._slots = [_Slot(i) for i in range(slots)]
+        # where a slot's cache lives: the device block pool with its radix
+        # directory, or the dense per-slot rows with the host prefix cache
+        self.slot_cache = make_slot_cache(
+            self._eng, spec, self._slots, _upload,
+            prefix_cache=prefix_cache, blocks=prefix_cache_blocks,
+            block_tokens=prefix_block_tokens, q80=prefix_cache_q80)
+        self.kv_pool = self.slot_cache.kv_pool  # None: the dense layout
+        self.prefix_cache = self.slot_cache.prefix_cache
         self._queue: "queue.Queue[BatchRequest]" = queue.Queue()
         # Multi-tenant policy (docs/SERVING.md "Multi-tenant serving"):
         # `tenants` configures per-tenant quotas + fair-share weights (None
@@ -1222,37 +979,6 @@ class BatchEngine:
         self.retry_backoff = retry_backoff
         self._last_dispatch_t: float | None = None  # monotonic, watchdog
         _DISPATCH_AGE.set_function(self._dispatch_age)
-        # Cross-request prefix cache (cache/): pass False to disable, True for
-        # defaults, or a ready PrefixCache instance to share one across
-        # engines. Host/disc-spill paged engines are excluded — their ring
-        # layout has no plain [0, n) row prefix to seed. In device-pool mode
-        # the cache is the radix DIRECTORY over device blocks
-        # (cache/device_pool.py): hits remap block tables instead of copying
-        # rows, and its cold tier is the same host KVBlockPool the dense
-        # cache used (one unified demotion path, docs/PAGED_KV.md).
-        self.prefix_cache = None
-        if self.kv_pool is not None:
-            if prefix_cache:
-                from ..cache import default_pool_blocks
-                from ..cache.device_pool import PagedPrefixCache
-
-                hk = self._eng.k_cache.shape[2]
-                cold = prefix_cache_blocks or default_pool_blocks(
-                    (spec.n_layers, slots, hk, spec.seq_len,
-                     spec.head_size),
-                    self._eng.k_cache.dtype.itemsize, self._kv_bt, slots,
-                    token_values=sum(spec.cache_widths))
-                self.prefix_cache = PagedPrefixCache(
-                    self.kv_pool, self._kv_bt, cold_blocks=cold,
-                    q80=prefix_cache_q80)
-        elif not self._eng.paged:
-            from ..cache import make_prefix_cache
-
-            self.prefix_cache = make_prefix_cache(
-                self._eng.k_cache.shape, self._eng.k_cache.dtype.itemsize,
-                slots=slots, prefix_cache=prefix_cache,
-                blocks=prefix_cache_blocks, block_tokens=prefix_block_tokens,
-                q80=prefix_cache_q80)
         _SLOTS_TOTAL.set(slots)
 
     @classmethod
@@ -1314,8 +1040,8 @@ class BatchEngine:
         if klass not in CLASSES:
             raise InvalidRequest(
                 f"unknown scheduling class {klass!r} (want one of {CLASSES})")
-        if export_kv and self.spec.mixed:
-            raise InvalidRequest(_NO_KV_STREAM)
+        if export_kv and self.slot_cache.no_stream:
+            raise InvalidRequest(self.slot_cache.no_stream)
         c = ctx if ctx is not None else reqctx.current()
         tenant = tenant or (c.tenant if c is not None else "") \
             or DEFAULT_TENANT
@@ -1500,6 +1226,20 @@ class BatchEngine:
     def draining(self) -> bool:
         return self._draining and not self._shutdown
 
+    # admission seeding readouts (/v1/stats): host→device KV bytes, wall ms
+    seed_bytes = property(lambda self: self.slot_cache.seed_bytes)
+    seed_ms = property(lambda self: self.slot_cache.seed_ms)
+
+    def import_kv_blocks(self, tokens: list[int], blocks: list) -> int:
+        """Adopt externally-shipped HOST KV blocks into the prefix cache
+        (docs/DISAGG.md; `slot_cache.import_blocks`), from any thread.
+        Returns the token span the cache now covers."""
+        return self.slot_cache.import_blocks(tokens, blocks)
+
+    def _read_block(self, bid: int):
+        """benchmark/run.py's warm-up calls it by this name (read_block)."""
+        return self.slot_cache.read_block(bid)
+
     def scheduler_alive(self) -> bool:
         """True while the scheduler thread can serve (running, or not yet
         lazily started). False only after the thread died — the /healthz
@@ -1630,19 +1370,16 @@ class BatchEngine:
         with self._plock:
             # fresh slot objects FIRST: the abandoned thread's locals hold
             # refs to the old list, so nothing it does can reach new requests
-            self._slots = [_Slot(i) for i in range(self.slots_n)]
+            self._slots = self.slot_cache.slots = [
+                _Slot(i) for i in range(self.slots_n)]
             # constraint table regions were keyed by the old slots; drop the
             # whole table (re-created lazily at the next constrained
             # admission) rather than freeing per-row under a wedged epoch
             self.constrain_table = None
             _CONSTRAIN_ROWS.set(0)
             for s in old_slots:
-                if self.prefix_cache is not None and s.lease is not None:
-                    self.prefix_cache.release(s.lease)
-                    s.lease = None
-                if self.kv_pool is not None and s.blocks:
-                    self.kv_pool.decref(s.blocks)
-                    s.blocks = []
+                self.slot_cache.unpin(s)
+                self.slot_cache.release(s)
                 req = s.req
                 s.req = None
                 s.pending = []
@@ -1685,14 +1422,7 @@ class BatchEngine:
                     # a zombie may still hold (and have donated) the
                     # drafter's buffers — fresh caches, programs, row state
                     self.drafter.reset_backend()
-                if self.kv_pool is not None:
-                    # fresh pool arrays: every allocation and directory
-                    # handle referenced the replaced buffers
-                    self.kv_pool.reset()
-                    if self.prefix_cache is not None:
-                        self.prefix_cache.reset()
-                    self._tables_np[:] = 0
-                    self._tables_dev = None
+                self.slot_cache.reset()
             except Exception as e:
                 ok = False
                 print(f"🔴 backend re-initialization failed: {e!r}")
@@ -1732,7 +1462,7 @@ class BatchEngine:
             # the directory outlives the device arrays: what it holds of
             # them as pending reads becomes host arrays now (not behind a
             # scheduler that is still stuck in a dispatch)
-            self._settle_demotions(force=True)
+            self.slot_cache.settle(force=True)
         # detach the watchdog callback IF it is still ours (a later engine
         # may have claimed the gauge): a bound method left on the
         # module-global gauge would pin this engine's params + KV caches
@@ -1749,9 +1479,7 @@ class BatchEngine:
         err = EngineClosed("BatchEngine closed")
         with self._plock:
             for s in self._slots:
-                if self.prefix_cache is not None and s.lease is not None:
-                    self.prefix_cache.release(s.lease)
-                    s.lease = None
+                self.slot_cache.unpin(s)
                 req = s.req
                 if req is not None and not req.done.is_set():
                     req.error = err
@@ -1786,11 +1514,10 @@ class BatchEngine:
 
     def _assign(self, req: BatchRequest) -> _Slot | None:
         """Place a request on the free slot with the longest common token prefix
-        (the multi-slot generalization of the reference NaiveCache), then try
-        to extend the reuse from the cross-request prefix cache: when the radix
-        index covers more of the prompt than the slot's own history, the extra
-        rows are copied in from the block pool and prefill starts at the seeded
-        position (docs/PREFIX_CACHE.md).
+        (the multi-slot generalization of the reference NaiveCache), the
+        reuse extended from the cross-request prefix cache where the radix
+        index covers more of the prompt than the slot's own history
+        (`slot_cache.admit`, docs/PREFIX_CACHE.md).
 
         A PREEMPTED request (req.out non-empty: a batch row displaced by an
         interactive admission, docs/SERVING.md "Multi-tenant serving")
@@ -1815,40 +1542,7 @@ class BatchEngine:
                 n += 1
             return min(n, len(full) - 1)
         best = max(free, key=common)
-        rewind = common(best)
-        if self.spec.mixed:
-            # a state layer's state is not a list of positions: a rewind
-            # lands where a snapshot exists (a block end; of a state-space
-            # model the newest block under it that carries one), and
-            # prefill goes on from there
-            rewind = self._state_landing(
-                rewind, lambda i: best.blocks[i] if i < len(best.blocks)
-                else None)
-        reuse = rewind
-        if self.kv_pool is not None:
-            # paged admission (docs/PAGED_KV.md): the radix directory hit
-            # is a refcounted block-table remap, not a row copy — bind the
-            # request's context so the batch.prefix_seed span attributes
-            with reqctx.use(req.ctx):
-                reuse = self._paged_adopt(best, req, rewind, full)
-                if self.spec.mixed and reuse:
-                    # continue from the snapshot of the block that ends there
-                    try:
-                        self._seed_state(
-                            best.index,
-                            best.blocks[reuse // self._kv_bt - 1], reuse)
-                        _STATE_RESTORES.inc()
-                    except LookupError:  # its entry went meanwhile: cold
-                        reuse = self._paged_adopt_rewind_only(best, 0)
-        elif self.prefix_cache is not None:
-            # [0, reuse) is served by the slot's own resident rows; anything
-            # the radix seed adds on top is counted as hit_tokens inside.
-            # Cross-thread trace re-entry: the seed runs on the scheduler
-            # thread but belongs to THIS request — bind its context so the
-            # batch.prefix_seed span carries the request's trace id.
-            self.prefix_cache.note_resident(reuse)
-            with reqctx.use(req.ctx):
-                reuse = self._seed_from_cache(best, req, reuse, full)
+        rewind, reuse = self.slot_cache.admit(best, req, full, common(best))
         best.admit_t = time.monotonic()  # before .req: the watchdog keys on req
         best.req = req
         best.pos = reuse
@@ -1972,443 +1666,6 @@ class BatchEngine:
             flight.event(slot.req.rid, "constrain_degraded", reason=reason,
                          grammar=sc.ghash)
 
-    def _seed_from_cache(self, slot: _Slot, req: BatchRequest,
-                         reuse: int, full: list[int] | None = None) -> int:
-        """Consult the radix index for the admission prompt (`full` =
-        prompt ⊕ preemption-delivered tokens; defaults to req.prompt); when
-        it beats the same-slot rewind, scatter the pool blocks' rows into
-        the slot's cache rows [reuse, n) and return the seeded length n
-        (the new prefill start). The acquired lease stays on the slot until
-        _finish (eviction must respect in-flight slots); seeding failures
-        fall back to plain prefill — the cache is an optimization, never a
-        correctness gate."""
-        if full is None:
-            full = req.prompt
-        try:
-            faults.fire("batch.cache_seed", slot=slot.index)
-            lease = self.prefix_cache.lookup(full,
-                                             cap=self.spec.seq_len - 1)
-            if lease is None:
-                return reuse
-            if lease.tokens <= reuse:
-                self.prefix_cache.mark_unused(lease)
-                return reuse
-        except Exception as e:
-            # a raising radix lookup (or injected seed fault) must cost only
-            # the cache win — NOT escape into the scheduler loop, where it
-            # would fail every in-flight request and leave this one queued
-            from ..cache import warn_degraded
-
-            warn_degraded("lookup", e)
-            return reuse
-        eng = self._eng
-        n = lease.tokens
-        t0 = time.perf_counter()
-        try:
-            with trace.span("batch.prefix_seed",
-                            {"slot": slot.index, "tokens": n,
-                             "rewind": reuse}):
-                # fetch only the span the rewind doesn't already hold, as ONE
-                # contiguous (2, L, hk, n-reuse, hs) buffer: a single
-                # host->device transfer and one scatter per cache tensor
-                # (previously: contiguize + upload + scatter per K/V half)
-                rows = _upload(
-                    self.prefix_cache.fetch_packed(lease, skip=reuse),
-                    eng.dtype)
-                eng.k_cache = eng.k_cache.at[:, slot.index, :, reuse:n, :].set(
-                    rows[0])
-                eng.v_cache = eng.v_cache.at[:, slot.index, :, reuse:n, :].set(
-                    rows[1])
-        except Exception as e:
-            self.prefix_cache.mark_unused(lease)
-            from ..cache import warn_degraded
-
-            warn_degraded("seed", e)  # fall back to full prefill
-            return reuse
-        # host→device KV bytes this admission moved (the scatter baseline
-        # the paged remap path eliminates — bench.py shared-prefix columns)
-        self.seed_bytes += int(rows.nbytes)
-        self.seed_ms += (time.perf_counter() - t0) * 1e3
-        slot.lease = lease
-        self.prefix_cache.mark_seeded(lease, n - reuse)
-        _PREFIX_SEEDED.inc(n - reuse)
-        return n
-
-    # ------------------------------------------------------------------
-    # device-resident paged KV (docs/PAGED_KV.md)
-    # ------------------------------------------------------------------
-
-    def _tables(self):
-        """Current (B, W) device block table, once a dispatch. Re-uploaded
-        WHOLE after a table edit (`_table_row`), never patched: slots x the
-        blocks of a context x 4 bytes of metadata (8 or 16 KB in the
-        benchmark's cells); batch_table_uploads_total counts the dispatches
-        that re-sent it, most of them while every row gains a block every
-        few tokens (the counted shares: PERF.md section 5). What it costs
-        is the transfer, not the bytes. Never KV rows."""
-        if self._tables_dev is None:
-            self._tables_dev = _upload(self._tables_np)
-            _TABLE_UPLOADS.inc()
-        return self._tables_dev
-
-    def _table_row(self, slot: _Slot) -> None:  # hot-path
-        """Rewrite one slot's table row from slot.blocks (filler entries
-        point at the scratch block, whose contents are never read)."""
-        row = self._tables_np[slot.index]
-        row[:] = 0
-        row[:len(slot.blocks)] = slot.blocks
-        self._tables_dev = None
-
-    def _paged_release_slot(self, slot: _Slot) -> None:
-        """Drop a slot's whole table (and the rewind stock it backs). The
-        committed full blocks live on through any directory references."""
-        if slot.blocks:
-            self.kv_pool.decref(slot.blocks)
-        slot.blocks = []
-        slot.history = []
-        slot.pos = 0
-        self._table_row(slot)
-
-    def _paged_alloc(self, n: int, exclude: _Slot | None = None) -> list[int]:
-        """Allocate n pool blocks, reclaiming directory/idle-slot stock
-        under pressure; raises KVPoolExhausted (request-scope) when the
-        pool genuinely cannot serve. `exclude` shields one slot from the
-        idle-slot reclaim tier — the ADOPTING slot looks idle (req is
-        bound only after _paged_adopt returns), and releasing it mid-adopt
-        would double-free the very blocks being rewired."""
-        ids = self.kv_pool.alloc(n)
-        if ids is None:
-            self._paged_reclaim(n, exclude=exclude)
-            ids = self.kv_pool.alloc(n)
-        if ids is None:
-            from ..cache.device_pool import KVPoolExhausted
-
-            raise KVPoolExhausted(
-                f"device KV pool exhausted: {n} block(s) needed, "
-                f"{self.kv_pool.free_blocks()} free after reclaim "
-                "(raise --kv-pool-blocks or admit fewer long contexts)")
-        return ids
-
-    def _paged_reclaim(self, need: int, exclude: _Slot | None = None) -> None:
-        """Free device blocks: demote/evict LRU unreferenced directory
-        nodes first (cold tier keeps the prefix servable), then drop idle
-        slots' retained rewind tables — their committed blocks survive via
-        the directory where it references them. `exclude` (see
-        _paged_alloc) is never released. Only the DEFICIT is reclaimed:
-        demoting `need` blocks when all but one are already free would
-        churn the directory (and its D2H copies) for nothing."""
-        deficit = need - self.kv_pool.free_blocks()
-        if deficit <= 0:
-            return
-        if self.prefix_cache is not None:
-            self._demote(deficit)
-        if self.kv_pool.free_blocks() >= need:
-            return
-        for sl in self._slots:
-            if sl.req is None and sl.blocks and sl is not exclude:
-                self._paged_release_slot(sl)
-                if self.kv_pool.free_blocks() >= need:
-                    return
-
-    def _demote(self, deficit: int) -> None:
-        """Have the directory demote (or evict) `deficit` blocks. The
-        victims' rows are read by ONE gather, enqueued here, ahead of
-        whatever dispatch will write the freed blocks, and NOT waited for:
-        the cold tier holds the pending read and _settle_demotions makes
-        host arrays of it while a later dispatch runs."""
-        pool = _pool_sides(self._eng)
-        read = _DemoteRead(pool)
-        with trace.span("batch.demote") as sp:
-            self.prefix_cache.reclaim(deficit, read.block)
-            reads = read.issue(pool) if read.bids else 0
-            sp.add(blocks=len(read.bids), reads=reads)
-            if len(pool) > 2:  # of the bytes read, the snapshots'
-                sp.add(state_bytes=len(read.bids) * pool[2].nbytes
-                       // pool[2].shape[1])
-        if reads:
-            from ..cache.device_pool import _DEMOTE_READS
-
-            _DEMOTE_READS.inc(reads)
-
-    def _settle_demotions(self, force: bool = False) -> None:
-        """Where the scheduler only waits (a dispatch launched and not yet
-        fetched; idle; close): pending demotions whose read has finished
-        become host arrays. Never raises: the cache is an optimization."""
-        pc = self.prefix_cache
-        if self.kv_pool is None or pc is None or not pc.unsettled:
-            return
-        with trace.span("batch.demote_settle") as sp:
-            try:
-                blocks, waited = pc.settle(force)
-                sp.add(blocks=blocks, waited=waited)
-            except Exception as e:
-                from ..cache import warn_degraded
-
-                warn_degraded("demotion", e)
-
-    def _read_block(self, bid: int):
-        """Device→host copy of one pool block's rows (L, hk, bt, hs), waited
-        for: the disaggregation export's read. It goes through the
-        demotion's gather, and its first call on an engine compiles that
-        gather at every size a reclaim can issue, so that no eviction ever
-        compiles in the middle of serving (the benchmark's warm-up calls it
-        for that)."""
-        pool = _pool_sides(self._eng)
-        if not self._demote_warm:
-            self._demote_warm = True
-            for n in _DEMOTE_SIZES[1:]:
-                warm = _DemoteRead(pool)
-                warm.bids = [0] * n
-                warm.issue(pool)
-                warm.settle()
-            if self.spec.mixed:
-                # an admission's seed of a slot's running state, compiled
-                # here too: what it writes (the scratch block's snapshot
-                # behind slot 0's position bt) no sequence reads, a slot's
-                # ring being trusted only where its own sequence wrote it
-                # or an admission seeded it
-                self._seed_state(0, 0, self._stride, entry=0)
-        read = _DemoteRead(pool)
-        rows = read.block(bid)
-        read.issue(pool)
-        return rows.settle()
-
-    def _paged_ensure(self, slot: _Slot, upto: int) -> None:
-        """Grow the slot's table so every position < upto has a real block
-        (writes beyond coverage would land in the scratch block — fine for
-        parked garbage, fatal for committed rows)."""
-        need = -(-min(upto, self.spec.seq_len) // self._kv_bt) \
-            - len(slot.blocks)
-        if need <= 0:
-            return
-        ids = self._paged_alloc(need, exclude=slot)
-        start = len(slot.blocks)
-        slot.blocks.extend(ids)
-        self._tables_np[slot.index, start:start + need] = ids
-        self._tables_dev = None
-
-    def _paged_cow(self, slot: _Slot, lo: int, hi: int) -> None:
-        """Copy-on-write: make the blocks backing positions [lo, hi)
-        exclusively owned before the slot writes there. A shared block
-        (directory reference or a sibling slot's remap) gets a private
-        device-side copy — a D2D transfer, zero host bytes — so the shared
-        copy's committed rows can never be scribbled on."""
-        bt = self._kv_bt
-        eng = self._eng
-        for idx in range(lo // bt, min(-(-hi // bt), len(slot.blocks))):
-            bid = slot.blocks[idx]
-            if not self.kv_pool.shared(bid):
-                continue
-            nb = self._paged_alloc(1, exclude=slot)[0]
-            _set_pool_sides(eng, [_pool_block_copy(c, bid, nb)
-                                  for c in _pool_sides(eng)])
-            self.kv_pool.decref([bid])
-            self.kv_pool.note_cow()
-            slot.blocks[idx] = nb
-            self._tables_np[slot.index, idx] = nb
-            self._tables_dev = None
-
-    def _paged_adopt(self, slot: _Slot, req: BatchRequest, rewind: int,
-                     full: list[int]) -> int:
-        """Paged admission seeding: extend the same-slot rewind with a
-        DIRECTORY REMAP — shared full blocks are increfed into the slot's
-        table (zero bytes moved), a partially-used boundary block is CoW'd
-        so the slot can append, and cold (demoted) blocks pay exactly one
-        host→device promotion upload. Returns the reuse length (the prefill
-        start). Mirrors _seed_from_cache's degraded-mode contract: any
-        failure falls back to what the rewind already covered."""
-        from ..cache.device_pool import _REMAPPED, _SEED_BYTES
-
-        bt = self._kv_bt
-        pc = self.prefix_cache
-        eng = self._eng
-        t0 = time.perf_counter()
-        lease = None
-        if pc is not None:
-            pc.note_resident(rewind)
-            try:
-                faults.fire("batch.cache_seed", slot=slot.index)
-                lease = pc.lookup(full, cap=self.spec.seq_len - 1)
-                if lease is not None and self.spec.mixed:
-                    # whole blocks alone: a hit lands on a block's snapshot
-                    pc.shrink(lease, self._state_landing(
-                        lease.tokens, lambda i: lease.nodes[i].handle[1]
-                        if lease.nodes[i].handle[0] == "dev" else None))
-                if lease is not None and lease.tokens <= rewind:
-                    pc.mark_unused(lease)
-                    lease = None
-            except Exception as e:
-                from ..cache import warn_degraded
-
-                warn_degraded("lookup", e)
-                lease = None
-        if lease is None:
-            # rewind-only: trim the retained table to the rewound prefix
-            # and make its boundary block writable (the first append lands
-            # at `rewind`, possibly inside a directory-shared block)
-            reuse = self._paged_adopt_rewind_only(slot, rewind)
-            self.seed_ms += (time.perf_counter() - t0) * 1e3
-            return reuse
-        n = lease.tokens
-        m, part = n // bt, n % bt
-        blocks: list[int] = []
-        moved = 0
-        try:
-            with trace.span("batch.prefix_seed",
-                            {"slot": slot.index, "tokens": n,
-                             "rewind": rewind, "remap": True}):
-                for i, node in enumerate(lease.nodes):
-                    tier, h = node.handle
-                    if tier == "cold":
-                        # promote: one host→device upload, then the
-                        # directory itself holds the device copy again.
-                        # promote() takes the DIRECTORY's own ref — drop
-                        # the allocation ref right after, or every
-                        # promotion leaks one never-freeable block
-                        # (k, v), and the block's state snapshot with them
-                        rows = pc.fetch_cold(h)
-                        nb = self._paged_alloc(1, exclude=slot)[0]
-                        _set_pool_sides(eng, [
-                            _pool_block_set(c, nb, _upload(a, eng.dtype))
-                            for c, a in zip(_pool_sides(eng), rows,
-                                            strict=True)])
-                        moved += sum(a.nbytes for a in rows)
-                        pc.promote(node, nb)
-                        self.kv_pool.decref([nb])
-                        tier, h = node.handle
-                    if i < m:
-                        self.kv_pool.incref([h])
-                        blocks.append(h)
-                    else:
-                        # partial boundary block: private copy (D2D) the
-                        # slot can append into without touching the
-                        # directory's committed rows
-                        nb = self._paged_alloc(1, exclude=slot)[0]
-                        _set_pool_sides(eng, [_pool_block_copy(c, h, nb)
-                                              for c in _pool_sides(eng)])
-                        self.kv_pool.note_cow()
-                        blocks.append(nb)
-        except Exception as e:
-            if blocks:
-                self.kv_pool.decref(blocks)
-            pc.mark_unused(lease)
-            from ..cache import warn_degraded
-
-            warn_degraded("seed", e)  # fall back to the rewind stock
-            self.seed_ms += (time.perf_counter() - t0) * 1e3
-            return self._paged_adopt_rewind_only(slot, rewind)
-        old = slot.blocks
-        slot.blocks = blocks
-        if old:
-            self.kv_pool.decref(old)
-        self._table_row(slot)
-        slot.lease = lease
-        pc.mark_seeded(lease, n - rewind)
-        _PREFIX_SEEDED.inc(n - rewind)
-        _REMAPPED.inc(m)
-        if moved:
-            _SEED_BYTES.inc(moved)
-            self.seed_bytes += moved
-        self.seed_ms += (time.perf_counter() - t0) * 1e3
-        return n
-
-    def _paged_adopt_rewind_only(self, slot: _Slot, rewind: int) -> int:
-        """Degraded-seed fallback: keep only the rewound prefix's blocks."""
-        bt = self._kv_bt
-        keep = min(-(-rewind // bt), len(slot.blocks))
-        if keep < len(slot.blocks):
-            self.kv_pool.decref(slot.blocks[keep:])
-            del slot.blocks[keep:]
-            self._table_row(slot)
-        if rewind % bt:
-            self._paged_cow(slot, rewind, rewind + 1)
-        return rewind
-
-    def _seed_state(self, slot: int, bid: int, pos: int,
-                    entry: int | None = None) -> None:
-        """A model with state layers: slot `slot`'s running state at position
-        `pos` (a block boundary > 0: a prefix hit's or a rewind's) becomes
-        what block `bid`, which ends there, snapshot: one small jitted copy
-        on the device (models/forward.py seed_state). A state-space model's
-        snapshot lies at the block's ENTRY of the snapshot pool (`entry`:
-        given by the warm-up alone); LookupError where the block has none."""
-        eng = self._eng
-        if self.spec.ssm:
-            bid = (entry if entry is not None
-                   else self.kv_pool.snapshots.entry(bid))
-            if bid is None:
-                raise LookupError("the block carries no snapshot")
-        eng.v_cache = seed_state(eng.v_cache, np.int32(slot), np.int32(bid),
-                                 np.int32(pos), len(self.spec.state_layers),
-                                 self.spec.state_rows)
-
-    def _state_landing(self, tokens: int, block_at) -> int:
-        """The longest prefix of `tokens` positions a state model can
-        continue from: a multiple of the stride whose last block carries a
-        snapshot; 0 with none. `block_at(i)`: the device block that holds
-        positions [i bt, (i + 1) bt) of the match, None where it has none."""
-        n = tokens - tokens % self._stride
-        if not self.spec.ssm:
-            return n  # a convolution's state lies in every block
-        while n > 0:
-            bid = block_at(n // self._kv_bt - 1)
-            if bid is not None and self.kv_pool.snapshots.entry(
-                    bid) is not None:
-                return n
-            n -= self._stride
-        return 0
-
-    def _state_word(self, rows, starts: list[int], budget: list[int],
-                    chunk: int = 0) -> tuple[list, dict]:
-        """A state-space model's word to the dispatch about to be issued
-        (`StateCache.ctl`): which slots are live in it, and for each row that
-        will end a stride the entry of the snapshot pool its state goes to,
-        allotted here. `rows` its (slot, request) pairs, `starts` and
-        `budget` every slot's position and the tokens it advances; `chunk`:
-        the prefilling row's tokens (0: every row steps). Returns the
-        allotments (`_InflightStep.snaps`) and the dispatch span's args
-        (the work of exactly this dispatch, for a reader that joins it to
-        its execution)."""
-        spec, eng = self.spec, self._eng
-        word = np.zeros((2, self.slots_n, 1), np.int32)
-        snaps = []
-        layers = len(spec.state_layers)
-        stepped = tokens = 0
-        for slot, req in rows:
-            i, n = slot.index, budget[slot.index]
-            word[0, i, 0] = 1
-            if chunk and n == chunk and n > 1:
-                tokens += n
-            else:
-                stepped += n
-            if (starts[i] + n) // self._stride > starts[i] // self._stride:
-                last = (starts[i] + n) // self._stride * self._stride - 1
-                bid = slot.blocks[last // self._kv_bt]
-                entry, serial = self.kv_pool.snapshots.allot(bid)
-                word[1, i, 0] = entry
-                _SSM_STRIDE_ENDS.inc()
-                if entry:
-                    _SSM_SNAPSHOTS.inc()
-                    snaps.append((slot, req, bid, serial, last))
-        eng.v_cache = eng.v_cache._replace(ctl=_upload(word))
-        matrix = 4 * int(np.prod(spec.state_matrix)) * layers
-        nbytes = 2 * matrix * (stepped + (1 if tokens else 0) + len(snaps))
-        _SSM_ROWS.inc(stepped * layers)
-        _SSM_CHUNK_TOKENS.inc(tokens * layers)
-        _SSM_BYTES.inc(nbytes)
-        return snaps, {"ssm_rows": stepped * layers,
-                       "ssm_chunk": tokens * layers, "ssm_bytes": nbytes}
-
-    def _settle_snapshots(self, fl: _InflightStep, accepted: bool) -> None:
-        """A dispatch's snapshot allotments once it is delivered (or
-        flushed, `accepted` False): an entry counts where its row's request
-        got past the stride's last position, else it goes back."""
-        for slot, req, bid, serial, last in fl.snaps:
-            ok = accepted and (req.done.is_set() if slot.req is not req
-                               else slot.pos > last)
-            self.kv_pool.snapshots.settle(bid, serial, ok)
-        fl.snaps = []
-
     def _dispatched(self, kind: str, call):
         """Run one device dispatch with transient-fault retry: classify()
         'transient' errors (injected TransientDispatchError, or any exception
@@ -2472,10 +1729,7 @@ class BatchEngine:
             toks = _upload(np.asarray(tokens_rows, dtype=np.int32))
             start_pos = _upload(np.asarray(
                 starts if lead is None else starts + [lead], dtype=np.int32))
-            tables, resent = None, False
-            if self.kv_pool is not None:
-                resent = self._tables_dev is None
-                tables = self._tables()
+            tables, resent = self.slot_cache.table()
             sp.add(transfers=2 + resent, table=int(resent),
                    bytes=toks.nbytes + start_pos.nbytes
                    + (tables.nbytes if resent else 0))
@@ -2501,14 +1755,14 @@ class BatchEngine:
         computed = dispatched  # rows the weight kernels ran over
         if lead is not None:
             computed = compact_rows(positions, self.slots_n)
-            if getattr(self, "_kv_bt", 0):
+            if self.slot_cache.block_tokens:
                 dispatched = positions + self.slots_n
             else:
                 lead = None  # every read below is the rectangle's
         _POSITIONS_DISPATCHED.inc(computed)
         _ATTN_PAIRS_DISPATCHED.inc(dispatched * window)
+        bt = self.slot_cache.block_tokens
         if self._eng.paged_kernel:
-            bt = self._kv_bt
             n_read = -(-window // bt)
 
             def keys(length):
@@ -2558,14 +1812,14 @@ class BatchEngine:
             _LATENT_DISPATCH_ROWS.inc(dispatched * spec.n_layers)
         _POSITIONS_REAL.inc(sum(n for _, n in real))
         _ATTN_PAIRS_REAL.inc(sum(n * p + n * (n + 1) // 2 for p, n in real))
-        bt = getattr(self, "_kv_bt", 0)
         if bt:
             ends = sum((p + n) // bt - p // bt for p, n in real)
             _BLOCK_ENDS.inc(ends)
             if spec is not None and spec.mixed:
                 layers = len(spec.state_layers)
                 if spec.ssm:  # the tails are snapshot where a stride ends
-                    ends = sum((p + n) // self._stride - p // self._stride
+                    stride = self.slot_cache.stride
+                    ends = sum((p + n) // stride - p // stride
                                for p, n in real)
                 _STATE_ROWS.inc(layers * sum(n for _, n in real))
                 _STATE_SNAPSHOTS.inc(layers * ends)
@@ -2681,9 +1935,7 @@ class BatchEngine:
         epoch = getattr(self._tls, "epoch", self._epoch)
         # demotion reads issued and not yet host arrays: what else is
         # on the way to the host when this dispatch's results are
-        pending = 0
-        if self.kv_pool is not None and self.prefix_cache is not None:
-            pending = self.prefix_cache.unsettled * self._kv_block_bytes
+        pending = self.slot_cache.pending_bytes()
         with trace.span("batch.fetch", {"bytes": what.nbytes}):
             t2 = time.perf_counter()
             with trace.span("batch.fetch_wait"):
@@ -2734,13 +1986,9 @@ class BatchEngine:
             tenant=(self.tenants.canonical(req.tenant)
                     if self.tenants is not None else req.tenant),
             **{"class": req.klass}).inc()
-        if self.prefix_cache is not None and slot.lease is not None:
-            # the lease pins blocks for the IN-FLIGHT period only; release
-            # before done.set() so a caller observing completion sees no
-            # residual reservation (the harvest below re-walks the tree and
-            # needs no pin — insert guards its own chain)
-            self.prefix_cache.release(slot.lease)
-            slot.lease = None
+        # the lease goes before done.set(), so a caller observing completion
+        # sees no residual reservation (the harvest below needs no pin)
+        self.slot_cache.unpin(slot)
         if req.export_kv:
             # disaggregation export (docs/DISAGG.md): host-snapshot the
             # committed prompt blocks BEFORE done.set() — the /v1/kv
@@ -2748,70 +1996,36 @@ class BatchEngine:
             # this runs on the scheduler thread, the only place device
             # cache reads cannot race a donating dispatch
             try:
-                req.kv_export = self._export_slot_blocks(
+                req.kv_export = self.slot_cache.export_blocks(
                     slot, len(req.prompt))
             except Exception as e:
-                from ..cache import warn_degraded
-
                 warn_degraded("export", e)
         _REQUESTS.labels(finish=finish).inc()
         req.done.set()
         # harvest AFTER done.set(): the slot's history/rows stay valid (they
         # also back the same-slot rewind), and the copy-out must not extend
         # the finished client's wait
-        if self.prefix_cache is not None:
-            self._harvest_into_cache(slot)
+        self.slot_cache.harvest(slot)
 
-    def _harvest_into_cache(self, slot: _Slot) -> None:
-        """Copy the finished slot's committed prefix into the block pool (the
-        cross-request half of prefix reuse). history's rows [0, len(history))
-        are committed by construction — every truncation site shrinks history
-        before the rows are overwritten."""
-        pc = self.prefix_cache
-        if slot.clamp_pos is not None:
-            # the in-flight super-step parked this row clamped at clamp_pos,
-            # destroying that row — drop it from the harvestable history NOW
-            # (the post-loop truncation would run too late for this harvest)
-            self._truncate_history(slot, slot.clamp_pos)
-            slot.clamp_pos = None
-        try:
-            if self.kv_pool is not None:
-                # zero-copy harvest: the directory takes REFS on the slot's
-                # committed full blocks — no device→host transfer at all
-                n = len(slot.history) // self._kv_bt
-                if n:
-                    with trace.span("batch.prefix_insert",
-                                    {"slot": slot.index,
-                                     "tokens": n * self._kv_bt,
-                                     "remap": True}):
-                        pc.insert_blocks(slot.history, slot.blocks[:n])
-            elif len(slot.history) >= pc.block_tokens:
-                eng = self._eng
-
-                def harvest(t0: int, t1: int):
-                    return (np.asarray(eng.k_cache[:, slot.index, :, t0:t1]),
-                            np.asarray(eng.v_cache[:, slot.index, :, t0:t1]))
-
-                with trace.span("batch.prefix_insert",
-                                {"slot": slot.index,
-                                 "tokens": len(slot.history)}):
-                    pc.insert(slot.history, harvest)
-        except Exception as e:  # a failed insert must not kill the scheduler
-            from ..cache import warn_degraded
-
-            warn_degraded("insert", e)
-
-    def _truncate_history(self, sl: _Slot, p: int) -> None:
-        """Truncate a slot's reusable history to p tokens — its rows >= p are
-        (about to be) overwritten by clamped scratch writes — and shrink any
-        prefix-cache lease past p. Without the shrink a clamped park would
-        leave the radix reservation pinning blocks for a prefix the slot no
-        longer holds, blocking their eviction until _finish (and lying about
-        what the slot can re-insert)."""
-        if p < len(sl.history):
-            sl.history = sl.history[:p]
-        if sl.lease is not None and p < sl.lease.tokens:
-            self.prefix_cache.shrink(sl.lease, p)
+    def _cover(self, rows: list[_Slot], upto,
+               decline: bool = False) -> list[_Slot]:
+        """Block coverage for every committed write a dispatch makes: each
+        of `rows` up to position `upto(row)` (scratch beyond it lands in the
+        scratch block by design). A pool that cannot serve a row even after
+        reclaim fails ONLY that row's request and takes it out of `rows`
+        (the rows returned); `decline` (a dispatch planned ahead) fails
+        nobody and raises: the synchronous path fails the row it is."""
+        short = []
+        for slot in rows[:]:
+            try:
+                self.slot_cache.cover(slot, upto(slot))
+            except Exception as e:
+                if decline or classify(e) != "request":
+                    raise
+                self._fail_request(slot, e)
+                rows.remove(slot)
+                short.append(slot)
+        return short
 
     def _park_positions(self, t: int) -> list[int]:
         """Per-row start positions for rows not participating in this step: park at the
@@ -2827,31 +2041,19 @@ class BatchEngine:
             pos = sl.pos + sl.ahead
             p = min(pos, max(s - t, 0))
             if p < pos:
-                if self.kv_pool is not None and sl.req is None:
-                    # paged idle slot: a clamped park would scribble into
-                    # possibly directory-shared tail blocks — drop the
-                    # rewind stock instead of CoW-ing for garbage (the
-                    # committed full blocks live on in the directory)
-                    self._paged_release_slot(sl)
+                # a pool that cannot serve the park's copy-on-write fails
+                # ONLY this request: the slot parks empty like any idle row
+                # (callers re-filter for reaped rows after _park_positions)
+                try:
+                    kept = self.slot_cache.park(sl, p, min(p + t, s))
+                except Exception as e:
+                    if classify(e) != "request":
+                        raise
+                    self._fail_request(sl, e)
+                    self.slot_cache.release(sl)
+                    kept = False
+                if not kept:
                     p = 0
-                    starts.append(p)
-                    continue
-                self._truncate_history(sl, p)
-                if self.kv_pool is not None:
-                    # the clamped scratch writes [p, p+t) must not land in
-                    # shared blocks (the directory's committed rows). A
-                    # pool that cannot even serve the CoW fails ONLY this
-                    # request — the slot then parks empty on the scratch
-                    # block like any idle row (callers re-filter for
-                    # reaped rows after _park_positions)
-                    try:
-                        self._paged_cow(sl, p, min(p + t, s))
-                    except Exception as e:
-                        if classify(e) != "request":
-                            raise
-                        self._fail_request(sl, e)
-                        self._paged_release_slot(sl)
-                        p = 0
             starts.append(p)
         return starts
 
@@ -2867,11 +2069,10 @@ class BatchEngine:
         (requests given a slot, requests left queued)."""
         now = time.perf_counter()
         admitted = 0
-        # preempted rows' prefix harvests are SNAPSHOTTED under the lock
-        # but copied device→host after it: jax arrays are immutable, so
-        # the captured cache refs survive the slot's reassignment, and the
-        # transfer must not stall submit()/admission callers on _plock
-        harvests: list[tuple] = []
+        # preempted rows' prefix harvests are snapshotted under the lock but
+        # copied device→host after it: the transfer must not stall
+        # submit()/admission callers on _plock
+        harvests: list[Callable[[], None]] = []
         with self._plock:
             self._drain_submit_queue()
             # queue-TTL / deadline expiry applies to EVERY queued request,
@@ -2941,8 +2142,8 @@ class BatchEngine:
                 admitted += 1
             queued = len(self._pending) + self._queue.qsize()
             _QUEUE_DEPTH.set(queued)
-        for history, kc, vc, index in harvests:
-            self._harvest_rows(history, kc, vc, index)
+        for harvest in harvests:
+            harvest()
         return admitted, queued
 
     def _drain_submit_queue(self) -> None:  # holds: self._plock
@@ -2977,10 +2178,8 @@ class BatchEngine:
         """Release a batch row at a super-step boundary and re-queue its
         request (docs/SERVING.md "Multi-tenant serving"). The release
         mirrors _finish WITHOUT completing the request: the prefix-cache
-        lease is released and a SNAPSHOT of the committed history + cache
-        arrays is returned for a deferred harvest into the radix pool
-        (device→host copies must not run under _plock; jax arrays are
-        immutable so the snapshot survives the slot's reassignment) — the
+        lease is released and the committed history harvested (where that
+        is a copy, it is returned to be made outside _plock) — the
         later re-admission (prompt ⊕ delivered, _assign) is then mostly a
         cache hit, the same "resume cost ≈ one suffix prefill" economics
         as a durable failover. An in-flight chained dispatch covering this
@@ -3006,123 +2205,14 @@ class BatchEngine:
         # replays prompt ⊕ delivered through the automaton in
         # _attach_constraint, the same rebuild-from-truth the proposer does
         self._release_constraint(slot)
-        harvest = None
-        if self.prefix_cache is not None:
-            if slot.lease is not None:
-                self.prefix_cache.release(slot.lease)
-                slot.lease = None
-            if slot.clamp_pos is not None:
-                # an in-flight scan flagged a clamped park: the poisoned
-                # tail must not be harvested (mirrors _harvest_into_cache)
-                self._truncate_history(slot, slot.clamp_pos)
-                slot.clamp_pos = None
-            if self.kv_pool is not None:
-                # paged: the harvest is a refcount, not a copy — run it
-                # inline (deferring would race the slot's reassignment
-                # CoW-ing or freeing the very blocks being inserted)
-                try:
-                    n = len(slot.history) // self._kv_bt
-                    if n:
-                        self.prefix_cache.insert_blocks(slot.history,
-                                                        slot.blocks[:n])
-                except Exception as e:
-                    from ..cache import warn_degraded
-
-                    warn_degraded("insert", e)
-            else:
-                eng = self._eng
-                harvest = (list(slot.history), eng.k_cache, eng.v_cache,
-                           slot.index)
+        self.slot_cache.unpin(slot)
+        harvest = self.slot_cache.harvest(slot, deferred=True)
         # nominal re-queue cost: the original admission already charged the
         # FULL request cost into the tenant's virtual time — charging the
         # remainder again would double-bill every preemption and erode the
         # tenant's configured share
         self._pending.push(req, req.tenant, req.klass, 1.0)
         return harvest
-
-    def _harvest_rows(self, history: list[int], kc, vc, index: int) -> None:
-        """Deferred preemption harvest: copy the snapshotted committed rows
-        into the prefix-cache pool. Runs OUTSIDE _plock — the snapshot
-        arrays are immutable, so the slot may already be serving its next
-        request."""
-        pc = self.prefix_cache
-        if pc is None:
-            return
-        try:
-            if len(history) >= pc.block_tokens:
-                def harvest(t0: int, t1: int):
-                    return (np.asarray(kc[:, index, :, t0:t1]),
-                            np.asarray(vc[:, index, :, t0:t1]))
-
-                with trace.span("batch.prefix_insert",
-                                {"slot": index, "tokens": len(history)}):
-                    pc.insert(history, harvest)
-        except Exception as e:  # degraded cache, never a scheduler error
-            from ..cache import warn_degraded
-
-            warn_degraded("insert", e)
-
-    def _export_slot_blocks(self, slot: _Slot, prompt_len: int):
-        """Host snapshot of the slot's committed prompt-prefix KV as
-        fixed-size blocks — the disaggregation export payload (docs/
-        DISAGG.md): (tokens, [(k, v) per block], block_tokens), each side
-        an (L, hk, bt, hs) host array. Scheduler thread ONLY: device cache
-        reads must not race a donating dispatch. Only FULL blocks of the
-        prompt export (a partial tail block has no directory home on the
-        importing side); a clamped park truncates the exportable span the
-        same way it truncates the harvest."""
-        bt = self._kv_bt or (self.prefix_cache.block_tokens
-                             if self.prefix_cache is not None else 0)
-        if bt <= 0 or self.spec.mixed:  # submit() refused export_kv
-            return None
-        p = min(prompt_len, len(slot.history))
-        if slot.clamp_pos is not None:
-            p = min(p, slot.clamp_pos)
-        n = p // bt
-        if n == 0:
-            return None
-        tokens = list(slot.history[:n * bt])
-        eng = self._eng
-        if self.kv_pool is not None:
-            blocks = [self._read_block(bid) for bid in slot.blocks[:n]]
-        else:
-            k = np.asarray(eng.k_cache[:, slot.index, :, :n * bt])
-            v = np.asarray(eng.v_cache[:, slot.index, :, :n * bt])
-            blocks = [(k[:, :, i * bt:(i + 1) * bt],
-                       v[:, :, i * bt:(i + 1) * bt]) for i in range(n)]
-        return tokens, blocks, bt
-
-    def import_kv_blocks(self, tokens: list[int], blocks: list) -> int:
-        """Adopt externally-shipped HOST KV blocks (the decode half of a
-        disaggregated admission, docs/DISAGG.md) into the prefix cache:
-        `blocks[i]` is the (k, v) pair covering token block i of `tokens`.
-        Pure host bookkeeping — a paged directory stores them as COLD
-        nodes (the existing admission path pays the one host→device
-        promotion upload, on the scheduler thread), a dense cache inserts
-        them into its host pool (the existing seed scatter applies them) —
-        so this is safe to call from any HTTP handler thread. Returns the
-        token span the cache now covers (0 = nothing imported; the caller
-        admits with a plain local prefill)."""
-        pc = self.prefix_cache
-        if pc is None:
-            return 0
-        if self.spec.mixed:
-            raise ValueError(_NO_KV_STREAM)
-        bt = pc.block_tokens
-        n = min(len(tokens) // bt, len(blocks))
-        if n <= 0:
-            return 0
-        span = list(tokens[:n * bt])
-        if self.kv_pool is not None:
-            return pc.insert_cold(span, blocks[:n]) * bt
-        k = np.concatenate([np.asarray(b[0]) for b in blocks[:n]], axis=2)
-        v = np.concatenate([np.asarray(b[1]) for b in blocks[:n]], axis=2)
-        pc.insert(span, lambda t0, t1: (k[:, :, t0:t1], v[:, :, t0:t1]))
-        # report what the cache actually HOLDS, not what it was handed: a
-        # lease-pinned-full pool can refuse every block, and claiming the
-        # span anyway would count an "imported" success for KV the
-        # admission must then re-prefill
-        return pc.covered_blocks(span) * bt
 
     def _reap_slots(self) -> None:
         """Free slots whose request was cancelled or whose wall-clock
@@ -3255,7 +2345,7 @@ class BatchEngine:
                 # enqueue latency is set by the notify, not this number.
                 # 0.1 s also bounds queue-TTL/deadline detection while idle.
                 self._gap_t = None  # an idle device is not a starved one
-                self._settle_demotions()
+                self.slot_cache.settle()
                 with self._cond, trace.span("batch.wait"):
                     if self._queue.empty() and not self._shutdown:
                         self._cond.wait(timeout=0.1)
@@ -3429,11 +2519,9 @@ class BatchEngine:
         pending = slot.pending[slot.ahead:]
         chunk = next((c for c in PREFILL_CHUNKS if len(pending) >= c), 1)
         chunk = min(chunk, s - pos)
-        if self.spec.ssm and chunk > self._stride - pos % self._stride:
-            # a chunk never runs past a stride's end: the slot's running
-            # matrix is snapshot as the chunk leaves it
-            chunk = next(c for c in PREFILL_CHUNKS
-                         if c <= self._stride - pos % self._stride)
+        limit = self.slot_cache.chunk_limit(pos)
+        if chunk > limit:  # a chunk never runs past a state snapshot's end
+            chunk = next(c for c in PREFILL_CHUNKS if c <= limit)
         # keep parked rows' scratch writes inside the cache without
         # touching history: a parked row writes [pos, pos+chunk) which
         # must fit under seq_len; shrink the chunk when any OTHER row
@@ -3459,24 +2547,12 @@ class BatchEngine:
             starts[r.index] = r.pos + r.ahead
             rows[r.index] = ([r.last_token if chain is None else -1]
                              + [0] * (t - 1))
-        if self.kv_pool is not None:
-            # block coverage for every committed write this dispatch
-            # makes (the prefill chunk, each rider's one real token);
-            # scratch beyond coverage lands in the scratch block by
-            # design. A RIDER's exhaustion fails the rider, not the
-            # innocent prefill (the victim's own failure propagates and
-            # is attributed to it by _loop_once's request-scope handler)
-            self._paged_ensure(slot, pos + t)
-            for r in riders[:]:
-                try:
-                    self._paged_ensure(r, starts[r.index] + 1)
-                except Exception as e:
-                    if classify(e) != "request":
-                        raise
-                    self._fail_request(r, e)
-                    riders.remove(r)
-                    starts[r.index] = r.pos  # an idle row's park
-                    rows[r.index] = [0] * t
+        # a RIDER's exhaustion fails the rider, not the innocent prefill (the
+        # victim's own propagates to _loop_once's request-scope handler)
+        self.slot_cache.cover(slot, pos + t)
+        for r in self._cover(riders, lambda r: starts[r.index] + 1):
+            starts[r.index] = r.pos  # an idle row's park
+            rows[r.index] = [0] * t
         # a chunk with scratch in it: the program is told which row
         # prefills and runs its weights over the chunk and one row a slot
         # (rows sharded over dp have no one stream to be compacted into)
@@ -3531,17 +2607,16 @@ class BatchEngine:
         ahead = (chain is not None or self._runs_ahead()) and all(
             self._samples_here(s) for s in sampled)
         name, args = fl.span
-        if self.spec.ssm:
-            fl.snaps, work = self._state_word(
-                fl.rows, fl.starts, fl.budget,
-                chunk=fl.k if lead is not None else 0)
-            args.update(work)
+        fl.snaps, work = self.slot_cache.state_word(
+            fl.rows, fl.starts, fl.budget,
+            chunk=fl.k if lead is not None else 0)
+        args.update(work)
 
         def launch():
             fl.toks, fl.tok, fl.moe = self._launch_step(staged, kind, chain)
             # what the host will fetch, behind the program in the device's
             # own order, where the fetch's np.asarray alone would have put it
-            _start_host_copy(fl.tok if ahead else fl.toks,
+            start_host_copy(fl.tok if ahead else fl.toks,
                              *(() if fl.moe is None else (fl.moe,)))
 
         if not ahead:
@@ -3550,7 +2625,7 @@ class BatchEngine:
             # context so the span (and any dispatch fault) carries its id
             with reqctx.use(ctx), trace.span(name, args):
                 launch()
-                self._settle_demotions()  # the host only waits from here on
+                self.slot_cache.settle()  # the host only waits from here on
                 out = self._fetch_step(fl, sampled_here=False)
             with trace.span("batch.deliver"):
                 self._settle_step(fl, out, sampled_here=False)
@@ -3658,12 +2733,10 @@ class BatchEngine:
             return False
         if reach + 1 > s:
             return False
-        if self.kv_pool is not None:
-            try:
-                for sl in riders:
-                    self._paged_ensure(sl, sl.pos + sl.ahead + 1)
-            except Exception:
-                return False  # the synchronous path fails the row it is
+        try:
+            self._cover(riders, lambda sl: sl.pos + sl.ahead + 1, decline=True)
+        except Exception:
+            return False
         plan = self._plan_single(riders, t0, chain=fl)
         if plan is None:
             return False
@@ -3683,7 +2756,7 @@ class BatchEngine:
             # inside the span, as between a synchronous dispatch's launch
             # and its fetch (a reclaim's 10 to 20 MB take milliseconds, and
             # a span opened after them would miss its own execution)
-            self._settle_demotions()
+            self.slot_cache.settle()
             out = self._fetch_step(fl, sampled_here=True)
         with trace.span("batch.deliver"):
             self._settle_step(fl, out, sampled_here=True)
@@ -3758,7 +2831,7 @@ class BatchEngine:
             else:
                 slot.last_logits = out[slot.index,
                                        -1 if slot is lead or t == 1 else 0]
-        self._settle_snapshots(fl, True)
+        self.slot_cache.settle_state(fl.snaps, True)
 
     def _decode_step(self, active: list[_Slot]) -> None:
         # bring every row to its next un-ingested token (host-samples rows at a
@@ -3769,17 +2842,8 @@ class BatchEngine:
                 if not self._advance_row(slot):
                     active.remove(slot)
         with trace.span("batch.build"):
-            if self.kv_pool is not None:
-                # every row's next write needs a real block behind it; a pool
-                # that cannot serve even after reclaim fails ONLY that request
-                for slot in active[:]:
-                    try:
-                        self._paged_ensure(slot, slot.pos + 1)
-                    except Exception as e:
-                        if classify(e) != "request":
-                            raise
-                        self._fail_request(slot, e)
-                        active.remove(slot)
+            # every row's next write needs a real block behind it
+            self._cover(active, lambda slot: slot.pos + 1)
             if not active:
                 return
             # speculative path: draft per-row n-gram proposals; when any row
@@ -3839,7 +2903,7 @@ class BatchEngine:
                 use_pallas=eng.use_pallas,
                 compress_collectives=eng.compress, donate_cache=True,
                 attn_window=window, moe_sharding=eng.moe_sharding,
-                kv_block_tokens=self._kv_bt,
+                kv_block_tokens=self.slot_cache.block_tokens,
                 paged_kernel=eng.paged_kernel,
                 masked=masked, moe_stats=eng.moe_stats)
         return self._loops[key]
@@ -3861,7 +2925,7 @@ class BatchEngine:
                 use_pallas=eng.use_pallas,
                 compress_collectives=eng.compress, donate_cache=True,
                 attn_window=window, moe_sharding=eng.moe_sharding,
-                kv_block_tokens=self._kv_bt,
+                kv_block_tokens=self.slot_cache.block_tokens,
                 paged_kernel=eng.paged_kernel,
                 masked=masked)
         return self._loops[key]
@@ -3968,18 +3032,10 @@ class BatchEngine:
         the frontier so a chained scan composes for any accept outcome."""
         faults.fire("batch.verify", rows=len(active), block=t)
         with trace.span("batch.build"):
-            if self.kv_pool is not None:
-                for slot in active[:]:
-                    try:
-                        self._paged_ensure(slot, slot.pos + t)
-                    except Exception as e:
-                        if classify(e) != "request":
-                            raise
-                        self._fail_request(slot, e)
-                        active.remove(slot)
-                        drafts.pop(slot.index, None)
-                if not active:
-                    return
+            for slot in self._cover(active, lambda slot: slot.pos + t):
+                drafts.pop(slot.index, None)
+            if not active:
+                return
             starts = self._park_positions(t)
             # a clamp-park CoW under pool exhaustion may have reaped a row
             active = [s for s in active if s.req is not None]
@@ -4029,10 +3085,9 @@ class BatchEngine:
         window = window or self.spec.seq_len
         self._observe_gap()
         t_issue = time.perf_counter()
-        snaps, work = (self._state_word(rows, starts, budget)
-                       if self.spec.ssm else ([], {}))
+        snaps, work = self.slot_cache.state_word(rows, starts, budget)
         kc_in, vc_in = eng.k_cache, eng.v_cache  # same stale-epoch discipline
-        tables = self._tables() if self.kv_pool is not None else None
+        tables, _resent = self.slot_cache.table()
         constrain = None
         if masked:
             # a verify is never chained FROM, so its constraint states come
@@ -4075,7 +3130,7 @@ class BatchEngine:
                  eng.v_cache) = self._dispatched("verify", call)
                 cst = None
         _PIPELINE_DEPTH.set(1)
-        _start_host_copy(toks, acc, rng_out)
+        start_host_copy(toks, acc, rng_out)
         return _InflightStep(rows, t, starts, budget, temps, toks, tok, pos,
                              rng_out, t_issue, False, window, kind="verify",
                              ndraft=ndraft, acc=acc, cstate=cst)
@@ -4105,18 +3160,9 @@ class BatchEngine:
         pipelining, the NEXT super-step is chained from this one's device
         carry before delivery starts (_pipeline_advance)."""
         with trace.span("batch.build"):
-            if self.kv_pool is not None:
-                for slot in active[:]:
-                    try:
-                        self._paged_ensure(slot,
-                                           slot.pos + budgets[slot.index])
-                    except Exception as e:
-                        if classify(e) != "request":
-                            raise
-                        self._fail_request(slot, e)
-                        active.remove(slot)
-                if not active:
-                    return
+            self._cover(active, lambda slot: slot.pos + budgets[slot.index])
+            if not active:
+                return
             starts = self._park_positions(1)
             # a clamp-park CoW under pool exhaustion may have reaped a row
             active = [s for s in active if s.req is not None]
@@ -4151,19 +3197,19 @@ class BatchEngine:
                     # pipelined analog of the K -> 1 admission-latency drop
                     _PIPELINE_FLUSHES.labels(reason="admission").inc()
                     plan = None
-            if plan is not None and self.kv_pool is not None:
+            if plan is not None:
                 # the chained dispatch's speculative writes need block
                 # coverage (and clamped parks need exclusive blocks) BEFORE
                 # issue; a pool that cannot serve declines the chain instead
                 # of failing rows
                 rows, starts, budget, clamp = plan
                 try:
-                    for slot, _req in rows:
-                        self._paged_ensure(slot, starts[slot.index]
-                                           + budget[slot.index])
+                    self._cover(
+                        [slot for slot, _req in rows], lambda slot:
+                        starts[slot.index] + budget[slot.index], decline=True)
                     for slot in clamp:
-                        self._paged_cow(slot, self.spec.seq_len - 1,
-                                        self.spec.seq_len)
+                        self.slot_cache.own(slot, self.spec.seq_len - 1,
+                                            self.spec.seq_len)
                 except Exception:
                     _PIPELINE_FLUSHES.labels(reason="pool").inc()
                     plan = None
@@ -4177,7 +3223,7 @@ class BatchEngine:
                     slot.clamp_pos = self.spec.seq_len - 1
                 nxt = self._issue_super_step(rows, self.superstep, budget,
                                              starts, chain=fl)
-        self._settle_demotions()  # the host waits for `fl` from here on
+        self.slot_cache.settle()  # the host waits for `fl` from here on
         with trace.span("batch.deliver"):
             try:
                 status = self._deliver_super_step(fl)
@@ -4292,10 +3338,9 @@ class BatchEngine:
             tok_in, pos_in, rng_in = chain.tok, chain.pos, chain.rng
             _DISPATCH_GAP.observe(0.0)  # chained: the device never went idle
         t_issue = time.perf_counter()
-        snaps, work = (self._state_word(rows, starts, budget)
-                       if self.spec.ssm else ([], {}))
+        snaps, work = self.slot_cache.state_word(rows, starts, budget)
         kc_in, vc_in = eng.k_cache, eng.v_cache  # same stale-epoch discipline
-        tables = self._tables() if self.kv_pool is not None else None
+        tables, _resent = self.slot_cache.table()
         constrain = None
         if masked:
             # constraint carry: a chained dispatch consumes the
@@ -4349,7 +3394,7 @@ class BatchEngine:
                  eng.v_cache, *moe) = self._dispatched("super_step", call)
                 cst = None
         _PIPELINE_DEPTH.set(2 if chain is not None else 1)
-        _start_host_copy(toks, rng_out)  # delivery's np.asarray picks them up
+        start_host_copy(toks, rng_out)  # delivery's np.asarray picks them up
         fl = _InflightStep(rows, k, starts, budget, temps, toks, tok, pos,
                            rng_out, t_issue, chain is not None, window,
                            cstate=cst, moe=moe[0] if moe else None)
@@ -4540,7 +3585,7 @@ class BatchEngine:
                 # row did not finish mid-loop (the harvest consumes clamp_pos
                 # when it did): apply the clamp truncation here — mirror of
                 # the _park_positions clamp, incl. the lease shrink
-                self._truncate_history(slot, slot.clamp_pos)
+                self.slot_cache.truncate(slot, slot.clamp_pos)
                 slot.clamp_pos = None
             # per-row timeline + trace attribution: one super_step entry per
             # request it advanced, and (tracing on) a per-row instant bound
@@ -4578,7 +3623,7 @@ class BatchEngine:
                 # without these rows drafting
                 for slot, _req in fl.rows:
                     self.adaptive.tick(slot.index)
-        self._settle_snapshots(fl, True)
+        self.slot_cache.settle_state(fl.snaps, True)
         return status
 
     def _chain_divergence(self, nxt: _InflightStep,
@@ -4607,14 +3652,7 @@ class BatchEngine:
         _PIPELINE_FLUSHES.labels(reason=reason).inc()
         _ROLLBACK_TOKENS.inc(sum(fl.budget))
         self._count_inflight(fl, [])  # ran on the device for nothing
-        self._settle_snapshots(fl, False)
-        vc = self._eng.v_cache
-        if fl.kind == "scan" and isinstance(vc, StateCache) and (
-                vc.h is not None):
-            # a running matrix sums every earlier position and cannot be
-            # written over: the survivors go back to H as the flushed scan
-            # FOUND it, their accepted frontier (the scan kept it: `held`)
-            self._eng.v_cache = vc._replace(h=vc.held, held=vc.h)
+        self.slot_cache.settle_state(fl.snaps, False, scan=fl.kind == "scan")
         for slot, req in fl.rows:
             flight.event(req.rid, "pipeline_flush", reason=reason,
                          tokens=fl.budget[slot.index])

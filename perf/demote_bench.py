@@ -11,8 +11,8 @@ blocks at each configuration's pool shape, two ways:
 - `per block`: the path until ISSUE 39, kept here as the reference: two eager
   slices and two synchronous device-to-host copies a block,
   `np.asarray(k[:, bid]), np.asarray(v[:, bid])`, one block after another;
-- `gather`: the engine's own `_DemoteRead`: ONE jitted gather of the n blocks
-  from both sides (n padded to 1, 2, 4 or 8; an empty side is not read), its
+- `gather`: the engine's own `DemoteRead` (runtime/slot_cache.py): ONE
+  jitted gather of the n blocks from both sides (n padded to 1, 2, 4 or 8; an empty side is not read), its
   host copy started and not waited for. `issue` is what the scheduler pays
   before it launches the next dispatch; `settle now` is the wait if the rows
   were asked for at once (nothing overlapped: the read's whole latency);
@@ -79,15 +79,15 @@ def programs(pool) -> list[str]:
     """The gather's lowered signature at every size a reclaim issues."""
     import jax
 
-    from distributed_llama_tpu.runtime.batch_engine import (_DEMOTE_SIZES,
-                                                            _pool_gather)
+    from distributed_llama_tpu.runtime.slot_cache import (DEMOTE_SIZES,
+                                                          pool_gather)
 
     sides = tuple(c for c in pool if c.shape[-1])
     out = []
-    for n in _DEMOTE_SIZES:
+    for n in DEMOTE_SIZES:
         ids = jax.ShapeDtypeStruct((n,), np.int32)
-        got = jax.eval_shape(_pool_gather, sides, ids)
-        out.append(f"_pool_gather[{n}]: "
+        got = jax.eval_shape(pool_gather, sides, ids)
+        out.append(f"pool_gather[{n}]: "
                    + ", ".join(f"{a.dtype}{list(a.shape)}" for a in sides)
                    + " -> " + ", ".join(f"{a.dtype}{list(a.shape)}"
                                         for a in got))
@@ -98,7 +98,7 @@ def bench(name, shape, w2, iters, busy_ms, rng):
     import jax
     import jax.numpy as jnp
 
-    from distributed_llama_tpu.runtime.batch_engine import _DemoteRead
+    from distributed_llama_tpu.runtime.slot_cache import DemoteRead
 
     pool = make_pool(shape, w2, 39)
     jax.block_until_ready(pool)
@@ -124,7 +124,7 @@ def bench(name, shape, w2, iters, busy_ms, rng):
             t0 = time.perf_counter()
             want = per_block(pool, bids)
             t1 = time.perf_counter()
-            read = _DemoteRead(pool)
+            read = DemoteRead(pool)
             got = [read.block(b) for b in bids]
             read.issue(pool)
             t2 = time.perf_counter()
@@ -135,7 +135,7 @@ def bench(name, shape, w2, iters, busy_ms, rng):
                 assert gk.shape == wk.shape and gv.shape == wv.shape
             bids = rng.choice(np.arange(1, shape[1]), n, replace=False).tolist()
             t4 = time.perf_counter()
-            read = _DemoteRead(pool)
+            read = DemoteRead(pool)
             got = [read.block(b) for b in bids]
             read.issue(pool)
             t5 = time.perf_counter()
@@ -150,7 +150,7 @@ def bench(name, shape, w2, iters, busy_ms, rng):
             t8 = time.perf_counter()
             jax.block_until_ready(busy(a, reps))
             t9 = time.perf_counter()
-            read = _DemoteRead(pool)
+            read = DemoteRead(pool)
             got = [read.block(b) for b in bids]
             read.issue(pool)
             t10 = time.perf_counter()

@@ -48,7 +48,7 @@ def _rows_at(be, cache, tables, row, lo, n):
     cache = np.asarray(cache)
     if tables is None:  # (L, B, hk, S, w)
         return cache[:, row, :, lo:lo + n]
-    bt = be._kv_bt
+    bt = be.slot_cache.block_tokens
     return np.stack([cache[:, tables[row, p // bt], :, p % bt]
                      for p in range(lo, lo + n)], axis=2)
 
@@ -154,8 +154,8 @@ def test_a_compact_chunk_is_the_rectangle_at_every_real_position(
         tables_np = tables = None
         if paged:
             for sl in be._slots:
-                be._paged_ensure(sl, CONTEXT)
-            tables_np, tables = be._tables_np.copy(), be._tables()
+                be.slot_cache.cover(sl, CONTEXT)
+            tables_np, tables = be.slot_cache.tables_np.copy(), be.slot_cache.table()[0]
         step = eng._step_for(None)
 
         def run(tokens, starts, kc, vc):
@@ -227,7 +227,7 @@ def _same_state(be, got, want, before, tables_np, lead, ride, chunk, slots,
         at = [p % w for p in range(max(HISTORY - 2, 0), HISTORY)]
         np.testing.assert_array_equal(ring_g[b, at], ring_0[b, at])
     snaps_g, snaps_w = np.asarray(got.snaps)[0], np.asarray(want.snaps)[0]
-    bt = be._kv_bt
+    bt = be.slot_cache.block_tokens
     ends = [p for p in range(HISTORY, HISTORY + chunk) if (p + 1) % bt == 0]
     assert chunk < bt or ends
     for p in ends:
@@ -259,12 +259,12 @@ def test_programs_without_a_lead_row_hold_no_row_map(monkeypatch):
         jax.eval_shape(
             lambda *a: eng._step_for(None)(*a), eng.params, eng.rope, tok,
             eng.k_cache, eng.v_cache, jnp.zeros((2,), jnp.int32),
-            be._tables())
+            be.slot_cache.table()[0])
         with pytest.raises(AssertionError, match="built a RowMap"):
             jax.eval_shape(
                 lambda *a: eng._step_for(None)(*a), eng.params, eng.rope,
                 tok, eng.k_cache, eng.v_cache, jnp.zeros((3,), jnp.int32),
-                be._tables())
+                be.slot_cache.table()[0])
     finally:
         be.close()
 
@@ -277,7 +277,7 @@ def _lowered_chunk(be, chunk, lead):
     return jax.jit(eng._step_for(None)).lower(
         eng.params, eng.rope, jnp.zeros((slots, chunk), jnp.int32),
         eng.k_cache, eng.v_cache, jnp.zeros((slots + lead,), jnp.int32),
-        be._tables()).as_text()
+        be.slot_cache.table()[0]).as_text()
 
 
 @pytest.mark.parametrize("toy", ["tiny-dense", "tiny-laguna", "tiny-axk1"])
@@ -304,7 +304,7 @@ def test_a_chunk_program_reads_the_pool_twice_and_builds_no_rectangle_of_q(
             return reader(*a, **k)
 
         monkeypatch.setattr(P, name, spy)
-        runs, width = len(spec.runs()), be._tables_np.shape[1]
+        runs, width = len(spec.runs()), be.slot_cache.tables_np.shape[1]
         text = _lowered_chunk(be, chunk, lead=True)
         assert seen == [(1, width), (slots, width)] * runs
         seen.clear()

@@ -504,7 +504,7 @@ def test_import_seeded_admission_stays_on_manifest():
                           tp=1, prefix_cache=True)
         try:
             assert eng.kv_pool is not None
-            bt = eng._kv_bt
+            bt = eng.slot_cache.block_tokens
             rng = np.random.default_rng(9)
             L, _n, hk, _bt, hs = eng._eng.k_cache.shape
             blocks = [(rng.standard_normal((L, hk, bt, hs))

@@ -314,8 +314,8 @@ def _warm_slots(be, hist):
     returns (step, tables, kc, vc)."""
     eng = be._eng
     for sl in be._slots:
-        be._paged_ensure(sl, CONTEXT)
-    tables = be._tables()
+        be.slot_cache.cover(sl, CONTEXT)
+    tables = be.slot_cache.table()[0]
     step = eng._step_for(None)
     _, kc, vc, _ = step(eng.params, eng.rope, jnp.asarray(hist),
                         eng.k_cache, eng.v_cache,
@@ -640,7 +640,7 @@ def test_lfm2_keeps_a_snapshot_in_every_block():
                      kv_block_tokens=BT, prefix_cache=True,
                      dtype=jnp.float32, tp=1)
     try:
-        assert be._stride == BT and be.kv_pool.snapshots is None
+        assert be.slot_cache.stride == BT and be.kv_pool.snapshots is None
         assert be._eng.v_cache.h is None and be._eng.v_cache.ctl is None
         assert be._eng.v_cache.snaps.shape[1] == be.kv_pool.n_blocks
         prompt = _prompt(100, 77)
@@ -648,7 +648,7 @@ def test_lfm2_keeps_a_snapshot_in_every_block():
         again = be.submit(prompt, 6, _greedy_sampler())
         assert again.wait(300) == cold
         assert again.stats.reused_tokens == 96
-        assert be._state_landing(99, lambda i: None) == 96
+        assert be.slot_cache.state_landing(99, lambda i: None) == 96
     finally:
         be.close()
 
@@ -663,12 +663,12 @@ def test_the_state_space_counters_and_the_span_args_of_a_dispatch(toy):
                  "batch_ssm_state_bytes_total",
                  "batch_ssm_stride_ends_total", "batch_ssm_snapshots_total")
         for sl in be._slots:
-            be._paged_ensure(sl, CONTEXT)
+            be.slot_cache.cover(sl, CONTEXT)
         rows = [(be._slots[i], None) for i in (0, 1, 3)]
         before = metrics.snapshot()
         # slot 1 prefills 64 tokens from 192 (its last ends the stride at
         # 255), slot 0 rides at 255 (it ends one too), slot 3 rides at 30
-        snaps, args = be._state_word(rows, [255, 192, 0, 30], [1, 64, 0, 1],
+        snaps, args = be.slot_cache.state_word(rows, [255, 192, 0, 30], [1, 64, 0, 1],
                                      chunk=64)
         after = metrics.snapshot()
         d = [after[k] - before.get(k, 0) for k in names]
@@ -681,7 +681,7 @@ def test_the_state_space_counters_and_the_span_args_of_a_dispatch(toy):
         assert [(s.index, last) for s, _, _, _, last in snaps] == [
             (0, 255), (1, 255)]
         # a K-step scan of 8: every step of every live row through ssd_step
-        _, args = be._state_word(rows, [40, 50, 0, 60], [8, 8, 0, 3])
+        _, args = be.slot_cache.state_word(rows, [40, 50, 0, 60], [8, 8, 0, 3])
         assert args["ssm_rows"] == 9 * 19 and args["ssm_chunk"] == 0
     finally:
         be.close()
@@ -700,7 +700,7 @@ def test_a_chunk_is_cut_at_a_stride_end(toy):
             be._loop_once()
         sl.pos, sl.ahead = 250, 0  # as if it stood at 250 with 64 to go
         sl.pending = _prompt(64, 6)
-        be._paged_ensure(sl, 320)
+        be.slot_cache.cover(sl, 320)
         fl, _, _ = be._plan_chunk(sl, [], 0.0)
         assert fl.k == 1  # 6 positions to the stride's end: chunks of 1
         sl.pos = 192

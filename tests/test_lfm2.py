@@ -255,8 +255,8 @@ def test_a_k_step_scan_equals_k_single_steps_and_parks_rows(toy):
         eng, spec = be._eng, be.spec
         n = len(spec.state_layers)
         for sl in be._slots:
-            be._paged_ensure(sl, CONTEXT)
-        tables = be._tables()
+            be.slot_cache.cover(sl, CONTEXT)
+        tables = be.slot_cache.table()[0]
         hist = np.random.default_rng(3).integers(3, 512, size=(4, 27))
         step = eng._step_for(None)
         copy = lambda t: jax.tree.map(jnp.copy, t)  # noqa: E731
@@ -292,7 +292,7 @@ def test_a_k_step_scan_equals_k_single_steps_and_parks_rows(toy):
         # block 1 of rows 0 and 1 ended at position 31: snapshot = the state
         snaps = np.asarray(vc_s.snaps)[0]
         for b in (0, 1, 3):
-            blk = int(be._tables_np[b, 1])
+            blk = int(be.slot_cache.tables_np[b, 1])
             want = _state_of(vc_s, b, 32, n).reshape(2 * n, -1)
             np.testing.assert_array_equal(snaps[blk, :2 * n], want)
         np.testing.assert_allclose(np.asarray(kc_s), np.asarray(kc_1),
@@ -310,8 +310,8 @@ def test_a_parked_rows_state_is_untouched_by_a_mixed_dispatch(toy):
     try:
         eng, n = be._eng, len(be.spec.state_layers)
         for sl in be._slots:
-            be._paged_ensure(sl, CONTEXT)
-        tables = be._tables()
+            be.slot_cache.cover(sl, CONTEXT)
+        tables = be.slot_cache.table()[0]
         step = eng._step_for(None)
         hist = np.random.default_rng(4).integers(3, 512, size=(4, 40))
         _, kc, vc, _ = step(eng.params, eng.rope, jnp.asarray(hist),
@@ -330,11 +330,11 @@ def test_a_parked_rows_state_is_untouched_by_a_mixed_dispatch(toy):
         snaps0, snaps1 = before.snaps[0], np.asarray(vc2.snaps)[0]
         for b in range(4):  # blocks 0 and 1 of every row were finished
             for j in (0, 1):
-                blk = int(be._tables_np[b, j])
+                blk = int(be.slot_cache.tables_np[b, j])
                 np.testing.assert_array_equal(snaps1[blk], snaps0[blk])
         # the lead crossed the block ends at 47, 63, 79, 95: four snapshots
         for j in (2, 3, 4, 5):
-            blk = int(be._tables_np[1, j])
+            blk = int(be.slot_cache.tables_np[1, j])
             assert np.abs(snaps1[blk, :2 * n]).max() > 0
             np.testing.assert_array_equal(
                 snaps1[blk, :2 * n],
@@ -452,9 +452,9 @@ def test_a_demoted_and_promoted_block_brings_its_snapshot_back(toy):
         snaps = np.asarray(be._eng.v_cache.snaps)[0].copy()
         # drop the slots' own tables, then demote every directory block
         for sl in be._slots:
-            be._paged_release_slot(sl)
-        be._demote(pc.stats()["dev_blocks"])
-        be._settle_demotions(force=True)
+            be.slot_cache.release(sl)
+        be.slot_cache.demote(pc.stats()["dev_blocks"])
+        be.slot_cache.settle(force=True)
         st = pc.stats()
         assert st["dev_blocks"] == 0 and st["cold_blocks"] >= 6
         # the host tier holds the typed payload: (k, v, state)
@@ -482,14 +482,14 @@ def test_close_and_a_pool_reclaim_free_both_kinds_together(toy):
     try:
         be.submit(_prompt(100, 93), 4, Sampler(512, temperature=0.0)).wait(300)
         assert be._eng.v_cache.snaps.shape[1] == be.kv_pool.n_blocks
-        assert be._kv_block_bytes == sum(
+        assert be.slot_cache.block_bytes == sum(
             c.nbytes // c.shape[1]
             for c in (be._eng.k_cache, be._eng.v_cache.rows,
                       be._eng.v_cache.snaps))
         used = be.kv_pool.used_blocks()
         assert used > 0
         for sl in be._slots:
-            be._paged_release_slot(sl)
+            be.slot_cache.release(sl)
         be.prefix_cache.reclaim(used, lambda bid: (_ for _ in ()).throw(
             RuntimeError("evict")))
         assert be.kv_pool.used_blocks() == 0

@@ -174,7 +174,7 @@ def test_cold_subtree_eviction_releases_dev_descendants():
 
 def test_reclaim_spares_the_excluded_slot():
     """Review regression: the adopting slot looks idle (req bound only
-    after _paged_adopt returns) — reclaim must never release the slot the
+    after the manager's `admit` returns) — reclaim must never release the slot the
     allocation is being performed FOR."""
     spec = _spec(seq_len=64)
     params = init_random_params(spec, FloatType.Q40, seed=3)
@@ -182,11 +182,11 @@ def test_reclaim_spares_the_excluded_slot():
                      prefix_cache=False, kv_block_tokens=8)
     try:
         slot = be._slots[0]
-        be._paged_ensure(slot, 16)
+        be.slot_cache.cover(slot, 16)
         assert len(slot.blocks) == 2 and slot.req is None
-        be._paged_reclaim(10 ** 6, exclude=slot)  # cannot be satisfied
+        be.slot_cache.reclaim(10 ** 6, exclude=slot)  # cannot be satisfied
         assert len(slot.blocks) == 2  # the excluded slot kept its table
-        be._paged_reclaim(10 ** 6)    # unshielded: idle stock IS reclaimed
+        be.slot_cache.reclaim(10 ** 6)    # unshielded: idle stock IS reclaimed
         assert slot.blocks == []
     finally:
         be.close()
@@ -321,7 +321,7 @@ def test_resume_over_remapped_blocks_byte_identical():
 
 
 def test_cold_promotion_does_not_leak_pool_blocks():
-    """Review regression (confirmed leak): _paged_adopt's cold promotion
+    """Review regression (confirmed leak): the admission's cold promotion
     allocates a device block, promote() takes the directory's ref, and the
     ALLOCATION ref must be dropped — or every demote→promote cycle orphans
     one block until the pool starves. Cycle the same prefix through the
@@ -336,7 +336,7 @@ def test_cold_promotion_does_not_leak_pool_blocks():
         time.sleep(0.2)
         used = []
         for i in range(3):
-            be._paged_reclaim(be.kv_pool.n_blocks)  # demote to cold
+            be.slot_cache.reclaim(be.kv_pool.n_blocks)  # demote to cold
             out = _run(be, prompt + [240 + i], 4)   # promote + remap
             assert len(out) == 4
             time.sleep(0.2)
@@ -481,9 +481,9 @@ def _engine(toy, **kw):
 def _sides(eng, bid):
     """What the pool holds of block `bid`, a host array a side: keys, values
     and, of a model with state layers, the block's state snapshot."""
-    from distributed_llama_tpu.runtime.batch_engine import _pool_sides
+    from distributed_llama_tpu.runtime.slot_cache import pool_sides
 
-    return tuple(np.asarray(c[:, bid]) for c in _pool_sides(eng))
+    return tuple(np.asarray(c[:, bid]) for c in pool_sides(eng))
 
 
 def _reference_reclaim(pc, n_blocks, read_block):
@@ -544,7 +544,7 @@ def _as_reference(be, reads):
         reads.append(bid)
         return _sides(eng, bid)
 
-    be._demote = lambda deficit: _reference_reclaim(pc, deficit, read_block)
+    be.slot_cache.demote = lambda deficit: _reference_reclaim(pc, deficit, read_block)
 
 
 def _directory(pc):
@@ -636,8 +636,8 @@ def test_hit_on_a_pending_payload_promotes_the_right_rows(toy):
         assert len(held) == 4
         for bid in held:
             held[bid] = _sides(eng, bid)
-        be._settle_demotions = lambda force=False: None  # nobody settles
-        be._paged_reclaim(be.kv_pool.n_blocks)
+        be.slot_cache.settle = lambda force=False: None  # nobody settles
+        be.slot_cache.reclaim(be.kv_pool.n_blocks)
         assert pc.unsettled == 4 and pc.stats()["cold_blocks"] == 4
         got = [_run(be, prompt + [9, 8], 6, vocab=vocab)]
         assert got == want
@@ -658,7 +658,7 @@ def _fill_directory(be, n_nodes, seed=0):
     over freshly written pool blocks."""
     import jax.numpy as jnp
 
-    eng, bt = be._eng, be._kv_bt
+    eng, bt = be._eng, be.slot_cache.block_tokens
     ids = be.kv_pool.alloc(n_nodes)
     rng = np.random.default_rng(seed)
     eng.k_cache = eng.k_cache.at[:, np.asarray(ids)].set(jnp.asarray(
@@ -672,7 +672,7 @@ def _fill_directory(be, n_nodes, seed=0):
 
 
 def test_no_program_compiles_for_reclaims_of_1_to_8_blocks(toy):
-    """(e) After the constructor and `_read_block(0)`, which is what the
+    """(e) After the constructor and `read_block(0)`, which is what the
     benchmark's warm-up calls, a reclaim of any size compiles nothing
     (counted as benchmark/run.py counts)."""
     import jax
@@ -686,13 +686,13 @@ def test_no_program_compiles_for_reclaims_of_1_to_8_blocks(toy):
 
     try:
         _fill_directory(be, 50)
-        be._read_block(0)
+        be.slot_cache.read_block(0)
         jax.monitoring.register_event_duration_secs_listener(on)
         for n in range(1, 9):
             free = be.kv_pool.free_blocks()
-            be._demote(n)
+            be.slot_cache.demote(n)
             assert be.kv_pool.free_blocks() == free + n
-        be._demote(11)  # more than the largest size: two gathers
+        be.slot_cache.demote(11)  # more than the largest size: two gathers
         assert be.prefix_cache.settle(force=True)[0] == 47
         assert not compiled
     finally:
@@ -709,7 +709,7 @@ def test_reset_and_close_with_payloads_pending(toy):
         pc, eng = be.prefix_cache, be._eng
         want = {b: _sides(eng, b) for b in ids}
         nodes = {n.handle[1]: n for n in pc.radix.root.children.values()}
-        be._demote(6)
+        be.slot_cache.demote(6)
         assert pc.unsettled == 6
         be.close()
         assert pc.unsettled == 0
@@ -723,7 +723,7 @@ def test_reset_and_close_with_payloads_pending(toy):
     be = _engine(toy, kv_pool_blocks=32, prefix_cache_blocks=16)
     try:
         _fill_directory(be, 6)
-        be._demote(4)
+        be.slot_cache.demote(4)
         assert be.prefix_cache.unsettled == 4
         be.kv_pool.reset()
         be.prefix_cache.reset()
@@ -926,8 +926,8 @@ def test_demotion_with_the_pool_sharded_over_kv_heads():
         held = [(np.asarray(eng.k_cache[:, n.handle[1]]),
                  np.asarray(eng.v_cache[:, n.handle[1]])) for n in lease.nodes]
         pc.release(lease)
-        be._settle_demotions = lambda force=False: None
-        be._paged_reclaim(be.kv_pool.n_blocks)
+        be.slot_cache.settle = lambda force=False: None
+        be.slot_cache.reclaim(be.kv_pool.n_blocks)
         assert pc.unsettled == len(held) == 4
         assert _run(be, prompt + [77], 6) == want
         lease = pc.lookup(prompt + [77])
@@ -951,7 +951,7 @@ def test_a_demoted_block_of_a_state_space_model_gives_its_snapshot_up():
 
     from benchmark import cells
     from benchmark import weights as W
-    from distributed_llama_tpu.runtime.batch_engine import _pool_sides
+    from distributed_llama_tpu.runtime.slot_cache import pool_sides
 
     cfg = {**cells.load_config("tiny-granite-hybrid"), "context": 512}
     weights = W.make_weights(cfg, 2**31 + 39)
@@ -960,7 +960,7 @@ def test_a_demoted_block_of_a_state_space_model_gives_its_snapshot_up():
                      superstep=4, kv_block_tokens=16, kv_pool_blocks=80,
                      tp=1, dtype=jnp.float32)
     try:
-        assert len(_pool_sides(be._eng)) == 2  # keys and values alone
+        assert len(pool_sides(be._eng)) == 2  # keys and values alone
         prompt = np.random.default_rng(9).integers(3, 512, 300).tolist()
         want = _run(be, prompt, 6, vocab=512)
         _settle(lambda: be.prefix_cache.total_refs() == 0)
@@ -971,9 +971,9 @@ def test_a_demoted_block_of_a_state_space_model_gives_its_snapshot_up():
         assert again.stats.reused_tokens == 256
         _settle(lambda: be.prefix_cache.total_refs() == 0)
         for sl in be._slots:
-            be._paged_release_slot(sl)
-        be._demote(be.prefix_cache.stats()["dev_blocks"])
-        be._settle_demotions(force=True)
+            be.slot_cache.release(sl)
+        be.slot_cache.demote(be.prefix_cache.stats()["dev_blocks"])
+        be.slot_cache.settle(force=True)
         assert be.prefix_cache.stats()["cold_blocks"] >= 16
         assert snaps.held() == 0
         cold = be.submit(list(prompt), 6, Sampler(512, temperature=0.0))

@@ -436,7 +436,7 @@ def test_clamped_park_releases_radix_reservation():
     params = init_random_params(spec, FloatType.Q40, seed=5)
     # paged_kv=False: white-box test of the DENSE lease-shrink machinery
     # (slot.history/lease poking); paged leases shrink through the same
-    # _truncate_history path and are covered by test_paged_kv.py
+    # manager's `truncate` path and are covered by test_paged_kv.py
     be = BatchEngine(spec, params, slots=2, tp=1, prefix_cache=True,
                      prefix_block_tokens=4, paged_kv=False)
     try:

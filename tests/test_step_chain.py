@@ -360,7 +360,7 @@ def _harvest_run(pipeline):
             assert n < 500
         slot = be._slots[0]
         history, blocks = list(slot.history), list(slot.blocks)
-        bt = be._kv_bt
+        bt = be.slot_cache.block_tokens
         fl = be._inflight
         if pipeline:
             # A stopped at a delivery; the dispatch issued before it still
@@ -629,10 +629,10 @@ def test_a_direct_caller_of_a_step_program_gets_what_it_always_got():
     try:
         eng = be._eng
         step = eng._step_for(None)
-        tables = jnp.asarray(be._tables_np)
-        be._paged_ensure(be._slots[0], 2)
-        be._paged_ensure(be._slots[1], 2)
-        tables = jnp.asarray(be._tables_np)
+        tables = jnp.asarray(be.slot_cache.tables_np)
+        be.slot_cache.cover(be._slots[0], 2)
+        be.slot_cache.cover(be._slots[1], 2)
+        tables = jnp.asarray(be.slot_cache.tables_np)
         toks = jnp.asarray(np.array([[5], [7]], np.int32))
         pos = jnp.asarray(np.zeros(2, np.int32))
         logits, kc, vc = step(eng.params, eng.rope, toks, eng.k_cache,

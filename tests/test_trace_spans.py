@@ -351,8 +351,9 @@ def test_visited_pairs_follow_each_rows_length(positions, starts, budget,
                                                lead, want):
     import types
 
-    be = types.SimpleNamespace(slots_n=len(starts), _kv_bt=16,
-                               _eng=types.SimpleNamespace(paged_kernel=True))
+    be = types.SimpleNamespace(
+        slots_n=len(starts), _eng=types.SimpleNamespace(paged_kernel=True),
+        slot_cache=types.SimpleNamespace(block_tokens=16))
     names = ("batch_attn_pairs_visited_total",
              "batch_attn_pairs_dispatched_total")
     before = metrics.snapshot()
