@@ -119,6 +119,17 @@ _KIND_FIELDS = (
     ("ssm_heads", int), ("ssm_head_dim", int), ("ssm_state", int),
     ("ssm_groups", _or_one),
 )
+# a second bank a kind, at KIND_MORE_0: a delta-rule kind's heads, key and
+# value sizes and gate rank, and a latent kind's row and head widths (0 of
+# every other kind, and of a file written before them)
+_KIND_MORE_FIELDS = (
+    ("kda_heads", int), ("kda_key_dim", int), ("kda_value_dim", int),
+    ("kda_rank", int),
+    ("q_lora_rank", int), ("kv_lora_rank", int), ("qk_nope_head_dim", int),
+    ("qk_rope_head_dim", int), ("v_head_dim", int),
+)
+_KIND_BANKS = ((HeaderKey.KIND_0, _KIND_FIELDS),
+               (HeaderKey.KIND_MORE_0, _KIND_MORE_FIELDS))
 _KIND_STRIDE = 16
 _MAX_KINDS = 4  # two bits a layer
 _KIND_LAYERS_A_WORD = 15
@@ -136,10 +147,12 @@ def _pack_layer_kinds(spec: ModelSpec) -> list[tuple[int, int]]:
             spec.layer_kinds[w:w + _KIND_LAYERS_A_WORD]))
         kv.append((HeaderKey.LAYER_KINDS_0 + w // _KIND_LAYERS_A_WORD, word))
     for k, kind in enumerate(spec.kinds):
-        for i, (name, conv) in enumerate(_KIND_FIELDS):
-            value = getattr(kind, name)
-            kv.append((HeaderKey.KIND_0 + _KIND_STRIDE * k + i,
-                       _as_int(value, conv)))
+        for base, bank in _KIND_BANKS:
+            for i, (name, conv) in enumerate(bank):
+                value = getattr(kind, name)
+                if base == HeaderKey.KIND_0 or value:
+                    kv.append((base + _KIND_STRIDE * k + i,
+                               _as_int(value, conv)))
     return kv
 
 
@@ -150,8 +163,9 @@ def _unpack_layer_kinds(kv: dict[int, int], n_layers: int) -> dict:
         return {}
     kinds = tuple(
         LayerKind(name=f"kind{k}", **{
-            name: conv(kv.get(HeaderKey.KIND_0 + _KIND_STRIDE * k + i, 0))
-            for i, (name, conv) in enumerate(_KIND_FIELDS)})
+            name: conv(kv.get(base + _KIND_STRIDE * k + i, 0))
+            for base, bank in _KIND_BANKS
+            for i, (name, conv) in enumerate(bank)})
         for k in range(n))
     layer_kinds = tuple(
         (kv[HeaderKey.LAYER_KINDS_0 + l // _KIND_LAYERS_A_WORD]
@@ -463,7 +477,8 @@ def write_model(path: str, spec: ModelSpec, tensors_iter, weights_ftype: FloatTy
     norm_names = {"embedding", "rms_att", "rms_ffn", "rms_moe", "rms_ffn2", "rms_final",
                   "rms_q", "rms_kv", "rms_qh", "rms_kh", "conv_w",
                   "router_bias",
-                  *(n for n in SSM if n not in ("ssm_in", "ssm_out"))}
+                  *(n for n in SSM if n not in ("ssm_in", "ssm_out")),
+                  "kda_conv_w", "kda_dt_bias", "kda_a_log", "kda_norm"}
     with open(path, "wb") as f:
         write_header(f, spec, weights_ftype)
         for name, tensor in tensors_iter:

@@ -53,6 +53,13 @@ Arch-specific structure:
   residual branches, logits), a stated attention scale and a kind without
   a rotation are data on `ModelSpec` as well and absent from every other
   model's program.
+- A delta-rule mixer (`LayerKind.kda_heads`; Kimi-Linear's KDA layers,
+  `_kda_mixer`) is the third state kind, picked by the same flag: its tail
+  is the convolution's input [q | k | v], its matrix a head (key x value) the
+  same carry, updated in place by `ops/pallas_kda.py`. The attention kind
+  beside it may be LATENT (`LayerKind.kv_lora_rank`): `_Mixers.mix` then
+  calls `_latent_attention`, with q through one projection where the kind
+  states no q rank, and no rotation where it states none.
 - GROK1: embedding x78.38367176906169 (grok1-tasks.cpp:11-14); attention output is
   rmsnorm'd (rms_ffn) BEFORE the residual join (grokRmfFfn*, grok1-tasks.cpp:16-41);
   MoE input norm uses rms_moe; MoE output is rmsnorm'd with rms_ffn2 before its residual
@@ -476,6 +483,38 @@ def _silu(v):
     return v * jax.nn.sigmoid(v)
 
 
+def _through_the_stream(h, stream: _Stream, rows: "RowMap | None", args,
+                        chunk, step):
+    """A matrix state's recurrence on whatever rows the stream has: `args`
+    the per-position arrays, each (cb, ct, ...); `chunk(h, slot, *a, live,
+    fresh)` takes T positions of ONE slot, `step(h, *a, live, fresh)` one
+    position of every slot, both updating h in place. A compact stream is
+    the lead's chunk and one row a slot, a T = 1 dispatch one step, a
+    rectangle a chunk a row. Returns (y (cb, ct, ...) float32, h)."""
+    fresh, live = stream.pos == 0, stream.live
+    cb, ct = stream.pos.shape
+    if rows is not None:  # a compact stream: the lead's chunk, riders
+        t, slots = rows.t, rows.slots
+        y0, h = chunk(h, rows.lead, *(a[0, :t] for a in args), live[0, 0],
+                      fresh[0, 0])
+        rid = slice(t, t + slots)
+        y1, h = step(h, *(a[0, rid] for a in args), live[0, rid],
+                     fresh[0, rid])
+        y = jnp.concatenate([y0, y1, jnp.zeros(
+            (ct - t - slots, *y0.shape[1:]), jnp.float32)])[None]
+    elif ct == 1:
+        y, h = step(h, *(a[:, 0] for a in args), live[:, 0], fresh[:, 0])
+        y = y[:, None]
+    else:  # a rectangle: every row is a chunk of its own slot
+        ys = []
+        for i in range(cb):
+            yi, h = chunk(h, i, *(a[i] for a in args), live[i, 0],
+                          fresh[i, 0])
+            ys.append(yi)
+        y = jnp.stack(ys)
+    return y, h
+
+
 def _ssm_mixer(x, bp, state_idx, spec: ModelSpec, ring, h, stream: _Stream,
                rows: "RowMap | None", use_pallas, residual):
     """A state-space mixer (Mamba-2) in attention's place, on whatever rows
@@ -521,35 +560,99 @@ def _ssm_mixer(x, bp, state_idx, spec: ModelSpec, ring, h, stream: _Stream,
         dt = jax.nn.softplus(dt.astype(jnp.float32)
                              + bp["ssm_dt_bias"].astype(jnp.float32))
         a = -jnp.exp(bp["ssm_a_log"].astype(jnp.float32))
-        fresh, live = stream.pos == 0, stream.live
-        kw = {"use_pallas": use_pallas}
-        if rows is not None:  # a compact stream: the lead's chunk, riders
-            t, slots = rows.t, rows.slots
-            y0, h = ssd_chunk(h, state_idx, rows.lead, xs[0, :t], dt[0, :t],
-                              a, b_[0, :t], c_[0, :t], live[0, 0],
-                              fresh[0, 0], **kw)
-            rid = slice(t, t + slots)
-            y1, h = ssd_step(h, state_idx, xs[0, rid], dt[0, rid], a,
-                             b_[0, rid], c_[0, rid], live[0, rid],
-                             fresh[0, rid], **kw)
-            y = jnp.concatenate([y0, y1, jnp.zeros(
-                (ct - t - slots, heads, p), jnp.float32)])[None]
-        elif ct == 1:
-            y, h = ssd_step(h, state_idx, xs[:, 0], dt[:, 0], a, b_[:, 0],
-                            c_[:, 0], live[:, 0], fresh[:, 0], **kw)
-            y = y[:, None]
-        else:  # a rectangle: every row is a chunk of its own slot
-            ys = []
-            for i in range(cb):
-                yi, h = ssd_chunk(h, state_idx, i, xs[i], dt[i], a, b_[i],
-                                  c_[i], live[i, 0], fresh[i, 0], **kw)
-                ys.append(yi)
-            y = jnp.stack(ys)
+        y, h = _through_the_stream(
+            h, stream, rows, (xs, dt, b_, c_),
+            lambda h, slot, x_, d_, *rest: ssd_chunk(
+                h, state_idx, slot, x_, d_, a, *rest, use_pallas=use_pallas),
+            lambda h, x_, d_, *rest: ssd_step(
+                h, state_idx, x_, d_, a, *rest, use_pallas=use_pallas))
         y = y + bp["ssm_d"].astype(jnp.float32)[:, None] * xs
         g = rmsnorm(y.reshape(cb, ct, inner) * _silu(z.astype(jnp.float32)),
                     bp["ssm_norm"], spec.norm_eps).astype(x.dtype)
         out = qmatmul(g, bp["ssm_out"], use_pallas=use_pallas,
                       name="q4_mm_ssm_out")
+    return residual + _times(out, spec.residual_multiplier), u, h
+
+
+def _held_mm(a, w, use_pallas):
+    """a @ w^T for a weight the engine holds dense (`params.hold_dense`):
+    a plain product in the stream's type, float32 sums; through `qmatmul`
+    where a caller hands the loader's blocks as they are."""
+    if isinstance(w, (QTensor, LayerOf)):
+        return qmatmul(a, w, use_pallas=use_pallas)
+    return jnp.einsum("...i,oi->...o", a, w.astype(a.dtype),
+                      preferred_element_type=jnp.float32).astype(a.dtype)
+
+
+def _kda_mixer(x, bp, state_idx, spec: ModelSpec, ring, h, stream: _Stream,
+               rows: "RowMap | None", use_pallas, residual):
+    """A delta-rule mixer (Kimi Delta Attention) in attention's place, on
+    whatever rows the stream has, h the normed block input, a head of K key
+    and V value channels:
+
+        u    = kda_in h                   [q | k | v] ahead of the taps
+        u'_p = silu(sum_j w[:, j] u_{p-k+1+j})   depthwise, causal, k taps,
+                                          zeros before position 0, no bias
+        q, k = l2norm(u' q-part), l2norm(u' k-part) a head;  v = u' v-part
+        [f | z] = kda_lo h                the two gates' first projections
+        g    = -exp(a_log) softplus(kda_fb f + dt_bias)      a key channel
+        beta = sigmoid(kda_b h)                              a head
+        S_p  = (I - beta k k^T) Diag(exp g) S_{p-1} + beta k v^T   float32
+        o_p  = K^-1/2 S_p^T q
+        out  = kda_out [RMSNorm(o; kda_norm, a head) * sigmoid(kda_gb z)]
+
+    A row's earlier u come from the stream or the slot's ring, exactly as a
+    state-space mixer's do (`_state_prev`), and the new rows u are returned
+    for forward() to commit. S is the layer scan's carry `h`, updated in
+    place (ops/pallas_kda.py): a lone token of every slot through `kda_step`,
+    the T tokens of one slot through `kda_chunk`. Returns (residual-joined
+    output, u, h)."""
+    from ..ops.pallas_kda import kda_chunk, kda_step
+
+    heads, dk, dv = spec.state_matrix
+    rank = spec.kda_rank
+    xb = rmsnorm(x, bp["rms_att"], spec.norm_eps)
+    f32 = jnp.float32
+    with jax.named_scope("kda_mixer"):
+        u = qmatmul(xb, bp["kda_in"], use_pallas=use_pallas,
+                    name="q4_mm_kda_in")
+        taps = bp["kda_conv_w"].astype(f32)  # (state_width, k)
+        k = taps.shape[-1]
+        acc = taps[:, k - 1] * u.astype(f32)
+        for j in range(1, k):
+            acc = acc + taps[:, k - 1 - j] * _state_prev(
+                u, ring, state_idx, stream, j).astype(f32)
+        qkv = _silu(acc)
+        cb, ct = qkv.shape[:2]
+
+        def unit(a):  # a head's values at unit length
+            a = a.reshape(cb, ct, heads, dk)
+            return a * jax.lax.rsqrt(
+                jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+
+        qs = unit(qkv[..., :heads * dk])
+        ks = unit(qkv[..., heads * dk:2 * heads * dk])
+        vs = qkv[..., 2 * heads * dk:].reshape(cb, ct, heads, dv)
+        with jax.named_scope("kda_gate"):
+            lo = qmatmul(xb, bp["kda_lo"], use_pallas=use_pallas,
+                         name="q4_mm_kda_lo")
+            f = _held_mm(lo[..., :rank], bp["kda_fb"], use_pallas)
+            g = -jnp.exp(bp["kda_a_log"].astype(f32))[:, None] * (
+                jax.nn.softplus(f.astype(f32) + bp["kda_dt_bias"].astype(
+                    f32)).reshape(cb, ct, heads, dk))
+            beta = jax.nn.sigmoid(qmatmul(
+                xb, bp["kda_b"], use_pallas=use_pallas).astype(f32))
+            gate = jax.nn.sigmoid(_held_mm(
+                lo[..., rank:], bp["kda_gb"], use_pallas).astype(f32))
+        o, h = _through_the_stream(
+            h, stream, rows, (qs, ks, vs, g, beta),
+            lambda h, slot, *a: kda_chunk(h, state_idx, slot, *a,
+                                          use_pallas=use_pallas),
+            lambda h, *a: kda_step(h, state_idx, *a, use_pallas=use_pallas))
+        y = (rmsnorm(o, bp["kda_norm"], spec.norm_eps).reshape(cb, ct, -1)
+             * gate).astype(x.dtype)
+        out = qmatmul(y, bp["kda_out"], use_pallas=use_pallas,
+                      name="q4_mm_kda_out")
     return residual + _times(out, spec.residual_multiplier), u, h
 
 
@@ -956,9 +1059,10 @@ def _latent_attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc,
     """Latent attention (spec.latent; the DeepSeek-V3 graph) in its ABSORBED
     form, against a cache of ONE row a token a layer, which it only reads:
 
-        q          = wq_b norm_q(wq_a h), a head [q_nope ; q_pe]
+        q          = wq_b norm_q(wq_a h), a head [q_nope ; q_pe] (wq h where
+                     the kind states no q rank)
         [c ; k_pe] = wkv_a h; c normed, k_pe (one vector for all heads) and
-                     q_pe rotated
+                     q_pe rotated (as they are under RopeType.NONE)
         row        = [c ; k_pe ; 0..] (what the cache holds, spec.cache_widths)
         q'         = [q_nope w_uk ; q_pe ; 0..]     per head, as wide as a row
         scores     = q' . row x spec.attn_scale, causal, softmax in float32
@@ -984,9 +1088,12 @@ def _latent_attention(x, bp, layer_idx, spec: ModelSpec, rope: RopeTables, kc,
     width = kc.shape[-1]
     s = kc.shape[3]
     xb = rmsnorm(x, bp["rms_att"], spec.norm_eps)
-    qa = rmsnorm(qmatmul(xb, bp["wq_a"], use_pallas=use_pallas), bp["rms_q"],
-                 spec.norm_eps)
-    q = qmatmul(qa, bp["wq_b"], use_pallas=use_pallas)
+    if "wq_a" in bp:
+        qa = rmsnorm(qmatmul(xb, bp["wq_a"], use_pallas=use_pallas),
+                     bp["rms_q"], spec.norm_eps)
+        q = qmatmul(qa, bp["wq_b"], use_pallas=use_pallas)
+    else:  # a kind that states no q rank: one projection, no norm
+        q = qmatmul(xb, bp["wq"], use_pallas=use_pallas)
     q = q.reshape(cb, ct, q.shape[-1] // (dn + dr), dn + dr)
     kv = qmatmul(xb, bp["wkv_a"], use_pallas=use_pallas)  # (B, T, r + dr)
     c = rmsnorm(kv[..., :r], bp["rms_kv"], spec.norm_eps)
@@ -1402,22 +1509,29 @@ class _Mixers(NamedTuple):
 
         def attend(x, h):
             names = [n for n in self.tensors if not is_state_tensor(n)]
+            own = {**bp, **{n: pick(self.tensors[n]) for n in names}}
+            kw = {"block_tables": block_tables, "block_tokens": block_tokens,
+                  "paged_kernel": paged_kernel, "residual": x, "rows": rows}
             with jax.named_scope("attn"):
-                out, kv = _attention(
-                    x, {**bp, **{n: pick(self.tensors[n]) for n in names}},
-                    idx, self.attn, self.rope, kc, vc, start_pos, positions,
-                    axis_name, None, 1, use_pallas, compress, window,
-                    block_tables=block_tables, block_tokens=block_tokens,
-                    paged_kernel=paged_kernel, residual=x, rows=rows)
+                if self.attn.latent:
+                    out, kv = _latent_attention(
+                        x, own, idx, self.attn, self.rope, kc, vc, start_pos,
+                        positions, axis_name, use_pallas, compress, window,
+                        **kw)
+                else:
+                    out, kv = _attention(
+                        x, own, idx, self.attn, self.rope, kc, vc, start_pos,
+                        positions, axis_name, None, 1, use_pallas, compress,
+                        window, **kw)
             return (out, *kv, no_state, h)
 
         def convolve(x, h):
             names = [n for n in self.tensors if is_state_tensor(n)]
             own = {**bp, **{n: pick(self.tensors[n]) for n in names}}
-            if self.conv.ssm_state:
-                out, v, h = _ssm_mixer(x, own, idx, self.conv, self.ring, h,
-                                       self.stream, rows, use_pallas,
-                                       residual=x)
+            if self.conv.ssm_state or self.conv.kda_heads:
+                mixer = _kda_mixer if self.conv.kda_heads else _ssm_mixer
+                out, v, h = mixer(x, own, idx, self.conv, self.ring, h,
+                                  self.stream, rows, use_pallas, residual=x)
             else:
                 out, v = _short_conv(x, own, idx, self.conv, self.ring,
                                      self.stream, use_pallas, residual=x)

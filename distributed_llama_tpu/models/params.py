@@ -64,7 +64,15 @@ def block_tensor_shapes(spec: ModelSpec, lead: bool = False
     state_width + heads, dim: the gate z, the convolution's input [x | B | C]
     and dt, in that order), the taps ssm_conv_w (state_width, conv_kernel)
     with their bias ssm_conv_b, a head's ssm_dt_bias, ssm_a_log and ssm_d,
-    the gated norm's weight ssm_norm (inner,) and ssm_out (dim, inner).
+    the gated norm's weight ssm_norm (inner,) and ssm_out (dim, inner);
+    a delta-rule kind (spec.kda_heads > 0; Kimi Delta Attention) has kda_in
+    (state_width, dim: the convolution's input [q | k | v]), the taps
+    kda_conv_w (state_width, conv_kernel), kda_lo (2 rank, dim: the decay's
+    and the output gate's first projections [f_a | g_a]), their second
+    projections kda_fb (heads x key, rank) and kda_gb (heads x value, rank),
+    kda_b (heads, dim: beta), a channel's kda_dt_bias, a head's kda_a_log,
+    the head norm's weight kda_norm (value,) and kda_out (dim, heads x value).
+    A latent kind with q_lora_rank 0 has ONE wq (q_dim, dim) and no rms_q.
     QK-norm (spec.qk_norm) adds rms_qh and rms_kh (head_size,), a selection
     bias (spec.router_bias) router_bias (n_router,) behind the router.
     """
@@ -72,7 +80,21 @@ def block_tensor_shapes(spec: ModelSpec, lead: bool = False
     d, h, kv, e = spec.dim, spec.hidden_dim, spec.kv_dim, spec.n_experts
     qd = spec.q_dim  # n_heads x head_size: dim unless the header states head_dim
     shapes: dict[str, tuple[tuple[int, ...], bool]]
-    if spec.ssm_state:
+    if spec.kda_heads:
+        nh, cw, rank = spec.kda_heads, spec.state_width, spec.kda_rank
+        shapes = {
+            "kda_in": ((cw, d), True),
+            "kda_conv_w": ((cw, spec.conv_kernel), False),
+            "kda_lo": ((2 * rank, d), True),
+            "kda_fb": ((nh * spec.kda_key_dim, rank), True),
+            "kda_gb": ((nh * spec.kda_value_dim, rank), True),
+            "kda_b": ((nh, d), True),
+            "kda_dt_bias": ((nh * spec.kda_key_dim,), False),
+            "kda_a_log": ((nh,), False),
+            "kda_norm": ((spec.kda_value_dim,), False),
+            "kda_out": ((d, nh * spec.kda_value_dim), True),
+        }
+    elif spec.ssm_state:
         inner, cw = spec.ssm_inner, spec.state_width
         nh = spec.ssm_heads
         shapes = {
@@ -94,8 +116,9 @@ def block_tensor_shapes(spec: ModelSpec, lead: bool = False
     elif spec.latent:
         r, nh = spec.kv_lora_rank, spec.n_heads
         shapes = {
-            "wq_a": ((spec.q_lora_rank, d), True),
-            "wq_b": ((qd, spec.q_lora_rank), True),
+            **({"wq_a": ((spec.q_lora_rank, d), True),
+                "wq_b": ((qd, spec.q_lora_rank), True)}
+               if spec.q_lora_rank else {"wq": ((qd, d), True)}),
             "wkv_a": ((r + spec.qk_rope_head_dim, d), True),
             "w_uk": ((nh, spec.qk_nope_head_dim, r), True),
             "w_uv": ((nh, spec.v_head_dim, r), True),
@@ -134,7 +157,8 @@ def block_tensor_shapes(spec: ModelSpec, lead: bool = False
     shapes["rms_att"] = ((d,), False)
     shapes["rms_ffn"] = ((d,), False)
     if spec.latent:
-        shapes["rms_q"] = ((spec.q_lora_rank,), False)
+        if spec.q_lora_rank:
+            shapes["rms_q"] = ((spec.q_lora_rank,), False)
         shapes["rms_kv"] = ((spec.kv_lora_rank,), False)
     if spec.arch_type == ArchType.GROK1:
         shapes["rms_moe"] = ((d,), False)
@@ -148,14 +172,17 @@ def block_tensor_shapes(spec: ModelSpec, lead: bool = False
 # the run's (`run_tensor_shapes`)
 SSM = ("ssm_in", "ssm_conv_w", "ssm_conv_b", "ssm_dt_bias", "ssm_a_log",
        "ssm_d", "ssm_norm", "ssm_out")
+KDA = ("kda_in", "kda_conv_w", "kda_lo", "kda_fb", "kda_gb", "kda_b",
+       "kda_dt_bias", "kda_a_log", "kda_norm", "kda_out")
+LATENT = ("wq_a", "wq_b", "wkv_a", "w_uk", "w_uv", "rms_q", "rms_kv")
 MIXER = frozenset({"wq", "wk", "wv", "wqkv", "wo", "rms_qh", "rms_kh",
-                   "conv_in", "conv_w", "conv_out", *SSM})
+                   "conv_in", "conv_w", "conv_out", *SSM, *KDA, *LATENT})
 
 
 def is_state_tensor(name: str) -> bool:
     """Whether a MIXER tensor is the state kind's (a convolution's, a
-    state-space mixer's) and not attention's."""
-    return name.startswith(("conv_", "ssm_"))
+    state-space mixer's, a delta-rule mixer's) and not attention's."""
+    return name.startswith(("conv_", "ssm_", "kda_"))
 
 
 def run_tensor_shapes(spec: ModelSpec, run) -> dict[
@@ -197,6 +224,10 @@ _SSM_DRAWN = {
     "ssm_a_log": lambda n, k: np.log(np.linspace(1.0, 16.0, n)),
     "ssm_d": lambda n, k: 1.0,
 }
+# a delta-rule mixer's: the same taps (no bias), a channel's step of 0.001 to
+# 0.1 ahead of the softplus, A between 1 and 16 over the heads
+_SSM_DRAWN.update({"kda_" + n: _SSM_DRAWN["ssm_" + n]
+                   for n in ("conv_w", "dt_bias", "a_log")})
 
 
 def init_random_params(spec: ModelSpec, weights_ftype: FloatType = FloatType.F32,
@@ -245,8 +276,11 @@ def stack_names(params: Params) -> list[str]:
 
 
 # the two halves of latent attention's kv_b projection: batched by head and
-# small, so they are held dense in the engine's dtype, not as Q40 blocks
-_HELD_DENSE = ("w_uk", "w_uv")
+# small, so they are held dense in the engine's dtype, not as Q40 blocks; and
+# a delta-rule mixer's two second gate projections, whose input is the rank
+# (128): four blocks a row, which no kernel here reads and whose scale plane
+# XLA re-laid in every program (the compiled text, PR 48)
+_HELD_DENSE = ("w_uk", "w_uv", "kda_fb", "kda_gb")
 
 
 def hold_dense(params: Params, dtype) -> Params:
@@ -271,6 +305,7 @@ _I8_CONVERTIBLE = (FloatType.Q40, FloatType.Q80)
 # (QTensor.to_i4p_layout).
 _DENSE_MATMULS = {"wq", "wk", "wv", "wo", "wg", "w1", "w2", "w3",
                   "conv_in", "conv_out", "ssm_in", "ssm_out",
+                  "kda_in", "kda_lo", "kda_b", "kda_out",
                   "moe_up", "moe_gate", "moe_down",
                   "wq_a", "wq_b", "wkv_a", "sh_gate", "sh_up", "sh_down"}
 _COL_SHARDED = {"wo", "w2", "moe_down", "sh_down"}

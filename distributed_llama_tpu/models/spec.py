@@ -137,6 +137,10 @@ class HeaderKey(enum.IntEnum):
     STATE_SNAPSHOTS = 84
     LAYER_KINDS_0 = 64  # ..79
     KIND_0 = 100
+    # a kind's further fields (a delta-rule mixer's, latent attention's as a
+    # kind), at KIND_MORE_0 + KIND_STRIDE x k + its offset
+    # (formats/mfile.py _KIND_MORE_FIELDS)
+    KIND_MORE_0 = 200
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -148,10 +152,12 @@ def yarn_mscale(factor: float, mscale: float) -> float:
 class LayerKind:
     """One kind of layer of a model whose layers differ in more than a 0/1
     switch (ModelSpec.kinds): an attention layer's query heads, its window,
-    its rotation, or (conv_kernel > 0) a layer that holds a STATE and no
-    keys and values at all: a gated short convolution, or (ssm_state > 0) a
-    state-space mixer (Mamba-2). Every field overrides the ModelSpec field
-    of the same name for the layers of this kind (`ModelSpec.of_kind`)."""
+    its rotation, its latent row (kv_lora_rank > 0), or (conv_kernel > 0) a
+    layer that holds a STATE and no keys and values at all: a gated short
+    convolution, (ssm_state > 0) a state-space mixer (Mamba-2), or
+    (kda_heads > 0) a delta-rule mixer (Kimi Delta Attention). Every field
+    overrides the ModelSpec field of the same name for the layers of this
+    kind (`ModelSpec.of_kind`)."""
 
     name: str
     n_heads: int
@@ -183,6 +189,29 @@ class LayerKind:
     ssm_head_dim: int = 0
     ssm_state: int = 0
     ssm_groups: int = 1
+    # a delta-rule mixer (Kimi Delta Attention; models/forward.py
+    # _kda_mixer): kda_heads heads, each with a running MATRIX S of
+    # kda_key_dim x kda_value_dim in float32 that is decayed BY CHANNEL
+    # (a decay a key channel a position) and corrected by the delta rule
+    #   S <- Diag(a_t) S;  u_t = beta_t (v_t - S^T k_t);  S <- S + k_t u_t^T
+    # ahead of it a depthwise causal convolution of conv_kernel taps (no
+    # bias) over the kda_heads x (2 kda_key_dim + kda_value_dim) values of
+    # [q | k | v]; the decay and the output gate each through a pair of
+    # projections of rank kda_rank. Its state after position p: the
+    # matrices, and the convolution's last conv_kernel - 1 input rows
+    kda_heads: int = 0
+    kda_key_dim: int = 0
+    kda_value_dim: int = 0
+    kda_rank: int = 0
+    # latent attention as a KIND (the attention layers beside a model's
+    # state layers): the fields of ModelSpec's latent attention, stated
+    # here where the model's other layers hold no keys at all.
+    # q_lora_rank 0: q through ONE projection wq and no norm of its own
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
 
 class Run(NamedTuple):
@@ -234,7 +263,9 @@ class ModelSpec:
     # a rank-q_lora_rank pair of projections, keys and values through ONE
     # latent row a token of kv_lora_rank values (normed) and qk_rope_head_dim
     # rotated ones, shared by all heads; a head's q and k are qk_nope_head_dim
-    # + qk_rope_head_dim wide, its v v_head_dim. n_kv_heads is 1
+    # + qk_rope_head_dim wide, its v v_head_dim. n_kv_heads is 1. A model
+    # with state layers states these on its attention KIND (LayerKind), and
+    # q_lora_rank 0 there is a q through one projection `wq`
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -297,6 +328,11 @@ class ModelSpec:
     ssm_head_dim: int = 0
     ssm_state: int = 0
     ssm_groups: int = 1
+    # a kind's delta-rule mixer (LayerKind.kda_*), likewise
+    kda_heads: int = 0
+    kda_key_dim: int = 0
+    kda_value_dim: int = 0
+    kda_rank: int = 0
     # the graph's stated multipliers: the embedding's rows times the first,
     # each residual branch (mixer, FFN) times the second before it joins the
     # stream, the logits DIVIDED by the third. 1.0: not in the program
@@ -313,30 +349,36 @@ class ModelSpec:
     state_snapshots: int = 0
 
     # --- derived (reference: transformer.cpp:102-106) ---
+    def _latent_kind(self):
+        """Where latent attention's fields stand: the kind that states a
+        latent row (a model with state layers), else this spec."""
+        return next((k for k in self.kinds if k.kv_lora_rank), self)
+
     @property
     def latent(self) -> bool:
-        return self.kv_lora_rank > 0
+        return self._latent_kind().kv_lora_rank > 0
 
     @property
     def head_size(self) -> int:
         """Width of a head's q (and k): where attention is latent, the part
         that is not rotated and the part that is."""
         if self.latent:
-            return self.qk_nope_head_dim + self.qk_rope_head_dim
+            k = self._latent_kind()
+            return k.qk_nope_head_dim + k.qk_rope_head_dim
         return self.head_dim or self.dim // self.n_heads
 
     @property
     def rope_width(self) -> int:
         """Values of a head the rotation covers (the tables' width x 2)."""
         if self.latent:
-            return self.qk_rope_head_dim
+            return self._latent_kind().qk_rope_head_dim
         return self.rotary_dim or self.head_size
 
     @property
     def o_dim(self) -> int:
         """Width of wo's input: n_heads x the width of a head's v."""
-        return self.n_heads * (self.v_head_dim if self.latent
-                               else self.head_size)
+        k = self._latent_kind()
+        return k.n_heads * (k.v_head_dim if self.latent else self.head_size)
 
     @property
     def cache_widths(self) -> tuple[int, int]:
@@ -345,8 +387,8 @@ class ModelSpec:
         with zeros to whole lanes of 128 (576 to 640: the chip's tiled memory
         pads the minor axis so whatever is asked for), and no second side."""
         if self.latent:
-            return (-(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128)
-                    * 128, 0)
+            k = self._latent_kind()
+            return (-(-(k.kv_lora_rank + k.qk_rope_head_dim) // 128) * 128, 0)
         return self.head_size, self.head_size
 
     def cache_row_bytes(self, itemsize: int) -> int:
@@ -356,16 +398,20 @@ class ModelSpec:
     @property
     def mixed(self) -> bool:
         """Whether some kind of layer holds a state and no keys and values
-        (a convolution, a state-space mixer): such a model's layers stand in
+        (a convolution, a state-space mixer, a delta-rule mixer): such a
+        model's layers stand in
         TWO runs at most (`runs`), each one scan whose body picks the mixer
         by a per-layer flag."""
         return any(k.conv_kernel for k in self.kinds)
 
     @property
     def ssm(self) -> bool:
-        """Whether the state layers are state-space mixers: their state is a
-        matrix a head beside the convolution's rows (`state_matrix`)."""
-        return any(k.ssm_state for k in self.kinds) or self.ssm_state > 0
+        """Whether the state layers hold a MATRIX a head beside the
+        convolution's rows (`state_matrix`): state-space mixers, whose
+        matrix decays by a scalar, and delta-rule mixers, whose matrix
+        decays by channel and is corrected. What the cache manager does for
+        one (stride snapshots, `held`, the host's word) it does for both."""
+        return self.state_matrix is not None
 
     @property
     def state_layers(self) -> tuple[int, ...]:
@@ -390,15 +436,21 @@ class ModelSpec:
         convolution (its v), and of a state-space mixer the convolution's
         input [x | B | C]."""
         k = self._state_kind()
+        if k.kda_heads:  # the convolution's input [q | k | v]
+            return k.kda_heads * (2 * k.kda_key_dim + k.kda_value_dim)
         if not k.ssm_state:
             return self.dim
         return k.ssm_heads * k.ssm_head_dim + 2 * k.ssm_groups * k.ssm_state
 
     @property
     def state_matrix(self) -> tuple[int, int, int] | None:
-        """(heads, head size, state size) of the running matrix a state
-        layer holds a sequence in float32; None: a convolution holds none."""
+        """(heads, rows, columns) of the running matrix a state layer holds
+        a sequence in float32: (heads, head size, state size) of a
+        state-space mixer, (heads, key size, value size) of a delta-rule
+        mixer; None: a convolution holds none."""
         k = self._state_kind()
+        if k.kda_heads:
+            return k.kda_heads, k.kda_key_dim, k.kda_value_dim
         if not k.ssm_state:
             return None
         return k.ssm_heads, k.ssm_head_dim, k.ssm_state
@@ -542,7 +594,9 @@ class ModelSpec:
         if spec.latent:
             assert spec.n_kv_heads == 1, "a latent row is one kv head"
             assert not (spec.rope_layers or spec.sliding_window), (
-                "latent attention has one kind of layer")
+                "latent attention's layers have no window and no 0/1 "
+                "rotation switch (beside state layers it is stated as a "
+                "kind)")
         if spec.lead_layers:
             # the 0/1 switches ride in ONE scan's xs; a model whose leading
             # layers differ from the rest states its kinds (runs of their own)
@@ -555,10 +609,16 @@ class ModelSpec:
                 0 <= k < len(spec.kinds) for k in spec.layer_kinds), (
                 f"layer_kinds {spec.layer_kinds} names {spec.n_layers} "
                 f"layers' kinds among {len(spec.kinds)}")
-            assert not (spec.latent or spec.rope_layers or spec.window_layers
-                        or spec.sliding_window), (
-                "kinds of layer state their own windows and rotation")
-            assert spec.arch_type != ArchType.GROK1 and spec.head_dim, (
+            assert not (spec.kv_lora_rank or spec.rope_layers
+                        or spec.window_layers or spec.sliding_window), (
+                "kinds of layer state their own windows, rotation and "
+                "latent row")
+            assert not spec.latent or (spec.mixed and all(
+                k.kv_lora_rank or k.conv_kernel for k in spec.kinds)), (
+                "latent attention is a kind only beside state layers: "
+                "kinds of attention layer share per-head keys and values")
+            assert spec.arch_type != ArchType.GROK1 and (
+                spec.head_dim or spec.latent), (
                 "kinds of layer share a stated head size")
             assert not (spec.mixed and spec.attn_gate), (
                 "the per-head gate is not stated beside convolution layers")
@@ -567,9 +627,10 @@ class ModelSpec:
                 # their own names, and the pool has a layer to page
                 assert (len([k for k in spec.kinds if k.conv_kernel]) == 1
                         and len(spec.kinds) == 2), (
-                    "a model with state layers has one convolution kind "
-                    "(a gated short convolution or a state-space mixer) and "
-                    "one attention kind")
+                    "a model with state layers has one state kind (a gated "
+                    "short convolution, a state-space mixer or a delta-rule "
+                    "mixer) and one attention kind (per-head keys and "
+                    "values, or a latent row)")
                 assert spec.cache_layers, (
                     "a model with state layers has an attention layer too")
             for k in spec.kinds:
@@ -578,6 +639,17 @@ class ModelSpec:
                     and k.ssm_groups == 1), (
                     "a state-space kind states its heads, head size, state "
                     "size and taps, and one group of B and C", k)
+                assert not k.kda_heads or (
+                    k.conv_kernel > 1 and k.kda_key_dim and k.kda_value_dim
+                    and k.kda_rank and not k.ssm_state), (
+                    "a delta-rule kind states its heads, key and value "
+                    "sizes, taps and the gates' rank, and is no "
+                    "state-space kind", k)
+                assert not k.kv_lora_rank or (
+                    not k.conv_kernel and k.qk_nope_head_dim
+                    and k.v_head_dim and not k.sliding_window), (
+                    "a latent kind states its row and its heads' widths, "
+                    "holds no state and has no window", k)
                 assert k.n_heads % spec.n_kv_heads == 0, (k, spec.n_kv_heads)
                 assert (0 <= k.rotary_dim <= spec.head_size
                         and not k.rotary_dim % 2), k

@@ -23,7 +23,7 @@ from typing import Any
 
 from jax.sharding import PartitionSpec as P
 
-from ..models.params import SSM
+from ..models.params import KDA, SSM
 from ..models.spec import ModelSpec
 from .mesh import AXIS_TP
 
@@ -78,6 +78,8 @@ _BLOCK_SPECS = {
     "conv_out": P(),
     # and so is a state-space mixer
     **{n: P() for n in SSM},
+    # and a delta-rule mixer
+    **{n: P() for n in KDA},
 }
 
 

@@ -763,13 +763,6 @@ class BatchEngine:
             kv_pool_cfg = (max(n_blocks, w + 2), bt)
         # a routed model's step programs and scans also return what their
         # expert layers did (the batch_moe_* counters)
-        if spec.latent and prefix_cache and (
-                prefix_cache_q80 or kv_pool_cfg is None):
-            raise ValueError(
-                "a latent cache row (kv_lora_rank > 0) is not supported by "
-                + ("the Q80 cold tier (prefix_cache_q80)" if prefix_cache_q80
-                   else "the dense host prefix cache (paged_kv off)")
-                + ": both hold per-head keys and values")
         if spec.mixed:
             # what cannot carry a state that is not a list of positions says
             # so here, in one sentence, before anything is built
@@ -789,14 +782,23 @@ class BatchEngine:
             if why:
                 raise ValueError(
                     "a model with state layers (a gated short convolution, "
-                    f"a state-space mixer) is not supported by {why}")
+                    "a state-space mixer, a delta-rule mixer) is not "
+                    f"supported by {why}")
+        if spec.latent and prefix_cache and (
+                prefix_cache_q80 or kv_pool_cfg is None):
+            raise ValueError(
+                "a latent cache row (kv_lora_rank > 0) is not supported by "
+                + ("the Q80 cold tier (prefix_cache_q80)" if prefix_cache_q80
+                   else "the dense host prefix cache (paged_kv off)")
+                + ": both hold per-head keys and values")
         self._eng = Engine(spec, params, tokenizer, batch=slots,
                            kv_pool=kv_pool_cfg, moe_stats=spec.is_moe,
                            **engine_kw)
         if spec.mixed and self._eng.kv_pool is None:
             raise ValueError(
                 "a model with state layers (a gated short convolution, a "
-                "state-space mixer) is served from the device block pool, "
+                "state-space mixer, a delta-rule mixer) is served from the "
+                "device block pool, "
                 "which this engine's sharding or KV storage turned off")
         _KV_ROW_BYTES.set(spec.cache_row_bytes(
             self._eng.k_cache.dtype.itemsize))

@@ -200,8 +200,9 @@ class Engine:
             if (tp or 1) > 1 or dp > 1:
                 raise ValueError(
                     f"tp={tp}, dp={dp}: a model with state layers (a gated "
-                    "short convolution, a state-space mixer) runs whole on "
-                    "one chip; its state is not sharded")
+                    "short convolution, a state-space mixer, a delta-rule "
+                    "mixer) runs whole on one chip; its state is not "
+                    "sharded")
             tp = 1
         if self.paged and tp is None:
             tp = 1  # paged mode is single-chip; don't let the mesh grab every device
@@ -445,7 +446,7 @@ class Engine:
 
             if self.spec.ssm:
                 raise ValueError(
-                    f"seek({pos}) from {self.pos}: a state-space layer's "
+                    f"seek({pos}) from {self.pos}: a state layer's "
                     "running matrix sums every earlier position and this "
                     "cache keeps no snapshot; rewind to 0 and prefill")
             if self.pos - pos > STATE_RING - self.spec.state_rows - 1:

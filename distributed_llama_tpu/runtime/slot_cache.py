@@ -76,9 +76,15 @@ _SSM_SNAPSHOTS = metrics.counter(
     "batch_ssm_snapshots_total",
     "Stride ends for which the dispatch was given an entry of the snapshot "
     "pool to write the row's state into")
+_MATRIX_BYTES = metrics.gauge(
+    "batch_state_matrix_bytes",
+    "Bytes of running matrix one (slot, state layer) holds in float32: "
+    "heads x rows x columns x 4 of the state kind (a state-space mixer's "
+    "or a delta-rule mixer's); 0: the model's state layers hold none")
 
 _NO_KV_STREAM = (
-    "a model with state layers (a gated short convolution) is not supported "
+    "a model with state layers (a gated short convolution, a state-space "
+    "mixer, a delta-rule mixer) is not supported "
     "by KV-block streaming between replicas (cache/wire.py): a block's "
     "state snapshot does not travel with its keys and values")
 
@@ -516,6 +522,7 @@ class PoolSlotCache(_SlotCache):
         self.stride = 0 if not spec.mixed else (
             STATE_STRIDE if spec.ssm else bt)
         self.no_stream = _NO_KV_STREAM if spec.mixed else None
+        _MATRIX_BYTES.set(4 * int(np.prod(spec.state_matrix or (0,))))
         if spec.ssm:
             assert STATE_STRIDE % bt == 0, bt
             self.kv_pool.snapshots = SnapshotPool(

@@ -156,7 +156,7 @@ def _mixed(**over):
     (dict(lead_layers=1, lead_hidden_dim=64), None),
     (dict(kinds=(LayerKind("conv", 4, conv_kernel=3),
                  LayerKind("conv4", 4, conv_kernel=4), LayerKind("full", 4)),
-          layer_kinds=(0, 1, 2, 0)), "one convolution kind"),
+          layer_kinds=(0, 1, 2, 0)), "one state kind"),
     (dict(layer_kinds=(0, 0, 0, 0)), "an attention layer too"),
     (dict(attn_gate=True), "per-head gate"),
     (dict(layer_kinds=(0, 0, 0)), "layer_kinds"),

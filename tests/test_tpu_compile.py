@@ -184,6 +184,26 @@ def grouped_share(rows, k, held, width, hidden, dim, layers=1):
              *up, *up, *down], {"tile": tile, "act": "silu", "merged": True})
 
 
+def kda(t):
+    """The delta-rule kernels at Kimi-Linear's published sizes (8 slots, 6
+    state layers, 32 heads of 128 x 128): `kda_step` (t = 0), one position of
+    every slot, and `kda_chunk`, t positions of one slot; the matrices
+    donated to the call by the step program, updated in place here."""
+    from distributed_llama_tpu.ops.pallas_kda import (_kda_chunk_pallas,
+                                                      _kda_step_pallas)
+
+    h = ((8, 6, 32, 128, 128), F32)
+    n = t or 8
+    rows = [((n, 32, 128), F32)] * 4  # q, k, v, g
+    if not t:
+        return (_kda_step_pallas,
+                [h, ((), I32), *rows, ((8, 32), F32), ((8,), jnp.bool_),
+                 ((8,), jnp.bool_)], {"name": "kda_step"})
+    return (_kda_chunk_pallas,
+            [h, ((), I32), ((), I32), *rows, ((t, 32), F32),
+             ((), jnp.bool_), ((), jnp.bool_)], {"name": "kda_chunk"})
+
+
 def decode_attention(hk, window):
     cache = ((LAYERS, 1, hk, 2048, HS), BF16)
     new = ((hk, 1, HS), BF16)
@@ -297,6 +317,11 @@ CASES = {
     **CELL_MATMULS,
     # repaired: inline matvec VMEM at K=14336
     "repaired-matvec-inline-w2": matvec_inline(DIM, HIDDEN),
+    # the delta-rule kernels at Kimi-Linear's sizes: a step of 8 slots, an
+    # 8-token and a 64-token chunk of one
+    "kda-step": kda(0),
+    "kda-chunk-t8": kda(8),
+    "kda-chunk-t64": kda(64),
 }
 
 
@@ -335,6 +360,8 @@ STEP_MODELS = {
     **STEP_POOLS,
     "e8": ("mixtral-8x7b-l8", {"num_local_experts": 4}),
     "ssm": ("granite-4.0-h-small-l10", {"num_local_experts": 16}),
+    # a delta-rule kind beside a latent kind, a leading dense layer
+    "kda": ("kimi-linear-48b-a3b-l8", {"num_experts": 16}),
 }
 # `jit_step` at T = 1 and at a 64-token chunk as the scheduler dispatches it
 # (told which row prefills: 72 compact rows, `forward.RowMap`), and a 2-step
@@ -481,6 +508,44 @@ def test_step_program_carries_the_running_matrices_in_place(chip, monkeypatch):
     ring, snaps, h, _, snap_h, _ = jax.eval_shape(lambda: init_state(
         spec, 8, cfg["engine"]["kv_pool_blocks"], jnp.bfloat16))
     assert h.shape == (8, 9, 128, 64, 128) and snap_h.shape[0] == 49
+    assert aot_step.pool_relayouts(text, ring.shape) == []
+    assert aot_step.pool_relayouts(text, snaps.shape) == []
+    for a in (h, snap_h):
+        shape = "f32[" + ",".join(str(d) for d in a.shape) + "]"
+        copies = [ln for ln in text.splitlines()
+                  if re.search(r"= " + re.escape(shape) + r"\S* copy\(", ln)]
+        assert copies == [], copies[:2]
+
+
+@pytest.mark.parametrize("program", ["t1", "t8", "t64", "scan8"])
+def test_step_program_carries_the_delta_rule_matrices_in_place(
+        chip, step_model, program, monkeypatch):
+    """Kimi-Linear's four step programs for a described v5e: the KDA kernels
+    are in them under their names (`kda_step`; a chunk's `kda_chunk` too),
+    beside the latent attention kernel; the running matrices (8 slots x 6
+    layers x 32 heads x 128 x 128 float32, 101 MB) ride BOTH layer scans
+    (the leading dense layer's and the expert layers') and the `lax.cond`
+    without a copy, the snapshot pool is written in place, and the slots'
+    rings of [q | k | v] rows (12288 wide), the snapshots' tails and the
+    pool's latent rows are neither copied nor re-laid."""
+    import re
+
+    from distributed_llama_tpu.models.forward import init_state
+
+    spec, shapes, cfg = step_model("kda")
+    monkeypatch.delenv("DLT_PALLAS_INTERPRET")
+    how = {"t1": {"chunk": 1}, "t8": {"chunk": 8}, "t64": {"chunk": 64},
+           "scan8": {"scan": 8}}[program]
+    text = aot_step.compile_step(spec, shapes, cfg, chip, **how).as_text()
+    assert "kda_step" in text and "latent_paged_attention" in text
+    assert ("kda_chunk" in text) == (how.get("chunk", 1) > 1)
+    assert len(spec.runs()) == 2 and text.count("tpu_custom_call") <= 26
+    for side in aot_step.held_pools(spec, cfg):
+        assert aot_step.pool_relayouts(text, side) == []
+    ring, snaps, h, _, snap_h, _ = jax.eval_shape(lambda: init_state(
+        spec, 8, cfg["engine"]["kv_pool_blocks"], jnp.bfloat16))
+    assert h.shape == (8, 6, 32, 128, 128) and snap_h.shape[0] == 25
+    assert ring.shape == (8, 64, 16, 12288)
     assert aot_step.pool_relayouts(text, ring.shape) == []
     assert aot_step.pool_relayouts(text, snaps.shape) == []
     for a in (h, snap_h):
