@@ -1671,7 +1671,9 @@ def forward(params: dict[str, Any], spec: ModelSpec, rope: RopeTables,
     else:
         positions = start_pos + jnp.arange(t, dtype=jnp.int32)
     x = jnp.take(params["embedding"], tokens, axis=0)
-    if spec.embedding_multiplier != 1.0:
+    # a table the engine holds in its dtype has the multiplier in it
+    # (params.hold_dense); a loader's float32 rows take it before the cast
+    if spec.embedding_multiplier != 1.0 and x.dtype == jnp.float32:
         x = x * spec.embedding_multiplier
     x = x.astype(dtype)
     if spec.arch_type == ArchType.GROK1:

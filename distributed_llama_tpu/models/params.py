@@ -8,7 +8,9 @@ Weight matrices keep the reference's (out, in) row-major orientation with quanti
 blocks along `in`. Tensor inventory mirrors the `.m` file exactly
 (transformer.cpp:494-529):
 
-    embedding (vocab, dim) f32           wcls (vocab, dim) [weights ftype]
+    embedding (vocab, dim) f32 as a loader gives it and a file holds it; the
+       engine holds it in ITS dtype (`hold_dense`)
+    wcls (vocab, dim) [weights ftype]
     per layer: wq (q_dim, dim), wk (kv_dim, dim), wv (kv_dim, dim), wo (dim, q_dim)
        (q_dim = n_heads x head_size, which is dim unless the header states head_dim),
        dense: w1/gate (hidden, dim), w2/down (dim, hidden), w3/up (hidden, dim)
@@ -283,16 +285,30 @@ def stack_names(params: Params) -> list[str]:
 _HELD_DENSE = ("w_uk", "w_uv", "kda_fb", "kda_gb")
 
 
-def hold_dense(params: Params, dtype) -> Params:
+def hold_dense(params: Params, dtype, spec: ModelSpec | None = None) -> Params:
     """`params` with the tensors the program multiplies by head
     (`_HELD_DENSE`) dequantized once into `dtype`: what a loader's QTensor of
-    them becomes before the engine places the weights."""
+    them becomes before the engine places the weights. Given the `spec`, the
+    embedding table too is held in `dtype` where that is not float32, the
+    spec's `embedding_multiplier` made in float32 before the cast, as
+    `forward` orders the two on the rows it gathers from a float32 table: a
+    step program then gathers rows of the dtype it computes in, and a K-step
+    scan no longer casts the whole table once a block (XLA moved the rows'
+    cast in front of the gather and out of the loop: 1.17 GB read and 0.59
+    written a block of A.X-K1's; PERF.md section 6, PR 49). A table that is
+    not float32 is one already held."""
     out = dict(params)
     for st in stack_names(params):
         if any(isinstance(params[st].get(n), QTensor) for n in _HELD_DENSE):
             out[st] = {n: (t.dequantize(dtype=dtype)
                            if n in _HELD_DENSE and isinstance(t, QTensor)
                            else t) for n, t in params[st].items()}
+    table = params["embedding"]
+    if (spec is not None and table.dtype == np.float32
+            and np.dtype(dtype) != np.float32):
+        if spec.embedding_multiplier != 1.0:
+            table = table * np.float32(spec.embedding_multiplier)
+        out["embedding"] = table.astype(dtype)
     return out
 
 
@@ -311,15 +327,29 @@ _DENSE_MATMULS = {"wq", "wk", "wv", "wo", "wg", "w1", "w2", "w3",
 _COL_SHARDED = {"wo", "w2", "moe_down", "sh_down"}
 
 
-def _kernel_convertible(t: QTensor, stacked: bool) -> bool:
+def _kernel_convertible(t: QTensor, stacked: bool,
+                        col_groups: int | None = None) -> bool:
+    """Whether some kernel reads the decode layout this weight would take, at
+    some number of rows. `col_groups`: the column groups of its split-plane
+    pack (`_i4p_groups`) where the caller would pack a `_DENSE_MATMULS` matrix
+    so: the dequant-matmul reads that pack at 2 to 512 rows whatever K is
+    (`q4_mm_reads`), and at ONE row and a K over the matvec's bound `qmatmul`
+    dequantizes it as it would the planar blocks. Without it the one-row
+    matvec's bound (`q8_shape_supported`) decides alone: int8 planes are read
+    by that kernel only, and the head and an expert stack (one expert's slice
+    is what a kernel sees) keep that gate."""
+    from ..ops.pallas_q4_mm import q4_mm_reads
     from ..ops.pallas_q8 import q8_shape_supported
 
     if not (isinstance(t, QTensor) and t.ftype in _I8_CONVERTIBLE):
         return False
     shape = t.shape[1:] if stacked else t.shape
-    if len(shape) == 3:  # MoE expert stack (E, out, in): kernel sees one expert slice
-        shape = shape[1:]
-    return len(shape) == 2 and q8_shape_supported(*shape)
+    if len(shape) == 3:  # MoE expert stack (E, out, in)
+        return q8_shape_supported(*shape[1:])
+    if len(shape) != 2:
+        return False
+    return q8_shape_supported(*shape) or bool(
+        col_groups and q4_mm_reads(shape[1] // col_groups))
 
 
 _REPACKED = metrics.counter(
@@ -347,10 +377,39 @@ def scale_plane_bytes(params: Params) -> int:
     return n
 
 
+_STEP_CONVERTED_BYTES = metrics.gauge(
+    "weights_step_converted_bytes",
+    "bytes of weights, of the engine built last, that a step program "
+    "converts whole before it can use them: block-quantized matrices of the "
+    "layers left planar beside the kernels (XLA dequantizes such a matrix "
+    "every step; the router, planar by design, apart), and the embedding "
+    "table where its dtype is not the engine's (a K-step scan casts it once "
+    "a block)")
+
+
+def step_converted_bytes(params: Params, dtype, use_pallas: bool) -> int:
+    """Bytes of the placed weights that every step (the matrices) or every
+    scan block (the table) computes from the weights alone; sets the
+    `weights_step_converted_bytes` gauge. An engine without the kernels
+    dequantizes every matrix by choice (the tests' oracle): its matrices are
+    not counted."""
+    n = 0
+    if use_pallas:
+        n = sum(t.nbytes() for st in stack_names(params)
+                for name, t in params[st].items()
+                if name in _DENSE_MATMULS and isinstance(t, QTensor)
+                and t.ftype in _I8_CONVERTIBLE and t.layout == "planar")
+    table = params["embedding"]
+    if table.dtype != np.dtype(dtype):
+        n += table.nbytes
+    _STEP_CONVERTED_BYTES.set(n)
+    return n
+
+
 def _i4p_groups(t: QTensor, tp: int, col_sharded: bool) -> int | None:
     """The column groups a Q40 weight's split-plane pack needs, None where the
     i4p alignment does not hold (the weight then takes int8 planes)."""
-    if t.ftype != FloatType.Q40:
+    if not isinstance(t, QTensor) or t.ftype != FloatType.Q40:
         return None
     k = t.shape[-1]
     groups = tp if col_sharded else 1
@@ -423,8 +482,9 @@ def _fuse_plan(blocks: Params, spec: ModelSpec | None, tp: int,
     plan = {}
     for fused, members in _FUSE_GROUPS.items():
         ts = [blocks.get(m) for m in members]
-        if not all(isinstance(t, QTensor) and t.layout == "planar"
-                   and _kernel_convertible(t, stacked=True) for t in ts):
+        # a merged group's rows are sharded, never its columns: one group
+        if not all(_kernel_convertible(t, True, _i4p_groups(t, 1, False))
+                   and t.layout == "planar" for t in ts):
             continue
         if len({t.ftype for t in ts}) != 1:
             continue
@@ -562,10 +622,10 @@ def prepare_for_pallas(params: Params, tp: int = 1,
 
     def convert(members, row_groups, col_sharded, pspec, row_axis=1):
         t = members[0]
-        if not all(_kernel_convertible(m, stacked=True)
+        col_groups = _i4p_groups(t, tp, col_sharded)
+        if not all(_kernel_convertible(m, True, col_groups)
                    and m.layout == "planar" for m in members):
             return t
-        col_groups = _i4p_groups(t, tp, col_sharded)
         if col_groups is None:  # int8 planes: the host's
             if len(members) > 1:
                 t = _concat_rows_grouped(members, row_groups, row_axis)

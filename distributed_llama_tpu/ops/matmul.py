@@ -6,7 +6,11 @@ logical op: y[..., out] = x[..., in] · W[out, in], where W may be dense or bloc
 
 Execution paths (`qmatmul`, one rule from shapes):
 - one row of activations: the matvec kernels (`pallas_q4.q4_matvec` on split-plane
-  Q40, `pallas_q8.q8_matvec` on int8 planes), HBM-bandwidth-bound.
+  Q40, `pallas_q8.q8_matvec` on int8 planes), HBM-bandwidth-bound. Their bound on K
+  (`pallas_q8.q8_shape_supported`, `pallas_q4.q4_shape_supported`: the resident Xexp
+  operand, K <= 17378) is the one-row matvec's and is asked at one row only: a
+  split-plane weight over it (A.X-K1's dense `w2`, K 18432) is dequantized from its
+  pack by XLA at one row and read by the dequant-matmul at 2 to 512.
 - 2 to 512 rows on split-plane Q40: `pallas_q4_mm.q4_matmul`, the packed weights
   decoded in VMEM and fed to the MXU as bf16.
 - everything else, and `use_pallas=False`: dequantize-to-dtype + `dot_general`.

@@ -210,6 +210,15 @@ def _pick_bn(n: int, kh: int, stacked: int = 1) -> int:
     return bn
 
 
+def q4_mm_reads(k: int) -> bool:
+    """Whether the kernel reads a split-plane pack of k columns (a weight's
+    K, or one column group's of it) at its 2 to `_MAX_ROWS` rows: a
+    half-plane of whole lane tiles, whatever else the shape is. What
+    `models/params.py` asks before it packs a matrix over the one-row
+    matvec's bound."""
+    return k % 256 == 0
+
+
 def q4_mm_supported(w: QTensor, m: int, stacked: int = 0) -> bool:
     """Whether the fused dequant-matmul runs this weight for m activation
     rows: split-plane Q40 in one self-contained pack (`groups` folded away by
@@ -219,7 +228,7 @@ def q4_mm_supported(w: QTensor, m: int, stacked: int = 0) -> bool:
     for. One row is the matvec kernel's."""
     if w.layout != "i4p" or w.groups != 1 or w.data.ndim != 2 + stacked:
         return False
-    return w.data.shape[-1] % 128 == 0 and 2 <= m <= _MAX_ROWS
+    return q4_mm_reads(2 * w.data.shape[-1]) and 2 <= m <= _MAX_ROWS
 
 
 @functools.partial(jax.jit,

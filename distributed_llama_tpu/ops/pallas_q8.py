@@ -82,7 +82,12 @@ _XEXP_VMEM_LIMIT = 9 << 20
 
 
 def q8_shape_supported(n: int, k: int, precise: bool = False) -> bool:
-    """Whether the fused matvec kernel can run a (n, k)-logical weight on TPU."""
+    """Whether the fused matvec kernel can run a (n, k)-logical weight on TPU:
+    the ONE-ROW matvec's bound on its resident Xexp operand (k <= 17378), true
+    of this kernel and of `pallas_q4`'s matvec and asked at one row only. It
+    decides the layout of the weights that kernel alone reads (int8 planes, the
+    head, an expert stack); a split-plane Q40 matrix over it is still packed
+    for the dequant-matmul's 2 to 512 rows (`models/params._kernel_convertible`)."""
     nb = k // QK
     esize = 4 if precise else 1
     return k * nb * esize <= _XEXP_VMEM_LIMIT

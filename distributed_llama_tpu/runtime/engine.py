@@ -26,7 +26,7 @@ import numpy as np
 
 from ..models.params import (Params, decode_stream_bytes, hold_dense,
                              prepare_for_pallas, scale_plane_bytes,
-                             stack_names)
+                             stack_names, step_converted_bytes)
 from ..models.spec import ModelSpec
 from ..obs import flight, metrics, trace
 from ..resilience import faults
@@ -248,7 +248,7 @@ class Engine:
         # reference's scheme); "expert" shards WHOLE experts over tp — the capacity
         # axis for Grok-1-314B-class expert weights (parallel/sharding.py)
         self.moe_sharding = moe_sharding if spec.is_moe else "slice" 
-        params = hold_dense(params, self.dtype)
+        params = hold_dense(params, self.dtype, spec)
         has_quant = any(
             getattr(t, "ftype", None) in (FloatType.Q40, FloatType.Q80)
             for st in stack_names(params) for t in params[st].values())
@@ -274,7 +274,8 @@ class Engine:
         # global (all-shard) weight bytes one decode step streams — per-chip traffic
         # divides by tp; used for the achieved-GB/s printout
         self.decode_weight_bytes = decode_stream_bytes(self.params, spec, batch)
-        scale_plane_bytes(self.params)  # the gauge beside the memory's peak
+        scale_plane_bytes(self.params)  # the gauges beside the memory's peak
+        step_converted_bytes(self.params, self.dtype, self.use_pallas)
         self.rope = RopeTables.create(spec)
         self.batch = batch
         # Paged (out-of-core) KV cache — the reference's --kv-cache-storage

@@ -1,6 +1,6 @@
 """What the chip's compiler makes of a whole step program, without the chip.
 
-    JAX_PLATFORMS=cpu python3 perf/aot_step.py <configuration> [--rows 8] [--chunk 64] [--scan K] [--rectangle 1]
+    JAX_PLATFORMS=cpu python3 perf/aot_step.py <configuration> [--rows 8] [--chunk 64] [--scan K] [--rectangle 1] [--own-vocab 1]
 
 Compiles `models/forward.forward` (kernels on, paged KV, bf16, the pools
 DONATED as the engines donate them: undonated, XLA copies each pool once
@@ -21,13 +21,18 @@ that RESULTS in packed weights (`u8[...]`), their scales (`s16[...]`) or an
 array of the KV pool's shape (`bf16[L,N,hk,bt,hs]`, with its layout): the
 copies XLA puts around a kernel or a scatter show only here (the kernels
 alone compile in tests/test_tpu_compile.py, which also holds the step
-programs to `pool_relayouts` and `scale_relayouts` being empty). Nothing
-runs: no time comes out of this (PERF.md section 6, PRs 33, 37 and 46).
+programs to `pool_relayouts`, `scale_relayouts` and `weight_conversions`
+being empty). The last is every instruction that computes a whole weight
+from the weights alone: a block-quantized matrix dequantized by XLA, the
+embedding table cast (`--own-vocab 1` gives the table and the head the
+file's vocabulary, where the table's cast is its real size). Nothing runs:
+no time comes out of this (PERF.md section 6, PRs 33, 37, 46 and 49).
 """
 
 from __future__ import annotations
 
 import argparse
+import math
 import os
 import re
 import sys
@@ -68,11 +73,14 @@ def _sds(a, chip, shape=None):
     return jax.ShapeDtypeStruct(shape or a.shape, a.dtype, sharding=chip)
 
 
-def model_shapes(config: str, chip, **keys):
+def model_shapes(config: str, chip, own_vocab: bool = False, **keys):
     """(spec, parameter shapes, configuration) of `config` with vocabulary
     512 and `keys` over the file's: the parameter tree of a drawn cut, each
-    stack's leaves given the depth the spec states."""
-    full = dict(cells.load_config(config), **{"vocab_size": 512, **keys})
+    stack's leaves given the depth the spec states. `own_vocab`: the table
+    and the head re-shaped to the file's vocabulary as the depth is (what a
+    program does to the whole table shows at its own size)."""
+    file_cfg = cells.load_config(config)
+    full = dict(file_cfg, **{"vocab_size": 512, **keys})
     family = cells.load_family(full["family"])
     if hasattr(family, "one_layer_a_stack"):
         # the family cuts itself: a layer of each stack, two experts a layer
@@ -91,9 +99,12 @@ def model_shapes(config: str, chip, **keys):
                                            "sliding_window_layout")
                   if k in full}}
     # as runtime/engine.py prepares a loader's parameters
+    cut_spec = family.model_spec(cut)
     params = prepare_for_pallas(
         hold_dense(W.to_program_params(W.make_weights(cut, 7), cut),
-                   jnp.bfloat16), spec=family.model_spec(cut))
+                   jnp.bfloat16, cut_spec), spec=cut_spec)
+    if own_vocab:
+        full["vocab_size"] = file_cfg["vocab_size"]
     spec = family.model_spec(full)
     # a tensor is as deep as its run, a mixer's of a model with state layers
     # as its kind's layers of the run (a fused group as its first member)
@@ -116,6 +127,9 @@ def model_shapes(config: str, chip, **keys):
             shapes[st][name] = jax.tree.map(
                 lambda a, n=depth(st, name), e=wide: _sds(a, chip, (
                     n, *((e,) if e else a.shape[1:2]), *a.shape[2:])), t)
+    for name in ("embedding", "wcls"):
+        shapes[name] = jax.tree.map(lambda a: _sds(a, chip, (
+            spec.vocab_size, *a.shape[1:])), shapes[name])
     return spec, shapes, full
 
 
@@ -250,6 +264,55 @@ def scale_relayouts(text: str) -> list[str]:
     return [r for r in found if not PLANE_PREFETCH.fullmatch(r)]
 
 
+_FLOAT_RESULT = re.compile(
+    r"\s*(?:ROOT )?%?([\w.\-]+) = ((?:bf16|f16|f32)\[([\d,]+)\])"
+    r"(?:\{[^}]*\})? ([\w\-]+)\(")
+# what hands an array on as it is: the table itself, or a weight held dense,
+# as a parameter, an element of a loop's carry, another view of the bytes, or
+# XLA's prefetch of an array small enough into the faster memory space (the
+# 512-row table of the tests' compiles, as `PLANE_PREFETCH` for the planes)
+_HANDS_ON = {"parameter", "get-tuple-element", "bitcast", "tuple", "while",
+             "conditional", "call", "optimization-barrier", "copy-start",
+             "copy-done"}
+
+
+def weight_conversions(text: str, shapes, floor: int = 1 << 20) -> list[str]:
+    """Every instruction of a compiled program that computes a whole weight
+    from the weights alone, as "operation -> shape". Of two kinds. One whose
+    result is a block-quantized matrix of the layers (`shapes`' stacks; the
+    router, planar by design, apart) BY BLOCKS, (N, K/32, 32) in a float
+    dtype under any leading axes, of `floor` elements or more: XLA's
+    dequantization multiplies the values by their block's scale in that form,
+    inside a fusion or not, and no activation has it (the logical (N, K) is
+    also the shape of 576 rows of an 8-expert chunk against A.X-K1's `wkv_a`
+    and of a 72-row chunk against Laguna's gate, so it is not asked). And one
+    whose result is an array of the embedding table's (vocabulary, dim): the
+    table cast or copied. A program whose kernels read every matrix packed,
+    and which gathers the table's rows in the dtype it computes in, has
+    none. (Until PR 49 A.X-K1's dense `w2`, K 18432 over the one-row
+    matvec's bound, stayed planar and XLA dequantized it every step, and
+    the K-step scan of every configuration but Granite's cast the float32
+    table once a block.)"""
+    blocks = set()
+    for st in stack_names(shapes):
+        for name, t in shapes[st].items():
+            if name != "router" and getattr(t, "scales", None) is not None:
+                n, k = t.shape[-2:]
+                blocks.add((n, k // 32, 32))
+    table = tuple(shapes["embedding"].shape)
+    found = []
+    for line in text.splitlines():
+        m = _FLOAT_RESULT.match(line)
+        if not m or m.group(4) in _HANDS_ON:
+            continue
+        dims = tuple(int(d) for d in m.group(3).split(","))
+        if dims == table or (dims[-3:] in blocks
+                             and math.prod(dims) >= floor):
+            found.append(f"{re.sub(r'[.][0-9]+$', '', m.group(1))} -> "
+                         f"{m.group(2)}")
+    return found
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("config")
@@ -257,12 +320,15 @@ def main():
     ap.add_argument("--chunk", type=int, default=64)
     ap.add_argument("--scan", type=int, default=0, metavar="K",
                     help="K decode steps as one scan, the pools in its carry")
+    ap.add_argument("--own-vocab", type=int, default=0,
+                    help="1: the configuration's own vocabulary, not 512")
     ap.add_argument("--rectangle", type=int, default=0,
                     help="1: a chunk whose every position is real (no row "
                     "map), not a prefill dispatch's")
     args = ap.parse_args()
     chip = describe_chip()
-    spec, shapes, cfg = model_shapes(args.config, chip)
+    spec, shapes, cfg = model_shapes(args.config, chip,
+                                     own_vocab=bool(args.own_vocab))
     t0 = time.time()
     compiled = compile_step(spec, shapes, cfg, chip, rows=args.rows,
                             chunk=args.chunk, scan=args.scan,
@@ -301,6 +367,8 @@ def main():
               f"{pool_relayouts(text, side) or 'nothing'}")
     print(f"  scale planes (s16): re-laid or copied by "
           f"{scale_relayouts(text) or 'nothing'} (prefetches to S(1) apart)")
+    print(f"  whole weights computed from the weights alone: "
+          f"{weight_conversions(text, shapes) or 'none'}")
 
 
 if __name__ == "__main__":

@@ -34,3 +34,16 @@ def devices8():
     devs = jax.devices()
     assert len(devs) >= 8, f"expected 8 virtual CPU devices, got {len(devs)}"
     return devs[:8]
+
+
+@pytest.fixture
+def matvec_bound_under_1024(monkeypatch):
+    """The one-row matvec's bound on K lowered so that a K of 1024 stands
+    over it as A.X-K1's dense `w2` (18432) stands over the real one
+    (17378): K x K/32 <= 8192 admits 512 and no more."""
+    from distributed_llama_tpu.ops import pallas_q4, pallas_q8
+
+    monkeypatch.setattr(pallas_q4, "_XEXP_VMEM_LIMIT", 8192)
+    monkeypatch.setattr(pallas_q8, "_XEXP_VMEM_LIMIT", 8192)
+    assert pallas_q8.q8_shape_supported(256, 512)
+    assert not pallas_q8.q8_shape_supported(256, 1024)

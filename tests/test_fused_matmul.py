@@ -108,6 +108,42 @@ def test_declined_shapes_and_injected_faults_take_xla_by_name():
         qmatmul(x, w, use_pallas=False), np.float32))
 
 
+@pytest.mark.parametrize("rows", [1, 2, 8, 72])
+def test_a_weight_over_the_matvecs_bound_reaches_the_kernel(
+        matvec_bound_under_1024, rows):
+    """A Q40 matrix of the layers whose K is over the one-row matvec's bound
+    is packed all the same (`prepare_for_pallas` asks the kernels that read
+    the pack, not the matvec alone), and `qmatmul` reads it out of the stack
+    with the dequant-matmul at 2, 8 and 72 rows, within that kernel's
+    distance of dequantize-then-dot on the planar blocks. At ONE row neither
+    kernel takes it and XLA dequantizes the pack: the planar weight's result
+    bit for bit. (Until PR 49 such a weight stayed planar and XLA dequantized
+    it whole at every number of rows, every step: 10 % of A.X-K1's window.)"""
+    from distributed_llama_tpu.ops.matmul import LayerOf, _qmatmul_xla
+
+    spec = ModelSpec(arch_type=ArchType.LLAMA, dim=512, hidden_dim=1024,
+                     n_layers=2, n_heads=4, n_kv_heads=4, vocab_size=64,
+                     seq_len=32, rope_type=RopeType.LLAMA).resolved()
+    params = init_random_params(spec, FloatType.Q40, seed=11)
+    w2 = prepare_for_pallas(params, spec=spec)["blocks"]["w2"]
+    assert (w2.layout, w2.shape) == ("i4p", (2, 512, 1024))
+    planar = jax.tree.map(lambda a: jnp.asarray(a[1]), params["blocks"]["w2"])
+    x = jnp.asarray(np.random.RandomState(rows).randn(rows, 1024) * 0.1,
+                    jnp.bfloat16)
+    want = _qmatmul_xla(x, planar, out_dtype=jnp.float32)
+    reset_kernel_selections()
+    got = qmatmul(x, LayerOf(w2, (jnp.int32(1),)), use_pallas=True,
+                  out_dtype=jnp.float32)
+    assert kernel_selections() == {
+        f"m={rows},n=512,k=1024,layout=i4p,op=mm":
+        "xla" if rows == 1 else "q4_mm"}
+    if rows == 1:
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    else:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-4, rtol=1e-4)
+
+
 def test_bench_byte_model_within_packed_density():
     """Satellite smoke: at EVERY cell shape a call's HBM traffic is at most
     1.3 times the packed weights at 8 and 64 rows and, rows and outputs of a

@@ -174,6 +174,54 @@ def test_q4_mm_supported_gates():
         q4_matmul(jnp.ones((1, 1024), jnp.bfloat16), w, interpret=True)
 
 
+# what `prepare_for_pallas` makes of a weight whose K (1024) is over the
+# one-row matvec's bound, lowered to 512 (conftest.py): name -> (the file's
+# type, the spec's shape, the tensor of the prepared params, its layout)
+OVER_THE_BOUND = {
+    # split-plane Q40 of a layer: the dequant-matmul reads the pack
+    "w2": (FloatType.Q40, "dense", lambda p: p["blocks"]["w2"], "i4p"),
+    # under the bound nothing changed: merged and packed
+    "w13": (FloatType.Q40, "dense", lambda p: p["blocks"]["w13"], "i4p"),
+    # int8 planes are the matvec's alone: a Q80 weight over its bound stays
+    "w2-q80": (FloatType.Q80, "dense", lambda p: p["blocks"]["w2"], "planar"),
+    "w13-q80": (FloatType.Q80, "dense", lambda p: p["blocks"]["w13"], "i8"),
+    # the head and an expert stack keep the matvec's gate
+    "head": (FloatType.Q40, "wide", lambda p: p["wcls"], "planar"),
+    "experts": (FloatType.Q40, "moe", lambda p: p["blocks"]["moe_down"],
+                "planar"),
+    # a K the split-plane pack takes (64 | 1088) in no whole lane tiles
+    # (256 does not divide it): no kernel reads it at any number of rows
+    "w2-unaligned": (FloatType.Q40, "unaligned",
+                     lambda p: p["blocks"]["w2"], "planar"),
+}
+
+
+@pytest.mark.parametrize("case", list(OVER_THE_BOUND))
+def test_the_pack_asks_the_kernels_that_read_it(matvec_bound_under_1024,
+                                                case):
+    """`models/params._kernel_convertible`: a Q40 matrix of the layers is
+    packed where ANY kernel reads the pack at some number of rows (the
+    dequant-matmul at 2 to 512 whatever K is, `q4_mm_reads`), and the
+    one-row matvec's bound decides only what that kernel alone reads."""
+    ftype, shape, pick, layout = OVER_THE_BOUND[case]
+    kw = dict(arch_type=ArchType.LLAMA, dim=512, hidden_dim=1024, n_layers=1,
+              n_heads=4, n_kv_heads=4, vocab_size=64, seq_len=32,
+              rope_type=RopeType.LLAMA)
+    if shape == "wide":
+        kw.update(dim=1024, hidden_dim=512)
+    elif shape == "unaligned":
+        kw.update(hidden_dim=1088)
+    elif shape == "moe":
+        kw.update(arch_type=ArchType.MIXTRAL, n_experts=2,
+                  n_active_experts=1)
+    spec = ModelSpec(**kw).resolved()
+    params = init_random_params(spec, ftype, seed=3)
+    got = pick(prepare_for_pallas(params, spec=spec))
+    assert got.layout == layout, (case, got.layout, got.shape)
+    assert got.shape[-1] == (1088 if shape == "unaligned" else
+                             512 if case.startswith("w13") else 1024)
+
+
 @pytest.mark.parametrize("n,k,bn,bk", [
     (4096, 4096, 256, 512),      # Mistral wo: 512 KiB blocks of 256 rows
     (4096, 14336, 128, 512),     # w2: 7168 packed columns a row, 14 chunks

@@ -167,6 +167,94 @@ def test_an_engine_states_its_scale_planes_bytes(monkeypatch):
     assert got == sum(t.scales.nbytes for t in held) == 16 * file_bytes
 
 
+@pytest.mark.parametrize("multiplier", [1.0, 12.0])
+def test_a_bf16_engine_holds_the_table_in_its_dtype(multiplier):
+    """The engine places the embedding table in the dtype it computes in
+    (`hold_dense`), the spec's multiplier made in float32 before the cast as
+    `forward` orders the two on a float32 table's rows: the same logits bit
+    for bit, a cast commuting with a gather. The caller's params, and what
+    `mfile` would write of them, stay float32. `weights_step_converted_bytes`
+    reads 0, and the float32 table's bytes with that table put back."""
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    from distributed_llama_tpu.formats.mfile import params_file_order
+    from distributed_llama_tpu.models.params import step_converted_bytes
+    from distributed_llama_tpu.obs import metrics
+    from distributed_llama_tpu.runtime.engine import Engine
+
+    spec = dataclasses.replace(_dense_spec(), embedding_multiplier=multiplier)
+    params = init_random_params(spec, FloatType.Q40, seed=9)
+    table = params["embedding"]
+    eng = Engine(spec, params, tp=1, dtype=jnp.bfloat16, use_pallas=False)
+    held = eng.params["embedding"]
+    assert held.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(
+        np.asarray(held.astype(jnp.float32)),
+        (table * np.float32(multiplier)).astype(jnp.bfloat16).astype(
+            np.float32))
+    assert metrics.snapshot()["weights_step_converted_bytes"] == 0
+    assert params["embedding"] is table and table.dtype == np.float32
+    name, written = next(iter(params_file_order(spec, params)))
+    assert name == "embedding" and written.dtype == np.float32
+    tokens = [3, 17, 99, 4, 120]
+    with_held = eng.infer_chunk_logits(tokens)
+    eng.params = {**eng.params, "embedding": jnp.asarray(table)}
+    eng.reset()
+    np.testing.assert_array_equal(eng.infer_chunk_logits(tokens), with_held)
+    assert step_converted_bytes(eng.params, eng.dtype, eng.use_pallas) \
+        == table.nbytes == metrics.snapshot()["weights_step_converted_bytes"]
+
+
+def test_a_float32_engine_leaves_the_table_as_loaded():
+    """Nothing to hold where the engine computes in float32: the table is
+    the loader's, the multiplier not in it, and the gauge reads 0."""
+    import dataclasses
+
+    from distributed_llama_tpu.obs import metrics
+    from distributed_llama_tpu.runtime.engine import Engine
+
+    spec = dataclasses.replace(_dense_spec(), embedding_multiplier=12.0)
+    params = init_random_params(spec, FloatType.Q40, seed=9)
+    eng = Engine(spec, params, tp=1, use_pallas=False)
+    assert eng.dtype == np.float32
+    assert eng.params["embedding"].dtype == np.float32
+    np.testing.assert_array_equal(np.asarray(eng.params["embedding"]),
+                                  params["embedding"])
+    assert metrics.snapshot()["weights_step_converted_bytes"] == 0
+
+
+def test_the_gauge_counts_a_matrix_left_planar_beside_the_kernels(
+        monkeypatch):
+    """`weights_step_converted_bytes`, kernels on: 0 where every matrix of
+    the layers is packed; the blocks' bytes of one that no kernel's pack
+    takes (a Q80 `w2` over the matvec's bound, lowered here), which XLA
+    dequantizes whole every step. The router is planar by design and an
+    engine without the kernels dequantizes by choice: neither is counted."""
+    from distributed_llama_tpu.obs import metrics
+    from distributed_llama_tpu.ops import pallas_q8
+    from distributed_llama_tpu.runtime.engine import Engine
+
+    monkeypatch.setenv("DLT_PALLAS_INTERPRET", "1")
+    spec = _moe_spec()
+    eng = Engine(spec, init_random_params(spec, FloatType.Q40, seed=5),
+                 tp=1, use_pallas=True)
+    assert eng.params["blocks"]["router"].layout == "planar"
+    assert metrics.snapshot()["weights_step_converted_bytes"] == 0
+    monkeypatch.setattr(pallas_q8, "_XEXP_VMEM_LIMIT", 256 * 8 - 1)
+    spec = _dense_spec()  # every K is 256: over the lowered bound
+    params = init_random_params(spec, FloatType.Q80, seed=5)
+    eng = Engine(spec, params, tp=1, use_pallas=True)
+    left = [t for t in eng.params["blocks"].values()
+            if isinstance(t, QTensor)]
+    assert left and all(t.layout == "planar" for t in left)
+    assert metrics.snapshot()["weights_step_converted_bytes"] == sum(
+        t.nbytes() for t in left)
+    Engine(spec, params, tp=1, use_pallas=False)
+    assert metrics.snapshot()["weights_step_converted_bytes"] == 0
+
+
 CASES = [  # arch, tp, moe_sharding, on a mesh
     ("dense", 1, "slice", False),
     ("dense", 2, "slice", False),

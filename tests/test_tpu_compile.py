@@ -225,6 +225,11 @@ CELL_MATMULS = {
        for m in (8, 16, 64, 72, 512) for name, (n, k) in _MISTRAL.items()},
     **{f"matmul-smallthinker-m512-{name}": matmul(512, n, k)
        for name, (n, k) in _SMALLTHINKER.items()},
+    # A.X-K1's leading dense w2, a stack of one: K 18432 is over the one-row
+    # matvec's bound (17378) and packed since PR 49; 9216 packed columns a
+    # row (18 chunks of 512), a scale plane of 576 columns stored as 640
+    **{f"matmul-axk1-m{m}-lead-w2": matmul(m, 7168, 18432, lead=(1,))
+       for m in (8, 72)},
 }
 
 CASES = {
@@ -377,6 +382,27 @@ def step_model(chip):
         STEP_MODELS[pool][0], chip, **STEP_MODELS[pool][1]))
 
 
+# the programs the tests below read for more than one thing: the decode
+# step, an 8-token and a 64-token chunk as the scheduler dispatches them, the
+# K-step scan
+STEP_KINDS = {"t1": {"chunk": 1}, "t8": {"chunk": 8}, "t64": {"chunk": 64},
+              "scan8": {"scan": 8}}
+
+
+@pytest.fixture(scope="module")
+def step_text(chip, step_model):
+    """The compiled text of a `STEP_MODELS` entry's `STEP_KINDS` program,
+    compiled once a module (with `DLT_PALLAS_INTERPRET` taken out by the
+    test that asks: the chip's programs hold kernels)."""
+    def text(model, program):
+        assert "DLT_PALLAS_INTERPRET" not in os.environ
+        spec, shapes, cfg = step_model(model)
+        return aot_step.compile_step(spec, shapes, cfg, chip,
+                                     **STEP_KINDS[program]).as_text()
+
+    return functools.cache(text)
+
+
 @pytest.mark.parametrize("program", list(STEP_PROGRAMS))
 @pytest.mark.parametrize("pool", list(STEP_POOLS))
 def test_step_program_updates_the_pool_in_place(chip, step_model, pool,
@@ -413,11 +439,10 @@ def test_step_program_updates_the_pool_in_place(chip, step_model, pool,
         assert aot_step.logits_blocks(text, 8, chunk, cfg["vocab_size"]) == []
 
 
-@pytest.mark.parametrize("program", ["t1", "t8", "t64", "scan8"])
+@pytest.mark.parametrize("program", list(STEP_KINDS))
 @pytest.mark.parametrize("model", list(STEP_MODELS))
-def test_step_program_reads_the_scale_planes_as_stored(chip, step_model,
-                                                       model, program,
-                                                       monkeypatch):
+def test_step_program_reads_the_scale_planes_as_stored(step_text, model,
+                                                       program, monkeypatch):
     """No step program of any configuration (the decode step, an 8-token
     and a 64-token chunk as the scheduler dispatches them, the K-step scan)
     copies or re-lays a Q40 weight's scale plane: the weights' repack stores
@@ -435,11 +460,8 @@ def test_step_program_reads_the_scale_planes_as_stored(chip, step_model,
     of Laguna and Granite), overlapped with the work before its use; it pads
     and transposes nothing, and every program but a few has one. A `copy`,
     a fusion, or a `copy-done` in any other layout is a re-layout."""
-    spec, shapes, cfg = step_model(model)
     monkeypatch.delenv("DLT_PALLAS_INTERPRET")
-    how = {"t1": {"chunk": 1}, "t8": {"chunk": 8}, "t64": {"chunk": 64},
-           "scan8": {"scan": 8}}[program]
-    text = aot_step.compile_step(spec, shapes, cfg, chip, **how).as_text()
+    text = step_text(model, program)
     assert "tpu_custom_call" in text and "s16[" in text
     assert aot_step.scale_relayouts(text) == []
     # the reader sees what it is there to see: the same plane, rows minor
@@ -448,6 +470,48 @@ def test_step_program_reads_the_scale_planes_as_stored(chip, step_model,
         "  %copy-done.9 = s16[512,80]{0,1:T(8,128)(2,1)S(1)} copy-done(%c)"
     ) == ["copy -> s16[24,64,1536,80]{3,2,1,0:T(8,128)(2,1)}",
           "copy-done -> s16[512,80]{0,1:T(8,128)(2,1)S(1)}"]
+
+
+@pytest.mark.parametrize("program", ["t1", "t64", "scan8"])
+@pytest.mark.parametrize("model", list(STEP_MODELS))
+def test_step_program_converts_no_whole_weight(step_model, step_text, model,
+                                               program, monkeypatch):
+    """No step program of any configuration computes anything from a weight
+    alone (`aot_step.weight_conversions`): every block-quantized matrix of
+    the layers is read packed by a kernel, so none appears by blocks in a
+    float dtype, and the embedding table is gathered in the dtype the
+    program computes in, so no instruction results in an array of its
+    shape. Until PR 49 A.X-K1's leading dense `w2` (7168 x 18432, K over the
+    one-row matvec's bound) stayed planar and XLA dequantized it whole in
+    every program, three fusions and 264 MB written a step, and the K-step
+    scan of every configuration but Granite's cast the float32 table once a
+    block (XLA moves the rows' cast in front of the gather and out of the
+    loop): together 10 % of A.X-K1's window (PERF.md section 6, PR 49)."""
+    _, shapes, _ = step_model(model)
+    monkeypatch.delenv("DLT_PALLAS_INTERPRET")
+    text = step_text(model, program)
+    assert shapes["embedding"].dtype == BF16  # as the engine holds it
+    assert aot_step.weight_conversions(text, shapes) == []
+    # the reader sees what it is there to see: the parent's lines, at this
+    # model's own shapes (a matrix of its layers by blocks, its table)
+    t = next(t for st in aot_step.stack_names(shapes)
+             for name, t in shapes[st].items()
+             if name != "router" and getattr(t, "scales", None) is not None
+             and t.shape[-2] * t.shape[-1] >= 1 << 20)
+    n, k = t.shape[-2:]
+    v, d = shapes["embedding"].shape
+    assert aot_step.weight_conversions(
+        f"  %convert_multiply_fusion.7 = bf16[{n},{k // 32},32]"
+        "{0,2,1:T(8,128)(2,1)} fusion(%get-tuple-element.1216, %g.2), "
+        "kind=kLoop, calls=%fused_computation.146\n"
+        f"  %mul.9 = f32[{n},{k // 32},32]{{2,1,0}} multiply(%c.1, %b.2)\n"
+        f"  %convert.55 = bf16[{v},{d}]{{1,0:T(8,128)(2,1)}} "
+        "convert(%p__embedding__.1)\n"
+        f"  %p__embedding__.1 = f32[{v},{d}]{{1,0}} parameter(3)\n"
+        f"  %gte.5 = bf16[{v},{d}]{{1,0}} get-tuple-element(%w), index=4\n"
+        f"  %x.3 = bf16[72,{k // 32},32]{{2,1,0}} multiply(%a, %b)", shapes
+    ) == [f"convert_multiply_fusion -> bf16[{n},{k // 32},32]",
+          f"mul -> f32[{n},{k // 32},32]", f"convert -> bf16[{v},{d}]"]
 
 
 @pytest.mark.parametrize("pool", ["hk8", "kinds"])
@@ -517,9 +581,9 @@ def test_step_program_carries_the_running_matrices_in_place(chip, monkeypatch):
         assert copies == [], copies[:2]
 
 
-@pytest.mark.parametrize("program", ["t1", "t8", "t64", "scan8"])
+@pytest.mark.parametrize("program", list(STEP_KINDS))
 def test_step_program_carries_the_delta_rule_matrices_in_place(
-        chip, step_model, program, monkeypatch):
+        step_model, step_text, program, monkeypatch):
     """Kimi-Linear's four step programs for a described v5e: the KDA kernels
     are in them under their names (`kda_step`; a chunk's `kda_chunk` too),
     beside the latent attention kernel; the running matrices (8 slots x 6
@@ -534,11 +598,9 @@ def test_step_program_carries_the_delta_rule_matrices_in_place(
 
     spec, shapes, cfg = step_model("kda")
     monkeypatch.delenv("DLT_PALLAS_INTERPRET")
-    how = {"t1": {"chunk": 1}, "t8": {"chunk": 8}, "t64": {"chunk": 64},
-           "scan8": {"scan": 8}}[program]
-    text = aot_step.compile_step(spec, shapes, cfg, chip, **how).as_text()
+    text = step_text("kda", program)
     assert "kda_step" in text and "latent_paged_attention" in text
-    assert ("kda_chunk" in text) == (how.get("chunk", 1) > 1)
+    assert ("kda_chunk" in text) == (STEP_KINDS[program].get("chunk", 1) > 1)
     assert len(spec.runs()) == 2 and text.count("tpu_custom_call") <= 26
     for side in aot_step.held_pools(spec, cfg):
         assert aot_step.pool_relayouts(text, side) == []
