@@ -12,21 +12,39 @@ live here:
   bit-identical to the dense engine (the token-identity acceptance bar).
 
 - `paged_attention` — the Pallas kernel: grid (B,), one row a grid step,
-  all hk heads. The pools stay in HBM; the block table and the lengths ride
-  in as SCALAR-PREFETCH arguments. A row walks its window in steps of 128
-  keys (P = 128 // bt table blocks, 8 at bt 16): the step's blocks are
-  copied pool→VMEM by hand, a block's hk heads in one copy, into a
-  double-buffered (hk, P*bt, hs) tile — no gather, no materialized window —
-  and the score tile of a head is (T*g, 128): full lanes, one accumulator
-  rescale per 128 keys. A row runs ONLY the steps that hold committed keys
-  (trip count ceil(length / 128)): past its length no copy is issued and
-  nothing computed, so a row of length 0 runs only the in-chunk fold. A
-  flash-attention (m, l, acc) carry merges the steps; the current chunk's
-  uncommitted K/V (T = 1 for the decode scan, 1+k for the speculative
-  verify dispatch, 8 or 64 for a prefill chunk) folds in last with an
-  in-chunk causal mask. Operands reach the MXU in the dtype they arrive in
-  (bf16 x bf16 products are exact in f32); statistics, p and the
-  accumulator are f32. f16 never appears (Mosaic cannot lower f16 refs).
+  all hk heads, the rows in order on one core. The pools stay in HBM; the
+  block table and the lengths ride in as SCALAR-PREFETCH arguments. A row
+  walks its window in steps of 128 keys (P = 128 // bt table blocks, 8 at
+  bt 16): the step's blocks are copied pool→VMEM by hand, a block's hk heads
+  in one copy, into a double-buffered (hk, P*bt, hs) tile — no gather, no
+  materialized window. A row runs ONLY the steps that hold committed keys
+  (trip count ceil(length / 128), less the steps wholly behind a sliding
+  window): past its length no copy is issued and nothing computed, so a row
+  of length 0 runs only the in-chunk fold.
+  WHAT PERSISTS ACROSS GRID STEPS (PR 50): the K/V double buffers, their
+  DMA semaphores and one SMEM word, the buffer the call's next step lands
+  in. While row b computes its last step it starts row b + 1's first copies
+  (it reads that row's length and table from the prefetched scalars) into
+  the buffer that step is not reading; a row without steps starts them at
+  once. So only row 0 waits for a copy nothing hides, and a copy is started
+  only where a step will wait for it: the pool's garbage past a length is
+  still never moved.
+  THE HEADS OF A STEP ARE ONE BATCHED COMPUTATION (PR 50): the scores of a
+  group of kv heads are one `dot_general` with the heads as its batch
+  dimension, (heads, T*g, 128), and mask, maximum, exp, sum, the product
+  against V and the statistics' read and write are made once a group, not
+  once a head: independent heads fill the MXU's and the VPU's pipelines
+  where a loop over heads ran eight chains of dependent 4-row operations one
+  after another (3.3 us a step of 0.5 MB read 0.85, against 0.64 for its
+  bytes: `perf/paged_attn_bench.py --cells`). `head_group` takes all hk
+  heads where the group's score block is small and a divisor of hk where
+  T*g is large, from the call's shape alone. A flash-attention (m, l, acc)
+  carry merges the steps; the current chunk's uncommitted K/V (T = 1 for
+  the decode scan, 1+k for the speculative verify dispatch, 8 or 64 for a
+  prefill chunk) folds in last with an in-chunk causal mask, batched the
+  same way. Operands reach the MXU in the dtype they arrive in (bf16 x bf16
+  products are exact in f32); statistics, p and the accumulator are f32.
+  f16 never appears (Mosaic cannot lower f16 refs).
 
 - `latent_paged_attention` (and `latent_paged_attention_xla`, its twin) — the
   same walk for a LATENT spec's pool, (L, N, 1, bt, W): one row a token that
@@ -99,48 +117,86 @@ def visited_keys(length: int, n_read: int, bt: int, lo: int = 0) -> int:
     return max(end - min(lo, length) // step * step, 0)
 
 
+_GROUP_ROWS = 3072  # query rows (heads x T x g) one batched head group holds
+
+
+def head_group(t: int, g: int, hk: int) -> int:
+    """KV heads a step computes as ONE batched product: the largest divisor
+    of hk whose score block stays under `_GROUP_ROWS` query rows, 1.5 MB a
+    float32 temporary of (rows, 128), the most that compiles at every
+    cell's shape within the default scoped VMEM (g 9 at a 64-token chunk,
+    4608 rows for 8 heads, does not). All hk heads at T = 1, 8 and 1 + k
+    and at a 64-token chunk up to g 6; four of eight at g 9. On the chip
+    the larger group was the faster at every shape tried (a 64-token chunk
+    at g 4 / 6 / 9: 0.043 / 0.134 / 0.113 ms a head at a time, 0.030 / 0.068
+    / 0.056 by this rule; PERF.md section 6, PR 50). From the call's shape
+    only."""
+    fit = max(1, _GROUP_ROWS // (t * g))
+    return max(d for d in range(1, hk + 1) if hk % d == 0 and d <= fit)
+
+
+_QK = (((2,), (2,)), ((0,), (0,)))  # (h, r, d) x (h, k, d) -> (h, r, k)
+_PV = (((2,), (1,)), ((0,), (0,)))  # (h, r, k) x (h, k, d) -> (h, r, d)
+
+
 def _kernel(li_ref, tbl_ref, len_ref, win_ref, q_ref, kn_ref, vn_ref, k_hbm,
-            v_hbm, o_ref, kbuf, vbuf, sem, m_ref, l_ref, *, bt, nb, pp, t, g,
-            windowed, head_size=None):
+            v_hbm, o_ref, kbuf, vbuf, sem, m_ref, l_ref, turn_ref, *, bt, nb,
+            pp, t, g, hg, windowed, head_size=None):
     """Grid step b: one row's queries, all hk kv heads, against the steps
     of pp pool blocks that hold its committed keys.
 
     Blocks: q (1, hk, t*g, hs) | k_new/v_new (1, hk, t, hs) | out
     (1, hk, t*g, hs) f32, the flash accumulator until the last line. k_hbm/
-    v_hbm are the whole pools, left in HBM. Scratch: kbuf/vbuf (2, hk,
-    pp*bt, hs) double buffers in the pool dtype, sem (2, 2) DMA semaphores
-    (k|v, buffer), the flash (m, l) statistics (hk, t*g, 1). li/tbl/len are
-    scalar-prefetched, and so is win, this layer's sliding window (0: none;
-    compiled in only where the model has one, `windowed`): query ti of the
-    row, at position len + ti, reads keys above len + ti - win, so the steps
-    wholly under the FIRST query's bound are not run and the others mask."""
+    v_hbm are the whole pools, left in HBM. Scratch, which PERSISTS from one
+    grid step to the next (the grid is sequential): kbuf/vbuf (2, hk, pp*bt,
+    hs) double buffers in the pool dtype, sem (2, 2) DMA semaphores (k|v,
+    buffer), the flash (m, l) statistics (hk, t*g, 1), and turn (1,) in
+    SMEM, the buffer the NEXT step of the call lands in: a step's buffer
+    follows the steps the call has run, not the step's index in its row, so
+    that row b can start row b + 1's first copies into the buffer its own
+    last step is not reading and the two rows never meet in one. li/tbl/len
+    are scalar-prefetched, and so is win, this layer's sliding window (0:
+    none; compiled in only where the model has one, `windowed`): query ti of
+    the row, at position len + ti, reads keys above len + ti - win, so the
+    steps wholly under the FIRST query's bound are not run and the others
+    mask. `hg` heads are computed at once (`head_group`)."""
     b = pl.program_id(0)
+    n_rows = pl.num_programs(0)
     hk, sk, hs = kbuf.shape[1:]
-    length = len_ref[b]
     li = li_ref[0]
     scale = jnp.float32(1.0 / math.sqrt(head_size or hs))
-    # steps that hold a committed key; a row of length 0 runs none
-    n_live = jnp.minimum((length + sk - 1) // sk, -(-nb // pp))
-    j0 = 0
     if windowed:
         win = win_ref[0]
         # key position > qpos - win; no window: every key passes
         reach = jnp.where(win > 0, win, jnp.int32(1 << 30))
-        j0 = jnp.minimum(jnp.maximum(length - reach + 1, 0) // sk, n_live)
+
+    def steps_of(length):
+        """[first, end) of the steps a row of `length` runs: those that hold
+        a committed key (a row of length 0 runs none), less the ones wholly
+        behind its first query's window."""
+        n_live = jnp.minimum((length + sk - 1) // sk, -(-nb // pp))
+        if not windowed:
+            return 0, n_live
+        return (jnp.minimum(jnp.maximum(length - reach + 1, 0) // sk, n_live),
+                n_live)
+
+    length = len_ref[b]
+    j0, n_live = steps_of(length)
+    if windowed:
         # query row r = ti * g + gi sits at position length + r // g
         qpos = length + jax.lax.broadcasted_iota(jnp.int32, (t * g, 1), 0) // g
     # the MXU takes q and K as they arrive: bf16 x bf16 with f32 accumulation
     # gives the products an upcast would; any f32 operand makes the dot f32
     dt = jnp.promote_types(q_ref.dtype, kbuf.dtype)
 
-    def copies(j, buf):
-        """The step's pp pages, all heads of a page in one copy, landing at
-        16-row offsets of one (pp*bt, hs) tile a head. A page past the
-        window (nb not a multiple of pp) re-reads the window's last one;
+    def copies(r, j, buf):
+        """Step j of row r: its pp pages, all heads of a page in one copy,
+        landing at 16-row offsets of one (pp*bt, hs) tile a head. A page past
+        the window (nb not a multiple of pp) re-reads the window's last one;
         its keys sit past every length and mask out."""
         out = []
         for i in range(pp):
-            page = tbl_ref[b * nb + jnp.minimum(j * pp + i, nb - 1)]
+            page = tbl_ref[r * nb + jnp.minimum(j * pp + i, nb - 1)]
             rows = pl.ds(i * bt, bt)
             out.append(pltpu.make_async_copy(
                 k_hbm.at[li, page], kbuf.at[buf, :, rows, :], sem.at[0, buf]))
@@ -148,24 +204,60 @@ def _kernel(li_ref, tbl_ref, len_ref, win_ref, q_ref, kn_ref, vn_ref, k_hbm,
                 v_hbm.at[li, page], vbuf.at[buf, :, rows, :], sem.at[1, buf]))
         return out
 
-    @pl.when(n_live > j0)
-    def _first():
-        for c in copies(j0, j0 % 2):
+    def start(r, j, buf):
+        for c in copies(r, j, buf):
             c.start()
+
+    @pl.when(b == 0)
+    def _first_row():  # the only row that starts its own first copies
+        turn_ref[0] = 0
+
+        @pl.when(n_live > j0)
+        def _():
+            start(0, j0, 0)
+
+    first = turn_ref[0]  # where this row's first step was (or would be) sent
+    turn_ref[0] = (first + n_live - j0) % 2
+    # the next row that exists: its first copies are started HERE, during
+    # this row's last step (at once, if this row has no step), so that the
+    # row itself only waits. A row without steps is sent nothing and passes
+    # the duty on; nothing is started that no step waits for.
+    nxt = jnp.minimum(b + 1, n_rows - 1)
+    nj0, n_end = steps_of(len_ref[nxt])
+    feeds_next = (b + 1 < n_rows) & (n_end > nj0)
+
+    @pl.when(feeds_next & (n_live == j0))
+    def _():
+        start(nxt, nj0, first)
 
     m_ref[:] = jnp.full_like(m_ref, _NEG)
     l_ref[:] = jnp.zeros_like(l_ref)
     o_ref[:] = jnp.zeros_like(o_ref)
 
+    def by_group(attend):
+        """`attend(heads)` over the hk heads, hg at once."""
+        if hg == hk:
+            attend(slice(None))
+            return
+
+        def group(i, c):
+            attend(pl.ds(i * hg, hg))
+            return c
+
+        jax.lax.fori_loop(0, hk // hg, group, 0)
+
     def step(j, carry):
-        buf = j % 2
+        buf = (first + j - j0) % 2
 
         @pl.when(j + 1 < n_live)
-        def _next():
-            for c in copies(j + 1, 1 - buf):
-                c.start()
+        def _():
+            start(b, j + 1, 1 - buf)
 
-        for c in copies(j, buf):
+        @pl.when(feeds_next & (j + 1 == n_live))
+        def _():
+            start(nxt, nj0, 1 - buf)
+
+        for c in copies(b, j, buf):
             c.wait()
         # keys at/after the row's committed length are uncommitted garbage
         # (scratch writes, CoW slack): only the last live step has any. One
@@ -179,30 +271,28 @@ def _kernel(li_ref, tbl_ref, len_ref, win_ref, q_ref, kn_ref, vn_ref, k_hbm,
             kpos = j * sk + jax.lax.broadcasted_iota(jnp.int32, (1, sk), 1)
             ok = live & (kpos > qpos - reach)  # (t*g, sk)
 
-        def head(h, c):
+        def attend(heads):
             s = jax.lax.dot_general(
-                q_ref[0, h].astype(dt), kbuf[buf, h].astype(dt),
-                (((1,), (1,)), ((), ())),
+                q_ref[0, heads].astype(dt), kbuf[buf, heads].astype(dt), _QK,
                 preferred_element_type=jnp.float32) * scale
-            s = jnp.where(ok, s, _NEG)  # (t*g, sk)
+            s = jnp.where(ok, s, _NEG)  # (hg, t*g, sk)
             # NaN guard: 0 * garbage stays finite
-            vb = jnp.where(live_v, vbuf[buf, h].astype(jnp.float32), 0.0)
-            m_old = m_ref[h]
-            m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
+            vb = jnp.where(live_v, vbuf[buf, heads].astype(jnp.float32), 0.0)
+            m_old = m_ref[heads]
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
             a = jnp.exp(m_old - m_new)
             p = jnp.exp(s - m_new)
             if windowed:
                 # a query whose window lies past this whole step has m_new
                 # at _NEG, where exp(s - m_new) would read 1
                 p = jnp.where(ok, p, 0.0)
-            l_ref[h] = l_ref[h] * a + jnp.sum(p, axis=1, keepdims=True)
-            o_ref[0, h] = o_ref[0, h] * a + jax.lax.dot_general(
-                p, vb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[h] = m_new
-            return c
+            l_ref[heads] = l_ref[heads] * a + jnp.sum(p, axis=-1,
+                                                      keepdims=True)
+            o_ref[0, heads] = o_ref[0, heads] * a + jax.lax.dot_general(
+                p, vb, _PV, preferred_element_type=jnp.float32)
+            m_ref[heads] = m_new
 
-        jax.lax.fori_loop(0, hk, head, 0)
+        by_group(attend)
         return carry
 
     jax.lax.fori_loop(j0, n_live, step, 0)
@@ -215,25 +305,23 @@ def _kernel(li_ref, tbl_ref, len_ref, win_ref, q_ref, kn_ref, vn_ref, k_hbm,
     if windowed:
         chunk_ok &= ti - tau < reach
 
-    def fold(h, c):
-        q = q_ref[0, h].astype(jnp.float32)
-        kn = kn_ref[0, h].astype(jnp.float32)  # (t, hs)
-        vn = vn_ref[0, h].astype(jnp.float32)
-        s_new = jax.lax.dot_general(q, kn, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
+    def fold(heads):
+        q = q_ref[0, heads].astype(jnp.float32)
+        kn = kn_ref[0, heads].astype(jnp.float32)  # (hg, t, hs)
+        vn = vn_ref[0, heads].astype(jnp.float32)
+        s_new = jax.lax.dot_general(
+            q, kn, _QK, preferred_element_type=jnp.float32) * scale
         s_new = jnp.where(chunk_ok, s_new, _NEG)
-        m_old = m_ref[h]
-        m_f = jnp.maximum(m_old, jnp.max(s_new, axis=1, keepdims=True))
+        m_old = m_ref[heads]
+        m_f = jnp.maximum(m_old, jnp.max(s_new, axis=-1, keepdims=True))
         a_f = jnp.exp(m_old - m_f)
         p_new = jnp.exp(s_new - m_f)
-        denom = l_ref[h] * a_f + jnp.sum(p_new, axis=1, keepdims=True)
-        out = o_ref[0, h] * a_f + jax.lax.dot_general(
-            p_new, vn, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        o_ref[0, h] = out / denom
-        return c
+        denom = l_ref[heads] * a_f + jnp.sum(p_new, axis=-1, keepdims=True)
+        out = o_ref[0, heads] * a_f + jax.lax.dot_general(
+            p_new, vn, _PV, preferred_element_type=jnp.float32)
+        o_ref[0, heads] = out / denom
 
-    jax.lax.fori_loop(0, hk, fold, 0)
+    by_group(fold)
 
 
 @functools.partial(jax.jit, static_argnames=("n_read", "interpret", "name",
@@ -298,14 +386,20 @@ def paged_attention(q, kc, vc, k_new, v_new, tables, lengths, layer_idx, *,
                         pltpu.VMEM((2, hk, pp * bt, hs), vc.dtype),
                         pltpu.SemaphoreType.DMA((2, 2)),
                         pltpu.VMEM((hk, t * g, 1), jnp.float32),
-                        pltpu.VMEM((hk, t * g, 1), jnp.float32)],
+                        pltpu.VMEM((hk, t * g, 1), jnp.float32),
+                        pltpu.SMEM((1,), jnp.int32)],
     )
     body = functools.partial(_kernel, bt=bt, nb=nb, pp=pp, t=t, g=g,
-                             windowed=windowed, head_size=head_size)
+                             hg=head_group(t, g, hk), windowed=windowed,
+                             head_size=head_size)
     out = pl.pallas_call(
         body,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hk, t * g, hs), jnp.float32),
+        # rows in order on one core: the scratch carries a row's prefetched
+        # first copies into the next grid step
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret, name=name,
     )(jnp.asarray([layer_idx], jnp.int32), tbl_flat,
       jnp.asarray(lengths, jnp.int32),

@@ -25,7 +25,9 @@ buckets, rows' lengths read from a run of `chat-closed`; and against 2048
 and 4096 keys with illustrative long rows; and, since a prefill dispatch
 reads the pool in two calls (`models/forward.py RowMap.attend`, PR 45), the
 lead's call B 1, T in {8, 64} at the longest of those rows beside the riders'
-call, which is the B 8, T 1 row), the kernel beside
+call, which is the B 8, T 1 row; and last a LENGTH SWEEP of that call, every
+row at 0 to 1024 keys, fitted to rows x (a + b x steps): a row's fixed cost
+and a step's, beside a step's bytes at the chip's peak), the kernel beside
 the XLA gather path (an engine's `paged_kernel=False`), one call a layer of a
 scan inside one jit, with the bytes and FLOP a call needs over the chip's
 peaks. It uses only `paged_attention` and `paged_attention_xla`, so a copy
@@ -173,6 +175,60 @@ CELL_LENGTHS = {512: (0, 0, 101, 145, 186, 221, 275, 332),
                 4096: (0, 75, 190, 330, 520, 640, 1200, 3000)}
 
 
+def _time_calls(attend, args, nb, layers, reps):
+    """Seconds a call of `attend(*args, layer, n_read=nb)`: one call a layer
+    of a scan over `layers * reps` layers inside one jit (a lone call is all
+    launch), the shortest of three runs after the one that compiles.
+    Returns (the scan's summed output, seconds a call)."""
+    import jax
+    import jax.numpy as jnp
+
+    q = args[0]
+
+    @jax.jit
+    def run(*a):
+        def body(acc, li):
+            return acc + attend(*a, li, n_read=nb), None
+
+        return jax.lax.scan(body, jnp.zeros(q.shape, jnp.float32),
+                            jnp.tile(jnp.arange(layers), reps))[0]
+
+    out = run(*args).block_until_ready()
+    best = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        run(*args).block_until_ready()
+        best = min(best, time.perf_counter() - t0)
+    return out, best / (layers * reps)
+
+
+_CELL_HEADS = (8, 4, 128)  # the dense cells' hk, g, hs
+_CELL_BT = 16
+
+
+def _cell_inputs(b, t, nb, layers, seed, pool_blocks):
+    """bf16 q (b, t, hq, hs), K and V pools of `layers` x at least
+    `pool_blocks` blocks, the chunk's K and V and a shuffled (b, nb) table at
+    the dense cells' heads."""
+    import jax.numpy as jnp
+
+    hk, g, hs = _CELL_HEADS
+    n = max(b * nb + 1, pool_blocks)
+    rng = np.random.default_rng(seed)
+
+    def mk(shape):
+        return _mk(rng, shape).astype(jnp.bfloat16)
+
+    pool = (layers, n, hk, _CELL_BT, hs)
+    kc, vc = mk(pool), mk(pool)
+    q = mk((b, t, hk * g, hs))
+    kn, vn = mk((b, hk, t, hs)), mk((b, hk, t, hs))
+    ids = np.arange(1, n)
+    rng.shuffle(ids)
+    tables = jnp.asarray(ids[:b * nb].reshape(b, nb).astype(np.int32))
+    return q, kc, vc, kn, vn, tables
+
+
 def bench_cell(t: int, window: int, *, layers=8, reps=4, seed=0,
                pool_blocks=1280, lead=False):
     """One (T, window bucket) of the cells' dispatches: ms a call of the
@@ -191,40 +247,15 @@ def bench_cell(t: int, window: int, *, layers=8, reps=4, seed=0,
     lens = np.asarray(CELL_LENGTHS[window], np.int32)
     if lead:
         lens = lens.max(keepdims=True)
-    B, hk, g, hs, bt = len(lens), 8, 4, 128, 16
-    nb = window // bt
-    n = max(B * nb + 1, pool_blocks)  # the dense cell's pool by default
-    rng = np.random.default_rng(seed)
-
-    def mk(shape):
-        return _mk(rng, shape).astype(jnp.bfloat16)
-
-    kc, vc = mk((layers, n, hk, bt, hs)), mk((layers, n, hk, bt, hs))
-    q = mk((B, t, hk * g, hs))
-    kn, vn = mk((B, hk, t, hs)), mk((B, hk, t, hs))
-    ids = np.arange(1, n)
-    rng.shuffle(ids)
-    tables = jnp.asarray(ids[:B * nb].reshape(B, nb).astype(np.int32))
+    B, hk, g, hs = len(lens), *_CELL_HEADS
+    nb = window // _CELL_BT
+    q, kc, vc, kn, vn, tables = _cell_inputs(B, t, nb, layers, seed,
+                                             pool_blocks)
     lengths = jnp.asarray(lens)
 
-    def timed(attend):
-        @jax.jit
-        def run(*args):
-            def body(acc, li):
-                return acc + attend(*args, li, n_read=nb), None
-
-            zero = jnp.zeros((B, t, hk * g, hs), jnp.float32)
-            return jax.lax.scan(body, zero, jnp.tile(jnp.arange(layers),
-                                                     reps))[0]
-
-        args = (q, kc, vc, kn, vn, tables, lengths)
-        out = run(*args).block_until_ready()
-        t0 = time.perf_counter()
-        run(*args).block_until_ready()
-        return out, (time.perf_counter() - t0) / (layers * reps)
-
-    out_k, dt_k = timed(paged_attention)
-    out_x, dt_x = timed(paged_attention_xla)
+    args = (q, kc, vc, kn, vn, tables, lengths)
+    out_k, dt_k = _time_calls(paged_attention, args, nb, layers, reps)
+    out_x, dt_x = _time_calls(paged_attention_xla, args, nb, layers, reps)
     # what the call needs: each row's committed keys and its chunk, K and V
     # once; q in, the output out; two FLOP a multiply-add, QK and PV
     keys = int(lens.sum()) + B * t
@@ -246,10 +277,53 @@ def bench_cell(t: int, window: int, *, layers=8, reps=4, seed=0,
     }
 
 
+SWEEP_LENGTHS = (0, 128, 256, 384, 512, 768, 1024)
+
+
+def bench_sweep(*, window=1024, rows=8, layers=8, reps=16, seed=0,
+                pool_blocks=1280):
+    """What a ROW costs and what a STEP costs, apart: the riders' call (B 8 x
+    T 1 at the 1024 bucket) with every row at the same committed length, one
+    timing a length of `SWEEP_LENGTHS`, and the least-squares fit of
+    call = rows x (a + b x steps), a step being 128 keys. `a` is a row before
+    its first key (the grid step, the statistics' reset, a first copy that
+    nothing hides, the fold of the chunk's own key), `b` one more step; beside
+    them the time a step's K and V bytes take at the chip's peak."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_llama_tpu.ops.pallas_paged_attention import (
+        _STEP_KEYS, paged_attention)
+
+    hk, _, hs = _CELL_HEADS
+    nb = window // _CELL_BT
+    inputs = _cell_inputs(rows, 1, nb, layers, seed, pool_blocks)
+    steps = [-(-ln // _STEP_KEYS) for ln in SWEEP_LENGTHS]
+    calls_us = []
+    for ln in SWEEP_LENGTHS:
+        lengths = jnp.full((rows,), ln, jnp.int32)
+        _, dt = _time_calls(paged_attention, (*inputs, lengths), nb, layers,
+                            reps)
+        calls_us.append(dt * 1e6)
+    b_us, call0 = np.polyfit(steps, calls_us, 1)
+    step_bytes = 2 * hk * _STEP_KEYS * hs * 2  # K and V, bf16
+    return {
+        "sweep": f"B {rows} x T 1, window {window}", "lengths": SWEEP_LENGTHS,
+        "call_us": [round(c, 2) for c in calls_us],
+        "row_fixed_us_a": round(float(call0) / rows, 3),
+        "step_us_b": round(float(b_us) / rows, 3),
+        "step_bytes_floor_us": round(step_bytes / V5E_HBM_BPS * 1e6, 3),
+        "fit_worst_residual_us": round(float(np.max(np.abs(
+            np.polyval((b_us, call0), steps) - calls_us))), 2),
+        "backend": jax.default_backend(),
+    }
+
+
 def run_cells(layers=8, reps=4):
     return [bench_cell(t, w, layers=layers, reps=reps, lead=lead)
             for t, lead in ((1, False), (8, False), (64, False), (8, True),
-                            (64, True)) for w in CELL_LENGTHS]
+                            (64, True)) for w in CELL_LENGTHS] + [
+        bench_sweep(layers=layers, reps=4 * reps)]
 
 
 def main(argv=None):

@@ -21,6 +21,11 @@ TFLOP/s (Google Cloud, "TPU v5e"), and ps a weight. It uses only `q4_matmul`
 and `qmatmul`, so a copy of this file in another checkout times that
 checkout's kernel.
 
+`--section unit` (the chip; ROADMAP S10's first reading): what the MATRIX UNIT
+alone asks of the same matrices at the same rows, a bf16 weight block already
+in VMEM and no decode: ps a weight beside the 1.30 that four units taking 128
+operand values a cycle at 1.5 GHz would read.
+
 `--section model|consistency` run anywhere: the byte model, and the kernel
 under the interpreter against the oracle (tests/test_fused_matmul.py replays
 both without timing).
@@ -29,6 +34,7 @@ Each result prints as one JSON line (the microbench.py idiom).
 """
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -37,6 +43,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
@@ -150,14 +157,15 @@ def _chained(call, m, n):
     return jax.jit(f)
 
 
-def _ms_a_call(fn, *args, reps=5):
+def _ms_a_call(fn, *args, reps=5, calls=CHAIN):
+    """ms a call of a program that chains `calls` of them."""
     jax.block_until_ready(fn(*args))
     jax.block_until_ready(fn(*args))
     t0 = time.perf_counter()
     for _ in range(reps):
         out = fn(*args)
     jax.block_until_ready(out)
-    return (time.perf_counter() - t0) / reps / CHAIN * 1e3
+    return (time.perf_counter() - t0) / reps / calls * 1e3
 
 
 def sec_cells():
@@ -190,16 +198,65 @@ def sec_cells():
             emit(**rec)
 
 
+UNIT_BLOCK = (512, 2048)  # the resident bf16 weight block: 2 MB of VMEM
+
+
+def _unit_kernel(x_ref, w_ref, o_ref):
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    o_ref[...] += jax.lax.dot_general(
+        x_ref[...], w_ref[...], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnames=("steps", "interpret"))
+def unit_passes(x, w, *, steps, interpret=False):
+    """`steps` products of the m rows of x with ONE (bn, kc) bf16 weight
+    block, rows, block and the float32 accumulator resident in VMEM (their
+    block indices never change, so each is copied once): what is timed is
+    the matrix unit taking bn x kc weight values a step, nothing else."""
+    m, (bn, kc) = x.shape[0], w.shape
+    return pl.pallas_call(
+        _unit_kernel, grid=(steps,),
+        in_specs=[pl.BlockSpec((m, kc), lambda i: (0, 0)),
+                  pl.BlockSpec((bn, kc), lambda i: (0, 0))],
+        out_specs=pl.BlockSpec((m, bn), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((m, bn), jnp.float32),
+        interpret=interpret, name="mxu_unit_passes")(x, w)
+
+
+def sec_unit(rows=(8, 16, 72)):
+    if jax.default_backend() != "tpu":
+        sys.exit("--section unit times the chip's matrix unit: no TPU here "
+                 f"({jax.default_backend()})")
+    bn, kc = UNIT_BLOCK
+    w = jax.random.normal(jax.random.PRNGKey(1), (bn, kc), jnp.bfloat16)
+    for cfg, name, n, k in CELL_SHAPES:
+        steps = -(-n // bn) * -(-k // kc)  # the matrix, a block a step
+        for m in rows:
+            x = jax.random.normal(jax.random.PRNGKey(9), (m, kc), jnp.bfloat16)
+            ms = _ms_a_call(functools.partial(unit_passes, steps=steps), x, w,
+                            calls=1)
+            emit(section="unit", config=cfg, matrix=name, m=m, n=n, k=k,
+                 steps=steps, ms=round(ms, 4),
+                 ps_weight=round(ms * 1e9 / (steps * bn * kc), 3),
+                 unit_floor_ps=1.30)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--section", default=None,
-                    choices=["model", "consistency"])
+                    choices=["model", "consistency", "unit"])
     ap.add_argument("--cells", action="store_true")
     args = ap.parse_args()
     emit(section="meta", backend=jax.default_backend(),
          device=str(jax.devices()[0]))
     if args.cells:
         return sec_cells()
+    if args.section == "unit":
+        return sec_unit()
     if args.section in (None, "model"):
         sec_model()
     if args.section in (None, "consistency"):

@@ -14,7 +14,8 @@ import jax.numpy as jnp
 from distributed_llama_tpu.ops.attention import gqa_attention
 from distributed_llama_tpu.ops.pallas_attention import fused_decode_attention
 from distributed_llama_tpu.ops.pallas_paged_attention import (
-    paged_attention, paged_attention_xla, pages_per_step, visited_keys)
+    head_group, paged_attention, paged_attention_xla, pages_per_step,
+    visited_keys)
 
 
 def _oracle(q_btgh, kc, vc, k_new, v_new, layer_idx, pos, window):
@@ -97,24 +98,34 @@ def test_tiled_window_matches_one_block(monkeypatch):
 # --------------------------------------------------- the paged-attention kernel
 
 
-def _paged_case(t, g, bt, dtype, seed):
-    """Six rows whose committed lengths straddle every edge of a kernel step
-    (nothing, one key, a block less one, exactly a step, one past it, the
-    whole window), a window of two steps and three blocks (so the last step
-    is short), and NaN in every pool position and table entry past a row's
-    length. Returns the kernel's output and the float32 reference's, which
-    reads the same pool with the NaN taken out."""
+def _paged_case(t, g, bt, dtype, seed, lens=None, nb=None, hk=2, window=None,
+                real_hs=None):
+    """Rows of committed lengths `lens` (by default six that straddle every
+    edge of a kernel step: nothing, one key, a block less one, exactly a
+    step, one past it, the whole window) in a window of `nb` blocks (by
+    default two steps and three blocks, so the last step is short), and NaN
+    in every pool position and table entry past a row's length. `window`:
+    the layer's sliding window, a traced scalar to the kernel. `real_hs`: the
+    values of a head that are real in heads padded with zeros to 128 lanes.
+    Returns the kernel's output and the float32 reference's, which reads the
+    same pool with the NaN taken out."""
     rng = np.random.default_rng(seed)
-    layers, hk, hs, layer = 2, 2, 32, 1
+    layers, hs, layer = 2, 32, 1
     pp = 128 // bt
-    nb = 2 * pp + 3
-    assert pages_per_step(nb, bt) == pp and nb % pp
-    step = pp * bt
-    lens = [0, 1, bt - 1, step, step + 1, nb * bt]
+    if lens is None:
+        nb = 2 * pp + 3
+        assert pages_per_step(nb, bt) == pp and nb % pp
+        step = pp * bt
+        lens = [0, 1, bt - 1, step, step + 1, nb * bt]
+    if real_hs:
+        hs = 128
     b, n = len(lens), len(lens) * nb + 1
 
     def mk(shape):
-        return rng.normal(size=shape).astype(np.float32)
+        a = rng.normal(size=shape).astype(np.float32)
+        if real_hs:
+            a[..., real_hs:] = 0.0
+        return a
 
     kc, vc = mk((layers, n, hk, bt, hs)), mk((layers, n, hk, bt, hs))
     ids = np.arange(1, n)
@@ -136,12 +147,16 @@ def _paged_case(t, g, bt, dtype, seed):
 
     out = paged_attention(cast(q), cast(kc), cast(vc), cast(kn), cast(vn),
                           jnp.asarray(planted), lengths, layer, n_read=nb,
-                          interpret=True)
+                          interpret=True, head_size=real_hs,
+                          window=None if window is None else jnp.int32(window))
+    # the reference sees the real values of a padded head alone
     ref = paged_attention_xla(
-        *(cast(np.nan_to_num(a)).astype(jnp.float32)
+        *(cast(np.nan_to_num(a)).astype(jnp.float32)[..., :real_hs or hs]
           for a in (q, kc, vc, kn, vn)),
-        jnp.asarray(clean), lengths, layer, n_read=nb)
-    return np.asarray(out), np.asarray(ref)
+        jnp.asarray(clean), lengths, layer, n_read=nb, window=window or 0)
+    out = np.asarray(out)
+    assert not real_hs or not out[..., real_hs:].any()
+    return out[..., :real_hs or hs], np.asarray(ref)
 
 
 @pytest.mark.parametrize("bt", [8, 16])
@@ -161,6 +176,81 @@ def test_paged_attention_takes_bfloat16_operands_as_they_are():
     out, ref = _paged_case(8, 4, 16, jnp.bfloat16, seed=7)
     assert out.dtype == np.float32 and np.isfinite(out).all()
     assert np.abs(out - ref).max() < 2e-5
+
+
+# what the kernel's own pipeline can break (PR 50): row b starts row b + 1's
+# first copies into the buffer its own last step is not reading, and a step's
+# heads are one batched product, in groups where T*g is large. Rows by their
+# steps of 128 keys in a window of three: odd then even, even then odd, a row
+# of length 0 between live rows and last, a row that fills the window
+_TURNS = [100, 200, 0, 300, 256, 128, 384, 0]
+PIPELINE_CASES = {
+    **{f"turns-g{g}-t{t}": dict(t=t, g=g, lens=_TURNS)
+       for g in (4, 7, 9) for t in (1, 8, 64)},
+    # eight heads in two groups of four at a 64-token chunk (576 query rows
+    # a head)
+    "turns-hk8-g9-t64": dict(t=64, g=9, hk=8, lens=_TURNS),
+    "one-row": dict(t=1, g=4, lens=[300]),
+    "one-empty-row": dict(t=1, g=4, lens=[0]),
+    "empty-rows-first": dict(t=1, g=4, lens=[0, 0, 257, 0, 0, 130]),
+    # behind a window of 100 the row of 300 starts at its SECOND step (the
+    # copy the row of 50 before it issues), the row of 384 at its third, the
+    # row of 290 after an empty one at its second
+    **{f"window-j0-g{g}-t{t}": dict(t=t, g=g, window=100,
+                                    lens=[50, 300, 384, 0, 290, 128])
+       for g, t in ((7, 1), (7, 8), (9, 64))},
+    # a window that leaves a row NO step of committed keys is not a case: the
+    # first query always reads its own row's last step; window 0 is "none"
+    "window-none-g7-t1": dict(t=1, g=7, window=0, lens=_TURNS),
+    # heads of 64 in lanes of 128
+    **{f"padded-heads-t{t}": dict(t=t, g=4, real_hs=64, lens=_TURNS)
+       for t in (1, 64)},
+    "padded-heads-bf16-t1": dict(t=1, g=4, real_hs=64, lens=_TURNS,
+                                 dtype=jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", list(PIPELINE_CASES))
+def test_paged_attention_matches_the_gather_path_across_rows(case):
+    kw = dict(PIPELINE_CASES[case])
+    out, ref = _paged_case(kw.pop("t"), kw.pop("g"), 16,
+                           kw.pop("dtype", jnp.float32),
+                           seed=list(PIPELINE_CASES).index(case), nb=24,
+                           **kw)
+    assert np.isfinite(out).all()
+    assert np.abs(out - ref).max() < 2e-5
+
+
+@pytest.mark.parametrize("rows,hg", [(64, 2), (16, 1)])
+def test_paged_attention_in_head_groups_is_the_ungrouped_result(monkeypatch,
+                                                                rows, hg):
+    """The same call with its four heads taken two at a time and one at a
+    time (the group's bound shrunk) gives the bits the whole batch gives."""
+    import distributed_llama_tpu.ops.pallas_paged_attention as ppa
+
+    kw = dict(t=8, g=4, bt=16, dtype=jnp.float32, seed=3, lens=_TURNS, nb=24,
+              hk=4, window=100)
+    assert head_group(8, 4, 4) == 4
+    whole, ref = _paged_case(**kw)
+    monkeypatch.setattr(ppa, "_GROUP_ROWS", rows)
+    assert head_group(8, 4, 4) == hg
+    paged_attention.clear_cache()
+    try:
+        grouped, _ = _paged_case(**kw)
+    finally:
+        paged_attention.clear_cache()
+    np.testing.assert_array_equal(grouped, whole)
+    assert np.abs(whole - ref).max() < 2e-5
+
+
+@pytest.mark.parametrize("t,g,hk,want", [
+    (1, 4, 8, 8), (1, 7, 4, 4), (1, 9, 8, 8), (8, 4, 8, 8), (8, 9, 8, 8),
+    (5, 4, 8, 8), (64, 4, 8, 8), (64, 4, 2, 2), (64, 6, 8, 8), (64, 7, 4, 4),
+    (64, 9, 8, 4), (64, 9, 2, 2), (64, 32, 8, 1), (64, 4, 1, 1)])
+def test_head_group_follows_the_query_rows(t, g, hk, want):
+    """All heads at once up to 3072 query rows a group, a divisor of hk that
+    keeps the group's score block under that where T*g is larger."""
+    assert head_group(t, g, hk) == want
 
 
 @pytest.mark.parametrize("length,n_read,bt,want", [

@@ -38,7 +38,7 @@ from distributed_llama_tpu.ops.moe_grouped import capacity, row_tile
 from distributed_llama_tpu.ops.pallas_attention import fused_decode_attention
 from distributed_llama_tpu.ops.pallas_moe_grouped import _moe_grouped_q4
 from distributed_llama_tpu.ops.pallas_paged_attention import (
-    latent_paged_attention, paged_attention)
+    head_group, latent_paged_attention, paged_attention)
 from distributed_llama_tpu.ops.pallas_q4 import _q4_matvec, _q4_matvec_inline
 from distributed_llama_tpu.ops.pallas_q4_mm import q4_matmul, q4_mm_supported
 from distributed_llama_tpu.quants import FloatType, QTensor, scale_plane_cols
@@ -131,12 +131,14 @@ def matmul(m, n, k, out=BF16, lead=(2,)):
 TRACED_I32 = object()  # a keyword argument that is a traced i32 scalar
 
 
-def paged(b, t, hq, hk, n_read, q=F32, window=False):
+def paged(b, t, hq, hk, n_read, q=F32, window=False, head_size=None):
     """B rows x T chunk queries against a (L, N, hk, 16, 128) bf16 pool;
-    `window`: the layer's sliding window rides in as a traced scalar."""
+    `window`: the layer's sliding window rides in as a traced scalar;
+    `head_size`: the real values of heads padded to the pool's 128 lanes."""
     pool = ((LAYERS, 517, hk, 16, HS), BF16)
     new = ((b, hk, t, HS), BF16)
-    static = {"n_read": n_read, **({"window": TRACED_I32} if window else {})}
+    static = {"n_read": n_read, **({"window": TRACED_I32} if window else {}),
+              **({"head_size": head_size} if head_size else {})}
     return (paged_attention,
             [((b, t, hq, HS), q), pool, pool, new, new, ((b, 512), I32),
              ((b,), I32), ((), I32)], static)
@@ -268,6 +270,23 @@ CASES = {
     "paged-window-g7-b8-t1-w1024": paged(8, 1, 28, 4, 64, BF16, True),
     "paged-window-g7-b8-t64-w8192": paged(8, 64, 28, 4, 512, BF16, True),
     "paged-window-b8-t8-w512": paged(8, 8, 32, 8, 32, BF16, True),
+    # the two calls a dispatch makes since PR 45, heads batched since PR 50
+    # (`PAGED_HEAD_GROUPS` below): the lead's chunk alone, B 1 x T 8 and T 64,
+    # beside the riders' B 8 x T 1 above; Laguna's kinds at the largest score
+    # block (its full layers' g 6 and its window layers' g 9, a long row's
+    # 2048 keys);
+    # SmallThinker's lead; heads of 64 padded to the pool's 128 lanes (LFM2)
+    "paged-lead-b1-t8-w1024": paged(1, 8, 32, 8, 64, BF16),
+    "paged-lead-b1-t64-w1024": paged(1, 64, 32, 8, 64, BF16),
+    "paged-window-g7-b1-t64-w1024": paged(1, 64, 28, 4, 64, BF16, True),
+    "paged-window-g6-b1-t64-w2048": paged(1, 64, 48, 8, 128, BF16, True),
+    "paged-window-g9-b1-t64-w2048": paged(1, 64, 72, 8, 128, BF16, True),
+    "paged-window-g9-b8-t64-w1024": paged(8, 64, 72, 8, 64, BF16, True),
+    "paged-window-g9-b8-t1-w2048": paged(8, 1, 72, 8, 128, BF16, True),
+    "paged-padded-heads-b8-t1-w1024": paged(8, 1, 32, 8, 64, BF16,
+                                            head_size=64),
+    "paged-padded-heads-b1-t64-w1024": paged(1, 64, 32, 8, 64, BF16,
+                                             head_size=64),
     # latent attention: the decode step, an 8-token and a 64-token chunk
     # (eight query blocks of 512 rows) at the 1024 bucket, and a long row's
     # 8192
@@ -339,6 +358,34 @@ def test_kernel_compiles_for_v5e(chip, case):
                   if v is TRACED_I32 else v) for k, v in static.items()}
     compiled = fn.lower(*args, interpret=False, **static).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# kv heads a step of the paged-attention kernel takes as one batched product
+# (`head_group`), by case: all of them up to 3072 query rows a group, which is
+# every case but a 64-token chunk at g 9 (four of eight, 2304 rows)
+PAGED_HEAD_GROUPS = {
+    "paged-b8-t1": 8, "paged-b8-t5": 8, "paged-tp4-b4-t1": 2,
+    "paged-b8-t64-w1024": 8, "paged-b8-t8-w512": 8, "paged-b8-t1-w1024": 8,
+    "paged-b8-t64-w256": 8, "paged-tp4-b8-t64": 2, "paged-g6-b8-t1": 8,
+    "paged-g6-b8-t64": 8, "paged-b8-t5-w304": 8, "paged-b8-t1-w4096": 8,
+    "paged-window-g7-b8-t64-w1024": 4, "paged-window-g7-b8-t1-w1024": 4,
+    "paged-window-g7-b8-t64-w8192": 4, "paged-window-b8-t8-w512": 8,
+    "paged-lead-b1-t8-w1024": 8, "paged-lead-b1-t64-w1024": 8,
+    "paged-window-g7-b1-t64-w1024": 4, "paged-window-g6-b1-t64-w2048": 8,
+    "paged-window-g9-b1-t64-w2048": 4, "paged-window-g9-b8-t64-w1024": 4,
+    "paged-window-g9-b8-t1-w2048": 8,
+    "paged-padded-heads-b8-t1-w1024": 8, "paged-padded-heads-b1-t64-w1024": 8,
+}
+
+
+def test_paged_cases_take_their_heads_in_the_groups_stated():
+    """What the head-group rule chose at every paged case compiled above."""
+    chosen = {}
+    for case, (fn, shapes, _) in CASES.items():
+        if fn is paged_attention:
+            (_, t, hq, _), (_, _, hk, _, _) = shapes[0][0], shapes[1][0]
+            chosen[case] = head_group(t, hq // hk, hk)
+    assert chosen == PAGED_HEAD_GROUPS
 
 
 # the kinds of block pool the cells hold (and a fourth model on the first), each at its configuration's
